@@ -16,7 +16,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from examples._common import respect_jax_platform_env  # noqa: E402
 
 
 def main():
@@ -25,7 +24,6 @@ def main():
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    respect_jax_platform_env()
     if args.smoke:
         args.size_mb, args.iters = 4.0, 3
 
@@ -34,8 +32,9 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as Ps
+
+    from ray_tpu.parallel.ops import shard_map
 
     # The intra-host/slice data plane: psum/all_gather over the local
     # device mesh — the ICI path the reference reaches via NCCL. (The
@@ -53,16 +52,10 @@ def main():
         mesh=mesh, in_specs=Ps("world"), out_specs=Ps("world")))
     gather_fn = functools.partial(jax.lax.all_gather, axis_name="world",
                                   tiled=True)
-    try:
-        # all_gather's replicated output needs the replication check off
-        # (kwarg renamed across jax versions).
-        allgather = jax.jit(shard_map(
-            gather_fn, mesh=mesh, in_specs=Ps("world"), out_specs=Ps(),
-            check_vma=False))
-    except TypeError:
-        allgather = jax.jit(shard_map(
-            gather_fn, mesh=mesh, in_specs=Ps("world"), out_specs=Ps(),
-            check_rep=False))
+    # all_gather's replicated output needs the replication check off.
+    allgather = jax.jit(shard_map(
+        gather_fn, mesh=mesh, in_specs=Ps("world"), out_specs=Ps(),
+        check_vma=False))
 
     jax.block_until_ready(allreduce(x))  # compile
     t0 = time.perf_counter()

@@ -15,7 +15,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from examples._common import respect_jax_platform_env  # noqa: E402
 
 
 class ResNetPredictor:
@@ -41,7 +40,6 @@ def main():
     ap.add_argument("--num-tpus", type=float, default=0)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    respect_jax_platform_env()
     if args.smoke:
         args.images, args.image_size = 64, 64
 

@@ -13,11 +13,14 @@ TPU:         python examples/llm_serve_continuous.py  (pins a chip per
 """
 import argparse
 import json
+import os
+import sys
 import threading
 import time
 import urllib.request
 
-from _common import respect_jax_platform_env
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def main():
@@ -29,19 +32,19 @@ def main():
     args = ap.parse_args()
 
     if args.smoke:
-        import os
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    respect_jax_platform_env()
-    import jax
 
     import ray_tpu
     ray_tpu.init(ignore_reinit_error=True)
 
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_app
-    from ray_tpu.models import GPTConfig, gpt_init
+    from ray_tpu.models import GPTConfig
 
     if args.smoke:
+        import jax
+
+        from ray_tpu.models import gpt_init
         cfg = GPTConfig(vocab_size=272, d_model=64, n_heads=4,
                         n_layers=2, d_ff=128, max_seq_len=256)
         params = gpt_init(jax.random.PRNGKey(0), cfg)
@@ -49,7 +52,10 @@ def main():
                             continuous_batching=True,
                             max_batch=args.streams)
     else:
-        app = build_llm_app(continuous_batching=True,
+        # The replica owns the chip and initialises the (random) weights
+        # there; this driver process never creates a device array.
+        app = build_llm_app(cfg=GPTConfig.gpt2_small(),
+                            continuous_batching=True,
                             max_batch=args.streams, num_tpus=1)
 
     serve.start()

@@ -12,7 +12,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from examples._common import respect_jax_platform_env  # noqa: E402
 
 
 def main():
@@ -23,7 +22,6 @@ def main():
     ap.add_argument("--fragment", type=int, default=512)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    respect_jax_platform_env()
     if args.smoke:
         args.iters, args.fragment = 2, 128
 

@@ -15,7 +15,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-from examples._common import respect_jax_platform_env  # noqa: E402
 
 
 def train_loop(config):
@@ -83,7 +82,6 @@ def main():
     ap.add_argument("--rows", type=int, default=8192)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
-    respect_jax_platform_env()
     if args.smoke:
         args.rows, args.epochs = 1024, 1
 

@@ -3,11 +3,18 @@
 Reference: the role of _raylet.pyx — binding Python to the C++ layer —
 without Cython (not baked into this image): a plain C ABI + ctypes.
 
-Builds lazily with g++ on first use (cached as _native/libray_tpu.so,
-rebuilt when sources are newer). Everything degrades gracefully: callers
-check `available()` and fall back to the pure-Python paths.
+Builds lazily with g++ on first use. The library's file name carries a
+hash of its sources (libray_tpu.<hash>.so), so a library on disk is
+reused exactly when it was built from these sources — file times, which
+a copy of the tree resets, decide nothing. A failed build is reported by
+`available()` / `build_error()`; the runtime's entry points
+(`create_store`, the scheduler's recv mux) raise on it rather than pick
+another implementation.
 """
+import contextlib
 import ctypes
+import glob
+import hashlib
 import mmap as _mmap
 import os
 import subprocess
@@ -18,26 +25,37 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = [os.path.join(_DIR, "src", f)
         for f in ("store.cpp", "transfer.cpp", "dispatch.cpp",
                   "memcopy.cpp")]
-_SO = os.path.join(_DIR, "libray_tpu.so")
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_SO):
-        return True
-    so_m = os.path.getmtime(_SO)
-    return any(os.path.getmtime(s) > so_m for s in _SRC)
+def _so_path() -> str:
+    h = hashlib.sha256()
+    for src in _SRC:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_DIR, f"libray_tpu.{h.hexdigest()[:16]}.so")
 
 
-def _build():
+def _build(so: str):
+    # Per-process temp name: the head and a daemon may build at once.
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-pthread", "-std=c++17",
-           "-o", _SO + ".tmp"] + _SRC
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    if proc.returncode != 0:
-        raise RuntimeError(f"native build failed:\n{proc.stderr}")
-    os.replace(_SO + ".tmp", _SO)
+           "-o", tmp] + _SRC
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True,
+                              timeout=120)
+        if proc.returncode != 0:
+            raise RuntimeError(f"native build failed:\n{proc.stderr}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libray_tpu*.so")):
+        if stale != so:
+            with contextlib.suppress(OSError):  # a racing builder's remove
+                os.remove(stale)
 
 
 def _load():
@@ -46,11 +64,12 @@ def _load():
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if _needs_build():
-                _build()
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
         except Exception as e:  # noqa: BLE001
-            _build_error = str(e)
+            _build_error = f"{type(e).__name__}: {e}"
             return None
         # signatures
         lib.rt_store_create.restype = ctypes.c_void_p
@@ -105,7 +124,7 @@ def _load():
         # memcpy + enqueue + (maybe) one eventfd write, so releasing
         # the GIL around them costs more (a handoff/context-switch
         # opportunity per call) than it buys.
-        qlib = ctypes.PyDLL(_SO)
+        qlib = ctypes.PyDLL(so)
         qlib.disp_add.restype = ctypes.c_int
         qlib.disp_add.argtypes = [ctypes.c_void_p, ctypes.c_int,
                                   ctypes.c_uint64]

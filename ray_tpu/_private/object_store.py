@@ -1502,7 +1502,7 @@ class ArenaObjectStore:
     allocation, not copying, dominates the file store's put path; the
     raw single-core memcpy ceiling is 7.9 GB/s, so the reference's
     18.5 GB/s single-client figure — measured on a 64-vCPU host — is
-    not reachable on this hardware class; see ROUND2_NOTES).
+    not reachable on this hardware class).
 
     Reads are ZERO-COPY with pin-until-release: deserialized arrays
     alias the arena through an _ArenaPin buffer owner, and the reader
@@ -1985,17 +1985,19 @@ def _native_key(object_id: ObjectID) -> bytes:
 
 
 def create_store(session_dir: str, capacity: Optional[int] = None):
-    """Pick the store backend: the native C++ arena by DEFAULT (2x put
-    bandwidth — page reuse instead of per-put tmpfs page allocation),
-    falling back to the file-per-object store where the native lib can't
-    build. RAY_TPU_FILE_STORE=1 forces the fallback."""
+    """Pick the store backend: the native C++ arena (2x put bandwidth —
+    page reuse instead of per-put tmpfs page allocation) wherever
+    _ArenaPin can exist (PEP 688, Python >= 3.12). RAY_TPU_FILE_STORE=1
+    chooses the file-per-object store. A native library that fails to
+    build is an error, not a reason to run on the other store: head and
+    workers must agree, and a slow store must not pass unnoticed."""
     import sys
-    if (os.environ.get("RAY_TPU_FILE_STORE") != "1"
-            and sys.version_info >= (3, 12)):  # _ArenaPin needs PEP 688
-        try:
-            from .. import _native
-            if _native.available():
-                return ArenaObjectStore(session_dir, capacity)
-        except Exception:
-            pass
-    return ObjectStore(session_dir, capacity)
+    if (os.environ.get("RAY_TPU_FILE_STORE") == "1"
+            or sys.version_info < (3, 12)):
+        return ObjectStore(session_dir, capacity)
+    from .. import _native
+    if not _native.available():
+        raise RuntimeError(
+            f"native library unavailable ({_native.build_error()}); "
+            "set RAY_TPU_FILE_STORE=1 to run on the file store")
+    return ArenaObjectStore(session_dir, capacity)

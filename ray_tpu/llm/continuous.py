@@ -192,6 +192,13 @@ class ContinuousBatchingEngine:
         req = slot.req
         if req.temperature <= 0.0:
             token = int(np.argmax(logits))
+            # argmax lands on a NaN when there is one, so one scalar
+            # read tells whether the row was finite; a broken forward
+            # must fail the streams, not emit token 0 forever.
+            if not np.isfinite(logits[token]):
+                raise FloatingPointError(
+                    f"non-finite logits in slot {i} at position "
+                    f"{slot.pos}")
         else:
             z = logits.astype(np.float64) / req.temperature
             z -= z.max()

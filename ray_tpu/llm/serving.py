@@ -90,7 +90,8 @@ def build_llm_app(cfg=None, params=None, *, num_replicas: int = 1,
                   num_tpus: float = 0, continuous_batching: bool = False,
                   max_batch: int = 8):
     """Serve application: POST {"prompt": ..., "max_tokens": ...,
-    "stream": bool} — streaming responses ride Serve's chunked path.
+    "stream": bool} — streaming responses ride Serve's chunked path;
+    a non-streaming reply is {"text", "device", "engine_steps"}.
 
     ``continuous_batching=True`` backs each replica with ONE shared
     ContinuousBatchingEngine (llm/continuous.py): concurrent requests
@@ -115,6 +116,23 @@ def build_llm_app(cfg=None, params=None, *, num_replicas: int = 1,
             else:
                 self.engine = LLMEngine(cfg=cfg, params=params)
                 self._stream = self.engine.stream
+            # Which device this replica computes on, as its own process
+            # sees it: every reply carries it, so a client (and the chip
+            # smoke) can tell a chip replica from one that fell to CPU.
+            import os
+
+            import jax
+
+            from .. import api
+            dev = jax.tree.leaves(self.engine.params)[0].devices()
+            self._device = {
+                "platform": jax.devices()[0].platform,
+                "device_kind": jax.devices()[0].device_kind,
+                "param_device_ids": sorted(d.id for d in dev),
+                "local_device_count": jax.local_device_count(),
+                "tpu_ids": api.get_tpu_ids(),
+                "pid": os.getpid(),
+            }
 
         def _lazy_stream(self, prompt, max_tokens, temperature):
             # Defer the submit to first iteration: the serve replica's
@@ -139,8 +157,9 @@ def build_llm_app(cfg=None, params=None, *, num_replicas: int = 1,
             if body.get("stream"):
                 return self._lazy_stream(prompt, max_tokens,
                                          temperature)
-            return {"text": "".join(
-                self._stream(prompt, max_tokens, temperature))}
+            text = "".join(self._stream(prompt, max_tokens, temperature))
+            return {"text": text, "device": self._device,
+                    "engine_steps": getattr(self.engine, "steps", None)}
 
         def generate_stream(self, prompt: str, max_tokens: int = 32,
                             temperature: float = 0.0):

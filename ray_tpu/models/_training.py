@@ -8,10 +8,14 @@ are identical, so they live here once. Model modules supply
 
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.attention import kernel_sharding
 
 
 def place_params(params, axes, mesh, rules):
@@ -48,6 +52,15 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
     import optax
 
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
+    # XLA partitions everything from the shardings except the attention
+    # kernels, which are told their split (batch and heads, by the same
+    # rule table) while the step is traced.
+    if mesh is not None and rules is not None:
+        attention_split = functools.partial(
+            kernel_sharding, mesh,
+            rules.spec(("batch", "heads", None, None)))
+    else:
+        attention_split = contextlib.nullcontext
 
     def init_state(key):
         params = init_fn(key)
@@ -58,7 +71,9 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
                 "step": jnp.zeros((), dtype=jnp.int32)}
 
     def train_step(state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
+        with attention_split():
+            loss, grads = jax.value_and_grad(loss_fn)(
+                state["params"], batch)
         updates, new_opt = optimizer.update(
             grads, state["opt_state"], state["params"])
         new_params = optax.apply_updates(state["params"], updates)

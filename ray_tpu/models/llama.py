@@ -56,8 +56,8 @@ class LlamaConfig:
     @classmethod
     def tpu_bench(cls) -> "LlamaConfig":
         """Single-chip MFU-bench shape: head_dim 128 (MXU-native lane
-        width — GPT-2's head_dim 64 half-fills the systolic array, the
-        documented MFU sink in docs/MFU_ROOFLINE.md), 4:1 GQA, S=2048,
+        width — GPT-2's head_dim 64 half-fills the systolic array),
+        4:1 GQA, S=2048,
         ~250M params so optimizer+activations fit v5e HBM without
         remat."""
         return cls(vocab_size=32000, d_model=1024, n_heads=8,

@@ -14,13 +14,17 @@ attention written for the TPU memory hierarchy, forward AND backward:
   matrix is ever materialized, so long-context *training* fits.
 
 Layout: [batch, heads, seq, head_dim]. The jax reference implementation
-serves non-TPU backends and correctness tests; set
-RAY_TPU_PALLAS_INTERPRET=1 to run the kernels in interpreter mode on CPU
-(the SURVEY §4 CPU-mirror pattern for kernel tests).
+serves non-TPU backends, sequences that are not a multiple of 128, and
+correctness tests; set RAY_TPU_PALLAS_INTERPRET=1 to run the kernels in
+interpreter mode on CPU (the SURVEY §4 CPU-mirror pattern for kernel
+tests). On a TPU backend the kernels are the only path for a tileable
+sequence: a kernel that fails to compile is an error.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 import os
@@ -57,25 +61,72 @@ def _interpret() -> bool:
 
 
 def _on_tpu() -> bool:
+    """Whether the kernels run: compiled on a TPU backend, interpreted
+    where RAY_TPU_PALLAS_INTERPRET=1 asks for it on another backend. A
+    backend that fails to initialise raises here, and interpret mode on
+    a TPU is refused: neither may quietly pick an implementation."""
+    on_tpu = jax.devices()[0].platform == "tpu"
     if _interpret():
+        if on_tpu:
+            raise RuntimeError(
+                "RAY_TPU_PALLAS_INTERPRET=1 on a TPU backend: the "
+                "interpreter is the CPU test mode; unset it to run the "
+                "compiled kernels")
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return on_tpu
 
 
 def _kernel_ok(seq_len: int) -> bool:
     return _on_tpu() and seq_len >= 128 and seq_len % 128 == 0
 
 
+# (mesh, PartitionSpec over [batch, heads, seq, head_dim]) while a sharded
+# program is being traced; see kernel_sharding.
+_SHARDING: contextvars.ContextVar = contextvars.ContextVar(
+    "flash_attention_sharding", default=None)
+
+
+@contextlib.contextmanager
+def kernel_sharding(mesh, spec):
+    """Trace the enclosed code with flash_attention's kernels run once per
+    shard of `mesh`: q/k/v [batch, heads, seq, head_dim] are split by
+    `spec`, which may name mesh axes for batch and heads only — each
+    kernel instance sees whole sequences.
+
+    A pallas_call is opaque to the SPMD partitioner: jax refuses to lower
+    one inside a program partitioned over several devices ("Mosaic
+    kernels cannot be automatically partitioned"), so a sharded train
+    step states here how its attention is split
+    (models._training.make_train_step_for does, from its rule table)."""
+    if spec[2:] != (None,) * len(spec[2:]):
+        raise ValueError(
+            f"flash_attention kernels need whole sequences and head_dim; "
+            f"{spec} splits them (parallel.sequence does sequence "
+            f"parallelism)")
+    token = _SHARDING.set((mesh, spec))
+    try:
+        yield
+    finally:
+        _SHARDING.reset(token)
+
+
+def _per_shard(fn):
+    """`fn` over q-shaped arrays, run per shard under kernel_sharding."""
+    ctx = _SHARDING.get()
+    if ctx is None:
+        return fn
+    mesh, spec = ctx
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
+
+
 def _pick_block(seq_len: int) -> int:
     """Largest block that divides the sequence: fewer grid steps amortize
-    the per-step VPU/online-softmax overhead (measured on v5e: 512 beats
-    128 by ~2.5x at S=2048, and 1024 beats 512 by ~10% at S=1024 —
-    docs/MFU_ROOFLINE.md block sweep). Capped at 1024: the f32 score
-    block is block_q*block_k*4B of VMEM (4 MB at 1024²); the causal
-    index clamp assumes exact tiling."""
+    the per-step VPU/online-softmax overhead (measured in round 3 on a
+    v5e, not re-measured: 512 beats 128 by ~2.5x at S=2048, and 1024
+    beats 512 by ~10% at S=1024). Capped at 1024: the f32 score block
+    is block_q*block_k*4B of VMEM (4 MB at 1024²); the causal index
+    clamp assumes exact tiling."""
     for b in (1024, 512, 256, 128):
         if seq_len % b == 0:
             return b
@@ -221,10 +272,13 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
         interpret=_interpret(),
     )(qf, kf, vf)
     out = result[0].reshape(batch, heads, seq_len, head_dim)
-    # lse stays lane-replicated (bh, seq, 128): the backward feeds it
+    # lse stays lane-replicated (.., seq, 128): the backward feeds it
     # straight back to the kernels, avoiding a slice + rebroadcast HBM
-    # round trip per training step.
-    return out, (result[1] if save_lse else None)
+    # round trip per training step. It carries q's leading [batch, heads]
+    # so it shards like q (see kernel_sharding).
+    lse = result[1].reshape(batch, heads, seq_len, 128) if save_lse \
+        else None
+    return out, lse
 
 
 # ---------------------------------------------------------------------------
@@ -345,7 +399,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     kf = k.reshape(bh, seq_len, head_dim)
     vf = v.reshape(bh, seq_len, head_dim)
     dof = g.reshape(bh, seq_len, head_dim)
-    lsef = lse  # already lane-replicated (bh, seq, 128) from forward
+    lsef = lse.reshape(bh, seq_len, 128)  # lane-replicated by forward
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA.
     delta = jnp.broadcast_to(
         jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
@@ -463,9 +517,9 @@ def _flash_attention_fwd_impl(q, k, v, causal, sm_scale,
     seq_len = q.shape[-2]
     if _kernel_ok(seq_len):
         block = _pick_block(seq_len)
-        out, lse = _flash_forward(q, k, v, causal, scale,
-                                  block_q=block, block_k=block,
-                                  save_lse=save_lse)
+        out, lse = _per_shard(functools.partial(
+            _flash_forward, causal=causal, sm_scale=scale,
+            block_q=block, block_k=block, save_lse=save_lse))(q, k, v)
         return out, (out, lse)
     return mha_reference(q, k, v, causal, scale), (None, None)
 
@@ -486,8 +540,9 @@ def _flash_bwd(causal, sm_scale, residuals, g):
             q, k, v)
         return vjp(g)
     block = _pick_block(q.shape[-2])
-    return _flash_backward(q, k, v, o, lse, g, causal, scale,
-                           block_q=block, block_k=block)
+    return _per_shard(functools.partial(
+        _flash_backward, causal=causal, sm_scale=scale,
+        block_q=block, block_k=block))(q, k, v, o, lse, g)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

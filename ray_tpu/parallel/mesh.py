@@ -73,7 +73,8 @@ def make_mesh(config: Optional[MeshConfig] = None,
 
     On real hardware, uses jax's device topology ordering
     (mesh_utils.create_device_mesh) so ICI neighbours land adjacent on the
-    mesh; on CPU test backends it falls back to a plain reshape.
+    mesh, and a shape the topology cannot hold is an error. CPU devices
+    have no topology: they are reshaped in order.
     """
     import jax
     from jax.sharding import Mesh
@@ -83,12 +84,12 @@ def make_mesh(config: Optional[MeshConfig] = None,
     shape_map = config.resolve(len(devices))
     names = tuple(axis_names or [a for a in AXIS_ORDER])
     shape = tuple(shape_map.get(a, 1) for a in names)
-    try:
+    if devices[0].platform == "cpu":
+        dev_array = np.array(devices).reshape(shape)
+    else:
         from jax.experimental import mesh_utils
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=np.array(devices))
-    except Exception:
-        dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, names)
 
 

@@ -14,47 +14,31 @@ AxisName = Union[str, Sequence[str]]
 
 
 def shard_map(f, *, mesh=None, in_specs=None, out_specs=None, **kw):
-    """Compat shim: jax.shard_map (new home, keyword-only) with fallback
-    to jax.experimental.shard_map on older jax. All ray_tpu call sites
-    route through here so the deprecated import lives in one place."""
+    """jax.shard_map under the name every ray_tpu call site imports."""
     import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pvary(tree, axis_name):
     """Mark values as device-varying over `axis_name` for shard_map's
-    varying-manual-axes type system (no-op on jax versions without it).
-    Needed on scan/fori_loop carries initialized from constants.
-
-    jax is renaming lax.pvary -> lax.pcast(..., to='varying') (the old
-    name warns on recent jax); prefer the new spelling when present."""
+    varying-manual-axes type system. Needed on scan/fori_loop carries
+    initialized from constants. Idempotent: a value that already varies
+    over the axis is returned as is."""
     import jax
     from jax import lax
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        def fn(x):
-            try:
-                return pcast(x, (axis_name,), to="varying")
-            except ValueError as e:
-                # Only the already-varying case is benign (pvary was
-                # idempotent); other ValueErrors must surface here, not
-                # as confusing type mismatches deep inside shard_map.
-                if "varying" in str(e):
-                    return x
-                raise
-    elif hasattr(lax, "pvary"):
-        fn = lambda x: lax.pvary(x, (axis_name,))  # noqa: E731
-    else:
-        return tree
-    try:
-        return jax.tree.map(fn, tree)
-    except AttributeError:
-        return tree
+
+    def fn(x):
+        try:
+            return lax.pcast(x, (axis_name,), to="varying")
+        except ValueError as e:
+            # Only the already-varying case is benign; other ValueErrors
+            # must surface here, not as confusing type mismatches deep
+            # inside shard_map.
+            if "varying" in str(e):
+                return x
+            raise
+    return jax.tree.map(fn, tree)
 
 
 def allreduce(x, axis_name: AxisName = "dp"):

@@ -25,6 +25,13 @@ class _DeploymentState:
         self.replicas: List = []          # live actor handles
         self.replica_seq = 0              # monotonic replica name suffix
         self.target = info["initial_replicas"]
+        # actor id -> future of the replica's first check_health call,
+        # which the actor runs once its constructor has returned. Until
+        # it completes the replica is STARTING, not unhealthy: a model
+        # that takes a minute to load onto a chip must not be replaced
+        # every health_check_timeout_s (reference: deployment_state.py
+        # ReplicaState.STARTING).
+        self.starting: Dict[Any, "asyncio.Future"] = {}
         self.last_upscale_ok_t = 0.0      # autoscaling decision debounce
         self.last_downscale_ok_t = 0.0
 
@@ -211,6 +218,7 @@ class ServeController:
             except Exception:
                 pass
         st.replicas = []
+        st.starting.clear()
         self._long_poll.notify_changed(f"replicas::{name}", [])
 
     def _start_one(self, name: str, st: _DeploymentState):
@@ -225,10 +233,14 @@ class ServeController:
         for name, st in self._deployments.items():
             changed = False
             while len(st.replicas) < st.target:
-                st.replicas.append(self._start_one(name, st))
+                replica = self._start_one(name, st)
+                st.starting[replica._actor_id] = asyncio.ensure_future(
+                    replica.check_health.remote())
+                st.replicas.append(replica)
                 changed = True
             while len(st.replicas) > st.target:
                 victim = st.replicas.pop()
+                st.starting.pop(victim._actor_id, None)
                 try:
                     ray_tpu.kill(victim)
                 except Exception:
@@ -243,19 +255,31 @@ class ServeController:
         for name, st in self._deployments.items():
             # Health: replace dead replicas (reference:
             # deployment_state.py check_and_update_replicas).
-            alive, dead = [], 0
+            alive, dead = [], []
             for r in st.replicas:
+                started = st.starting.get(r._actor_id)
+                if started is not None and not started.done():
+                    alive.append(r)  # constructor still running
+                    continue
                 try:
-                    ok = await asyncio.wait_for(
-                        r.check_health.remote(),
-                        timeout=st.info["health_check_timeout_s"])
-                    if ok:
-                        alive.append(r)
+                    if started is not None:
+                        del st.starting[r._actor_id]
+                        ok = started.result()
                     else:
-                        dead += 1
-                except Exception:
-                    dead += 1
-            if dead or len(alive) != len(st.replicas):
+                        ok = await asyncio.wait_for(
+                            r.check_health.remote(),
+                            timeout=st.info["health_check_timeout_s"])
+                except Exception:  # lint: broad-except-ok any failure of the health call (actor died, constructor raised, timeout) means the replica is replaced
+                    ok = False
+                (alive if ok else dead).append(r)
+            for r in dead:
+                # A replica given up on must release what it holds (its
+                # chip, above all) before its replacement can be placed.
+                try:
+                    ray_tpu.kill(r)
+                except Exception:  # lint: broad-except-ok racing actor death; kill is idempotent
+                    pass
+            if dead:
                 st.replicas = alive
                 self._long_poll.notify_changed(
                     f"replicas::{name}", list(st.replicas))

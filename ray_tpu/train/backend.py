@@ -34,9 +34,10 @@ class JaxBackendConfig(BackendConfig):
     (replacing `dist.init_process_group(nccl|gloo)`, torch/config.py:115).
 
     After on_start, `jax.devices()` inside every worker spans the whole
-    group: each worker contributes its visible TPU chips (or one CPU
-    device on test backends) and data-parallel training proceeds by mesh
-    sharding, not gradient hooks.
+    group — each worker contributes its local devices and data-parallel
+    training proceeds by mesh sharding, not gradient hooks — or on_start
+    has raised: a group whose runtimes stayed isolated (one-chip workers
+    on one TPU host) never passes for a data-parallel one.
     """
 
     coordinator_port: Optional[int] = None
@@ -58,6 +59,23 @@ class JaxBackendConfig(BackendConfig):
                                   context.world_rank)
         ensure_distributed(coordinator, context.world_size,
                            context.world_rank)
+        import jax
+        expected = context.world_size * jax.local_device_count()
+        if jax.device_count() != expected:
+            # jax.distributed joins CPU processes over gRPC, but it does
+            # not join TPU runtimes: workers pinned to separate chips of
+            # one host each keep a runtime of their own, and a "data
+            # parallel" step there would be world_size unsynchronised
+            # replicas. Refuse rather than train that way.
+            raise RuntimeError(
+                f"JaxBackendConfig: {context.world_size} workers joined "
+                f"jax.distributed but this worker's runtime holds "
+                f"{jax.device_count()} device(s), not {expected}: the "
+                f"workers' {jax.devices()[0].platform} runtimes are "
+                "isolated. Give one worker all chips of the host "
+                "(resources_per_worker={'TPU': n}) and shard over a "
+                "mesh, or pass init_distributed=False for independent "
+                "replicas.")
 
 
 @dataclass

@@ -118,8 +118,8 @@ def test_map_batches_actor_pool_survives_worker_death():
 
 
 def test_map_batches_actor_pool_survives_transient_exception():
-    """The BENCH_r02 regression class: a transient in-actor exception
-    (remote-compile hiccup) retries instead of killing the pipeline."""
+    """A transient in-actor exception (the regression class of an early
+    bench record) retries instead of killing the pipeline."""
     from ray_tpu import data as rdata
 
     marker = _marker()

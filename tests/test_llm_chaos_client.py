@@ -155,7 +155,7 @@ import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %r)
 import jax
-jax.config.update("jax_platforms", "cpu")  # env alone is overridable
+jax.config.update("jax_platforms", "cpu")
 import ray_tpu
 from ray_tpu._private import state
 from ray_tpu.util.client import server

@@ -213,6 +213,37 @@ class TestFlashKernelInterpret:
                 np.asarray(a) / scale, np.asarray(b) / scale,
                 atol=6e-3, rtol=6e-3)
 
+    def test_kernels_run_per_shard_under_a_mesh(self):
+        """A sharded train step runs the kernels under shard_map over the
+        rule table's batch/head axes (a pallas_call cannot be
+        auto-partitioned on TPU) and matches the unsharded step."""
+        import dataclasses
+        cfg = dataclasses.replace(GPTConfig.tiny(), remat=False)
+        tokens = np.random.default_rng(0).integers(
+            0, cfg.vocab_size, (4, 128), dtype=np.int32)  # S=128: kernels
+        batch = (jnp.asarray(tokens), jnp.asarray(np.roll(tokens, -1, 1)))
+        init1, step1 = make_train_step(cfg, donate=False)
+        _, m1 = step1(init1(jax.random.PRNGKey(0)), batch)
+        mesh = make_mesh(MeshConfig(dp=4, tp=2))
+        init2, step2 = make_train_step(cfg, mesh=mesh, rules=tp_rules(),
+                                       donate=False)
+        state2 = init2(jax.random.PRNGKey(0))
+        sharded = shard_batch(batch, mesh)
+        assert "sdy.manual_computation" in step2.lower(
+            state2, sharded).as_text()          # shard_map's lowering
+        _, m2 = step2(state2, sharded)
+        np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
+                                   rtol=1e-3)
+
+    def test_kernel_sharding_refuses_a_sequence_split(self):
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.ops.attention import kernel_sharding
+        with pytest.raises(ValueError, match="whole sequences"):
+            with kernel_sharding(make_mesh(MeshConfig(dp=4, sp=2)),
+                                 P("dp", None, "sp", None)):
+                pass
+
     def test_kernel_uneven_heads_batch(self):
         ks = jax.random.split(jax.random.PRNGKey(5), 3)
         q, k, v = (jax.random.normal(kk, (3, 5, 128, 32), jnp.float32)

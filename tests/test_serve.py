@@ -161,6 +161,34 @@ def test_status_and_delete():
     assert "noop" not in serve.status()
 
 
+def test_slow_constructor_is_starting_not_dead():
+    """A replica whose constructor outlasts health_check_timeout_s (a
+    model loading onto a chip) is STARTING: the controller must neither
+    replace it nor leave replaced replicas alive holding resources."""
+    from ray_tpu.util.state import list_actors
+
+    @serve.deployment(health_check_timeout_s=0.5)
+    class SlowInit:
+        def __init__(self):
+            time.sleep(3.0)  # > a health tick + the 0.5 s timeout
+
+        def __call__(self, _):
+            import os
+            return os.getpid()
+
+    handle = serve.run(SlowInit.bind(), name="slowinit_app",
+                       route_prefix="/slowinit")
+    pids = {handle.remote(i).result(timeout_s=60) for i in range(4)}
+    time.sleep(2.1)  # one more health tick after the replica is ready
+    pids.add(handle.remote(0).result(timeout_s=60))
+    assert len(pids) == 1, f"replica was replaced while starting: {pids}"
+    replicas = [a["name"] for a in list_actors()
+                if (a.get("name") or "").startswith(
+                    "SERVE_REPLICA::SlowInit#")
+                and a.get("state") != "DEAD"]
+    assert replicas == ["SERVE_REPLICA::SlowInit#1"], replicas
+
+
 def test_autoscaling_policy_math():
     cfg = AutoscalingConfig(min_replicas=1, max_replicas=10,
                             target_ongoing_requests=2.0)
