@@ -596,6 +596,8 @@ def main(argv=None) -> int:
         node = state.current()
         report["store_backend"] = type(node.store).__name__
         report["native_dispatch"] = type(node.pool._mux).__name__
+        report["store_dir"] = node.store_dir
+        report["store_capacity"] = node.store.capacity
         _require(report["store_backend"] == "ArenaObjectStore"
                  and report["native_dispatch"] == "_NativeMux",
                  f"not on the native store/dispatcher: {report} "
@@ -661,6 +663,8 @@ def main(argv=None) -> int:
          first_step_s=train["first_step_s"],
          store_backend=report["store_backend"],
          native_dispatch=report["native_dispatch"],
+         store_dir=report["store_dir"],
+         store_capacity=report["store_capacity"],
          compile_cache_dir=cache_dir,
          compile_cache_entries=[cache_entries_before, n_cache],
          parent_backend_initialised=False,

@@ -101,6 +101,10 @@ def _load():
         lib.rt_store_close.restype = None
         lib.rt_store_close.argtypes = [ctypes.c_void_p]
         lib.rt_store_unlink.argtypes = [ctypes.c_char_p]
+        lib.rt_store_fail_step.restype = ctypes.c_char_p
+        lib.rt_store_fail_step.argtypes = []
+        lib.rt_store_fail_errno.restype = ctypes.c_int
+        lib.rt_store_fail_errno.argtypes = []
         lib.rt_transfer_serve.restype = ctypes.c_void_p
         lib.rt_transfer_serve.argtypes = [ctypes.c_void_p, ctypes.c_uint16]
         lib.rt_transfer_port.restype = ctypes.c_uint16
@@ -246,13 +250,29 @@ class NativeStore:
         else:
             self._h = lib.rt_store_open(path.encode())
         if not self._h:
-            raise RuntimeError(
-                f"failed to {'create' if create else 'open'} arena {path}")
-        fd = os.open(path, os.O_RDWR)
+            # OSError with the failing step's errno: EEXIST arrives as
+            # FileExistsError (a peer made the arena first), and anything
+            # else names what the machine refused.
+            err = lib.rt_store_fail_errno()
+            raise OSError(
+                err, f"arena {'create' if create else 'open'}: "
+                f"{lib.rt_store_fail_step().decode()} failed"
+                + (f" at capacity {int(capacity)}" if create and capacity
+                   else "") + f": {os.strerror(err)}", path)
         try:
-            self._map = _mmap.mmap(fd, os.path.getsize(path))
-        finally:
-            os.close(fd)
+            fd = os.open(path, os.O_RDWR)
+            try:
+                self._map = _mmap.mmap(fd, os.path.getsize(path))
+            finally:
+                os.close(fd)
+        except OSError:
+            # The C side mapped the arena but this process's own view
+            # did not fit (RLIMIT_AS): leave nothing half-made behind.
+            lib.rt_store_close(self._h)
+            self._h = None
+            if create:
+                lib.rt_store_unlink(path.encode())
+            raise
         self._view = memoryview(self._map)
 
     # -- object API --------------------------------------------------------

@@ -216,6 +216,21 @@ bool evict_one(Store* st) {
   return true;
 }
 
+// Why the last rt_store_create/rt_store_open of this thread returned
+// null: the step that failed and its errno, for the Python side's OSError.
+thread_local const char* g_fail_step = "";
+thread_local int g_fail_errno = 0;
+
+// Failure exit of create/open: record step + errno, drop the fd, and
+// remove a half-made arena file so it cannot be mistaken for a peer's.
+Store* fail(const char* step, int fd, const char* unlink_path) {
+  g_fail_errno = errno;
+  g_fail_step = step;
+  if (fd >= 0) close(fd);
+  if (unlink_path) unlink(unlink_path);
+  return nullptr;
+}
+
 }  // namespace
 
 extern "C" {
@@ -223,10 +238,10 @@ extern "C" {
 Store* rt_store_create(const char* path, uint64_t capacity) {
   if (capacity < sizeof(Header) + (1 << 20)) capacity = sizeof(Header) + (1 << 20);
   int fd = open(path, O_CREAT | O_RDWR | O_EXCL, 0600);
-  if (fd < 0) return nullptr;
-  if (ftruncate(fd, static_cast<off_t>(capacity)) != 0) { close(fd); return nullptr; }
+  if (fd < 0) return fail("open", -1, nullptr);
+  if (ftruncate(fd, static_cast<off_t>(capacity)) != 0) return fail("ftruncate", fd, path);
   void* base = mmap(nullptr, capacity, PROT_READ | PROT_WRITE, MAP_SHARED, fd, 0);
-  if (base == MAP_FAILED) { close(fd); return nullptr; }
+  if (base == MAP_FAILED) return fail("mmap", fd, path);
   Store* st = new Store{fd, static_cast<uint8_t*>(base), capacity, nullptr};
   Header* h = reinterpret_cast<Header*>(base);
   memset(h, 0, sizeof(Header));
@@ -250,13 +265,17 @@ Store* rt_store_create(const char* path, uint64_t capacity) {
 
 Store* rt_store_open(const char* path) {
   int fd = open(path, O_RDWR);
-  if (fd < 0) return nullptr;
+  if (fd < 0) return fail("open", -1, nullptr);
   struct stat sb;
-  if (fstat(fd, &sb) != 0) { close(fd); return nullptr; }
+  if (fstat(fd, &sb) != 0) return fail("fstat", fd, nullptr);
   void* base = mmap(nullptr, sb.st_size, PROT_READ | PROT_WRITE, MAP_SHARED, fd, 0);
-  if (base == MAP_FAILED) { close(fd); return nullptr; }
+  if (base == MAP_FAILED) return fail("mmap", fd, nullptr);
   Header* h = reinterpret_cast<Header*>(base);
-  if (h->magic != kMagic) { munmap(base, sb.st_size); close(fd); return nullptr; }
+  if (h->magic != kMagic) {
+    munmap(base, sb.st_size);
+    errno = EILSEQ;  // a file, but not a published arena
+    return fail("magic check", fd, nullptr);
+  }
   return new Store{fd, static_cast<uint8_t*>(base),
                    static_cast<uint64_t>(sb.st_size), h};
 }
@@ -353,6 +372,9 @@ void rt_store_close(Store* st) {
 }
 
 int rt_store_unlink(const char* path) { return unlink(path); }
+
+const char* rt_store_fail_step() { return g_fail_step; }
+int rt_store_fail_errno() { return g_fail_errno; }
 
 uint8_t* rt_store_base_ptr(Store* st) { return st->base; }
 

@@ -35,8 +35,9 @@ class RayConfig:
         # objects below this size ride inline in control messages
         # (reference: max_direct_call_object_size)
         "inline_object_max_bytes": 100 * 1024,
-        # object store capacity as a fraction of /dev/shm when not set
-        # explicitly (reference: object_store_memory default 30%)
+        # object store capacity, when not set explicitly, as a fraction of
+        # what /dev/shm has free, within physical memory and the process's
+        # rlimits (reference: object_store_memory default 30%)
         "object_store_memory_fraction": 0.5,
         # worker boot: seconds to wait for the process to connect
         "worker_register_timeout_s": 60.0,

@@ -37,7 +37,7 @@ from . import wiretap
 from .config import ray_config
 from .ids import NodeID, WorkerID
 from .netcomm import PullManager, TransferServer, store_paths_factory
-from .object_store import create_store
+from .object_store import create_session_store
 from .resources import detect_node_resources
 from .scheduler import WorkerHandle, WorkerPool
 
@@ -56,10 +56,9 @@ class NodeDaemon:
         self.node_hex = self.node_id.hex()
         session_name = f"node_{int(time.time())}_{uuid.uuid4().hex[:8]}"
         self.session_dir = os.path.join("/tmp/ray_tpu_sessions", session_name)
-        self.store_dir = os.path.join("/dev/shm", f"ray_tpu_{session_name}")
         os.makedirs(self.session_dir, exist_ok=True)
-        self.store = create_store(self.store_dir,
-                                  capacity=object_store_memory)
+        self.store, self.store_dir = create_session_store(
+            session_name, self.session_dir, object_store_memory)
         for d in (self.session_dir, self.store_dir):
             try:
                 with open(os.path.join(d, ".owner_pid"), "w") as f:

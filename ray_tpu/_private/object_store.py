@@ -84,16 +84,35 @@ def _put_gate(size: int, prefaulted: bool = False):
     return _NullGate()
 
 
-def _default_capacity() -> int:
-    """Default store capacity: a fraction of /dev/shm (reference defaults
-    plasma to 30% of system memory, ray_config_def.h object_store_memory;
-    RAY_TPU_OBJECT_STORE_MEMORY_FRACTION overrides)."""
+def _default_capacity(store_dir: str = "/dev/shm") -> int:
+    """Default store capacity: a fraction of what the store's filesystem
+    has free (reference defaults plasma to 30% of system memory,
+    ray_config_def.h object_store_memory;
+    RAY_TPU_OBJECT_STORE_MEMORY_FRACTION overrides) — bounded by what the
+    machine lets one process map. A sandbox's tmpfs can report more room
+    than the machine has memory (the v5e machine: 95G of /dev/shm on 45
+    GiB), and every process maps the arena twice (the C side and its own
+    zero-copy view), so the bounds are physical memory, an eighth of a
+    finite RLIMIT_AS, and a finite RLIMIT_FSIZE."""
+    import resource
     try:
-        st = os.statvfs("/dev/shm")
-        return int(st.f_bsize * st.f_bavail
-                   * float(ray_config.object_store_memory_fraction))
+        st = os.statvfs(store_dir)
+        room = st.f_bsize * st.f_bavail
     except OSError:
         return 2 << 30
+    try:
+        room = min(room, os.sysconf("SC_PHYS_PAGES")
+                   * os.sysconf("SC_PAGE_SIZE"))
+    except (ValueError, OSError):
+        pass
+    cap = int(room * float(ray_config.object_store_memory_fraction))
+    vm_limit = resource.getrlimit(resource.RLIMIT_AS)[0]
+    if vm_limit != resource.RLIM_INFINITY:
+        cap = min(cap, vm_limit // 8)
+    file_limit = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    if file_limit != resource.RLIM_INFINITY:
+        cap = min(cap, file_limit)
+    return cap
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +329,7 @@ class ObjectStore:
     def __init__(self, session_dir: str, capacity: Optional[int] = None):
         self._dir = session_dir
         os.makedirs(session_dir, exist_ok=True)
-        self._capacity = capacity or _default_capacity()
+        self._capacity = capacity or _default_capacity(session_dir)
         self._segments: Dict[ObjectID, _Segment] = {}
         self._used = 0
         self._graveyard = []  # mmaps with live exported buffers
@@ -1520,14 +1539,17 @@ class ArenaObjectStore:
         from .. import _native
         os.makedirs(session_dir, exist_ok=True)
         self._path = os.path.join(session_dir, "arena.shm")
-        self._capacity = capacity or _default_capacity()
+        self._capacity = capacity or _default_capacity(session_dir)
         self._spill_dir = session_dir.rstrip("/") + "_spill"
         self._spill = _SpillTarget(self._spill_dir)
         try:
             self._store = _native.NativeStore(
                 self._path, self._capacity, create=True)
             self._owner = True
-        except (RuntimeError, FileExistsError):
+        except FileExistsError:
+            # A peer of this session made the arena first. Any other
+            # failure to create it is the machine's refusal and surfaces
+            # with its errno.
             self._store = _native.NativeStore(self._path, create=False)
             self._owner = False
         self._lock = lockdep.rlock("object_store.arena_store")
@@ -2001,3 +2023,54 @@ def create_store(session_dir: str, capacity: Optional[int] = None):
             f"native library unavailable ({_native.build_error()}); "
             "set RAY_TPU_FILE_STORE=1 to run on the file store")
     return ArenaObjectStore(session_dir, capacity)
+
+
+def create_session_store(session_name: str, session_dir: str,
+                         capacity: Optional[int] = None):
+    """The store of a head or daemon session, as (store, store_dir).
+
+    It lives in /dev/shm. A machine that refuses it there (no tmpfs at
+    /dev/shm, a mount that cannot be mapped shared, a limit the bounds of
+    `_default_capacity` do not know) gets the same store under the
+    session's own directory — pages behind a disk instead of memory, so
+    slower, and said loudly; the reference's plasma falls back to /tmp
+    the same way. The first refusal is chained to a second one."""
+    import shutil
+    import sys
+    store_dir = os.path.join("/dev/shm", f"ray_tpu_{session_name}")
+    try:
+        return create_store(store_dir, capacity), store_dir
+    except OSError as e:
+        refused = e
+    shutil.rmtree(store_dir, ignore_errors=True)
+    fallback_dir = os.path.join(session_dir, "store")
+    sys.stderr.write(
+        f"ray_tpu: WARNING: /dev/shm refused the object store "
+        f"({refused}; {_shm_facts()}); using {fallback_dir} instead, "
+        f"which is slower.\n")
+    try:
+        return create_store(fallback_dir, capacity), fallback_dir
+    except OSError as e:
+        raise e from refused
+
+
+def _shm_facts() -> str:
+    """What this process can see of /dev/shm and its own limits, for the
+    message of a refused store."""
+    import resource
+    facts = []
+    try:
+        st = os.statvfs("/dev/shm")
+        facts.append(f"/dev/shm free {st.f_bsize * st.f_bavail} of "
+                     f"{st.f_frsize * st.f_blocks} bytes")
+    except OSError as e:
+        facts.append(f"statvfs(/dev/shm): {e}")
+    try:
+        with open("/proc/mounts") as f:
+            facts += [line.strip() for line in f
+                      if line.split()[1:2] == ["/dev/shm"]]
+    except OSError:
+        pass
+    for name in ("RLIMIT_AS", "RLIMIT_FSIZE"):
+        facts.append(f"{name} {resource.getrlimit(getattr(resource, name))}")
+    return ", ".join(facts)

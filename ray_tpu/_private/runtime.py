@@ -41,7 +41,8 @@ from . import serialization
 from . import telemetry
 from . import wiretap
 from .ids import ActorID, NodeID, ObjectID, TaskID, WorkerID
-from .object_store import ObjectStore, create_store, inline_threshold
+from .object_store import (ObjectStore, create_session_store,
+                           inline_threshold)
 from .resources import detect_node_resources
 from .scheduler import ResourceManager, Scheduler, WorkerHandle, WorkerPool
 
@@ -185,10 +186,9 @@ class Node:
         session_name = f"session_{int(time.time())}_{uuid.uuid4().hex[:8]}"
         self.session_dir = session_dir or os.path.join(
             "/tmp/ray_tpu_sessions", session_name)
-        self.store_dir = os.path.join("/dev/shm", f"ray_tpu_{session_name}")
         os.makedirs(self.session_dir, exist_ok=True)
-        self.store = create_store(self.store_dir,
-                                 capacity=object_store_memory)
+        self.store, self.store_dir = create_session_store(
+            session_name, self.session_dir, object_store_memory)
         for d in (self.session_dir, self.store_dir):
             try:
                 with open(os.path.join(d, ".owner_pid"), "w") as f:

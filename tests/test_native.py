@@ -251,3 +251,98 @@ def test_init_shutdown_churn_no_native_crash():
             # native mux, not the pure-Python fallback.
             assert isinstance(_state.current().pool._mux, _NativeMux)
         ray_tpu.shutdown()  # immediately: prestart threads still booting
+
+
+def test_refused_arena_names_step_and_errno(tmp_path):
+    """A machine that refuses the arena says which step and why, and
+    leaves no half-made file that a retry would take for a peer's."""
+    path = str(tmp_path / "arena")
+    with pytest.raises(FileNotFoundError, match="arena open: open failed"):
+        _native.NativeStore(path, create=False)
+    open(path, "w").close()
+    with pytest.raises(FileExistsError):
+        _native.NativeStore(path, capacity=32 << 20)
+    with pytest.raises(OSError, match="arena open: mmap failed"):
+        _native.NativeStore(path, create=False)     # empty: not an arena
+    os.unlink(path)
+    # The two limits a harness may put on a process, each in a child.
+    code = f"""
+import errno, os, resource, sys
+from ray_tpu import _native
+_native.available()   # g++ and dlopen need room themselves: build first
+resource.setrlimit(resource.{{limit}}, ({{soft}}, resource.RLIM_INFINITY))
+try:
+    _native.NativeStore({path!r}, capacity=8 << 30)
+except OSError as e:
+    assert e.errno == errno.{{err}} and "{{step}} failed" in str(e), e
+    assert not os.path.exists({path!r})
+    sys.exit(7)
+"""
+    for limit, soft, err, step in (
+            ("RLIMIT_FSIZE", 1 << 24, "EFBIG", "ftruncate"),
+            ("RLIMIT_AS", 4 << 30, "ENOMEM", "mmap")):
+        proc = subprocess.run(
+            [sys.executable, "-c", code.format(
+                limit=limit, soft=soft, err=err, step=step)],
+            capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 7, proc.stderr
+
+
+def test_default_capacity_fits_process_limits():
+    """The default arena is one a process under RLIMIT_AS / RLIMIT_FSIZE
+    can create and map twice, and never larger than physical memory."""
+    code = """
+import os, resource
+resource.setrlimit(resource.RLIMIT_AS, (16 << 30, resource.RLIM_INFINITY))
+from ray_tpu._private import object_store
+cap = object_store._default_capacity()
+assert 0 < cap <= 2 << 30, cap
+assert cap <= os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+resource.setrlimit(resource.RLIMIT_FSIZE, (1 << 28, resource.RLIM_INFINITY))
+assert object_store._default_capacity() <= 1 << 28
+import ray_tpu
+ray_tpu.init(num_cpus=1)
+ref = ray_tpu.put(b"x" * (1 << 20))
+assert len(ray_tpu.get(ref)) == 1 << 20
+ray_tpu.shutdown()
+"""
+    proc = subprocess.run([sys.executable, "-c", code],
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_store_falls_back_to_session_dir_loudly(tmp_path, monkeypatch,
+                                                capsys):
+    """/dev/shm refusing the store moves the same store under the session
+    directory, with a warning that carries the errno; a second refusal
+    raises with the first chained."""
+    from ray_tpu._private import object_store
+    real = _native.NativeStore
+
+    def refuse_shm(path, capacity=None, create=True):
+        if path.startswith("/dev/shm/"):
+            raise OSError(19, "arena create: mmap failed", path)
+        return real(path, capacity, create)
+
+    monkeypatch.setattr(_native, "NativeStore", refuse_shm)
+    store, store_dir = object_store.create_session_store(
+        "session_test_fallback", str(tmp_path), 32 << 20)
+    try:
+        assert store_dir == str(tmp_path / "store")
+        assert type(store).__name__ == "ArenaObjectStore"
+        assert os.path.exists(os.path.join(store_dir, "arena.shm"))
+        assert not os.path.exists("/dev/shm/ray_tpu_session_test_fallback")
+        err = capsys.readouterr().err
+        assert "WARNING: /dev/shm refused" in err and "Errno 19" in err
+        assert "RLIMIT_AS" in err
+    finally:
+        store.shutdown()
+
+    def refuse_all(path, capacity=None, create=True):
+        raise OSError(12, "arena create: mmap failed", path)
+
+    monkeypatch.setattr(_native, "NativeStore", refuse_all)
+    with pytest.raises(OSError) as exc:
+        object_store.create_session_store(
+            "session_test_fallback", str(tmp_path), 32 << 20)
+    assert isinstance(exc.value.__cause__, OSError)
