@@ -56,6 +56,7 @@ from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..exceptions import ActorDiedError, GetTimeoutError
+from ..util.profiling import PROFILE_METHOD
 from . import fault
 from . import object_store
 from . import lockdep
@@ -1880,7 +1881,8 @@ class DirectPlane:
         if (aspec is not None and aspec.max_concurrency == 1
                 and not w._cg_executors
                 and all(not s.streaming
-                        and s.method_name != "__adag_exec_loop__"
+                        and s.method_name not in ("__adag_exec_loop__",
+                                                  PROFILE_METHOD)
                         for s in specs)):
             # Traced calls stay on this lean path too — the batch
             # executor adopts each spec's trace context itself.

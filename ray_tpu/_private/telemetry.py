@@ -482,6 +482,16 @@ def serve_replica_request(deployment: str, dt: float) -> None:
                   deployment).observe(max(dt, 1e-9))
 
 
+def serve_replica_handler_wait(deployment: str, dt: float) -> None:
+    """Receipt of a request by the replica -> start of the user's
+    handler in its executor thread: the executor's size as a number."""
+    global _ops
+    _ops += 1
+    _serve_handle("serve_replica_handler_wait_s",
+                  "Wait from replica receipt to handler start",
+                  deployment).observe(max(dt, 1e-9))
+
+
 def serve_replica_ongoing(deployment: str, n: int) -> None:
     global _ops
     _ops += 1
@@ -520,6 +530,63 @@ def serve_shed(deployment: str) -> None:
             "Requests shed 503 by proxy-side admission control",
             tag_keys=("deployment",)).inc(
                 tags={"deployment": deployment})
+
+
+# ---------------------------------------------------------------------------
+# device gauges of a process that runs jax (a chip worker)
+# ---------------------------------------------------------------------------
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_programs_built: Optional[int] = None   # None: not listening yet
+
+
+def _on_jax_duration(event, duration, **kw) -> None:
+    global _programs_built
+    if event == _COMPILE_EVENT:
+        _programs_built += 1
+
+
+def flush_device_gauges() -> None:
+    """At metrics-push time, in a process that runs jax: how many
+    programs it has built (every miss of jit's in-memory cache, whether
+    XLA compiled or the persistent cache answered; counted from this
+    worker's first push after jax was imported) and, once it has built
+    one, each local device's peak memory.
+
+    Never imports jax, never starts a backend and never waits for one:
+    asking jax for its devices takes the lock that backend start-up
+    holds, for as long as a jax.distributed gang takes to gather, and
+    this runs on the thread that is about to send a completion. A
+    program built is proof that start-up is over."""
+    global _ops, _programs_built
+    _ops += 1
+    import sys
+    monitoring = sys.modules.get("jax.monitoring")
+    if monitoring is None:
+        return
+    if _programs_built is None:
+        # getattr: another thread may be half way through importing jax.
+        register = getattr(monitoring,
+                           "register_event_duration_secs_listener", None)
+        if register is None:
+            return
+        register(_on_jax_duration)
+        _programs_built = 0
+    _metric("device_programs_built", "gauge",
+            "Programs this process built since its first metrics push"
+            ).set(float(_programs_built))
+    if not _programs_built:
+        return
+    for dev in sys.modules["jax"].local_devices():
+        stats = dev.memory_stats()
+        if stats:
+            # in_use alone leaves a running program's temporaries out
+            # (PERF.md, PR 22).
+            _metric("device_memory_peak_bytes", "gauge",
+                    "peak_bytes_in_use + peak_bytes_reserved of a "
+                    "local device", tag_keys=("device",)).set(
+                        float(stats.get("peak_bytes_in_use", 0)
+                              + stats.get("peak_bytes_reserved", 0)),
+                        tags={"device": str(dev.id)})
 
 
 # ---------------------------------------------------------------------------

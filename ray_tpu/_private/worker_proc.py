@@ -39,6 +39,7 @@ import cloudpickle
 
 from ..exceptions import TaskCancelledError, TaskError
 from ..util import tracing
+from ..util.profiling import PROFILE_METHOD, capture_for
 from . import fault
 from . import lockdep
 from . import protocol as P
@@ -892,6 +893,7 @@ class Worker:
                 self._metrics_last_push = now
                 from ..util import metrics as M
                 telemetry.flush_serve_gauges()  # lint: ungated-instrumentation-ok the telemetry.enabled early return above gates this
+                telemetry.flush_device_gauges()  # lint: ungated-instrumentation-ok gated as the line above
                 groups = M.registry_samples()
                 if groups:
                     self.send(P.METRICS_PUSH, {
@@ -1033,6 +1035,8 @@ class Worker:
                     from ..dag.compiled import _run_actor_loop
                     result = _run_actor_loop(self._actor_instance,
                                              *args, **kwargs)
+                elif spec.method_name == PROFILE_METHOD:
+                    result = capture_for(*args, **kwargs)
                 else:
                     method = getattr(self._actor_instance, spec.method_name)
                     result = method(*args, **kwargs)
@@ -1354,7 +1358,14 @@ class Worker:
         with self._running_lock:
             self._queued_meta[spec.task_id.binary()] = \
                 (spec.actor_id, spec.fn_id)
-        if spec.actor_id is not None and self._actor_executor is not None:
+        if spec.method_name == PROFILE_METHOD:
+            # profiling.profile_actor: a thread of its own, so that an
+            # actor whose executors are all busy (a TrainWorker inside
+            # its loop, a replica at max_ongoing_requests) can still be
+            # profiled, and the profile takes none of their slots.
+            threading.Thread(target=self._execute, args=(spec,),
+                             daemon=True, name="ray_tpu-profile").start()
+        elif spec.actor_id is not None and self._actor_executor is not None:
             self._executor_for(spec).submit(self._execute, spec)
         else:
             fut = self._task_pool.submit(self._execute, spec)

@@ -33,6 +33,7 @@ from typing import (
 import numpy as np
 
 from .. import api
+from ..util.profiling import annotate
 from . import block as B
 
 
@@ -343,8 +344,9 @@ class _MapBatchesActorPool:
                 for s in range(0, n, step):
                     batch = B.to_batch_format(
                         B.block_slice(blk, s, s + step), batch_format)
-                    outs.append(B.from_batch_format(
-                        self.fn(batch, *fn_args, **fn_kwargs)))
+                    with annotate("ray_tpu.data.map_batch"):
+                        out = self.fn(batch, *fn_args, **fn_kwargs)
+                    outs.append(B.from_batch_format(out))
                 return B.block_concat(outs)
 
         import cloudpickle

@@ -14,6 +14,7 @@ import collections
 from typing import Callable, Iterator, List, Optional, Tuple
 
 from .. import api
+from ..util.profiling import annotate
 from . import block as B
 from .context import DataContext
 
@@ -29,13 +30,17 @@ def iter_blocks(bundles: Iterator[StreamedBundle],
     bundles execute (and their results land in the store) while the
     current block is being consumed — the reference's iter_batches
     read-ahead."""
+    def fetch(bundle):
+        with annotate("ray_tpu.feed.fetch_block"):
+            return api.get(bundle[0])
+
     window: collections.deque = collections.deque()
     for bundle in bundles:
         window.append(bundle)
         if len(window) > prefetch:
-            yield api.get(window.popleft()[0])
+            yield fetch(window.popleft())
     while window:
-        yield api.get(window.popleft()[0])
+        yield fetch(window.popleft())
 
 
 def shuffled_blocks(blocks: Iterator[B.Block], buffer_size: int,
@@ -73,25 +78,31 @@ def batches_from_blocks(
 ) -> Iterator:
     """Re-chunk a block stream into fixed-size batches (reference:
     _internal/block_batching)."""
+    def batch_of(blk, start=None, stop=None):
+        with annotate("ray_tpu.feed.assemble"):
+            if start is not None:
+                blk = B.block_slice(blk, start, stop)
+            return B.to_batch_format(blk, batch_format)
+
     leftover: Optional[B.Block] = None
     for blk in blocks:
         if leftover is not None:
-            blk = B.block_concat([leftover, blk])
+            with annotate("ray_tpu.feed.assemble"):
+                blk = B.block_concat([leftover, blk])
             leftover = None
         n = B.block_length(blk)
         if batch_size is None:
             if n:
-                yield B.to_batch_format(blk, batch_format)
+                yield batch_of(blk)
             continue
         pos = 0
         while n - pos >= batch_size:
-            yield B.to_batch_format(
-                B.block_slice(blk, pos, pos + batch_size), batch_format)
+            yield batch_of(blk, pos, pos + batch_size)
             pos += batch_size
         if pos < n:
             leftover = B.block_slice(blk, pos, n)
     if leftover is not None and B.block_length(leftover) and not drop_last:
-        yield B.to_batch_format(leftover, batch_format)
+        yield batch_of(leftover)
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +178,8 @@ def jax_device_feed(batches: Iterator, *, device=None, sharding=None,
         raise ValueError("device_prefetch must be >= 0")
     window: collections.deque = collections.deque()
     for batch in batches:
-        put = {k: jax.device_put(v, target) for k, v in batch.items()}
+        with annotate("ray_tpu.feed.device_put"):
+            put = {k: jax.device_put(v, target) for k, v in batch.items()}
         if depth == 0:
             yield put
             continue

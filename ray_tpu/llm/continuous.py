@@ -13,20 +13,37 @@ batching re-expressed for XLA's compile-once model).
 Driven by a single decode thread per engine (a Serve replica owns one
 engine; its requests share the batch). Thread-safe submit() returns an
 iterator of decoded text pieces.
+
+What the loop does with its time is on two records. Spans on the
+profiler's clock, `ray_tpu.engine.<phase>` (util/profiling.py
+HOST_SPANS), name the host's part of every gap in a device trace. The
+same phases are counted always, beside `steps`: `counters()` gives
+seconds and entries per phase, occupied slots summed over decode steps,
+tokens out, and requests by finish reason; `finished` holds the timing
+of the latest requests (wait for a slot, first token, done).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import queue
 import threading
-from typing import Iterator, List, Optional
+import time
+from typing import Dict, List, Optional
+
+from ..util.profiling import annotate
+from .serving import RequestTiming, TokenStream
 
 _SENTINEL = object()
+
+# The loop's phases, disjoint in time: each is a span and a counter.
+PHASES = ("admit", "prefill", "decode", "fetch", "sample", "idle")
 
 
 class _Request:
     __slots__ = ("ids", "max_new", "temperature", "out", "stop_token",
-                 "seed")
+                 "seed", "timing")
 
     def __init__(self, ids, max_new, temperature, stop_token, seed):
         self.ids = ids
@@ -35,6 +52,7 @@ class _Request:
         self.stop_token = stop_token
         self.seed = seed
         self.out: "queue.Queue" = queue.Queue()
+        self.timing = RequestTiming()
 
 
 class _Slot:
@@ -82,15 +100,24 @@ class ContinuousBatchingEngine:
         # RUNNING batch (their first token decoded at a step > 0 while
         # another slot was mid-stream).
         self.steps = 0
+        # Written by the decode thread only; read through counters().
+        self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_n: Dict[str, int] = dict.fromkeys(PHASES, 0)
+        self.slot_steps = 0     # occupied slots, summed over decode steps
+        self.tokens_out = 0     # = slot_steps + requests admitted
+        self.admitted = 0
+        self.finish_reasons: Dict[str, int] = {}
+        self.finished: collections.deque = collections.deque(maxlen=4096)
 
     # -- public api --------------------------------------------------------
     def submit(self, prompt: str, max_new_tokens: int = 32,
                temperature: float = 0.0,
                stop_token: Optional[int] = None,
-               seed: int = 0) -> Iterator[str]:
+               seed: int = 0) -> TokenStream:
         """Enqueue a request; returns an iterator of decoded text
-        pieces. The request joins the running batch as soon as a slot
-        frees (or immediately when one is open)."""
+        pieces whose `timing` fills in as the request runs. The request
+        joins the running batch as soon as a slot frees (or immediately
+        when one is open)."""
         import codecs
 
         encoded = self.tokenizer.encode(prompt)
@@ -127,7 +154,7 @@ class ContinuousBatchingEngine:
             tail = decoder.decode(b"", final=True)
             if tail:
                 yield tail
-        return _stream()
+        return TokenStream(_stream(), req.timing)
 
     def complete(self, prompt: str, max_new_tokens: int = 32,
                  temperature: float = 0.0, **kw) -> str:
@@ -138,6 +165,33 @@ class ContinuousBatchingEngine:
         with self._lock:
             self._closed = True
         self._wake.set()
+
+    def counters(self) -> Dict:
+        """A copy of the always-on counters (the decode thread writes
+        them unlocked: a reader sees each number whole, and the set at
+        most one step apart)."""
+        return {"steps": self.steps, "phase_s": dict(self.phase_s),
+                "phase_n": dict(self.phase_n),
+                "slot_steps": self.slot_steps,
+                "tokens_out": self.tokens_out, "admitted": self.admitted,
+                "finish_reasons": dict(self.finish_reasons)}
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        t0 = time.perf_counter()
+        try:
+            with annotate("ray_tpu.engine." + name):
+                yield
+        finally:
+            self.phase_s[name] += time.perf_counter() - t0
+            self.phase_n[name] += 1
+
+    def _finish(self, req: _Request, reason: str) -> None:
+        t = req.timing
+        t.done_unix = time.time()
+        t.finish_reason = reason
+        self.finish_reasons[reason] = self.finish_reasons.get(reason, 0) + 1
+        self.finished.append(t.as_dict())
 
     # -- decode loop -------------------------------------------------------
     def _admit(self) -> None:
@@ -155,26 +209,33 @@ class ContinuousBatchingEngine:
                 req = self._pending.get_nowait()
             except queue.Empty:
                 return
-            true_len = len(req.ids)
-            bucket = min(_bucket_len(true_len, self.max_len),
-                         self.max_len)
-            padded = req.ids + [0] * (bucket - true_len)
-            tokens = np.asarray([padded], np.int32)
+            with self._phase("admit"):
+                req.timing.admit_unix = time.time()
+                self.admitted += 1
+                true_len = len(req.ids)
+                bucket = min(_bucket_len(true_len, self.max_len),
+                             self.max_len)
+                padded = req.ids + [0] * (bucket - true_len)
+                tokens = np.asarray([padded], np.int32)
             try:
-                last, self._cache = self._prefill(
-                    self.params, tokens, self._cache, i, true_len)
+                with self._phase("prefill"):
+                    last, self._cache = self._prefill(
+                        self.params, tokens, self._cache, i, true_len)
+                    last = np.asarray(last)
             except BaseException as e:  # noqa: BLE001
                 # The request is already popped from _pending and holds
                 # no slot: _fail_all can't see it, so a prefill failure
                 # (OOM, compile error) must terminate ITS stream here or
                 # submit()'s consumer blocks forever on req.out.
+                self._finish(req, "error")
                 req.out.put(e)
                 req.out.put(_SENTINEL)
                 raise
-            rng = np.random.default_rng(req.seed)
-            slot = _Slot(req, true_len, rng)
-            self._slots[i] = slot
-            self._emit(i, np.asarray(last))
+            with self._phase("sample"):
+                rng = np.random.default_rng(req.seed)
+                slot = _Slot(req, true_len, rng)
+                self._slots[i] = slot
+                self._emit(i, last)
 
     def _emit(self, i: int, logits) -> None:
         """Sample one token for slot i from host-side logits; push to
@@ -208,19 +269,29 @@ class ContinuousBatchingEngine:
         req.out.put(token)
         slot.emitted += 1
         slot.last_token = token
-        done = (slot.emitted >= req.max_new
-                or (req.stop_token is not None
-                    and token == req.stop_token)
-                or slot.pos >= self.max_len)
-        if done:
-            req.out.put(_SENTINEL)
-            self._slots[i] = None   # slot free: next _admit reuses it
+        self.tokens_out += 1
+        req.timing.tokens = slot.emitted
+        if slot.emitted == 1:
+            req.timing.first_token_unix = time.time()
+        if req.stop_token is not None and token == req.stop_token:
+            reason = "stop"
+        elif slot.emitted >= req.max_new:
+            reason = "length"
+        elif slot.pos >= self.max_len:
+            reason = "max_len"
+        else:
+            return
+        self._finish(req, reason)
+        req.out.put(_SENTINEL)
+        self._slots[i] = None   # slot free: next _admit reuses it
 
     def _fail_all(self, exc: Optional[BaseException]) -> None:
         """Terminate every active and pending stream; exc is re-raised
         in consumers when given, else the streams just end."""
+        reason = "error" if exc is not None else "closed"
         for i, s in enumerate(self._slots):
             if s is not None:
+                self._finish(s.req, reason)
                 if exc is not None:
                     s.req.out.put(exc)
                 s.req.out.put(_SENTINEL)
@@ -230,6 +301,7 @@ class ContinuousBatchingEngine:
                 req = self._pending.get_nowait()
             except queue.Empty:
                 return
+            self._finish(req, reason)
             if exc is not None:
                 req.out.put(exc)
             req.out.put(_SENTINEL)
@@ -250,24 +322,29 @@ class ContinuousBatchingEngine:
                             self._fail_all(
                                 RuntimeError("engine closed"))
                             return
-                    self._wake.wait(timeout=0.5)
-                    self._wake.clear()
+                    with self._phase("idle"):
+                        self._wake.wait(timeout=0.5)
+                        self._wake.clear()
                     continue
-                tokens = np.zeros(self.max_batch, np.int32)
-                pos = np.zeros(self.max_batch, np.int32)
-                for i in active:
-                    slot = self._slots[i]
-                    tokens[i] = slot.last_token
-                    pos[i] = slot.pos  # where this token is written
-                logits, self._cache = self._decode(
-                    self.params, tokens, pos, self._cache)
-                self.steps += 1
-                logits_np = np.asarray(logits)
-                for i in active:
-                    slot = self._slots[i]
-                    if slot is not None:
-                        slot.pos += 1  # the decode wrote at old pos
-                        self._emit(i, logits_np[i])
+                with self._phase("decode"):
+                    tokens = np.zeros(self.max_batch, np.int32)
+                    pos = np.zeros(self.max_batch, np.int32)
+                    for i in active:
+                        slot = self._slots[i]
+                        tokens[i] = slot.last_token
+                        pos[i] = slot.pos  # where this token is written
+                    logits, self._cache = self._decode(
+                        self.params, tokens, pos, self._cache)
+                    self.steps += 1
+                    self.slot_steps += len(active)
+                with self._phase("fetch"):
+                    logits_np = np.asarray(logits)
+                with self._phase("sample"):
+                    for i in active:
+                        slot = self._slots[i]
+                        if slot is not None:
+                            slot.pos += 1  # the decode wrote at old pos
+                            self._emit(i, logits_np[i])
         except BaseException as e:  # noqa: BLE001
             # The engine is dead: close it so later submit() raises
             # instead of enqueueing into a loop that no longer runs,

@@ -14,9 +14,55 @@ one in production.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, Iterator, Optional
 
 BOS = 256
+
+
+class RequestTiming:
+    """One request's clock readings, as unix seconds (the clock of a
+    profile and of util/tracing.py's request spans), written by the
+    engine as the request runs."""
+
+    __slots__ = ("submit_unix", "admit_unix", "first_token_unix",
+                 "done_unix", "tokens", "finish_reason")
+
+    def __init__(self):
+        self.submit_unix = time.time()
+        self.admit_unix: Optional[float] = None
+        self.first_token_unix: Optional[float] = None
+        self.done_unix: Optional[float] = None
+        self.tokens = 0
+        self.finish_reason: Optional[str] = None
+
+    def as_dict(self) -> Dict[str, Any]:
+        """The reply's `timing`: seconds from submit until a slot took
+        the request, until its first token, until it ended; tokens
+        generated; why it ended ("length", "stop", "max_len", "error",
+        "closed"). None where the request did not get that far."""
+        def since(t):
+            return None if t is None else t - self.submit_unix
+        return {"submit_unix": self.submit_unix,
+                "queued_s": since(self.admit_unix),
+                "first_token_s": since(self.first_token_unix),
+                "done_s": since(self.done_unix),
+                "tokens": self.tokens,
+                "finish_reason": self.finish_reason}
+
+
+class TokenStream:
+    """Iterator of one request's decoded text pieces, with its timing."""
+
+    def __init__(self, pieces: Iterator[str], timing: RequestTiming):
+        self._pieces = pieces
+        self.timing = timing
+
+    def __iter__(self):
+        return self._pieces
+
+    def __next__(self) -> str:
+        return next(self._pieces)
 
 
 class ByteTokenizer:
@@ -51,17 +97,26 @@ class LLMEngine:
             jax.random.PRNGKey(seed), self.cfg)
 
     def stream(self, prompt: str, max_new_tokens: int = 64,
-               temperature: float = 0.0) -> Iterator[str]:
+               temperature: float = 0.0) -> TokenStream:
         """Yield decoded text fragments token by token. Multi-byte
         UTF-8 sequences are buffered across tokens (an incremental
         decoder), and over-long prompts keep their TAIL so the model
         conditions on the most recent context."""
+        timing = RequestTiming()
+        return TokenStream(
+            self._pieces(prompt, max_new_tokens, temperature, timing),
+            timing)
+
+    def _pieces(self, prompt, max_new_tokens, temperature,
+                timing: RequestTiming) -> Iterator[str]:
         import codecs
 
         import numpy as np
 
         from ..models.generate import generate
 
+        # No queue and no slots: the request runs in its caller's thread.
+        timing.admit_unix = time.time()
         encoded = self.tokenizer.encode(prompt)
         # Leave room for at least one generated token.
         keep = self.cfg.max_seq_len - max(1, min(max_new_tokens, 16))
@@ -74,9 +129,14 @@ class LLMEngine:
                               max_new_tokens=min(max_new_tokens, budget),
                               temperature=temperature):
             t = int(token[0])
+            timing.tokens += 1
+            if timing.tokens == 1:
+                timing.first_token_unix = time.time()
             piece = decoder.decode(bytes([t])) if 0 <= t < 256 else ""
             if piece:
                 yield piece
+        timing.done_unix = time.time()
+        timing.finish_reason = "length"
         tail = decoder.decode(b"", final=True)
         if tail:
             yield tail
@@ -91,7 +151,8 @@ def build_llm_app(cfg=None, params=None, *, num_replicas: int = 1,
                   max_batch: int = 8):
     """Serve application: POST {"prompt": ..., "max_tokens": ...,
     "stream": bool} — streaming responses ride Serve's chunked path;
-    a non-streaming reply is {"text", "device", "engine_steps"}.
+    a non-streaming reply is {"text", "device", "engine_steps",
+    "timing"} (`timing`: RequestTiming.as_dict).
 
     ``continuous_batching=True`` backs each replica with ONE shared
     ContinuousBatchingEngine (llm/continuous.py): concurrent requests
@@ -157,12 +218,23 @@ def build_llm_app(cfg=None, params=None, *, num_replicas: int = 1,
             if body.get("stream"):
                 return self._lazy_stream(prompt, max_tokens,
                                          temperature)
-            text = "".join(self._stream(prompt, max_tokens, temperature))
+            stream = self._stream(prompt, max_tokens, temperature)
+            text = "".join(stream)
             return {"text": text, "device": self._device,
-                    "engine_steps": getattr(self.engine, "steps", None)}
+                    "engine_steps": getattr(self.engine, "steps", None),
+                    "timing": stream.timing.as_dict()}
 
         def generate_stream(self, prompt: str, max_tokens: int = 32,
                             temperature: float = 0.0):
             yield from self._stream(prompt, max_tokens, temperature)
+
+        def engine_counters(self):
+            """The continuous engine's always-on counters and the timing
+            of its latest finished requests (llm/continuous.py); None
+            from the sequential engine, which has no loop to count."""
+            if not continuous_batching:
+                return None
+            return {**self.engine.counters(),
+                    "finished": list(self.engine.finished)}
 
     return LLMServer.bind()

@@ -74,9 +74,10 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
         with attention_split():
             loss, grads = jax.value_and_grad(loss_fn)(
                 state["params"], batch)
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt_state"], state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
         return ({"params": new_params, "opt_state": new_opt,
                  "step": state["step"] + 1},
                 {"loss": loss})

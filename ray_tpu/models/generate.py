@@ -134,13 +134,15 @@ def make_generate_fns(cfg: GPTConfig, max_len: int):
 
     @functools.partial(jax.jit, donate_argnums=(2,))
     def prefill(params, tokens, cache):
-        logits, cache = cached_forward(params, tokens, cache, 0, cfg)
+        with jax.named_scope("prefill"):
+            logits, cache = cached_forward(params, tokens, cache, 0, cfg)
         return logits[:, -1, :], cache
 
     @functools.partial(jax.jit, donate_argnums=(3,))
     def decode_step(params, token, pos, cache):
-        logits, cache = cached_forward(
-            params, token[:, None], cache, pos, cfg)
+        with jax.named_scope("decode"):
+            logits, cache = cached_forward(
+                params, token[:, None], cache, pos, cfg)
         return logits[:, 0, :], cache
 
     return prefill, decode_step
@@ -170,7 +172,8 @@ def make_continuous_fns(cfg: GPTConfig, max_len: int, batch: int):
     def insert_prefill(params, tokens, cache, slot, true_len):
         sub = [{k: jax.lax.dynamic_slice_in_dim(cl[k], slot, 1, axis=0)
                 for k in ("k", "v")} for cl in cache]
-        logits, new_sub = cached_forward(params, tokens, sub, 0, cfg)
+        with jax.named_scope("prefill"):
+            logits, new_sub = cached_forward(params, tokens, sub, 0, cfg)
         out = [{k: jax.lax.dynamic_update_slice_in_dim(
                     cl[k], ns[k], slot, axis=0) for k in ("k", "v")}
                for cl, ns in zip(cache, new_sub)]
@@ -182,8 +185,9 @@ def make_continuous_fns(cfg: GPTConfig, max_len: int, batch: int):
     def decode_batch(params, tokens, pos, cache):
         # cached_forward with a PER-ROW start_pos vector — the same
         # block implementation as prefill and sequential decode.
-        logits, cache = cached_forward(
-            params, tokens[:, None], cache, pos, cfg)
+        with jax.named_scope("decode"):
+            logits, cache = cached_forward(
+                params, tokens[:, None], cache, pos, cfg)
         return logits[:, 0, :], cache
 
     return insert_prefill, decode_batch

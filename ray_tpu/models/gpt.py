@@ -147,8 +147,9 @@ def _backbone(params: Dict, tokens, cfg: GPTConfig):
         block = jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    for layer in params["layers"]:
-        x = block(x, layer)
+    with jax.named_scope("layers"):
+        for layer in params["layers"]:
+            x = block(x, layer)
     x = rms_norm(x, params["lnf"])
     head = params.get("head")
     if head is None:
@@ -174,6 +175,12 @@ def gpt_loss(params: Dict, batch: Tuple, cfg: GPTConfig):
     O(chunk * vocab) live memory and measurably higher MFU."""
     tokens, targets = batch
     x, head = _backbone(params, tokens, cfg)
+    with jax.named_scope("loss"):
+        return _loss_of(x, head, targets)
+
+
+def _loss_of(x, head, targets):
+    """gpt_loss after the backbone: x [b, s, d] hidden rows -> scalar."""
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     tf = targets.reshape(-1)

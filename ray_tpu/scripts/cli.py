@@ -226,6 +226,23 @@ def cmd_trace(args):
     return 0
 
 
+def cmd_profile(args):
+    """Profile the chip path of a running actor (a Serve replica, a
+    TrainWorker) from inside its own process: device operations beside
+    the program's `ray_tpu.*` host spans (util/profiling.py), for
+    xprof/TensorBoard or jax.profiler.ProfileData.from_file."""
+    call = _backend(args)
+    out = call("profile_actor", args.actor, args.seconds)
+    path = args.output or f"profile_{out['pid']}_{int(time.time())}" \
+        ".xplane.pb"
+    with open(path, "wb") as f:
+        f.write(out["xplane"])
+    print(f"wrote {len(out['xplane'])} bytes of pid {out['pid']}'s "
+          f"profile to {path} ({args.seconds:g} s from unix ns "
+          f"{out['start_unix_ns']}; stop took {out['stop_s']:.2f} s)")
+    return 0
+
+
 def cmd_job(args):
     call = _backend(args)
     if args.job_cmd == "submit":
@@ -428,6 +445,16 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("-o", "--output", default=None)
     add_address(sp)
     sp.set_defaults(fn=cmd_trace)
+
+    sp = sub.add_parser("profile", help="profile a running actor's "
+                        "chip path from inside its process "
+                        "(jax.profiler xplane)")
+    sp.add_argument("actor", help="actor name, or a prefix of its id "
+                    "(`ray_tpu list actors`)")
+    sp.add_argument("--seconds", type=float, default=5.0)
+    sp.add_argument("-o", "--output", default=None)
+    add_address(sp)
+    sp.set_defaults(fn=cmd_profile)
 
     sp = sub.add_parser("job", help="job submission")
     add_address(sp)

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional
 
 import ray_tpu
 from ray_tpu._private import telemetry
+from ray_tpu.util.profiling import annotate
 
 
 class StreamingResponseRequired(Exception):
@@ -119,15 +120,26 @@ class Replica:
                 # streaming retry doesn't double-run side effects.
                 raise StreamingResponseRequired(self._deployment_name)
             if inspect.iscoroutinefunction(target):
-                result = await target(*args, **kwargs)
+                with annotate("ray_tpu.serve.handle"):
+                    result = await target(*args, **kwargs)
             else:
                 import contextvars
                 # ctx.run: the executor thread must see the request's
                 # multiplexed model id (run_in_executor does not
                 # propagate contextvars by itself).
                 ctx = contextvars.copy_context()
+
+                def in_handler_thread():
+                    if t0 is not None:
+                        # What the executor's thread count (asyncio's
+                        # default: min(32, cores + 4)) makes a request
+                        # wait once that many handlers run.
+                        telemetry.serve_replica_handler_wait(  # lint: ungated-instrumentation-ok t0 is non-None only when telemetry.enabled was set at entry
+                            self._deployment_name, time.monotonic() - t0)
+                    with annotate("ray_tpu.serve.handle"):
+                        return ctx.run(target, *args, **kwargs)
                 result = await asyncio.get_event_loop().run_in_executor(
-                    None, lambda: ctx.run(target, *args, **kwargs))
+                    None, in_handler_thread)
             if inspect.isgenerator(result) or inspect.isasyncgen(result):
                 # Caller used the non-streaming path on a handler that
                 # DYNAMICALLY returned a generator; tell it to retry via
@@ -184,9 +196,10 @@ class Replica:
                     _check_trim(args[0], self._callable,
                                 self._deployment_name)
                 target = self._resolve_target(method_name)
-                result = target(*args, **kwargs)
-                if inspect.iscoroutine(result):
-                    result = asyncio.run(result)
+                with annotate("ray_tpu.serve.handle"):
+                    result = target(*args, **kwargs)
+                    if inspect.iscoroutine(result):
+                        result = asyncio.run(result)
                 return result
 
             result = req_ctx.run(_start)

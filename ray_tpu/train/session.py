@@ -12,6 +12,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ..util.profiling import annotate
 from .checkpoint import Checkpoint
 
 
@@ -71,7 +72,8 @@ def _get_session() -> _Session:
 def report(metrics: Dict, *, checkpoint: Optional[Checkpoint] = None):
     """Report metrics (+ optional checkpoint) to the controller
     (reference: session.py:405)."""
-    _get_session().report(metrics, checkpoint)
+    with annotate("ray_tpu.train.report"):
+        _get_session().report(metrics, checkpoint)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 from .. import api
+from ..util.profiling import annotate
 from .checkpoint import Checkpoint
 from .session import TrainContext, _Session, _set_session
 
@@ -55,7 +56,8 @@ class TrainWorker:
         """Drain buffered reports (controller calls this periodically)."""
         if self._session is None:
             return []
-        return self._session.drain()
+        with annotate("ray_tpu.train.poll"):
+            return self._session.drain()
 
     def get_env_info(self):
         import os

@@ -43,6 +43,7 @@ def registry() -> Dict[str, Callable[..., Any]]:
         # chrome export, served from the head's span store.
         "get_trace": _get_trace,
         "export_chrome_trace": _export_chrome_trace,
+        "profile_actor": _profile_actor,
         "job_submit": lambda **kw: job_client().submit_job(**kw),
         "job_status": lambda job_id: job_client().get_job_status(job_id),
         "job_logs": lambda job_id: job_client().get_job_logs(job_id),
@@ -64,6 +65,28 @@ def _get_trace(trace_id: str) -> dict:
 def _export_chrome_trace(trace_id=None) -> list:
     from ray_tpu.util import tracing
     return tracing.export_chrome_trace(filename=None, trace_id=trace_id)
+
+
+def _profile_actor(actor: str, seconds: float) -> dict:
+    """A chip-path profile taken inside the worker that hosts `actor`
+    (its name, or a prefix of its id as `ray_tpu list actors` shows
+    it); the trace's bytes come back under "xplane"."""
+    from ray_tpu._private import gcs, state
+    from ray_tpu.api import ActorHandle
+    from ray_tpu.util import profiling
+
+    hits = [e.spec for e in state.get_node().gcs.actors.list()
+            if e.state == gcs.ACTOR_ALIVE
+            and (e.spec.name == actor
+                 or e.spec.actor_id.hex().startswith(actor))]
+    if len(hits) != 1:
+        raise ValueError(
+            f"{len(hits)} live actors match {actor!r}: give a name or "
+            f"an id prefix that `ray_tpu list actors` shows once")
+    spec = hits[0]
+    return profiling.remote_capture(
+        ActorHandle(spec.actor_id, spec.cls_id, spec.method_meta),
+        seconds)
 
 
 def _cluster_metrics() -> str:

@@ -1,22 +1,130 @@
-"""Device profiling utilities — the TPU-native analogue of the
-reference's GPU profiling hooks (nsight runtime-env plugin,
-_private/runtime_env/nsight.py, and per-function hooks in
-_private/profiling.py).
+"""Names on the profiler's clock for the chip path, and the profile itself.
 
-On TPU the profiler of record is jax.profiler: traces capture XLA
-execution, HBM usage, and ICI communication, viewable in TensorBoard or
-Perfetto. These helpers wrap it with the framework's session layout and
-compose with remote tasks (each worker process can trace its own device
-work).
+On TPU the profiler of record is jax.profiler: one trace holds what the
+device ran ("/device:TPU:n": XLA operations and jitted programs) and what
+the host was doing meanwhile ("/host:CPU": TraceAnnotation spans). This
+module is the one route by which the chip path (ops/, models/, llm/,
+train/, data/) puts names into that trace:
+
+  * `annotate(name)`: a host span. Free of jax until jax is imported, and
+    a few hundred nanoseconds when no profiler runs, so sites need no gate.
+    Request spans that cross processes are util/tracing.py's; a hot loop
+    never goes through those (two uuid4s, a lock and a buffer per span).
+  * `HOST_SPANS` / `DEVICE_SCOPES`: every name the program emits, once.
+    Tests check each against its site; PERF.md and docs/OBSERVABILITY.md
+    quote the table.
+  * `capture(logdir)`: a profile of this process, Python tracer off.
+  * `profile_actor(actor, seconds)`: the same, taken by another process's
+    worker for an operator (`ray_tpu profile`): only the process that
+    owns a chip can trace it.
+
+One clock: a read-back trace counts every event's `start_ns`, host and
+device, from its own `profile_start_time`, which is unix nanoseconds
+(`profile_start_unix_ns`); their sum is `time.time_ns()` at a span's entry
+to within microseconds (PERF.md has the chip's reading). So
+util/tracing.py's request spans from other processes, on `time.time()`,
+lie on the device trace's clock with no bridge but that one addition.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
+import glob
 import os
+import sys
+import tempfile
 import time
 from typing import Any, Callable, Dict, Optional
+
+# Host spans: name -> (site, the per-layer metric it is for).
+HOST_SPANS: Dict[str, tuple] = {
+    "ray_tpu.engine.admit": (
+        "llm/continuous.py _admit: one pending request taken into a "
+        "free slot", "engine_step_ms"),
+    "ray_tpu.engine.prefill": (
+        "llm/continuous.py _admit: dispatch of insert_prefill and the "
+        "fetch of its last-position logits", "ttft"),
+    "ray_tpu.engine.decode": (
+        "llm/continuous.py _loop: dispatch of decode_batch",
+        "engine_step_ms"),
+    "ray_tpu.engine.fetch": (
+        "llm/continuous.py _loop: np.asarray(logits), the wait for the "
+        "device and the copy to the host", "engine_step_ms"),
+    "ray_tpu.engine.sample": (
+        "llm/continuous.py _loop/_admit: host-side sampling and the "
+        "push to each request's stream", "engine_step_ms"),
+    "ray_tpu.engine.idle": (
+        "llm/continuous.py _loop: no slot occupied, waiting for a "
+        "submit", "batch_occupancy"),
+    "ray_tpu.serve.handle": (
+        "serve/_private/replica.py handle_request(_streaming): the "
+        "user's handler", "generator_late_p99_ms"),
+    "ray_tpu.train.report": (
+        "train/session.py report, in the training loop's thread",
+        "step_ms_p50"),
+    "ray_tpu.train.poll": (
+        "train/worker_group.py TrainWorker.poll: the controller's "
+        "result poll draining the reports, on the worker's second "
+        "thread", "train_device_idle_share"),
+    "ray_tpu.feed.fetch_block": (
+        "data/streaming.py iter_blocks: api.get of one block",
+        "fetch_ms_per_batch"),
+    "ray_tpu.feed.assemble": (
+        "data/streaming.py batches_from_blocks: concat, slice and "
+        "format of one batch", "fetch_ms_per_batch"),
+    "ray_tpu.feed.device_put": (
+        "data/streaming.py jax_device_feed: jax.device_put of one batch",
+        "h2d_ms_per_batch"),
+    "ray_tpu.data.map_batch": (
+        "data/dataset.py _BatchMapper.apply (the map_batches actor): "
+        "the user's function on one batch", "compute_ms_per_batch"),
+}
+
+# Device scopes (jax.named_scope, trace-time only): name -> site. A scope
+# reaches the HLO instruction's name, which is what the device plane
+# shows: the forward kernel is `flash_attention_fwd.N`, alone or under
+# shard_map, while its kernel_name stays `_fwd_kernel`.
+DEVICE_SCOPES: Dict[str, str] = {
+    "flash_attention_fwd": "ops/attention.py _flash_forward, the "
+                           "_fwd_kernel pallas_call",
+    "flash_attention_dq": "ops/attention.py _flash_backward, the "
+                          "_dq_kernel pallas_call",
+    "flash_attention_dkv": "ops/attention.py _flash_backward, the "
+                           "_dkv_kernel pallas_call",
+    "layers": "models/gpt.py _backbone, the layer stack",
+    "loss": "models/gpt.py gpt_loss after the backbone (the chunked "
+            "scan)",
+    "optimizer_update": "models/_training.py train_step, optimizer "
+                        "update and apply",
+    "prefill": "models/generate.py prefill / insert_prefill",
+    "decode": "models/generate.py decode_step / decode_batch",
+}
+
+# The worker-level actor method behind profile_actor: any actor's worker
+# answers it on a thread of its own (_private/worker_proc.py).
+PROFILE_METHOD = "__ray_tpu_profile__"
+
+_NO_SPAN = contextlib.nullcontext()
+_trace_annotation = None
+
+
+def annotate(name: str):
+    """Host span on the profiler's clock: `with annotate(name): ...`.
+    Names come from HOST_SPANS. Never imports jax: where the process has
+    not (a driver, the benchmark's parent) there is no profiler to write
+    to and the span is nothing."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        # getattr twice: another thread may be in the middle of importing
+        # jax, and a module half imported has no `profiler` yet.
+        span = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                       "TraceAnnotation", None)
+        if span is None:
+            return _NO_SPAN
+        _trace_annotation = span
+    return _trace_annotation(name)
 
 
 def default_logdir() -> str:
@@ -25,35 +133,81 @@ def default_logdir() -> str:
     rt = state.current_or_none()
     base = getattr(rt, "session_dir", None) if rt is not None else None
     if base is None:
-        base = "/tmp/ray_tpu_profiles"
+        base = os.path.join(tempfile.gettempdir(), "ray_tpu_profiles")
     return os.path.join(base, "profiles")
 
 
-@contextlib.contextmanager
-def trace(logdir: Optional[str] = None, *, host_tracer_level: int = 2,
-          create_perfetto_link: bool = False):
-    """Context manager: capture a jax.profiler trace of the enclosed
-    device work (reference: the nsight plugin wraps a worker in `nsys
-    profile`; here the XLA profiler wraps a region).
+@dataclasses.dataclass
+class Capture:
+    """What `capture` hands its block; filled in when the block ends."""
+    logdir: str
+    start_unix_ns: int = 0          # just before the profiler started
+    xplane: Optional[str] = None    # the .xplane.pb, once stopped
+    stop_s: Optional[float] = None  # how long stop_trace took
 
-        with profiling.trace("/tmp/tb"):
+
+@contextlib.contextmanager
+def capture(logdir: Optional[str] = None):
+    """Profile this process's device and host for the enclosed block.
+
+        with profiling.capture("/tmp/tb") as cap:
             state, _ = train_step(state, batch)
             jax.block_until_ready(state)
-    """
+        cap.xplane   # .../plugins/profile/<time>/<host>.xplane.pb
+
+    The Python tracer is off and the host tracer at 1: TraceAnnotation
+    spans and little else. With both up, traces of this program were
+    24-212 MB and took minutes to stop (PERF.md, PR 22). One capture
+    per process at a time: a second start raises."""
     import jax
+
     logdir = logdir or default_logdir()
     os.makedirs(logdir, exist_ok=True)
-    jax.profiler.start_trace(logdir,
-                             create_perfetto_link=create_perfetto_link)
+    before = set(_xplanes(logdir))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    cap = Capture(logdir, time.time_ns())
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
-        yield logdir
+        yield cap
     finally:
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        cap.stop_s = time.perf_counter() - t0
+        new = sorted(set(_xplanes(logdir)) - before)
+        cap.xplane = new[-1] if new else None
+
+
+def profile_start_unix_ns(xplane: str) -> int:
+    """Unix nanoseconds of a trace's zero: add an event's `start_ns` (as
+    jax.profiler.ProfileData gives it) to place it on time.time_ns()'s
+    clock. Reads the whole file."""
+    import jax
+
+    for plane in jax.profiler.ProfileData.from_file(xplane).planes:
+        if plane.name == "Task Environment":
+            for stat in plane.stats:
+                if stat[0] == "profile_start_time":
+                    return int(stat[1])
+    raise ValueError(f"{xplane} holds no profile_start_time")
+
+
+def _xplanes(logdir: str):
+    return glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                     recursive=True)
+
+
+@contextlib.contextmanager
+def trace(logdir: Optional[str] = None):
+    """`capture` under its older name; yields the log directory."""
+    with capture(logdir) as cap:
+        yield cap.logdir
 
 
 def profile(fn: Optional[Callable] = None, *,
             logdir: Optional[str] = None):
-    """Decorator variant of `trace` for remote task/actor methods:
+    """Decorator variant of `capture` for remote task/actor methods:
 
         @ray_tpu.remote(num_tpus=1)
         @profiling.profile
@@ -62,10 +216,54 @@ def profile(fn: Optional[Callable] = None, *,
     def deco(f):
         @functools.wraps(f)
         def wrapper(*args, **kwargs):
-            with trace(logdir):
+            with capture(logdir):
                 return f(*args, **kwargs)
         return wrapper
     return deco(fn) if fn is not None else deco
+
+
+def capture_for(seconds: float) -> Dict[str, Any]:
+    """Profile this process for `seconds` and return the trace's bytes:
+    what a worker runs for PROFILE_METHOD, on a thread of its own, so
+    the actor keeps serving meanwhile."""
+    with tempfile.TemporaryDirectory(prefix="ray_tpu_profile_") as tmp:
+        with capture(tmp) as cap:
+            time.sleep(max(0.0, float(seconds)))
+        if cap.xplane is None:
+            raise RuntimeError("the profiler wrote no trace")
+        with open(cap.xplane, "rb") as f:
+            data = f.read()
+    return {"xplane": data, "pid": os.getpid(),
+            "start_unix_ns": cap.start_unix_ns, "stop_s": cap.stop_s}
+
+
+def remote_capture(actor, seconds: float) -> Dict[str, Any]:
+    """`capture_for(seconds)` in the process that hosts `actor`, by its
+    worker, on a thread that is none of the actor's request threads:
+    its own methods keep running, and an actor whose threads are all
+    taken (a TrainWorker inside its loop) still answers."""
+    from .. import api
+
+    return api.get(actor._actor_method_call(
+        PROFILE_METHOD, (float(seconds),), {}, {}))
+
+
+def profile_actor(actor, seconds: float,
+                  logdir: Optional[str] = None) -> Dict[str, Any]:
+    """Have the process that hosts `actor` (a Serve replica, a
+    TrainWorker: anything that owns a chip) profile itself for
+    `seconds`, and write the trace under `logdir` here. Returns
+    {"path", "bytes", "pid", "start_unix_ns", "stop_s"}."""
+    out = remote_capture(actor, seconds)
+    data = out.pop("xplane")
+    logdir = logdir or default_logdir()
+    os.makedirs(logdir, exist_ok=True)
+    path = os.path.join(
+        logdir, f"pid{out['pid']}_{out['start_unix_ns']}.xplane.pb")
+    with open(path, "wb") as f:
+        f.write(data)
+    out.update(path=path, bytes=len(data))
+    return out
 
 
 def device_memory_stats(device_index: int = 0) -> Dict[str, Any]:
@@ -78,16 +276,6 @@ def device_memory_stats(device_index: int = 0) -> Dict[str, Any]:
         return {}
     stats = devs[device_index].memory_stats() or {}
     return dict(stats)
-
-
-def annotate(name: str):
-    """Named profiler span (reference: _private/profiling.profile):
-    shows up as a labeled region in the trace viewer.
-
-        with profiling.annotate("tokenize"): ...
-    """
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 class Timer:

@@ -4,8 +4,8 @@ Reference parity: python/ray/util/tracing/tracing_helper.py — the
 reference injects OpenTelemetry spans around task/actor submission and
 execution and propagates span context *inside task specs*
 (_DictPropagator:165, span decorators :195+), and aggregates per-task
-events in the GCS task manager (SURVEY §2.2, §5). Same design here
-without a hard OpenTelemetry dependency.
+events in the GCS task manager (SURVEY §2.2, §5). Same design here,
+with no OpenTelemetry in it: spans are this module's own records.
 
 Architecture (PR 7 — everything piggybacks on the telemetry plane):
 
@@ -238,8 +238,7 @@ def span(name: str, **attributes: Any):
     start = time.time()
     error = None
     try:
-        with _maybe_otel_span(name, attributes):
-            yield span_id
+        yield span_id
     except BaseException as e:
         error = repr(e)
         raise
@@ -269,21 +268,6 @@ def activate_context(ctx: Optional[Dict[str, str]]):
 def deactivate_context(token) -> None:
     if token is not None:
         _current.reset(token)
-
-
-@contextlib.contextmanager
-def _maybe_otel_span(name: str, attributes: Dict):
-    """Mirror to OpenTelemetry when available (reference:
-    _OpenTelemetryProxy:34 — tracing works without it installed)."""
-    try:
-        from opentelemetry import trace as otel_trace
-        tracer = otel_trace.get_tracer("ray_tpu")
-    except Exception:
-        yield
-        return
-    with tracer.start_as_current_span(name, attributes={
-            k: str(v) for k, v in (attributes or {}).items()}):
-        yield
 
 
 # ---------------------------------------------------------------------------

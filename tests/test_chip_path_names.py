@@ -1,0 +1,390 @@
+"""The chip path's names on the profiler's clock (util/profiling.py).
+
+Every name in HOST_SPANS is emitted by its site into a `capture`, the
+lowered train step carries DEVICE_SCOPES and still the kernels'
+`kernel_name`s, the engine's always-on counters add up, a profile can be
+taken by the process that hosts an actor while the actor keeps serving,
+and the LLM reply's `timing` is additive. CPU backend: the host plane is
+what is checked here, the device plane only on the chip (PERF.md)."""
+
+import asyncio
+import glob
+import os
+import re
+import subprocess
+import sys
+import threading
+import time
+
+import cloudpickle
+import jax
+import jax.numpy as jnp
+import pytest
+
+import ray_tpu
+from ray_tpu.models import GPTConfig, gpt_init, make_train_step
+from ray_tpu.util import profiling
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIP_PATH = ("ops", "models", "llm", "train", "data")
+
+
+def ray_tpu_spans(xplane: str) -> dict:
+    """{name: [(start_ns, duration_ns), ...]} of the `ray_tpu.*` spans on
+    the host plane, plus "$python": the Python tracer's events."""
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(xplane).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                key = e.name if e.name.startswith("ray_tpu.") else (
+                    "$python" if e.name.startswith("$") else None)
+                if key:
+                    out.setdefault(key, []).append(
+                        (e.start_ns, e.duration_ns))
+    return out
+
+
+@pytest.fixture(scope="module")
+def small_setup():
+    cfg = GPTConfig(vocab_size=272, d_model=64, n_heads=4, n_layers=2,
+                    d_ff=128, max_seq_len=256)
+    return cfg, gpt_init(jax.random.PRNGKey(7), cfg)
+
+
+def _run_engine(cfg, params, prompts, n_tokens):
+    """A whole engine life: requests in, streams out, loop ended."""
+    from ray_tpu.llm.continuous import ContinuousBatchingEngine
+    eng = ContinuousBatchingEngine(cfg=cfg, params=params, max_batch=2)
+    t0 = time.perf_counter()
+    streams = [eng.submit(p, n_tokens, 0.0) for p in prompts]
+    texts = ["".join(s) for s in streams]
+    deadline = time.time() + 10
+    while not eng.phase_n["idle"] and time.time() < deadline:
+        time.sleep(0.01)    # the loop goes idle once the slots empty
+    eng.close()
+    eng._thread.join(timeout=10)
+    assert not eng._thread.is_alive()
+    return eng, streams, texts, time.perf_counter() - t0
+
+
+class _Doubler:
+    def __call__(self, batch):
+        return {"id": batch["id"] * 2}
+
+
+@pytest.fixture(scope="module")
+def spans_seen(ray_start_shared, small_setup, tmp_path_factory):
+    """One capture in this process over a tiny engine run, a tiny
+    iter_jax_batches, a train.report with its poll and a replica's
+    handler; one taken by a map_batches actor's worker of itself."""
+    from ray_tpu import data as rd
+    from ray_tpu.data.dataset import _MapBatchesActorPool
+    from ray_tpu.serve._private.replica import Replica
+    from ray_tpu.train import session
+    from ray_tpu.train.worker_group import TrainWorker
+
+    cfg, params = small_setup
+    logdir = str(tmp_path_factory.mktemp("capture"))
+    with profiling.capture(logdir) as cap:
+        _run_engine(cfg, params, ["hello", "late one", "third"], 4)
+        rows = sum(int(b["id"].shape[0]) for b in
+                   rd.range(48).iter_jax_batches(batch_size=16))
+        worker = TrainWorker._cls()
+        worker._session = session._Session(session.TrainContext())
+        session._set_session(worker._session)
+        try:
+            session.report({"loss": 1.0})
+            reports = worker.poll()
+        finally:
+            session._set_session(None)
+        replica = Replica(cloudpickle.dumps(lambda x: x + 1), (), {}, "d")
+        handled = asyncio.run(replica.handle_request("__call__", (1,), {}))
+    assert rows == 48 and handled == 2
+    assert [r["metrics"] for r in reports] == [{"loss": 1.0}]
+
+    pool = _MapBatchesActorPool(_Doubler, 1, 1, {}, (), {})
+    actor = pool.actors[0]
+    blk = {"id": jnp.arange(8).__array__()}
+    ray_tpu.get(actor.apply.remote(blk, 4, "numpy", (), {}))
+    got = {}
+    t = threading.Thread(target=lambda: got.update(
+        profiling.profile_actor(actor, 1.0, logdir)))
+    t.start()
+    outs = []
+    while t.is_alive():
+        outs.append(ray_tpu.get(actor.apply.remote(blk, 4, "numpy", (), {})))
+    t.join()
+    assert outs and all(list(o["id"]) == list(blk["id"] * 2) for o in outs)
+    return {"local": cap, "local_spans": ray_tpu_spans(cap.xplane),
+            "remote": got, "remote_spans": ray_tpu_spans(got["path"]),
+            "served_during_profile": len(outs)}
+
+
+@pytest.mark.parametrize("name", sorted(profiling.HOST_SPANS))
+def test_host_span_is_emitted_by_its_site(spans_seen, name):
+    where = "remote_spans" if name == "ray_tpu.data.map_batch" \
+        else "local_spans"
+    assert name in spans_seen[where], sorted(spans_seen[where])
+
+
+def test_capture_writes_one_xplane_with_the_python_tracer_off(spans_seen):
+    cap = spans_seen["local"]
+    assert glob.glob(os.path.join(cap.logdir, "**", "*.xplane.pb"),
+                     recursive=True).count(cap.xplane) == 1
+    assert cap.stop_s is not None and os.path.getsize(cap.xplane) > 0
+    assert "$python" not in spans_seen["local_spans"]
+    # Spans count from the trace's own unix zero (PERF.md: the chip's).
+    zero = profiling.profile_start_unix_ns(cap.xplane)
+    first = zero + min(s for s, _ in spans_seen["local_spans"]
+                       ["ray_tpu.engine.decode"])
+    assert cap.start_unix_ns <= zero < first < time.time_ns()
+    assert first - cap.start_unix_ns < 120e9
+
+
+def test_remote_profile_is_readable_and_the_actor_kept_serving(spans_seen):
+    got = spans_seen["remote"]
+    assert got["pid"] != os.getpid() and got["bytes"] > 0
+    assert got["bytes"] == os.path.getsize(got["path"])
+    assert spans_seen["served_during_profile"] >= 1
+    assert len(spans_seen["remote_spans"]["ray_tpu.data.map_batch"]) >= 2
+
+
+def _literals(pattern: str, under=("",)):
+    found = {}
+    for sub in under:
+        for path in glob.glob(os.path.join(ROOT, "ray_tpu", sub, "**",
+                                           "*.py"), recursive=True):
+            with open(path) as f:
+                for m in re.finditer(pattern, f.read()):
+                    found.setdefault(m.group(1), set()).add(
+                        os.path.relpath(path, ROOT))
+    return found
+
+
+def test_the_table_lists_exactly_the_names_the_program_emits():
+    from ray_tpu.llm.continuous import PHASES
+    emitted = set(_literals(r'\bannotate\(\s*"([^"]+)"'))
+    emitted.discard("ray_tpu.engine.")       # + one of PHASES
+    emitted |= {"ray_tpu.engine." + p for p in PHASES}
+    assert emitted == set(profiling.HOST_SPANS)
+    assert all(re.fullmatch(r"ray_tpu\.[a-z]+\.[a-z_]+", n)
+               for n in profiling.HOST_SPANS)
+    scopes = _literals(r'named_scope\(\s*"([^"]+)"', CHIP_PATH)
+    assert set(scopes) == set(profiling.DEVICE_SCOPES)
+
+
+def test_the_chip_path_opens_spans_through_profiling_only():
+    other = _literals(r"(TraceAnnotation|tracing\.span|start_trace)\(",
+                      CHIP_PATH)
+    assert other == {}
+
+
+def test_annotate_keeps_a_process_off_jax():
+    code = ("import sys\n"
+            "from ray_tpu.util import profiling\n"
+            "with profiling.annotate('ray_tpu.feed.fetch_block'):\n"
+            "    pass\n"
+            "import ray_tpu.data.streaming, ray_tpu.train.session\n"
+            "import ray_tpu.serve._private.replica\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
+                   timeout=60)
+
+
+def test_lowered_train_step_carries_scopes_and_kernel_names(monkeypatch):
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = GPTConfig(vocab_size=512, d_model=128, n_heads=2, n_layers=1,
+                    d_ff=256, max_seq_len=256, remat=False)
+    init_state, step = make_train_step(cfg)
+    state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    text = step.trace(state, (tok, tok)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
+        "_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
+    for kernel in ("fwd", "dq", "dkv"):
+        assert re.search(r'loc\("[^"]*/flash_attention_%s/pallas_call"'
+                         % kernel, text), kernel
+    for scope in ("layers", "loss", "optimizer_update"):
+        assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
+                         text), scope
+
+
+def test_generate_steps_carry_their_scopes():
+    from ray_tpu.models.generate import (init_cache, make_continuous_fns,
+                                         make_generate_fns)
+    cfg = GPTConfig(vocab_size=272, d_model=64, n_heads=4, n_layers=1,
+                    d_ff=128, max_seq_len=64)
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, 64))
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+    one = jax.ShapeDtypeStruct((), jnp.int32)
+    insert, decode_batch = make_continuous_fns(cfg, 64, 2)
+    prefill, decode_step = make_generate_fns(cfg, 64)
+    lowered = {
+        "prefill": [
+            insert.lower(params, jax.ShapeDtypeStruct((1, 64), jnp.int32),
+                         cache, one, one),
+            prefill.lower(params, jax.ShapeDtypeStruct((2, 8), jnp.int32),
+                          cache)],
+        "decode": [decode_batch.lower(params, vec, vec, cache),
+                   decode_step.lower(params, vec, one, cache)]}
+    for scope, programs in lowered.items():
+        for low in programs:
+            assert re.search(r'loc\("jit\([a-z_]+\)/%s/' % scope,
+                             low.as_text(debug_info=True)), scope
+
+
+def test_engine_counters_add_up(small_setup):
+    cfg, params = small_setup
+    prompts = ["a", "bb", "ccc", "dddd", "eeeee"]     # > max_batch=2
+    eng, streams, texts, wall = _run_engine(cfg, params, prompts, 6)
+    c = eng.counters()
+    assert set(c["phase_s"]) == set(c["phase_n"]) == {
+        "admit", "prefill", "decode", "fetch", "sample", "idle"}
+    assert all(v >= 0 for v in c["phase_s"].values())
+    assert sum(c["phase_s"].values()) <= wall
+    assert c["phase_n"]["decode"] == c["phase_n"]["fetch"] == c["steps"]
+    assert c["phase_n"]["admit"] == c["phase_n"]["prefill"] \
+        == c["admitted"] == len(prompts)
+    assert c["phase_n"]["sample"] == c["steps"] + len(prompts)
+    # A prefill gives a request its first token, every decode step one
+    # token to each occupied slot.
+    assert c["tokens_out"] == c["slot_steps"] + c["admitted"] \
+        == 6 * len(prompts)
+    assert c["slot_steps"] <= c["steps"] * eng.max_batch
+    assert c["finish_reasons"] == {"length": len(prompts)}
+    assert len(eng.finished) == len(prompts)
+    for s, text in zip(streams, texts):
+        t = s.timing.as_dict()
+        assert t in list(eng.finished)
+        assert 0 <= t["queued_s"] <= t["first_token_s"] <= t["done_s"] \
+            <= wall
+        assert t["tokens"] == 6 and t["finish_reason"] == "length"
+    # The third request had to wait for a slot; the first did not.
+    assert streams[2].timing.as_dict()["queued_s"] \
+        > streams[0].timing.as_dict()["queued_s"]
+
+
+def test_stop_token_is_a_finish_reason(small_setup):
+    from ray_tpu.llm.continuous import ContinuousBatchingEngine
+    cfg, params = small_setup
+    eng = ContinuousBatchingEngine(cfg=cfg, params=params, max_batch=2)
+    try:
+        free = eng.submit("hello", 8, 0.0)
+        "".join(free)
+        first = next(iter(eng.finished))
+        assert first["finish_reason"] == "length" and first["tokens"] == 8
+        # Greedy decoding repeats itself: stop on the token it opens with.
+        from ray_tpu.models.generate import generate
+        opener = int(next(generate(
+            params, cfg, eng.tokenizer.encode("hello"),
+            max_new_tokens=1))[0])
+        stopped = eng.submit("hello", 8, 0.0, stop_token=opener)
+        "".join(stopped)
+        t = stopped.timing.as_dict()
+        assert t["finish_reason"] == "stop" and t["tokens"] == 1
+    finally:
+        eng.close()
+
+
+def test_device_gauges_never_start_or_wait_for_a_backend():
+    from ray_tpu._private import telemetry
+    from ray_tpu.util import metrics as M
+
+    def built():
+        m = M._REGISTRY.get("device_programs_built")
+        return None if m is None else m._samples()[0][2]
+
+    if telemetry.enabled:
+        telemetry.flush_device_gauges()
+        before = built()
+        assert before is not None
+        jax.block_until_ready(jax.jit(lambda x: x * 3 + before)(1.0))
+        telemetry.flush_device_gauges()
+        assert built() == before + 1
+    # Backend start-up holds jax's backend lock for as long as a
+    # jax.distributed gang takes to gather; the flush runs on the thread
+    # that sends completions and must not queue behind it.
+    code = ("import sys, threading, jax\n"
+            "from ray_tpu._private import telemetry\n"
+            "from jax._src import xla_bridge\n"
+            "with xla_bridge._backend_lock:\n"
+            "    t = threading.Thread(target=telemetry.flush_device_gauges)\n"
+            "    t.start(); t.join(20)\n"
+            "    assert not t.is_alive(), 'flush waited for backend start-up'\n"
+            "assert telemetry._programs_built == 0\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
+                   timeout=60)
+
+
+def test_replica_counts_the_wait_for_a_handler_thread():
+    from ray_tpu._private import telemetry
+    from ray_tpu.serve._private.replica import Replica
+    from ray_tpu.util import metrics as M
+
+    if not telemetry.enabled:
+        pytest.skip("telemetry off in this run")
+    replica = Replica(cloudpickle.dumps(lambda x: x), (), {}, "waitdep")
+    assert asyncio.run(replica.handle_request("__call__", (5,), {})) == 5
+    text = M.prometheus_text()
+    assert re.search(r'serve_replica_handler_wait_s_count\{[^}]*'
+                     r'deployment="waitdep"[^}]*\} 1', text), text[-2000:]
+
+
+def test_llm_reply_timing_and_cli_profile_of_the_replica(
+        ray_start_shared, small_setup, tmp_path):
+    from ray_tpu import serve
+    from ray_tpu.llm import build_llm_app
+    from ray_tpu.llm.serving import LLMEngine
+    from ray_tpu.scripts import cli
+    from ray_tpu.util.state import list_actors
+
+    cfg, params = small_setup
+    serve.start()
+    serve.run(build_llm_app(cfg=cfg, params=params,
+                            continuous_batching=True, max_batch=4),
+              name="timed", route_prefix="/timed")
+    try:
+        h = serve.get_deployment_handle("LLMServer", "timed")
+        ask = {"body": {"prompt": "hi", "max_tokens": 8}}
+        out = h.remote(ask).result(timeout_s=120)
+        assert out["text"] == LLMEngine(cfg=cfg, params=params).complete(
+            "hi", max_new_tokens=8, temperature=0.0)
+        assert out["device"]["platform"] == "cpu"
+        assert out["device"]["pid"] != os.getpid()
+        assert out["engine_steps"] >= 7
+        t = out["timing"]
+        assert set(t) == {"submit_unix", "queued_s", "first_token_s",
+                          "done_s", "tokens", "finish_reason"}
+        assert 0 <= t["queued_s"] <= t["first_token_s"] <= t["done_s"]
+        assert t["tokens"] == 8 and t["finish_reason"] == "length"
+        assert abs(t["submit_unix"] - time.time()) < 120
+
+        name = next(a["name"] for a in list_actors()
+                    if a["state"] == "ALIVE" and a["name"]
+                    and a["name"].startswith("SERVE_REPLICA::")
+                    and "LLMServer" in a["name"])
+        path = str(tmp_path / "replica.xplane.pb")
+        done = {}
+        prof = threading.Thread(target=lambda: done.update(rc=cli.main(
+            ["profile", name, "--seconds", "1.5", "-o", path])))
+        prof.start()
+        replies = []
+        while prof.is_alive():
+            replies.append(h.remote(ask).result(timeout_s=120))
+        prof.join()
+        assert done == {"rc": 0} and replies
+        assert all(r["text"] == out["text"] for r in replies)
+        spans = ray_tpu_spans(path)
+        for phase in ("admit", "prefill", "decode", "fetch", "sample"):
+            assert "ray_tpu.engine." + phase in spans, sorted(spans)
+        assert "ray_tpu.serve.handle" in spans
+        assert "$python" not in spans
+    finally:
+        serve.shutdown()
