@@ -53,14 +53,14 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
 
     optimizer = optimizer or optax.adamw(3e-4, weight_decay=0.01)
     # XLA partitions everything from the shardings except the attention
-    # kernels, which are told their split (batch and heads, by the same
-    # rule table) while the step is traced.
+    # kernels and the loss's scan over rows, which are told their split
+    # (batch and heads, by the same rule table) while the step is traced.
     if mesh is not None and rules is not None:
-        attention_split = functools.partial(
+        step_split = functools.partial(
             kernel_sharding, mesh,
             rules.spec(("batch", "heads", None, None)))
     else:
-        attention_split = contextlib.nullcontext
+        step_split = contextlib.nullcontext
 
     def init_state(key):
         params = init_fn(key)
@@ -71,7 +71,7 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
                 "step": jnp.zeros((), dtype=jnp.int32)}
 
     def train_step(state, batch):
-        with attention_split():
+        with step_split():
             loss, grads = jax.value_and_grad(loss_fn)(
                 state["params"], batch)
         with jax.named_scope("optimizer_update"):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
 from ..ops.layers import rms_norm, rope
+from ..ops.loss import cross_entropy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,8 +149,8 @@ def _block(x, layer, cfg: LlamaConfig):
     return x
 
 
-def llama_forward(params: Dict, tokens, cfg: LlamaConfig):
-    """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
+def _hidden(params: Dict, tokens, cfg: LlamaConfig):
+    """Embedding + blocks + final norm: [b, s] -> [b, s, d]."""
     x = jnp.take(params["embed"], tokens, axis=0)
     block = functools.partial(_block, cfg=cfg)
     if cfg.remat:
@@ -157,17 +158,20 @@ def llama_forward(params: Dict, tokens, cfg: LlamaConfig):
             block, policy=jax.checkpoint_policies.nothing_saveable)
     for layer in params["layers"]:
         x = block(x, layer)
-    x = rms_norm(x, params["lnf"])
+    return rms_norm(x, params["lnf"])
+
+
+def llama_forward(params: Dict, tokens, cfg: LlamaConfig):
+    """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
+    x = _hidden(params, tokens, cfg)
     return jnp.einsum("bsd,dv->bsv", x, params["head"]
                       ).astype(jnp.float32)
 
 
 def llama_loss(params: Dict, batch: Tuple, cfg: LlamaConfig):
     tokens, targets = batch
-    logits = llama_forward(params, tokens, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return cross_entropy(_hidden(params, tokens, cfg), params["head"],
+                         targets)
 
 
 def make_llama_train_step(cfg: LlamaConfig, optimizer=None,

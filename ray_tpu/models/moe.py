@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import flash_attention
 from ..ops.layers import rms_norm, rope
+from ..ops.loss import cross_entropy
 from ..parallel.moe import moe_layer
 
 
@@ -126,8 +127,8 @@ def _block(x, layer, cfg: MoEConfig):
     return x, aux
 
 
-def moe_forward(params: Dict, tokens, cfg: MoEConfig):
-    """tokens [b, s] -> (logits [b, s, vocab] fp32, aux_loss scalar)."""
+def _hidden(params: Dict, tokens, cfg: MoEConfig):
+    """tokens [b, s] -> (final-norm rows [b, s, d], aux_loss scalar)."""
     x = jnp.take(params["embed"], tokens, axis=0)
     aux_total = jnp.zeros((), jnp.float32)
     block = functools.partial(_block, cfg=cfg)
@@ -137,18 +138,22 @@ def moe_forward(params: Dict, tokens, cfg: MoEConfig):
     for layer in params["layers"]:
         x, aux = block(x, layer)
         aux_total = aux_total + aux
-    x = rms_norm(x, params["lnf"])
+    return rms_norm(x, params["lnf"]), aux_total / len(params["layers"])
+
+
+def moe_forward(params: Dict, tokens, cfg: MoEConfig):
+    """tokens [b, s] -> (logits [b, s, vocab] fp32, aux_loss scalar)."""
+    x, aux = _hidden(params, tokens, cfg)
     logits = jnp.einsum("bsd,dv->bsv", x, params["embed"].T
                         ).astype(jnp.float32)
-    return logits, aux_total / len(params["layers"])
+    return logits, aux
 
 
 def moe_loss(params: Dict, batch: Tuple, cfg: MoEConfig):
     tokens, targets = batch
-    logits, aux = moe_forward(params, tokens, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll) + cfg.aux_loss_weight * aux
+    x, aux = _hidden(params, tokens, cfg)
+    return (cross_entropy(x, params["embed"].T, targets)
+            + cfg.aux_loss_weight * aux)
 
 
 def make_moe_train_step(cfg: MoEConfig, optimizer=None,

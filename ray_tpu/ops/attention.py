@@ -81,22 +81,24 @@ def _kernel_ok(seq_len: int) -> bool:
 
 
 # (mesh, PartitionSpec over [batch, heads, seq, head_dim]) while a sharded
-# program is being traced; see kernel_sharding.
+# step is being traced; see kernel_sharding.
 _SHARDING: contextvars.ContextVar = contextvars.ContextVar(
-    "flash_attention_sharding", default=None)
+    "kernel_sharding", default=None)
 
 
 @contextlib.contextmanager
 def kernel_sharding(mesh, spec):
-    """Trace the enclosed code with flash_attention's kernels run once per
-    shard of `mesh`: q/k/v [batch, heads, seq, head_dim] are split by
-    `spec`, which may name mesh axes for batch and heads only — each
-    kernel instance sees whole sequences.
+    """Trace the enclosed step with the parts XLA cannot partition run
+    once per shard of `mesh`: q/k/v [batch, heads, seq, head_dim] are
+    split by `spec`, which may name mesh axes for batch and heads only —
+    each kernel instance sees whole sequences — and ops.loss scans each
+    chip's own rows of the batch axes.
 
     A pallas_call is opaque to the SPMD partitioner: jax refuses to lower
     one inside a program partitioned over several devices ("Mosaic
-    kernels cannot be automatically partitioned"), so a sharded train
-    step states here how its attention is split
+    kernels cannot be automatically partitioned"); a scan that slices
+    the batch at a traced offset is gathered whole onto every chip. So a
+    sharded train step states here how its batch and heads are split
     (models._training.make_train_step_for does, from its rule table)."""
     if spec[2:] != (None,) * len(spec[2:]):
         raise ValueError(
@@ -110,9 +112,14 @@ def kernel_sharding(mesh, spec):
         _SHARDING.reset(token)
 
 
+def step_sharding():
+    """(mesh, spec) of the enclosing kernel_sharding, or None."""
+    return _SHARDING.get()
+
+
 def _per_shard(fn):
     """`fn` over q-shaped arrays, run per shard under kernel_sharding."""
-    ctx = _SHARDING.get()
+    ctx = step_sharding()
     if ctx is None:
         return fn
     mesh, spec = ctx

@@ -94,8 +94,9 @@ DEVICE_SCOPES: Dict[str, str] = {
     "flash_attention_dkv": "ops/attention.py _flash_backward, the "
                            "_dkv_kernel pallas_call",
     "layers": "models/gpt.py _backbone, the layer stack",
-    "loss": "models/gpt.py gpt_loss after the backbone (the chunked "
-            "scan)",
+    "loss": "ops/loss.py cross_entropy, every family's loss after its "
+            "backbone: the scan over chunks of rows, forward and "
+            "gradient in one pass",
     "optimizer_update": "models/_training.py train_step, optimizer "
                         "update and apply",
     "prefill": "models/generate.py prefill / insert_prefill",
