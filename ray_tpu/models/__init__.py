@@ -26,6 +26,7 @@ from .moe import (  # noqa: F401
     moe_forward,
     moe_init,
     moe_loss,
+    moe_loss_and_counters,
     moe_param_axes,
 )
 from .resnet import (  # noqa: F401

@@ -40,10 +40,12 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
                         axes: Optional[Dict] = None,
                         optimizer=None,
                         donate: bool = True,
-                        mesh=None, rules=None):
+                        mesh=None, rules=None, has_aux: bool = False):
     """Build (init_state, train_step) for a model family.
 
-    init_fn(key) -> params; loss_fn(params, batch) -> scalar loss.
+    init_fn(key) -> params; loss_fn(params, batch) -> scalar loss, or with
+    `has_aux` (loss, dict of counters): the step returns the counters in
+    its metrics beside `loss` (models/moe.py's router counters).
     With mesh + rules (+ axes), params/opt-state carry NamedShardings and
     XLA inserts the dp gradient psum / tp collectives from the shardings —
     no explicit pmap/DDP wrapper (contrast: the reference's
@@ -72,15 +74,16 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
 
     def train_step(state, batch):
         with step_split():
-            loss, grads = jax.value_and_grad(loss_fn)(
+            loss, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
                 state["params"], batch)
+        loss, counters = loss if has_aux else (loss, {})
         with jax.named_scope("optimizer_update"):
             updates, new_opt = optimizer.update(
                 grads, state["opt_state"], state["params"])
             new_params = optax.apply_updates(state["params"], updates)
         return ({"params": new_params, "opt_state": new_opt,
                  "step": state["step"] + 1},
-                {"loss": loss})
+                {**counters, "loss": loss})
 
     donate_argnums = (0,) if donate else ()
     return init_state, jax.jit(train_step, donate_argnums=donate_argnums)

@@ -1,10 +1,14 @@
-"""Mixture-of-Experts decoder (Mixtral-style), TPU-first.
+"""Mixture-of-Experts decoder (OLMoE-style), TPU-first.
 
-Net-new vs the reference (SURVEY.md §2.4: EP "Absent"): a GPT-family
-decoder whose MLP is a top-2 routed expert layer
-(parallel.moe.moe_layer). Single-mesh execution computes experts with
-batched einsums; under shard_map with an `ep` axis the layer all_to_alls
-tokens to their experts' shards (pass axis_name via cfg.ep_axis).
+Net-new vs the reference (SURVEY.md §2.4: EP "Absent"): a decoder whose
+MLP is a dropless top-k routed layer of SwiGLU experts
+(parallel.moe.dropless_moe_layer over ops.grouped_matmul), with RMSNorm
+on the whole of q and k before the head split, rotary positions, an
+untied head, router probabilities that are not renormalised over the
+chosen experts, and the load-balancing and router-z auxiliary losses of
+OLMoE (arXiv:2409.02060; `MoEConfig.olmoe_1b_7b()` is
+allenai/OLMoE-1B-7B's config.json). Every token reaches its
+`experts_per_token` experts: there is no capacity to overflow.
 
 Same conventions as models.gpt: dict pytrees, logical axis tables
 (experts carry a leading 'expert' axis that partition rules map to the
@@ -15,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +27,7 @@ import jax.numpy as jnp
 from ..ops.attention import flash_attention
 from ..ops.layers import rms_norm, rope
 from ..ops.loss import cross_entropy
-from ..parallel.moe import moe_layer
+from ..parallel.moe import dropless_moe_layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,15 +37,18 @@ class MoEConfig:
     n_heads: int = 8
     n_layers: int = 4
     n_experts: int = 8
-    d_ff: int = 1024
-    capacity_factor: float = 1.25
-    aux_loss_weight: float = 0.01
+    experts_per_token: int = 2
+    d_expert: int = 1024            # width of one expert's SwiGLU
+    qk_norm: bool = True            # RMSNorm over all of q and of k
+    tie_embeddings: bool = False
+    norm_topk_prob: bool = False    # renormalise the k router weights
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    aux_loss_weight: float = 0.01   # load-balancing loss
+    z_loss_weight: float = 0.001    # router z-loss
     max_seq_len: int = 1024
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # Mesh axis name for expert parallelism (used inside shard_map);
-    # None = single-shard dense-dispatch path.
-    ep_axis: Optional[str] = None
 
     @property
     def head_dim(self) -> int:
@@ -50,41 +57,61 @@ class MoEConfig:
     @classmethod
     def tiny(cls) -> "MoEConfig":
         return cls(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
-                   n_experts=4, d_ff=96, max_seq_len=64)
+                   n_experts=4, experts_per_token=2, d_expert=96,
+                   max_seq_len=64)
+
+    @classmethod
+    def olmoe_1b_7b(cls) -> "MoEConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct: 6.92 B parameters, 1.3 B
+        active a token. The loss weights are the OLMoE paper's."""
+        return cls(vocab_size=50304, d_model=2048, n_heads=16,
+                   n_layers=16, n_experts=64, experts_per_token=8,
+                   d_expert=1024, max_seq_len=4096)
 
 
 def _layer_init(key, cfg: MoEConfig) -> Dict:
-    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+    d, f, e = cfg.d_model, cfg.d_expert, cfg.n_experts
     scale = d ** -0.5
     out_scale = scale / (2 * cfg.n_layers) ** 0.5
-    return {
+
+    def normal(k, shape, s):
+        return (jax.random.normal(k, shape) * s).astype(cfg.dtype)
+
+    layer = {
         "ln1": jnp.ones((d,), dtype=jnp.float32),
-        "wqkv": (jax.random.normal(k1, (d, 3 * d)) * scale
-                 ).astype(cfg.dtype),
-        "wo": (jax.random.normal(k2, (d, d)) * out_scale
-               ).astype(cfg.dtype),
+        "wqkv": normal(k1, (d, 3 * d), scale),
+        "wo": normal(k2, (d, d), out_scale),
         "ln2": jnp.ones((d,), dtype=jnp.float32),
         # Router weights stay fp32: routing decisions are
         # precision-sensitive (flips reroute whole tokens).
-        "gate": jax.random.normal(k3, (d, e)) * scale,
-        "expert_w1": (jax.random.normal(k4, (e, d, f)) * scale
-                      ).astype(cfg.dtype),
-        "expert_w2": (jax.random.normal(k5, (e, f, d)) * out_scale
-                      ).astype(cfg.dtype),
+        "router": jax.random.normal(k3, (d, e)) * scale,
+        "expert_gate": normal(k4, (e, d, f), scale),
+        "expert_up": normal(k5, (e, d, f), scale),
+        "expert_down": normal(k6, (e, f, d),
+                              f ** -0.5 / (2 * cfg.n_layers) ** 0.5),
     }
+    if cfg.qk_norm:
+        layer["q_norm"] = jnp.ones((d,), dtype=jnp.float32)
+        layer["k_norm"] = jnp.ones((d,), dtype=jnp.float32)
+    return layer
 
 
 def moe_init(key, cfg: MoEConfig) -> Dict:
-    keys = jax.random.split(key, cfg.n_layers + 1)
-    return {
+    keys = jax.random.split(key, cfg.n_layers + 2)
+    params = {
         "embed": (jax.random.normal(keys[0],
                                     (cfg.vocab_size, cfg.d_model))
                   * cfg.d_model ** -0.5).astype(cfg.dtype),
         "lnf": jnp.ones((cfg.d_model,), dtype=jnp.float32),
-        "layers": [_layer_init(keys[i + 1], cfg)
+        "layers": [_layer_init(keys[i + 2], cfg)
                    for i in range(cfg.n_layers)],
     }
+    if not cfg.tie_embeddings:
+        params["head"] = (jax.random.normal(
+            keys[1], (cfg.d_model, cfg.vocab_size))
+            * cfg.d_model ** -0.5).astype(cfg.dtype)
+    return params
 
 
 def moe_param_axes(cfg: MoEConfig) -> Dict:
@@ -93,75 +120,119 @@ def moe_param_axes(cfg: MoEConfig) -> Dict:
         "wqkv": ("embed", "mlp"),
         "wo": ("mlp", "embed"),
         "ln2": ("embed",),
-        "gate": ("embed", None),
-        "expert_w1": ("expert", "embed", "mlp"),
-        "expert_w2": ("expert", "mlp", "embed"),
+        "router": ("embed", None),
+        "expert_gate": ("expert", "embed", "mlp"),
+        "expert_up": ("expert", "embed", "mlp"),
+        "expert_down": ("expert", "mlp", "embed"),
     }
-    return {
+    if cfg.qk_norm:
+        layer["q_norm"] = ("mlp",)
+        layer["k_norm"] = ("mlp",)
+    axes = {
         "embed": ("vocab", "embed"),
         "lnf": ("embed",),
         "layers": [dict(layer) for _ in range(cfg.n_layers)],
     }
+    if not cfg.tie_embeddings:
+        axes["head"] = ("embed", "vocab")
+    return axes
 
 
 def _block(x, layer, cfg: MoEConfig):
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
-    y = rms_norm(x, layer["ln1"])
+    y = rms_norm(x, layer["ln1"], cfg.norm_eps)
     qkv = jnp.einsum("bsd,de->bse", y, layer["wqkv"])
     q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rope(q.reshape(b, s, h, hd).transpose(0, 2, 1, 3))
-    k = rope(k.reshape(b, s, h, hd).transpose(0, 2, 1, 3))
+    if cfg.qk_norm:
+        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    q = rope(q.reshape(b, s, h, hd).transpose(0, 2, 1, 3),
+             base=cfg.rope_theta)
+    k = rope(k.reshape(b, s, h, hd).transpose(0, 2, 1, 3),
+             base=cfg.rope_theta)
     v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
     attn = flash_attention(q, k, v, True, None)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
     x = x + jnp.einsum("bsd,de->bse", attn, layer["wo"])
     # Routed expert MLP over flattened tokens
-    y = rms_norm(x, layer["ln2"])
-    flat = y.reshape(b * s, d)
-    out, aux = moe_layer(flat, layer["gate"], layer["expert_w1"],
-                         layer["expert_w2"],
-                         capacity_factor=cfg.capacity_factor,
-                         axis_name=cfg.ep_axis)
-    x = x + out.reshape(b, s, d)
-    return x, aux
+    y = rms_norm(x, layer["ln2"], cfg.norm_eps)
+    out, stats = dropless_moe_layer(
+        y.reshape(b * s, d), layer["router"], layer["expert_gate"],
+        layer["expert_up"], layer["expert_down"],
+        experts_per_token=cfg.experts_per_token,
+        norm_topk_prob=cfg.norm_topk_prob)
+    return x + out.reshape(b, s, d), stats
 
 
 def _hidden(params: Dict, tokens, cfg: MoEConfig):
-    """tokens [b, s] -> (final-norm rows [b, s, d], aux_loss scalar)."""
+    """tokens [b, s] -> (final-norm rows [b, s, d], the router's counters:
+    `balance_loss`, `router_z`, `expert_tokens` [E] summed over layers,
+    `expert_load_max_over_mean`). Both losses are taken over all layers'
+    tokens together, as HF's load_balancing_loss_func does."""
     x = jnp.take(params["embed"], tokens, axis=0)
-    aux_total = jnp.zeros((), jnp.float32)
     block = functools.partial(_block, cfg=cfg)
     if cfg.remat:
         block = jax.checkpoint(
             block, policy=jax.checkpoint_policies.nothing_saveable)
+    total = None
     for layer in params["layers"]:
-        x, aux = block(x, layer)
-        aux_total = aux_total + aux
-    return rms_norm(x, params["lnf"]), aux_total / len(params["layers"])
+        x, stats = block(x, layer)
+        total = stats if total is None else jax.tree.map(
+            jnp.add, total, stats)
+    rows = tokens.size * len(params["layers"])
+    counts = total["expert_tokens"]
+    share = counts.astype(jnp.float32) / rows       # sums to k
+    counters = {
+        "balance_loss": cfg.n_experts * jnp.sum(
+            share * total["router_prob_sum"] / rows),
+        "router_z": total["router_z_sq_sum"] / rows,
+        "expert_tokens": counts,
+        "expert_load_max_over_mean":
+            jnp.max(counts) / jnp.mean(counts.astype(jnp.float32)),
+    }
+    return rms_norm(x, params["lnf"], cfg.norm_eps), counters
+
+
+def _head(params: Dict):
+    return params["head"] if "head" in params else params["embed"].T
+
+
+def _aux_loss(counters: Dict, cfg: MoEConfig):
+    return (cfg.aux_loss_weight * counters["balance_loss"]
+            + cfg.z_loss_weight * counters["router_z"])
 
 
 def moe_forward(params: Dict, tokens, cfg: MoEConfig):
-    """tokens [b, s] -> (logits [b, s, vocab] fp32, aux_loss scalar)."""
-    x, aux = _hidden(params, tokens, cfg)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["embed"].T
+    """tokens [b, s] -> (logits [b, s, vocab] fp32, the weighted
+    auxiliary loss that moe_loss adds to the cross entropy)."""
+    x, counters = _hidden(params, tokens, cfg)
+    logits = jnp.einsum("bsd,dv->bsv", x, _head(params)
                         ).astype(jnp.float32)
-    return logits, aux
+    return logits, _aux_loss(counters, cfg)
+
+
+def moe_loss_and_counters(params: Dict, batch: Tuple, cfg: MoEConfig):
+    """(loss, the router's counters): cross entropy + aux_loss_weight *
+    balance_loss + z_loss_weight * router_z."""
+    tokens, targets = batch
+    x, counters = _hidden(params, tokens, cfg)
+    loss = cross_entropy(x, _head(params), targets) \
+        + _aux_loss(counters, cfg)
+    return loss, counters
 
 
 def moe_loss(params: Dict, batch: Tuple, cfg: MoEConfig):
-    tokens, targets = batch
-    x, aux = _hidden(params, tokens, cfg)
-    return (cross_entropy(x, params["embed"].T, targets)
-            + cfg.aux_loss_weight * aux)
+    return moe_loss_and_counters(params, batch, cfg)[0]
 
 
 def make_moe_train_step(cfg: MoEConfig, optimizer=None,
                         donate: bool = True, mesh=None, rules=None):
+    """The step's metrics carry the router's counters beside `loss`."""
     from ._training import make_train_step_for
 
     return make_train_step_for(
         lambda key: moe_init(key, cfg),
-        lambda params, batch: moe_loss(params, batch, cfg),
+        lambda params, batch: moe_loss_and_counters(params, batch, cfg),
         axes=moe_param_axes(cfg), optimizer=optimizer, donate=donate,
-        mesh=mesh, rules=rules)
+        mesh=mesh, rules=rules, has_aux=True)

@@ -8,5 +8,6 @@ and as the autodiff backward.
 """
 
 from .attention import flash_attention, mha_reference  # noqa: F401
+from .grouped_matmul import grouped_matmul  # noqa: F401
 from .layers import rms_norm, rope, swiglu  # noqa: F401
 from .loss import cross_entropy  # noqa: F401

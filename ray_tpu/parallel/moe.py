@@ -1,9 +1,17 @@
-"""Mixture-of-Experts with expert parallelism over the `ep` mesh axis.
+"""Mixture-of-Experts layers.
 
-Net-new vs the reference (SURVEY.md §2.4: EP "Absent"): top-k token
-routing with capacity-bounded dense dispatch — einsum-based combine/
-dispatch (compiler-friendly, no dynamic shapes) and `lax.all_to_all`
-shuffles across the expert axis when experts are sharded.
+Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Two layers:
+
+* `moe_layer` (GShard): top-2 routing with capacity-bounded dense
+  dispatch — einsum-based combine/dispatch over one-hot [T, E, C] tensors
+  and `lax.all_to_all` shuffles across the `ep` mesh axis when experts are
+  sharded. Right for few experts; at 64 experts and top-8 its dispatch
+  tensors outgrow a chip.
+* `dropless_moe_layer`: top-k routing with no capacity and no dropped
+  token — the T*k assignments are sorted by expert, each expert's rows are
+  multiplied by its own matrices through ops.grouped_matmul, and the
+  results are summed back per token. Shapes are static (T*k rows always);
+  the uneven split is data. What models/moe.py runs.
 """
 
 from __future__ import annotations
@@ -14,6 +22,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops.grouped_matmul import grouped_matmul
 
 
 def top2_gating(logits, capacity: int):
@@ -123,3 +133,105 @@ def moe_layer(x, gate_w, expert_w1, expert_w2,
     y = jnp.einsum("ecd,tec->td", ye_all, combine)
     aux = lax.pmean(aux, axis_name)
     return y.astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k layer
+# ---------------------------------------------------------------------------
+def _rows(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, perm, inv, k: int):
+    """x [T, d] -> [T*k, d]: row i is the token of sorted assignment i,
+    x[perm[i] // k]. `inv` is perm's inverse: the cotangent is a gather by
+    it and a sum over each token's k copies, never a scatter-add."""
+    return _rows(x, perm // k)
+
+
+def _dispatch_fwd(x, perm, inv, k):
+    return _rows(x, perm // k), inv
+
+
+def _dispatch_bwd(k, inv, g):
+    per_token = _rows(g, inv).reshape(-1, k, g.shape[-1])
+    dx = jnp.sum(per_token.astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort(rows, perm, inv):
+    """rows[inv]: the sorted rows back in assignment order (token-major).
+    The cotangent is the gather by `perm`."""
+    return _rows(rows, inv)
+
+
+def _unsort_fwd(rows, perm, inv):
+    return _rows(rows, inv), perm
+
+
+def _unsort_bwd(perm, g):
+    return _rows(g, perm), None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
+                       experts_per_token: int,
+                       norm_topk_prob: bool = False):
+    """Top-k MoE with SwiGLU experts and no dropped token.
+
+    x [T, d]; router_w [d, E] float32; w_gate, w_up [E, d, f];
+    w_down [E, f, d]. Returns (out [T, d] in x's dtype, stats) where
+
+        out[t] = sum_j w[t, j] * (silu(x[t] G[e_j]) * (x[t] U[e_j])) D[e_j]
+
+    over the top-k experts e_j of softmax(x[t] router_w), weighted by
+    their probabilities (renormalised over the k only with
+    `norm_topk_prob`). The router runs in float32 at full precision;
+    expert matmuls take the operands' dtype (bf16) and accumulate in
+    float32. `stats`: `expert_tokens` [E] int32 (they sum to T*k),
+    `router_prob_sum` [E] (sum over tokens of the probabilities) and
+    `router_z_sq_sum` (sum over tokens of logsumexp(logits)**2): what the
+    load-balancing and z losses are made of, summable over layers.
+    """
+    t, d = x.shape
+    e = router_w.shape[-1]
+    k = experts_per_token
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        weights, experts = lax.top_k(probs, k)                # [T, k]
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        # Assignment a = t*k + j goes to expert `experts[t, j]`; a stable
+        # sort by expert groups them and keeps token order inside a group.
+        iota = jnp.arange(t * k, dtype=jnp.int32)
+        _, perm = lax.sort((experts.reshape(-1).astype(jnp.int32), iota),
+                           num_keys=1, is_stable=True)
+        _, inv = lax.sort((perm, iota), num_keys=1)
+        counts = jnp.sum(
+            experts.reshape(-1, 1) == jnp.arange(e, dtype=experts.dtype),
+            axis=0, dtype=jnp.int32)
+        xs = _dispatch(x, perm, inv, k)                       # [T*k, d]
+    gate = grouped_matmul(xs, w_gate, counts)
+    up = grouped_matmul(xs, w_up, counts)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32))
+              * up.astype(jnp.float32)).astype(x.dtype)
+    ys = grouped_matmul(hidden, w_down, counts)               # [T*k, d]
+    with jax.named_scope("moe_combine"):
+        per_token = _unsort(ys, perm, inv).reshape(t, k, d)
+        out = jnp.sum(per_token.astype(jnp.float32) * weights[:, :, None],
+                      axis=1).astype(x.dtype)
+    stats = {"expert_tokens": counts,
+             "router_prob_sum": jnp.sum(probs, axis=0),
+             "router_z_sq_sum": jnp.sum(jnp.square(lse))}
+    return out, stats

@@ -93,6 +93,20 @@ DEVICE_SCOPES: Dict[str, str] = {
                           "_dq_kernel pallas_call",
     "flash_attention_dkv": "ops/attention.py _flash_backward, the "
                            "_dkv_kernel pallas_call",
+    "grouped_matmul_fwd": "ops/grouped_matmul.py _gmm, the _gmm_kernel "
+                          "pallas_call: an expert layer's gate, up and "
+                          "down matmuls",
+    "grouped_matmul_dlhs": "ops/grouped_matmul.py _gmm with transpose_rhs, "
+                           "the _gmm_kernel pallas_call: the gradient by "
+                           "the rows",
+    "grouped_matmul_drhs": "ops/grouped_matmul.py _tgmm, the _tgmm_kernel "
+                           "pallas_call: the gradient by the experts' "
+                           "weights",
+    "moe_route": "parallel/moe.py dropless_moe_layer: float32 router, "
+                 "top-k, the sort of the assignments by expert, the "
+                 "per-expert counts and the gather of the rows",
+    "moe_combine": "parallel/moe.py dropless_moe_layer: the experts' rows "
+                   "back in token order and their weighted sum",
     "layers": "models/gpt.py _backbone, the layer stack",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "

@@ -26,7 +26,7 @@ from ray_tpu.models import GPTConfig, gpt_init, make_train_step
 from ray_tpu.util import profiling
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHIP_PATH = ("ops", "models", "llm", "train", "data")
+CHIP_PATH = ("ops", "models", "parallel", "llm", "train", "data")
 
 
 def ray_tpu_spans(xplane: str) -> dict:

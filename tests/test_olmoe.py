@@ -1,0 +1,350 @@
+"""OLMoE through the program: models/moe.py against the plain float32
+reference (chipbench/families/olmoe.py, the one copy), the dropless
+top-k layer (parallel/moe.py), the grouped-matmul kernels
+(ops/grouped_matmul.py) and the names and counters the step carries.
+CPU, tiny sizes, seeded weights."""
+
+import dataclasses
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.families import olmoe
+from ray_tpu import train
+from ray_tpu.models import (
+    MoEConfig,
+    make_moe_train_step,
+    moe_forward,
+    moe_init,
+    moe_loss,
+    moe_loss_and_counters,
+)
+from ray_tpu.parallel.moe import dropless_moe_layer
+from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+from ray_tpu.util import profiling
+
+# `ray_tpu.ops.grouped_matmul` the attribute is the function; the module:
+gm = importlib.import_module("ray_tpu.ops.grouped_matmul")
+
+F32 = dataclasses.replace(MoEConfig.tiny(), dtype=jnp.float32, n_experts=8,
+                          experts_per_token=3)
+
+
+def _tokens(cfg, batch=2, seq=32, seed=2):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0,
+                              cfg.vocab_size)
+    return toks, jnp.roll(toks, -1, 1)
+
+
+def _rel(a, b):
+    a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+# ---------------------------------------------------------------------------
+# the model against the reference
+# ---------------------------------------------------------------------------
+# float32 program against the float32 reference: the same mathematics in
+# another order (sorted rows against every expert for every token), so
+# they differ by float32 rounding only, 1e-6 of scale; 1e-4 leaves room
+# and would not pass a renormalised weight, a missing q/k norm or a
+# dropped assignment (each moves logits by 1e-2 or more). bf16 weights
+# against the float32 reference on the same weights: bf16 has 8 bits, two
+# layers of matmuls give about 2e-2 of the logits' scale.
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 5e-2)])
+def test_forward_logits_match_the_reference(dtype, tol):
+    cfg = dataclasses.replace(F32, dtype=dtype)
+    params = moe_init(jax.random.PRNGKey(1), cfg)
+    toks, _ = _tokens(cfg)
+    with jax.default_matmul_precision("highest"):
+        got, aux = moe_forward(params, toks, cfg)
+        want = olmoe.reference_logits(params, toks, cfg)
+    assert got.shape == (2, 32, cfg.vocab_size) and got.dtype == jnp.float32
+    assert _rel(got, want) < tol
+    assert float(aux) > 0
+
+
+@pytest.mark.parametrize("variant", ["olmoe", "tied_no_qk_norm_normed"])
+def test_loss_and_every_gradient_match_the_reference(variant):
+    cfg = F32 if variant == "olmoe" else dataclasses.replace(
+        F32, tie_embeddings=True, qk_norm=False, norm_topk_prob=True)
+    params = moe_init(jax.random.PRNGKey(3), cfg)
+    assert ("head" in params) == (variant == "olmoe")
+    batch = _tokens(cfg)
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.value_and_grad(moe_loss)(params, batch, cfg)
+        want, g_want = jax.value_and_grad(
+            lambda p: olmoe.reference_loss(p, *batch, cfg))(params)
+    assert abs(float(got) - float(want)) < 1e-5
+    # float32 rounding in another order: 1e-6 of each leaf's scale seen.
+    errs = jax.tree.map(_rel, g_got, g_want)
+    assert max(jax.tree.leaves(errs)) < 1e-4, errs
+    assert float(jnp.abs(g_got["layers"][0]["router"]).max()) > 0
+
+
+def test_balanced_router_gives_balance_loss_k_and_z_log_e_squared():
+    """A router of zeros: p = 1/E everywhere, sum_e f_e = k, so L_balance
+    = E * k / E = k whichever k experts the ties pick; L_z = (ln E)^2."""
+    cfg = F32
+    params = moe_init(jax.random.PRNGKey(0), cfg)
+    for lay in params["layers"]:
+        lay["router"] = jnp.zeros_like(lay["router"])
+    _, c = moe_loss_and_counters(params, _tokens(cfg), cfg)
+    assert float(c["balance_loss"]) == pytest.approx(cfg.experts_per_token)
+    assert float(c["router_z"]) == pytest.approx(
+        np.log(cfg.n_experts) ** 2, rel=1e-5)
+    assert int(c["expert_tokens"].sum()) == 2 * 32 * 3 * cfg.n_layers
+
+
+def test_olmoe_1b_7b_preset_is_the_published_model():
+    cfg = MoEConfig.olmoe_1b_7b()
+    shapes = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), cfg))
+    total = sum(x.size for x in jax.tree.leaves(shapes))
+    lay = shapes["layers"][0]
+    experts = sum(lay[k].size for k in ("expert_gate", "expert_up",
+                                        "expert_down"))
+    assert total == pytest.approx(6.92e9, rel=2e-3)
+    assert experts == 402_653_184
+    active = total - cfg.n_layers * experts * (1 - 8 / 64)
+    assert active == pytest.approx(1.28e9, rel=2e-2)
+    assert lay["expert_gate"].dtype == jnp.bfloat16
+    assert lay["router"].dtype == jnp.float32
+    assert shapes["head"].shape == (2048, 50304)
+
+
+def test_counts_equal_hand_counts_from_dict_and_config_object():
+    config = {"hidden_size": 2048, "num_attention_heads": 16,
+              "num_hidden_layers": 2, "num_experts": 64,
+              "num_experts_per_tok": 8, "intermediate_size": 1024,
+              "vocab_size": 50304}
+    cfg = dataclasses.replace(MoEConfig.olmoe_1b_7b(), n_layers=2)
+    layer = (2 * 2048 * 6144 + 2 * 2048 * 2048 + 2 * 2048 * 64
+             + 8 * 3 * 2 * 2048 * 1024 + 2 * 2 * 4096 * 2048 / 2)
+    want = 3 * (2 * layer + 2 * 2048 * 50304)
+    assert want == pytest.approx(1.526e9, rel=1e-3)
+    for c in (config, cfg):
+        assert olmoe.train_flops_per_token(c, 4096) == want
+        assert olmoe.expert_matmul_flops(c, 16384) == \
+            2 * 9 * 2 * 131072 * 2048 * 1024
+        assert olmoe.expert_matmul_bytes(c, 16384) == \
+            2 * 9 * 2 * (131072 * 3072 + 64 * 2048 * 1024)
+        assert olmoe.attention_kernel_flops(c, 4, 4096) == \
+            2 * 6 * 2 * 4 * 16 * 4096 * 4096 * 128 / 2
+        assert olmoe.attention_kernel_bytes(c, 4, 4096) == \
+            2 * 12 * 4 * 16 * 4096 * 128 * 2
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer
+# ---------------------------------------------------------------------------
+def _layer_inputs(t=64, d=32, f=48, e=64, dtype=jnp.float32, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return dict(
+        x=jax.random.normal(k[0], (t, d), dtype),
+        router=jax.random.normal(k[1], (d, e)) * d ** -0.5,
+        gate=(jax.random.normal(k[2], (e, d, f)) * d ** -0.5).astype(dtype),
+        up=(jax.random.normal(k[3], (e, d, f)) * d ** -0.5).astype(dtype),
+        down=(jax.random.normal(k[4], (e, f, d)) * f ** -0.5).astype(dtype))
+
+
+def _dense_reference(a, k, norm=False):
+    """The reference's every-expert-for-every-token layer on the same
+    tensors."""
+    lay = {"router": a["router"], "expert_gate": a["gate"],
+           "expert_up": a["up"], "expert_down": a["down"]}
+    cfg = MoEConfig(n_experts=a["router"].shape[1], experts_per_token=k,
+                    norm_topk_prob=norm)
+    return olmoe._experts(a["x"].astype(jnp.float32), jax.tree.map(
+        lambda w: w.astype(jnp.float32), lay), cfg)
+
+
+@pytest.mark.parametrize("kernels", ["interpreted", "ragged_dot"])
+def test_no_token_is_dropped_under_forced_imbalance(kernels, monkeypatch):
+    """A constant input feature and a router row that favours 8 of 64
+    experts send every token to the same 8: a capacity of 1.25 * T * 8 / 64
+    would drop 84% of the assignments; here all T * 8 arrive and 56 groups
+    are empty."""
+    if kernels == "interpreted":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(gm, "_TILES", (128, 128, 128))
+    a = _layer_inputs(t=64)
+    favoured = jnp.arange(8) * 7 + 3
+    a["x"] = a["x"].at[:, 0].set(10.0)
+    a["router"] = a["router"].at[0, :].set(0.0).at[0, favoured].set(5.0)
+    with jax.default_matmul_precision("highest"):
+        out, stats = dropless_moe_layer(
+            a["x"], a["router"], a["gate"], a["up"], a["down"],
+            experts_per_token=8)
+        want, _, chosen = _dense_reference(a, 8)
+    counts = np.asarray(stats["expert_tokens"])
+    assert set(np.asarray(chosen).ravel()) == set(np.asarray(favoured))
+    assert counts.sum() == 64 * 8
+    assert (counts == 0).sum() == 56
+    assert (counts[np.asarray(favoured)] == 64).all()
+    assert _rel(out, want) < 1e-5
+
+
+@pytest.mark.parametrize("norm", [False, True])
+def test_norm_topk_prob_false_leaves_the_weights_unnormalised(norm):
+    a = _layer_inputs(t=32, e=8)
+    with jax.default_matmul_precision("highest"):
+        out, _ = dropless_moe_layer(
+            a["x"], a["router"], a["gate"], a["up"], a["down"],
+            experts_per_token=2, norm_topk_prob=norm)
+        want, logits, _ = _dense_reference(a, 2, norm=norm)
+        normed, _ = dropless_moe_layer(
+            a["x"], a["router"], a["gate"], a["up"], a["down"],
+            experts_per_token=2, norm_topk_prob=True)
+    assert _rel(out, want) < 1e-5
+    # The two chosen probabilities sum to well under 1 with 8 experts;
+    # unnormalised, the output is the normalised one times that sum.
+    top2 = jnp.sum(jax.lax.top_k(jax.nn.softmax(logits, -1), 2)[0], -1)
+    assert float(top2.max()) < 0.9
+    scale = 1.0 if norm else top2[:, None]
+    assert _rel(out, normed * scale) < 1e-5
+
+
+def _all_avals(jaxpr):
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_avals(sub)
+
+
+def test_layer_holds_no_dispatch_tensor_and_no_float32_expert_copy():
+    t, d, f, e, k = 256, 32, 48, 16, 4
+    a = _layer_inputs(t=t, d=d, f=f, e=e, dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda *xs: dropless_moe_layer(*xs, experts_per_token=k)[0])(
+        a["x"], a["router"], a["gate"], a["up"], a["down"])
+    avals = [v for v in _all_avals(jaxpr.jaxpr) if hasattr(v, "shape")]
+    capacity = int(1.25 * t * k / e)
+    assert max(int(np.prod(v.shape)) for v in avals) < t * e * capacity
+    assert any(v.shape == (t * k, d) and v.dtype == jnp.bfloat16
+               for v in avals)                   # T*k rows, always
+    for v in avals:
+        if v.shape in ((e, d, f), (e, f, d)):
+            assert v.dtype == jnp.bfloat16, v
+
+
+# ---------------------------------------------------------------------------
+# the grouped matmul
+# ---------------------------------------------------------------------------
+SIZES = (0, 100, 0, 300, 112, 0)      # uneven, empty groups, 512 rows
+
+
+def _loop(lhs, rhs):
+    """Per group, in a loop: rows of group g times rhs[g]."""
+    out, start = [], 0
+    for g, n in enumerate(SIZES):
+        out.append(lhs[start:start + n] @ rhs[g])
+        start += n
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("kernels", ["interpreted", "ragged_dot"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_grouped_matmul_and_both_gradients(kernels, dtype, tol, monkeypatch):
+    """float32: accumulation order only, 1e-6 seen. bf16 operands,
+    float32 accumulation, one bf16 rounding of the output: 2^-8 = 4e-3 of
+    a value, against a float32 loop on the same bf16 inputs."""
+    if kernels == "interpreted":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        # 128-row tiles: groups start inside tiles and span several.
+        monkeypatch.setattr(gm, "_TILES", (128, 128, 128))
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(k[0], (512, 256), dtype)
+    rhs = jax.random.normal(k[1], (len(SIZES), 256, 384), dtype)
+    ct = jax.random.normal(k[2], (512, 384), dtype)
+    sizes = jnp.asarray(SIZES, jnp.int32)
+    f32 = [x.astype(jnp.float32) for x in (lhs, rhs, ct)]
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda a, b: gm.grouped_matmul(a, b, sizes), lhs, rhs)
+        dlhs, drhs = vjp(ct)
+        want, want_vjp = jax.vjp(_loop, f32[0], f32[1])
+        want_dlhs, want_drhs = want_vjp(f32[2])
+    assert out.dtype == dtype and drhs.dtype == dtype
+    assert _rel(out, want) < tol
+    assert _rel(dlhs, want_dlhs) < tol
+    assert _rel(drhs, want_drhs) < tol
+    assert float(jnp.abs(drhs[0]).max()) == 0.0       # an empty group
+
+
+# ---------------------------------------------------------------------------
+# names and counters
+# ---------------------------------------------------------------------------
+SCOPES = ("grouped_matmul_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
+          "moe_route", "moe_combine")
+
+
+def test_lowered_step_carries_the_scopes_and_kernels_and_no_top2_gating(
+        monkeypatch):
+    from ray_tpu.ops import attention
+
+    assert all(s in profiling.DEVICE_SCOPES for s in SCOPES)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = MoEConfig(vocab_size=512, d_model=128, n_heads=1, n_layers=1,
+                    n_experts=4, experts_per_token=2, d_expert=128,
+                    max_seq_len=256)
+    init_state, step = make_moe_train_step(cfg)
+    state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    text = step.trace(state, (tok, tok)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == set(
+        olmoe.MOSAIC_KERNELS)
+    for scope in SCOPES[:3]:
+        assert re.search(r'loc\("[^"]*/%s/pallas_call"' % scope, text), scope
+    for scope in SCOPES[3:] + ("loss", "optimizer_update"):
+        assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
+                         text), scope
+    # call frames by function name: the dropless layer's, none of GShard's
+    assert '"dropless_moe_layer"' in text
+    assert '"top2_gating"' not in text and '"moe_layer"' not in text
+
+
+def test_step_returns_the_router_counters_beside_the_loss():
+    cfg = MoEConfig.tiny()
+    init_state, step = make_moe_train_step(cfg, donate=False)
+    state = init_state(jax.random.PRNGKey(0))
+    state, m = step(state, _tokens(cfg, batch=4, seq=16))
+    assert set(m) == {"loss", "expert_tokens", "expert_load_max_over_mean",
+                      "router_z", "balance_loss"}
+    assert m["expert_tokens"].shape == (cfg.n_experts,)
+    assert int(m["expert_tokens"].sum()) == \
+        4 * 16 * cfg.experts_per_token * cfg.n_layers
+    assert float(m["expert_load_max_over_mean"]) >= 1.0
+    assert np.isfinite([float(m["loss"]), float(m["router_z"]),
+                        float(m["balance_loss"])]).all()
+
+
+def test_train_report_passes_the_counters_on(ray_start_shared, tmp_path):
+    def loop(config):
+        import jax as jax_
+
+        from ray_tpu.models import MoEConfig as Cfg
+        from ray_tpu.models import make_moe_train_step as make
+
+        cfg = Cfg.tiny()
+        init_state, step = make(cfg, donate=False)
+        toks = jax_.random.randint(jax_.random.PRNGKey(1), (2, 16), 0,
+                                   cfg.vocab_size)
+        _, metrics = step(init_state(jax_.random.PRNGKey(0)), (toks, toks))
+        train.report(jax_.device_get(metrics))
+
+    result = JaxTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="olmoe_counters",
+                             storage_path=str(tmp_path))).fit()
+    assert result.error is None, result.error
+    m = result.metrics
+    assert int(np.sum(m["expert_tokens"])) == 2 * 16 * 2 * 2
+    assert m["expert_load_max_over_mean"] >= 1.0 and m["loss"] > 0
