@@ -4,14 +4,27 @@ Net-new vs the reference codebase (SURVEY.md §2.4: no attention kernels
 in-tree — torch users bring their own): blockwise online-softmax (flash)
 attention written for the TPU memory hierarchy, forward AND backward:
 
-* Forward: Q tiles stream through VMEM; K/V are tiled over the innermost
-  grid dimension (never whole-sequence VMEM-resident, so sequence length
-  is bounded by HBM, not VMEM); fp32 accumulators persist in VMEM scratch
-  across the K sweep; the log-sum-exp per row is saved for the backward.
-* Backward: flash-2 style blockwise dQ (Q-outer, K-inner sweep) and
-  dK/dV (K-outer, Q-inner sweep) kernels that recompute attention
-  probabilities per block from the saved logsumexp — no (seq, seq)
-  matrix is ever materialized, so long-context *training* fits.
+* Forward: a grid program holds a block of Q rows and, where VMEM allows,
+  the head's whole K and V (fetched once a head: their block index does
+  not depend on the Q block); fp32 accumulators persist in VMEM scratch;
+  the log-sum-exp per row is saved for the backward.
+* Backward: flash-2 style dQ (a Q block against resident K/V) and dK/dV
+  (a K/V block against resident Q, dO) kernels that recompute attention
+  probabilities from the saved logsumexp — no (seq, seq) matrix is ever
+  materialized, so long-context *training* fits.
+* Causal calls work the triangle only (`attention_plan` says what runs):
+  blocks wholly under the diagonal are computed in unmasked tiles by a
+  loop whose trip count is the causal limit, the block the diagonal
+  crosses strip by strip up to the diagonal, and only the sub-block on
+  the diagonal builds a mask. Nothing above it is computed or fetched.
+  Where a whole sequence would not fit VMEM_BUDGET the swept side comes
+  in blocks on the grid and the same loops run inside.
+
+On the chip tool's v5e (PERF.md §6, PR 26; bf16, causal, a call's device
+time fwd / dQ / dK+dV): (192, 1024, 64) 0.71 / 0.58 / 0.81 ms where one
+masked 1024 x 1024 tile a head took 1.26 / 0.88 / 1.21; (64, 4096, 128)
+2.50 / 2.38 / 3.56 ms where blocks of 1,024 on the grid took 4.18 / 3.50 /
+3.86.
 
 Layout: [batch, heads, seq, head_dim]. The jax reference implementation
 serves non-TPU backends, sequences that are not a multiple of 128, and
@@ -25,6 +38,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import dataclasses
 import functools
 import math
 import os
@@ -127,26 +141,254 @@ def _per_shard(fn):
                          check_vma=False)
 
 
-def _pick_block(seq_len: int) -> int:
-    """Largest block that divides the sequence: fewer grid steps amortize
-    the per-step VPU/online-softmax overhead (measured in round 3 on a
-    v5e, not re-measured: 512 beats 128 by ~2.5x at S=2048, and 1024
-    beats 512 by ~10% at S=1024). Capped at 1024: the f32 score block
-    is block_q*block_k*4B of VMEM (4 MB at 1024²); the causal index
-    clamp assumes exact tiling."""
-    for b in (1024, 512, 256, 128):
-        if seq_len % b == 0:
-            return b
-    return seq_len
+# ---------------------------------------------------------------------------
+# The plan: block and sub-block sizes from the shape, and what they execute
+# ---------------------------------------------------------------------------
+# What one kernel instance may hold in VMEM, and the limit the kernels hand
+# the compiler (a v5e has 128 MiB of it; the compiler's default scope is 16).
+VMEM_BUDGET = 32 * 1024 * 1024
+_MAX_BLOCK = 1024             # a kernel's own block: its score tile is
+#                               block x block float32 (4 MiB at 1024;
+#                               2048 does not fit a v5e's VMEM in dK/dV)
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelPlan:
+    """One kernel's tiling and, for one head, what it executes.
+
+    A grid program owns `block` positions of its own side (Q rows in the
+    forward and dQ kernels, K/V rows in dK/dV) and sees `swept` positions
+    of the other side (the whole sequence where VMEM allows). It computes
+    the part of the swept side wholly on the visible side of its block in
+    unmasked tiles of block x block, in a loop whose trip count is the
+    causal limit, and the block the diagonal crosses as a triangle of
+    `sub` x `sub` sub-blocks: per strip of `sub` own positions one tile
+    that ends on the diagonal, of which only the last sub-block is
+    masked. `computed` + `skipped` = every sub-block of the
+    seq_len x seq_len square; `masked` of the computed ones build a mask."""
+    block: int
+    swept: int
+    sub: int
+    vmem_bytes: int
+    computed: int
+    masked: int
+    skipped: int
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionPlan:
+    seq_len: int
+    head_dim: int
+    causal: bool
+    vmem_budget: int
+    fwd: KernelPlan
+    dq: KernelPlan
+    dkv: KernelPlan
+
+    @property
+    def executed_share(self) -> float:
+        """Share of the score square the kernels compute (the same in all
+        three; 0.5 + sub / (2 * seq_len) when causal)."""
+        return self.fwd.computed / (self.fwd.computed + self.fwd.skipped)
+
+
+def _clip(x, hi):
+    if isinstance(x, int):
+        return max(0, min(x, hi))
+    return jnp.clip(x, 0, hi)
+
+
+def _visible_blocks(rel, block: int, swept: int, mirrored: bool):
+    """[lo, hi): the block-sized chunks of a swept block that a program's
+    own block sees whole, `rel` being its first own position less the swept
+    block's first. Forward and dQ (own = queries) see the keys before
+    their block; dK/dV (`mirrored`, own = keys) are seen by the queries
+    after theirs. Python ints give ints (the plan's counts), traced
+    scalars the kernels' loop bounds: one rule for both."""
+    if mirrored:
+        return _clip(rel + block, swept) // block, swept // block
+    return 0, _clip(rel, swept) // block
+
+
+def _on_diagonal(rel, swept: int):
+    """Whether the block the diagonal crosses lies in this swept block."""
+    return (rel >= 0) & (rel < swept)
+
+
+def _triangle(block: int, sub: int, mirrored: bool):
+    """The diagonal block, per strip of `sub` own positions: (strip, first,
+    width) of the other side's positions the strip sees, counted from the
+    block's first. The sub-block on the diagonal is the tile's last; its
+    first where `mirrored`."""
+    for strip in range(block // sub):
+        at = strip * sub
+        yield (strip, at, block - at) if mirrored else (strip, 0, at + sub)
+
+
+def _count(seq_len: int, block: int, swept: int, sub: int, causal: bool,
+           mirrored: bool):
+    """(computed, masked, skipped) sub-blocks of one head, by the rules
+    the kernel's loops follow."""
+    n = seq_len // sub
+    if not causal:
+        return n * n, 0, 0
+    computed = masked = 0
+    for own in range(0, seq_len, block):
+        for other in range(0, seq_len, swept):
+            lo, hi = _visible_blocks(own - other, block, swept, mirrored)
+            computed += (hi - lo) * (block // sub) ** 2
+            if _on_diagonal(own - other, swept):
+                for _, _, width in _triangle(block, sub, mirrored):
+                    computed += width // sub
+                    masked += 1
+    return computed, masked, n * n - computed
+
+
+def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
+                itemsize: int) -> int:
+    """An upper estimate of one program's VMEM: every operand and result
+    block twice (the pipeline's two buffers), float32 scratch, and three
+    float32 block x block tiles (scores, probabilities, their gradient)."""
+    row = 128 * 4                                   # a lane-padded f32 row
+    own, other = block * head_dim * itemsize, swept * head_dim * itemsize
+    tiles = 3 * block * block * 4
+    if kernel == "fwd":     # q | k, v -> o, lse; acc, m, l
+        return (2 * (own + 2 * other) + 2 * (own + block * row)
+                + block * (head_dim * 4 + 2 * row) + tiles)
+    if kernel == "dq":      # q, do, lse, delta | k, v -> dq; acc
+        return (2 * (2 * own + 2 * block * row + 2 * other) + 2 * own
+                + block * head_dim * 4 + tiles)
+    # dkv: k, v | q, do, lse, delta -> dk, dv; two accs
+    return (2 * (2 * own + 2 * other + 2 * swept * row) + 2 * 2 * own
+            + 2 * block * head_dim * 4 + tiles)
+
+
+def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
+                   dtype=jnp.bfloat16) -> AttentionPlan:
+    """The tiling `flash_attention` runs a [.., seq_len, head_dim] call at,
+    and the sub-blocks a head computes, masks and skips in each kernel.
+
+    The kernels take their sizes from this function and their loop bounds
+    and strips from the rules that count here (`_visible_blocks`,
+    `_triangle`), so what it reports is what runs. Sizes follow from the
+    shape alone. Sub-blocks are 128 where the sequence is one block, so
+    that the triangle is all the work and its offsets are static, and 256
+    where the sequence tiles by it and is longer (measured on a v5e at
+    head_dim 64 and 128: 128 is 8-9% faster at 1,024 positions, 256 2-3%
+    faster at 2,048 and 4,096; PERF.md §6, PR 26). A kernel's own block is
+    the largest multiple of the sub-block up to 1,024 that tiles the
+    sequence; the swept side (K and V in forward and dQ; Q, dO and their
+    rows in dK/dV) is resident whole where the estimate fits VMEM_BUDGET,
+    else in the largest blocks that do, swept on the grid with the same
+    loops inside."""
+    if seq_len < 128 or seq_len % 128:
+        raise ValueError(
+            f"the kernels tile sequences in multiples of 128, not {seq_len}")
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 128 if seq_len <= _MAX_BLOCK or seq_len % 256 else 256
+    sizes = [b for b in range(seq_len, 0, -sub) if seq_len % b == 0]
+
+    def plan(kernel: str) -> KernelPlan:
+        for swept in sizes:
+            for block in sizes:
+                if block > _MAX_BLOCK or swept % block:
+                    continue
+                need = _vmem_bytes(kernel, block, swept, head_dim, itemsize)
+                if need <= VMEM_BUDGET:
+                    return KernelPlan(block, swept, sub, need, *_count(
+                        seq_len, block, swept, sub, causal,
+                        kernel == "dkv"))
+        raise ValueError(
+            f"no {kernel} tiling of ({seq_len}, {head_dim}) fits "
+            f"{VMEM_BUDGET} bytes of VMEM")
+
+    return AttentionPlan(seq_len, head_dim, causal, VMEM_BUDGET,
+                         plan("fwd"), plan("dq"), plan("dkv"))
+
+
+def _scale_is_exact(sm_scale: float) -> bool:
+    """A power of two scales any operand without rounding, so it can move
+    off the scores onto a head_dim-wide operand (1/8 at head_dim 64; not
+    1/sqrt(128))."""
+    return math.frexp(sm_scale)[0] == 0.5
+
+
+def _block_id(axis: int, blocks: int):
+    """This program's index along a grid axis; a Python 0 where the axis
+    has one block, so what depends on nothing else is static."""
+    from jax.experimental import pallas as pl
+    return pl.program_id(axis) if blocks > 1 else 0
+
+
+def _ds(start, size: int, align: int):
+    """pl.ds of `size` at `start`, a multiple of `align` (said to the
+    compiler where `start` is traced)."""
+    from jax.experimental import pallas as pl
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, align)
+    return pl.ds(start, size)
+
+
+def _sweep(lo, hi, body):
+    """body(j) for j in [lo, hi); nothing at all where both are Python
+    ints and the range is empty."""
+    if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
+        return
+    jax.lax.fori_loop(lo, hi, lambda j, _: body(j), None)
+
+
+def _causal_work(step, rel, block: int, swept: int, sub: int,
+                 causal: bool, mirrored: bool):
+    """Everything one program computes, as calls of step(own rows, other
+    rows, on_diagonal): the visible chunks whole, then the block the
+    diagonal crosses strip by strip."""
+    from jax.experimental import pallas as pl
+
+    def whole(j):
+        step(_ds(0, block, block), _ds(j * block, block, block), False)
+    if not causal:
+        _sweep(0, swept // block, whole)
+        return
+    _sweep(*_visible_blocks(rel, block, swept, mirrored), whole)
+
+    @pl.when(_on_diagonal(rel, swept))
+    def _diagonal():
+        for strip, at, width in _triangle(block, sub, mirrored):
+            step(_ds(strip * sub, sub, sub), _ds(rel + at, width, sub), True)
+
+
+def _mask_diagonal(s, sub: int, mirrored: bool):
+    """Mask the sub-block of a strip's scores that the diagonal crosses:
+    the last `sub` columns, the first where the scores are transposed
+    (rows keys, columns queries). No other sub-block builds a mask."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    if mirrored:
+        parts = [jnp.where(cols >= rows, s[:, :sub], DEFAULT_MASK_VALUE),
+                 s[:, sub:]]
+    else:
+        parts = [s[:, :-sub],
+                 jnp.where(rows >= cols, s[:, -sub:], DEFAULT_MASK_VALUE)]
+    return jnp.concatenate([p for p in parts if p.shape[1]], axis=1)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    # Operands in their own dtype (bf16 runs the MXU at full rate),
+    # float32 accumulation.
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
-# Forward kernel: grid (bh, q_blocks, k_blocks); K innermost so fp32
-# accumulators ride VMEM scratch across the K sweep.
+# Forward kernel: grid (bh, q blocks, k blocks). Float32 accumulators ride
+# VMEM scratch across a program's tiles and the K blocks of the grid.
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
-                causal: bool, block_q: int, block_k: int,
-                save_lse: bool):
+                causal: bool, sub: int, grid: tuple, save_lse: bool):
     if save_lse:
         lse_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -154,9 +396,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         acc_scr, m_scr, l_scr = rest
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    block, swept = q_ref.shape[1], k_ref.shape[1]
+    qi, ki = _block_id(1, grid[1]), _block_id(2, grid[2])
+    fold = _scale_is_exact(sm_scale)
 
     @pl.when(ki == 0)
     def _init():
@@ -164,87 +406,85 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    # Causal: K blocks strictly right of the Q block's last row contribute
-    # nothing; skip their compute entirely (the grid still steps, the
-    # body is predicated off).
-    needed = (ki * block_k <= qi * block_q + block_q - 1) if causal \
-        else (ki >= 0)
-
-    @pl.when(needed)
-    def _compute():
-        # Dots run on the operands' native dtype (bf16 hits the MXU at
-        # full rate; pre-casting to f32 would quarter it) and accumulate
-        # in f32 via preferred_element_type.
-        q = q_ref[0]                                      # (bq, d)
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+    def step(rows, cols, on_diagonal):
+        q = q_ref[0, rows, :]
+        if fold:
+            q = q * sm_scale
+        s = _dot(q, k_ref[0, cols, :], _NT)
+        if not fold:
+            s = s * sm_scale
+        if on_diagonal:
+            s = _mask_diagonal(s, sub, False)
+        m_prev = m_scr[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_scr[rows, :] = alpha * l_scr[rows, :] + jnp.sum(
+            p, axis=-1, keepdims=True)
+        m_scr[rows, :] = m_new
+        v = v_ref[0, cols, :]
+        acc_scr[rows, :] = alpha * acc_scr[rows, :] + _dot(
+            p.astype(v.dtype), v, _NN)
 
-    @pl.when(ki == nk - 1)
+    _causal_work(step, qi * block - ki * swept, block, swept, sub, causal,
+                 False)
+
+    @pl.when(ki == grid[2] - 1)
     def _finalize():
         l = l_scr[...]
         # Fully-masked rows (can't happen causally, but keep it safe for
         # degenerate inputs): avoid 0/0.
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
         if save_lse:
-            lse = m_scr[...] + jnp.log(l_safe)      # (block_q,)
-            lse_ref[0] = jax.lax.broadcast_in_dim(
-                lse, (block_q, 128), (0,))
+            lse_ref[0] = jnp.broadcast_to(
+                m_scr[...] + jnp.log(l_safe), (block, 128))
 
 
-def _flash_forward(q, k, v, causal: bool, sm_scale: float,
-                   block_q: int, block_k: int, save_lse: bool = True):
+def _swept_index(causal: bool, block: int, swept: int, mirrored: bool):
+    """Index map of the swept side's blocks on a grid (bh, own, swept).
+    Causal blocks wholly past the diagonal are never used: clamp their
+    index to the last used one, so Mosaic sees an unchanged block and
+    skips the HBM->VMEM copy (the kernel's loops run zero times there)."""
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+    if mirrored:    # dK/dV: Q blocks from the one holding the K block on
+        return lambda b, i, j: (b, jnp.maximum(j, i * block // swept), 0)
+    return lambda b, i, j: (b, jnp.minimum(j, i * block // swept), 0)
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_BUDGET)
+
+
+# The kernel calls are jitted so that a model's layers, which call them at
+# one shape, trace and lower each kernel once a step and not once a layer
+# (12 x 3 kernels of 8 unrolled strips cost gpt2-small's step 4 s of
+# set-up otherwise). Only the pallas_call is inside: what XLA can fuse
+# with its neighbours (reshapes, delta) stays in the caller's program.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "plan", "save_lse"))
+def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
+                  plan: KernelPlan, save_lse: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    batch, heads, seq_len, head_dim = q.shape
-    bh = batch * heads
-    qf = q.reshape(bh, seq_len, head_dim)
-    kf = k.reshape(bh, seq_len, head_dim)
-    vf = v.reshape(bh, seq_len, head_dim)
-
-    block_q = min(block_q, seq_len)
-    block_k = min(block_k, seq_len)
-    grid = (bh, pl.cdiv(seq_len, block_q), pl.cdiv(seq_len, block_k))
-
+    bh, seq_len, head_dim = qf.shape
+    block, swept = plan.block, plan.swept
+    grid = (bh, seq_len // block, seq_len // swept)
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, save_lse=save_lse)
-    if causal:
-        # Upper-triangle K blocks are never used: clamp their index to
-        # the diagonal so Mosaic sees an unchanged block and skips the
-        # HBM->VMEM DMA entirely (the compute is pl.when-predicated off).
-        ratio = max(1, block_q // block_k)
-        def kv_index(b, i, j):
-            return (b, jnp.minimum(j, (i + 1) * ratio - 1)
-                    if ratio > 1 else jnp.minimum(j, i), 0)
-    else:
-        def kv_index(b, i, j):
-            return (b, j, 0)
-    out_specs = [
-        pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    out_shape = [jax.ShapeDtypeStruct(qf.shape, q.dtype)]
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, sub=plan.sub,
+        grid=grid, save_lse=save_lse)
+    q_spec = pl.BlockSpec((1, block, head_dim), lambda b, i, j: (b, i, 0),
+                          memory_space=pltpu.VMEM)
+    kv_spec = pl.BlockSpec((1, swept, head_dim),
+                           _swept_index(causal, block, swept, False),
+                           memory_space=pltpu.VMEM)
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct(qf.shape, qf.dtype)]
     if save_lse:
         # lse is lane-replicated to 128 so its block satisfies the TPU
         # (8, 128) tile rule (the layout jax's own TPU flash kernel uses
@@ -252,38 +492,39 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
         # pallas outputs are opaque to XLA DCE, so an unused lse would
         # still cost seq*128*4 bytes of HBM writes per (batch, head).
         out_specs.append(
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block, 128), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, seq_len, 128), jnp.float32))
     fwd = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, head_dim), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, head_dim), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, head_dim), kv_index,
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block, head_dim), jnp.float32),
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         interpret=_interpret(),
     )
     # A scope, never pallas_call(name=...): the scope reaches the name of
     # the HLO instruction, which is what a device trace shows, and leaves
     # kernel_name (_fwd_kernel) as it is (util/profiling.py DEVICE_SCOPES).
     with jax.named_scope("flash_attention_fwd"):
-        result = fwd(qf, kf, vf)
-    out = result[0].reshape(batch, heads, seq_len, head_dim)
+        return fwd(qf, kf, vf)
+
+
+def _flash_forward(q, k, v, causal: bool, sm_scale: float,
+                   plan: KernelPlan, save_lse: bool = True):
+    batch, heads, seq_len, head_dim = q.shape
+    flat = (batch * heads, seq_len, head_dim)
+    result = _forward_call(
+        q.reshape(flat), k.reshape(flat), v.reshape(flat), causal=causal,
+        sm_scale=sm_scale, plan=plan, save_lse=save_lse)
+    out = result[0].reshape(q.shape)
     # lse stays lane-replicated (.., seq, 128): the backward feeds it
     # straight back to the kernels, avoiding a slice + rebroadcast HBM
     # round trip per training step. It carries q's leading [batch, heads]
@@ -294,213 +535,163 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels (flash-2): recompute P per block from saved lse.
+# Backward kernels (flash-2): recompute P per tile from saved lse.
 # ---------------------------------------------------------------------------
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale: float, causal: bool,
-               block_q: int, block_k: int):
+               dq_scr, *, sm_scale: float, causal: bool, sub: int,
+               grid: tuple):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    block, swept = q_ref.shape[1], k_ref.shape[1]
+    qi, ki = _block_id(1, grid[1]), _block_id(2, grid[2])
+    fold = _scale_is_exact(sm_scale)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    needed = (ki * block_k <= qi * block_q + block_q - 1) if causal \
-        else (ki >= 0)
+    def step(rows, cols, on_diagonal):
+        q = q_ref[0, rows, :]
+        if fold:
+            q = q * sm_scale
+        k = k_ref[0, cols, :]
+        s = _dot(q, k, _NT)
+        if not fold:
+            s = s * sm_scale
+        if on_diagonal:
+            s = _mask_diagonal(s, sub, False)
+        p = jnp.exp(s - lse_ref[0, rows, :1])
+        dp = _dot(do_ref[0, rows, :], v_ref[0, cols, :], _NT)
+        ds = p * (dp - delta_ref[0, rows, :1])
+        if not fold:
+            ds = ds * sm_scale
+        dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0]
-        delta = delta_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse[:, None])                     # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (bq, bk)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _causal_work(step, qi * block - ki * swept, block, swept, sub, causal,
+                 False)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == grid[2] - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq = dq_scr[...]
+        if fold:
+            dq = dq * sm_scale
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                causal: bool, block_q: int, block_k: int):
+                causal: bool, sub: int, grid: tuple):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    block, swept = k_ref.shape[1], q_ref.shape[1]
+    ki, qi = _block_id(1, grid[1]), _block_id(2, grid[2])
+    fold = _scale_is_exact(sm_scale)
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # Causal: Q blocks whose last row is above the K block's first row
-    # see none of it.
-    needed = (qi * block_q + block_q - 1 >= ki * block_k) if causal \
-        else (qi >= 0)
+    def step(rows, cols, on_diagonal):
+        k = k_ref[0, rows, :]
+        if fold:
+            k = k * sm_scale
+        q = q_ref[0, cols, :]
+        do = do_ref[0, cols, :]
+        s_t = _dot(k, q, _NT)                     # rows keys, columns queries
+        if not fold:
+            s_t = s_t * sm_scale
+        if on_diagonal:
+            s_t = _mask_diagonal(s_t, sub, True)
+        p_t = jnp.exp(s_t - lse_ref[0, cols, 0][None, :])
+        dv_scr[rows, :] += _dot(p_t.astype(do.dtype), do, _NN)
+        dp_t = _dot(v_ref[0, rows, :], do, _NT)
+        ds_t = p_t * (dp_t - delta_ref[0, cols, 0][None, :])
+        if not fold:
+            ds_t = ds_t * sm_scale
+        dk_scr[rows, :] += _dot(ds_t.astype(q.dtype), q, _NN)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0]
-        delta = delta_ref[0, :, 0]
-        # s_T: (bk, bq)
-        s_t = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            s_t = jnp.where(q_pos >= k_pos, s_t, DEFAULT_MASK_VALUE)
-        p_t = jnp.exp(s_t - lse[None, :])                 # (bk, bq)
-        dv_scr[...] += jax.lax.dot_general(
-            p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (bk, bq)
-        ds_t = p_t * (dp_t - delta[None, :]) * sm_scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _causal_work(step, ki * block - qi * swept, block, swept, sub, causal,
+                 True)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(qi == grid[2] - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk = dk_scr[...]
+        if fold:
+            dk = dk * sm_scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
-                    block_q: int, block_k: int):
+def _backward_pallas(kernel, mirrored: bool, n_out: int, like, *,
+                     causal: bool, sm_scale: float, plan: KernelPlan):
+    """The pallas_call of a backward kernel on a grid (bh, own blocks,
+    swept blocks): Q, dO and their lse and delta rows on one side, K and V
+    on the other; `n_out` results shaped like `like` [bh, seq, head_dim]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    bh, seq_len, head_dim = like.shape
+    own = lambda b, i, j: (b, i, 0)  # noqa: E731
+    other = _swept_index(causal, plan.block, plan.swept, mirrored)
+    q_map, k_map = (other, own) if mirrored else (own, other)
+    q_len, k_len = (plan.swept, plan.block) if mirrored \
+        else (plan.block, plan.swept)
+    q_spec = pl.BlockSpec((1, q_len, head_dim), q_map,
+                          memory_space=pltpu.VMEM)
+    k_spec = pl.BlockSpec((1, k_len, head_dim), k_map,
+                          memory_space=pltpu.VMEM)
+    row_spec = pl.BlockSpec((1, q_len, 128), q_map, memory_space=pltpu.VMEM)
+    out_spec = k_spec if mirrored else q_spec
+    grid = (bh, seq_len // plan.block, seq_len // plan.swept)
+    return pl.pallas_call(
+        functools.partial(kernel, sm_scale=sm_scale, causal=causal,
+                          sub=plan.sub, grid=grid),
+        grid=grid,
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[out_spec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct(like.shape, like.dtype)] * n_out,
+        scratch_shapes=[pltpu.VMEM((plan.block, head_dim),
+                                   jnp.float32)] * n_out,
+        compiler_params=_compiler_params(),
+        interpret=_interpret(),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "plan"))
+def _dq_call(*operands, **static):
+    with jax.named_scope("flash_attention_dq"):
+        return _backward_pallas(_dq_kernel, False, 1, operands[0],
+                                **static)(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "plan"))
+def _dkv_call(*operands, **static):
+    with jax.named_scope("flash_attention_dkv"):
+        return _backward_pallas(_dkv_kernel, True, 2, operands[0],
+                                **static)(*operands)
+
+
+def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
+                    dq_plan: KernelPlan, dkv_plan: KernelPlan):
     batch, heads, seq_len, head_dim = q.shape
     bh = batch * heads
-    block_q = min(block_q, seq_len)
-    block_k = min(block_k, seq_len)
-    qf = q.reshape(bh, seq_len, head_dim)
-    kf = k.reshape(bh, seq_len, head_dim)
-    vf = v.reshape(bh, seq_len, head_dim)
-    dof = g.reshape(bh, seq_len, head_dim)
-    lsef = lse.reshape(bh, seq_len, 128)  # lane-replicated by forward
+    flat = (bh, seq_len, head_dim)
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA.
     delta = jnp.broadcast_to(
         jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                 axis=-1).reshape(bh, seq_len)[:, :, None],
         (bh, seq_len, 128))
-
-    # Causal index clamps: blocks that the pl.when predicate skips are
-    # mapped to the previously-fetched block so Mosaic elides their DMA.
-    kq_ratio = max(1, block_q // block_k)
-    qk_ratio = max(1, block_k // block_q)
-    if causal:
-        def dq_kv_index(b, i, j):
-            return (b, jnp.minimum(j, (i + 1) * kq_ratio - 1), 0)
-
-        def dkv_q_index(b, i, j):
-            return (b, jnp.maximum(j, i * qk_ratio), 0)
-    else:
-        def dq_kv_index(b, i, j):
-            return (b, j, 0)
-
-        def dkv_q_index(b, i, j):
-            return (b, j, 0)
-    q_spec = pl.BlockSpec((1, block_q, head_dim),
-                          lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    kq_spec = pl.BlockSpec((1, block_k, head_dim), dq_kv_index,
-                           memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0),
-                            memory_space=pltpu.VMEM)
-
-
-    dq_call = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, pl.cdiv(seq_len, block_q), pl.cdiv(seq_len, block_k)),
-        in_specs=[q_spec, kq_spec, kq_spec, q_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, block_q, head_dim),
-                               lambda b, i, j: (b, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )
-    with jax.named_scope("flash_attention_dq"):
-        dq = dq_call(qf, kf, vf, dof, lsef, delta)
-
+    operands = (q.reshape(flat), k.reshape(flat), v.reshape(flat),
+                g.reshape(flat),
+                lse.reshape(bh, seq_len, 128),  # lane-replicated by forward
+                delta)
+    dq, = _dq_call(*operands, causal=causal, sm_scale=sm_scale,
+                   plan=dq_plan)
     # dK/dV: K-outer, Q-inner sweep.
-    k_spec = pl.BlockSpec((1, block_k, head_dim),
-                          lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    qk_spec = pl.BlockSpec((1, block_q, head_dim), dkv_q_index,
-                           memory_space=pltpu.VMEM)
-
-    def dkv_row_index(b, i, j):
-        bi, ji, _ = dkv_q_index(b, i, j)
-        return (bi, ji, 0)
-    row_j_spec = pl.BlockSpec((1, block_q, 128), dkv_row_index,
-                              memory_space=pltpu.VMEM)
-    dkv_call = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, pl.cdiv(seq_len, block_k), pl.cdiv(seq_len, block_q)),
-        in_specs=[qk_spec, k_spec, k_spec, qk_spec, row_j_spec,
-                  row_j_spec],  # full-row lse/delta; sliced by q block
-        out_specs=[
-            pl.BlockSpec((1, block_k, head_dim), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, head_dim), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(kf.shape, k.dtype),
-            jax.ShapeDtypeStruct(vf.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )
-    with jax.named_scope("flash_attention_dkv"):
-        dk, dv = dkv_call(qf, kf, vf, dof, lsef, delta)
-
-    shape = (batch, heads, seq_len, head_dim)
-    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape))
+    dk, dv = _dkv_call(*operands, causal=causal, sm_scale=sm_scale,
+                       plan=dkv_plan)
+    return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -532,10 +723,10 @@ def _flash_attention_fwd_impl(q, k, v, causal, sm_scale,
     scale = _scale_of(q, sm_scale)
     seq_len = q.shape[-2]
     if _kernel_ok(seq_len):
-        block = _pick_block(seq_len)
+        plan = attention_plan(seq_len, q.shape[-1], causal, q.dtype)
         out, lse = _per_shard(functools.partial(
             _flash_forward, causal=causal, sm_scale=scale,
-            block_q=block, block_k=block, save_lse=save_lse))(q, k, v)
+            plan=plan.fwd, save_lse=save_lse))(q, k, v)
         return out, (out, lse)
     return mha_reference(q, k, v, causal, scale), (None, None)
 
@@ -555,10 +746,10 @@ def _flash_bwd(causal, sm_scale, residuals, g):
             lambda q_, k_, v_: mha_reference(q_, k_, v_, causal, sm_scale),
             q, k, v)
         return vjp(g)
-    block = _pick_block(q.shape[-2])
+    plan = attention_plan(q.shape[-2], q.shape[-1], causal, q.dtype)
     return _per_shard(functools.partial(
         _flash_backward, causal=causal, sm_scale=scale,
-        block_q=block, block_k=block))(q, k, v, o, lse, g)
+        dq_plan=plan.dq, dkv_plan=plan.dkv))(q, k, v, o, lse, g)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

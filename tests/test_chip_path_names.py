@@ -8,6 +8,7 @@ and the LLM reply's `timing` is additive. CPU backend: the host plane is
 what is checked here, the device plane only on the chip (PERF.md)."""
 
 import asyncio
+import dataclasses
 import glob
 import os
 import re
@@ -193,21 +194,28 @@ def test_annotate_keeps_a_process_off_jax():
                    timeout=60)
 
 
-def test_lowered_train_step_carries_scopes_and_kernel_names(monkeypatch):
+@pytest.mark.parametrize("cfg,batch", [
+    (GPTConfig(vocab_size=512, d_model=128, n_heads=2, n_layers=1,
+               d_ff=256, max_seq_len=256, remat=False), 2),
+    # gpt2s-train-1chip's step: 16 sequences of 1,024, 12 heads of 64.
+    (dataclasses.replace(GPTConfig.gpt2_small(), remat=False), 16),
+], ids=["tiny", "gpt2-small"])
+def test_lowered_train_step_carries_scopes_and_kernel_names(monkeypatch,
+                                                            cfg, batch):
     from ray_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    cfg = GPTConfig(vocab_size=512, d_model=128, n_heads=2, n_layers=1,
-                    d_ff=256, max_seq_len=256, remat=False)
     init_state, step = make_train_step(cfg)
     state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0)))
-    tok = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    tok = jax.ShapeDtypeStruct((batch, cfg.max_seq_len), jnp.int32)
     text = step.trace(state, (tok, tok)).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
         "_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
+    # The kernels' wrappers are jitted (one trace a step, not one a
+    # layer), so their locations start at the scope.
     for kernel in ("fwd", "dq", "dkv"):
-        assert re.search(r'loc\("[^"]*/flash_attention_%s/pallas_call"'
+        assert re.search(r'loc\("(?:[^"]*/)?flash_attention_%s/pallas_call"'
                          % kernel, text), kernel
     for scope in ("layers", "loss", "optimizer_update"):
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,

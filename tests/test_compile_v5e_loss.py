@@ -2,9 +2,10 @@
 data-parallel gpt2-small train step of the `gpt2s-train-dp4` cell keeps
 no chunk's logits, gathers no activations, and fits a chip at 16
 sequences a chip (global B=64; 16.62 GB of 15.75 before ops/loss.py took
-the gradient in the forward scan; PERF.md has the figure now). The
-topology is described inside a fixture, and this is the only file under
-tests/ that does (see the on-chip-measurement guide)."""
+the gradient in the forward scan; PERF.md has the figure now), and the
+three attention kernels compile at the plans `attention_plan` gives the
+cells' shapes. The topology is described inside a fixture (see the
+on-chip-measurement guide)."""
 
 import dataclasses
 import os
@@ -95,3 +96,37 @@ def test_dp4_step_fits_a_chip_at_16_sequences_a_chip(compile_dp4,
     record_property("dp4_b64_bytes_per_chip", total)
     print(f"dp4 global B=64: {total / 1e9:.2f} GB a chip")
     assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("bh,seq_len,head_dim", [
+    (192, 1024, 64),        # gpt2s-train-1chip: 16 sequences x 12 heads
+    (64, 4096, 128),        # olmoe-train-1chip: 4 sequences x 16 heads
+    (8, 384, 64),           # three sub-blocks of 128 a side
+    (8, 128, 64),           # one sub-block
+])
+def test_attention_kernels_compile_at_the_cells_shapes(topo, bh, seq_len,
+                                                       head_dim):
+    """The three kernels at the plan attention_plan gives these shapes:
+    Mosaic takes their slices, loops and VMEM as the chip's compiler
+    would (interpret mode refuses none of that)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import attention
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    plan = attention.attention_plan(seq_len, head_dim, True, jnp.bfloat16)
+    scale = head_dim ** -0.5
+    x = jax.ShapeDtypeStruct((1, bh, seq_len, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, bh, seq_len, 128), jnp.float32,
+                               sharding=one_chip)
+    fwd = jax.jit(lambda q, k, v: attention._flash_forward(
+        q, k, v, True, scale, plan.fwd)).lower(x, x, x)
+    bwd = jax.jit(lambda q, k, v, o, l, g: attention._flash_backward(
+        q, k, v, o, l, g, True, scale, plan.dq, plan.dkv)).lower(
+            x, x, x, x, lse, x)
+    for lowered, kernels in ((fwd, 1), (bwd, 2)):
+        text = lowered.compile().as_text()
+        assert text.count("tpu_custom_call") == kernels

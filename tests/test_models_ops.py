@@ -173,6 +173,65 @@ class TestGraftEntry:
         __graft_entry__.dryrun_multichip(8)
 
 
+class TestAttentionPlan:
+    """attention_plan: the sizes the kernels run at and the sub-blocks a
+    head computes, masks and skips (the kernels' loop bounds come from the
+    rule that counts them)."""
+
+    @staticmethod
+    def _brute(seq_len, sub):
+        """(computed, masked, skipped) by looking at every position."""
+        vis = np.tril(np.ones((seq_len, seq_len), bool))
+        n = seq_len // sub
+        tiles = vis.reshape(n, sub, n, sub).transpose(0, 2, 1, 3)
+        some, every = tiles.any((2, 3)), tiles.all((2, 3))
+        return (int(some.sum()), int((some & ~every).sum()),
+                int((~some).sum()))
+
+    @pytest.mark.parametrize("seq_len,head_dim,share", [
+        (1024, 64, 0.625), (2048, 64, 0.5625), (4096, 128, 0.532)])
+    def test_causal_plan_works_the_triangle(self, seq_len, head_dim, share):
+        from ray_tpu.ops.attention import attention_plan
+        plan = attention_plan(seq_len, head_dim, True, jnp.bfloat16)
+        assert plan.executed_share <= share
+        for kernel in (plan.fwd, plan.dq, plan.dkv):
+            n = seq_len // kernel.sub
+            assert (kernel.computed, kernel.masked, kernel.skipped) == \
+                self._brute(seq_len, kernel.sub)
+            assert kernel.computed + kernel.skipped == n * n
+            assert kernel.masked == n          # the diagonal's own
+
+    @pytest.mark.parametrize("seq_len,head_dim", [(1024, 64), (4096, 128)])
+    def test_non_causal_plan_computes_all_and_masks_none(self, seq_len,
+                                                         head_dim):
+        from ray_tpu.ops.attention import attention_plan
+        plan = attention_plan(seq_len, head_dim, False, jnp.bfloat16)
+        assert plan.executed_share == 1.0
+        for kernel in (plan.fwd, plan.dq, plan.dkv):
+            assert kernel.computed == (seq_len // kernel.sub) ** 2
+            assert kernel.masked == kernel.skipped == 0
+
+    @pytest.mark.parametrize("head_dim,dtype", [
+        (64, jnp.bfloat16), (128, jnp.bfloat16), (128, jnp.float32)])
+    def test_every_tileable_sequence_gets_a_legal_plan(self, head_dim,
+                                                       dtype):
+        from ray_tpu.ops.attention import VMEM_BUDGET, attention_plan
+        for seq_len in range(128, 8192 + 1, 128):
+            plan = attention_plan(seq_len, head_dim, True, dtype)
+            assert plan.vmem_budget == VMEM_BUDGET
+            for kernel in (plan.fwd, plan.dq, plan.dkv):
+                assert kernel.sub % 128 == 0, (seq_len, kernel)
+                assert kernel.block % kernel.sub == 0, (seq_len, kernel)
+                assert kernel.swept % kernel.block == 0, (seq_len, kernel)
+                assert seq_len % kernel.swept == 0, (seq_len, kernel)
+                assert kernel.vmem_bytes <= VMEM_BUDGET, (seq_len, kernel)
+
+    def test_untileable_sequence_is_refused(self):
+        from ray_tpu.ops.attention import attention_plan
+        with pytest.raises(ValueError, match="multiples of 128"):
+            attention_plan(197, 64, False)
+
+
 class TestFlashKernelInterpret:
     """The actual Pallas kernels (fwd + blockwise flash-2 backward) in
     interpreter mode — the SURVEY §4 CPU-mirror of the on-TPU path."""
@@ -181,10 +240,37 @@ class TestFlashKernelInterpret:
     def _interpret(self, monkeypatch):
         monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
 
+    # (shape, VMEM budget or None for the module's own). The plans: two
+    # strips of 128 in one block; one block of 1,024 in 8 strips, 36
+    # sub-blocks computed and 8 masked; 4 strips at head_dim 128, whose
+    # scale is not a power of two; 3 strips; and, under a budget that
+    # whole sequences do not fit, the swept side in blocks on the grid
+    # (forward and dQ 128 against 512, dK/dV 128 against 256) round the
+    # same loops.
+    SHAPES = [((1, 2, 256, 64), None), ((1, 3, 1024, 64), None),
+              ((1, 3, 512, 128), None), ((3, 1, 384, 64), None),
+              ((1, 3, 1024, 64), 1_500_000)]
+    shapes = pytest.mark.parametrize(
+        "shape,budget", SHAPES,
+        ids=["256x64", "1024x64", "512x128", "384x64", "1024x64-grid"])
+
+    @staticmethod
+    def _budget(monkeypatch, shape, budget):
+        from ray_tpu.ops import attention
+        if budget is not None:
+            monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
+        plan = attention.attention_plan(*shape[-2:], True, jnp.float32)
+        on_grid = plan.fwd.swept < shape[-2] and \
+            plan.dkv.swept < shape[-2]
+        assert on_grid == (budget is not None), plan
+
+    @shapes
     @pytest.mark.parametrize("causal", [True, False])
-    def test_kernel_fwd_matches_reference(self, causal):
+    def test_kernel_fwd_matches_reference(self, causal, shape, budget,
+                                          monkeypatch):
+        self._budget(monkeypatch, shape, budget)
         ks = jax.random.split(jax.random.PRNGKey(3), 3)
-        q, k, v = (jax.random.normal(kk, (1, 2, 256, 64), jnp.float32)
+        q, k, v = (jax.random.normal(kk, shape, jnp.float32)
                    for kk in ks)
         out = flash_attention(q, k, v, causal, None)
         ref = mha_reference(q, k, v, causal)
@@ -193,10 +279,13 @@ class TestFlashKernelInterpret:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-2, rtol=2e-2)
 
+    @shapes
     @pytest.mark.parametrize("causal", [True, False])
-    def test_kernel_bwd_matches_reference(self, causal):
+    def test_kernel_bwd_matches_reference(self, causal, shape, budget,
+                                          monkeypatch):
+        self._budget(monkeypatch, shape, budget)
         ks = jax.random.split(jax.random.PRNGKey(4), 3)
-        q, k, v = (jax.random.normal(kk, (1, 2, 256, 64), jnp.float32)
+        q, k, v = (jax.random.normal(kk, shape, jnp.float32)
                    for kk in ks)
 
         def loss_k(q, k, v):
@@ -212,6 +301,47 @@ class TestFlashKernelInterpret:
             np.testing.assert_allclose(
                 np.asarray(a) / scale, np.asarray(b) / scale,
                 atol=6e-3, rtol=6e-3)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("block,swept", [(256, 512), (512, 512)])
+    def test_kernels_with_the_swept_side_on_the_grid(self, causal, block,
+                                                     swept):
+        """Blocks of several strips against a swept side that is not
+        resident: the loops' bounds and the triangle's offsets are traced
+        values there, and accumulators carry across grid steps."""
+        from ray_tpu.ops import attention
+        ks = jax.random.split(jax.random.PRNGKey(7), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, 3, 1024, 64), jnp.float32)
+                      for kk in ks)
+        plan = attention.KernelPlan(block, swept, 128, 0, 0, 0, 0)
+        out, lse = attention._flash_forward(q, k, v, causal, 0.125, plan)
+        ref, vjp = jax.vjp(
+            lambda *a: mha_reference(*a, causal), q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-2, rtol=2e-2)
+        grads = attention._flash_backward(q, k, v, out, lse, g, causal,
+                                          0.125, plan, plan)
+        for a, b in zip(grads, vjp(g)):
+            scale = max(1.0, float(jnp.abs(b).max()))
+            np.testing.assert_allclose(
+                np.asarray(a) / scale, np.asarray(b) / scale,
+                atol=6e-3, rtol=6e-3)
+
+    @pytest.mark.parametrize("t", [256, 257, 255, 384])
+    def test_kernel_causality(self, t):
+        """Keys and values from position t on reach no output before t,
+        with t on a sub-block boundary (256 and 384, at sub-blocks of 128)
+        and one position to either side of it."""
+        ks = jax.random.split(jax.random.PRNGKey(6), 3)
+        q, k, v = (jax.random.normal(kk, (1, 2, 512, 64), jnp.float32)
+                   for kk in ks)
+        out1 = flash_attention(q, k, v, True, None)
+        out2 = flash_attention(q, k.at[:, :, t:].set(99.0),
+                               v.at[:, :, t:].set(-99.0), True, None)
+        np.testing.assert_array_equal(np.asarray(out1[:, :, :t]),
+                                      np.asarray(out2[:, :, :t]))
+        assert not np.allclose(np.asarray(out1[:, :, t]),
+                               np.asarray(out2[:, :, t]))
 
     def test_kernels_run_per_shard_under_a_mesh(self):
         """A sharded train step runs the kernels under shard_map over the
