@@ -1,4 +1,5 @@
-"""Autoregressive generation with a KV cache (GPT family).
+"""Autoregressive generation with a KV cache, for any config with a
+`decoder()` (models.gpt, models.llama, models.moe).
 
 Parity role: the reference serves LLMs by hosting external engines
 (vLLM etc.) on its actors; here the decode path is native — a
@@ -7,7 +8,7 @@ prompt bucket, one for the single-token decode step), rotary offsets per
 position, fp32 logits. The serving layer (llm.serving) drives these
 jitted steps and streams tokens through Serve.
 
-Cache layout: per layer {"k"|"v": [batch, heads, max_len, head_dim]}.
+The cache's layout is models.decoder's.
 """
 
 from __future__ import annotations
@@ -18,111 +19,26 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import DEFAULT_MASK_VALUE
-from ..ops.layers import rms_norm, rope
-from .gpt import GPTConfig
+from .decoder import decoder_hidden, empty_cache
 
 
-def init_cache(cfg: GPTConfig, batch: int, max_len: int) -> List[Dict]:
-    h, hd = cfg.n_heads, cfg.head_dim
-    return [
-        {"k": jnp.zeros((batch, h, max_len, hd), cfg.dtype),
-         "v": jnp.zeros((batch, h, max_len, hd), cfg.dtype)}
-        for _ in range(cfg.n_layers)
-    ]
-
-
-def _cached_block(x, layer, cache_layer, start_pos, cfg: GPTConfig):
-    """One transformer block reading/writing the KV cache.
-
-    x: [b, L, d]. `start_pos` is the absolute offset of x's positions —
-    a scalar (all rows aligned: prefill / single-stream decode) or a
-    [b] vector (continuous batching: every row decodes at its own
-    position). One implementation serves both so the attention formulas
-    can't diverge; only the cache write and causal mask specialize on
-    the index shape. Returns (x_out, new_cache_layer).
-    """
-    b, L, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    max_len = cache_layer["k"].shape[-2]
-    sp = jnp.asarray(start_pos)
-    per_row = sp.ndim == 1
-
-    y = rms_norm(x, layer["ln1"])
-    qkv = jnp.einsum("bsd,de->bse", y, layer["wqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, L, h, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(b, L, h, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(b, L, h, hd).transpose(0, 2, 1, 3)
-    # Rotary embeddings at absolute (possibly traced) positions —
-    # the same rope() the training forward uses ([L] or [b, L]).
-    if per_row:
-        positions = sp[:, None] + jnp.arange(L)[None]
-    else:
-        positions = sp + jnp.arange(L)
-    q = rope(q, positions=positions)
-    k = rope(k, positions=positions)
-
-    if per_row:
-        rows = jnp.arange(b)[:, None]                    # (b, 1)
-        cols = sp[:, None] + jnp.arange(L)[None]         # (b, L)
-        # Advanced indexing on axes 0 and 2 moves the index dims to
-        # the front: value shape (b, L, h, hd).
-        k_cache = cache_layer["k"].at[rows, :, cols, :].set(
-            k.transpose(0, 2, 1, 3).astype(cache_layer["k"].dtype))
-        v_cache = cache_layer["v"].at[rows, :, cols, :].set(
-            v.transpose(0, 2, 1, 3).astype(cache_layer["v"].dtype))
-    else:
-        k_cache = jax.lax.dynamic_update_slice(
-            cache_layer["k"], k.astype(cache_layer["k"].dtype),
-            (0, 0, sp, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            cache_layer["v"], v.astype(cache_layer["v"].dtype),
-            (0, 0, sp, 0))
-
-    scale = hd ** -0.5
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * scale
-    q_iota = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 1)
-    if per_row:
-        q_pos = sp[:, None, None] + q_iota[None]         # (b, L, max)
-        mask = (k_pos[None] <= q_pos)[:, None]           # (b,1,L,max)
-    else:
-        mask = (k_pos <= sp + q_iota)[None, None]        # (1,1,L,max)
-    s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1)
-    attn = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v_cache.dtype),
-                      v_cache)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, L, d)
-    x = x + jnp.einsum("bsd,de->bse", attn, layer["wo"])
-    y = rms_norm(x, layer["ln2"])
-    hidden = jax.nn.gelu(jnp.einsum("bsd,df->bsf", y, layer["w1"]))
-    x = x + jnp.einsum("bsf,fd->bsd", hidden, layer["w2"])
-    return x, {"k": k_cache, "v": v_cache}
+def init_cache(cfg, batch: int, max_len: int) -> List[Dict]:
+    return empty_cache(cfg.decoder(), cfg.n_layers, batch, max_len, cfg.dtype)
 
 
 def cached_forward(params: Dict, tokens, cache: List[Dict],
-                   start_pos, cfg: GPTConfig
-                   ) -> Tuple[jnp.ndarray, List[Dict]]:
-    """Forward over `tokens` [b, L] at absolute offset start_pos using
-    (and updating) the cache. Returns (logits [b, L, vocab] fp32,
-    new_cache)."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    new_cache = []
-    for layer, cache_layer in zip(params["layers"], cache):
-        x, cl = _cached_block(x, layer, cache_layer, start_pos, cfg)
-        new_cache.append(cl)
-    x = rms_norm(x, params["lnf"])
-    head = params.get("head")
-    if head is None:
-        head = params["embed"].T
+                   start_pos, cfg) -> Tuple[jnp.ndarray, List[Dict]]:
+    """Forward over `tokens` [b, L] at absolute offset start_pos (a
+    scalar, or one offset a row) using (and updating) the cache. Returns
+    (logits [b, L, vocab] fp32, new_cache)."""
+    x, head, _, new_cache = decoder_hidden(
+        params, tokens, cfg.decoder(), cache, start_pos)
     return (jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32),
             new_cache)
 
 
 @functools.lru_cache(maxsize=8)
-def make_generate_fns(cfg: GPTConfig, max_len: int):
+def make_generate_fns(cfg, max_len: int):
     """(prefill, decode_step) jitted with donated caches, cached per
     (cfg, max_len) so repeated serving requests reuse the XLA compiles
     (the lru key is why max_len is a parameter — caches passed in must
@@ -149,7 +65,7 @@ def make_generate_fns(cfg: GPTConfig, max_len: int):
 
 
 @functools.lru_cache(maxsize=8)
-def make_continuous_fns(cfg: GPTConfig, max_len: int, batch: int):
+def make_continuous_fns(cfg, max_len: int, batch: int):
     """(insert_prefill, decode_batch) for CONTINUOUS BATCHING: one
     shared [batch, ...] KV cache whose slots belong to independent
     requests. A new request prefills into a free slot while the other
@@ -209,7 +125,7 @@ def sample_token(logits, key, temperature: float = 0.0):
     return jax.random.categorical(key, logits / temperature, axis=-1)
 
 
-def generate(params: Dict, cfg: GPTConfig, prompt,
+def generate(params: Dict, cfg, prompt,
              max_new_tokens: int = 32, temperature: float = 0.0,
              max_len: Optional[int] = None, seed: int = 0,
              stop_token: Optional[int] = None):

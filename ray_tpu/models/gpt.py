@@ -15,15 +15,13 @@ pytree of logical axis tuples.
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import flash_attention
-from ..ops.layers import rms_norm, rope
 from ..ops.loss import cross_entropy
+from .decoder import Decoder, decoder_hidden, gelu_mlp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +39,19 @@ class GPTConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def decoder(self) -> Decoder:
+        """MHA from a fused `wqkv`, rotary positions and RMSNorm at the
+        defaults of ops.layers, a GELU MLP."""
+        # dots-saveable: keep matmul outputs, recompute elementwise (full
+        # recompute only pays off when memory is the binding constraint;
+        # callers can still pass remat=False to skip remat). Which policy
+        # is faster is not measured on this chip (ROADMAP A5(b)).
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return Decoder(
+            n_heads=self.n_heads, n_kv_heads=self.n_heads,
+            head_dim=self.head_dim, mlp=gelu_mlp,
+            remat=policy if self.remat else None)
 
     @classmethod
     def gpt2_small(cls) -> "GPTConfig":
@@ -115,52 +126,9 @@ def gpt_param_axes(cfg: GPTConfig) -> Dict:
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _block(x, layer, cfg: GPTConfig):
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    # Attention
-    y = rms_norm(x, layer["ln1"])
-    qkv = jnp.einsum("bsd,de->bse", y, layer["wqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rope(q.reshape(b, s, h, hd).transpose(0, 2, 1, 3))
-    k = rope(k.reshape(b, s, h, hd).transpose(0, 2, 1, 3))
-    v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    attn = flash_attention(q, k, v, True, None)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + jnp.einsum("bsd,de->bse", attn, layer["wo"])
-    # MLP (gelu; fused into the matmuls by XLA)
-    y = rms_norm(x, layer["ln2"])
-    hminner = jax.nn.gelu(jnp.einsum("bsd,df->bsf", y, layer["w1"]))
-    x = x + jnp.einsum("bsf,fd->bsd", hminner, layer["w2"])
-    return x
-
-
-def _backbone(params: Dict, tokens, cfg: GPTConfig):
-    """Embedding + blocks + final norm: [b, s] -> [b, s, d] and the
-    (possibly tied) output head."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    block = functools.partial(_block, cfg=cfg)
-    if cfg.remat:
-        # dots-saveable: keep matmul outputs, recompute elementwise —
-        # measured ~10% faster than nothing_saveable on v5e at the same
-        # fit (full recompute only pays off when memory is the binding
-        # constraint; callers can still pass remat=False to skip remat).
-        block = jax.checkpoint(
-            block,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    with jax.named_scope("layers"):
-        for layer in params["layers"]:
-            x = block(x, layer)
-    x = rms_norm(x, params["lnf"])
-    head = params.get("head")
-    if head is None:
-        head = params["embed"].T
-    return x, head
-
-
 def gpt_forward(params: Dict, tokens, cfg: GPTConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] (fp32)."""
-    x, head = _backbone(params, tokens, cfg)
+    x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
     return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
 
 
@@ -172,7 +140,7 @@ def gpt_loss(params: Dict, batch: Tuple, cfg: GPTConfig):
     formulation (12.3 GB at B=64/S=1024/V=50k — it OOMs a v5e chip) are
     held neither in the forward nor for the backward pass."""
     tokens, targets = batch
-    x, head = _backbone(params, tokens, cfg)
+    x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
     return cross_entropy(x, head, targets)
 
 

@@ -13,15 +13,13 @@ parallel.partition, bf16 matmuls / fp32 norms, per-block remat.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import flash_attention
-from ..ops.layers import rms_norm, rope
 from ..ops.loss import cross_entropy
+from .decoder import Decoder, decoder_hidden, swiglu_mlp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,21 +47,20 @@ class LlamaConfig:
         assert self.n_heads % self.n_kv_heads == 0, \
             "n_heads must be a multiple of n_kv_heads"
 
+    def decoder(self) -> Decoder:
+        """GQA from `wq` + `wkv` (n_kv_heads heads of k and of v), rotary
+        positions at `rope_base`, RMSNorm at ops.layers' eps, a SwiGLU MLP."""
+        return Decoder(
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim, mlp=swiglu_mlp,
+            remat=(jax.checkpoint_policies.nothing_saveable
+                   if self.remat else None),
+            rope_base=self.rope_base)
+
     @classmethod
     def tiny(cls) -> "LlamaConfig":
         return cls(vocab_size=512, d_model=64, n_heads=4, n_kv_heads=2,
                    n_layers=2, d_ff=96, max_seq_len=128)
-
-    @classmethod
-    def tpu_bench(cls) -> "LlamaConfig":
-        """Single-chip MFU-bench shape: head_dim 128 (MXU-native lane
-        width — GPT-2's head_dim 64 half-fills the systolic array),
-        4:1 GQA, S=2048,
-        ~250M params so optimizer+activations fit v5e HBM without
-        remat."""
-        return cls(vocab_size=32000, d_model=1024, n_heads=8,
-                   n_kv_heads=2, n_layers=16, d_ff=2816,
-                   max_seq_len=2048, remat=False)
 
 
 def _layer_init(key, cfg: LlamaConfig) -> Dict:
@@ -122,56 +119,16 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict:
     }
 
 
-def _block(x, layer, cfg: LlamaConfig):
-    b, s, d = x.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    y = rms_norm(x, layer["ln1"])
-    q = jnp.einsum("bsd,de->bse", y, layer["wq"])
-    kv = jnp.einsum("bsd,de->bse", y, layer["wkv"])
-    k, v = jnp.split(kv, 2, axis=-1)
-    q = rope(q.reshape(b, s, h, hd).transpose(0, 2, 1, 3),
-             base=cfg.rope_base)
-    k = rope(k.reshape(b, s, kvh, hd).transpose(0, 2, 1, 3),
-             base=cfg.rope_base)
-    v = v.reshape(b, s, kvh, hd).transpose(0, 2, 1, 3)
-    # GQA: replicate each kv head across its query group. XLA lowers the
-    # repeat to a broadcast feeding the attention matmuls — no HBM copy of
-    # the expanded kv is materialized outside the kernel.
-    k = jnp.repeat(k, cfg.group_size, axis=1)
-    v = jnp.repeat(v, cfg.group_size, axis=1)
-    attn = flash_attention(q, k, v, True, None)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + jnp.einsum("bsd,de->bse", attn, layer["wo"])
-    y = rms_norm(x, layer["ln2"])
-    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", y, layer["w_gate"]))
-    up = jnp.einsum("bsd,df->bsf", y, layer["w_up"])
-    x = x + jnp.einsum("bsf,fd->bsd", gate * up, layer["w_down"])
-    return x
-
-
-def _hidden(params: Dict, tokens, cfg: LlamaConfig):
-    """Embedding + blocks + final norm: [b, s] -> [b, s, d]."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    block = functools.partial(_block, cfg=cfg)
-    if cfg.remat:
-        block = jax.checkpoint(
-            block, policy=jax.checkpoint_policies.nothing_saveable)
-    for layer in params["layers"]:
-        x = block(x, layer)
-    return rms_norm(x, params["lnf"])
-
-
 def llama_forward(params: Dict, tokens, cfg: LlamaConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
-    x = _hidden(params, tokens, cfg)
-    return jnp.einsum("bsd,dv->bsv", x, params["head"]
-                      ).astype(jnp.float32)
+    x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
+    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
 
 
 def llama_loss(params: Dict, batch: Tuple, cfg: LlamaConfig):
     tokens, targets = batch
-    return cross_entropy(_hidden(params, tokens, cfg), params["head"],
-                         targets)
+    x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
+    return cross_entropy(x, head, targets)
 
 
 def make_llama_train_step(cfg: LlamaConfig, optimizer=None,

@@ -24,10 +24,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import flash_attention
-from ..ops.layers import rms_norm, rope
 from ..ops.loss import cross_entropy
-from ..parallel.moe import dropless_moe_layer
+from .decoder import Decoder, decoder_hidden, routed_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +51,19 @@ class MoEConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def decoder(self) -> Decoder:
+        """MHA from a fused `wqkv` (q/k norm where the layers hold
+        `q_norm`), rotary positions at `rope_theta`, routed experts."""
+        return Decoder(
+            n_heads=self.n_heads, n_kv_heads=self.n_heads,
+            head_dim=self.head_dim, rope_base=self.rope_theta,
+            norm_eps=self.norm_eps,
+            mlp=functools.partial(
+                routed_experts, experts_per_token=self.experts_per_token,
+                norm_topk_prob=self.norm_topk_prob),
+            remat=(jax.checkpoint_policies.nothing_saveable
+                   if self.remat else None))
 
     @classmethod
     def tiny(cls) -> "MoEConfig":
@@ -138,48 +149,12 @@ def moe_param_axes(cfg: MoEConfig) -> Dict:
     return axes
 
 
-def _block(x, layer, cfg: MoEConfig):
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
-    y = rms_norm(x, layer["ln1"], cfg.norm_eps)
-    qkv = jnp.einsum("bsd,de->bse", y, layer["wqkv"])
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    if cfg.qk_norm:
-        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    q = rope(q.reshape(b, s, h, hd).transpose(0, 2, 1, 3),
-             base=cfg.rope_theta)
-    k = rope(k.reshape(b, s, h, hd).transpose(0, 2, 1, 3),
-             base=cfg.rope_theta)
-    v = v.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    attn = flash_attention(q, k, v, True, None)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + jnp.einsum("bsd,de->bse", attn, layer["wo"])
-    # Routed expert MLP over flattened tokens
-    y = rms_norm(x, layer["ln2"], cfg.norm_eps)
-    out, stats = dropless_moe_layer(
-        y.reshape(b * s, d), layer["router"], layer["expert_gate"],
-        layer["expert_up"], layer["expert_down"],
-        experts_per_token=cfg.experts_per_token,
-        norm_topk_prob=cfg.norm_topk_prob)
-    return x + out.reshape(b, s, d), stats
-
-
 def _hidden(params: Dict, tokens, cfg: MoEConfig):
-    """tokens [b, s] -> (final-norm rows [b, s, d], the router's counters:
-    `balance_loss`, `router_z`, `expert_tokens` [E] summed over layers,
-    `expert_load_max_over_mean`). Both losses are taken over all layers'
-    tokens together, as HF's load_balancing_loss_func does."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    block = functools.partial(_block, cfg=cfg)
-    if cfg.remat:
-        block = jax.checkpoint(
-            block, policy=jax.checkpoint_policies.nothing_saveable)
-    total = None
-    for layer in params["layers"]:
-        x, stats = block(x, layer)
-        total = stats if total is None else jax.tree.map(
-            jnp.add, total, stats)
+    """tokens [b, s] -> (final-norm rows [b, s, d], the head, the router's
+    counters: `balance_loss`, `router_z`, `expert_tokens` [E] summed over
+    layers, `expert_load_max_over_mean`). Both losses are taken over all
+    layers' tokens together, as HF's load_balancing_loss_func does."""
+    x, head, total, _ = decoder_hidden(params, tokens, cfg.decoder())
     rows = tokens.size * len(params["layers"])
     counts = total["expert_tokens"]
     share = counts.astype(jnp.float32) / rows       # sums to k
@@ -191,11 +166,7 @@ def _hidden(params: Dict, tokens, cfg: MoEConfig):
         "expert_load_max_over_mean":
             jnp.max(counts) / jnp.mean(counts.astype(jnp.float32)),
     }
-    return rms_norm(x, params["lnf"], cfg.norm_eps), counters
-
-
-def _head(params: Dict):
-    return params["head"] if "head" in params else params["embed"].T
+    return x, head, counters
 
 
 def _aux_loss(counters: Dict, cfg: MoEConfig):
@@ -206,9 +177,8 @@ def _aux_loss(counters: Dict, cfg: MoEConfig):
 def moe_forward(params: Dict, tokens, cfg: MoEConfig):
     """tokens [b, s] -> (logits [b, s, vocab] fp32, the weighted
     auxiliary loss that moe_loss adds to the cross entropy)."""
-    x, counters = _hidden(params, tokens, cfg)
-    logits = jnp.einsum("bsd,dv->bsv", x, _head(params)
-                        ).astype(jnp.float32)
+    x, head, counters = _hidden(params, tokens, cfg)
+    logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
     return logits, _aux_loss(counters, cfg)
 
 
@@ -216,9 +186,8 @@ def moe_loss_and_counters(params: Dict, batch: Tuple, cfg: MoEConfig):
     """(loss, the router's counters): cross entropy + aux_loss_weight *
     balance_loss + z_loss_weight * router_z."""
     tokens, targets = batch
-    x, counters = _hidden(params, tokens, cfg)
-    loss = cross_entropy(x, _head(params), targets) \
-        + _aux_loss(counters, cfg)
+    x, head, counters = _hidden(params, tokens, cfg)
+    loss = cross_entropy(x, head, targets) + _aux_loss(counters, cfg)
     return loss, counters
 
 

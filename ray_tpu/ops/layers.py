@@ -10,8 +10,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# What a model that names neither constant runs on (models.decoder.Decoder).
+NORM_EPS = 1e-6
+ROPE_BASE = 10000.0
 
-def rms_norm(x, weight, eps: float = 1e-6):
+
+def rms_norm(x, weight, eps: float = NORM_EPS):
     """RMSNorm; computed in fp32, cast back to input dtype."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
@@ -19,7 +23,7 @@ def rms_norm(x, weight, eps: float = 1e-6):
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, position_offset=0, base: float = 10000.0, positions=None):
+def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None):
     """Rotary position embedding for [batch, heads, seq, head_dim].
 
     `positions` overrides `position_offset` and may be traced: shape

@@ -107,14 +107,18 @@ DEVICE_SCOPES: Dict[str, str] = {
                  "per-expert counts and the gather of the rows",
     "moe_combine": "parallel/moe.py dropless_moe_layer: the experts' rows "
                    "back in token order and their weighted sum",
-    "layers": "models/gpt.py _backbone, the layer stack",
+    "layers": "models/decoder.py decoder_hidden, the layer stack of "
+              "every decoder family (gpt, llama, moe), in the train step "
+              "and under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "
             "gradient in one pass",
     "optimizer_update": "models/_training.py train_step, optimizer "
                         "update and apply",
-    "prefill": "models/generate.py prefill / insert_prefill",
-    "decode": "models/generate.py decode_step / decode_batch",
+    "prefill": "models/generate.py prefill / insert_prefill, round "
+               "models/decoder.py's stack with a cache",
+    "decode": "models/generate.py decode_step / decode_batch, round the "
+              "same stack one token a row",
 }
 
 # The worker-level actor method behind profile_actor: any actor's worker

@@ -23,7 +23,9 @@ import jax.numpy as jnp
 import pytest
 
 import ray_tpu
-from ray_tpu.models import GPTConfig, gpt_init, make_train_step
+from ray_tpu.models import (GPTConfig, LlamaConfig, MoEConfig, gpt_init,
+                            make_llama_train_step, make_moe_train_step,
+                            make_train_step)
 from ray_tpu.util import profiling
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -194,24 +196,40 @@ def test_annotate_keeps_a_process_off_jax():
                    timeout=60)
 
 
-@pytest.mark.parametrize("cfg,batch", [
-    (GPTConfig(vocab_size=512, d_model=128, n_heads=2, n_layers=1,
-               d_ff=256, max_seq_len=256, remat=False), 2),
+ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
+
+
+@pytest.mark.parametrize("make_step,cfg,batch,kernels", [
+    (make_train_step,
+     GPTConfig(vocab_size=512, d_model=128, n_heads=2, n_layers=1,
+               d_ff=256, max_seq_len=256, remat=False), 2,
+     ATTENTION_KERNELS),
     # gpt2s-train-1chip's step: 16 sequences of 1,024, 12 heads of 64.
-    (dataclasses.replace(GPTConfig.gpt2_small(), remat=False), 16),
-], ids=["tiny", "gpt2-small"])
-def test_lowered_train_step_carries_scopes_and_kernel_names(monkeypatch,
-                                                            cfg, batch):
+    (make_train_step,
+     dataclasses.replace(GPTConfig.gpt2_small(), remat=False), 16,
+     ATTENTION_KERNELS),
+    # The other two families run the same stack (models/decoder.py).
+    (make_llama_train_step,
+     LlamaConfig(vocab_size=512, d_model=256, n_heads=2, n_kv_heads=1,
+                 n_layers=1, d_ff=256, max_seq_len=256), 2,
+     ATTENTION_KERNELS),
+    (make_moe_train_step,
+     MoEConfig(vocab_size=512, d_model=128, n_heads=1, n_layers=1,
+               n_experts=4, experts_per_token=2, d_expert=128,
+               max_seq_len=256), 2,
+     ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
+], ids=["tiny", "gpt2-small", "llama", "moe"])
+def test_lowered_train_step_carries_scopes_and_kernel_names(
+        monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    init_state, step = make_train_step(cfg)
+    init_state, step = make_step(cfg)
     state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0)))
     tok = jax.ShapeDtypeStruct((batch, cfg.max_seq_len), jnp.int32)
     text = step.trace(state, (tok, tok)).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
-        "_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == kernels
     # The kernels' wrappers are jitted (one trace a step, not one a
     # layer), so their locations start at the scope.
     for kernel in ("fwd", "dq", "dkv"):
