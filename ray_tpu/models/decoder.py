@@ -25,6 +25,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
 from ..ops.layers import NORM_EPS, ROPE_BASE, rms_norm, rope, swiglu
@@ -130,8 +131,9 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
 
     y = rms_norm(x, layer["ln1"], dec.norm_eps)
     if "wqkv" in layer:
-        q, k, v = jnp.split(
-            jnp.einsum("bsd,de->bse", y, layer["wqkv"]), 3, axis=-1)
+        q, k, v = jnp.split(checkpoint_name(
+            jnp.einsum("bsd,de->bse", y, layer["wqkv"]), "attention_qkv"),
+            3, axis=-1)
     else:
         q = jnp.einsum("bsd,de->bse", y, layer["wq"])
         k, v = jnp.split(
@@ -156,6 +158,31 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
         attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, d)
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+# What a rematerialised block keeps for its backward pass, by the names
+# `attention` above, ops/attention.py and parallel/moe.py give their
+# values (a name lowers to nothing where no jax.checkpoint is round it).
+# Kept: what a Pallas kernel or a gather over a permutation made and the
+# backward reads (attention's `out` and `lse`; the gate and up grouped
+# matmuls' rows; the experts' rows back in token order); what the
+# attention's backward reads besides, q, k, v and the projection they are
+# normed and rotated from (0.2 GB each at OLMoE's 16,384 tokens, for 4.8
+# and 4.2 ms a step); and the router's probabilities, because rows kept
+# in sorted order must meet the same order again (parallel/moe.py). Made
+# again: the block's two norms and its output projection, the float32
+# router up to its probabilities, the top-k and sorts, the dispatch
+# gather, silu(gate) * up; the down matmul's output is read by nothing
+# once the unsorted rows are kept. What is kept scales with the tokens as
+# the block's own live set does, and is alive at the peak anyway, in the
+# layer being differentiated: 0.5 GB over keeping nothing at OLMoE's
+# 16,384 tokens, where keeping everything does not fit (PERF.md §6, PR 28).
+KEPT_UNDER_REMAT = (
+    "attention_qkv", "flash_attention_q", "flash_attention_k",
+    "flash_attention_v", "flash_attention_out", "flash_attention_lse",
+    "moe_probs", "moe_gate", "moe_up", "moe_unsorted")
+keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
+    *KEPT_UNDER_REMAT)
 
 
 def _block(x, layer, cache, start_pos, dec: Decoder):

@@ -45,8 +45,11 @@ class GPTConfig:
         defaults of ops.layers, a GELU MLP."""
         # dots-saveable: keep matmul outputs, recompute elementwise (full
         # recompute only pays off when memory is the binding constraint;
-        # callers can still pass remat=False to skip remat). Which policy
-        # is faster is not measured on this chip (ROADMAP A5(b)).
+        # callers can still pass remat=False to skip remat). It reads no
+        # names, so the attention kernel's forward runs again under it.
+        # The policy measured on this chip is decoder.keep_kernel_outputs
+        # (OLMoE, PR 28); this literal waits for a cell that runs gpt
+        # with remat on (ROADMAP C1(e)).
         policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,

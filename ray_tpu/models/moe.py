@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, routed_experts
+from .decoder import (Decoder, decoder_hidden, keep_kernel_outputs,
+                      routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +55,9 @@ class MoEConfig:
 
     def decoder(self) -> Decoder:
         """MHA from a fused `wqkv` (q/k norm where the layers hold
-        `q_norm`), rotary positions at `rope_theta`, routed experts."""
+        `q_norm`), rotary positions at `rope_theta`, routed experts;
+        under `remat` a block keeps what its kernels and its row unsort
+        made and makes the rest again."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
             head_dim=self.head_dim, rope_base=self.rope_theta,
@@ -62,8 +65,7 @@ class MoEConfig:
             mlp=functools.partial(
                 routed_experts, experts_per_token=self.experts_per_token,
                 norm_topk_prob=self.norm_topk_prob),
-            remat=(jax.checkpoint_policies.nothing_saveable
-                   if self.remat else None))
+            remat=keep_kernel_outputs if self.remat else None)
 
     @classmethod
     def tiny(cls) -> "MoEConfig":

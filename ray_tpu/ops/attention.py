@@ -46,6 +46,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -734,6 +735,17 @@ def _flash_attention_fwd_impl(q, k, v, causal, sm_scale,
 def _flash_fwd(q, k, v, causal, sm_scale):
     out, (o_saved, lse) = _flash_attention_fwd_impl(
         q, k, v, causal, sm_scale)
+    if o_saved is not None:
+        # What the backward kernels read, by name: a rematerialised block
+        # keeps these, so the forward kernel does not run again, nor the
+        # norm, projection and rotary that made q, k and v
+        # (models/decoder.py KEPT_UNDER_REMAT). With no jax.checkpoint
+        # round the caller a name lowers to nothing.
+        out = o_saved = checkpoint_name(out, "flash_attention_out")
+        lse = checkpoint_name(lse, "flash_attention_lse")
+        q = checkpoint_name(q, "flash_attention_q")
+        k = checkpoint_name(k, "flash_attention_k")
+        v = checkpoint_name(v, "flash_attention_v")
     return out, (q, k, v, o_saved, lse)
 
 

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.grouped_matmul import grouped_matmul
 
@@ -208,7 +209,14 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        probs = jnp.exp(logits - lse[:, None])
+        # Kept under remat (models/decoder.py KEPT_UNDER_REMAT; [T, E]
+        # float32, 4 MB at 16,384 tokens): rows kept in sorted order must
+        # meet the order they were sorted in. A router made again can
+        # break a near tie the other way (XLA may feed it an unrounded
+        # copy of x, or sum in another order), and one flipped choice
+        # shifts every row between the two experts; top_k of the same
+        # probabilities chooses the same.
+        probs = checkpoint_name(jnp.exp(logits - lse[:, None]), "moe_probs")
         weights, experts = lax.top_k(probs, k)                # [T, k]
         if norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
@@ -222,13 +230,20 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
             experts.reshape(-1, 1) == jnp.arange(e, dtype=experts.dtype),
             axis=0, dtype=jnp.int32)
         xs = _dispatch(x, perm, inv, k)                       # [T*k, d]
-    gate = grouped_matmul(xs, w_gate, counts)
-    up = grouped_matmul(xs, w_up, counts)
+    # The names are for a rematerialised block (models/decoder.py
+    # KEPT_UNDER_REMAT): the gate and up rows and the unsorted rows are
+    # what a kernel or a gather over a permutation made and the backward
+    # reads. `xs`, `hidden` and `ys` have none: a gather from the T
+    # tokens' rows and an elementwise pass make the first two again, and
+    # nothing reads `ys` once the unsorted rows are kept.
+    gate = checkpoint_name(grouped_matmul(xs, w_gate, counts), "moe_gate")
+    up = checkpoint_name(grouped_matmul(xs, w_up, counts), "moe_up")
     hidden = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(x.dtype)
     ys = grouped_matmul(hidden, w_down, counts)               # [T*k, d]
     with jax.named_scope("moe_combine"):
-        per_token = _unsort(ys, perm, inv).reshape(t, k, d)
+        per_token = checkpoint_name(
+            _unsort(ys, perm, inv), "moe_unsorted").reshape(t, k, d)
         out = jnp.sum(per_token.astype(jnp.float32) * weights[:, :, None],
                       axis=1).astype(x.dtype)
     stats = {"expert_tokens": counts,

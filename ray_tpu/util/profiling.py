@@ -121,6 +121,37 @@ DEVICE_SCOPES: Dict[str, str] = {
               "same stack one token a row",
 }
 
+
+def kernel_calls(compiled_text: str) -> Dict[str, int]:
+    """The Mosaic kernel calls of a compiled program, counted by scope:
+    a static counter, read from `jitted.lower(...).compile().as_text()`
+    and not from a run. A call is an instruction whose target is
+    `tpu_custom_call`; it counts under the DEVICE_SCOPES name its own name
+    holds (`%grouped_matmul_fwd.12`, `%jvp_flash_attention_fwd_.3`), or
+    under its own name with the number dropped where it holds none, so
+    the values sum to the program's kernel calls. A call in a loop's body
+    counts once.
+
+    What it is for: a forward kernel that runs twice a step. A kernel's
+    forward runs once for each of its backward passes unless a
+    rematerialised block makes it again, so in a train step
+    `calls["grouped_matmul_fwd"] - calls["grouped_matmul_dlhs"]` and
+    `calls["flash_attention_fwd"] - calls["flash_attention_dq"]` are the
+    forward calls the step repeats: 6 + 2 in OLMoE's two-layer step while
+    its blocks kept nothing, 0 since they keep what
+    models/decoder.py KEPT_UNDER_REMAT names (PERF.md §6, PR 28)."""
+    calls: Dict[str, int] = {}
+    for line in compiled_text.splitlines():
+        name, eq, rest = line.strip().partition(" = ")
+        if not eq or 'custom_call_target="tpu_custom_call"' not in rest:
+            continue
+        name = name.removeprefix("ROOT ").lstrip("%")
+        scope = next((s for s in DEVICE_SCOPES if s in name),
+                     name.rstrip(".0123456789"))
+        calls[scope] = calls.get(scope, 0) + 1
+    return calls
+
+
 # The worker-level actor method behind profile_actor: any actor's worker
 # answers it on a thread of its own (_private/worker_proc.py).
 PROFILE_METHOD = "__ray_tpu_profile__"

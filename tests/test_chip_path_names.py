@@ -178,6 +178,37 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
     assert set(scopes) == set(profiling.DEVICE_SCOPES)
 
 
+# Lines as XLA:TPU prints them (tests/test_compile_v5e_olmoe.py reads a
+# whole compiled step; these are cut from one, and from PR 25's, whose
+# forward kernel stood under `jvp(...)`).
+_CALL = ('custom-call(%a, %b), custom_call_target="tpu_custom_call", '
+         'operand_layout_constraints={bf16[8,128]{1,0}}')
+_COMPILED = "\n".join([
+    "HloModule jit_train_step, is_scheduled=true",
+    "  %grouped_matmul_fwd.12 = bf16[131072,1024]{1,0:T(8,128)(2,1)} " + _CALL,
+    "  %grouped_matmul_fwd.13 = bf16[131072,1024]{1,0:T(8,128)(2,1)} " + _CALL,
+    "  %jvp_grouped_matmul_fwd_.2 = bf16[131072,2048]{1,0} " + _CALL,
+    "  %grouped_matmul_dlhs.4 = bf16[131072,2048]{1,0} " + _CALL,
+    "  %flash_attention_fwd.4 = (bf16[64,4096,128]{2,1,0}, "
+    "f32[64,4096,128]{2,1,0}) " + _CALL,
+    "  ROOT %flash_attention_dkv.1 = (bf16[8,128]{1,0}, bf16[8,128]{1,0}) "
+    + _CALL,
+    "  %a_kernel_of_no_scope.7 = f32[8,128]{1,0} " + _CALL,
+    '  %fusion.6 = bf16[8,128]{1,0} fusion(%a), kind=kLoop, calls=%fused',
+    '  %custom-call.3 = f32[8]{0} custom-call(%a), '
+    'custom_call_target="Sharding"',
+])
+
+
+def test_kernel_calls_counts_mosaic_calls_by_scope():
+    calls = profiling.kernel_calls(_COMPILED)
+    assert calls == {"grouped_matmul_fwd": 3, "grouped_matmul_dlhs": 1,
+                     "flash_attention_fwd": 1, "flash_attention_dkv": 1,
+                     "a_kernel_of_no_scope": 1}
+    assert sum(calls.values()) == _COMPILED.count('"tpu_custom_call"')
+    assert profiling.kernel_calls("") == {}
+
+
 def test_the_chip_path_opens_spans_through_profiling_only():
     other = _literals(r"(TraceAnnotation|tracing\.span|start_trace)\(",
                       CHIP_PATH)

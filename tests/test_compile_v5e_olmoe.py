@@ -3,8 +3,9 @@ train step of the `olmoe-train-1chip` cell as the cell runs it — OLMoE-1B-7B
 at its published widths (d 2048, 16 heads of 128, 64 experts of 1024, 8 a
 token, V 50,304), depth 2, B=4 x S=4096, remat on, the default optimizer —
 compiles for one chip, calls the attention and the grouped-matmul kernels
-under the program's scopes, holds no [T, E, C] dispatch tensor and no
-float32 copy of an expert tensor, and fits the chip by XLA's memory
+under the program's scopes, each forward once though remat is on (the
+blocks keep what the kernels made), holds no [T, E, C] dispatch tensor and
+no float32 copy of an expert tensor, and fits the chip by XLA's memory
 analysis (PERF.md §4 has the figure). The topology is described inside a
 fixture (see the on-chip-measurement guide); under several test workers
 without ALLOW_MULTIPLE_LIBTPU_LOAD only one of this file and
@@ -97,6 +98,31 @@ def test_step_calls_the_attention_and_grouped_matmul_kernels(step):
     for scope in scopes:
         assert any(scope in r for r in rows), (scope, rows)
     assert all(any(s in r for s in scopes) for r in rows), rows
+
+
+def test_no_forward_kernel_runs_twice_a_step(step):
+    """Remat is on in this cell, and a block keeps what its kernels and
+    its row unsort made (models/decoder.py KEPT_UNDER_REMAT): per layer
+    three grouped matmuls and one attention, each forward once. While the
+    blocks kept nothing (until PR 28) the counter read grouped_matmul_fwd
+    12 and flash_attention_fwd 4, eight forward calls run twice, and
+    there were 12 of the gathers below."""
+    from ray_tpu.util import profiling
+
+    text = step[1].as_text()
+    calls = profiling.kernel_calls(text)
+    assert calls == {
+        "grouped_matmul_fwd": 6, "grouped_matmul_dlhs": 6,
+        "grouped_matmul_drhs": 6, "flash_attention_fwd": 2,
+        "flash_attention_dq": 2, "flash_attention_dkv": 2}
+    # forward kernels run twice: 0 (6 + 2 before)
+    assert (calls["grouped_matmul_fwd"] - calls["grouped_matmul_dlhs"]
+            + calls["flash_attention_fwd"] - calls["flash_attention_dq"]) == 0
+    # Gathers of the T*k rows: a layer's dispatch, unsort and their two
+    # cotangents, and the dispatch made again for the backward pass; the
+    # unsort is not made again.
+    row_gathers = re.findall(r"= bf16\[131072,2048\]\S* gather\(", text)
+    assert len(row_gathers) <= 10, len(row_gathers)
 
 
 def test_step_holds_no_dispatch_tensor_and_no_float32_expert_copy(step):

@@ -1,10 +1,13 @@
 """Model + ops tests (CPU backend; kernel-vs-reference equivalence is the
 test pattern — the TPU kernel path is exercised on hardware by bench.py)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import (
     GPTConfig,
@@ -159,6 +162,69 @@ class TestGPT:
         _, m2 = train_step2(state2, shard_batch(batch, mesh))
         np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
                                    rtol=1e-3)
+
+    # PR 28 gave names (jax.ad_checkpoint.checkpoint_name) to what the
+    # attention kernel makes, for the policy OLMoE's blocks remat under
+    # (models/decoder.py KEPT_UNDER_REMAT). gpt's blocks run under none
+    # (the gpt2 cells) or under one that reads no names.
+    def test_remat_names_lower_to_nothing_where_no_block_is_rematerialised(
+            self, monkeypatch):
+        """The step the gpt2 cells run (remat off), kernels in it: its
+        lowered text holds none of the names and is the text of the same
+        step with `checkpoint_name` taken out of models/decoder.py and
+        ops/attention.py."""
+        import dataclasses
+
+        from ray_tpu.models import decoder
+        from ray_tpu.ops import attention
+
+        monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+        cfg = dataclasses.replace(GPTConfig.tiny(), remat=False)
+        tok = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+
+        def lowered_text():
+            init_state, step = make_train_step(cfg)
+            state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0)))
+            text = step.trace(state, (tok, tok)).lower(
+                lowering_platforms=("tpu",)).as_text()
+            # jax numbers a module's private functions (@_take_123) from
+            # a counter that every lowering of the process advances
+            return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+        named = []
+        for module in (attention, decoder):
+            monkeypatch.setattr(
+                module, "checkpoint_name",
+                lambda x, name: named.append(name) or checkpoint_name(x, name))
+        with_names = lowered_text()
+        assert set(named) == {n for n in decoder.KEPT_UNDER_REMAT
+                              if "attention" in n}
+        assert len(named) == 6 * cfg.n_layers
+        assert "tpu_custom_call" in with_names
+        assert not any(n in with_names for n in decoder.KEPT_UNDER_REMAT)
+        for module in (attention, decoder):
+            monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+        assert lowered_text() == with_names
+
+    def test_remat_changes_no_loss_or_gradient(self):
+        """gpt's policy keeps matmul outputs and reads no names. Run
+        operation by operation (tests/test_olmoe.py says why): equal, not
+        close."""
+        import dataclasses
+        on = GPTConfig.tiny()
+        off = dataclasses.replace(on, remat=False)
+        assert on.remat and on.decoder().remat is not None
+        params = gpt_init(jax.random.PRNGKey(0), on)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                                    on.vocab_size)
+        batch = (tokens, jnp.roll(tokens, -1, axis=1))
+        loss_on, grads_on = jax.value_and_grad(gpt_loss)(params, batch, on)
+        loss_off, grads_off = jax.value_and_grad(gpt_loss)(params, batch, off)
+        assert float(loss_on) == float(loss_off)
+        for a, b in zip(jax.tree.leaves(grads_on), jax.tree.leaves(grads_off),
+                        strict=True):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
 
 
 class TestGraftEntry:

@@ -4,7 +4,9 @@ top-k layer (parallel/moe.py), the grouped-matmul kernels
 (ops/grouped_matmul.py) and the names and counters the step carries.
 CPU, tiny sizes, seeded weights."""
 
+import collections
 import dataclasses
+import functools
 import importlib
 import re
 
@@ -12,9 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import print_saved_residuals
 
 from chipbench.families import olmoe
 from ray_tpu import train
+from ray_tpu.models import decoder
 from ray_tpu.models import (
     MoEConfig,
     make_moe_train_step,
@@ -276,6 +280,141 @@ def test_grouped_matmul_and_both_gradients(kernels, dtype, tol, monkeypatch):
     assert _rel(dlhs, want_dlhs) < tol
     assert _rel(drhs, want_drhs) < tol
     assert float(jnp.abs(drhs[0]).max()) == 0.0       # an empty group
+
+
+# ---------------------------------------------------------------------------
+# what a rematerialised block keeps (models/decoder.py KEPT_UNDER_REMAT)
+# ---------------------------------------------------------------------------
+def _kernel_path(kernels, monkeypatch):
+    monkeypatch.setattr(gm, "_TILES", (128, 128, 128))
+    if kernels == "interpreted":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+
+
+# A kept value is bit for bit the value the second forward would have
+# made, so remat changes no number: equality, not a tolerance. That is
+# said of the operations as written, so they run one by one, not under
+# one jit: compiled whole, where the block is cut moves XLA's fusions, and
+# with them which bf16 chains stay in float32 and the order of a fused
+# sum (3e-6 of a norm's gradient on the CPU). 128 positions so that the
+# interpreted case runs the attention kernels too.
+@pytest.mark.parametrize("kernels", ["interpreted", "ragged_dot"])
+def test_remat_changes_no_loss_counter_or_gradient(kernels, monkeypatch):
+    _kernel_path(kernels, monkeypatch)
+    on = MoEConfig.tiny()
+    off = dataclasses.replace(on, remat=False)
+    assert on.remat and on.decoder().remat is decoder.keep_kernel_outputs
+    assert off.decoder().remat is None
+    params = moe_init(jax.random.PRNGKey(0), on)
+    batch = _tokens(on, batch=1, seq=128)
+
+    def value_and_grads(cfg):
+        return jax.value_and_grad(
+            lambda p: moe_loss_and_counters(p, batch, cfg),
+            has_aux=True)(params)
+
+    (loss_on, counters_on), grads_on = value_and_grads(on)
+    (loss_off, counters_off), grads_off = value_and_grads(off)
+    assert float(loss_on) == float(loss_off)
+    for a, b in zip(jax.tree.leaves((counters_on, grads_on)),
+                    jax.tree.leaves((counters_off, grads_off)), strict=True):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def _count_kernels_and_row_gathers(jaxpr, rows_shape, counts):
+    """Walk a jaxpr and what it holds: pallas_calls by the DEVICE_SCOPES
+    name they stand under, gathers that make a `rows_shape` value, and
+    top-k choices a rematerialised body makes from a value it made again
+    (one that reads a kept value chooses as the forward pass did)."""
+    for eqn in jaxpr.eqns:
+        if "policy" in eqn.params:              # a jax.checkpoint's body
+            body = eqn.params["jaxpr"]
+            counts["choices_made_again"] += sum(
+                e.primitive.name == "top_k" and e.invars[0] not in body.invars
+                for e in body.eqns)
+        if eqn.primitive.name == "pallas_call":
+            scope = str(eqn.source_info.name_stack).split("/")[-1]
+            counts[next(s for s in profiling.DEVICE_SCOPES
+                        if s in scope)] += 1
+        elif (eqn.primitive.name == "gather"
+              and eqn.outvars[0].aval.shape == rows_shape):
+            counts["row_gathers"] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _count_kernels_and_row_gathers(sub, rows_shape, counts)
+    return counts
+
+
+# One block's gradient, traced with the kernels in it (no chip needed to
+# trace). Without remat a layer is one attention and three grouped matmuls
+# forward, each with its two gradients, and four [T*k, d] gathers (dispatch,
+# unsort, and their cotangents). With it the block may add the dispatch
+# gather and nothing else: no kernel's forward runs twice. Keeping nothing,
+# as before PR 28, gave 6 grouped_matmul_fwd, 2 flash_attention_fwd and 6
+# gathers here. And the backward pass sorts by the forward's choice of
+# experts: its top-k reads the kept probabilities. With the router made
+# again and the gate and up rows kept, every expert's weight gradient was
+# 20-90% off on the chip (PERF.md §6, PR 28): a flipped near tie shifts the
+# sorted rows, and no test on the CPU, where both passes round alike, sees it.
+@pytest.mark.parametrize("remat,row_gathers", [(True, 5), (False, 4)])
+def test_no_forward_kernel_runs_twice_in_a_block(remat, row_gathers,
+                                                 monkeypatch):
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(MoEConfig.tiny(), n_layers=1, remat=remat)
+    params = jax.eval_shape(lambda: moe_init(jax.random.PRNGKey(0), cfg))
+    tok = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    grad = jax.make_jaxpr(jax.grad(
+        lambda p, t: moe_loss(p, (t, t), cfg)))(params, tok)
+    rows = (2 * 128 * cfg.experts_per_token, cfg.d_model)
+    assert _count_kernels_and_row_gathers(
+        grad.jaxpr, rows, collections.Counter()) == collections.Counter({
+            "flash_attention_fwd": 1, "flash_attention_dq": 1,
+            "flash_attention_dkv": 1, "grouped_matmul_fwd": 3,
+            "grouped_matmul_dlhs": 3, "grouped_matmul_drhs": 3,
+            "row_gathers": row_gathers, "choices_made_again": 0})
+
+
+# What leaves a rematerialised block for its backward pass, besides its
+# arguments: the ten named values and nothing else. Of the four [T*k, d]
+# values of a layer (dispatched rows, down matmul's output, unsorted rows
+# and their product with the weights) one is kept, and it is the unsorted
+# rows: the test above shows the down matmul is not run again, which
+# keeping `xs` would need, and 5 gathers, where keeping `ys` gives 6.
+@pytest.mark.parametrize("dtype,short", [(jnp.bfloat16, "bf16"),
+                                         (jnp.float32, "f32")])
+def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
+                                                         monkeypatch, capsys):
+    _kernel_path("interpreted", monkeypatch)
+    cfg = dataclasses.replace(MoEConfig.tiny(), n_layers=1, dtype=dtype)
+    dec = cfg.decoder()
+    b, s, d, k = 2, 128, cfg.d_model, cfg.experts_per_token
+    layer = moe_init(jax.random.PRNGKey(0), cfg)["layers"][0]
+    block = jax.checkpoint(functools.partial(decoder._block, dec=dec),
+                           policy=dec.remat)
+    print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
+                          jnp.ones((b, s, d), dtype), layer)
+    # a line: `bf16[256,64] from the argument x`, `... named 'n' from f.py`
+    lines = capsys.readouterr().out.splitlines()
+    kept = sorted(line.split()[0] for line in lines
+                  if "from the argument" not in line
+                  and "from a constant" not in line)
+    heads = f"{short}[{b},{cfg.n_heads},{s},{cfg.head_dim}]"
+    assert len(decoder.KEPT_UNDER_REMAT) == 10
+    assert kept == sorted([
+        f"{short}[{b},{s},{3 * d}]",                    # attention_qkv
+        heads, heads, heads, heads,     # flash_attention_q, _k, _v, _out
+        f"f32[{b},{cfg.n_heads},{s},128]",              # flash_attention_lse
+        f"f32[{b * s},{cfg.n_experts}]",                # moe_probs
+        f"{short}[{b * s * k},{cfg.d_expert}]",         # moe_gate
+        f"{short}[{b * s * k},{cfg.d_expert}]",         # moe_up
+        f"{short}[{b * s * k},{d}]",                    # moe_unsorted
+    ])
+    # Remat puts a reduce_precision after a kept value the forward reads
+    # too, and the description then names that; the one that is a residual
+    # and nothing else still reads by name.
+    assert any("named 'flash_attention_lse'" in line for line in lines)
 
 
 # ---------------------------------------------------------------------------
