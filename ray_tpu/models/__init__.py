@@ -12,6 +12,14 @@ from .gpt import (  # noqa: F401
     gpt_param_axes,
     make_train_step,
 )
+from .hybrid import (  # noqa: F401
+    HybridConfig,
+    hybrid_forward,
+    hybrid_init,
+    hybrid_loss,
+    hybrid_param_axes,
+    make_hybrid_train_step,
+)
 from .llama import (  # noqa: F401
     LlamaConfig,
     llama_forward,

@@ -3,19 +3,32 @@ models.moe train and what models.generate prefills and decodes.
 
 A family says what it is with a `Decoder`, which its config's `decoder()`
 builds from the fields it already has: the attention's head counts, its
-channel mixer, its remat policy, and the rope base and norm eps where it
-names them (ops.layers' own where it does not). Nothing here reads a
+channel mixer, its remat policy, the rope base (None: no positions at
+all), the norm eps, the score scale and the sizes of a state-space mixer
+where it names them (ops.layers' own, 1/sqrt(head_dim), none where it
+does not), and what its embedding, its two residual branches and its
+logits are multiplied by (1 where it says nothing). Nothing here reads a
 config: one family differs from another by those values and by which
-weights a layer holds (`wqkv` or `wq` + `wkv`; `q_norm`), and by nothing else.
+weights a layer holds (`in_proj`: a Mamba-2 layer; else attention, from
+`wqkv` or `wq` + `wkv`, with `q_norm` or not), and by nothing else. A
+model's layers need not be alike: each picks its mixer by what it holds.
 
     decoder_hidden      embedding, layer stack, final norm, head
-      attention         the one sequence mixer: with no cache the flash
-                        kernel over the whole sequence, with one a write
-                        into it and a masked read of it
+      attention | mamba2   the sequence mixers, (x, layer, dec, cache,
+                        start_pos) -> (y, new cache): attention is the
+                        flash kernel over the whole sequence with no
+                        cache, with one a write into it and a masked read
+                        of it; mamba2 the chunked scan (ops.ssm_scan) over
+                        the tokens given, from the cached state where
+                        there is one, and one step of the recurrence for a
+                        single token
       gelu_mlp | swiglu_mlp | routed_experts   the channel mixers,
                         (y, layer) -> (out, stats or None)
 
-Cache layout: per layer {"k"|"v": [batch, n_kv_heads, max_len, head_dim]}.
+Cache layout, per layer by its kind: attention {"k"|"v": [batch,
+n_kv_heads, max_len, head_dim]}; Mamba-2 {"conv": [batch, d_conv - 1,
+inner + 2 groups x state] the convolution's last inputs, "ssm": [batch,
+heads, head_dim, state] float32}, which does not grow.
 """
 
 from __future__ import annotations
@@ -28,7 +41,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
-from ..ops.layers import NORM_EPS, ROPE_BASE, rms_norm, rope, swiglu
+from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d, gated_rms_norm,
+                          rms_norm, rope, swiglu)
+from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import dropless_moe_layer
 
 
@@ -38,8 +53,19 @@ class Decoder(NamedTuple):
     head_dim: int
     mlp: Callable                   # (y, layer) -> (out, stats or None)
     remat: Optional[Callable]       # a jax.checkpoint policy; None: keep all
-    rope_base: float = ROPE_BASE
+    rope_base: Optional[float] = ROPE_BASE     # None: no rotary
     norm_eps: float = NORM_EPS
+    sm_scale: Optional[float] = None           # None: 1 / sqrt(head_dim)
+    residual_scale: float = 1.0                # on both branches of a block
+    embed_scale: float = 1.0
+    logit_scale: float = 1.0                   # on the final-norm rows
+    # A Mamba-2 layer's sizes (layers that hold `in_proj`): inner width
+    # ssm_heads * ssm_head_dim; B and C are ssm_groups * ssm_state wide.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
 
 
 def gelu_mlp(y, layer):
@@ -64,20 +90,36 @@ def routed_experts(y, layer, experts_per_token: int, norm_topk_prob: bool):
     return out.reshape(b, s, d), stats
 
 
-def empty_cache(dec: Decoder, n_layers, batch, max_len, dtype) -> List[Dict]:
-    """A layer holds its kv heads, not their copies across a group."""
-    shape = (batch, dec.n_kv_heads, max_len, dec.head_dim)
-    return [{"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-            for _ in range(n_layers)]
+def _is_mamba2(layer) -> bool:
+    return "in_proj" in layer
+
+
+def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
+    """The state of each of `layers` (a model's `params["layers"]`, or
+    anything that holds their keys), by its kind: an attention layer its
+    kv heads up to `max_len`, not their copies across a group; a Mamba-2
+    layer its convolution's last inputs and its state."""
+    def one(layer):
+        if _is_mamba2(layer):
+            conv_dim = (dec.ssm_heads * dec.ssm_head_dim
+                        + 2 * dec.ssm_groups * dec.ssm_state)
+            taps = layer["conv_w"].shape[1]
+            return {"conv": jnp.zeros((batch, taps - 1, conv_dim), dtype),
+                    "ssm": jnp.zeros((batch, dec.ssm_heads, dec.ssm_head_dim,
+                                      dec.ssm_state), jnp.float32)}
+        shape = (batch, dec.n_kv_heads, max_len, dec.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return [one(layer) for layer in layers]
 
 
 def _across_group(t, group: int):
     """GQA: each kv head [b, kvh, ., hd] serves its whole query group.
-    (GQA inside the kernel, with no expanded copy, is ROADMAP B5's.)"""
+    (GQA inside the kernels, with no expanded copy, is in ROADMAP's list
+    of what the system cannot run yet.)"""
     return t if group == 1 else jnp.repeat(t, group, axis=1)
 
 
-def _cached_attention(q, k, v, cache, sp, group: int):
+def _cached_attention(q, k, v, cache, sp, group: int, sm_scale):
     """Write k, v [b, kvh, L, hd] into the cache at positions sp + [0, L)
     and attend q [b, h, L, hd] over the cache up to each query's own
     position. Only the write and the mask specialize on whether `sp` is
@@ -99,7 +141,7 @@ def _cached_attention(q, k, v, cache, sp, group: int):
         v_cache = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, 0, sp, 0))
 
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if sm_scale is None else sm_scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    _across_group(k_cache, group).astype(jnp.float32)) * scale
     q_iota = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 0)
@@ -142,22 +184,76 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
         q = rms_norm(q, layer["q_norm"], dec.norm_eps)
         k = rms_norm(k, layer["k_norm"], dec.norm_eps)
     # Rotary embeddings at absolute (possibly traced) positions, [L] or
-    # [b, L]; with no cache rope counts from 0 itself.
+    # [b, L]; with no cache rope counts from 0 itself. A model with no
+    # rope base has no positions at all.
     sp = positions = None
     if cache is not None:
         sp = jnp.asarray(start_pos)
         positions = (sp[:, None] if sp.ndim == 1 else sp) + jnp.arange(L)
-    q = rope(heads(q, h), base=dec.rope_base, positions=positions)
-    k = rope(heads(k, kvh), base=dec.rope_base, positions=positions)
+    def rotate(t):
+        if dec.rope_base is None:
+            return t
+        return rope(t, base=dec.rope_base, positions=positions)
+    q = rotate(heads(q, h))
+    k = rotate(heads(k, kvh))
     v = heads(v, kvh)
     if cache is None:
         attn = flash_attention(q, _across_group(k, h // kvh),
-                               _across_group(v, h // kvh), True, None)
+                               _across_group(v, h // kvh), True,
+                               dec.sm_scale)
         new_cache = None
     else:
-        attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh)
+        attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh,
+                                            dec.sm_scale)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, d)
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """The Mamba-2 mixer of x [b, L, d], from the input norm to the
+    output projection: one input projection to the gate z, the
+    convolved x | B | C and the step sizes; a causal depthwise
+    convolution and silu; the selective scan; the gated norm; the output
+    projection. One implementation serves training (the chunked scan over
+    the whole sequence, no cache), prefill (the same from the cached
+    state, returning the final state and the convolution's last inputs)
+    and decode (L = 1: one step of the recurrence). The state knows no
+    positions: `start_pos` is not read. Returns (y, new_cache or None)."""
+    b, L, d = x.shape
+    H, P, N, G = dec.ssm_heads, dec.ssm_head_dim, dec.ssm_state, dec.ssm_groups
+    inner, bc = H * P, G * N
+    y = rms_norm(x, layer["ln1"], dec.norm_eps)
+    z, xbc, dt = jnp.split(
+        jnp.einsum("bsd,de->bse", y, layer["in_proj"]),
+        [inner, 2 * inner + 2 * bc], axis=-1)
+    with jax.named_scope("ssm_conv"):
+        xbc, tail = causal_conv1d(
+            xbc, layer["conv_w"], layer["conv_b"],
+            None if cache is None else cache["conv"])
+        xbc = jax.nn.silu(xbc)
+    xs, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
+    xs = xs.reshape(b, L, H, P)
+    B, C = B.reshape(b, L, G, N), C.reshape(b, L, G, N)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
+    a = -jnp.exp(layer["A_log"].astype(jnp.float32))
+    if cache is not None and L == 1:
+        f32 = jnp.float32
+        x1, dt1 = xs[:, 0].astype(f32), dt[:, 0]               # [b,H,P], [b,H]
+        B1, C1 = (jnp.repeat(t[:, 0].astype(f32), H // G, axis=1)
+                  for t in (B, C))                             # [b, H, N]
+        state = (jnp.exp(dt1 * a)[..., None, None] * cache["ssm"]
+                 + (dt1[..., None] * x1)[..., None] * B1[:, :, None, :])
+        ys = (jnp.einsum("bhpn,bhn->bhp", state, C1)
+              + layer["D"].astype(f32)[:, None] * x1)[:, None].astype(x.dtype)
+    else:
+        ys, state = ssm_scan(
+            xs, dt, a, B, C, layer["D"], dec.ssm_chunk,
+            None if cache is None else cache["ssm"])
+    with jax.named_scope("ssm_gate_norm"):
+        ys = gated_rms_norm(ys.reshape(b, L, inner), z, layer["ssm_norm"],
+                            dec.norm_eps)
+    new_cache = None if cache is None else {"conv": tail, "ssm": state}
+    return jnp.einsum("bse,ed->bsd", ys, layer["out_proj"]), new_cache
 
 
 # What a rematerialised block keeps for its backward pass, by the names
@@ -177,19 +273,30 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
 # the block's own live set does, and is alive at the peak anyway, in the
 # layer being differentiated: 0.5 GB over keeping nothing at OLMoE's
 # 16,384 tokens, where keeping everything does not fit (PERF.md §6, PR 28).
+# Of a Mamba-2 layer, by the same rule: what the scan kernel made and a
+# backward pass reads, the state each chunk left (its backward kernel's)
+# and y (the gated norm's, after it) (ops/ssm_scan.py; 0.13 GB each a
+# layer at 16,384 tokens of granite-4.0-h-micro). Its input projection,
+# convolution, gated norm and output projection are made again.
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
-    "moe_probs", "moe_gate", "moe_up", "moe_unsorted")
+    "moe_probs", "moe_gate", "moe_up", "moe_unsorted",
+    "ssm_scan_y", "ssm_scan_states")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
 
 
+def _scaled(t, scale: float):
+    return t if scale == 1.0 else t * scale
+
+
 def _block(x, layer, cache, start_pos, dec: Decoder):
-    y, new_cache = attention(x, layer, dec, cache, start_pos)
-    x = x + y
+    mixer = mamba2 if _is_mamba2(layer) else attention
+    y, new_cache = mixer(x, layer, dec, cache, start_pos)
+    x = x + _scaled(y, dec.residual_scale)
     out, stats = dec.mlp(rms_norm(x, layer["ln2"], dec.norm_eps), layer)
-    return x + out, stats, new_cache
+    return x + _scaled(out, dec.residual_scale), stats, new_cache
 
 
 def decoder_hidden(params: Dict, tokens, dec: Decoder,
@@ -197,9 +304,10 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     """tokens [b, L] -> (final-norm rows [b, L, d], the output head
     [d, vocab], the mixers' `stats` summed over layers or None, the new
     cache or None). With a `cache` (an `empty_cache`, or the last call's) the
-    tokens sit at `start_pos` + [0, L) and attention reads and writes it;
-    with none this is the training forward."""
-    x = jnp.take(params["embed"], tokens, axis=0)
+    tokens sit at `start_pos` + [0, L) and the mixers read and write it;
+    with none this is the training forward. The rows come multiplied by
+    `dec.logit_scale`, so rows @ head are the model's logits."""
+    x = _scaled(jnp.take(params["embed"], tokens, axis=0), dec.embed_scale)
     block = functools.partial(_block, dec=dec)
     if dec.remat is not None and cache is None:    # remat is training's
         block = jax.checkpoint(block, policy=dec.remat)
@@ -211,6 +319,6 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
             total = stats if total is None else jax.tree.map(
                 jnp.add, total, stats)
             new_cache.append(cache_layer)
-    x = rms_norm(x, params["lnf"], dec.norm_eps)
+    x = _scaled(rms_norm(x, params["lnf"], dec.norm_eps), dec.logit_scale)
     head = params["head"] if "head" in params else params["embed"].T
     return x, head, total, (new_cache if cache is not None else None)

@@ -1,5 +1,5 @@
-"""Autoregressive generation with a KV cache, for any config with a
-`decoder()` (models.gpt, models.llama, models.moe).
+"""Autoregressive generation with a cache, for any config with a
+`decoder()` (models.gpt, models.llama, models.moe, models.hybrid).
 
 Parity role: the reference serves LLMs by hosting external engines
 (vLLM etc.) on its actors; here the decode path is native — a
@@ -20,10 +20,22 @@ import jax
 import jax.numpy as jnp
 
 from .decoder import decoder_hidden, empty_cache
+from .hybrid import MAMBA
 
 
 def init_cache(cfg, batch: int, max_len: int) -> List[Dict]:
-    return empty_cache(cfg.decoder(), cfg.n_layers, batch, max_len, cfg.dtype)
+    """An empty cache: each layer gets the state of its kind. A config
+    that names its layers' kinds (`layer_types`, models.hybrid) gets them
+    from there, as stand-ins that hold what decoder.empty_cache reads of
+    a layer; every layer of any other family is an attention layer."""
+    kinds = getattr(cfg, "layer_types", None)
+    if kinds is None:
+        layers = [{}] * cfg.n_layers
+    else:
+        mamba = {"in_proj": None, "conv_w": jax.ShapeDtypeStruct(
+            (cfg.mamba_conv_dim, cfg.mamba_d_conv), cfg.dtype)}
+        layers = [mamba if kind == MAMBA else {} for kind in kinds]
+    return empty_cache(cfg.decoder(), layers, batch, max_len, cfg.dtype)
 
 
 def cached_forward(params: Dict, tokens, cache: List[Dict],
@@ -87,11 +99,11 @@ def make_continuous_fns(cfg, max_len: int, batch: int):
     @functools.partial(jax.jit, donate_argnums=(2,))
     def insert_prefill(params, tokens, cache, slot, true_len):
         sub = [{k: jax.lax.dynamic_slice_in_dim(cl[k], slot, 1, axis=0)
-                for k in ("k", "v")} for cl in cache]
+                for k in cl} for cl in cache]
         with jax.named_scope("prefill"):
             logits, new_sub = cached_forward(params, tokens, sub, 0, cfg)
         out = [{k: jax.lax.dynamic_update_slice_in_dim(
-                    cl[k], ns[k], slot, axis=0) for k in ("k", "v")}
+                    cl[k], ns[k], slot, axis=0) for k in cl}
                for cl, ns in zip(cache, new_sub)]
         last = jax.lax.dynamic_slice_in_dim(
             logits[0], true_len - 1, 1, axis=0)[0]

@@ -9,5 +9,7 @@ and as the autodiff backward.
 
 from .attention import flash_attention, mha_reference  # noqa: F401
 from .grouped_matmul import grouped_matmul  # noqa: F401
-from .layers import rms_norm, rope, swiglu  # noqa: F401
+from .layers import (causal_conv1d, gated_rms_norm, rms_norm,  # noqa: F401
+                     rope, swiglu)
 from .loss import cross_entropy  # noqa: F401
+from .ssm_scan import ssm_scan, ssm_scan_plan, ssm_scan_reference  # noqa: F401

@@ -56,3 +56,29 @@ def swiglu(x, w_gate, w_up, w_down):
     gate = jax.nn.silu(jnp.einsum("...d,df->...f", x, w_gate))
     up = jnp.einsum("...d,df->...f", x, w_up)
     return jnp.einsum("...f,fd->...d", gate * up, w_down)
+
+
+def causal_conv1d(x, weight, bias, tail=None):
+    """Depthwise causal convolution along the sequence: x [b, L, C],
+    weight [C, K] (K taps, the last one on the current position), bias
+    [C]: y_t = bias + sum_k weight[:, k] x_{t-K+1+k}, with `tail`
+    [b, K-1, C] the inputs before x (zeros where None). K shifted
+    multiply-adds in float32, no kernel. Returns (y like x, the last K-1
+    inputs: the tail a cache hands to the next call)."""
+    K = weight.shape[1]
+    L = x.shape[1]
+    if tail is None:
+        tail = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
+    padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = weight.astype(jnp.float32)
+    y = bias.astype(jnp.float32)
+    for k in range(K):
+        y = y + padded[:, k:k + L].astype(jnp.float32) * w[:, k]
+    return y.astype(x.dtype), padded[:, L:]
+
+
+def gated_rms_norm(y, gate, weight, eps: float = NORM_EPS):
+    """Mamba-2's output norm with one group: the gate first, then ONE
+    RMSNorm over all channels, rmsnorm(y * silu(gate); weight)."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    return rms_norm(gated, weight, eps).astype(y.dtype)

@@ -102,14 +102,24 @@ DEVICE_SCOPES: Dict[str, str] = {
     "grouped_matmul_drhs": "ops/grouped_matmul.py _tgmm, the _tgmm_kernel "
                            "pallas_call: the gradient by the experts' "
                            "weights",
+    "ssm_scan_fwd": "ops/ssm_scan.py _scan_forward_call, the _ssm_fwd_kernel "
+                    "pallas_call: a Mamba-2 layer's chunked selective "
+                    "scan, y and one state a chunk",
+    "ssm_scan_bwd": "ops/ssm_scan.py _scan_backward_call, the _ssm_bwd_kernel "
+                    "pallas_call: every gradient of the scan, chunks last "
+                    "to first",
+    "ssm_conv": "models/decoder.py mamba2: the causal depthwise "
+                "convolution over x | B | C and its silu",
+    "ssm_gate_norm": "models/decoder.py mamba2: y * silu(z) and the one "
+                     "RMSNorm over the inner width",
     "moe_route": "parallel/moe.py dropless_moe_layer: float32 router, "
                  "top-k, the sort of the assignments by expert, the "
                  "per-expert counts and the gather of the rows",
     "moe_combine": "parallel/moe.py dropless_moe_layer: the experts' rows "
                    "back in token order and their weighted sum",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
-              "every decoder family (gpt, llama, moe), in the train step "
-              "and under prefill / decode alike",
+              "every decoder family (gpt, llama, moe, hybrid), in the train "
+              "step and under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "
             "gradient in one pass",
@@ -139,7 +149,10 @@ def kernel_calls(compiled_text: str) -> Dict[str, int]:
     `calls["flash_attention_fwd"] - calls["flash_attention_dq"]` are the
     forward calls the step repeats: 6 + 2 in OLMoE's two-layer step while
     its blocks kept nothing, 0 since they keep what
-    models/decoder.py KEPT_UNDER_REMAT names (PERF.md §6, PR 28)."""
+    models/decoder.py KEPT_UNDER_REMAT names (PERF.md §6, PR 28). The
+    scan kernels count the same way: `calls["ssm_scan_fwd"] -
+    calls["ssm_scan_bwd"]`, 0 in granite-4.0-h-micro's step of nine
+    Mamba-2 layers (9 and 9, not 18 and 9)."""
     calls: Dict[str, int] = {}
     for line in compiled_text.splitlines():
         name, eq, rest = line.strip().partition(" = ")

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import pytest
 
 import ray_tpu
-from ray_tpu.models import (GPTConfig, LlamaConfig, MoEConfig, gpt_init,
+from ray_tpu.models import (GPTConfig, HybridConfig, LlamaConfig, MoEConfig,
+                            gpt_init, make_hybrid_train_step,
                             make_llama_train_step, make_moe_train_step,
                             make_train_step)
 from ray_tpu.util import profiling
@@ -193,6 +194,10 @@ _COMPILED = "\n".join([
     "f32[64,4096,128]{2,1,0}) " + _CALL,
     "  ROOT %flash_attention_dkv.1 = (bf16[8,128]{1,0}, bf16[8,128]{1,0}) "
     + _CALL,
+    "  %ssm_scan_fwd.3 = (bf16[1,16384,4096]{2,1,0}, "
+    "f32[1,64,32,128,128]{4,3,2,1,0}) " + _CALL,
+    "  %ssm_scan_bwd.3 = (bf16[1,16384,4096]{2,1,0}, f32[8,128]{1,0}) "
+    + _CALL,
     "  %a_kernel_of_no_scope.7 = f32[8,128]{1,0} " + _CALL,
     '  %fusion.6 = bf16[8,128]{1,0} fusion(%a), kind=kLoop, calls=%fused',
     '  %custom-call.3 = f32[8]{0} custom-call(%a), '
@@ -204,6 +209,7 @@ def test_kernel_calls_counts_mosaic_calls_by_scope():
     calls = profiling.kernel_calls(_COMPILED)
     assert calls == {"grouped_matmul_fwd": 3, "grouped_matmul_dlhs": 1,
                      "flash_attention_fwd": 1, "flash_attention_dkv": 1,
+                     "ssm_scan_fwd": 1, "ssm_scan_bwd": 1,
                      "a_kernel_of_no_scope": 1}
     assert sum(calls.values()) == _COMPILED.count('"tpu_custom_call"')
     assert profiling.kernel_calls("") == {}
@@ -228,6 +234,7 @@ def test_annotate_keeps_a_process_off_jax():
 
 
 ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
+SCAN_KERNELS = {"_ssm_fwd_kernel", "_ssm_bwd_kernel"}
 
 
 @pytest.mark.parametrize("make_step,cfg,batch,kernels", [
@@ -249,7 +256,14 @@ ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
                n_experts=4, experts_per_token=2, d_expert=128,
                max_seq_len=256), 2,
      ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
-], ids=["tiny", "gpt2-small", "llama", "moe"])
+    # One Mamba-2 layer and one attention layer in one stack.
+    (make_hybrid_train_step,
+     HybridConfig(vocab_size=512, d_model=128, n_heads=2, n_kv_heads=1,
+                  layer_types=("mamba", "attention"), d_ff=256,
+                  mamba_n_heads=4, mamba_d_head=64, mamba_d_state=128,
+                  mamba_chunk_size=128, max_seq_len=256), 2,
+     ATTENTION_KERNELS | SCAN_KERNELS),
+], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -266,7 +280,13 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
     for kernel in ("fwd", "dq", "dkv"):
         assert re.search(r'loc\("(?:[^"]*/)?flash_attention_%s/pallas_call"'
                          % kernel, text), kernel
-    for scope in ("layers", "loss", "optimizer_update"):
+    scopes = ["layers", "loss", "optimizer_update"]
+    if kernels >= SCAN_KERNELS:
+        for kernel in ("fwd", "bwd"):
+            assert re.search(r'loc\("(?:[^"]*/)?ssm_scan_%s/pallas_call"'
+                             % kernel, text), kernel
+        scopes += ["ssm_conv", "ssm_gate_norm"]
+    for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
 

@@ -51,3 +51,19 @@ class TestProfiling:
         from ray_tpu._private.state import get_node
         spans = get_node().gcs.spans()
         assert any(s["name"] == "my-section" for s in spans)
+
+    def test_the_scan_kernels_have_scopes_and_are_counted(self):
+        """The selective scan's two kernels and the two element-wise
+        stages of a Mamba-2 layer are in the table of device scopes, and
+        the static counter counts the kernels under them."""
+        from ray_tpu.util import profiling
+        assert {"ssm_scan_fwd", "ssm_scan_bwd", "ssm_conv",
+                "ssm_gate_norm"} <= set(profiling.DEVICE_SCOPES)
+        call = ('custom-call(%a), custom_call_target="tpu_custom_call"')
+        text = "\n".join(
+            [f"  %ssm_scan_fwd.{i} = bf16[8,128]{{1,0}} {call}"
+             for i in range(9)]
+            + [f"  %transpose_jvp_ssm_scan_bwd_.{i} = bf16[8,128]{{1,0}} "
+               f"{call}" for i in range(9)])
+        assert profiling.kernel_calls(text) == {"ssm_scan_fwd": 9,
+                                                "ssm_scan_bwd": 9}
