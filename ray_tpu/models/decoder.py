@@ -260,19 +260,32 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
 # `attention` above, ops/attention.py and parallel/moe.py give their
 # values (a name lowers to nothing where no jax.checkpoint is round it).
 # Kept: what a Pallas kernel or a gather over a permutation made and the
-# backward reads (attention's `out` and `lse`; the gate and up grouped
-# matmuls' rows; the experts' rows back in token order); what the
-# attention's backward reads besides, q, k, v and the projection they are
-# normed and rotated from (0.2 GB each at OLMoE's 16,384 tokens, for 4.8
-# and 4.2 ms a step); and the router's probabilities, because rows kept
-# in sorted order must meet the same order again (parallel/moe.py). Made
-# again: the block's two norms and its output projection, the float32
-# router up to its probabilities, the top-k and sorts, the dispatch
-# gather, silu(gate) * up; the down matmul's output is read by nothing
-# once the unsorted rows are kept. What is kept scales with the tokens as
+# backward reads (attention's `out` and `lse`; the experts' dispatched rows
+# and their gate and up grouped matmuls); what the attention's backward
+# reads besides, q, k, v and the projection they are normed and rotated
+# from (0.2 GB each at OLMoE's 16,384 tokens, for 4.8 and 4.2 ms a step);
+# and the router's probabilities, because rows kept in sorted order must
+# meet the same order again (parallel/moe.py). Made again: the block's two
+# norms and its output projection, the float32 router up to its
+# probabilities, the top-k and sorts, w * silu(gate) * up.
+# The routed experts' three are residuals of their one gradient rule
+# (parallel/moe.py `_experts`) and of nothing else, so jax.checkpoint puts
+# no `reduce_precision` copy after them (6.6 ms a step while the forward
+# read the kept values too). Of a layer's [T*k, d] values exactly one is
+# kept, the dispatched rows `moe_xs` (537 MB a layer at 16,384 tokens):
+# gathered again from the T tokens' rows instead they cost 4.3 ms a
+# gather, not the forward's 0.83 (XLA holds the source in fast memory
+# there and not here) -- `olmoe-train-1chip`'s step 275.77 ms against
+# 267.33 with them kept, for 11.15 against 11.71 GB by XLA's analysis
+# (the parent of PR 30: 283.97 ms, 11.69 GB). No experts' output is kept:
+# the rule multiplies the router's weights in ahead of the down matmul, so
+# its backward needs neither the sorted nor the unsorted rows; with the
+# weights after it and the sorted rows kept for their gradient the step
+# took 269.83 ms and 12.42 GB (all four: PERF.md §6, PR 30, one chip call,
+# the host's clock over 12 steps). What is kept scales with the tokens as
 # the block's own live set does, and is alive at the peak anyway, in the
-# layer being differentiated: 0.5 GB over keeping nothing at OLMoE's
-# 16,384 tokens, where keeping everything does not fit (PERF.md §6, PR 28).
+# layer being differentiated: keeping everything does not fit at OLMoE's
+# 16,384 tokens (PERF.md §6, PR 28).
 # Of a Mamba-2 layer, by the same rule: what the scan kernel made and a
 # backward pass reads, the state each chunk left (its backward kernel's)
 # and y (the gated norm's, after it) (ops/ssm_scan.py; 0.13 GB each a
@@ -281,7 +294,7 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
-    "moe_probs", "moe_gate", "moe_up", "moe_unsorted",
+    "moe_probs", "moe_xs", "moe_gate", "moe_up",
     "ssm_scan_y", "ssm_scan_states")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)

@@ -262,8 +262,10 @@ def _fwd(lhs, rhs, group_sizes):
     return _forward(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
 
 
-def _bwd(residuals, g):
-    lhs, rhs, group_sizes = residuals
+def grouped_matmul_grads(lhs, rhs, group_sizes, g):
+    """(dlhs, drhs) of `grouped_matmul(lhs, rhs, group_sizes)` for the
+    output's cotangent `g`: its own gradient rule, and what a rule that
+    holds several grouped matmuls (parallel/moe.py) calls for each."""
     if attention._on_tpu():
         dlhs = _gmm(g, rhs, group_sizes, transpose_rhs=True)
         drhs = _tgmm(lhs, g, group_sizes, rhs.dtype)
@@ -271,7 +273,12 @@ def _bwd(residuals, g):
         _, vjp = jax.vjp(
             lambda a, b: _ragged_dot(a, b, group_sizes), lhs, rhs)
         dlhs, drhs = vjp(g)
-    return dlhs, drhs, None
+    return dlhs, drhs
+
+
+def _bwd(residuals, g):
+    lhs, rhs, group_sizes = residuals
+    return (*grouped_matmul_grads(lhs, rhs, group_sizes, g), None)
 
 
 grouped_matmul.defvjp(_fwd, _bwd)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.grouped_matmul import grouped_matmul
+from ..ops.grouped_matmul import grouped_matmul, grouped_matmul_grads
 
 
 def top2_gating(logits, capacity: int):
@@ -143,43 +143,101 @@ def _rows(x, idx):
     return x.at[idx].get(mode="promise_in_bounds")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, perm, inv, k: int):
+def _spread(x, perm, k: int):
     """x [T, d] -> [T*k, d]: row i is the token of sorted assignment i,
-    x[perm[i] // k]. `inv` is perm's inverse: the cotangent is a gather by
-    it and a sum over each token's k copies, never a scatter-add."""
+    x[perm[i] // k]. A gather from T rows."""
     return _rows(x, perm // k)
 
 
-def _dispatch_fwd(x, perm, inv, k):
-    return _rows(x, perm // k), inv
+def _sum_back(rows, inv, k: int):
+    """Sorted rows [T*k, d] -> [T, d]: each token's k rows, found by
+    perm's inverse `inv`, summed in float32. The transpose of `_spread`
+    as a gather, never a scatter-add."""
+    per_token = _rows(rows, inv).reshape(-1, k, rows.shape[-1])
+    return jnp.sum(per_token.astype(jnp.float32), axis=1).astype(rows.dtype)
 
 
-def _dispatch_bwd(k, inv, g):
-    per_token = _rows(g, inv).reshape(-1, k, g.shape[-1])
-    dx = jnp.sum(per_token.astype(jnp.float32), axis=1).astype(g.dtype)
-    return dx, None, None
+def _permuted(values, inv):
+    """values[perm] for the permutation whose inverse is `inv`, as a sort
+    of (inv, values) by `inv`: T*k scalars gathered one by one take 1.1 ms
+    on the chip, the sort 0.1 (PERF.md §6, PR 30)."""
+    return lax.sort((inv, values), num_keys=1)[1]
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _experts_fwd(x, weights, w_gate, w_up, w_down, perm, inv, counts):
+    k = weights.shape[1]
+    with jax.named_scope("moe_route"):
+        xs = _spread(x, perm, k)                              # [T*k, d]
+        w_sorted = _permuted(weights.reshape(-1), inv)        # [T*k]
+    gate = grouped_matmul(xs, w_gate, counts)
+    up = grouped_matmul(xs, w_up, counts)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+              * w_sorted[:, None]).astype(x.dtype)
+    ys = grouped_matmul(hidden, w_down, counts)               # [T*k, d]
+    with jax.named_scope("moe_combine"):
+        out = _sum_back(ys, inv, k)
+    # The names are for a rematerialised block (models/decoder.py
+    # KEPT_UNDER_REMAT). Each names a copy that only the backward rule
+    # reads, the forward above going on from the unnamed value: a kept
+    # value the forward also reads gets a `reduce_precision` from
+    # jax.checkpoint, a plain copy after a kernel or a gather.
+    return out, (checkpoint_name(xs, "moe_xs"),
+                 checkpoint_name(gate, "moe_gate"),
+                 checkpoint_name(up, "moe_up"),
+                 w_sorted, w_gate, w_up, w_down, perm, inv, counts)
+
+
+def _experts_bwd(residuals, dout):
+    xs, gate, up, w_sorted, w_gate, w_up, w_down, perm, inv, counts = residuals
+    k, f32 = inv.shape[0] // dout.shape[0], jnp.float32
+    dys = _spread(dout, perm, k)              # the combine is a plain sum
+    gate, up, w = gate.astype(f32), up.astype(f32), w_sorted[:, None]
+    sig = jax.nn.sigmoid(gate)
+    act = gate * sig                                          # silu(gate)
+    hidden = act * up                                         # unweighted
+    dhidden, dw_down = grouped_matmul_grads(
+        (hidden * w).astype(dout.dtype), w_down, counts, dys)
+    dhidden = dhidden.astype(f32)
+    dw_sorted = jnp.sum(dhidden * hidden, axis=-1)
+    dhidden = dhidden * w
+    dgate = dhidden * up * (sig * (1.0 + gate * (1.0 - sig)))
+    dup = dhidden * act
+    dxs_gate, dw_gate = grouped_matmul_grads(
+        xs, w_gate, counts, dgate.astype(dout.dtype))
+    dxs_up, dw_up = grouped_matmul_grads(
+        xs, w_up, counts, dup.astype(dout.dtype))
+    dx = _sum_back(dxs_gate + dxs_up, inv, k)
+    dweights = _permuted(dw_sorted, perm).reshape(-1, k)
+    return dx, dweights, dw_gate, dw_up, dw_down, None, None, None
 
 
 @jax.custom_vjp
-def _unsort(rows, perm, inv):
-    """rows[inv]: the sorted rows back in assignment order (token-major).
-    The cotangent is the gather by `perm`."""
-    return _rows(rows, inv)
+def _experts(x, weights, w_gate, w_up, w_down, perm, inv, counts):
+    """The routed experts of x [T, d] for router weights [T, k] float32:
+    dispatch, three grouped matmuls with silu(gate) * up between them, and
+    the combine, as one function with one gradient rule, written so that
+    the fewest rows move. `perm` sorts the T*k assignments (t*k + j) by
+    expert, `inv` is its inverse, `counts` [E] the experts' rows.
+
+        xs[i]  = x[perm[i] // k]                       a gather from T rows
+        ys[i]  = (w[perm[i]] * silu(xs[i] G[e]) * (xs[i] U[e])) D[e]
+        out[t] = sum_j ys[inv[t*k + j]]                in float32
+
+    The weights go in ahead of the down matmul, which is linear in its
+    rows, so the combine is an unweighted sum and its transpose is the
+    dispatch itself (`dys` = `_spread(dout)`, from T rows: no [T, k, d]
+    product, no gather from T*k rows on the way in); the dispatch's
+    transpose is `_sum_back`; the weights' gradient is a row dot product
+    on the f-wide side and T*k scalars put back by a sort. No [T*k, d]
+    value but `xs` is a residual. Under remat `xs`, `gate` and `up` are
+    kept by name and the rest is made again from the kept router
+    probabilities; models/decoder.py KEPT_UNDER_REMAT has what the chip
+    said of gathering `xs` again and of keeping the sorted `ys` instead."""
+    return _experts_fwd(x, weights, w_gate, w_up, w_down, perm, inv,
+                        counts)[0]
 
 
-def _unsort_fwd(rows, perm, inv):
-    return _rows(rows, inv), perm
-
-
-def _unsort_bwd(perm, g):
-    return _rows(g, perm), None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+_experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
@@ -201,7 +259,7 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
     `router_z_sq_sum` (sum over tokens of logsumexp(logits)**2): what the
     load-balancing and z losses are made of, summable over layers.
     """
-    t, d = x.shape
+    t = x.shape[0]
     e = router_w.shape[-1]
     k = experts_per_token
     with jax.named_scope("moe_route"):
@@ -229,23 +287,7 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
         counts = jnp.sum(
             experts.reshape(-1, 1) == jnp.arange(e, dtype=experts.dtype),
             axis=0, dtype=jnp.int32)
-        xs = _dispatch(x, perm, inv, k)                       # [T*k, d]
-    # The names are for a rematerialised block (models/decoder.py
-    # KEPT_UNDER_REMAT): the gate and up rows and the unsorted rows are
-    # what a kernel or a gather over a permutation made and the backward
-    # reads. `xs`, `hidden` and `ys` have none: a gather from the T
-    # tokens' rows and an elementwise pass make the first two again, and
-    # nothing reads `ys` once the unsorted rows are kept.
-    gate = checkpoint_name(grouped_matmul(xs, w_gate, counts), "moe_gate")
-    up = checkpoint_name(grouped_matmul(xs, w_up, counts), "moe_up")
-    hidden = (jax.nn.silu(gate.astype(jnp.float32))
-              * up.astype(jnp.float32)).astype(x.dtype)
-    ys = grouped_matmul(hidden, w_down, counts)               # [T*k, d]
-    with jax.named_scope("moe_combine"):
-        per_token = checkpoint_name(
-            _unsort(ys, perm, inv), "moe_unsorted").reshape(t, k, d)
-        out = jnp.sum(per_token.astype(jnp.float32) * weights[:, :, None],
-                      axis=1).astype(x.dtype)
+    out = _experts(x, weights, w_gate, w_up, w_down, perm, inv, counts)
     stats = {"expert_tokens": counts,
              "router_prob_sum": jnp.sum(probs, axis=0),
              "router_z_sq_sum": jnp.sum(jnp.square(lse))}

@@ -114,9 +114,10 @@ DEVICE_SCOPES: Dict[str, str] = {
                      "RMSNorm over the inner width",
     "moe_route": "parallel/moe.py dropless_moe_layer: float32 router, "
                  "top-k, the sort of the assignments by expert, the "
-                 "per-expert counts and the gather of the rows",
-    "moe_combine": "parallel/moe.py dropless_moe_layer: the experts' rows "
-                   "back in token order and their weighted sum",
+                 "per-expert counts and (in the experts' rule) the gather "
+                 "of the rows and the weights in sorted order",
+    "moe_combine": "parallel/moe.py _experts: the experts' weighted rows "
+                   "back in token order and their sum",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
               "every decoder family (gpt, llama, moe, hybrid), in the train "
               "step and under prefill / decode alike",

@@ -4,7 +4,8 @@ at its published widths (d 2048, 16 heads of 128, 64 experts of 1024, 8 a
 token, V 50,304), depth 2, B=4 x S=4096, remat on, the default optimizer —
 compiles for one chip, calls the attention and the grouped-matmul kernels
 under the program's scopes, each forward once though remat is on (the
-blocks keep what the kernels made), holds no [T, E, C] dispatch tensor and
+blocks keep what the kernels made), gathers the T*k rows eight times and
+copies none of them to keep it, holds no [T, E, C] dispatch tensor and
 no float32 copy of an expert tensor, and fits the chip by XLA's memory
 analysis (PERF.md §4 has the figure). The topology is described inside a
 fixture (see the on-chip-measurement guide); under several test workers
@@ -100,13 +101,22 @@ def test_step_calls_the_attention_and_grouped_matmul_kernels(step):
     assert all(any(s in r for s in scopes) for r in rows), rows
 
 
+def _row_gathers(text):
+    """(gathers that make a bf16[131072, 2048] value, those of them whose
+    operand has 131,072 rows) in a compiled program's text."""
+    shape = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    operands = re.findall(
+        r"= bf16\[131072,2048\]\S* gather\(%([\w.\-]+),", text)
+    return len(operands), sum(
+        shape[name].startswith("bf16[131072,") for name in operands)
+
+
 def test_no_forward_kernel_runs_twice_a_step(step):
     """Remat is on in this cell, and a block keeps what its kernels and
-    its row unsort made (models/decoder.py KEPT_UNDER_REMAT): per layer
+    its row dispatch made (models/decoder.py KEPT_UNDER_REMAT): per layer
     three grouped matmuls and one attention, each forward once. While the
     blocks kept nothing (until PR 28) the counter read grouped_matmul_fwd
-    12 and flash_attention_fwd 4, eight forward calls run twice, and
-    there were 12 of the gathers below."""
+    12 and flash_attention_fwd 4, eight forward calls run twice."""
     from ray_tpu.util import profiling
 
     text = step[1].as_text()
@@ -118,11 +128,23 @@ def test_no_forward_kernel_runs_twice_a_step(step):
     # forward kernels run twice: 0 (6 + 2 before)
     assert (calls["grouped_matmul_fwd"] - calls["grouped_matmul_dlhs"]
             + calls["flash_attention_fwd"] - calls["flash_attention_dq"]) == 0
-    # Gathers of the T*k rows: a layer's dispatch, unsort and their two
-    # cotangents, and the dispatch made again for the backward pass; the
-    # unsort is not made again.
-    row_gathers = re.findall(r"= bf16\[131072,2048\]\S* gather\(", text)
-    assert len(row_gathers) <= 10, len(row_gathers)
+    # Gathers that make the T*k rows, and those of them whose operand has
+    # T*k rows too (4.5 ms each on the chip; from the T tokens' rows 0.83
+    # to 4.3): a layer's dispatch and its rows back in token order, and in
+    # the backward pass the output's cotangent spread from the tokens' rows
+    # and the dispatch's cotangent brought back. The routed experts' one
+    # gradient rule (parallel/moe.py `_experts`) gathers no [T, k, d]
+    # product, and `moe_xs` is kept, so the dispatch is not made again:
+    # 8 and 4 (10 and 6 before PR 30, 12 while the blocks kept nothing).
+    made, from_rows = _row_gathers(text)
+    assert made <= 8 and from_rows <= 4, (made, from_rows)
+    # A kept value the forward pass reads too gets a `reduce_precision`
+    # from jax.checkpoint, on the chip a plain copy of it; the experts'
+    # kept values are residuals of their rule and of nothing else. (12
+    # such lines before PR 30: gate, up and the unsorted rows of each
+    # layer, in a fused computation and at its call.)
+    assert not re.findall(
+        r"= \w+\[131072,\d+\]\S* reduce-precision\(", text)
 
 
 def test_step_holds_no_dispatch_tensor_and_no_float32_expert_copy(step):
@@ -142,6 +164,8 @@ def test_step_fits_a_chip(step, record_property):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     record_property("olmoe_b4_s4096_bytes", total)
+    # 11.71 GB since PR 30 (11.69 before it: `moe_xs` is kept where the
+    # unsorted rows were); the runtime's peak on the chip is in PERF.md §2.
     print(f"olmoe-train-1chip step: {total / 1e9:.2f} GB "
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")

@@ -213,6 +213,88 @@ def test_norm_topk_prob_false_leaves_the_weights_unnormalised(norm):
     assert _rel(out, normed * scale) < 1e-5
 
 
+def _written_out_layer(x, router, gate, up, down, k, norm):
+    """The layer's equations in jnp alone, for plain autodiff: no
+    custom_vjp, no kernel, no inverse permutation; the weights multiply
+    the experts' rows after the down matmul, as the docstring's formula
+    has them. The program's own rounding points: rows in x's dtype,
+    products accumulated in float32."""
+    t, d = x.shape
+    f32 = jnp.float32
+
+    def matmul(rows, w):
+        return jax.lax.ragged_dot(rows, w, counts,
+                                  preferred_element_type=f32).astype(x.dtype)
+
+    logits = jnp.dot(x.astype(f32), router.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if norm:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    perm = jnp.argsort(experts.reshape(-1), stable=True)
+    counts = jnp.bincount(experts.reshape(-1),
+                          length=router.shape[1]).astype(jnp.int32)
+    xs = x[perm // k]
+    hidden = (jax.nn.silu(matmul(xs, gate).astype(f32))
+              * matmul(xs, up).astype(f32)).astype(x.dtype)
+    ys = matmul(hidden, down)
+    per_token = jnp.zeros_like(ys).at[perm].set(ys).reshape(t, k, d)
+    out = jnp.sum(per_token.astype(f32) * weights[:, :, None], axis=1)
+    return out.astype(x.dtype), counts
+
+
+# The routed experts have one gradient rule written by hand
+# (parallel/moe.py `_experts`), and the driver's `correct` cannot see a
+# wrong gradient (PERF.md §7, item 13): so the rule against plain autodiff
+# of the layer written out, on a router that starves one expert and
+# favours two. float32: the same sums in another order (and the weights
+# multiplied in before the down matmul, not after), 5e-7 of a leaf's scale
+# seen. bf16: the rule rounds w * silu(gate) * up where the written-out
+# layer rounds silu(gate) * up, 2^-8 a rounding; they differ by up to
+# 7.6e-3 of a leaf's scale, each within 7e-3 of the same layer in float32.
+@pytest.mark.parametrize("norm", [False, True])
+@pytest.mark.parametrize("kernels", ["interpreted", "ragged_dot"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 3e-2)])
+def test_experts_rule_equals_autodiff_of_the_written_out_layer(
+        dtype, tol, kernels, norm, monkeypatch):
+    if kernels == "interpreted":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(gm, "_TILES", (128, 128, 128))
+    t, e, k = 96, 8, 3
+    a = _layer_inputs(t=t, e=e, dtype=dtype, seed=4)
+    a["x"] = a["x"].at[:, 0].set(4.0)
+    a["router"] = a["router"].at[0, :].set(0.0).at[0, 5].set(-5.0) \
+        .at[0, jnp.array([1, 2])].set(0.5)
+    ct = jax.random.normal(jax.random.PRNGKey(9), a["x"].shape, dtype)
+    args = (a["x"], a["router"], a["gate"], a["up"], a["down"])
+
+    def program(*xs):
+        out, stats = dropless_moe_layer(*xs, experts_per_token=k,
+                                        norm_topk_prob=norm)
+        return out, stats["expert_tokens"]
+
+    with jax.default_matmul_precision("highest"):
+        (out, counts), vjp = jax.vjp(program, *args)
+        (want, want_counts), want_vjp = jax.vjp(
+            lambda *xs: _written_out_layer(*xs, k, norm), *args)
+        zero = np.zeros(counts.shape, jax.dtypes.float0)
+        grads, want_grads = vjp((ct, zero)), want_vjp((ct, zero))
+    counts = np.asarray(counts)
+    np.testing.assert_array_equal(counts, np.asarray(want_counts))
+    assert counts[5] == 0 and counts.sum() == t * k
+    assert counts.max() > 1.5 * t * k / e
+    assert out.dtype == dtype and _rel(out, want) < tol
+    names = ("x", "router (through w)", "expert_gate", "expert_up",
+             "expert_down")
+    errs = {n: _rel(g, w) for n, g, w in zip(names, grads, want_grads,
+                                             strict=True)}
+    assert max(errs.values()) < tol, errs
+    assert all(g.dtype == x.dtype and float(jnp.abs(g).max()) > 0
+               for g, x in zip(grads, args))
+    assert float(jnp.abs(grads[2][5]).max()) == 0.0    # the starved expert
+
+
 def _all_avals(jaxpr):
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
@@ -346,17 +428,22 @@ def _count_kernels_and_row_gathers(jaxpr, rows_shape, counts):
 
 
 # One block's gradient, traced with the kernels in it (no chip needed to
-# trace). Without remat a layer is one attention and three grouped matmuls
-# forward, each with its two gradients, and four [T*k, d] gathers (dispatch,
-# unsort, and their cotangents). With it the block may add the dispatch
-# gather and nothing else: no kernel's forward runs twice. Keeping nothing,
-# as before PR 28, gave 6 grouped_matmul_fwd, 2 flash_attention_fwd and 6
-# gathers here. And the backward pass sorts by the forward's choice of
-# experts: its top-k reads the kept probabilities. With the router made
-# again and the gate and up rows kept, every expert's weight gradient was
-# 20-90% off on the chip (PERF.md §6, PR 28): a flipped near tie shifts the
-# sorted rows, and no test on the CPU, where both passes round alike, sees it.
-@pytest.mark.parametrize("remat,row_gathers", [(True, 5), (False, 4)])
+# trace). A layer is one attention and three grouped matmuls forward, each
+# with its two gradients, and four [T*k, d] gathers: the dispatch and the
+# combine's rows back in token order, and in the backward pass the same
+# two with their roles exchanged (the output's cotangent spread over the
+# sorted rows from the T tokens' rows, the dispatch's cotangent summed
+# back) -- the routed experts' one gradient rule, parallel/moe.py
+# `_experts`. Remat adds nothing to that: no kernel's forward runs twice,
+# and the dispatched rows are kept (`moe_xs`), so there are 4 gathers where
+# PR 28's blocks made 5 (the dispatch again) and, keeping nothing, PR 27's
+# made 6 with 6 grouped_matmul_fwd and 2 flash_attention_fwd. And the
+# backward pass sorts by the forward's choice of experts: its top-k reads
+# the kept probabilities. With the router made again and the gate and up
+# rows kept, every expert's weight gradient was 20-90% off on the chip
+# (PERF.md §6, PR 28): a flipped near tie shifts the sorted rows, and no
+# test on the CPU, where both passes round alike, sees it.
+@pytest.mark.parametrize("remat,row_gathers", [(True, 4), (False, 4)])
 def test_no_forward_kernel_runs_twice_in_a_block(remat, row_gathers,
                                                  monkeypatch):
     from ray_tpu.ops import attention
@@ -377,11 +464,13 @@ def test_no_forward_kernel_runs_twice_in_a_block(remat, row_gathers,
 
 
 # What leaves a rematerialised block for its backward pass, besides its
-# arguments: the ten named values and nothing else. Of the four [T*k, d]
-# values of a layer (dispatched rows, down matmul's output, unsorted rows
-# and their product with the weights) one is kept, and it is the unsorted
-# rows: the test above shows the down matmul is not run again, which
-# keeping `xs` would need, and 5 gathers, where keeping `ys` gives 6.
+# arguments: the ten named values and nothing else. Of the [T*k, d] values
+# of a layer (dispatched rows, down matmul's output, its rows in token
+# order) one is kept, and it is the dispatched rows: the experts' rule
+# (parallel/moe.py `_experts`) multiplies the weights in ahead of the down
+# matmul, so its backward reads no output of the experts, and the chip
+# says gathering `xs` again costs more than keeping it (models/decoder.py
+# KEPT_UNDER_REMAT has the numbers).
 @pytest.mark.parametrize("dtype,short", [(jnp.bfloat16, "bf16"),
                                          (jnp.float32, "f32")])
 def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
@@ -408,14 +497,18 @@ def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
         heads, heads, heads, heads,     # flash_attention_q, _k, _v, _out
         f"f32[{b},{cfg.n_heads},{s},128]",              # flash_attention_lse
         f"f32[{b * s},{cfg.n_experts}]",                # moe_probs
+        f"{short}[{b * s * k},{d}]",                    # moe_xs
         f"{short}[{b * s * k},{cfg.d_expert}]",         # moe_gate
         f"{short}[{b * s * k},{cfg.d_expert}]",         # moe_up
-        f"{short}[{b * s * k},{d}]",                    # moe_unsorted
     ])
     # Remat puts a reduce_precision after a kept value the forward reads
-    # too, and the description then names that; the one that is a residual
-    # and nothing else still reads by name.
-    assert any("named 'flash_attention_lse'" in line for line in lines)
+    # too (a plain copy on the chip, after a kernel or a gather), and the
+    # description then names that; one that is a residual of a gradient
+    # rule and nothing else still reads by name: attention's lse and the
+    # routed experts' three.
+    for name in ("flash_attention_lse", "moe_xs", "moe_gate", "moe_up"):
+        assert any(f"named '{name}'" in line for line in lines), name
+    assert not any("named 'flash_attention_out'" in line for line in lines)
 
 
 # ---------------------------------------------------------------------------
