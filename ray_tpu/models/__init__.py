@@ -44,6 +44,14 @@ from .resnet import (  # noqa: F401
     resnet_init,
     resnet_param_axes,
 )
+from .sambay import (  # noqa: F401
+    SambaYConfig,
+    make_sambay_train_step,
+    sambay_forward,
+    sambay_init,
+    sambay_loss,
+    sambay_param_axes,
+)
 from .vit import (  # noqa: F401
     ViTConfig,
     make_classifier,

@@ -9,31 +9,55 @@ where it names them (ops.layers' own, 1/sqrt(head_dim), none where it
 does not), and what its embedding, its two residual branches and its
 logits are multiplied by (1 where it says nothing). Nothing here reads a
 config: one family differs from another by those values and by which
-weights a layer holds (`in_proj`: a Mamba-2 layer; else attention, from
-`wqkv` or `wq` + `wkv`, with `q_norm` or not), and by nothing else. A
-model's layers need not be alike: each picks its mixer by what it holds.
+weights a layer holds (`x_proj`: a Mamba-1 layer; `in_proj` without it: a
+Mamba-2 layer; `gmu_in`: a gated memory unit; `lambda_q1`: differential
+attention, over its own keys and values from `wqkv` or over another
+layer's from `wq` alone; else attention, from `wqkv` or `wq` + `wkv`, with
+`q_norm` or not; `ln1_b`: LayerNorm with bias where the others have
+RMSNorm), and by nothing else. A model's layers need not be alike: each
+picks its mixer by what it holds (`_mixer_of`).
+
+Nor need they be independent (SambaY, models.sambay): the layer stack
+carries two values forward besides x, `Shared`: the scan's output `m` of
+the last Mamba-1 layer so far, which every gated memory unit after it
+multiplies, and the keys and values of the last differential-attention
+layer that saw the whole sequence, which every cross-attention layer
+after it attends over. Both pass through `jax.checkpoint` as block inputs
+and outputs, and their gradients arrive from every reader. A
+differential-attention layer of its own keys is windowed (`dec.window`)
+while a Mamba-1 layer still follows it in the stack, and sees everything
+after the last one: which, `decoder_hidden` reads off the weights too.
 
     decoder_hidden      embedding, layer stack, final norm, head
-      attention | mamba2   the sequence mixers, (x, layer, dec, cache,
-                        start_pos) -> (y, new cache): attention is the
-                        flash kernel over the whole sequence with no
-                        cache, with one a write into it and a masked read
-                        of it; mamba2 the chunked scan (ops.ssm_scan) over
-                        the tokens given, from the cached state where
-                        there is one, and one step of the recurrence for a
-                        single token
-      gelu_mlp | swiglu_mlp | routed_experts   the channel mixers,
-                        (y, layer) -> (out, stats or None)
+      attention | mamba2 | mamba1 | gmu | diff_attention
+                        the sequence mixers, (x, layer, dec, cache,
+                        start_pos[, shared, index, window]) -> (y, new
+                        cache[, shared]): attention is the flash kernel
+                        over the whole sequence with no cache, with one a
+                        write into it and a masked read of it; mamba2 the
+                        chunked scan (ops.ssm_scan) over the tokens given,
+                        from the cached state where there is one, and one
+                        step of the recurrence for a single token; mamba1
+                        the same over ops.selective_scan; gmu no state at
+                        all; diff_attention two softmax maps a pair of
+                        heads, their difference times both heads' values
+      gelu_mlp | swiglu_mlp | fused_swiglu_mlp | routed_experts
+                        the channel mixers, (y, layer) -> (out, stats or
+                        None)
 
-Cache layout, per layer by its kind: attention {"k"|"v": [batch,
-n_kv_heads, max_len, head_dim]}; Mamba-2 {"conv": [batch, d_conv - 1,
-inner + 2 groups x state] the convolution's last inputs, "ssm": [batch,
-heads, head_dim, state] float32}, which does not grow.
+Cache layout, per layer by its kind: attention (differential too)
+{"k"|"v": [batch, n_kv_heads, max_len, head_dim]}; Mamba-2 {"conv":
+[batch, d_conv - 1, inner + 2 groups x state] the convolution's last
+inputs, "ssm": [batch, heads, head_dim, state] float32}, which does not
+grow; Mamba-1 {"conv": [batch, d_conv - 1, inner], "ssm": [batch, inner,
+state] float32}; a gated memory unit and a cross-attention layer hold
+nothing ({}): they read the tokens in flight and the other layer's cache.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
@@ -42,7 +66,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d, gated_rms_norm,
-                          rms_norm, rope, swiglu)
+                          layer_norm, rms_norm, rope, swiglu)
+from ..ops.selective_scan import selective_scan
 from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import dropless_moe_layer
 
@@ -66,6 +91,9 @@ class Decoder(NamedTuple):
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 256
+    # What a differential-attention layer of its own keys sees while a
+    # Mamba-1 layer still follows it: itself and the window - 1 before.
+    window: Optional[int] = None
 
 
 def gelu_mlp(y, layer):
@@ -76,6 +104,14 @@ def gelu_mlp(y, layer):
 
 def swiglu_mlp(y, layer):
     return swiglu(y, layer["w_gate"], layer["w_up"], layer["w_down"]), None
+
+
+def fused_swiglu_mlp(y, layer):
+    """SwiGLU from one input matrix: [gate | up] = y fc1, the gate first."""
+    gate, up = jnp.split(jnp.einsum("bsd,df->bsf", y, layer["fc1"]), 2,
+                         axis=-1)
+    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                      layer["fc2"]), None
 
 
 def routed_experts(y, layer, experts_per_token: int, norm_topk_prob: bool):
@@ -90,16 +126,43 @@ def routed_experts(y, layer, experts_per_token: int, norm_topk_prob: bool):
     return out.reshape(b, s, d), stats
 
 
+def _is_mamba1(layer) -> bool:
+    return "x_proj" in layer
+
+
 def _is_mamba2(layer) -> bool:
-    return "in_proj" in layer
+    return "in_proj" in layer and not _is_mamba1(layer)
+
+
+def _reads_shared(layer) -> bool:
+    """A gated memory unit, or differential attention over another
+    layer's keys and values: no state of its own."""
+    return "gmu_in" in layer or ("lambda_q1" in layer
+                                 and "wqkv" not in layer)
+
+
+def _norm(x, holder, name: str, eps: float):
+    """The norm whose weight `holder` has under `name`: LayerNorm where
+    it has a bias beside it, RMSNorm where not."""
+    if name + "_b" in holder:
+        return layer_norm(x, holder[name], holder[name + "_b"], eps)
+    return rms_norm(x, holder[name], eps)
 
 
 def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
     """The state of each of `layers` (a model's `params["layers"]`, or
     anything that holds their keys), by its kind: an attention layer its
     kv heads up to `max_len`, not their copies across a group; a Mamba-2
-    layer its convolution's last inputs and its state."""
+    or Mamba-1 layer its convolution's last inputs and its state; a layer
+    that reads what another made, nothing."""
     def one(layer):
+        if _reads_shared(layer):
+            return {}
+        if _is_mamba1(layer):
+            inner, taps = layer["conv_w"].shape
+            return {"conv": jnp.zeros((batch, taps - 1, inner), dtype),
+                    "ssm": jnp.zeros((batch, inner,
+                                      layer["A_log"].shape[1]), jnp.float32)}
         if _is_mamba2(layer):
             conv_dim = (dec.ssm_heads * dec.ssm_head_dim
                         + 2 * dec.ssm_groups * dec.ssm_state)
@@ -119,13 +182,10 @@ def _across_group(t, group: int):
     return t if group == 1 else jnp.repeat(t, group, axis=1)
 
 
-def _cached_attention(q, k, v, cache, sp, group: int, sm_scale):
-    """Write k, v [b, kvh, L, hd] into the cache at positions sp + [0, L)
-    and attend q [b, h, L, hd] over the cache up to each query's own
-    position. Only the write and the mask specialize on whether `sp` is
-    a scalar or one position a row."""
-    b, _, L, hd = q.shape
-    max_len = cache["k"].shape[-2]
+def _write_cache(cache, k, v, sp):
+    """k, v [b, kvh, L, hd] written into the cache at positions sp +
+    [0, L), `sp` a scalar or one position a row."""
+    b, _, L, _ = k.shape
     if sp.ndim == 1:
         rows = jnp.arange(b)[:, None]                    # (b, 1)
         cols = sp[:, None] + jnp.arange(L)[None]         # (b, L)
@@ -140,22 +200,42 @@ def _cached_attention(q, k, v, cache, sp, group: int, sm_scale):
             cache["k"], k.astype(cache["k"].dtype), (0, 0, sp, 0))
         v_cache = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, 0, sp, 0))
+    return {"k": k_cache, "v": v_cache}
 
+
+def _attend_cache(q, k_all, v_all, sp, sm_scale, window=None):
+    """q [b, h, L, hd] at positions sp + [0, L) over k_all, v_all
+    [b, h, max_len, .], each query up to its own position (and, under a
+    `window`, no further back than window - 1): plain masked softmax."""
+    L, hd = q.shape[-2:]
+    max_len = k_all.shape[-2]
     scale = hd ** -0.5 if sm_scale is None else sm_scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   _across_group(k_cache, group).astype(jnp.float32)) * scale
+                   k_all.astype(jnp.float32)) * scale
     q_iota = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 0)
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 1)
     if sp.ndim == 1:
         q_pos = sp[:, None, None] + q_iota[None]         # (b, L, max)
-        mask = (k_pos[None] <= q_pos)[:, None]           # (b,1,L,max)
+        k_pos, lead = k_pos[None], (slice(None), None)   # (b,1,L,max)
     else:
-        mask = (k_pos <= sp + q_iota)[None, None]        # (1,1,L,max)
-    s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
+        q_pos, lead = sp + q_iota, (None, None)          # (1,1,L,max)
+    mask = k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    s = jnp.where(mask[lead], s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
-    v_all = _across_group(v_cache, group)
-    attn = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v_all.dtype), v_all)
-    return attn, {"k": k_cache, "v": v_cache}
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v_all.dtype), v_all)
+
+
+def _cached_attention(q, k, v, cache, sp, group: int, sm_scale):
+    """Write k, v [b, kvh, L, hd] into the cache at positions sp + [0, L)
+    and attend q [b, h, L, hd] over the cache up to each query's own
+    position. Only the write and the mask specialize on whether `sp` is
+    a scalar or one position a row."""
+    new_cache = _write_cache(cache, k, v, sp)
+    attn = _attend_cache(q, _across_group(new_cache["k"], group),
+                         _across_group(new_cache["v"], group), sp, sm_scale)
+    return attn, new_cache
 
 
 def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
@@ -171,7 +251,7 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
     def heads(t, n):
         return t.reshape(b, L, n, hd).transpose(0, 2, 1, 3)
 
-    y = rms_norm(x, layer["ln1"], dec.norm_eps)
+    y = _norm(x, layer, "ln1", dec.norm_eps)
     if "wqkv" in layer:
         q, k, v = jnp.split(checkpoint_name(
             jnp.einsum("bsd,de->bse", y, layer["wqkv"]), "attention_qkv"),
@@ -222,7 +302,7 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
     b, L, d = x.shape
     H, P, N, G = dec.ssm_heads, dec.ssm_head_dim, dec.ssm_state, dec.ssm_groups
     inner, bc = H * P, G * N
-    y = rms_norm(x, layer["ln1"], dec.norm_eps)
+    y = _norm(x, layer, "ln1", dec.norm_eps)
     z, xbc, dt = jnp.split(
         jnp.einsum("bsd,de->bse", y, layer["in_proj"]),
         [inner, 2 * inner + 2 * bc], axis=-1)
@@ -254,6 +334,149 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
                             dec.norm_eps)
     new_cache = None if cache is None else {"conv": tail, "ssm": state}
     return jnp.einsum("bse,ed->bsd", ys, layer["out_proj"]), new_cache
+
+
+class Shared(NamedTuple):
+    """What the layer stack carries forward besides x (None: no layer has
+    made it yet): `m` [b, L, inner], the scan's output of the last Mamba-1
+    layer, before its gate; `k`, `v` [b, kvh, ., hd], the keys and values
+    of the last differential-attention layer that saw the whole sequence
+    (with a cache, that layer's cache)."""
+    m: Optional[jax.Array] = None
+    k: Optional[jax.Array] = None
+    v: Optional[jax.Array] = None
+
+
+def mamba1(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """The Mamba-1 mixer of x [b, L, d], from the input norm to the
+    output projection: the input projection to x | z, a causal depthwise
+    convolution and silu, x_proj to the low-rank step | B | C, dt_proj and
+    softplus to a step a channel, the selective scan with a decay of its
+    own for every (channel, state) pair, the gate, the output projection.
+    Every size is read off the weights. Training, prefill and decode as
+    `mamba2`. Returns (y, new_cache or None, the scan's output m before
+    the gate: what the gated memory units after it read)."""
+    L = x.shape[1]
+    N = layer["A_log"].shape[1]
+    rank = layer["dt_proj"].shape[0]
+    y = _norm(x, layer, "ln1", dec.norm_eps)
+    xs, z = jnp.split(jnp.einsum("bsd,de->bse", y, layer["in_proj"]), 2,
+                      axis=-1)
+    with jax.named_scope("ssm_conv"):
+        xs, tail = causal_conv1d(xs, layer["conv_w"], layer["conv_b"],
+                                 None if cache is None else cache["conv"])
+        xs = jax.nn.silu(xs)
+    low, B, C = jnp.split(jnp.einsum("bse,er->bsr", xs, layer["x_proj"]),
+                          [rank, rank + N], axis=-1)
+    dt = jax.nn.softplus(
+        jnp.einsum("bsr,re->bse", low, layer["dt_proj"],
+                   preferred_element_type=jnp.float32) + layer["dt_bias"])
+    a = -jnp.exp(layer["A_log"].astype(jnp.float32))
+    if cache is not None and L == 1:
+        f32 = jnp.float32
+        x1, dt1 = xs[:, 0].astype(f32), dt[:, 0]               # [b, inner]
+        state = (jnp.exp(dt1[..., None] * a) * cache["ssm"]
+                 + (dt1 * x1)[..., None] * B[:, 0].astype(f32)[:, None, :])
+        m = (jnp.einsum("bcn,bn->bc", state, C[:, 0].astype(f32))
+             + layer["D"].astype(f32) * x1)[:, None].astype(x.dtype)
+    else:
+        m, state = selective_scan(
+            xs, dt, a, B, C, layer["D"],
+            None if cache is None else cache["ssm"])
+    new_cache = None if cache is None else {"conv": tail, "ssm": state}
+    gated = m * jax.nn.silu(z)
+    return jnp.einsum("bse,ed->bsd", gated, layer["out_proj"]), new_cache, m
+
+
+def gmu(x, layer, dec: Decoder, m):
+    """A gated memory unit over x [b, L, d]: the scan's output `m` of an
+    earlier Mamba-1 layer, at the same tokens, gated by this layer's own
+    projection of x. No scan, no convolution, no state."""
+    y = _norm(x, layer, "ln1", dec.norm_eps)
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu(jnp.einsum("bsd,de->bse", y, layer["gmu_in"]))
+        return jnp.einsum("bse,ed->bsd", gate * m, layer["gmu_out"])
+
+
+def diff_lambda_init(index: int) -> float:
+    """Differential attention's lambda at initialisation, by the layer's
+    place in the stack."""
+    return 0.8 - 0.6 * math.exp(-0.3 * index)
+
+
+def diff_attention(x, layer, dec: Decoder, cache, start_pos, shared: Shared,
+                   index: int, window: Optional[int]):
+    """Differential attention of x [b, L, d], from the input norm to the
+    output projection, no positions at all. Heads pair up neighbours:
+    query pair p is heads 2p, 2p + 1; kv pair g = p // group is k heads
+    2g, 2g + 1 and V_g = [v_2g | v_2g+1], twice as wide. O_p = (softmax(
+    q_2p k_2g^T) - lambda softmax(q_2p+1 k_2g+1^T)) V_g, one RMSNorm over
+    its 2 hd columns (one weight a layer), times 1 - lambda_init. Each
+    score map is computed once: the flash kernel takes v wider than q and
+    k, so head 2p + e is q_2p+e, k_2g+e and V_g, and the subtraction comes
+    after (`diff_attention_combine`). With `wqkv` the layer attends over
+    its own keys and values (under `window`, or all of them, which it then
+    hands on in `shared`); with `wq` alone over `shared`'s. Returns (y,
+    new_cache or None, shared)."""
+    b, L, d = x.shape
+    h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
+    own = "wqkv" in layer
+    y = _norm(x, layer, "ln1", dec.norm_eps)
+    sp = None if cache is None else jnp.asarray(start_pos)
+
+    def heads(t, n):
+        return t.reshape(b, L, n, hd).transpose(0, 2, 1, 3)
+
+    new_cache = cache
+    if own:
+        q, k, v = jnp.split(
+            jnp.einsum("bsd,de->bse", y, layer["wqkv"]) + layer["bqkv"],
+            [h * hd, (h + kvh) * hd], axis=-1)
+        k, v = heads(k, kvh), heads(v, kvh)
+        if cache is not None:
+            new_cache = _write_cache(cache, k, v, sp)
+            k, v = new_cache["k"], new_cache["v"]
+        if window is None:
+            shared = shared._replace(k=k, v=v)
+    else:
+        q = jnp.einsum("bsd,de->bse", y, layer["wq"]) + layer["bq"]
+        k, v = shared.k, shared.v
+    out = differential_maps(heads(q, h), k, v, layer, dec, index, window, sp)
+    return (jnp.einsum("bsd,de->bse", out, layer["wo"]) + layer["bo"],
+            new_cache, shared)
+
+
+def differential_maps(q, k, v, layer, dec: Decoder, index: int,
+                      window: Optional[int], sp=None):
+    """Differential attention between the projections: q [b, h, L, hd]
+    over k, v [b, kvh, n, hd] -> [b, L, h hd], `layer` holding the four
+    lambda vectors and the sub-norm's weight. The flash kernel over the
+    whole sequence, or (`sp`, the queries' positions: with a cache) a
+    masked read of all n cached positions."""
+    b, h, L, hd = q.shape
+    kvh, n = k.shape[1:3]
+    # head 2p + e of the 2 x pairs maps: k head 2g + e, and V_g.
+    group = h // kvh
+    k_all = jnp.repeat(k.reshape(b, kvh // 2, 2, n, hd), group,
+                       axis=1).reshape(b, h, n, hd)
+    v_all = jnp.repeat(
+        v.reshape(b, kvh // 2, 2, n, hd).transpose(0, 1, 3, 2, 4).reshape(
+            b, kvh // 2, n, 2 * hd), 2 * group, axis=1)
+    if sp is None:
+        maps = flash_attention(q, k_all, v_all, True, dec.sm_scale, window)
+    else:
+        maps = _attend_cache(q, k_all, v_all, sp, dec.sm_scale, window)
+    with jax.named_scope("diff_attention_combine"):
+        f32 = jnp.float32
+        lam_init = diff_lambda_init(index)
+        lam = (jnp.exp(jnp.sum(layer["lambda_q1"] * layer["lambda_k1"]))
+               - jnp.exp(jnp.sum(layer["lambda_q2"] * layer["lambda_k2"]))
+               + lam_init).astype(f32)
+        maps = maps.reshape(b, h // 2, 2, L, 2 * hd).astype(f32)
+        out = rms_norm(maps[:, :, 0] - lam * maps[:, :, 1],
+                       layer["sub_norm"], dec.norm_eps) * (1.0 - lam_init)
+        return out.astype(q.dtype).transpose(0, 2, 1, 3).reshape(
+            b, L, h * hd)
 
 
 # What a rematerialised block keeps for its backward pass, by the names
@@ -291,11 +514,16 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
 # and y (the gated norm's, after it) (ops/ssm_scan.py; 0.13 GB each a
 # layer at 16,384 tokens of granite-4.0-h-micro). Its input projection,
 # convolution, gated norm and output projection are made again.
+# Of a Mamba-1 layer the same two: the state each chunk of 64 tokens left
+# and the scan's output m (ops/selective_scan.py; 0.08 and 0.17 GB a layer
+# at 16,384 tokens of Phi-4-mini-flash-reasoning), which is a block output
+# besides where a gated memory unit reads it.
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
     "moe_probs", "moe_xs", "moe_gate", "moe_up",
-    "ssm_scan_y", "ssm_scan_states")
+    "ssm_scan_y", "ssm_scan_states",
+    "selective_scan_m", "selective_scan_states")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
 
@@ -304,12 +532,29 @@ def _scaled(t, scale: float):
     return t if scale == 1.0 else t * scale
 
 
-def _block(x, layer, cache, start_pos, dec: Decoder):
+def _mix(x, layer, cache, start_pos, shared: Shared, dec: Decoder,
+         index: int, window: Optional[int]):
+    """The layer's sequence mixer, picked by the weights it holds:
+    (y, new cache, what the stack carries on)."""
+    if _is_mamba1(layer):
+        y, new_cache, m = mamba1(x, layer, dec, cache, start_pos)
+        return y, new_cache, shared._replace(m=m)
+    if "gmu_in" in layer:
+        return gmu(x, layer, dec, shared.m), cache, shared
+    if "lambda_q1" in layer:
+        return diff_attention(x, layer, dec, cache, start_pos, shared,
+                              index, window)
     mixer = mamba2 if _is_mamba2(layer) else attention
-    y, new_cache = mixer(x, layer, dec, cache, start_pos)
+    return (*mixer(x, layer, dec, cache, start_pos), shared)
+
+
+def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
+           dec: Decoder, index: int = 0, window: Optional[int] = None):
+    y, new_cache, shared = _mix(x, layer, cache, start_pos, shared, dec,
+                                index, window)
     x = x + _scaled(y, dec.residual_scale)
-    out, stats = dec.mlp(rms_norm(x, layer["ln2"], dec.norm_eps), layer)
-    return x + _scaled(out, dec.residual_scale), stats, new_cache
+    out, stats = dec.mlp(_norm(x, layer, "ln2", dec.norm_eps), layer)
+    return x + _scaled(out, dec.residual_scale), stats, new_cache, shared
 
 
 def decoder_hidden(params: Dict, tokens, dec: Decoder,
@@ -321,17 +566,33 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     with none this is the training forward. The rows come multiplied by
     `dec.logit_scale`, so rows @ head are the model's logits."""
     x = _scaled(jnp.take(params["embed"], tokens, axis=0), dec.embed_scale)
-    block = functools.partial(_block, dec=dec)
-    if dec.remat is not None and cache is None:    # remat is training's
-        block = jax.checkpoint(block, policy=dec.remat)
-    total, new_cache = None, []
+    layers = params["layers"]
+    # Differential attention is windowed while a Mamba-1 layer follows.
+    last_scan = max((i for i, layer in enumerate(layers)
+                     if _is_mamba1(layer)), default=-1)
+
+    @functools.cache
+    def block_at(index: int, window: Optional[int]):
+        block = functools.partial(_block, dec=dec, index=index, window=window)
+        if dec.remat is not None and cache is None:    # remat is training's
+            block = jax.checkpoint(block, policy=dec.remat)
+        return block
+
+    total, new_cache, shared = None, [], Shared()
     with jax.named_scope("layers"):
-        for layer, cache_layer in zip(
-                params["layers"], cache or [None] * len(params["layers"])):
-            x, stats, cache_layer = block(x, layer, cache_layer, start_pos)
+        for i, (layer, cache_layer) in enumerate(zip(
+                layers, cache or [None] * len(layers))):
+            # Only differential attention reads its place and a window:
+            # every other layer runs the one block, traced once a shape.
+            differential = "lambda_q1" in layer
+            block = block_at(
+                i if differential else 0,
+                dec.window if differential and i < last_scan else None)
+            x, stats, cache_layer, shared = block(
+                x, layer, cache_layer, start_pos, shared)
             total = stats if total is None else jax.tree.map(
                 jnp.add, total, stats)
             new_cache.append(cache_layer)
-    x = _scaled(rms_norm(x, params["lnf"], dec.norm_eps), dec.logit_scale)
+    x = _scaled(_norm(x, params, "lnf", dec.norm_eps), dec.logit_scale)
     head = params["head"] if "head" in params else params["embed"].T
     return x, head, total, (new_cache if cache is not None else None)

@@ -27,9 +27,13 @@ def init_cache(cfg, batch: int, max_len: int) -> List[Dict]:
     """An empty cache: each layer gets the state of its kind. A config
     that names its layers' kinds (`layer_types`, models.hybrid) gets them
     from there, as stand-ins that hold what decoder.empty_cache reads of
-    a layer; every layer of any other family is an attention layer."""
+    a layer, as does one that builds the stand-ins itself (`cache_layers`,
+    models.sambay); every layer of any other family is an attention
+    layer."""
     kinds = getattr(cfg, "layer_types", None)
-    if kinds is None:
+    if hasattr(cfg, "cache_layers"):      # models.sambay: five kinds
+        layers = cfg.cache_layers()
+    elif kinds is None:
         layers = [{}] * cfg.n_layers
     else:
         mamba = {"in_proj": None, "conv_w": jax.ShapeDtypeStruct(

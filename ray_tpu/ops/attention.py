@@ -55,8 +55,11 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # Reference implementation (CPU tests, non-TPU backends)
 # ---------------------------------------------------------------------------
 def mha_reference(q, k, v, causal: bool = True,
-                  sm_scale: Optional[float] = None):
-    """Plain XLA attention; numerically the ground truth for the kernel."""
+                  sm_scale: Optional[float] = None,
+                  window: Optional[int] = None):
+    """Plain XLA attention; numerically the ground truth for the kernel.
+    With a `window` a query sees itself and the window - 1 positions
+    before it."""
     *_, seq_q, head_dim = q.shape
     seq_k = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(head_dim)
@@ -66,6 +69,9 @@ def mha_reference(q, k, v, causal: bool = True,
     if causal:
         mask = jnp.tril(
             jnp.ones((seq_q, seq_k), dtype=bool), k=seq_k - seq_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool),
+                              k=seq_k - seq_q - window)
         logits = jnp.where(mask, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -185,11 +191,13 @@ class AttentionPlan:
     fwd: KernelPlan
     dq: KernelPlan
     dkv: KernelPlan
+    window: Optional[int] = None
 
     @property
     def executed_share(self) -> float:
         """Share of the score square the kernels compute (the same in all
-        three; 0.5 + sub / (2 * seq_len) when causal)."""
+        three; 0.5 + sub / (2 * seq_len) when causal, about
+        (window + sub) / seq_len under a window)."""
         return self.fwd.computed / (self.fwd.computed + self.fwd.skipped)
 
 
@@ -226,14 +234,47 @@ def _triangle(block: int, sub: int, mirrored: bool):
         yield (strip, at, block - at) if mirrored else (strip, 0, at + sub)
 
 
+def _band_blocks(r, block: int, swept: int, window: int, mirrored: bool):
+    """Under a window (a query sees the keys 0 <= q - k < window), the
+    block-sized chunks of a swept block that a program's own block works,
+    as three ranges [lo, hi) in the order they lie: `r` is the own block's
+    number counted from the swept block's first (the diagonal's chunk).
+    Forward and dQ: (the chunks the band's far edge crosses, the chunks
+    wholly inside the band, the diagonal's); dK/dV (`mirrored`): (the
+    diagonal's, wholly inside, the far edge's). The first and the last
+    range are masked, the middle one is not; every other chunk is wholly
+    outside the band and is not computed. Python ints give ints (the
+    plan's counts), traced scalars the kernels' loop bounds."""
+    n = swept // block
+    whole = window // block                  # chunks the band spans whole
+    reach = (window + block - 2) // block    # how far its far edge gets
+    if mirrored:
+        ranges = ((r, r + 1), (r + 1, r + whole),
+                  (r + max(1, whole), r + reach + 1))
+    else:
+        ranges = ((r - reach, r - max(whole - 1, 0)), (r + 1 - whole, r),
+                  (r, r + 1))
+    return tuple((_clip(lo, n), _clip(hi, n)) for lo, hi in ranges)
+
+
 def _count(seq_len: int, block: int, swept: int, sub: int, causal: bool,
-           mirrored: bool):
+           mirrored: bool, window: Optional[int] = None):
     """(computed, masked, skipped) sub-blocks of one head, by the rules
     the kernel's loops follow."""
     n = seq_len // sub
     if not causal:
         return n * n, 0, 0
     computed = masked = 0
+    if window is not None:        # block == sub: a chunk is a sub-block
+        for own in range(0, seq_len, block):
+            for other in range(0, seq_len, swept):
+                near, inside, far = (
+                    max(0, hi - lo) for lo, hi in _band_blocks(
+                        (own - other) // block, block, swept, window,
+                        mirrored))
+                computed += near + inside + far
+                masked += near + far
+        return computed, masked, n * n - computed
     for own in range(0, seq_len, block):
         for other in range(0, seq_len, swept):
             lo, hi = _visible_blocks(own - other, block, swept, mirrored)
@@ -246,26 +287,30 @@ def _count(seq_len: int, block: int, swept: int, sub: int, causal: bool,
 
 
 def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
-                itemsize: int) -> int:
+                itemsize: int, v_dim: Optional[int] = None) -> int:
     """An upper estimate of one program's VMEM: every operand and result
     block twice (the pipeline's two buffers), float32 scratch, and three
-    float32 block x block tiles (scores, probabilities, their gradient)."""
+    float32 block x block tiles (scores, probabilities, their gradient).
+    v, o and their gradients are `v_dim` wide (head_dim where None)."""
+    v_dim = head_dim if v_dim is None else v_dim
     row = 128 * 4                                   # a lane-padded f32 row
     own, other = block * head_dim * itemsize, swept * head_dim * itemsize
+    own_v, other_v = block * v_dim * itemsize, swept * v_dim * itemsize
     tiles = 3 * block * block * 4
     if kernel == "fwd":     # q | k, v -> o, lse; acc, m, l
-        return (2 * (own + 2 * other) + 2 * (own + block * row)
-                + block * (head_dim * 4 + 2 * row) + tiles)
+        return (2 * (own + other + other_v) + 2 * (own_v + block * row)
+                + block * (v_dim * 4 + 2 * row) + tiles)
     if kernel == "dq":      # q, do, lse, delta | k, v -> dq; acc
-        return (2 * (2 * own + 2 * block * row + 2 * other) + 2 * own
-                + block * head_dim * 4 + tiles)
+        return (2 * (own + own_v + 2 * block * row + other + other_v)
+                + 2 * own + block * head_dim * 4 + tiles)
     # dkv: k, v | q, do, lse, delta -> dk, dv; two accs
-    return (2 * (2 * own + 2 * other + 2 * swept * row) + 2 * 2 * own
-            + 2 * block * head_dim * 4 + tiles)
+    return (2 * (own + own_v + other + other_v + 2 * swept * row)
+            + 2 * (own + own_v) + block * (head_dim + v_dim) * 4 + tiles)
 
 
 def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
-                   dtype=jnp.bfloat16) -> AttentionPlan:
+                   dtype=jnp.bfloat16, window: Optional[int] = None,
+                   v_dim: Optional[int] = None) -> AttentionPlan:
     """The tiling `flash_attention` runs a [.., seq_len, head_dim] call at,
     and the sub-blocks a head computes, masks and skips in each kernel.
 
@@ -281,7 +326,17 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     sequence; the swept side (K and V in forward and dQ; Q, dO and their
     rows in dK/dV) is resident whole where the estimate fits VMEM_BUDGET,
     else in the largest blocks that do, swept on the grid with the same
-    loops inside."""
+    loops inside.
+
+    Under a `window` (causal, a query sees itself and the window - 1
+    positions before it) a kernel's own block is one sub-block, and of
+    the swept side it computes the chunks the band touches and no other
+    (`_band_blocks`): those wholly inside the band unmasked, the one the
+    diagonal crosses and the one or two the band's far edge crosses under
+    a mask, about (window + sub) / seq_len of the square. `v_dim` is the
+    width of v and of the output where it is not q's and k's."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is causal and at least 1 wide")
     if seq_len < 128 or seq_len % 128:
         raise ValueError(
             f"the kernels tile sequences in multiples of 128, not {seq_len}")
@@ -291,20 +346,21 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
 
     def plan(kernel: str) -> KernelPlan:
         for swept in sizes:
-            for block in sizes:
+            for block in sizes if window is None else [sub]:
                 if block > _MAX_BLOCK or swept % block:
                     continue
-                need = _vmem_bytes(kernel, block, swept, head_dim, itemsize)
+                need = _vmem_bytes(kernel, block, swept, head_dim, itemsize,
+                                   v_dim)
                 if need <= VMEM_BUDGET:
                     return KernelPlan(block, swept, sub, need, *_count(
                         seq_len, block, swept, sub, causal,
-                        kernel == "dkv"))
+                        kernel == "dkv", window))
         raise ValueError(
             f"no {kernel} tiling of ({seq_len}, {head_dim}) fits "
             f"{VMEM_BUDGET} bytes of VMEM")
 
     return AttentionPlan(seq_len, head_dim, causal, VMEM_BUDGET,
-                         plan("fwd"), plan("dq"), plan("dkv"))
+                         plan("fwd"), plan("dq"), plan("dkv"), window)
 
 
 def _scale_is_exact(sm_scale: float) -> bool:
@@ -358,6 +414,48 @@ def _causal_work(step, rel, block: int, swept: int, sub: int,
             step(_ds(strip * sub, sub, sub), _ds(rel + at, width, sub), True)
 
 
+def _band_work(step, r, block: int, swept: int, window: int,
+               mirrored: bool):
+    """Everything one program computes under a window, as calls of
+    step(own rows, other rows, mask): the chunks `_band_blocks` names,
+    the first and the last range under the band's mask (given as q - k of
+    the tile's first row and column), the middle one unmasked."""
+    own = _ds(0, block, block)
+
+    def tile(masked: bool):
+        def at(j):
+            first = (j - r if mirrored else r - j) * block
+            step(own, _ds(j * block, block, block),
+                 ("band", first) if masked else False)
+        return at
+
+    near, inside, far = _band_blocks(r, block, swept, window, mirrored)
+    _sweep(*near, tile(True))
+    _sweep(*inside, tile(False))
+    _sweep(*far, tile(True))
+
+
+def _mask_band(s, first, window: int, mirrored: bool):
+    """Mask a tile of scores outside 0 <= q - k < window, `first` being
+    q - k at its first row and column (rows queries; keys where
+    `mirrored`)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    ahead = first + (cols - rows if mirrored else rows - cols)
+    return jnp.where((ahead >= 0) & (ahead < window), s,
+                     DEFAULT_MASK_VALUE)
+
+
+def _masked(s, mask, sub: int, window, mirrored: bool):
+    """A tile of scores under what `step` was told: False, True (the
+    diagonal's sub-block of a causal strip) or ("band", first)."""
+    if mask is True:
+        return _mask_diagonal(s, sub, mirrored)
+    if mask is False:
+        return s
+    return _mask_band(s, mask[1], window, mirrored)
+
+
 def _mask_diagonal(s, sub: int, mirrored: bool):
     """Mask the sub-block of a strip's scores that the diagonal crosses:
     the last `sub` columns, the first where the scores are transposed
@@ -389,7 +487,8 @@ def _dot(a, b, dims):
 # VMEM scratch across a program's tiles and the K blocks of the grid.
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
-                causal: bool, sub: int, grid: tuple, save_lse: bool):
+                causal: bool, sub: int, grid: tuple, save_lse: bool,
+                window: Optional[int] = None):
     if save_lse:
         lse_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -414,8 +513,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         s = _dot(q, k_ref[0, cols, :], _NT)
         if not fold:
             s = s * sm_scale
-        if on_diagonal:
-            s = _mask_diagonal(s, sub, False)
+        s = _masked(s, on_diagonal, sub, window, False)
         m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -427,8 +525,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         acc_scr[rows, :] = alpha * acc_scr[rows, :] + _dot(
             p.astype(v.dtype), v, _NN)
 
-    _causal_work(step, qi * block - ki * swept, block, swept, sub, causal,
-                 False)
+    if window is None:
+        _causal_work(step, qi * block - ki * swept, block, swept, sub,
+                     causal, False)
+    else:
+        _band_work(step, qi - ki * (swept // block), block, swept, window,
+                   False)
 
     @pl.when(ki == grid[2] - 1)
     def _finalize():
@@ -442,13 +544,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
                 m_scr[...] + jnp.log(l_safe), (block, 128))
 
 
-def _swept_index(causal: bool, block: int, swept: int, mirrored: bool):
+def _swept_index(causal: bool, block: int, swept: int, mirrored: bool,
+                 window: Optional[int] = None):
     """Index map of the swept side's blocks on a grid (bh, own, swept).
     Causal blocks wholly past the diagonal are never used: clamp their
     index to the last used one, so Mosaic sees an unchanged block and
-    skips the HBM->VMEM copy (the kernel's loops run zero times there)."""
+    skips the HBM->VMEM copy (the kernel's loops run zero times there).
+    Under a window the blocks wholly before the band are clamped away
+    too."""
     if not causal:
         return lambda b, i, j: (b, j, 0)
+    if window is not None and mirrored:     # queries k .. k + window - 1
+        return lambda b, i, j: (b, jnp.clip(
+            j, i * block // swept,
+            ((i + 1) * block + window - 2) // swept), 0)
+    if window is not None:                  # keys q - window + 1 .. q
+        return lambda b, i, j: (b, jnp.clip(
+            j, jnp.maximum(i * block - window + 1, 0) // swept,
+            ((i + 1) * block - 1) // swept), 0)
     if mirrored:    # dK/dV: Q blocks from the one holding the K block on
         return lambda b, i, j: (b, jnp.maximum(j, i * block // swept), 0)
     return lambda b, i, j: (b, jnp.minimum(j, i * block // swept), 0)
@@ -467,25 +580,29 @@ def _compiler_params():
 # set-up otherwise). Only the pallas_call is inside: what XLA can fuse
 # with its neighbours (reshapes, delta) stays in the caller's program.
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sm_scale", "plan", "save_lse"))
+    "causal", "sm_scale", "plan", "save_lse", "window"))
 def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
-                  plan: KernelPlan, save_lse: bool):
+                  plan: KernelPlan, save_lse: bool,
+                  window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq_len, head_dim = qf.shape
+    v_dim = vf.shape[-1]
     block, swept = plan.block, plan.swept
     grid = (bh, seq_len // block, seq_len // swept)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, sub=plan.sub,
-        grid=grid, save_lse=save_lse)
-    q_spec = pl.BlockSpec((1, block, head_dim), lambda b, i, j: (b, i, 0),
+        grid=grid, save_lse=save_lse, window=window)
+    own = lambda b, i, j: (b, i, 0)  # noqa: E731
+    other = _swept_index(causal, block, swept, False, window)
+    q_spec = pl.BlockSpec((1, block, head_dim), own, memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, block, v_dim), own, memory_space=pltpu.VMEM)
+    k_spec = pl.BlockSpec((1, swept, head_dim), other,
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, swept, head_dim),
-                           _swept_index(causal, block, swept, False),
-                           memory_space=pltpu.VMEM)
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct(qf.shape, qf.dtype)]
+    v_spec = pl.BlockSpec((1, swept, v_dim), other, memory_space=pltpu.VMEM)
+    out_specs = [o_spec]
+    out_shape = [jax.ShapeDtypeStruct((bh, seq_len, v_dim), qf.dtype)]
     if save_lse:
         # lse is lane-replicated to 128 so its block satisfies the TPU
         # (8, 128) tile rule (the layout jax's own TPU flash kernel uses
@@ -500,11 +617,11 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
     fwd = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block, head_dim), jnp.float32),
+            pltpu.VMEM((block, v_dim), jnp.float32),
             pltpu.VMEM((block, 1), jnp.float32),
             pltpu.VMEM((block, 1), jnp.float32),
         ],
@@ -519,13 +636,15 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
 
 
 def _flash_forward(q, k, v, causal: bool, sm_scale: float,
-                   plan: KernelPlan, save_lse: bool = True):
+                   plan: KernelPlan, save_lse: bool = True,
+                   window: Optional[int] = None):
     batch, heads, seq_len, head_dim = q.shape
     flat = (batch * heads, seq_len, head_dim)
     result = _forward_call(
-        q.reshape(flat), k.reshape(flat), v.reshape(flat), causal=causal,
-        sm_scale=sm_scale, plan=plan, save_lse=save_lse)
-    out = result[0].reshape(q.shape)
+        q.reshape(flat), k.reshape(flat),
+        v.reshape(batch * heads, seq_len, v.shape[-1]), causal=causal,
+        sm_scale=sm_scale, plan=plan, save_lse=save_lse, window=window)
+    out = result[0].reshape(v.shape)
     # lse stays lane-replicated (.., seq, 128): the backward feeds it
     # straight back to the kernels, avoiding a slice + rebroadcast HBM
     # round trip per training step. It carries q's leading [batch, heads]
@@ -540,7 +659,7 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
 # ---------------------------------------------------------------------------
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, *, sm_scale: float, causal: bool, sub: int,
-               grid: tuple):
+               grid: tuple, window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     block, swept = q_ref.shape[1], k_ref.shape[1]
@@ -559,8 +678,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = _dot(q, k, _NT)
         if not fold:
             s = s * sm_scale
-        if on_diagonal:
-            s = _mask_diagonal(s, sub, False)
+        s = _masked(s, on_diagonal, sub, window, False)
         p = jnp.exp(s - lse_ref[0, rows, :1])
         dp = _dot(do_ref[0, rows, :], v_ref[0, cols, :], _NT)
         ds = p * (dp - delta_ref[0, rows, :1])
@@ -568,8 +686,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds = ds * sm_scale
         dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _causal_work(step, qi * block - ki * swept, block, swept, sub, causal,
-                 False)
+    if window is None:
+        _causal_work(step, qi * block - ki * swept, block, swept, sub,
+                     causal, False)
+    else:
+        _band_work(step, qi - ki * (swept // block), block, swept, window,
+                   False)
 
     @pl.when(ki == grid[2] - 1)
     def _finalize():
@@ -581,7 +703,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                causal: bool, sub: int, grid: tuple):
+                causal: bool, sub: int, grid: tuple,
+                window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     block, swept = k_ref.shape[1], q_ref.shape[1]
@@ -602,8 +725,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s_t = _dot(k, q, _NT)                     # rows keys, columns queries
         if not fold:
             s_t = s_t * sm_scale
-        if on_diagonal:
-            s_t = _mask_diagonal(s_t, sub, True)
+        s_t = _masked(s_t, on_diagonal, sub, window, True)
         p_t = jnp.exp(s_t - lse_ref[0, cols, 0][None, :])
         dv_scr[rows, :] += _dot(p_t.astype(do.dtype), do, _NN)
         dp_t = _dot(v_ref[0, rows, :], do, _NT)
@@ -612,8 +734,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds_t = ds_t * sm_scale
         dk_scr[rows, :] += _dot(ds_t.astype(q.dtype), q, _NN)
 
-    _causal_work(step, ki * block - qi * swept, block, swept, sub, causal,
-                 True)
+    if window is None:
+        _causal_work(step, ki * block - qi * swept, block, swept, sub,
+                     causal, True)
+    else:
+        _band_work(step, ki - qi * (swept // block), block, swept, window,
+                   True)
 
     @pl.when(qi == grid[2] - 1)
     def _finalize():
@@ -624,92 +750,106 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _backward_pallas(kernel, mirrored: bool, n_out: int, like, *,
-                     causal: bool, sm_scale: float, plan: KernelPlan):
+def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
+                     sm_scale: float, plan: KernelPlan,
+                     window: Optional[int] = None):
     """The pallas_call of a backward kernel on a grid (bh, own blocks,
     swept blocks): Q, dO and their lse and delta rows on one side, K and V
-    on the other; `n_out` results shaped like `like` [bh, seq, head_dim]."""
+    on the other, `q` [bh, seq, head_dim] and `v` [bh, seq, v_dim] giving
+    the widths; dQ like q, or (`mirrored`) dK like q and dV like v."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, seq_len, head_dim = like.shape
+    (bh, seq_len, head_dim), v_dim = q.shape, v.shape[-1]
     own = lambda b, i, j: (b, i, 0)  # noqa: E731
-    other = _swept_index(causal, plan.block, plan.swept, mirrored)
+    other = _swept_index(causal, plan.block, plan.swept, mirrored, window)
     q_map, k_map = (other, own) if mirrored else (own, other)
     q_len, k_len = (plan.swept, plan.block) if mirrored \
         else (plan.block, plan.swept)
-    q_spec = pl.BlockSpec((1, q_len, head_dim), q_map,
-                          memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, k_len, head_dim), k_map,
-                          memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, q_len, 128), q_map, memory_space=pltpu.VMEM)
-    out_spec = k_spec if mirrored else q_spec
+
+    def spec(length, width, index):
+        return pl.BlockSpec((1, length, width), index,
+                            memory_space=pltpu.VMEM)
+    q_spec, do_spec = spec(q_len, head_dim, q_map), spec(q_len, v_dim, q_map)
+    k_spec, v_spec = spec(k_len, head_dim, k_map), spec(k_len, v_dim, k_map)
+    row_spec = spec(q_len, 128, q_map)
+    outs = [(k_spec, q), (v_spec, v)] if mirrored else [(q_spec, q)]
     grid = (bh, seq_len // plan.block, seq_len // plan.swept)
     return pl.pallas_call(
         functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                          sub=plan.sub, grid=grid),
+                          sub=plan.sub, grid=grid, window=window),
         grid=grid,
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=[out_spec] * n_out,
-        out_shape=[jax.ShapeDtypeStruct(like.shape, like.dtype)] * n_out,
-        scratch_shapes=[pltpu.VMEM((plan.block, head_dim),
-                                   jnp.float32)] * n_out,
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[o for o, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(like.shape, like.dtype)
+                   for _, like in outs],
+        scratch_shapes=[pltpu.VMEM((plan.block, like.shape[-1]), jnp.float32)
+                        for _, like in outs],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "plan"))
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "plan", "window"))
 def _dq_call(*operands, **static):
     with jax.named_scope("flash_attention_dq"):
-        return _backward_pallas(_dq_kernel, False, 1, operands[0],
+        return _backward_pallas(_dq_kernel, False, operands[0], operands[2],
                                 **static)(*operands)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "plan"))
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "plan", "window"))
 def _dkv_call(*operands, **static):
     with jax.named_scope("flash_attention_dkv"):
-        return _backward_pallas(_dkv_kernel, True, 2, operands[0],
+        return _backward_pallas(_dkv_kernel, True, operands[0], operands[2],
                                 **static)(*operands)
 
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
-                    dq_plan: KernelPlan, dkv_plan: KernelPlan):
+                    dq_plan: KernelPlan, dkv_plan: KernelPlan,
+                    window: Optional[int] = None):
     batch, heads, seq_len, head_dim = q.shape
     bh = batch * heads
     flat = (bh, seq_len, head_dim)
+    flat_v = (bh, seq_len, v.shape[-1])
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA.
     delta = jnp.broadcast_to(
         jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                 axis=-1).reshape(bh, seq_len)[:, :, None],
         (bh, seq_len, 128))
-    operands = (q.reshape(flat), k.reshape(flat), v.reshape(flat),
-                g.reshape(flat),
+    operands = (q.reshape(flat), k.reshape(flat), v.reshape(flat_v),
+                g.reshape(flat_v),
                 lse.reshape(bh, seq_len, 128),  # lane-replicated by forward
                 delta)
     dq, = _dq_call(*operands, causal=causal, sm_scale=sm_scale,
-                   plan=dq_plan)
+                   plan=dq_plan, window=window)
     # dK/dV: K-outer, Q-inner sweep.
     dk, dv = _dkv_call(*operands, causal=causal, sm_scale=sm_scale,
-                       plan=dkv_plan)
-    return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(q.shape)
+                       plan=dkv_plan, window=window)
+    return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(v.shape)
 
 
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True,
-                    sm_scale: Optional[float] = None):
+                    sm_scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Flash attention: Pallas kernels on TPU, reference elsewhere.
 
     Differentiable end to end without materializing the (seq, seq)
     probability matrix: the backward recomputes attention blockwise from
     the saved logsumexp (flash-2), so both inference AND training scale
-    to long sequences (SURVEY.md hard-part #5).
+    to long sequences (SURVEY.md hard-part #5). With a `window` a query
+    sees itself and the window - 1 positions before it, and the kernels
+    compute the band and nothing else (`attention_plan`). v may be wider
+    than q and k (differential attention: one score map times two heads'
+    values side by side); the output is as wide as v.
     """
     # Primal-only call (no differentiation): skip the lse residual.
-    out, _ = _flash_attention_fwd_impl(q, k, v, causal, sm_scale,
+    out, _ = _flash_attention_fwd_impl(q, k, v, causal, sm_scale, window,
                                        save_lse=False)
     return out
 
@@ -719,22 +859,27 @@ def _scale_of(q, sm_scale):
         q.shape[-1])
 
 
-def _flash_attention_fwd_impl(q, k, v, causal, sm_scale,
+def _plan_of(q, v, causal, window) -> AttentionPlan:
+    return attention_plan(q.shape[-2], q.shape[-1], causal, q.dtype,
+                          window, v.shape[-1])
+
+
+def _flash_attention_fwd_impl(q, k, v, causal, sm_scale, window=None,
                               save_lse=True):
     scale = _scale_of(q, sm_scale)
     seq_len = q.shape[-2]
     if _kernel_ok(seq_len):
-        plan = attention_plan(seq_len, q.shape[-1], causal, q.dtype)
+        plan = _plan_of(q, v, causal, window)
         out, lse = _per_shard(functools.partial(
-            _flash_forward, causal=causal, sm_scale=scale,
-            plan=plan.fwd, save_lse=save_lse))(q, k, v)
+            _flash_forward, causal=causal, sm_scale=scale, plan=plan.fwd,
+            save_lse=save_lse, window=window))(q, k, v)
         return out, (out, lse)
-    return mha_reference(q, k, v, causal, scale), (None, None)
+    return mha_reference(q, k, v, causal, scale, window), (None, None)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale):
+def _flash_fwd(q, k, v, causal, sm_scale, window):
     out, (o_saved, lse) = _flash_attention_fwd_impl(
-        q, k, v, causal, sm_scale)
+        q, k, v, causal, sm_scale, window)
     if o_saved is not None:
         # What the backward kernels read, by name: a rematerialised block
         # keeps these, so the forward kernel does not run again, nor the
@@ -749,19 +894,20 @@ def _flash_fwd(q, k, v, causal, sm_scale):
     return out, (q, k, v, o_saved, lse)
 
 
-def _flash_bwd(causal, sm_scale, residuals, g):
+def _flash_bwd(causal, sm_scale, window, residuals, g):
     q, k, v, o, lse = residuals
     scale = _scale_of(q, sm_scale)
     if o is None:
         # Non-kernel path: autodiff through the reference.
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: mha_reference(q_, k_, v_, causal, sm_scale),
+            lambda q_, k_, v_: mha_reference(q_, k_, v_, causal, sm_scale,
+                                             window),
             q, k, v)
         return vjp(g)
-    plan = attention_plan(q.shape[-2], q.shape[-1], causal, q.dtype)
+    plan = _plan_of(q, v, causal, window)
     return _per_shard(functools.partial(
-        _flash_backward, causal=causal, sm_scale=scale,
-        dq_plan=plan.dq, dkv_plan=plan.dkv))(q, k, v, o, lse, g)
+        _flash_backward, causal=causal, sm_scale=scale, dq_plan=plan.dq,
+        dkv_plan=plan.dkv, window=window))(q, k, v, o, lse, g)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -23,6 +23,18 @@ def rms_norm(x, weight, eps: float = NORM_EPS):
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
 
 
+def layer_norm(x, weight, bias, eps: float = 1e-5):
+    """LayerNorm with weight and bias; computed in fp32, cast back to the
+    input dtype."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    centred = xf - mean
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(var + eps)
+    return (normed * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
 def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None):
     """Rotary position embedding for [batch, heads, seq, head_dim].
 

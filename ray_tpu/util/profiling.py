@@ -108,8 +108,21 @@ DEVICE_SCOPES: Dict[str, str] = {
     "ssm_scan_bwd": "ops/ssm_scan.py _scan_backward_call, the _ssm_bwd_kernel "
                     "pallas_call: every gradient of the scan, chunks last "
                     "to first",
-    "ssm_conv": "models/decoder.py mamba2: the causal depthwise "
-                "convolution over x | B | C and its silu",
+    "selective_scan_fwd": "ops/selective_scan.py _scan_forward_call, the "
+                          "_selective_fwd_kernel pallas_call: a Mamba-1 "
+                          "layer's selective scan, m and one state a chunk",
+    "selective_scan_bwd": "ops/selective_scan.py _scan_backward_call, the "
+                          "_selective_bwd_kernel pallas_call: a chunk's "
+                          "states again, then every gradient of the scan, "
+                          "chunks last to first",
+    "ssm_conv": "models/decoder.py mamba2 and mamba1: the causal depthwise "
+                "convolution (over x | B | C; Mamba-1: over x) and its silu",
+    "gmu": "models/decoder.py gmu: a gated memory unit's gate projection, "
+           "silu, the product with the handed-on scan output and the "
+           "output projection",
+    "diff_attention_combine": "models/decoder.py differential_maps: a "
+                              "pair's first map less lambda times its "
+                              "second, the sub-norm and the scale",
     "ssm_gate_norm": "models/decoder.py mamba2: y * silu(z) and the one "
                      "RMSNorm over the inner width",
     "moe_route": "parallel/moe.py dropless_moe_layer: float32 router, "
@@ -119,7 +132,8 @@ DEVICE_SCOPES: Dict[str, str] = {
     "moe_combine": "parallel/moe.py _experts: the experts' weighted rows "
                    "back in token order and their sum",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
-              "every decoder family (gpt, llama, moe, hybrid), in the train "
+              "every decoder family (gpt, llama, moe, hybrid, sambay), in the "
+              "train "
               "step and under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "

@@ -1,16 +1,16 @@
-"""The `granite4h-train-1chip` cell end to end at tiny size on the CPU,
+"""The `phi4flash-train-1chip` cell end to end at tiny size on the CPU,
 through the benchmark's own command line (`chipbench/run.py --rehearsal`),
 and its two new per-layer readers on hand-made records.
 
 The manifest is BENCHMARK.json as it is with the cell's configuration and
 traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/granite-tiny.json,
-.../traffic/tiny-train-granite.json: two Mamba-2 layers and one attention
-layer, chunks of 8, one sequence of 128). chipbench's own rehearsal
-(chipbench/tests, not part of tier-1) looks every configuration up in
-rehearsal/data/tiny.json and asserts `reduced == []`; both are files the
-benchmark already has, which PR 29 may not edit (PERF.md §7), so the new
-cell is rehearsed from here, as tests/test_olmoe_cell_rehearsal.py does
+(chipbench/tests/rehearsal/data/configs/phi4flash-tiny.json,
+.../traffic/tiny-train-phi4flash.json: eight layers by the model's own
+rule, 128 Mamba-1 channels x 4 states, a window of 32, one sequence of
+128). chipbench's own rehearsal (chipbench/tests, not part of tier-1)
+looks every configuration up in rehearsal/data/tiny.json, a file the
+benchmark already has, which PR 31 may not edit (PERF.md §7), so the new
+cell is rehearsed from here, as tests/test_granite_cell_rehearsal.py does
 for its cell. The numbers of a CPU run mean nothing and are written
 nowhere."""
 
@@ -22,7 +22,7 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CELL = "granite4h-train-1chip"
+CELL = "phi4flash-train-1chip"
 TINY = "chipbench/tests/rehearsal/data"
 
 
@@ -37,20 +37,17 @@ def manifest_path(tmp_path_factory) -> str:
     cell = next(w for w in m["workloads"] if w["name"] == CELL)
     config = next(c for c in m["configs"] if c["name"] == cell["config"])
     m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/granite-tiny.json"
-    cell["traffic"] = "tiny-train-granite"
+    config["file"] = f"{TINY}/configs/phi4flash-tiny.json"
+    cell["traffic"] = "tiny-train-phi4flash"
     m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("granite_rehearsal") / "BENCHMARK.json"
+    path = tmp_path_factory.mktemp("phi4flash_rehearsal") / "BENCHMARK.json"
     path.write_text(json.dumps(m))
     return str(path)
 
 
-# run.py ends by requiring that no /dev/shm/ray_tpu_session_* appeared
-# during its run and stayed. That looks at the whole machine, and tier-1
-# runs several test files, each with clusters of its own, at once: their
-# sessions are not this run's leftovers. So the rehearsal runs run.py as
-# __main__ with that one glob answering nothing, and everything else as it
-# is (the chip run keeps the check: there run.py is alone on its machine).
+# As tests/test_granite_cell_rehearsal.py: run.py as __main__ with the one
+# machine-wide glob for leftover object-store sessions answering nothing
+# (tier-1 runs several clusters at once), everything else as it is.
 RUN_PY = r"""
 import glob, runpy, sys
 _glob = glob.glob
@@ -61,15 +58,18 @@ runpy.run_path("chipbench/run.py", run_name="__main__")
 """
 
 
+def _env():
+    return {k: v for k, v in os.environ.items()
+            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path, trace):
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     proc = subprocess.run(
         [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed", "3",
-         "--seconds", "2.0", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
+         "--rehearsal", manifest_path, "--workload", CELL, "--seed",
+         "2147483900", "--seconds", "2.0", "--trace", str(trace)],
+        capture_output=True, text=True, timeout=600, env=_env(), cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
     detail, line = lines[-2], lines[-1]
@@ -97,33 +97,31 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
     """chipbench/limit_readings.py, the tool the cell's two limits were
     set with on the chip, end to end at tiny size: a loss for the program,
     the reference, the all-bfloat16 reference and each planted fault, and
-    the scan's own errors for the same. At this size the loss tells
-    nothing apart (at the timed size it sees only a scan whose state never
-    reaches the output, PERF.md section 4); the scan's own limit lies
-    between the program and everything else but the one fault of
-    precision that the kernels' bfloat16 products already hide."""
-    from chipbench.families import granite_hybrid as family
+    the kernels' own errors for the same. The kernels' limit lies between
+    the program and everything else, each of the four structural faults
+    among them."""
+    from chipbench.families import sambay as family
 
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     proc = subprocess.run(
         [sys.executable, "chipbench/limit_readings.py", "--rehearsal",
          manifest_path, "--workload", CELL, "--seeds", "3,2147483900"],
-        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
+        capture_output=True, text=True, timeout=600, env=_env(), cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     ranges = json.loads(proc.stdout.strip().splitlines()[-1])
-    faults = {*family.STRUCTURAL_FAULTS, *family.PRECISION_FAULTS}
+    faults = set(family.STRUCTURAL_FAULTS)
+    assert faults == {"window_ignored", "lambda_term_dropped",
+                      "chunk_carry_dropped", "gmu_memory_zeroed"}
     assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
                                             *faults}
     worst = ranges["kernel_errors_worst"]
     assert set(worst) == set(ranges["off_reference"])
     assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     assert worst["program"][1] <= family.KERNEL_LIMIT
-    for name in ("all_bfloat16", *family.STRUCTURAL_FAULTS):
+    for name in ("all_bfloat16", *faults):
         assert worst[name][0] > family.KERNEL_LIMIT, (name, worst[name])
 
 
-def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
+def test_benchmark_lists_the_cell_under_the_metrics_issue_31_names():
     m = _load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
@@ -131,52 +129,64 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
         "train_tokens_per_s", "time_to_first_step_s", "step_ms_p50", "mfu",
         "train_device_idle_share", "attn_fwd_kernel_ms_per_step",
         "attn_dq_kernel_ms_per_step", "attn_dkv_kernel_ms_per_step",
-        "attn_scoped_roofline", "ssm_scan_ms_per_step", "ssm_scan_roofline"}
-    # the two that divide by the time of ALL Mosaic kernels do not list it
-    for name in ("attn_kernel_ms_per_step", "flash_attention_roofline"):
+        "attn_scoped_roofline", "selective_scan_ms_per_step",
+        "selective_scan_roofline"}
+    # Mamba-2's scan readers match `ssm_scan` as a substring: not this cell's
+    for name in ("ssm_scan_ms_per_step", "ssm_scan_roofline",
+                 "attn_kernel_ms_per_step", "flash_attention_roofline"):
         metric = next(x for x in m["per_layer"] if x["name"] == name)
         assert CELL not in metric["workloads"]
     cell = next(w for w in m["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "granite-4.0-h-micro", "pretrain-granite4h-b1-s16384", 1)
-    assert m["workloads"][3] is cell and len(m["workloads"]) >= 4
+        "phi-4-mini-flash-reasoning", "pretrain-phi4flash-b1-s16384", 1)
+    assert m["workloads"][-1] is cell and len(m["workloads"]) == 5
+    assert len(cell["why"]) <= 200
     config = next(c for c in m["configs"] if c["name"] == cell["config"])
+    assert m["configs"][-1] is config and len(config["why"]) <= 200
     on_disk = _load(config["file"])
     assert on_disk["reduced"] == config["reduced"] == [
-        "num_hidden_layers", "layer_types"]
+        "num_hidden_layers", "vocab_size"]
     assert on_disk["source"] == config["source"]
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load("chipbench/traffic/pretrain-granite4h-b1-s16384.json")
-    assert (mix["global_batch"], mix["seq"], mix["remat"],
-            mix["report_every"]) == (1, 16384, True, 2)
+    mix = _load("chipbench/traffic/pretrain-phi4flash-b1-s16384.json")
+    assert (mix["global_batch"], mix["seq"], mix["remat"], mix["mesh_dp"],
+            mix["ring_batches"], mix["report_every"],
+            mix["fetch_lag_groups"], mix["median_over_groups"],
+            mix["reference_sample_sequences"]) == (
+                1, 16384, True, 0, 8, 2, 1, 6, 1)
 
 
-def test_configuration_is_one_whole_period_at_published_widths():
-    """Every number of the catalog's entry at its value but the depth:
-    the first ten of the forty published layer types, in order."""
-    on_disk = _load("chipbench/configs/granite-4.0-h-micro.json")
-    assert on_disk["layer_types"] == ["mamba"] * 5 + ["attention"] + [
-        "mamba"] * 4
-    assert on_disk["num_hidden_layers"] == len(on_disk["layer_types"]) == 10
-    published = {
-        "hidden_size": 2048, "shared_intermediate_size": 8192,
-        "intermediate_size": 8192, "num_attention_heads": 32,
-        "num_key_value_heads": 8, "vocab_size": 100352,
-        "mamba_n_heads": 64, "mamba_d_head": 64, "mamba_d_state": 128,
-        "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_chunk_size": 256,
-        "mamba_expand": 2, "attention_multiplier": 0.015625,
-        "embedding_multiplier": 12, "residual_multiplier": 0.22,
-        "logits_scaling": 8, "rms_norm_eps": 1e-05,
-        "max_position_embeddings": 131072, "position_embedding_type": "nope",
-        "num_local_experts": 0, "num_experts_per_tok": 0,
-        "tie_word_embeddings": True, "rope_theta": 10000}
-    assert {k: on_disk[k] for k in published} == published
-    for key in ("assumed", "departures", "deployment", "reduced_from"):
+def test_configuration_is_the_catalog_entry_but_depth_and_vocabulary():
+    """Every number of the catalog's entry at its value but the two that
+    `reduced` names, and every line of the layer equations that
+    config.json does not give under `assumed`."""
+    on_disk = _load("chipbench/configs/phi-4-mini-flash-reasoning.json")
+    catalog = {
+        "embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560,
+        "intermediate_size": 10240, "layer_norm_eps": 1e-05,
+        "max_position_embeddings": 262144, "mb_per_layer": 2,
+        "model_type": "phi4flash", "num_attention_heads": 40,
+        "num_hidden_layers": 32, "num_key_value_heads": 20,
+        "resid_pdrop": 0, "sliding_window": 512,
+        "tie_word_embeddings": True, "mlp_bias": False,
+        "lm_head_bias": False, "vocab_size": 200064}
+    differs = {k for k, v in catalog.items() if on_disk[k] != v}
+    assert differs == set(on_disk["reduced"]) == {"num_hidden_layers",
+                                                  "vocab_size"}
+    assert (on_disk["num_hidden_layers"], on_disk["vocab_size"]) == (
+        8, 50016)
+    assert on_disk["vocab_size"] * 4 == catalog["vocab_size"]
+    assert set(on_disk["reduced_from"]) == set(on_disk["reduced"])
+    assert {"mamba_d_state", "mamba_d_conv", "mamba_expand",
+            "mamba_dt_rank", "block", "mlp", "mamba1",
+            "differential_attention", "windows", "gmu", "cross_attention",
+            "init", "dtype"} <= set(on_disk["assumed"])
+    for key in ("departures", "deployment"):
         assert on_disk[key], key
 
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
-    """On a tree from before models/hybrid.py (the parent commit, with
+    """On a tree from before models/sambay.py (the parent commit, with
     this benchmark laid over it) looking the cell up fails at once, in
     run.py's own process, before a cluster or a chip is touched."""
     import shutil
@@ -186,18 +196,19 @@ def test_family_refuses_a_tree_without_the_program(tmp_path):
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
     shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
                     ignore=shutil.ignore_patterns(
-                        "__pycache__", "hybrid.py", "*.so"))
+                        "__pycache__", "sambay.py", "selective_scan.py",
+                        "*.so"))
     proc = subprocess.run(
         [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
          "1", "--seconds", "1", "--trace", "0"], cwd=tree,
         capture_output=True, text=True, timeout=60,
         env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
     assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
-    assert "cannot run a granite-hybrid configuration" in proc.stderr
+    assert "cannot run a sambay configuration" in proc.stderr
     assert proc.stdout.strip() == ""
 
 
-READERS = ("ssm_scan_ms_per_step", "ssm_scan_roofline")
+READERS = ("selective_scan_ms_per_step", "selective_scan_roofline")
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -206,30 +217,46 @@ def test_reader_returns_nothing_on_an_empty_record(name):
 
     empty = {"counters": {"chips": 1}, "trace": {}, "seconds": 1.0}
     assert harness.reader(name).read(empty) is None
-    # a program with attention kernels only (the parent) has no such row
+    # a program with Mamba-2's scan and attention kernels only (the
+    # parent) has no such row
     others = {"counters": {"chips": 1}, "seconds": 1.0, "trace": {
-        "steps": 4, "mosaic_by_name": {"mosaic:flash_attention_fwd": 0.1}}}
+        "steps": 4, "mosaic_by_name": {"mosaic:flash_attention_fwd": 0.1,
+                                       "mosaic:ssm_scan_fwd": 0.1}}}
     assert harness.reader(name).read(others) is None
+
+
+def test_ssm_scan_readers_do_not_read_the_selective_scan_rows():
+    from chipbench import harness
+
+    mine = {"counters": {"chips": 1}, "seconds": 1.0, "trace": {
+        "steps": 4, "mosaic_by_name": {
+            "mosaic:selective_scan_fwd": 0.1,
+            "mosaic:transpose_jvp_selective_scan_bwd__": 0.2}}}
+    assert harness.reader("ssm_scan_ms_per_step").read(mine) is None
+    assert harness.reader("selective_scan_ms_per_step").read(
+        mine) == pytest.approx(75.0)
 
 
 def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     """A hand-made mosaic_by_name at the cell's real sizes: 4 traced
-    steps, the scan kernels 0.04 + 0.1 s. By hand, nine Mamba-2 layers of
-    16,384 tokens: operations 9 x 3 x 16384 x (256 x 128 + 256 x 4096 + 4 x
-    4096 x 128) = 1.4061e12 -> 7.14 ms at 197 TFLOP/s; bytes 9 x (16384 x
-    (17152 + 26112) + 2 x 64 x 4096 x 128 x 4) = 8.7952e9 -> 10.74 ms at 819
-    GB/s, the larger: 10.74 / 35 ms = 30.68%."""
+    steps, the scan kernels 0.04 + 0.1 s. By hand, three Mamba-1 layers of
+    16,384 tokens x 5,120 channels x 16 states: operations 3 x 3 x 6 x
+    16384 x 5120 x 16 = 7.248e10 -> 0.37 ms at 197 TFLOP/s; bytes 3 x
+    16384 x (5120 x 22 + 6 x 16 x 2) = 5.546e9 -> 6.77 ms at 819 GB/s, the
+    larger: 6.77 / 35 ms = 19.35%. The attention kernels: two windowed
+    layers over the band and two over the triangle."""
     code = r"""
 import json, sys
 sys.path.insert(0, %r)
 from chipbench import harness
 record = {
-    "config": json.load(open("chipbench/configs/granite-4.0-h-micro.json")),
+    "config": json.load(open(
+        "chipbench/configs/phi-4-mini-flash-reasoning.json")),
     "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
                  "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
     "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:ssm_scan_fwd": 0.04,
-        "mosaic:transpose_jvp_ssm_scan_bwd__": 0.1,
+        "mosaic:selective_scan_fwd": 0.04,
+        "mosaic:transpose_jvp_selective_scan_bwd__": 0.1,
         "mosaic:flash_attention_fwd": 0.06,
         "mosaic:flash_attention_dq": 0.07,
         "mosaic:flash_attention_dkv": 0.12}}}
@@ -241,13 +268,16 @@ print(json.dumps(out))
                           capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0, proc.stderr[-2000:]
     got = json.loads(proc.stdout)
-    assert got["ssm_scan_ms_per_step"] == pytest.approx(35.0)
-    nbytes = 9 * (16384 * (17152 + 26112) + 2 * 64 * 4096 * 128 * 4)
-    flops = 9 * 3 * 16384 * (256 * 128 + 256 * 4096 + 4 * 4096 * 128)
+    assert got["selective_scan_ms_per_step"] == pytest.approx(35.0)
+    nbytes = 3 * 16384 * (5120 * 22 + 6 * 16 * 2)
+    flops = 3 * 3 * 6 * 16384 * 5120 * 16
     assert flops / 197e12 < nbytes / 819e9          # the bytes bound applies
-    assert got["ssm_scan_roofline"] == pytest.approx(
+    assert got["selective_scan_roofline"] == pytest.approx(
         100 * (nbytes / 819e9) / 0.035)
-    assert got["ssm_scan_roofline"] == pytest.approx(30.68, abs=0.01)
-    attn = 6 * 2 * 16384 ** 2 * 2048 / 2
+    assert got["selective_scan_roofline"] == pytest.approx(19.35, abs=0.01)
+    S, w = 16384, 512
+    band = w * (w + 1) // 2 + (S - w) * w
+    triangle = S * (S + 1) // 2
+    attn = 40 * 2 * 9 * 64 * (2 * band + 2 * triangle)
     assert got["attn_scoped_roofline"] == pytest.approx(
         100 * (attn / 197e12) / 0.0625)
