@@ -29,6 +29,7 @@ while a Mamba-1 layer still follows it in the stack, and sees everything
 after the last one: which, `decoder_hidden` reads off the weights too.
 
     decoder_hidden      embedding, layer stack, final norm, head
+    decoder_logits      its rows times its head, float32
       attention | mamba2 | mamba1 | gmu | diff_attention
                         the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window]) -> (y, new
@@ -67,6 +68,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d, gated_rms_norm,
                           layer_norm, rms_norm, rope, swiglu)
+from ..ops.loss import chip_views, lookup
 from ..ops.selective_scan import selective_scan
 from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import dropless_moe_layer
@@ -560,12 +562,21 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
 def decoder_hidden(params: Dict, tokens, dec: Decoder,
                    cache: Optional[List[Dict]] = None, start_pos=None):
     """tokens [b, L] -> (final-norm rows [b, L, d], the output head
-    [d, vocab], the mixers' `stats` summed over layers or None, the new
-    cache or None). With a `cache` (an `empty_cache`, or the last call's) the
-    tokens sit at `start_pos` + [0, L) and the mixers read and write it;
-    with none this is the training forward. The rows come multiplied by
+    [d, vocab] (for `cross_entropy` or `decoder_logits`: a tied head under
+    a data-parallel training step is `chip_views`, [chips, d, vocab]), the
+    mixers' `stats` summed over layers or None, the new cache or None).
+    With a `cache` (an `empty_cache`, or the last call's) the tokens sit
+    at `start_pos` + [0, L) and the mixers read and write it; with none
+    this is the training forward. The rows come multiplied by
     `dec.logit_scale`, so rows @ head are the model's logits."""
-    x = _scaled(jnp.take(params["embed"], tokens, axis=0), dec.embed_scale)
+    # A tied table under a data-parallel training step is one view a
+    # chip, so that its lookup's and its head's gradients cross the chips
+    # as one sum (ops/loss.py chip_views).
+    views = None if "head" in params or cache is not None \
+        else chip_views(params["embed"])
+    x = lookup(views, tokens) if views is not None \
+        else jnp.take(params["embed"], tokens, axis=0)
+    x = _scaled(x, dec.embed_scale)
     layers = params["layers"]
     # Differential attention is windowed while a Mamba-1 layer follows.
     last_scan = max((i for i, layer in enumerate(layers)
@@ -594,5 +605,15 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
                 jnp.add, total, stats)
             new_cache.append(cache_layer)
     x = _scaled(_norm(x, params, "lnf", dec.norm_eps), dec.logit_scale)
-    head = params["head"] if "head" in params else params["embed"].T
+    if views is not None:
+        head = views.swapaxes(1, 2)
+    else:
+        head = params["head"] if "head" in params else params["embed"].T
     return x, head, total, (new_cache if cache is not None else None)
+
+
+def decoder_logits(x, head):
+    """decoder_hidden's rows and head -> float32 logits [b, L, vocab]."""
+    if head.ndim == 3:          # chip_views: every view is the one table
+        head = head[0]
+    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)

@@ -19,7 +19,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .decoder import decoder_hidden, empty_cache
+from .decoder import decoder_hidden, decoder_logits, empty_cache
 from .hybrid import MAMBA
 
 
@@ -49,7 +49,7 @@ def cached_forward(params: Dict, tokens, cache: List[Dict],
     (logits [b, L, vocab] fp32, new_cache)."""
     x, head, _, new_cache = decoder_hidden(
         params, tokens, cfg.decoder(), cache, start_pos)
-    return (jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32),
+    return (decoder_logits(x, head),
             new_cache)
 
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, gelu_mlp
+from .decoder import Decoder, decoder_hidden, decoder_logits, gelu_mlp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,7 +132,7 @@ def gpt_param_axes(cfg: GPTConfig) -> Dict:
 def gpt_forward(params: Dict, tokens, cfg: GPTConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] (fp32)."""
     x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
-    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    return decoder_logits(x, head)
 
 
 def gpt_loss(params: Dict, batch: Tuple, cfg: GPTConfig):

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, keep_kernel_outputs, swiglu_mlp
+from .decoder import (Decoder, decoder_hidden, decoder_logits,
+                      keep_kernel_outputs, swiglu_mlp)
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -214,7 +215,7 @@ def hybrid_param_axes(cfg: HybridConfig) -> Dict:
 def hybrid_forward(params: Dict, tokens, cfg: HybridConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
     x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
-    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    return decoder_logits(x, head)
 
 
 def hybrid_loss(params: Dict, batch: Tuple, cfg: HybridConfig):

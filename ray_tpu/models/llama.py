@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, swiglu_mlp
+from .decoder import Decoder, decoder_hidden, decoder_logits, swiglu_mlp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +122,7 @@ def llama_param_axes(cfg: LlamaConfig) -> Dict:
 def llama_forward(params: Dict, tokens, cfg: LlamaConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
     x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
-    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    return decoder_logits(x, head)
 
 
 def llama_loss(params: Dict, batch: Tuple, cfg: LlamaConfig):

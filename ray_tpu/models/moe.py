@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, keep_kernel_outputs,
-                      routed_experts)
+from .decoder import (Decoder, decoder_hidden, decoder_logits,
+                      keep_kernel_outputs, routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,7 +180,7 @@ def moe_forward(params: Dict, tokens, cfg: MoEConfig):
     """tokens [b, s] -> (logits [b, s, vocab] fp32, the weighted
     auxiliary loss that moe_loss adds to the cross entropy)."""
     x, head, counters = _hidden(params, tokens, cfg)
-    logits = jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    logits = decoder_logits(x, head)
     return logits, _aux_loss(counters, cfg)
 
 

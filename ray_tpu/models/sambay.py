@@ -35,8 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, fused_swiglu_mlp,
-                      keep_kernel_outputs)
+from .decoder import (Decoder, decoder_hidden, decoder_logits,
+                      fused_swiglu_mlp, keep_kernel_outputs)
 
 MAMBA, WINDOWED, FULL, GMU, CROSS = (
     "mamba", "windowed_attention", "full_attention", "gmu",
@@ -269,7 +269,7 @@ def sambay_param_axes(cfg: SambaYConfig) -> Dict:
 def sambay_forward(params: Dict, tokens, cfg: SambaYConfig):
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] fp32."""
     x, head, _, _ = decoder_hidden(params, tokens, cfg.decoder())
-    return jnp.einsum("bsd,dv->bsv", x, head).astype(jnp.float32)
+    return decoder_logits(x, head)
 
 
 def sambay_loss(params: Dict, batch: Tuple, cfg: SambaYConfig):

@@ -12,9 +12,16 @@ models._training.make_train_step_for sets while it traces) each chip scans
 its own rows: a scan slices at a traced offset, which GSPMD cannot
 partition along the sharded axis, so left to it every chip would gather
 the whole batch and compute every chunk.
+
+A table that is both the embedding and the head (`chip_views`) reaches the
+loss as one view a chip of the batch axes, so that its two gradients, the
+lookup's and the head's, are added on each chip before anything crosses
+chips: the step reduces a tied table once, not once a use.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -24,13 +31,66 @@ from .attention import step_sharding
 _LOSS_CHUNK = 4096
 
 
+def _batch_split():
+    """(mesh, PartitionSpec over the batch axes, their names) of the
+    enclosing sharded step, or None where it has no batch axes."""
+    from jax.sharding import PartitionSpec as P
+
+    split = step_sharding()
+    axes = split[1][0] if split is not None else None
+    if axes is None:
+        return None
+    return split[0], P(axes), {axes} if isinstance(axes, str) else set(axes)
+
+
+def chip_views(table):
+    """`table` as [chips, *table.shape], one view for each chip of the
+    enclosing step's batch axes and sharded over them, or None where
+    those span one chip or there is no such step.
+
+    Every use of a view (`lookup`, `cross_entropy`) runs on its chip and
+    leaves its gradient there, a partial sum; autodiff adds a view's
+    gradients where they are, and the one sum over chips is this
+    function's: a table used twice crosses the chips once. The views cost
+    nothing forward (each chip already holds the table)."""
+    split = _batch_split()
+    if split is None or math.prod(split[0].shape[a] for a in split[2]) == 1:
+        return None
+    mesh, rows, manual = split
+
+    @jax.custom_vjp
+    def views(table):
+        from jax.sharding import PartitionSpec as P
+        return jax.shard_map(lambda t: t[None], mesh=mesh, in_specs=P(),
+                             out_specs=rows, axis_names=manual,
+                             check_vma=False)(table)
+
+    # The sum over the stacked partial gradients is GSPMD's all-reduce,
+    # in the table's dtype as the bytes on the wire were before.
+    views.defvjp(lambda table: (views(table), None),
+                 lambda _, g: (jnp.sum(g, axis=0),))
+    return views(table)
+
+
+def lookup(views, tokens):
+    """Rows of a table given as `chip_views`, tokens [batch, ...] ->
+    [batch, ..., d]: each chip takes its own rows from its own view, and
+    the gradient (a scatter-add) stays in that view."""
+    mesh, rows, manual = _batch_split()
+    return jax.shard_map(
+        lambda view, ids: jnp.take(view[0], ids, axis=0), mesh=mesh,
+        in_specs=(rows, rows), out_specs=rows, axis_names=manual,
+        check_vma=False)(views, tokens)
+
+
 def cross_entropy(x, head, targets):
     """Mean over every row of -log softmax(x @ head)[target].
 
-    x [batch, ..., d] hidden rows, head [d, vocab], targets [batch, ...]
-    int. bf16 operands keep their precision (the logits are the matmul's
-    output in the operands' dtype); the log-sum-exp and every
-    accumulation are float32."""
+    x [batch, ..., d] hidden rows, head [d, vocab] or, from `chip_views`,
+    [chips, d, vocab] (its gradient is then one partial sum a chip, which
+    the views add up), targets [batch, ...] int. bf16 operands keep their
+    precision (the logits are the matmul's output in the operands'
+    dtype); the log-sum-exp and every accumulation are float32."""
     with jax.named_scope("loss"):
         return -_sum_ll(x, head, targets) / targets.size
 
@@ -45,36 +105,37 @@ def _sum_ll_fwd(x, head, targets):
     """The loss and, as residuals, its whole gradient: dx like x, and the
     head's as one float32 [d, vocab] partial sum per chip of the batch
     axes, stacked; _sum_ll_bwd adds them up."""
-    split = step_sharding()
-    batch_axes = split[1][0] if split is not None else None
-    if batch_axes is None:
+    split = _batch_split()
+    if split is None:
         total, dx, dhead = _scan_chunks(x, head, targets)
         return total, (dx, dhead[None], head)
 
     # Each chip scans its own rows; the other axes (tp on vocab, fsdp on
     # embed) stay with GSPMD. Nothing is reduced in here: the sums over
-    # the stacked partial results below and in _sum_ll_bwd are GSPMD's,
-    # one all-reduce each, after the scan.
+    # the stacked partial results below and in _sum_ll_bwd (or, of a
+    # head given as views, in chip_views) are GSPMD's, one all-reduce
+    # each, after the scan.
     from jax.sharding import PartitionSpec as P
 
-    mesh = split[0]
-    rows = P(batch_axes)
-    manual = {batch_axes} if isinstance(batch_axes, str) else set(batch_axes)
+    mesh, rows, manual = split
 
     def per_chip(xl, hl, tl):
-        total, dx, dhead = _scan_chunks(xl, hl, tl)
+        total, dx, dhead = _scan_chunks(xl, hl[0] if hl.ndim == 3 else hl, tl)
         return total[None], dx, dhead[None]
 
     totals, dx, dheads = jax.shard_map(
-        per_chip, mesh=mesh, in_specs=(rows, P(), rows),
+        per_chip, mesh=mesh,
+        in_specs=(rows, rows if head.ndim == 3 else P(), rows),
         out_specs=(rows, rows, rows), axis_names=manual,
         check_vma=False)(x, head, targets)
     return jnp.sum(totals), (dx, dheads, head)
 
 
 def _sum_ll_bwd(residuals, g):
-    dx, dheads, head = residuals      # head for its dtype alone
-    dhead = jnp.sum((g * dheads).astype(head.dtype), axis=0)
+    dx, dheads, head = residuals      # head for its dtype and rank alone
+    dhead = (g * dheads).astype(head.dtype)
+    if head.ndim == 2:
+        dhead = jnp.sum(dhead, axis=0)
     return (g * dx).astype(dx.dtype), dhead, None
 
 

@@ -32,7 +32,9 @@ import contextlib
 import dataclasses
 import functools
 import glob
+import math
 import os
+import re
 import sys
 import tempfile
 import time
@@ -178,6 +180,127 @@ def kernel_calls(compiled_text: str) -> Dict[str, int]:
                      name.rstrip(".0123456789"))
         calls[scope] = calls.get(scope, 0) + 1
     return calls
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute", "collective-broadcast")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                "u64": 8}
+_ARRAY = re.compile(r"\b(%s)\[([\d,]*)\]" % "|".join(_DTYPE_BYTES))
+_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
+_CALLEE = re.compile(r"calls=%?([\w.-]+)")
+_ASYNC_FUSION = re.compile(r'custom_call_target="AsyncCollective(Start|Done)"')
+# What a collective can run under: whatever the schedule gives the
+# TensorCore to do meanwhile.
+_WORK = ("fusion", "custom-call", "while", "convolution", "dot")
+
+
+def _computations(compiled_text: str) -> Dict[str, list]:
+    """computation -> [(instruction, its shape, opcode, operands and
+    attributes)] of an HLO module's text, in the order printed: for a
+    scheduled module (`is_scheduled=true`) the order it runs in."""
+    computations, body = {}, None
+    for line in compiled_text.splitlines():
+        if line.startswith("}"):
+            body = None
+        elif not line.startswith(" "):
+            head = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+            body = computations.setdefault(head.group(1), []) if head else None
+        elif body is not None:
+            name, eq, rest = line.strip().partition(" = ")
+            op = _OPCODE.search(rest) if eq else None
+            if op:
+                body.append((name.removeprefix("ROOT ").lstrip("%"),
+                             rest[:op.start()], op.group(1), rest[op.end():]))
+    return computations
+
+
+def collective_calls(compiled_text: str) -> Dict[str, Any]:
+    """The collectives of a compiled program and where they stand in its
+    schedule: a static counter like `kernel_calls`, read from
+    `jitted.lower(...).compile().as_text()` and not from a run.
+
+    `collectives` has one entry a collective, in schedule order: `name`,
+    `kind` (one of COLLECTIVES), `operands` (each operand's shape as
+    printed, without its layout), `bytes` (theirs together), `async` (a
+    `-start` / `-done` pair, or XLA:TPU's `async-collective-start` /
+    `-done` fusions round the collective), `between` (the fusions, custom
+    calls, loops, convolutions and dots scheduled between its start and
+    its done: what it can run under; 0 for a blocking one) and `kernels`
+    (the Mosaic calls among them, by instruction name). A collective in a
+    loop's body counts once.
+
+    From them two numbers for a data-parallel train step:
+    `gradient_reduce_bytes`, the bytes the program's all-reduces take in
+    (gpt2-small under dp=4: 324 MB while the tied table's lookup and head
+    halves crossed the chips apart, 247 MB, the parameters' own bytes,
+    since they are added first), and `async_share`, the share of those
+    bytes whose reduce is asynchronous with work in between (0.0 where
+    every gradient is reduced by a blocking instruction and the
+    TensorCore waits for each). On the chip
+    `collective_exposed_ms_per_step` reads the result (PERF.md §3)."""
+    computations = _computations(compiled_text)
+    # XLA:TPU's async collective: two fusions whose fused computations
+    # hold the collective itself and an AsyncCollectiveStart / -Done, and
+    # between them `async_collective_fusion`s: compute that carries the
+    # collective on, its instruction standing in each of them again.
+    fused = {m.group(1) for body in computations.values()
+             for _, _, op, rest in body
+             if op == "fusion" and (m := _CALLEE.search(rest))}
+    wrapped = {}              # such a fused computation -> (its part, kind)
+    for comp in fused & computations.keys():
+        body = computations[comp]
+        kind = next((op for _, _, op, _ in body if op in COLLECTIVES), None)
+        if kind:
+            wrapped[comp] = (next((m.group(1) for *_, rest in body if (
+                m := _ASYNC_FUSION.search(rest))), "Carry"), kind)
+
+    def async_half(op, rest):
+        callee = _CALLEE.search(rest) if op == "fusion" else None
+        return wrapped.get(callee.group(1) if callee else None, (None, None))
+
+    found = []
+    for comp, body in computations.items():
+        if comp in wrapped:
+            continue
+        shapes = {name: shape for name, shape, _, _ in body}
+        element_of = {}       # a start's tuple element -> the start
+        started = {}          # a start -> (its entry, its place)
+        for at, (name, _, op, rest) in enumerate(body):
+            args = re.findall(r"%([\w.-]+)", rest.split(")", 1)[0])
+            half, inner = async_half(op, rest)
+            belongs = next((s for s in (element_of.get(a, a) for a in args)
+                            if s in started), None)
+            if belongs and (op == "get-tuple-element" or half == "Carry"):
+                element_of[name] = belongs
+            kind = inner if half == "Start" else next(
+                (c for c in COLLECTIVES if op in (c, c + "-start")), None)
+            if kind:
+                operands = [re.sub(r"\{[^{}]*\}", "", shapes.get(a, "")).strip()
+                            for a in args]
+                entry = {"name": name, "kind": kind, "operands": operands,
+                         "bytes": sum(
+                             _DTYPE_BYTES[dtype] * math.prod(
+                                 int(n) for n in dims.split(",") if n)
+                             for shape in operands
+                             for dtype, dims in _ARRAY.findall(shape)),
+                         "async": False, "between": 0, "kernels": []}
+                found.append(entry)
+                if op != kind:
+                    started[name] = (entry, at)
+            elif belongs and (half == "Done" or op.endswith("-done")):
+                entry, began = started.pop(belongs)
+                work = [(n, r) for n, _, o, r in body[began + 1:at]
+                        if o in _WORK
+                        and async_half(o, r)[0] in (None, "Carry")]
+                entry.update({"async": True, "between": len(work), "kernels": [
+                    n for n, r in work if '"tpu_custom_call"' in r]})
+    reduces = [c for c in found if c["kind"] == "all-reduce"]
+    total = sum(c["bytes"] for c in reduces)
+    hidden = sum(c["bytes"] for c in reduces if c["async"] and c["between"])
+    return {"collectives": found, "gradient_reduce_bytes": total,
+            "async_share": hidden / total if total else 0.0}
 
 
 # The worker-level actor method behind profile_actor: any actor's worker

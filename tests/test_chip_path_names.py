@@ -215,6 +215,134 @@ def test_kernel_calls_counts_mosaic_calls_by_scope():
     assert profiling.kernel_calls("") == {}
 
 
+# Three scheduled programs as XLA:TPU prints them, cut to what
+# collective_calls reads (tests/test_compile_v5e_loss.py reads the whole
+# dp=4 step): the combiner's blocking tuple; a start/done pair; and an
+# async collective fusion, whose collective stands in the start's and the
+# done's fused computation and again in the fusion that carries it on.
+_BLOCKING = """HloModule jit_train_step, is_scheduled=true
+
+%add.clone (x: bf16[], y: bf16[]) -> bf16[] {
+  %x = bf16[]{:T(256)} parameter(0)
+  %y = bf16[]{:T(256)} parameter(1)
+  ROOT %add.3 = bf16[]{:T(256)} add(%x, %y)
+}
+
+ENTRY %main.1_spmd (param.1: bf16[768,3072]) -> (bf16[768,3072], bf16[3072,768]) {
+  %param.1 = bf16[768,3072]{1,0:T(8,128)(2,1)} parameter(0)
+  %fusion.7 = bf16[768,3072]{1,0:T(8,128)(2,1)} fusion(%param.1), kind=kOutput, calls=%fused_computation.7
+  %fusion.8 = bf16[3072,768]{1,0:T(8,128)(2,1)} fusion(%param.1), kind=kOutput, calls=%fused_computation.8
+  %scale.2 = f32[768]{0:T(1024)} fusion(%param.1), kind=kLoop, calls=%fused_computation.9
+  %all-reduce.78 = (bf16[768,3072]{1,0:T(8,128)(2,1)}, bf16[3072,768]{1,0:T(8,128)(2,1)}, f32[768]{0:T(1024)}) all-reduce(%fusion.7, %fusion.8, %scale.2), channel_id=2, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.clone
+  %flash_attention_dq.21 = bf16[8,12,1024,64]{3,2,1,0} custom-call(%param.1), custom_call_target="tpu_custom_call"
+  ROOT %tuple.1 = (bf16[768,3072]{1,0}, bf16[3072,768]{1,0}) tuple(%all-reduce.78)
+}
+"""
+
+_START_DONE = """HloModule jit_step, is_scheduled=true
+
+ENTRY %main.2 (p: f32[1024,1024], q: f32[8]) -> f32[1024,1024] {
+  %p = f32[1024,1024]{1,0} parameter(0)
+  %q = f32[8]{0} parameter(1)
+  %all-reduce-start.1 = f32[1024,1024]{1,0} all-reduce-start(%p), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add
+  %fusion.3 = f32[1024,1024]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.3
+  %flash_attention_dkv.4 = f32[8]{0} custom-call(%q), custom_call_target="tpu_custom_call"
+  %bitcast.5 = f32[8]{0} bitcast(%flash_attention_dkv.4)
+  %all-reduce-done.1 = f32[1024,1024]{1,0} all-reduce-done(%all-reduce-start.1)
+  %all-gather.2 = f32[32]{0} all-gather(%q), dimensions={0}
+  ROOT %add.9 = f32[1024,1024]{1,0} add(%all-reduce-done.1, %fusion.3)
+}
+"""
+
+_ASYNC_FUSION = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.1595 (param_0.1: bf16[768,768]) -> (bf16[768,768], bf16[768,768], u32[]) {
+  %param_0.1 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %all-reduce.277 = bf16[768,768]{1,0:T(8,128)(2,1)} all-reduce(%param_0.1), channel_id=8, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.2.clone
+  ROOT %custom-call.9 = (bf16[768,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,768]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) custom-call(%all-reduce.277), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.1194 (param_0.2: bf16[768,768], param_1.2: bf16[768,768], param_2.2: u32[], param_3.2: bf16[8,1024,768]) -> (bf16[768,768], bf16[768,768], bf16[768,768], u32[]) {
+  %param_0.2 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %param_1.2 = bf16[768,768]{1,0:T(8,128)(2,1)} parameter(1)
+  %param_2.2 = u32[]{:S(2)} parameter(2)
+  %param_3.2 = bf16[8,1024,768]{2,1,0:T(8,128)(2,1)} parameter(3)
+  %convolution.399 = bf16[768,768]{1,0:T(8,128)(2,1)} convolution(%param_3.2, %param_3.2), dim_labels=0fb_0io->bf0
+  %all-reduce.279 = bf16[768,768]{1,0:T(8,128)(2,1)} all-reduce(%param_0.2), channel_id=8, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.2.clone
+  ROOT %tuple.530 = (bf16[768,768]{1,0}, bf16[768,768]{1,0}, bf16[768,768]{1,0}, u32[]{:S(2)}) tuple(%convolution.399, %param_0.2, %param_1.2, %param_2.2)
+}
+
+%fused_computation.1597 (param_0.3: bf16[768,768], param_1.3: bf16[768,768], param_2.3: u32[]) -> bf16[768,768] {
+  %param_0.3 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %param_1.3 = bf16[768,768]{1,0:T(8,128)(2,1)} parameter(1)
+  %all-reduce.281 = bf16[768,768]{1,0:T(8,128)(2,1)} all-reduce(%param_0.3), channel_id=8, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.2.clone
+  %param_2.3 = u32[]{:S(2)} parameter(2)
+  ROOT %custom-call.11 = bf16[768,768]{1,0:T(8,128)(2,1)} custom-call(%param_0.3, %param_1.3, %all-reduce.281, %param_2.3), custom_call_target="AsyncCollectiveDone"
+}
+
+ENTRY %main.122_spmd (param.5: bf16[8,1024,768]) -> bf16[768,768] {
+  %param.5 = bf16[8,1024,768]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %convolution_bitcast_fusion.11 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} fusion(%param.5), kind=kOutput, calls=%fused_computation.11
+  %async-collective-start = (bf16[768,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,768]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) fusion(%convolution_bitcast_fusion.11), kind=kCustom, output_to_operand_aliasing={{0}: (0, {})}, calls=%fused_computation.1595
+  %get-tuple-element.1146 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} get-tuple-element(%async-collective-start), index=0
+  %get-tuple-element.1147 = bf16[768,768]{1,0:T(8,128)(2,1)} get-tuple-element(%async-collective-start), index=1
+  %get-tuple-element.1148 = u32[]{:S(2)} get-tuple-element(%async-collective-start), index=2
+  %fusion.1194 = (bf16[768,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,768]{1,0:T(8,128)(2,1)S(1)}, bf16[768,768]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) fusion(%get-tuple-element.1146, %get-tuple-element.1147, %get-tuple-element.1148, %param.5), kind=kOutput, calls=%async_collective_fusion.1194
+  %get-tuple-element.1150 = bf16[768,768]{1,0:T(8,128)(2,1)S(1)} get-tuple-element(%fusion.1194), index=1
+  %get-tuple-element.1151 = bf16[768,768]{1,0:T(8,128)(2,1)} get-tuple-element(%fusion.1194), index=2
+  %get-tuple-element.1152 = u32[]{:S(2)} get-tuple-element(%fusion.1194), index=3
+  %copy.4 = bf16[8,1024,768]{2,1,0:T(8,128)(2,1)} copy(%param.5)
+  %async-collective-done = bf16[768,768]{1,0:T(8,128)(2,1)} fusion(%get-tuple-element.1150, %get-tuple-element.1151, %get-tuple-element.1152), kind=kCustom, output_to_operand_aliasing={{}: (1, {})}, calls=%fused_computation.1597
+  %all-reduce.556 = bf16[3072,768]{1,0:T(8,128)(2,1)} all-reduce(%async-collective-done), channel_id=9, replica_groups=[1,4]<=[4], to_apply=%add.2.clone
+  ROOT %copy.5 = bf16[768,768]{1,0:T(8,128)(2,1)} copy(%async-collective-done)
+}
+"""
+
+
+def test_collective_calls_reads_a_blocking_tuple():
+    got = profiling.collective_calls(_BLOCKING)
+    assert got["collectives"] == [{
+        "name": "all-reduce.78", "kind": "all-reduce",
+        "operands": ["bf16[768,3072]", "bf16[3072,768]", "f32[768]"],
+        "bytes": 2 * 2 * 768 * 3072 + 4 * 768,
+        "async": False, "between": 0, "kernels": []}]
+    assert got["gradient_reduce_bytes"] == 9_440_256
+    assert got["async_share"] == 0.0
+    assert profiling.collective_calls("") == {
+        "collectives": [], "gradient_reduce_bytes": 0, "async_share": 0.0}
+
+
+def test_collective_calls_reads_a_start_done_pair():
+    got = profiling.collective_calls(_START_DONE)
+    reduce, gather = got["collectives"]
+    # A fusion and a kernel stand between the start and the done; the
+    # bitcast is no work.
+    assert (reduce["name"], reduce["kind"], reduce["async"],
+            reduce["between"], reduce["kernels"]) == (
+        "all-reduce-start.1", "all-reduce", True, 2,
+        ["flash_attention_dkv.4"])
+    assert reduce["bytes"] == 4 * 1024 * 1024
+    assert (gather["kind"], gather["async"], gather["bytes"]) == (
+        "all-gather", False, 32)
+    # Only all-reduces are gradient reduces.
+    assert got["gradient_reduce_bytes"] == 4 * 1024 * 1024
+    assert got["async_share"] == 1.0
+
+
+def test_collective_calls_reads_an_async_collective_fusion():
+    got = profiling.collective_calls(_ASYNC_FUSION)
+    fused, blocking = got["collectives"]
+    # One collective, though its instruction is printed three times; the
+    # fusion that carries it on is the work it runs under.
+    assert (fused["name"], fused["kind"], fused["operands"], fused["async"],
+            fused["between"], fused["kernels"]) == (
+        "async-collective-start", "all-reduce", ["bf16[768,768]"], True, 1,
+        [])
+    assert (blocking["name"], blocking["async"]) == ("all-reduce.556", False)
+    assert got["gradient_reduce_bytes"] == 2 * 2 * 768 * 768
+    assert got["async_share"] == 0.5
+
+
 def test_the_chip_path_opens_spans_through_profiling_only():
     other = _literals(r"(TraceAnnotation|tracing\.span|start_trace)\(",
                       CHIP_PATH)

@@ -2,12 +2,15 @@
 data-parallel gpt2-small train step of the `gpt2s-train-dp4` cell keeps
 no chunk's logits, gathers no activations, and fits a chip at 16
 sequences a chip (global B=64; 16.62 GB of 15.75 before ops/loss.py took
-the gradient in the forward scan; PERF.md has the figure now), and the
-three attention kernels compile at the plans `attention_plan` gives the
-cells' shapes. The topology is described inside a fixture (see the
+the gradient in the forward scan; PERF.md has the figure now), reduces
+each gradient once and asynchronously (`profiling.collective_calls` over
+the scheduled program: the way to look at a schedule without a chip), and
+the three attention kernels compile at the plans `attention_plan` gives
+the cells' shapes. The topology is described inside a fixture (see the
 on-chip-measurement guide)."""
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -57,6 +60,7 @@ def compile_dp4(topo):
         jax.eval_shape(lambda: make_train_step(cfg)[0](
             jax.random.PRNGKey(0))))
 
+    @functools.cache        # three tests read the B=32 step: compile it once
     def compile_at(global_batch):
         # The backend here is the CPU, so flash_attention would take its
         # reference branch: steer it to the compiled Mosaic kernels.
@@ -78,7 +82,7 @@ def _total(mem) -> float:
 def test_dp4_step_keeps_no_logits_and_gathers_no_activations(compile_dp4):
     compiled = compile_dp4(32)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 36      # the kernels are in
+    assert text.count('custom_call_target="tpu_custom_call"') == 36
     assert not re.search(r"f32\[\d+,4096,50304\]", text)
     assert "all-gather" not in text
     # 8,192 rows a chip: a scan over 2 chunks, the head's gradient
@@ -96,6 +100,97 @@ def test_dp4_step_fits_a_chip_at_16_sequences_a_chip(compile_dp4,
     record_property("dp4_b64_bytes_per_chip", total)
     print(f"dp4 global B=64: {total / 1e9:.2f} GB a chip")
     assert total < HBM_BYTES
+
+
+def test_dp4_step_reduces_each_gradient_once(compile_dp4):
+    """The tied table's lookup and head halves are added on their chip
+    before anything crosses chips: one [50304, 768] reduce, and the bytes
+    reduced are the parameters' own (324 MB while each half crossed)."""
+    from ray_tpu.util import profiling
+
+    got = profiling.collective_calls(compile_dp4(32).as_text())
+    table = [c for c in got["collectives"]
+             if "bf16[50304,768]" in c["operands"]]
+    assert len(table) == 1 and table[0]["kind"] == "all-reduce"
+    assert 247e6 < got["gradient_reduce_bytes"] <= 250e6
+    assert {c["kind"] for c in got["collectives"]} == {"all-reduce"}
+
+
+def test_dp4_step_reduces_its_gradients_under_compute(compile_dp4):
+    """Every gradient of a megabyte or more is reduced alone, by an async
+    collective fusion with work scheduled between its start and its done:
+    XLA:TPU moves the weight gradients' matmuls behind the last backward
+    kernel and each carries the reduce of the one before it, and the
+    table's runs under the optimizer's fusions. No Mosaic call stands
+    between a start and a done: an async all-reduce advances only inside
+    fusions XLA makes itself (PERF.md §6, PR 32, has what the chip says)."""
+    from ray_tpu.util import profiling
+
+    got = profiling.collective_calls(compile_dp4(32).as_text())
+    large = [c for c in got["collectives"] if c["bytes"] > 1e6]
+    assert len(large) == 1 + 4 * 12      # the table, four matrices a layer
+    assert all(len(c["operands"]) == 1 for c in large)
+    assert all(c["async"] and c["between"] for c in large), [
+        c["name"] for c in large if not (c["async"] and c["between"])]
+    assert got["async_share"] >= 0.8
+    # What is smaller goes together and blocks: the norms' float32 scales.
+    small = [c for c in got["collectives"] if c["bytes"] <= 1e6]
+    assert sum(c["bytes"] for c in small) < 1e5 and len(small) <= 2
+    table = large[-1]                    # made last, reduced last
+    assert table["operands"] == ["bf16[50304,768]"]
+    assert table["between"] > 24         # AdamW's fusions, not one matmul
+    assert not any(c["kernels"] for c in large)
+
+
+def test_the_options_are_chosen_from_the_mesh_alone(topo):
+    """Batch axes over more than one TPU chip: the four options; a
+    tp-only mesh, no mesh, the CPU's devices: none."""
+    import jax
+
+    from ray_tpu.models import _training
+    from ray_tpu.parallel import MeshConfig, make_mesh, tp_rules
+
+    on = lambda config, devices: _training._reduce_options(  # noqa: E731
+        make_mesh(config, devices=devices), tp_rules())
+    assert on(MeshConfig(dp=4), topo.devices) == \
+        _training._ASYNC_GRADIENT_REDUCE
+    assert set(on(MeshConfig(dp=2, tp=2), topo.devices)) == set(
+        _training._ASYNC_GRADIENT_REDUCE)
+    assert on(MeshConfig(dp=1, tp=4), topo.devices) is None
+    assert on(MeshConfig(dp=4), jax.devices("cpu")[:4]) is None
+    assert _training._reduce_options(None, None) is None
+
+
+def test_one_device_step_lowers_as_without_the_mesh_path(topo, monkeypatch):
+    """A step over no mesh has no gradient reduce to schedule: it takes
+    no compiler option and lowers to the text it lowers to with the
+    tied table's per-chip views taken out of the program."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    import ray_tpu.models.decoder as decoder
+    import ray_tpu.ops.attention as attention
+    from ray_tpu.models import GPTConfig, make_train_step
+
+    cfg = dataclasses.replace(GPTConfig.gpt2_small(), remat=False)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+    def lowered_text():
+        init_state, step = make_train_step(cfg)
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0))))
+        tok = jax.ShapeDtypeStruct((16, cfg.max_seq_len), jnp.int32,
+                                   sharding=one_chip)
+        return step.lower(state, (tok, tok)).as_text()
+
+    with_path = lowered_text()
+    monkeypatch.setattr(decoder, "chip_views", lambda table: None)
+    assert lowered_text() == with_path
+    assert "all-reduce" not in with_path
 
 
 @pytest.mark.parametrize("bh,seq_len,head_dim", [
