@@ -173,6 +173,9 @@ class Node:
                  namespace: str = "default", session_dir: Optional[str] = None,
                  object_store_memory: Optional[int] = None):
         self.namespace = namespace
+        # api.init's own `ray_tpu.init` span (util/tracing.py Run), which a
+        # training run's run_timeline.json quotes.
+        self.init_span: Optional[dict] = None  # lint: guarded-by-ok set once by api.init under its _init_lock, before any reader; read by the controller's write_timeline
         # Snappier GIL handoff for the head's recv pump / handler pool /
         # submitter threads (see worker_proc.worker_main for the
         # measured rationale). Scoped to the runtime's lifetime: the

@@ -535,46 +535,31 @@ def serve_shed(deployment: str) -> None:
 # ---------------------------------------------------------------------------
 # device gauges of a process that runs jax (a chip worker)
 # ---------------------------------------------------------------------------
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_programs_built: Optional[int] = None   # None: not listening yet
-
-
-def _on_jax_duration(event, duration, **kw) -> None:
-    global _programs_built
-    if event == _COMPILE_EVENT:
-        _programs_built += 1
-
-
 def flush_device_gauges() -> None:
     """At metrics-push time, in a process that runs jax: how many
     programs it has built (every miss of jit's in-memory cache, whether
-    XLA compiled or the persistent cache answered; counted from this
-    worker's first push after jax was imported) and, once it has built
-    one, each local device's peak memory.
+    XLA compiled or the persistent cache answered; counted by
+    util/profiling.py's compile log, which a TrainWorker starts before
+    its loop and any other worker here, at its first push after jax was
+    imported) and, once it has built one, each local device's peak
+    memory.
 
     Never imports jax, never starts a backend and never waits for one:
     asking jax for its devices takes the lock that backend start-up
     holds, for as long as a jax.distributed gang takes to gather, and
     this runs on the thread that is about to send a completion. A
     program built is proof that start-up is over."""
-    global _ops, _programs_built
+    global _ops
     _ops += 1
     import sys
-    monitoring = sys.modules.get("jax.monitoring")
-    if monitoring is None:
+
+    from ..util.profiling import COMPILES
+    if not COMPILES.listen():
         return
-    if _programs_built is None:
-        # getattr: another thread may be half way through importing jax.
-        register = getattr(monitoring,
-                           "register_event_duration_secs_listener", None)
-        if register is None:
-            return
-        register(_on_jax_duration)
-        _programs_built = 0
     _metric("device_programs_built", "gauge",
-            "Programs this process built since its first metrics push"
-            ).set(float(_programs_built))
-    if not _programs_built:
+            "Programs this process built since its compile log began"
+            ).set(float(COMPILES.programs_built))
+    if not COMPILES.programs_built:
         return
     for dev in sys.modules["jax"].local_devices():
         stats = dev.memory_stats()

@@ -228,37 +228,42 @@ def init(address: Optional[str] = None, *, num_cpus: Optional[int] = None,
             state.set_local_runtime(LocalRuntime())
             return get_runtime_context()
         from ._private.runtime import Node
-        try:
-            node = Node(num_cpus=num_cpus, num_tpus=num_tpus,
-                        resources=resources, namespace=namespace,
-                        object_store_memory=object_store_memory)
-        except BaseException:
-            # Failed boot: roll the fault plane back (shutdown() never
-            # runs for a runtime that never existed) so a clean retry
-            # init isn't silently chaos-injected.
-            if fault_config is not None and _fault_installed_by_init:
-                from ._private import fault as fault_mod
-                fault_mod.configure(None)
-                _fault_installed_by_init = False
-            raise
-        state.set_node(node)
-        # Detached actors persisted by a previous head (same durable GCS
-        # path) respawn now — after the runtime is current, so creation
-        # machinery works (no-op without RAY_TPU_GCS_STORAGE_PATH).
-        try:
-            node.recover_detached_actors()
-        except Exception:
-            import traceback
-            print("[ray_tpu] detached-actor recovery failed:\n"
-                  + traceback.format_exc(), flush=True)
-        if log_to_driver:
-            node.log_monitor.start()
-        if prestart_workers is None:
-            prestart_workers = min(int(node.cluster_resources().get("CPU", 4)),
-                                   8)
-        if prestart_workers:
-            node.prestart_workers(prestart_workers)
-        return get_runtime_context()
+        from .util import tracing
+        # Always recorded (a span a driver): a training run's file quotes
+        # it, so that set-up splits from the cluster's start on.
+        with tracing.Run().span("ray_tpu.init") as init_span:
+            try:
+                node = Node(num_cpus=num_cpus, num_tpus=num_tpus,
+                            resources=resources, namespace=namespace,
+                            object_store_memory=object_store_memory)
+            except BaseException:
+                # Failed boot: roll the fault plane back (shutdown() never
+                # runs for a runtime that never existed) so a clean retry
+                # init isn't silently chaos-injected.
+                if fault_config is not None and _fault_installed_by_init:
+                    from ._private import fault as fault_mod
+                    fault_mod.configure(None)
+                    _fault_installed_by_init = False
+                raise
+            state.set_node(node)
+            node.init_span = init_span
+            # Detached actors persisted by a previous head (same durable GCS
+            # path) respawn now — after the runtime is current, so creation
+            # machinery works (no-op without RAY_TPU_GCS_STORAGE_PATH).
+            try:
+                node.recover_detached_actors()
+            except Exception:
+                import traceback
+                print("[ray_tpu] detached-actor recovery failed:\n"
+                      + traceback.format_exc(), flush=True)
+            if log_to_driver:
+                node.log_monitor.start()
+            if prestart_workers is None:
+                prestart_workers = min(
+                    int(node.cluster_resources().get("CPU", 4)), 8)
+            if prestart_workers:
+                node.prestart_workers(prestart_workers)
+            return get_runtime_context()
 
 
 _fault_installed_by_init = False

@@ -8,10 +8,12 @@ controller through the worker's report buffer.
 
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ..util import profiling, tracing
 from ..util.profiling import annotate
 from .checkpoint import Checkpoint
 
@@ -30,26 +32,50 @@ class TrainContext:
 class _Session:
     def __init__(self, context: TrainContext,
                  checkpoint: Optional[Checkpoint] = None,
-                 dataset_shards: Optional[Dict[str, Any]] = None):
+                 dataset_shards: Optional[Dict[str, Any]] = None,
+                 run: Optional[tracing.Run] = None):
         self.context = context
         self.restore_checkpoint = checkpoint
         self.dataset_shards = dataset_shards or {}
         self.reports: List[Dict] = []
         self.lock = threading.Lock()
         self.finished = False
+        # This worker's share of the run's timeline: its spans, and at
+        # three moments (the loop entered, the first report, the loop
+        # ended) a copy of them with the process's compile log for the
+        # controller's next poll to carry.
+        self.run = run or tracing.Run()
+        self.loop_span_id: Optional[str] = None
+        self._reported = False
+        self._timeline_due = False
 
     def report(self, metrics: Dict, checkpoint: Optional[Checkpoint]):
+        if not self._reported:
+            self._reported = True
+            self.run.mark("ray_tpu.train.first_report", self.loop_span_id)
+            self.want_timeline()
         with self.lock:
             self.reports.append({
                 "metrics": dict(metrics),
                 "checkpoint": checkpoint,
             })
 
-    def drain(self) -> List[Dict]:
+    def want_timeline(self) -> None:
         with self.lock:
-            out = self.reports
-            self.reports = []
-            return out
+            self._timeline_due = True
+
+    def drain(self) -> Dict[str, Any]:
+        """What a poll carries: the reports since the last one and, if
+        one is due, the timeline (else None)."""
+        with self.lock:
+            reports, self.reports = self.reports, []
+            due, self._timeline_due = self._timeline_due, False
+        timeline = None
+        if due:
+            timeline = {"pid": os.getpid(), "spans": self.run.snapshot(),
+                        "compile_log": profiling.COMPILES.entries(),
+                        "dropped": profiling.COMPILES.dropped}
+        return {"reports": reports, "timeline": timeline}
 
 
 _session: Optional[_Session] = None

@@ -21,6 +21,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
 from .. import api
+from ..util import tracing
 from .backend import BackendConfig, JaxBackendConfig
 from .checkpoint import Checkpoint, CheckpointManager
 from .config import RunConfig, ScalingConfig
@@ -137,11 +138,27 @@ class DataParallelTrainer(BaseTrainer):
         (reference: v2/_internal/execution/controller/controller.py:91) —
         Fixed or Elastic scaling policy per ScalingConfig, FailurePolicy
         from FailureConfig, checkpoints through the CheckpointManager."""
+        name, exp_dir = self._experiment_paths()
+        run = tracing.Run()
+        self._controller = None
+        try:
+            with run.span(
+                    "ray_tpu.train.fit", name=name,
+                    num_workers=self.scaling_config.num_workers,
+                    resources_per_worker=self.scaling_config
+                    .worker_resources()) as fit_span:
+                return self._fit(name, exp_dir, run, fit_span["span_id"])
+        finally:
+            # Once the span has closed: the file holds fit's end.
+            if self._controller is not None:
+                self._controller.write_timeline()
+
+    def _fit(self, name: str, exp_dir: str, run: tracing.Run,
+             fit_span_id: str) -> Result:
         from .v2 import (ElasticScalingPolicy, FailurePolicy,
                          FixedScalingPolicy, TrainController)
         if not api.is_initialized():
             api.init(ignore_reinit_error=True)
-        name, exp_dir = self._experiment_paths()
         ckpt_cfg = self.run_config.checkpoint_config
         manager = CheckpointManager(
             os.path.join(exp_dir, "checkpoints"),
@@ -166,7 +183,8 @@ class DataParallelTrainer(BaseTrainer):
             experiment_dir=exp_dir,
             resume_from_checkpoint=self.resume_from_checkpoint,
             dataset_splitter=self._split_datasets,
-            checkpoint_adopter=self._adopt_checkpoint)
+            checkpoint_adopter=self._adopt_checkpoint,
+            run=run, fit_span_id=fit_span_id)
         self._controller = controller  # exposed for tests/introspection
         metrics, checkpoint, error = controller.run()
         return Result(metrics=metrics, checkpoint=checkpoint,

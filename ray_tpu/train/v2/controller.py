@@ -15,23 +15,37 @@ Each (re)start asks the ScalingPolicy for a ResizeDecision, so recovery
 is elastic: the next gang may be smaller/larger than the last. Worker
 reports and checkpoints are drained every poll tick and registered with
 the CheckpointManager; restarts restore from the latest checkpoint.
+
+The same polls bring each worker's share of the run's timeline (its
+spans and its compile log), and the controller writes
+`<experiment dir>/run_timeline.json` from them and its own spans: at
+rank 0's first report, so a job killed after its first step has one, and
+when the run ends (`write_timeline`; docs/OBSERVABILITY.md has the
+fields).
 """
 
 from __future__ import annotations
 
 import enum
+import json
+import logging
+import os
 import time
 import uuid
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ... import api
+from ..._private import state
 from ...exceptions import (ActorDiedError, RayError, TaskError,
                            TaskUnschedulableError)
+from ...util import tracing
 from ..checkpoint import Checkpoint, CheckpointManager
 from ..session import TrainContext
 from ..worker_group import WorkerGroup
 from .failure_policy import FailureDecision, FailurePolicy
 from .scaling_policy import ResizeDecision, ScalingPolicy
+
+logger = logging.getLogger(__name__)
 
 
 class TrainControllerState(enum.Enum):
@@ -59,7 +73,9 @@ class TrainController:
                  dataset_splitter: Optional[Callable[[int], Optional[
                      List[Dict[str, Any]]]]] = None,
                  checkpoint_adopter: Optional[Callable] = None,
-                 poll_interval_s: float = 0.2):
+                 poll_interval_s: float = 0.2,
+                 run: Optional[tracing.Run] = None,
+                 fit_span_id: Optional[str] = None):
         self._train_fn = train_fn
         self._train_fn_config = train_fn_config or {}
         self._scaling_policy = scaling_policy
@@ -80,6 +96,15 @@ class TrainController:
         self._latest_metrics: Dict[str, Any] = {}
         self._error: Optional[BaseException] = None
         self._world_sizes: List[int] = []
+        # The run's timeline: this process's spans (the trainer's `fit`
+        # among them, `fit_span_id`), each worker's by span id (a later
+        # copy closes an earlier one's open spans) and each rank's latest
+        # compile log.
+        self._run = run or tracing.Run()
+        self._fit_span_id = fit_span_id
+        self._worker_spans: Dict[str, dict] = {}
+        self._worker_logs: Dict[str, dict] = {}
+        self._wrote_first_report = False
 
     # ------------------------------------------------------------------
     def _set_state(self, state: TrainControllerState):
@@ -109,7 +134,9 @@ class TrainController:
                                    TrainControllerState.RESTARTING):
                     self._set_state(TrainControllerState.SCHEDULING)
                 elif self._state == TrainControllerState.SCHEDULING:
-                    self._start_worker_group()
+                    with self._run.span("ray_tpu.train.start_group",
+                                        self._fit_span_id) as span:
+                        self._start_worker_group(span["span_id"])
                 elif self._state == TrainControllerState.RUNNING:
                     self._poll_worker_group()
         finally:
@@ -119,7 +146,7 @@ class TrainController:
         return self._latest_metrics, self._manager.latest, self._error
 
     # ------------------------------------------------------------------
-    def _start_worker_group(self):
+    def _start_worker_group(self, span_id: str):
         decision: ResizeDecision = \
             self._scaling_policy.make_decision_for_new_group()
         # Surface a gang the cluster can't currently hold (reference:
@@ -168,7 +195,10 @@ class TrainController:
         try:
             group.setup(make_context, self._backend_config,
                         self._restore or self._manager.latest,
-                        dataset_shards)
+                        dataset_shards,
+                        run_trace={"trace_id": self._run.trace_id,
+                                   "fit": self._fit_span_id,
+                                   "start_group": span_id})
             self._run_refs = group.run(self._train_fn,
                                        self._train_fn_config)
         except (ActorDiedError, TaskError, RayError, TimeoutError) as e:
@@ -230,15 +260,58 @@ class TrainController:
     def _drain_reports(self):
         if self._group is None:
             return
-        all_reports = self._group.poll_all(timeout=30.0)
-        for rank, reports in enumerate(all_reports):
-            for rep in reports:
+        answers = self._group.poll_all(timeout=30.0)
+        for rank, answer in enumerate(answers):
+            if answer["timeline"] is not None:
+                self._take_timeline(rank, answer["timeline"])
+            for rep in answer["reports"]:
                 ckpt = rep.get("checkpoint")
                 if ckpt is not None and rank == 0:
                     managed = self._adopt(self._manager, ckpt)
                     self._manager.register(managed, rep["metrics"])
                 if rank == 0:
                     self._latest_metrics.update(rep["metrics"])
+
+    def _take_timeline(self, rank: int, timeline: Dict[str, Any]):
+        # `worker_id`: what the head's store stamps on a worker's spans,
+        # and where format_trace and a chrome trace say a span ran.
+        spans = [dict(s, worker_id=f"rank{rank}") for s in timeline["spans"]]
+        self._worker_spans.update((s["span_id"], s) for s in spans)
+        self._worker_logs[str(rank)] = {
+            "pid": timeline["pid"], "compile_log": timeline["compile_log"],
+            "dropped": timeline["dropped"]}
+        if rank == 0 and not self._wrote_first_report and any(
+                s["name"] == "ray_tpu.train.first_report" for s in spans):
+            self._wrote_first_report = True
+            self.write_timeline()
+
+    def write_timeline(self) -> Optional[str]:
+        """Write what is known of the run's start to
+        `<experiment dir>/run_timeline.json`, whole or not at all:
+
+            {"trace_id", "spans": [...],
+             "workers": {"<rank>": {"pid", "compile_log", "dropped"}}}
+
+        `spans` are util/tracing.py's records (an open one has `end`
+        None): this driver's latest `ray_tpu.init`, a root of its own
+        under the run's trace id, then the run's tree under
+        `ray_tpu.train.fit`."""
+        spans = self._run.snapshot() + list(self._worker_spans.values())
+        init_span = getattr(state.get_node(), "init_span", None)
+        if init_span is not None:
+            spans.insert(0, dict(init_span, trace_id=self._run.trace_id))
+        path = os.path.join(self._exp_dir, "run_timeline.json")
+        try:
+            with open(path + ".tmp", "w") as f:
+                json.dump({"trace_id": self._run.trace_id, "spans": spans,
+                           "workers": self._worker_logs}, f, default=str)
+            os.replace(path + ".tmp", path)
+        except OSError:
+            # A record of the run, not part of it: a full disk must not
+            # fail the job.
+            logger.warning("could not write %s", path, exc_info=True)
+            return None
+        return path
 
     def _teardown_group(self):
         if self._group is not None:

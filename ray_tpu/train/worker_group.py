@@ -11,7 +11,9 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 from .. import api
+from ..util import profiling, tracing
 from ..util.profiling import annotate
+from .backend import JaxBackendConfig
 from .checkpoint import Checkpoint
 from .session import TrainContext, _Session, _set_session
 
@@ -25,37 +27,83 @@ class TrainWorker:
         self._session = None
         self._context = None
         self._backend = None
+        self._fit_span_id = None
 
     def setup(self, context: TrainContext, backend_config,
               checkpoint: Optional[Checkpoint],
-              dataset_shards: Optional[Dict[str, Any]] = None):
-        self._context = context
-        self._backend = backend_config
-        self._session = _Session(context, checkpoint, dataset_shards)
-        _set_session(self._session)
-        if backend_config is not None:
-            backend_config.on_start(context)
+              dataset_shards: Optional[Dict[str, Any]] = None,
+              run_trace: Optional[Dict[str, str]] = None):
+        """`run_trace`: the ids this worker's spans hang under, from the
+        controller: {"trace_id", "fit", "start_group"}."""
+        run_trace = run_trace or {}
+        run = tracing.Run(run_trace.get("trace_id"))
+        self._fit_span_id = run_trace.get("fit")
+        with run.span("ray_tpu.train.worker_setup",
+                      run_trace.get("start_group"),
+                      rank=context.world_rank):
+            self._context = context
+            self._backend = backend_config
+            self._session = _Session(context, checkpoint, dataset_shards,
+                                     run)
+            _set_session(self._session)
+            if backend_config is not None:
+                backend_config.on_start(context)
         return context.world_rank
+
+    def _runtime_is_settled(self) -> bool:
+        """Whether nothing the loop could still do would change the jax
+        runtime that starts at its first device call: the worker was
+        handed chips (its environment fixes platform and chips,
+        _private/resources.py tpu_worker_extra_env) and is alone or has
+        joined jax.distributed in on_start. A CPU worker's loop may still
+        configure jax before its first device call."""
+        return bool(api.get_tpu_ids()) and (
+            self._context.world_size == 1 or self._backend.init_distributed)
 
     def run(self, train_fn: Callable, config: Optional[Dict]):
         """Blocking: executes the user loop; reports flow via poll()."""
         import inspect
 
+        session, rank = self._session, self._context.world_rank
         try:
-            sig = inspect.signature(train_fn)
-            if len(sig.parameters) >= 1:
-                result = train_fn(config or {})
-            else:
-                result = train_fn()
-            self._session.finished = True
+            if isinstance(self._backend, JaxBackendConfig):
+                if self._runtime_is_settled():
+                    # The loop would pay this start at its first import
+                    # and device call; this only gives it a name.
+                    with session.run.span("ray_tpu.train.backend_start",
+                                          self._fit_span_id) as start:
+                        import jax
+                        devices = jax.local_devices()
+                        start["attributes"] = {
+                            "rank": rank, "platform": devices[0].platform,
+                            "device_kind": devices[0].device_kind,
+                            "local_devices": len(devices)}
+                else:
+                    # Importing starts no runtime (on_start does the same
+                    # for a gang).
+                    import jax  # noqa: F401
+                # From here on every program this process builds is logged.
+                profiling.COMPILES.listen()
+            with session.run.span("ray_tpu.train.loop", self._fit_span_id,
+                                  rank=rank) as loop:
+                session.loop_span_id = loop["span_id"]
+                session.want_timeline()
+                sig = inspect.signature(train_fn)
+                if len(sig.parameters) >= 1:
+                    result = train_fn(config or {})
+                else:
+                    result = train_fn()
             return {"status": "finished", "result": result}
         finally:
-            self._session.finished = True
+            session.finished = True
+            session.want_timeline()
 
     def poll(self):
-        """Drain buffered reports (controller calls this periodically)."""
+        """Drain buffered reports (controller calls this periodically):
+        {"reports": [...], "timeline": None or this worker's spans and
+        compile log, at the moments _Session names}."""
         if self._session is None:
-            return []
+            return {"reports": [], "timeline": None}
         with annotate("ray_tpu.train.poll"):
             return self._session.drain()
 
@@ -90,12 +138,14 @@ class WorkerGroup:
     def setup(self, make_context: Callable[[int], TrainContext],
               backend_config, checkpoint: Optional[Checkpoint],
               dataset_shards: Optional[List[Dict[str, Any]]] = None,
-              timeout: float = 120.0):
+              timeout: float = 120.0,
+              run_trace: Optional[Dict[str, str]] = None):
         refs = []
         for rank, w in enumerate(self.workers):
             shards = dataset_shards[rank] if dataset_shards else None
             refs.append(w.setup.remote(
-                make_context(rank), backend_config, checkpoint, shards))
+                make_context(rank), backend_config, checkpoint, shards,
+                run_trace))
         return api.get(refs, timeout=timeout)
 
     def run(self, train_fn: Callable, config: Optional[Dict]):

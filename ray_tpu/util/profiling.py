@@ -28,17 +28,18 @@ lie on the device trace's clock with no bridge but that one addition.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
-import functools
 import glob
 import math
 import os
 import re
 import sys
 import tempfile
+import threading
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 # Host spans: name -> (site, the per-layer metric it is for).
 HOST_SPANS: Dict[str, tuple] = {
@@ -63,6 +64,32 @@ HOST_SPANS: Dict[str, tuple] = {
     "ray_tpu.serve.handle": (
         "serve/_private/replica.py handle_request(_streaming): the "
         "user's handler", "generator_late_p99_ms"),
+    "ray_tpu.init": (
+        "api.py init: the cluster of this driver comes up (node, log "
+        "monitor, prestarted workers); its own root, the run's file "
+        "carries the driver's latest", "cluster_start_s"),
+    "ray_tpu.train.fit": (
+        "train/trainer.py DataParallelTrainer.fit: called -> Result "
+        "returned; the root of a run's spans",
+        "worker_start_s"),
+    "ray_tpu.train.start_group": (
+        "train/v2/controller.py TrainController.run, round "
+        "_start_worker_group: feasibility, dataset split, actors "
+        "created, setup answered, run submitted", "worker_start_s"),
+    "ray_tpu.train.worker_setup": (
+        "train/worker_group.py TrainWorker.setup: the session and the "
+        "backend's on_start (a gang's rendezvous)", "worker_start_s"),
+    "ray_tpu.train.backend_start": (
+        "train/worker_group.py TrainWorker.run, before the user's loop: "
+        "import jax and the first jax.local_devices(), in a worker that "
+        "was handed chips", "backend_start_s"),
+    "ray_tpu.train.loop": (
+        "train/worker_group.py TrainWorker.run: the user's loop, entry "
+        "-> return", "setup_exec_s"),
+    "ray_tpu.train.first_report": (
+        "train/session.py _Session.report: a zero-length mark at the "
+        "run's first train.report",
+        "none (an operator's time to first report)"),
     "ray_tpu.train.report": (
         "train/session.py report, in the training loop's thread",
         "step_ms_p50"),
@@ -328,6 +355,126 @@ def annotate(name: str):
     return _trace_annotation(name)
 
 
+# jax.monitoring's time spans of a program's build, by the phase each is.
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+# ... and its events inside the last: what the persistent cache said.
+_CACHE_ANSWERS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class CompileLog:
+    """Every program a process builds, one entry a phase:
+
+        {"fun", "phase": "trace" | "lower" | "compile", "start", "end",
+         "cache": "hit" | "miss" | None}
+
+    `fun` is jax's name for the jitted function, `start` / `end` are
+    `time.time()` (the clock of util/tracing.py's spans and, through
+    `profile_start_unix_ns`, of a device trace). `trace` is Python
+    running the function into a jaxpr and `lower` the jaxpr into
+    StableHLO: no cache saves either. `compile` is XLA building the
+    executable or the persistent cache answering: `cache` says which
+    (`"hit"`: the key was found, read, deserialised and loaded; `"miss"`:
+    XLA compiled and the entry was written; None: the cache was not
+    asked, or XLA compiled too quickly for jax to keep the program,
+    `jax_persistent_cache_min_compile_time_secs`).
+
+    A function traced while another is traced or lowered (jax.numpy's
+    own jitted functions, a kernel's body: 3,669 of them in one 8-layer
+    train step) is part of the outer entry and has none of its own.
+    Where threads interleave, or a program was compiled meanwhile, an
+    inner interval can stay: sum over the union of the intervals.
+
+    Always on once listening: an entry costs a dict and an append, and
+    there are three a program built, none a step. Keeps the newest
+    `keep` entries and counts the rest in `dropped`; `programs_built`
+    counts every `compile` entry ever, dropped ones too."""
+
+    def __init__(self, keep: int = 4096):
+        # (thread that logged it, entry), in the order they ended
+        self._entries: collections.deque = collections.deque(maxlen=keep)
+        self._lock = threading.Lock()
+        self._cache_said: Dict[int, str] = {}   # thread -> hit | miss
+        self._listening = False
+        self.dropped = 0
+        self.programs_built = 0
+
+    def on_time_span(self, event: str, start: float, end: float, **kw):
+        phase = _BUILD_PHASES.get(event)
+        if phase is None:
+            return
+        me = threading.get_ident()
+        entry = {"fun": kw.get("fun_name"), "phase": phase,
+                 "start": start, "end": end, "cache": None}
+        with self._lock:
+            if phase == "compile":
+                # The answer belongs to the compile entry of the same
+                # thread that closes next: this one.
+                entry["cache"] = self._cache_said.pop(me, None)
+                self.programs_built += 1
+            else:
+                # What began inside this one is part of it.
+                while self._entries:
+                    thread, last = self._entries[-1]
+                    if thread != me or last["phase"] == "compile" \
+                            or last["start"] < start:
+                        break
+                    self._entries.pop()
+            if len(self._entries) == self._entries.maxlen:
+                self.dropped += 1
+            self._entries.append((me, entry))
+
+    def on_event(self, event: str, **kw):
+        answer = _CACHE_ANSWERS.get(event)
+        if answer is not None:
+            with self._lock:
+                self._cache_said[threading.get_ident()] = answer
+
+    def entries(self) -> List[dict]:
+        with self._lock:
+            return [dict(entry) for _, entry in self._entries]
+
+    def listen(self) -> bool:
+        """Register with jax.monitoring, once; False where this process
+        has not imported jax (nothing is imported here: importing jax is
+        the caller's decision, and starts no runtime)."""
+        if not self._listening:
+            # getattr: another thread may be half way through importing jax.
+            monitoring = sys.modules.get("jax.monitoring")
+            spans = getattr(monitoring,
+                            "register_event_time_span_listener", None)
+            events = getattr(monitoring, "register_event_listener", None)
+            if spans is None or events is None:
+                return False
+            with self._lock:
+                if not self._listening:
+                    self._listening = True
+                    spans(self.on_time_span)
+                    events(self.on_event)
+        return True
+
+
+# The one log of this process (jax.monitoring's listeners are the
+# process's): TrainWorker.run starts it before the user's loop,
+# telemetry.flush_device_gauges at a worker's metrics push.
+COMPILES = CompileLog()
+
+
+def compile_log() -> List[dict]:
+    """The programs this process has built since it began listening (it
+    begins here if it has not), in the order their phases ended:
+    `CompileLog`'s entries.
+    `COMPILES.dropped` and `COMPILES.programs_built` hold the counts."""
+    COMPILES.listen()
+    return COMPILES.entries()
+
+
 def default_logdir() -> str:
     """Session-scoped trace dir (driver) or a /tmp fallback."""
     from .._private import state
@@ -406,23 +553,6 @@ def trace(logdir: Optional[str] = None):
         yield cap.logdir
 
 
-def profile(fn: Optional[Callable] = None, *,
-            logdir: Optional[str] = None):
-    """Decorator variant of `capture` for remote task/actor methods:
-
-        @ray_tpu.remote(num_tpus=1)
-        @profiling.profile
-        def step(batch): ...
-    """
-    def deco(f):
-        @functools.wraps(f)
-        def wrapper(*args, **kwargs):
-            with capture(logdir):
-                return f(*args, **kwargs)
-        return wrapper
-    return deco(fn) if fn is not None else deco
-
-
 def capture_for(seconds: float) -> Dict[str, Any]:
     """Profile this process for `seconds` and return the trace's bytes:
     what a worker runs for PROFILE_METHOD, on a thread of its own, so
@@ -465,41 +595,3 @@ def profile_actor(actor, seconds: float,
         f.write(data)
     out.update(path=path, bytes=len(data))
     return out
-
-
-def device_memory_stats(device_index: int = 0) -> Dict[str, Any]:
-    """Per-device HBM stats (reference: the dashboard's GPU memory
-    reporter; TPU runtimes expose bytes_in_use/peak via
-    Device.memory_stats)."""
-    import jax
-    devs = jax.local_devices()
-    if not devs or device_index >= len(devs):
-        return {}
-    stats = devs[device_index].memory_stats() or {}
-    return dict(stats)
-
-
-class Timer:
-    """Lightweight wall-clock section timer for host-side code paths
-    (reference: _private/profiling.py chrome-event helpers); records into
-    the GCS span store so `ray_tpu timeline` includes it."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self.elapsed_s: Optional[float] = None
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed_s = time.perf_counter() - self._t0
-        from .._private import state
-        rt = state.current_or_none()
-        gcs = getattr(rt, "gcs", None)
-        if gcs is not None:
-            gcs.record_spans([{
-                "name": self.name, "cat": "profiling",
-                "ts": (self._t0) * 1e6, "dur": self.elapsed_s * 1e6,
-                "pid": os.getpid(), "tid": 0, "ph": "X"}])
-        return False

@@ -41,6 +41,10 @@ Usage:
         ref = f.remote(...)        # submit span + context ride the spec
     tracing.get_trace(trace_id)    # cross-node tree + critical path
     tracing.export_chrome_trace("/tmp/trace.json")
+
+A run's spans (`Run`) are the exception to the gate: a dozen a training
+job, not one a request, so they are recorded always, into a list their
+owner keeps, and written to the run's own file (train/v2/controller.py).
 """
 
 from __future__ import annotations
@@ -53,6 +57,8 @@ import threading
 import time
 import uuid
 from typing import Any, Dict, List, Optional, Tuple
+
+from . import profiling
 
 _ENV_VAR = "RAY_TPU_TRACING"
 
@@ -252,6 +258,58 @@ def span(name: str, **attributes: Any):
         })
 
 
+class Run:
+    """The spans of one run (a training job from fit() to its Result; a
+    driver's init): the same record as `span` writes, under one
+    `trace_id`, kept in `spans` by whoever owns the run, so
+    `build_trace`, `format_trace` and a chrome trace read them as they
+    read a request's. Recorded whether or not tracing is enabled: a run
+    has a dozen, and they are what says where its start went. Each is
+    also a `profiling.annotate`, so a running `profiling.capture` shows
+    it on the device trace's clock. Where tracing is enabled a span also
+    goes, as it ends, the way every span goes: to the head's store.
+
+    Parents are given, not taken from the calling context: a run's spans
+    cross processes by their ids (`TrainWorker.setup`'s `run_trace`),
+    and the calls made inside one are not traced for its sake."""
+
+    def __init__(self, trace_id: Optional[str] = None):
+        self.trace_id = trace_id or uuid.uuid4().hex
+        self.spans: List[dict] = []
+
+    @contextlib.contextmanager
+    def span(self, name: str, parent: Optional[str] = None, /,
+             **attributes: Any):
+        """Yields the record, open (`end` None) and already in `spans`;
+        the block may fill `attributes` in."""
+        record = {"name": name, "trace_id": self.trace_id,
+                  "span_id": uuid.uuid4().hex[:16],
+                  "parent_span_id": parent, "start": time.time(),
+                  "end": None, "attributes": attributes or None,
+                  "error": None}
+        self.spans.append(record)
+        try:
+            with profiling.annotate(name):
+                yield record
+        except BaseException as e:
+            record["error"] = repr(e)
+            raise
+        finally:
+            record["end"] = time.time()
+            if enabled:
+                _record(dict(record))
+
+    def mark(self, name: str, parent: Optional[str] = None, /,
+             **attributes: Any) -> None:
+        """A zero-length span: a moment with a name."""
+        with self.span(name, parent, **attributes):
+            pass
+
+    def snapshot(self) -> List[dict]:
+        """Copies of the spans so far, open ones too."""
+        return [dict(s) for s in list(self.spans)]
+
+
 def activate_context(ctx: Optional[Dict[str, str]]):
     """Adopt a propagated context (worker side; reference: extract from
     the task spec before running the user function). Returns a reset
@@ -291,6 +349,13 @@ def get_spans(trace_id: Optional[str] = None) -> List[dict]:
     return []
 
 
+def _duration_s(span: dict) -> float:
+    """A span's length; 0 while it is open."""
+    if span.get("end") is None:
+        return 0.0
+    return span["end"] - (span.get("start") or 0.0)
+
+
 def build_trace(spans: List[dict]) -> dict:
     """Assemble one trace's spans into a tree + critical-path summary.
     Pure function of the span list (unit-testable; get_trace feeds it
@@ -313,21 +378,27 @@ def build_trace(spans: List[dict]) -> dict:
         node["children"].sort(key=lambda c: c.get("start", 0.0))
     roots.sort(key=lambda c: c.get("start", 0.0))
 
-    # Critical path: from the earliest root, descend into the child
-    # whose END is latest (the chain the trace's wall time waited on).
+    # Critical path: from the root whose END is latest (a request has
+    # one root; a run's file has two, `ray_tpu.init` and the run),
+    # descend into the child whose END is latest (the chain the trace's
+    # wall time waited on). A span still open (`end` None, in a run's
+    # file written while it runs) ends after every closed one.
+    def end_of(span):
+        end = span.get("end")
+        return float("inf") if end is None else end
+
     path: List[dict] = []
-    cur = roots[0] if roots else None
+    cur = max(roots, key=end_of) if roots else None
     while cur is not None:
         path.append({
             "name": cur.get("name"), "span_id": cur.get("span_id"),
             "start": cur.get("start"), "end": cur.get("end"),
-            "duration_s": round(
-                (cur.get("end") or 0.0) - (cur.get("start") or 0.0), 6),
+            "duration_s": round(_duration_s(cur), 6),
             "node_id": cur.get("node_id"),
             "worker_id": cur.get("worker_id"),
             "error": cur.get("error")})
         kids = cur["children"]
-        cur = max(kids, key=lambda c: c.get("end", 0.0)) if kids else None
+        cur = max(kids, key=end_of) if kids else None
     starts = [s.get("start") for s in spans if s.get("start") is not None]
     ends = [s.get("end") for s in spans if s.get("end") is not None]
     return {
@@ -357,11 +428,12 @@ def format_trace(trace: dict) -> str:
              f"nodes={','.join(n[:8] for n in trace.get('node_ids', []))}"]
 
     def walk(node, depth):
-        dur = (node.get("end") or 0.0) - (node.get("start") or 0.0)
+        took = "open" if node.get("end") is None \
+            else f"{_duration_s(node) * 1000:.2f} ms"
         where = (node.get("worker_id") or "driver")[:8]
         err = "  ERROR" if node.get("error") else ""
         lines.append(f"{'  ' * depth}{node.get('name')}  "
-                     f"[{dur * 1000:.2f} ms @ {where}]{err}")
+                     f"[{took} @ {where}]{err}")
         for c in node.get("children", ()):
             walk(c, depth + 1)
 
@@ -388,11 +460,6 @@ def export_chrome_trace(filename: Optional[str] = None,
 
     events = state_api.timeline()
     for s in get_spans(trace_id):
-        if "ph" in s:
-            # Pre-formed chrome event (util/profiling.py records these
-            # straight into the span store).
-            events.append(s)
-            continue
         if s.get("start") is None or s.get("end") is None:
             continue
         events.append({

@@ -78,33 +78,55 @@ class _Doubler:
         return {"id": batch["id"] * 2}
 
 
+def _one_report_loop():
+    from ray_tpu import train
+    train.report({"loss": 1.0})
+
+
 @pytest.fixture(scope="module")
-def spans_seen(ray_start_shared, small_setup, tmp_path_factory):
-    """One capture in this process over a tiny engine run, a tiny
-    iter_jax_batches, a train.report with its poll and a replica's
-    handler; one taken by a map_batches actor's worker of itself."""
+def spans_seen(small_setup, tmp_path_factory):
+    """One capture in this process over the cluster's start, a tiny
+    engine run, a tiny iter_jax_batches, a one-worker JaxTrainer job (its
+    `fit` and `start_group` run here), a TrainWorker's whole life run in
+    this process as if it held a chip (its four run spans, a report and
+    the poll) and a replica's handler; one taken by a map_batches actor's
+    worker of itself. The cluster is this fixture's: `ray_tpu.init` has
+    to happen under the capture."""
     from ray_tpu import data as rd
     from ray_tpu.data.dataset import _MapBatchesActorPool
     from ray_tpu.serve._private.replica import Replica
-    from ray_tpu.train import session
+    from ray_tpu.train import (JaxBackendConfig, JaxTrainer, RunConfig,
+                               ScalingConfig, session)
     from ray_tpu.train.worker_group import TrainWorker
 
     cfg, params = small_setup
     logdir = str(tmp_path_factory.mktemp("capture"))
+    ray_tpu.shutdown()
     with profiling.capture(logdir) as cap:
+        ray_tpu.init(num_cpus=4)
         _run_engine(cfg, params, ["hello", "late one", "third"], 4)
         rows = sum(int(b["id"].shape[0]) for b in
                    rd.range(48).iter_jax_batches(batch_size=16))
+        fitted = JaxTrainer(
+            _one_report_loop, scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(name="names", storage_path=str(
+                tmp_path_factory.mktemp("fit")))).fit()
         worker = TrainWorker._cls()
-        worker._session = session._Session(session.TrainContext())
-        session._set_session(worker._session)
-        try:
-            session.report({"loss": 1.0})
-            reports = worker.poll()
-        finally:
-            session._set_session(None)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setenv("TPU_VISIBLE_CHIPS", "0")
+            try:
+                worker.setup(session.TrainContext(), JaxBackendConfig(),
+                             None)
+                worker.run(_one_report_loop, None)
+                polled = worker.poll()
+            finally:
+                session._set_session(None)
         replica = Replica(cloudpickle.dumps(lambda x: x + 1), (), {}, "d")
         handled = asyncio.run(replica.handle_request("__call__", (1,), {}))
+    assert fitted.error is None and fitted.metrics == {"loss": 1.0}
+    reports = polled["reports"]
+    assert [s["name"].rsplit(".", 1)[1] for s in polled["timeline"]["spans"]
+            ] == ["worker_setup", "backend_start", "loop", "first_report"]
     assert rows == 48 and handled == 2
     assert [r["metrics"] for r in reports] == [{"loss": 1.0}]
 
@@ -121,9 +143,10 @@ def spans_seen(ray_start_shared, small_setup, tmp_path_factory):
         outs.append(ray_tpu.get(actor.apply.remote(blk, 4, "numpy", (), {})))
     t.join()
     assert outs and all(list(o["id"]) == list(blk["id"] * 2) for o in outs)
-    return {"local": cap, "local_spans": ray_tpu_spans(cap.xplane),
-            "remote": got, "remote_spans": ray_tpu_spans(got["path"]),
-            "served_during_profile": len(outs)}
+    yield {"local": cap, "local_spans": ray_tpu_spans(cap.xplane),
+           "remote": got, "remote_spans": ray_tpu_spans(got["path"]),
+           "served_during_profile": len(outs)}
+    ray_tpu.shutdown()
 
 
 @pytest.mark.parametrize("name", sorted(profiling.HOST_SPANS))
@@ -169,11 +192,14 @@ def _literals(pattern: str, under=("",)):
 
 def test_the_table_lists_exactly_the_names_the_program_emits():
     from ray_tpu.llm.continuous import PHASES
-    emitted = set(_literals(r'\bannotate\(\s*"([^"]+)"'))
+    # `annotate` itself, or a run's span or mark (util/tracing.py Run),
+    # which enters one
+    emitted = set(_literals(
+        r'(?:\bannotate|[Rr]un(?:\(\))?\.(?:span|mark))\(\s*"([^"]+)"'))
     emitted.discard("ray_tpu.engine.")       # + one of PHASES
     emitted |= {"ray_tpu.engine." + p for p in PHASES}
     assert emitted == set(profiling.HOST_SPANS)
-    assert all(re.fullmatch(r"ray_tpu\.[a-z]+\.[a-z_]+", n)
+    assert all(re.fullmatch(r"ray_tpu(\.[a-z]+)?\.[a-z_]+", n)
                for n in profiling.HOST_SPANS)
     scopes = _literals(r'named_scope\(\s*"([^"]+)"', CHIP_PATH)
     assert set(scopes) == set(profiling.DEVICE_SCOPES)
@@ -522,7 +548,8 @@ def test_device_gauges_never_start_or_wait_for_a_backend():
             "    t = threading.Thread(target=telemetry.flush_device_gauges)\n"
             "    t.start(); t.join(20)\n"
             "    assert not t.is_alive(), 'flush waited for backend start-up'\n"
-            "assert telemetry._programs_built == 0\n"
+            "from ray_tpu.util import profiling\n"
+            "assert profiling.COMPILES.programs_built == 0\n"
             "assert not xla_bridge.backends_are_initialized()\n")
     subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
                    timeout=60)

@@ -313,6 +313,7 @@ def test_direct_done_emits_submission_events():
     prev = os.environ.get("RAY_TPU_TELEMETRY")
     os.environ["RAY_TPU_TELEMETRY"] = "1"
     from ray_tpu._private import telemetry
+    was_enabled = telemetry.enabled
     telemetry.configure(True)
     ray_tpu.init(num_cpus=4)
     try:
@@ -359,7 +360,10 @@ def test_direct_done_emits_submission_events():
         assert rows and all(r.get("state") for r in rows), rows
     finally:
         ray_tpu.shutdown()
-        telemetry.configure(False)
+        # As it was found: left off, every later test file of this
+        # process runs without telemetry (tests/test_scale_sim.py then
+        # reads no head gauges).
+        telemetry.configure(was_enabled)
         if prev is None:
             os.environ.pop("RAY_TPU_TELEMETRY", None)
         else:

@@ -20,37 +20,12 @@ class TestProfiling:
         assert any("trace" in f or f.endswith(".pb") or ".xplane." in f
                    for f in files), files
 
-    def test_profile_decorator(self, tmp_path):
-        import jax.numpy as jnp
-
-        from ray_tpu.util import profiling
-
-        @profiling.profile(logdir=str(tmp_path / "tb2"))
-        def compute():
-            return float(jnp.arange(8).sum())
-
-        assert compute() == 28.0
-        assert os.path.isdir(str(tmp_path / "tb2"))
-
-    def test_annotate_and_memory_stats(self):
+    def test_annotate(self):
         import jax.numpy as jnp
 
         from ray_tpu.util import profiling
         with profiling.annotate("section"):
             jnp.ones(4).sum()
-        stats = profiling.device_memory_stats()
-        assert isinstance(stats, dict)  # cpu backend may return {}
-
-    def test_timer_records_span(self, shutdown_only):
-        import ray_tpu
-        from ray_tpu.util import profiling
-        ray_tpu.init(num_cpus=1)
-        with profiling.Timer("my-section") as t:
-            pass
-        assert t.elapsed_s is not None
-        from ray_tpu._private.state import get_node
-        spans = get_node().gcs.spans()
-        assert any(s["name"] == "my-section" for s in spans)
 
     def test_the_scan_kernels_have_scopes_and_are_counted(self):
         """The selective scan's two kernels and the two element-wise

@@ -32,8 +32,13 @@ def _ckpt_state(ckpt):
 
 @pytest.fixture(scope="module", autouse=True)
 def _runtime():
-    ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
+    # Its own cluster: test_resource_changing_scheduler counts on 4 CPUs,
+    # and a larger one left running by an earlier file of this process
+    # would be reused (conftest's shutdown_only has the same cure).
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4)
     yield
+    ray_tpu.shutdown()
 
 
 def _pb2_trainable(config):
