@@ -66,8 +66,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
-from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d, gated_rms_norm,
-                          layer_norm, rms_norm, rope, swiglu)
+from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
+                          gated_rms_norm, layer_norm, rms_norm, rope, swiglu)
 from ..ops.loss import chip_views, lookup
 from ..ops.selective_scan import selective_scan
 from ..ops.ssm_scan import ssm_scan
@@ -309,10 +309,9 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
         jnp.einsum("bsd,de->bse", y, layer["in_proj"]),
         [inner, 2 * inner + 2 * bc], axis=-1)
     with jax.named_scope("ssm_conv"):
-        xbc, tail = causal_conv1d(
+        xbc, tail = causal_conv1d_silu(
             xbc, layer["conv_w"], layer["conv_b"],
             None if cache is None else cache["conv"])
-        xbc = jax.nn.silu(xbc)
     xs, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
     xs = xs.reshape(b, L, H, P)
     B, C = B.reshape(b, L, G, N), C.reshape(b, L, G, N)
@@ -365,9 +364,9 @@ def mamba1(x, layer, dec: Decoder, cache=None, start_pos=None):
     xs, z = jnp.split(jnp.einsum("bsd,de->bse", y, layer["in_proj"]), 2,
                       axis=-1)
     with jax.named_scope("ssm_conv"):
-        xs, tail = causal_conv1d(xs, layer["conv_w"], layer["conv_b"],
-                                 None if cache is None else cache["conv"])
-        xs = jax.nn.silu(xs)
+        xs, tail = causal_conv1d_silu(
+            xs, layer["conv_w"], layer["conv_b"],
+            None if cache is None else cache["conv"])
     low, B, C = jnp.split(jnp.einsum("bse,er->bsr", xs, layer["x_proj"]),
                           [rank, rank + N], axis=-1)
     dt = jax.nn.softplus(

@@ -9,8 +9,8 @@ and as the autodiff backward.
 
 from .attention import flash_attention, mha_reference  # noqa: F401
 from .grouped_matmul import grouped_matmul  # noqa: F401
-from .layers import (causal_conv1d, gated_rms_norm, layer_norm,  # noqa: F401
-                     rms_norm, rope, swiglu)
+from .layers import (causal_conv1d_silu, gated_rms_norm,  # noqa: F401
+                     layer_norm, rms_norm, rope, swiglu)
 from .loss import cross_entropy  # noqa: F401
 from .selective_scan import (selective_scan, selective_scan_plan,  # noqa: F401
                              selective_scan_reference)

@@ -70,23 +70,101 @@ def swiglu(x, w_gate, w_up, w_down):
     return jnp.einsum("...f,fd->...d", gate * up, w_down)
 
 
-def causal_conv1d(x, weight, bias, tail=None):
-    """Depthwise causal convolution along the sequence: x [b, L, C],
-    weight [C, K] (K taps, the last one on the current position), bias
-    [C]: y_t = bias + sum_k weight[:, k] x_{t-K+1+k}, with `tail`
-    [b, K-1, C] the inputs before x (zeros where None). K shifted
-    multiply-adds in float32, no kernel. Returns (y like x, the last K-1
-    inputs: the tail a cache hands to the next call)."""
-    K = weight.shape[1]
-    L = x.shape[1]
-    if tail is None:
-        tail = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
-    padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+def _windows(x, tail, K):
+    """The K shifted views of (tail | x) a tap reads, in float32: view k
+    holds row t + k of (tail | x) at row t."""
+    padded = jnp.concatenate([tail, x], axis=1)
+    return [padded[:, k:k + x.shape[1]].astype(jnp.float32)
+            for k in range(K)]
+
+
+def _taps(windows, weight, bias, dtype):
+    """bias + sum_k weight[:, k] * windows[k]: K multiply-adds in float32,
+    the bias first, rounded to `dtype`."""
     w = weight.astype(jnp.float32)
     y = bias.astype(jnp.float32)
-    for k in range(K):
-        y = y + padded[:, k:k + L].astype(jnp.float32) * w[:, k]
-    return y.astype(x.dtype), padded[:, L:]
+    for k, view in enumerate(windows):
+        y = y + view * w[:, k]
+    return y.astype(dtype)
+
+
+@jax.custom_vjp
+def _conv_silu(x, weight, bias, tail):
+    return jax.nn.silu(_taps(_windows(x, tail, weight.shape[1]), weight,
+                             bias, x.dtype))
+
+
+def _conv_silu_fwd(x, weight, bias, tail):
+    # What the function was handed and nothing made inside: no name joins
+    # a block's KEPT_UNDER_REMAT for it. The backward pass makes the
+    # pre-activation again from x (where a rematerialised block has just
+    # made it, XLA reads that one instead: the compiler's choice, and the
+    # faster one on the chip).
+    return _conv_silu(x, weight, bias, tail), (x, weight, bias, tail)
+
+
+def _conv_silu_bwd(residuals, dy):
+    x, weight, bias, tail = residuals
+    K, (b, L, C) = weight.shape[1], x.shape
+    f32 = jnp.float32
+    windows = _windows(x, tail, K)
+    pre = _taps(windows, weight, bias, x.dtype).astype(f32)
+    sig = jax.nn.sigmoid(pre)
+    g = dy.astype(f32) * (sig * (1.0 + pre * (1.0 - sig)))
+    # The taps' and the bias's gradients: float32 column sums of the pass
+    # that makes g, over the same shifted rows the forward read.
+    dw = jnp.stack([jnp.sum(g * view, axis=(0, 1)) for view in windows],
+                   axis=1)
+    db = jnp.sum(g, axis=(0, 1))
+    # d(tail | x)[j] = sum_k weight[:, k] * g[j - k]: the same K shifted
+    # multiply-adds run the other way, over g (rounded once, as x is)
+    # between K - 1 rows of zeros on either side.
+    zeros = jnp.zeros((b, K - 1, C), x.dtype)
+    gz = jnp.concatenate([zeros, g.astype(x.dtype), zeros], axis=1)
+    w = weight.astype(f32)
+
+    def rows(first, n):
+        return sum(gz[:, first + K - 1 - k:first + K - 1 - k + n].astype(f32)
+                   * w[:, k] for k in range(K))
+
+    return (rows(K - 1, L).astype(x.dtype), dw.astype(weight.dtype),
+            db.astype(bias.dtype), rows(0, K - 1).astype(tail.dtype))
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def causal_conv1d_silu(x, weight, bias, tail=None):
+    """silu of a depthwise causal convolution along the sequence: x
+    [b, L, C], weight [C, K] (K taps, the last one on the current
+    position), bias [C]: y_t = silu(bias + sum_k weight[:, k] x_{t-K+1+k}),
+    with `tail` [b, K-1, C] the inputs before x (zeros where None). K
+    shifted multiply-adds in float32, no kernel. Returns (y like x, the
+    last K-1 inputs: the tail a cache hands to the next call).
+
+    The backward pass is written by hand (one `jax.custom_vjp`), not
+    derived. Autodiff transposes each of the K shifted slices into a
+    `pad`, and XLA:TPU fuses none of them with what feeds it: at
+    granite-4.0-h-micro's [1, 16384, 4352] the compiled step wrote the
+    cotangent times each tap, each product rounded to bfloat16, as four
+    arrays, 570 MB a layer, and read them back through four pads to add
+    them: four passes, 3.85 ms a layer. Here there are two (2.54 ms):
+    g = dy * silu'(pre) in float32 from x and dy, with the taps' and the
+    bias's gradients as float32 column sums of that same pass; then dx,
+    the same K shifted multiply-adds run the other way over g (an
+    anti-causal convolution), added in float32 and rounded once. A pass
+    over K shifted slices takes 1.1 ms there whatever its bytes (the
+    vector unit's shifts, not HBM, which x, dy and dx cross in 0.5), so
+    fewer passes is all there is to take without a kernel. The residuals
+    are x, the taps, the bias and the tail: nothing made inside is kept."""
+    K = weight.shape[1]
+    if tail is None:
+        tail = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
+    tail = tail.astype(x.dtype)
+    # The new tail is plain slicing outside the rule: autodiff's, and
+    # nothing at all in a train step, which hands no tail on.
+    return (_conv_silu(x, weight, bias, tail),
+            jnp.concatenate([tail, x], axis=1)[:, x.shape[1]:])
 
 
 def gated_rms_norm(y, gate, weight, eps: float = NORM_EPS):

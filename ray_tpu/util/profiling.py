@@ -223,6 +223,14 @@ _ASYNC_FUSION = re.compile(r'custom_call_target="AsyncCollective(Start|Done)"')
 _WORK = ("fusion", "custom-call", "while", "convolution", "dot")
 
 
+def _array_bytes(shape: str) -> list:
+    """The bytes of each array in a shape as HLO prints it (a tuple's
+    shape has several)."""
+    return [_DTYPE_BYTES[dtype] * math.prod(int(n) for n in dims.split(",")
+                                            if n)
+            for dtype, dims in _ARRAY.findall(shape)]
+
+
 def _computations(compiled_text: str) -> Dict[str, list]:
     """computation -> [(instruction, its shape, opcode, operands and
     attributes)] of an HLO module's text, in the order printed: for a
@@ -307,11 +315,8 @@ def collective_calls(compiled_text: str) -> Dict[str, Any]:
                 operands = [re.sub(r"\{[^{}]*\}", "", shapes.get(a, "")).strip()
                             for a in args]
                 entry = {"name": name, "kind": kind, "operands": operands,
-                         "bytes": sum(
-                             _DTYPE_BYTES[dtype] * math.prod(
-                                 int(n) for n in dims.split(",") if n)
-                             for shape in operands
-                             for dtype, dims in _ARRAY.findall(shape)),
+                         "bytes": sum(sum(_array_bytes(shape))
+                                      for shape in operands),
                          "async": False, "between": 0, "kernels": []}
                 found.append(entry)
                 if op != kind:
@@ -328,6 +333,50 @@ def collective_calls(compiled_text: str) -> Dict[str, Any]:
     hidden = sum(c["bytes"] for c in reduces if c["async"] and c["between"])
     return {"collectives": found, "gradient_reduce_bytes": total,
             "async_share": hidden / total if total else 0.0}
+
+
+# Instructions that name an array and write none: views and selections of
+# what another instruction made.
+_NO_WRITE = ("get-tuple-element", "tuple", "bitcast", "reshape", "parameter",
+             "constant")
+_ENTRY = re.compile(r"^ENTRY %?([\w.-]+)", re.M)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def scope_writes(compiled_text: str, scope: str) -> Dict[str, Any]:
+    """What a scope's instructions write: a static counter like
+    `kernel_calls`, read from `jitted.lower(...).compile().as_text()` and
+    not from a run. For work that is no kernel but XLA's own fusions under
+    a `jax.named_scope`, the bytes the compiler chose to put through HBM
+    are the first thing to read, before a chip run.
+
+    `writes` has one entry for each instruction of the entry computation
+    whose `op_name` holds `scope` and that makes an array of its own (a
+    fusion, a convert, a reduce, a copy; not the `get-tuple-element`s,
+    bitcasts and reshapes that name a part or a view of one): `name`,
+    `opcode`, and `results`, the bytes of each array it writes (a
+    multi-output fusion has several). `instructions` is their count and
+    `bytes` the sum. An instruction inside a loop's body or a fused
+    computation is not an instruction of the entry computation: a fusion
+    counts once, by what leaves it.
+
+    What it is for: `ssm_conv`, the causal depthwise convolution and its
+    silu (ops/layers.py `causal_conv1d_silu`), in granite-4.0-h-micro's
+    step of nine Mamba-2 layers at [1, 16384, 4352]: 108 instructions and
+    11.55 GB a step while autodiff derived its backward pass (one fusion a
+    layer wrote the cotangent times each tap as four 143 MB arrays), 90
+    and 6.42 GB since the rule is written by hand (PERF.md §6, PR 34)."""
+    entry = _ENTRY.search(compiled_text)
+    body = _computations(compiled_text)[entry.group(1)] if entry else []
+    writes = []
+    for name, shape, op, rest in body:
+        held = _OP_NAME.search(rest)
+        if op in _NO_WRITE or not held or scope not in held.group(1):
+            continue
+        writes.append({"name": name, "opcode": op,
+                       "results": _array_bytes(shape)})
+    return {"instructions": len(writes), "writes": writes,
+            "bytes": sum(sum(w["results"]) for w in writes)}
 
 
 # The worker-level actor method behind profile_actor: any actor's worker

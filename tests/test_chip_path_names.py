@@ -620,3 +620,59 @@ def test_llm_reply_timing_and_cli_profile_of_the_replica(
         assert "$python" not in spans
     finally:
         serve.shutdown()
+
+
+# A step as XLA:TPU prints it, cut to what scope_writes reads
+# (tests/test_compile_v5e_granite.py reads a whole one): a multi-output
+# fusion under the scope, the views of its parts, a fusion under another
+# scope, a fused computation whose instructions carry the scope too, and a
+# loop's body.
+_SCOPED = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.7 (param_0.1: bf16[1,64,256]) -> (f32[256], bf16[1,64,256]) {
+  %param_0.1 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %convert.1 = f32[1,64,256]{2,1,0:T(8,128)} convert(%param_0.1), metadata={op_name="jit(train_step)/ssm_conv/convert_element_type"}
+  %reduce.1 = f32[256]{0:T(256)} reduce(%convert.1, %param_0.1), dimensions={0,1}, metadata={op_name="jit(train_step)/ssm_conv/reduce_sum"}
+  ROOT %tuple.1 = (f32[256]{0:T(256)}, bf16[1,64,256]{2,1,0:T(8,128)(2,1)}) tuple(%reduce.1, %param_0.1)
+}
+
+%body.3 (p: (bf16[1,64,256])) -> (bf16[1,64,256]) {
+  %p = (bf16[1,64,256]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %fusion.99 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} fusion(%p), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(train_step)/while/body/ssm_conv/mul"}
+  ROOT %tuple.9 = (bf16[1,64,256]{2,1,0:T(8,128)(2,1)}) tuple(%fusion.99)
+}
+
+ENTRY %main.1 (x: bf16[1,64,256], w: bf16[256,4]) -> bf16[1,64,256] {
+  %x = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="x"}
+  %w = bf16[256,4]{0,1:T(4,128)(2,1)} parameter(1)
+  %convert_element_type.5 = f32[256,4]{0,1:T(4,128)} convert(%w), metadata={op_name="jit(train_step)/jvp(layers)/ssm_conv/convert_element_type" stack_frame_id=46}
+  %divide_multiply_fusion.2 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} fusion(%x, %convert_element_type.5), kind=kLoop, calls=%fused_computation.5, metadata={op_name="jit(train_step)/jvp(layers)/ssm_conv/jit(silu)/mul"}
+  %fusion.7 = (f32[256]{0:T(256)}, bf16[1,64,256]{2,1,0:T(8,128)(2,1)}) fusion(%divide_multiply_fusion.2), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(train_step)/transpose(jvp(layers))/ssm_conv/reduce_sum"}
+  %get-tuple-element.1 = f32[256]{0:T(256)} get-tuple-element(%fusion.7), index=0, metadata={op_name="jit(train_step)/transpose(jvp(layers))/ssm_conv/reduce_sum"}
+  %get-tuple-element.2 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} get-tuple-element(%fusion.7), index=1, metadata={op_name="jit(train_step)/transpose(jvp(layers))/ssm_conv/reduce_sum"}
+  %broadcast_in_dim.3 = f32[256,1]{0,1:T(1,128)} reshape(%get-tuple-element.1), metadata={op_name="jit(train_step)/transpose(jvp(layers))/ssm_conv/broadcast_in_dim"}
+  %fusion.8 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} fusion(%get-tuple-element.2), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(train_step)/transpose(jvp(layers))/ssm_gate_norm/mul"}
+  %while.3 = (bf16[1,64,256]{2,1,0:T(8,128)(2,1)}) while(%fusion.8), condition=%cond.3, body=%body.3
+  ROOT %copy.4 = bf16[1,64,256]{2,1,0:T(8,128)(2,1)} copy(%fusion.8)
+}
+"""
+
+
+def test_scope_writes_counts_what_the_entry_computation_writes_under_a_scope():
+    got = profiling.scope_writes(_SCOPED, "ssm_conv")
+    assert got["writes"] == [
+        {"name": "convert_element_type.5", "opcode": "convert",
+         "results": [256 * 4 * 4]},
+        {"name": "divide_multiply_fusion.2", "opcode": "fusion",
+         "results": [64 * 256 * 2]},
+        {"name": "fusion.7", "opcode": "fusion",
+         "results": [256 * 4, 64 * 256 * 2]}]
+    assert got["instructions"] == 3
+    assert got["bytes"] == 4096 + 32768 + 1024 + 32768
+    # another scope of the same step; a scope no instruction carries; a
+    # text with no entry computation
+    assert [w["name"] for w in profiling.scope_writes(
+        _SCOPED, "ssm_gate_norm")["writes"]] == ["fusion.8"]
+    empty = {"instructions": 0, "writes": [], "bytes": 0}
+    assert profiling.scope_writes(_SCOPED, "flash_attention_fwd") == empty
+    assert profiling.scope_writes("", "ssm_conv") == empty

@@ -125,6 +125,39 @@ def test_step_holds_no_array_with_two_chunk_long_axes_a_head(step):
     assert re.search(r"f32\[1,64,32,128,128\]", entry)
 
 
+def test_the_convolution_writes_half_of_what_autodiff_made_it_write(step):
+    """`profiling.scope_writes` over `ssm_conv`, the causal depthwise
+    convolution and its silu in nine Mamba-2 layers at [1, 16384, 4352]
+    (143 MB a crossing). With its backward pass derived by autodiff the
+    step read 108 instructions and 11.55 GB (PR 33's tree): a layer's
+    backward wrote the cotangent times each tap as four arrays, 570 MB in
+    one fusion, and read them back through four pads. With the rule of
+    ops/layers.py `causal_conv1d_silu` it reads 90 and 6.42 GB: a layer
+    writes y forward (143 MB), the pre-activation and y made again under
+    remat (285: XLA keeps the first for the backward pass rather than make
+    it a third time, which the chip runs 1.6 ms a step faster than a step
+    forced to, PERF.md section 6), then g with the taps' and the bias's
+    gradients as column sums of the same pass (143) and dx (143)."""
+    from ray_tpu.util import profiling
+
+    got = profiling.scope_writes(step[1].as_text(), "ssm_conv")
+    print(f"ssm_conv: {got['instructions']} instructions, "
+          f"{got['bytes'] / 1e9:.2f} GB a step")
+    assert got["instructions"] <= 90 and got["bytes"] < 6.5e9
+    crossing = 16384 * 4352 * 2
+    large = [[r for r in w["results"] if r >= crossing]
+             for w in got["writes"] if max(w["results"]) >= crossing]
+    # four passes a layer, five sequence-sized arrays, never more than two
+    # from one instruction
+    assert len(large) == 9 * 4 and max(len(r) for r in large) == 2
+    assert sum(sum(r) for r in large) == 9 * 5 * crossing
+    # the pass that makes g makes the five column sums too, and nothing
+    # sequence-sized beside g
+    sums = [w for w in got["writes"] if len(w["results"]) == 6]
+    assert len(sums) == 9
+    assert all(sorted(w["results"])[:5] == [4352 * 2] * 5 for w in sums)
+
+
 def test_step_fits_a_chip(step, record_property):
     mem = step[1].memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -134,3 +167,6 @@ def test_step_fits_a_chip(step, record_property):
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")
     assert total < HBM_BYTES
+    # no residual joined the step with the convolution's rule (PR 34):
+    # not above what XLA gave the step whose backward autodiff derived
+    assert total <= 14_473_369_600

@@ -141,6 +141,34 @@ def test_step_holds_no_state_a_token(step):
     assert re.search(r"f32\[1,256,16,40,128\]", entry)
 
 
+def test_the_convolution_writes_no_tap_scaled_copies(step):
+    """The causal depthwise convolution of three Mamba-1 layers at
+    [1, 16384, 5120] (168 MB a crossing), ops/layers.py
+    `causal_conv1d_silu`. `profiling.scope_writes` over `ssm_conv` reads
+    18 instructions and 0.50 GB here, as it did while autodiff derived the
+    backward pass (PR 33's tree): XLA folded that pass into a fusion named
+    after a neighbour outside the scope, so the scope's own rows never
+    held it. What did show it is what the whole step writes at that size:
+    101 arrays of 168 MB, five of them from each of three fusions (the
+    cotangent times each tap, and g). With the rule: 89, four fewer a
+    layer, and no instruction writes more than two."""
+    from ray_tpu.util import profiling
+
+    text = step[1].as_text()
+    got = profiling.scope_writes(text, "ssm_conv")
+    print(f"ssm_conv: {got['instructions']} instructions, "
+          f"{got['bytes'] / 1e9:.2f} GB a step")
+    crossing = 16384 * 5120 * 2
+    assert got["instructions"] <= 18 and got["bytes"] < 0.51e9
+    assert max(sum(r >= crossing for r in w["results"])
+               for w in got["writes"]) <= 2
+    # every instruction of the step, whatever scope names it
+    everywhere = profiling.scope_writes(text, "")["writes"]
+    wide = [sum(r == crossing for r in w["results"]) for w in everywhere]
+    assert max(wide) <= 2
+    assert sum(wide) <= 89
+
+
 def test_windowed_layers_compute_the_band_and_nothing_else():
     """What the windowed layers' kernels run at the cell's shape, by the
     plan the kernels take their loops from: three sub-blocks of 256 keys a
@@ -173,3 +201,6 @@ def test_step_fits_a_chip(step, record_property):
     assert total < HBM_BYTES
     # under ISSUE 31's line for its one fallback: the cell stays at 16,384
     assert total < FALLBACK_OVER
+    # no residual joined the step with the convolution's rule (PR 34):
+    # not above what XLA gave the step whose backward autodiff derived
+    assert total <= 14_254_285_824

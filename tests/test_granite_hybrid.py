@@ -21,7 +21,7 @@ from ray_tpu.models.hybrid import (HybridConfig, hybrid_forward, hybrid_init,
                                    make_hybrid_train_step)
 from ray_tpu.ops import ssm_scan, ssm_scan_plan, ssm_scan_reference
 from ray_tpu.ops.attention import VMEM_BUDGET
-from ray_tpu.ops.layers import causal_conv1d, gated_rms_norm
+from ray_tpu.ops.layers import causal_conv1d_silu, gated_rms_norm
 
 TOL = 1e-4
 
@@ -138,23 +138,144 @@ def test_ssm_scan_plan_gives_the_cell_its_sizes():
         ssm_scan_plan(1000, 64, 64, 128, 256)
 
 
-def test_causal_conv1d_and_its_tail():
+def _plain_conv_silu(x, weight, bias, tail=None):
+    """The plain K-shift form, as ops/layers.py had it until PR 34, its
+    gradient left to autodiff: what the rule is held to."""
+    K, L = weight.shape[1], x.shape[1]
+    if tail is None:
+        tail = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
+    padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = weight.astype(jnp.float32)
+    y = bias.astype(jnp.float32)
+    for k in range(K):
+        y = y + padded[:, k:k + L].astype(jnp.float32) * w[:, k]
+    return jax.nn.silu(y.astype(x.dtype)), padded[:, L:]
+
+
+def _conv_inputs(b, L, C, with_tail, dtype=jnp.float32, K=4, seed=0):
+    """(x, weight, bias, tail or None) and weights for y and the new tail:
+    a loss that reads both outputs."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + 7 * L + C), 6)
+    tail = jax.random.normal(ks[3], (b, K - 1, C)).astype(dtype)
+    return ((jax.random.normal(ks[0], (b, L, C)).astype(dtype),
+             (jax.random.normal(ks[1], (C, K)) * 0.5).astype(dtype),
+             jax.random.normal(ks[2], (C,)).astype(dtype),
+             tail if with_tail else None),
+            (jax.random.normal(ks[4], (b, L, C)).astype(dtype),
+             jax.random.normal(ks[5], (b, K - 1, C)).astype(dtype)))
+
+
+def _conv_gradients(fn, args, weights):
+    """d/d(x, weight, bias[, tail]) of <y, weights[0]> + <new tail,
+    weights[1]>, in float32."""
+    def loss(*given):
+        y, tail = fn(*given)
+        return (jnp.sum(y.astype(jnp.float32) * weights[0])
+                + jnp.sum(tail.astype(jnp.float32) * weights[1]))
+
+    given = args if args[3] is not None else args[:3]
+    return jax.jit(jax.grad(loss, argnums=tuple(range(len(given)))))(*given)
+
+
+# b in {1, 2}; L shorter than the taps, one token (decode) and longer; with
+# and without a tail; C under and over a lane's 128, a multiple of neither
+CONV_CASES = [(1, 12, 6, False), (2, 12, 6, True), (2, 2, 6, False),
+              (1, 2, 6, True), (2, 1, 6, True), (1, 3, 130, True),
+              (2, 37, 130, False), (2, 37, 130, True)]
+
+
+def test_causal_conv1d_silu_and_its_tail():
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(ks[0], (2, 12, 6))
     w, bias = jax.random.normal(ks[1], (6, 4)), jax.random.normal(ks[2], (6,))
-    y, tail = causal_conv1d(x, w, bias)
+    y, tail = causal_conv1d_silu(x, w, bias)
     padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
-    want = bias + sum(padded[:, k:k + 12] * w[:, k] for k in range(4))
+    want = reference._silu(
+        bias + sum(padded[:, k:k + 12] * w[:, k] for k in range(4)))
     _close(y, want)
     np.testing.assert_array_equal(tail, x[:, -3:])
     # in two pieces, the tail handed on, it is the same convolution
-    y1, t1 = causal_conv1d(x[:, :5], w, bias)
-    y2, t2 = causal_conv1d(x[:, 5:], w, bias, t1)
+    y1, t1 = causal_conv1d_silu(x[:, :5], w, bias)
+    y2, t2 = causal_conv1d_silu(x[:, 5:], w, bias, t1)
     _close(jnp.concatenate([y1, y2], 1), want)
     np.testing.assert_array_equal(t2, tail)
-    y3, t3 = causal_conv1d(x[:, :2], w, bias)      # shorter than the taps
+    y3, t3 = causal_conv1d_silu(x[:, :2], w, bias)      # shorter than the taps
     np.testing.assert_array_equal(t3[:, 1:], x[:, :2])
     np.testing.assert_array_equal(t3[:, :1], 0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,L,C,with_tail", CONV_CASES)
+def test_conv_rule_forward_is_the_plain_form_bit_for_bit(b, L, C, with_tail,
+                                                         dtype):
+    args, _ = _conv_inputs(b, L, C, with_tail, dtype)
+    for got, want in zip(jax.jit(causal_conv1d_silu)(*args),
+                         jax.jit(_plain_conv_silu)(*args)):
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("b,L,C,with_tail", CONV_CASES)
+def test_conv_rule_gradients_equal_autodiff_of_the_plain_form(b, L, C,
+                                                              with_tail):
+    args, weights = _conv_inputs(b, L, C, with_tail)
+    got = _conv_gradients(causal_conv1d_silu, args, weights)
+    want = _conv_gradients(_plain_conv_silu, args, weights)
+    assert len(got) == (4 if with_tail else 3)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        _close(g, w, 1e-5)
+
+
+@pytest.mark.parametrize("weights_dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("b,with_tail", [(1, False), (2, True)])
+def test_conv_rule_in_bfloat16_is_no_further_from_float32_than_autodiff(
+        b, with_tail, weights_dtype):
+    """bf16 in and out, as the cells run it: each of the rule's gradients
+    lies no further from the float32 gradient at the same inputs than
+    autodiff of the plain form does (which rounds g, and then each tap's
+    product, to bfloat16 before it adds them)."""
+    (x, w, bias, tail), weights = _conv_inputs(b, 512, 130, with_tail,
+                                               jnp.bfloat16)
+    args = (x, w.astype(weights_dtype), bias.astype(weights_dtype), tail)
+    exact = _conv_gradients(
+        _plain_conv_silu,
+        tuple(None if t is None else t.astype(jnp.float32) for t in args),
+        weights)
+    rule = _conv_gradients(causal_conv1d_silu, args, weights)
+    plain = _conv_gradients(_plain_conv_silu, args, weights)
+
+    def distance(got, want):
+        assert got.dtype in (jnp.bfloat16, weights_dtype)
+        return float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                     / jnp.linalg.norm(want))
+
+    for name, r, p, e in zip(("x", "weight", "bias", "tail"), rule, plain,
+                             exact):
+        assert distance(r, e) <= distance(p, e), name
+        assert distance(r, e) < 1e-2, name
+
+
+@pytest.mark.parametrize("cut", [1, 2, 5, 11])
+def test_a_sequence_in_two_calls_is_one_call_values_and_gradients(cut):
+    """The tail handed from one call to the next: the same values bit for
+    bit, and the same gradients by x, the taps, the bias and the first
+    call's tail."""
+    (x, w, bias, tail), weights = _conv_inputs(2, 12, 6, True)
+
+    def two_calls(x, w, bias, tail):
+        y1, t1 = causal_conv1d_silu(x[:, :cut], w, bias, tail)
+        y2, t2 = causal_conv1d_silu(x[:, cut:], w, bias, t1)
+        return jnp.concatenate([y1, y2], axis=1), t2
+
+    for got, want in zip(two_calls(x, w, bias, tail),
+                         causal_conv1d_silu(x, w, bias, tail)):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(
+            _conv_gradients(two_calls, (x, w, bias, tail), weights),
+            _conv_gradients(_plain_conv_silu, (x, w, bias, tail), weights)):
+        _close(got, want, 1e-5)
 
 
 def test_gated_norm_gates_first_and_norms_once():
