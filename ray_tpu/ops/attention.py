@@ -19,6 +19,9 @@ attention written for the TPU memory hierarchy, forward AND backward:
   the diagonal builds a mask. Nothing above it is computed or fetched.
   Where a whole sequence would not fit VMEM_BUDGET the swept side comes
   in blocks on the grid and the same loops run inside.
+* Under a `window` the same blocks work the band only, strip by strip:
+  a strip of own positions against the one tile of the other side it can
+  see, masked at the band's far edge and on the diagonal (`_band_work`).
 
 On the chip tool's v5e (PERF.md §6, PR 26; bf16, causal, a call's device
 time fwd / dQ / dK+dV): (192, 1024, 64) 0.71 / 0.58 / 0.81 ms where one
@@ -171,8 +174,12 @@ class KernelPlan:
     causal limit, and the block the diagonal crosses as a triangle of
     `sub` x `sub` sub-blocks: per strip of `sub` own positions one tile
     that ends on the diagonal, of which only the last sub-block is
-    masked. `computed` + `skipped` = every sub-block of the
-    seq_len x seq_len square; `masked` of the computed ones build a mask."""
+    masked. Under a window the strips are all there is (`_band_work`): a
+    strip's tile starts at the band's far edge and ends on the diagonal,
+    and its first and last sub-blocks are the masked ones. `computed` +
+    `skipped` = every sub-block of the seq_len x seq_len square; `masked`
+    of the computed ones build a mask; `tiles` is the matmul tiles (calls
+    of a kernel's `step`) the `computed` sub-blocks are worked in."""
     block: int
     swept: int
     sub: int
@@ -180,6 +187,7 @@ class KernelPlan:
     computed: int
     masked: int
     skipped: int
+    tiles: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -234,69 +242,69 @@ def _triangle(block: int, sub: int, mirrored: bool):
         yield (strip, at, block - at) if mirrored else (strip, 0, at + sub)
 
 
-def _band_blocks(r, block: int, swept: int, window: int, mirrored: bool):
-    """Under a window (a query sees the keys 0 <= q - k < window), the
-    block-sized chunks of a swept block that a program's own block works,
-    as three ranges [lo, hi) in the order they lie: `r` is the own block's
-    number counted from the swept block's first (the diagonal's chunk).
-    Forward and dQ: (the chunks the band's far edge crosses, the chunks
-    wholly inside the band, the diagonal's); dK/dV (`mirrored`): (the
-    diagonal's, wholly inside, the far edge's). The first and the last
-    range are masked, the middle one is not; every other chunk is wholly
-    outside the band and is not computed. Python ints give ints (the
-    plan's counts), traced scalars the kernels' loop bounds."""
-    n = swept // block
-    whole = window // block                  # chunks the band spans whole
-    reach = (window + block - 2) // block    # how far its far edge gets
-    if mirrored:
-        ranges = ((r, r + 1), (r + 1, r + whole),
-                  (r + max(1, whole), r + reach + 1))
-    else:
-        ranges = ((r - reach, r - max(whole - 1, 0)), (r + 1 - whole, r),
-                  (r, r + 1))
-    return tuple((_clip(lo, n), _clip(hi, n)) for lo, hi in ranges)
+def _strip_tile(sub: int, window: int, swept: int) -> Optional[int]:
+    """Width of the one tile a strip of `sub` own positions can work its
+    whole band in: its own sub-block and the `reach` the band gets to
+    before it (after it in dK/dV). None where that is wider than the swept
+    block, or its scores larger than a causal block's (_MAX_BLOCK squared:
+    a window of several thousand positions): the band then goes in pieces
+    (`_band_work`)."""
+    width = ((window + sub - 2) // sub + 1) * sub
+    return width if width <= swept and width * sub <= _MAX_BLOCK ** 2 \
+        else None
 
 
 def _count(seq_len: int, block: int, swept: int, sub: int, causal: bool,
            mirrored: bool, window: Optional[int] = None):
-    """(computed, masked, skipped) sub-blocks of one head, by the rules
-    the kernel's loops follow."""
+    """(computed, masked, skipped, tiles) of one head: sub-blocks, and the
+    tiles they are worked in, by the rules the kernel's loops follow."""
     n = seq_len // sub
     if not causal:
-        return n * n, 0, 0
-    computed = masked = 0
-    if window is not None:        # block == sub: a chunk is a sub-block
+        return n * n, 0, 0, (seq_len // block) ** 2
+    computed = masked = tiles = 0
+    if window is not None:        # the kernels' own rule, on Python ints
+
+        def step(rows, cols, mask):
+            nonlocal computed, masked, tiles
+            tiles += 1
+            computed += (rows.size // sub) * (cols.size // sub)
+            if mask:
+                masked += min(mask[2] + mask[3], cols.size) // sub
+
+        def each(lo, hi, body):
+            for j in range(lo, hi):
+                body(j)
         for own in range(0, seq_len, block):
             for other in range(0, seq_len, swept):
-                near, inside, far = (
-                    max(0, hi - lo) for lo, hi in _band_blocks(
-                        (own - other) // block, block, swept, window,
-                        mirrored))
-                computed += near + inside + far
-                masked += near + far
-        return computed, masked, n * n - computed
+                _band_work(step, (own - other) // sub, block, swept, sub,
+                           window, mirrored, swept == seq_len, sweep=each)
+        return computed, masked, n * n - computed, tiles
     for own in range(0, seq_len, block):
         for other in range(0, seq_len, swept):
             lo, hi = _visible_blocks(own - other, block, swept, mirrored)
             computed += (hi - lo) * (block // sub) ** 2
+            tiles += hi - lo
             if _on_diagonal(own - other, swept):
                 for _, _, width in _triangle(block, sub, mirrored):
                     computed += width // sub
                     masked += 1
-    return computed, masked, n * n - computed
+                    tiles += 1
+    return computed, masked, n * n - computed, tiles
 
 
 def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
-                itemsize: int, v_dim: Optional[int] = None) -> int:
+                itemsize: int, v_dim: Optional[int] = None,
+                tile: Optional[int] = None) -> int:
     """An upper estimate of one program's VMEM: every operand and result
     block twice (the pipeline's two buffers), float32 scratch, and three
-    float32 block x block tiles (scores, probabilities, their gradient).
-    v, o and their gradients are `v_dim` wide (head_dim where None)."""
+    float32 tiles (scores, probabilities, their gradient) of `tile`
+    elements, block x block where None. v, o and their gradients are
+    `v_dim` wide (head_dim where None)."""
     v_dim = head_dim if v_dim is None else v_dim
     row = 128 * 4                                   # a lane-padded f32 row
     own, other = block * head_dim * itemsize, swept * head_dim * itemsize
     own_v, other_v = block * v_dim * itemsize, swept * v_dim * itemsize
-    tiles = 3 * block * block * 4
+    tiles = 3 * (block * block if tile is None else tile) * 4
     if kernel == "fwd":     # q | k, v -> o, lse; acc, m, l
         return (2 * (own + other + other_v) + 2 * (own_v + block * row)
                 + block * (v_dim * 4 + 2 * row) + tiles)
@@ -329,12 +337,17 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     loops inside.
 
     Under a `window` (causal, a query sees itself and the window - 1
-    positions before it) a kernel's own block is one sub-block, and of
-    the swept side it computes the chunks the band touches and no other
-    (`_band_blocks`): those wholly inside the band unmasked, the one the
-    diagonal crosses and the one or two the band's far edge crosses under
-    a mask, about (window + sub) / seq_len of the square. `v_dim` is the
-    width of v and of the output where it is not q's and k's."""
+    positions before it) the sizes follow the same rules, and a program
+    works its block strip by strip and the band only (`_band_work`): per
+    strip of `sub` own positions one tile from the band's far edge to the
+    diagonal, (reach + 1) * sub wide with reach = ceil((window - 1) / sub),
+    of which the sub-blocks the far edge crosses (one or two) and the
+    diagonal's build a mask; about (window + sub) / seq_len of the square.
+    A strip whose tile is cut by the sequence's start or end, or lies
+    across two swept blocks, or would be larger than a causal block's
+    (`_strip_tile`), goes in pieces: a sub-block for each masked edge,
+    the sub-blocks between in chunks of up to 1,024 positions. `v_dim` is
+    the width of v and of the output where it is not q's and k's."""
     if window is not None and (not causal or window < 1):
         raise ValueError("a window is causal and at least 1 wide")
     if seq_len < 128 or seq_len % 128:
@@ -346,11 +359,13 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
 
     def plan(kernel: str) -> KernelPlan:
         for swept in sizes:
-            for block in sizes if window is None else [sub]:
+            tile = None if window is None else sub * (
+                _strip_tile(sub, window, swept) or min(_MAX_BLOCK, swept))
+            for block in sizes:
                 if block > _MAX_BLOCK or swept % block:
                     continue
                 need = _vmem_bytes(kernel, block, swept, head_dim, itemsize,
-                                   v_dim)
+                                   v_dim, tile)
                 if need <= VMEM_BUDGET:
                     return KernelPlan(block, swept, sub, need, *_count(
                         seq_len, block, swept, sub, causal,
@@ -414,25 +429,84 @@ def _causal_work(step, rel, block: int, swept: int, sub: int,
             step(_ds(strip * sub, sub, sub), _ds(rel + at, width, sub), True)
 
 
-def _band_work(step, r, block: int, swept: int, window: int,
-               mirrored: bool):
-    """Everything one program computes under a window, as calls of
-    step(own rows, other rows, mask): the chunks `_band_blocks` names,
-    the first and the last range under the band's mask (given as q - k of
-    the tile's first row and column), the middle one unmasked."""
-    own = _ds(0, block, block)
+def _band_work(step, r, block: int, swept: int, sub: int, window: int,
+               mirrored: bool, resident: bool, sweep=_sweep, tile=None,
+               begin=None, done=None):
+    """Everything one program computes under a window (a query sees the
+    keys 0 <= q - k < window), as calls of step(own rows, other rows,
+    mask), strip by strip of `sub` own positions; `r` is the own block's
+    first sub-block counted from the swept block's first. A strip sees its
+    own sub-block of the other side and the `reach` before it (forward and
+    dQ: keys) or after it (dK/dV, `mirrored`: queries). Where they lie
+    whole inside the swept block they are one tile (`_strip_tile`) in
+    straight-line code, masked at both ends: mask = ("band", q - k at the
+    tile's first row and column, columns masked at its start, at its
+    end). Such a tile is all its strip computes, so `tile` may take it in
+    `step`'s place (the forward's softmax then needs no carry). Any other
+    strip — cut by the sequence's start or end, across two swept blocks, a
+    tile too large — goes in pieces, in one loop over the block's strips:
+    each sub-block it sees of this swept block under the band's mask, but
+    those wholly inside the band in unmasked chunks of up to _MAX_BLOCK
+    positions where the window holds one; `begin(own rows)` and `done(own
+    rows)` are called before its first and after its last piece. With the
+    swept side `resident` (one swept block: the whole sequence) the forward
+    and dQ strips from the reach-th on cannot be cut. Python ints give the
+    plan's counts (`sweep` then a Python loop; pl.when decides a Python
+    bool at once), traced scalars the kernels' loops."""
+    from jax.experimental import pallas as pl
 
-    def tile(masked: bool):
-        def at(j):
-            first = (j - r if mirrored else r - j) * block
-            step(own, _ds(j * block, block, block),
-                 ("band", first) if masked else False)
-        return at
+    n = swept // sub
+    whole, reach = window // sub, (window + sub - 2) // sub
+    far = (reach - max(whole, 1) + 1) * sub     # what the far edge crosses
+    width = _strip_tile(sub, window, swept)
+    wide = min(_MAX_BLOCK, swept) // sub
+    strips = block // sub
 
-    near, inside, far = _band_blocks(r, block, swept, window, mirrored)
-    _sweep(*near, tile(True))
-    _sweep(*inside, tile(False))
-    _sweep(*far, tile(True))
+    def first_seen(t):
+        return t if mirrored else t - reach
+
+    def pieces(strip):
+        own, t = _ds(strip * sub, sub, sub), r + strip
+        lo, hi = (_clip(first_seen(t) + d, n) for d in (0, reach + 1))
+        if begin is not None:
+            begin(own)
+
+        def single(j):
+            step(own, _ds(j * sub, sub, sub),
+                 ("band", (j - t if mirrored else t - j) * sub, sub, 0))
+        if whole - 1 < wide:
+            sweep(lo, hi, single)
+        else:   # wholly inside the band: whole - 1 sub-blocks by the diagonal's
+            inside, end = (
+                _clip((t + 1 if mirrored else t - whole + 1) + d, n)
+                for d in (0, whole - 1))
+            chunks = (end - inside) // wide
+            sweep(lo, inside, single)
+            sweep(0, chunks, lambda j: step(
+                own, _ds((inside + j * wide) * sub, wide * sub, sub), False))
+            sweep(inside + chunks * wide, hi, single)
+        if done is not None:
+            done(own)
+
+    def cut(strip):
+        first = first_seen(r + strip)
+        pl.when((first < 0) | (first + reach >= n))(
+            functools.partial(pieces, strip))
+
+    if width is None:
+        sweep(0, strips, pieces)
+        return
+    sweep(0, strips if mirrored or not resident else min(reach, strips), cut)
+    edges = ("band", 0, sub, far) if mirrored \
+        else ("band", reach * sub, far, sub)
+    for strip in range(strips):
+        first = first_seen(r + strip)
+        work = functools.partial(tile or step, _ds(strip * sub, sub, sub),
+                                 _ds(first * sub, width, sub), edges)
+        if resident and not mirrored and strip >= reach:
+            work()
+        else:
+            pl.when((first >= 0) & (first + reach < n))(work)
 
 
 def _mask_band(s, first, window: int, mirrored: bool):
@@ -448,12 +522,21 @@ def _mask_band(s, first, window: int, mirrored: bool):
 
 def _masked(s, mask, sub: int, window, mirrored: bool):
     """A tile of scores under what `step` was told: False, True (the
-    diagonal's sub-block of a causal strip) or ("band", first)."""
+    diagonal's sub-block of a causal strip) or ("band", first, head,
+    tail): the band's mask on the tile's first `head` and last `tail`
+    columns, which hold its two edges; what lies between is whole."""
     if mask is True:
         return _mask_diagonal(s, sub, mirrored)
     if mask is False:
         return s
-    return _mask_band(s, mask[1], window, mirrored)
+    _, first, head, tail = mask
+    rest = s.shape[1] - tail
+    if head >= rest:
+        return _mask_band(s, first, window, mirrored)
+    return jnp.concatenate([
+        _mask_band(s[:, :head], first, window, mirrored), s[:, head:rest],
+        _mask_band(s[:, rest:], first + (rest if mirrored else -rest),
+                   window, mirrored)], axis=1)
 
 
 def _mask_diagonal(s, sub: int, mirrored: bool):
@@ -500,20 +583,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
     qi, ki = _block_id(1, grid[1]), _block_id(2, grid[2])
     fold = _scale_is_exact(sm_scale)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
+    # With K and V resident a strip under a window begins and ends in one
+    # program: it starts and writes its own rows, a strip in one tile
+    # without the scratch.
+    by_strip = window is not None and grid[2] == 1
 
-    def step(rows, cols, on_diagonal):
+    def start(rows):
+        acc_scr[rows, :] = jnp.zeros((rows.size, acc_scr.shape[1]),
+                                     jnp.float32)
+        m_scr[rows, :] = jnp.full((rows.size, 1), -jnp.inf, jnp.float32)
+        l_scr[rows, :] = jnp.zeros((rows.size, 1), jnp.float32)
+
+    if not by_strip:
+        @pl.when(ki == 0)
+        def _init():
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+            m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+            l_scr[...] = jnp.zeros_like(l_scr)
+
+    def scores(rows, cols, mask):
         q = q_ref[0, rows, :]
         if fold:
             q = q * sm_scale
         s = _dot(q, k_ref[0, cols, :], _NT)
         if not fold:
             s = s * sm_scale
-        s = _masked(s, on_diagonal, sub, window, False)
+        return _masked(s, mask, sub, window, False)
+
+    def step(rows, cols, mask):
+        s = scores(rows, cols, mask)
         m_prev = m_scr[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -525,12 +623,36 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         acc_scr[rows, :] = alpha * acc_scr[rows, :] + _dot(
             p.astype(v.dtype), v, _NN)
 
+    def whole_band(rows, cols, mask):
+        # All a strip of queries sees, in one tile: a softmax with no carry.
+        s = scores(rows, cols, mask)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)     # the diagonal's is in it
+        v = v_ref[0, cols, :]
+        o_ref[0, rows, :] = (_dot(p.astype(v.dtype), v, _NN) / l).astype(
+            o_ref.dtype)
+        if save_lse:
+            lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                                   (rows.size, 128))
+
+    def finish(rows):
+        l = l_scr[rows, :]
+        o_ref[0, rows, :] = (acc_scr[rows, :] / l).astype(o_ref.dtype)
+        if save_lse:
+            lse_ref[0, rows, :] = jnp.broadcast_to(
+                m_scr[rows, :] + jnp.log(l), (rows.size, 128))
+
     if window is None:
         _causal_work(step, qi * block - ki * swept, block, swept, sub,
                      causal, False)
     else:
-        _band_work(step, qi - ki * (swept // block), block, swept, window,
-                   False)
+        whole = dict(tile=whole_band, begin=start, done=finish) \
+            if by_strip else {}
+        _band_work(step, qi * (block // sub) - ki * (swept // sub), block,
+                   swept, sub, window, False, by_strip, **whole)
+    if by_strip:
+        return
 
     @pl.when(ki == grid[2] - 1)
     def _finalize():
@@ -690,8 +812,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         _causal_work(step, qi * block - ki * swept, block, swept, sub,
                      causal, False)
     else:
-        _band_work(step, qi - ki * (swept // block), block, swept, window,
-                   False)
+        _band_work(step, qi * (block // sub) - ki * (swept // sub), block,
+                   swept, sub, window, False, grid[2] == 1)
 
     @pl.when(ki == grid[2] - 1)
     def _finalize():
@@ -738,8 +860,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         _causal_work(step, ki * block - qi * swept, block, swept, sub,
                      causal, True)
     else:
-        _band_work(step, ki - qi * (swept // block), block, swept, window,
-                   True)
+        _band_work(step, ki * (block // sub) - qi * (swept // sub), block,
+                   swept, sub, window, True, grid[2] == 1)
 
     @pl.when(qi == grid[2] - 1)
     def _finalize():

@@ -4,7 +4,11 @@ here (a query sees itself and the window - 1 positions before it), for
 windows below, equal to and above the sub-block and block sizes, with the
 swept side resident and on the grid; and `attention_plan`'s window counts
 against a brute-force count of the sub-blocks the band touches. float32,
-seeded inputs; tolerances as tests/test_models_ops.py's kernel tests."""
+seeded inputs; tolerances as tests/test_models_ops.py's kernel tests.
+Where a kernel's own block is several sub-blocks (S = 2,048 and 4,096:
+strips of one tile each, the first block's cut by the sequence's start)
+bfloat16 in and out as the cells run it, each of the output and the three
+gradients against `mha_reference` in float32."""
 
 import jax
 import jax.numpy as jnp
@@ -83,13 +87,99 @@ def test_values_twice_as_wide_as_keys(S, window):
 @pytest.mark.parametrize("window", [100, 256, 700])
 def test_window_with_the_swept_side_on_the_grid(window, monkeypatch):
     """Under a budget whole sequences do not fit, keys (forward, dQ) and
-    queries (dK/dV) come in blocks on the grid: the band's chunk numbers
-    are traced values there and run negative and past the block."""
+    queries (dK/dV) come in blocks on the grid: a strip's sub-block
+    numbers are traced values there and run negative and past the block.
+    Swept blocks of two sub-blocks (one in dK/dV): a window of 100 has
+    every other strip's tile inside one, the wider ones none, and those
+    strips go in pieces."""
     monkeypatch.setattr(attention, "VMEM_BUDGET", 1_500_000)
     plan = attention_plan(1024, 64, True, jnp.float32, window, 128)
     assert plan.fwd.swept < 1024 and plan.dkv.swept < 1024, plan
     assert plan.fwd.block == plan.fwd.sub == 128
+    assert (attention._strip_tile(128, window, plan.fwd.swept)
+            == (256 if window == 100 else None))
     _check(1024, 64, 128, window)
+
+
+def _relative_errors(S, hd, vd, window, heads=1):
+    """|kernel - float32| / |float32| of the output, dq, dk and dv, the
+    kernels on bfloat16 operands and `mha_reference` on the same values
+    in float32."""
+    ks = jax.random.split(jax.random.PRNGKey(S + (window or 0)), 4)
+    q, k = (jax.random.normal(key, (1, heads, S, hd), jnp.bfloat16)
+            for key in ks[:2])
+    v, g = (jax.random.normal(key, (1, heads, S, vd), jnp.bfloat16)
+            for key in ks[2:])
+    scale = hd ** -0.5
+
+    def f32(x):
+        return x.astype(jnp.float32)
+    out, vjp = jax.vjp(lambda *a: flash_attention(*a, True, scale, window),
+                       q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda *a: mha_reference(*a, True, scale, window), f32(q), f32(k),
+        f32(v))
+    got = (out, *vjp(g))
+    assert all(a.dtype == jnp.bfloat16 for a in got)
+    return [float(jnp.linalg.norm(f32(a) - b) / jnp.linalg.norm(b))
+            for a, b in zip(got, (want, *want_vjp(f32(g))), strict=True)]
+
+
+# One rounding of bfloat16 is 2 ** -9 = 0.002 of a value on average; the
+# kernels read 0.0021 to 0.0026 here, a fault of structure 0.1 and more.
+BF16_LIMIT = 0.005
+
+
+# Own blocks of 1,024 in sub-blocks of 256: a window under a sub-block
+# (2 and 100: tiles of two), of one, of two (the cell's: tiles of three), between two
+# and three (the far edge crosses two sub-blocks), of four, one short of
+# and beyond the sequence (every strip cut by its start, in pieces).
+@pytest.mark.parametrize("S,window", [
+    (2048, 2), (2048, 100), (2048, 256), (2048, 512), (2048, 700),
+    (2048, 1024), (2048, 2047), (2048, 5000), (4096, 512), (4096, 700),
+    (4096, 1024)])
+def test_large_own_blocks_in_bfloat16_against_float32(S, window):
+    plan = attention_plan(S, 64, True, jnp.bfloat16, window, 128)
+    for kernel in (plan.fwd, plan.dq, plan.dkv):
+        assert (kernel.block, kernel.swept, kernel.sub) == (1024, S, 256)
+    # the first block's strips are cut by the sequence's start unless the
+    # window is under a sub-block and one: tiles < strips only if none is
+    assert plan.fwd.tiles >= S // 256
+    errors = _relative_errors(S, 64, 128, window)
+    assert max(errors) < BF16_LIMIT, errors
+
+
+def test_large_own_blocks_at_head_dim_128():
+    """The next window/global model's shape (window 1,024 at head_dim 128,
+    PERF.md §7): a scale that is no power of two stays on the scores."""
+    errors = _relative_errors(4096, 128, 128, 1024)
+    assert max(errors) < BF16_LIMIT, errors
+
+
+@pytest.mark.parametrize("S,window,dtype,budget,whole", [
+    (4096, 256, jnp.bfloat16, 5_000_000, (True, True, True)),
+    (4096, 512, jnp.bfloat16, 5_750_000, (True, True, False)),
+    (2048, 100, jnp.float32, 5_500_000, (True, True, True))])
+def test_strips_across_swept_blocks(S, window, dtype, budget, whole,
+                                    monkeypatch):
+    """Own blocks of several sub-blocks with the swept side on the grid:
+    a strip whose tile lies inside a swept block works it whole, one that
+    lies across two works its pieces in both; in dK/dV (swept blocks of
+    two sub-blocks) every other strip does."""
+    monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
+    plan = attention_plan(S, 64, True, dtype, window, 128)
+    for kernel, tile in zip((plan.fwd, plan.dq, plan.dkv), whole,
+                            strict=True):
+        assert kernel.swept < S and kernel.block > kernel.sub, plan
+        assert (attention._strip_tile(kernel.sub, window, kernel.swept)
+                is not None) == tile
+        assert kernel.tiles > S // kernel.sub       # some strip in pieces
+    assert plan.dkv.swept == 2 * plan.dkv.sub
+    if dtype == jnp.float32:
+        _check(S, 64, 128, window)
+    else:
+        errors = _relative_errors(S, 64, 128, window)
+        assert max(errors) < BF16_LIMIT, errors
 
 
 def test_reference_path_takes_the_window_too(monkeypatch):
@@ -105,15 +195,45 @@ def test_reference_path_takes_the_window_too(monkeypatch):
 
 
 def _brute(S, sub, window):
-    """(touched, crossed) sub-blocks of the S x S square: those holding a
-    visible (query, key) pair, and of them those holding an invisible one
-    too. By every pair."""
+    """(touched, crossed) sub-blocks of the S x S square, as boolean
+    [query sub-block, key sub-block] maps: those holding a visible (query,
+    key) pair, and of them those holding an invisible one too. By every
+    pair."""
     ahead = np.arange(S)[:, None] - np.arange(S)[None, :]
     seen = (ahead >= 0) & (ahead < window)
     tiles = seen.reshape(S // sub, sub, S // sub, sub)
     touched = tiles.any(axis=(1, 3))
     whole = tiles.all(axis=(1, 3))
-    return int(touched.sum()), int((touched & ~whole).sum())
+    return touched, touched & ~whole
+
+
+def _brute_tiles(touched, crossed, kernel, window, mirrored):
+    """Tiles a head's `touched` sub-blocks are worked in, counted from the
+    maps and the plan's sizes alone: a strip (a row of the map; a column in
+    dK/dV) whose touched sub-blocks in a swept block are all the band can
+    touch is one tile, if that tile is allowed (`_strip_tile`); any other
+    goes sub-block by sub-block, but the ones wholly inside the band in
+    chunks of `wide` where the window holds one."""
+    if mirrored:
+        touched, crossed = touched.T, crossed.T
+    n = kernel.swept // kernel.sub
+    reach = (window + kernel.sub - 2) // kernel.sub
+    wide = min(1024, kernel.swept) // kernel.sub
+    whole_tile = attention._strip_tile(kernel.sub, window, kernel.swept)
+    tiles = 0
+    for strip in range(touched.shape[0]):
+        for other in range(0, touched.shape[1], n):
+            seen = touched[strip, other:other + n]
+            if whole_tile and seen.sum() == reach + 1:
+                tiles += 1
+                continue
+            inside = int((seen & ~crossed[strip, other:other + n]).sum())
+            if window // kernel.sub - 1 < wide:     # no chunk fits the band
+                tiles += int(seen.sum())
+            else:
+                tiles += int(seen.sum()) - inside + inside // wide \
+                    + inside % wide
+    return tiles
 
 
 @pytest.mark.parametrize("budget", [None, 1_500_000],
@@ -121,7 +241,7 @@ def _brute(S, sub, window):
 @pytest.mark.parametrize("S,window", [
     (512, 1), (512, 64), (512, 128), (512, 129), (512, 200), (512, 256),
     (512, 511), (512, 512), (512, 4096), (1024, 300), (2048, 512),
-    (2048, 513), (4096, 1024)])
+    (2048, 513), (4096, 1024), (4096, 2000), (4096, 4096)])
 def test_plan_counts_the_band_as_a_brute_force_count_does(S, window, budget,
                                                           monkeypatch):
     if budget is not None:     # tiles of 256 x 256 need more than of 128
@@ -133,24 +253,76 @@ def test_plan_counts_the_band_as_a_brute_force_count_does(S, window, budget,
     n = (S // plan.fwd.sub) ** 2
     assert plan.window == window
     for kernel in (plan.fwd, plan.dq, plan.dkv):
-        assert kernel.block == kernel.sub
-        assert kernel.computed == touched          # nothing outside the band
-        assert kernel.skipped == n - touched
+        # the causal path's sizes: the largest block up to 1,024 that
+        # tiles the swept side
+        assert kernel.block % kernel.sub == 0
+        assert kernel.block == min(1024, kernel.swept) or budget
+        assert kernel.computed == touched.sum()    # nothing outside the band
+        assert kernel.skipped == n - touched.sum()
         # every sub-block the band's edges cross is masked; one the far
         # edge only just reaches whole may be masked besides (the rule
-        # works whole chunks: `reach` rounds up)
-        assert crossed <= kernel.masked <= kernel.computed
-    assert plan.executed_share == touched / n
+        # works whole sub-blocks: `reach` rounds up), and a strip in
+        # pieces masks each piece it does not take in a chunk
+        assert crossed.sum() <= kernel.masked <= kernel.computed
+        assert kernel.tiles == _brute_tiles(
+            touched, crossed, kernel, window, kernel is plan.dkv)
+        assert S // kernel.sub <= kernel.tiles <= kernel.computed
+    assert plan.executed_share == touched.sum() / n
 
 
 def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
     band = attention_plan(16384, 64, True, jnp.bfloat16, 512, 128)
     causal = attention_plan(16384, 64, True, jnp.bfloat16, None, 128)
     assert band.fwd.computed == 189 and causal.fwd.computed == 2080
-    assert band.fwd.masked == 126
+    # 16 programs a head of four strips, a strip one tile of three
+    # sub-blocks (768 keys: far edge and diagonal masked, 126 in all, one
+    # between whole); the sequence's first two strips have no three to see
+    # and go in 1 + 2 pieces, each masked: 65 tiles where blocks of one
+    # sub-block made 189 of 64 programs
+    for kernel in (band.fwd, band.dq):
+        assert (kernel.block, kernel.swept, kernel.sub) == (1024, 16384, 256)
+        assert (kernel.tiles, kernel.masked) == (65, 127)
+    assert attention._strip_tile(256, 512, 16384) == 768
+    # dK/dV: queries in two grid blocks of 8,192 (lse and delta travel
+    # lane-replicated), so the two key strips whose queries lie across the
+    # boundary go in 3 pieces each, as the last two do in 2 + 1
+    assert (band.dkv.block, band.dkv.swept) == (1024, 8192)
+    assert (band.dkv.tiles, band.dkv.masked) == (69, 129)
+    assert causal.dkv.block == 512 and causal.fwd.tiles == 184
+    for kernel in (band.fwd, band.dq, band.dkv):
+        assert kernel.tiles <= 16384 // 256 + 8
+        assert kernel.vmem_bytes <= attention.VMEM_BUDGET
     # and an unwindowed call's plan is what it was before windows existed
     assert attention_plan(16384, 64) == attention_plan(
         16384, 64, True, jnp.bfloat16, None, None)
+
+
+def test_unwindowed_plans_are_what_they_were():
+    """Every window=None plan the five cells run, field for field as
+    before a window could hold a large own block (a literal copy of the
+    parent commit's, PR 34's tree), beside the new count of tiles."""
+    fields = ("block", "swept", "sub", "vmem_bytes", "computed", "masked",
+              "skipped", "tiles")
+    for args, want in [
+            ((1024, 64), [(1024, 1024, 128, 15990784, 36, 8, 28, 8),
+                          (1024, 1024, 128, 16252928, 36, 8, 28, 8),
+                          (1024, 1024, 128, 16777216, 36, 8, 28, 8)]),
+            ((4096, 128), [(1024, 4096, 256, 20447232, 136, 16, 120, 22),
+                           (1024, 4096, 256, 20971520, 136, 16, 120, 22),
+                           (1024, 4096, 256, 28311552, 136, 16, 120, 22)]),
+            ((16384, 64), [(1024, 16384, 256, 23855104, 2080, 64, 2016, 184),
+                           (1024, 16384, 256, 24117248, 2080, 64, 2016, 184),
+                           (512, 8192, 256, 24903680, 2080, 64, 2016, 560)]),
+            ((16384, 64, True, jnp.bfloat16, None, 128),
+             [(1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
+              (1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
+              (512, 8192, 256, 27394048, 2080, 64, 2016, 560)])]:
+        plan = attention_plan(*args)
+        assert (plan.seq_len, plan.head_dim, plan.causal, plan.window,
+                plan.vmem_budget) == (*args[:2], True, None, 32 * 2 ** 20)
+        got = [tuple(getattr(kernel, f) for f in fields)
+               for kernel in (plan.fwd, plan.dq, plan.dkv)]
+        assert got == want, args
 
 
 def test_a_window_is_causal_and_positive():

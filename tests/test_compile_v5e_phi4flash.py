@@ -171,21 +171,28 @@ def test_the_convolution_writes_no_tap_scaled_copies(step):
 
 def test_windowed_layers_compute_the_band_and_nothing_else():
     """What the windowed layers' kernels run at the cell's shape, by the
-    plan the kernels take their loops from: three sub-blocks of 256 keys a
-    block of 256 queries (the far edge's and the diagonal's masked, the
-    one between whole), 189 of the 4,096 in the square a head, where the
-    causal triangle alone is 2,080."""
+    plan the kernels take their loops from (the step above compiled with
+    it): own blocks of 1,024 as the full layers', 16 programs a head, a
+    program four strips of 256 own positions, a strip one tile of 768 of
+    the other side (the far edge's sub-block and the diagonal's masked,
+    the one between whole): 189 of the 4,096 sub-blocks in the square a
+    head, where the causal triangle alone is 2,080, in 65 tiles (69 in
+    dK/dV, whose queries come in two grid blocks of 8,192) where own
+    blocks of one sub-block ran them as 189 tiles of 64 programs."""
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import attention_plan
+    from ray_tpu.ops.attention import VMEM_BUDGET, attention_plan
 
     band = attention_plan(16384, 64, True, jnp.bfloat16, window=512,
                           v_dim=128)
     causal = attention_plan(16384, 64, True, jnp.bfloat16, v_dim=128)
-    for kernel in (band.fwd, band.dq, band.dkv):
-        assert (kernel.block, kernel.sub) == (256, 256)
-        assert (kernel.computed, kernel.masked, kernel.skipped) == (
-            189, 126, 4096 - 189)
+    for kernel, swept, tiles in ((band.fwd, 16384, 65), (band.dq, 16384, 65),
+                                 (band.dkv, 8192, 69)):
+        assert (kernel.block, kernel.swept, kernel.sub) == (1024, swept, 256)
+        assert (kernel.computed, kernel.skipped) == (189, 4096 - 189)
+        assert 126 <= kernel.masked <= 129
+        assert kernel.tiles == tiles <= 16384 // 256 + 8
+        assert kernel.vmem_bytes <= VMEM_BUDGET
     assert causal.fwd.computed == 2080
     assert band.executed_share < causal.executed_share / 10
 
@@ -202,5 +209,6 @@ def test_step_fits_a_chip(step, record_property):
     # under ISSUE 31's line for its one fallback: the cell stays at 16,384
     assert total < FALLBACK_OVER
     # no residual joined the step with the convolution's rule (PR 34):
-    # not above what XLA gave the step whose backward autodiff derived
+    # not above what XLA gave the step whose backward autodiff derived;
+    # nor with the band's large own blocks (PR 37: VMEM, not HBM)
     assert total <= 14_254_285_824

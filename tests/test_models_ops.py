@@ -379,7 +379,7 @@ class TestFlashKernelInterpret:
         ks = jax.random.split(jax.random.PRNGKey(7), 4)
         q, k, v, g = (jax.random.normal(kk, (1, 3, 1024, 64), jnp.float32)
                       for kk in ks)
-        plan = attention.KernelPlan(block, swept, 128, 0, 0, 0, 0)
+        plan = attention.KernelPlan(block, swept, 128, 0, 0, 0, 0, 0)
         out, lse = attention._flash_forward(q, k, v, causal, 0.125, plan)
         ref, vjp = jax.vjp(
             lambda *a: mha_reference(*a, causal), q, k, v)
