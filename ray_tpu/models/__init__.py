@@ -37,6 +37,14 @@ from .moe import (  # noqa: F401
     moe_loss_and_counters,
     moe_param_axes,
 )
+from .olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig,
+    make_olmo_hybrid_train_step,
+    olmo_hybrid_forward,
+    olmo_hybrid_init,
+    olmo_hybrid_loss,
+    olmo_hybrid_param_axes,
+)
 from .resnet import (  # noqa: F401
     ResNetConfig,
     make_predictor,

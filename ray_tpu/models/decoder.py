@@ -10,12 +10,16 @@ does not), and what its embedding, its two residual branches and its
 logits are multiplied by (1 where it says nothing). Nothing here reads a
 config: one family differs from another by those values and by which
 weights a layer holds (`x_proj`: a Mamba-1 layer; `in_proj` without it: a
-Mamba-2 layer; `gmu_in`: a gated memory unit; `lambda_q1`: differential
-attention, over its own keys and values from `wqkv` or over another
-layer's from `wq` alone; else attention, from `wqkv` or `wq` + `wkv`, with
-`q_norm` or not; `ln1_b`: LayerNorm with bias where the others have
-RMSNorm), and by nothing else. A model's layers need not be alike: each
-picks its mixer by what it holds (`_mixer_of`).
+Mamba-2 layer; `delta_in`: a gated-delta-rule layer; `gmu_in`: a gated
+memory unit; `lambda_q1`: differential attention, over its own keys and
+values from `wqkv` or over another layer's from `wq` alone; else
+attention, from `wqkv` or `wq` + `wkv`, with `q_norm` or not; `ln1_b`:
+LayerNorm with bias where the others have RMSNorm; `ln1`, `ln2`: a block
+that norms what its branches read, x + mixer(norm(x)), `post_attention`,
+`post_feedforward` and neither of those: one that norms what they return,
+x + norm(mixer(x))), and by nothing else. A model's layers need not be
+alike: each picks its mixer and the place of its norms by what it holds
+(`_mix`, `_block`).
 
 Nor need they be independent (SambaY, models.sambay): the layer stack
 carries two values forward besides x, `Shared`: the scan's output `m` of
@@ -30,7 +34,7 @@ after the last one: which, `decoder_hidden` reads off the weights too.
 
     decoder_hidden      embedding, layer stack, final norm, head
     decoder_logits      its rows times its head, float32
-      attention | mamba2 | mamba1 | gmu | diff_attention
+      attention | mamba2 | mamba1 | gated_delta | gmu | diff_attention
                         the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window]) -> (y, new
                         cache[, shared]): attention is the flash kernel
@@ -39,8 +43,10 @@ after the last one: which, `decoder_hidden` reads off the weights too.
                         chunked scan (ops.ssm_scan) over the tokens given,
                         from the cached state where there is one, and one
                         step of the recurrence for a single token; mamba1
-                        the same over ops.selective_scan; gmu no state at
-                        all; diff_attention two softmax maps a pair of
+                        the same over ops.selective_scan; gated_delta the
+                        same over ops.gated_delta, a matrix state a head
+                        that is read back before it is written; gmu no
+                        state at all; diff_attention two softmax maps a pair of
                         heads, their difference times both heads' values
       gelu_mlp | swiglu_mlp | fused_swiglu_mlp | routed_experts
                         the channel mixers, (y, layer) -> (out, stats or
@@ -51,8 +57,11 @@ Cache layout, per layer by its kind: attention (differential too)
 [batch, d_conv - 1, inner + 2 groups x state] the convolution's last
 inputs, "ssm": [batch, heads, head_dim, state] float32}, which does not
 grow; Mamba-1 {"conv": [batch, d_conv - 1, inner], "ssm": [batch, inner,
-state] float32}; a gated memory unit and a cross-attention layer hold
-nothing ({}): they read the tokens in flight and the other layer's cache.
+state] float32}; the gated delta rule {"conv": [batch, taps - 1, heads x
+(2 K + V)] the convolution's last inputs over q | k | v, "delta": [batch,
+heads, K, V] float32}, which does not grow either; a gated memory unit and
+a cross-attention layer hold nothing ({}): they read the tokens in flight
+and the other layer's cache.
 """
 
 from __future__ import annotations
@@ -66,8 +75,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
+from ..ops.gated_delta import gated_delta_rule
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
-                          gated_rms_norm, layer_norm, rms_norm, rope, swiglu)
+                          gated_rms_norm, head_rms_norm_gated, head_spread,
+                          head_sums, layer_norm, rms_norm, rope, swiglu)
 from ..ops.loss import chip_views, lookup
 from ..ops.selective_scan import selective_scan
 from ..ops.ssm_scan import ssm_scan
@@ -93,6 +104,9 @@ class Decoder(NamedTuple):
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 256
+    # A gated-delta-rule layer's chunk (layers that hold `delta_in`; its
+    # heads and widths are read off its weights).
+    delta_chunk: int = 64
     # What a differential-attention layer of its own keys sees while a
     # Mamba-1 layer still follows it: itself and the window - 1 before.
     window: Optional[int] = None
@@ -136,6 +150,10 @@ def _is_mamba2(layer) -> bool:
     return "in_proj" in layer and not _is_mamba1(layer)
 
 
+def _is_gated_delta(layer) -> bool:
+    return "delta_in" in layer
+
+
 def _reads_shared(layer) -> bool:
     """A gated memory unit, or differential attention over another
     layer's keys and values: no state of its own."""
@@ -151,12 +169,21 @@ def _norm(x, holder, name: str, eps: float):
     return rms_norm(x, holder[name], eps)
 
 
+def _norm_if_held(x, layer, name: str, eps: float):
+    """`_norm` where the layer holds the weight, x as it is where not. A
+    block norms what a branch READS (`ln1`, `ln2`) or what it RETURNS
+    (`post_attention`, `post_feedforward`: OLMo 2's order), and says which
+    by the weights it holds."""
+    return _norm(x, layer, name, eps) if name in layer else x
+
+
 def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
     """The state of each of `layers` (a model's `params["layers"]`, or
     anything that holds their keys), by its kind: an attention layer its
     kv heads up to `max_len`, not their copies across a group; a Mamba-2
-    or Mamba-1 layer its convolution's last inputs and its state; a layer
-    that reads what another made, nothing."""
+    or Mamba-1 layer its convolution's last inputs and its state, as a
+    gated-delta-rule layer; a layer that reads what another made,
+    nothing."""
     def one(layer):
         if _reads_shared(layer):
             return {}
@@ -165,6 +192,12 @@ def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
             return {"conv": jnp.zeros((batch, taps - 1, inner), dtype),
                     "ssm": jnp.zeros((batch, inner,
                                       layer["A_log"].shape[1]), jnp.float32)}
+        if _is_gated_delta(layer):
+            H, K, V = _delta_sizes(layer)
+            taps = layer["conv_w"].shape[1]
+            return {"conv": jnp.zeros((batch, taps - 1, H * (2 * K + V)),
+                                      dtype),
+                    "delta": jnp.zeros((batch, H, K, V), jnp.float32)}
         if _is_mamba2(layer):
             conv_dim = (dec.ssm_heads * dec.ssm_head_dim
                         + 2 * dec.ssm_groups * dec.ssm_state)
@@ -253,7 +286,7 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
     def heads(t, n):
         return t.reshape(b, L, n, hd).transpose(0, 2, 1, 3)
 
-    y = _norm(x, layer, "ln1", dec.norm_eps)
+    y = _norm_if_held(x, layer, "ln1", dec.norm_eps)
     if "wqkv" in layer:
         q, k, v = jnp.split(checkpoint_name(
             jnp.einsum("bsd,de->bse", y, layer["wqkv"]), "attention_qkv"),
@@ -335,6 +368,72 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
                             dec.norm_eps)
     new_cache = None if cache is None else {"conv": tail, "ssm": state}
     return jnp.einsum("bse,ed->bsd", ys, layer["out_proj"]), new_cache
+
+
+def _delta_sizes(layer):
+    """(heads, key width, value width) of a gated-delta-rule layer, off
+    its weights (or their shapes): `delta_in` is q | k | v side by side."""
+    H, V = layer["A_log"].shape[0], layer["delta_norm"].shape[0]
+    return H, (layer["delta_in"].shape[1] // H - V) // 2, V
+
+
+def _unit_heads(t, heads: int, scale: float, eps: float):
+    """t [b, L, heads * K] -> [b, L, heads, K], each head's K columns
+    divided by sqrt(their sum of squares + eps) and multiplied by `scale`,
+    in float32, in t's own layout (ops.layers.head_sums tells why)."""
+    b, L, width = t.shape
+    tf = t.astype(jnp.float32)
+    inv = jax.lax.rsqrt(head_sums(jnp.square(tf), heads) + eps) * scale
+    return (tf * head_spread(inv, width)).astype(t.dtype).reshape(
+        b, L, heads, -1)
+
+
+def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """The Gated DeltaNet mixer of x [b, L, d], from the input norm to the
+    output projection: one projection to q | k | v, a causal depthwise
+    convolution with no bias and silu over all of them, q and k
+    L2-normalised a head (q scaled by 1 / sqrt(key width) besides), beta =
+    2 sigmoid(.) in (0, 2) and g = -exp(A_log) softplus(. + dt_bias) <= 0 a
+    head from `delta_ab`, the delta rule (ops.gated_delta), an RMSNorm a
+    head THEN the gate silu(y W_g), the output projection. Training,
+    prefill from a cached state and decode (L = 1: one step of the
+    recurrence) as `mamba2`; `start_pos` is not read. Returns (y, new_cache
+    or None)."""
+    b, L, d = x.shape
+    H, K, V = _delta_sizes(layer)
+    f32 = jnp.float32
+    y = _norm_if_held(x, layer, "ln1", dec.norm_eps)
+    qkv = jnp.einsum("bsd,de->bse", y, layer["delta_in"])
+    with jax.named_scope("ssm_conv"):
+        qkv, tail = causal_conv1d_silu(
+            qkv, layer["conv_w"], None,
+            None if cache is None else cache["conv"])
+    q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
+    with jax.named_scope("delta_qk_norm"):
+        q = _unit_heads(q, H, K ** -0.5, dec.norm_eps)
+        k = _unit_heads(k, H, 1.0, dec.norm_eps)
+    v = v.reshape(b, L, H, V)
+    a, bt = jnp.split(jnp.einsum("bsd,de->bse", y, layer["delta_ab"],
+                                 preferred_element_type=f32), 2, axis=-1)
+    beta = 2.0 * jax.nn.sigmoid(bt)
+    g = -jnp.exp(layer["A_log"].astype(f32)) * jax.nn.softplus(
+        a + layer["dt_bias"])
+    if cache is not None and L == 1:
+        q1, k1, v1 = (t[:, 0].astype(f32) for t in (q, k, v))
+        state = jnp.exp(g[:, 0])[..., None, None] * cache["delta"]
+        u = beta[:, 0, :, None] * (
+            v1 - jnp.einsum("bhkv,bhk->bhv", state, k1))
+        state = state + k1[..., :, None] * u[..., None, :]
+        o = jnp.einsum("bhkv,bhk->bhv", state, q1)[:, None].astype(x.dtype)
+    else:
+        o, state = gated_delta_rule(
+            q, k, v, g, beta, dec.delta_chunk,
+            None if cache is None else cache["delta"])
+    gate = jnp.einsum("bsd,de->bse", y, layer["delta_gate"])
+    with jax.named_scope("delta_gate_norm"):
+        o = head_rms_norm_gated(o, gate, layer["delta_norm"], dec.norm_eps)
+    new_cache = None if cache is None else {"conv": tail, "delta": state}
+    return jnp.einsum("bse,ed->bsd", o, layer["delta_out"]), new_cache
 
 
 class Shared(NamedTuple):
@@ -519,12 +618,20 @@ def differential_maps(q, k, v, layer, dec: Decoder, index: int,
 # and the scan's output m (ops/selective_scan.py; 0.08 and 0.17 GB a layer
 # at 16,384 tokens of Phi-4-mini-flash-reasoning), which is a block output
 # besides where a gated memory unit reads it.
+# Of a gated-delta-rule layer the same two: the state ENTERING each chunk
+# of 64 tokens, float32 [chunks, heads, K, V], from which the backward
+# kernel makes the chunk's T, W, U and V' again (0.57 GB a layer at 16,384
+# tokens of Olmo-Hybrid-7B, 30 heads of 96 x 192), and the rule's output o
+# (the gated norm's, after it; 0.19 GB). Its projections, convolution, L2
+# norms, beta, g, gated norm and output projection are made again; no
+# [C, C] tile, inverse or V' ever reaches HBM.
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
     "moe_probs", "moe_xs", "moe_gate", "moe_up",
     "ssm_scan_y", "ssm_scan_states",
-    "selective_scan_m", "selective_scan_states")
+    "selective_scan_m", "selective_scan_states",
+    "gated_delta_o", "gated_delta_states")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
 
@@ -545,16 +652,20 @@ def _mix(x, layer, cache, start_pos, shared: Shared, dec: Decoder,
     if "lambda_q1" in layer:
         return diff_attention(x, layer, dec, cache, start_pos, shared,
                               index, window)
-    mixer = mamba2 if _is_mamba2(layer) else attention
+    mixer = (gated_delta if _is_gated_delta(layer)
+             else mamba2 if _is_mamba2(layer) else attention)
     return (*mixer(x, layer, dec, cache, start_pos), shared)
 
 
 def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
            dec: Decoder, index: int = 0, window: Optional[int] = None):
+    eps = dec.norm_eps
     y, new_cache, shared = _mix(x, layer, cache, start_pos, shared, dec,
                                 index, window)
-    x = x + _scaled(y, dec.residual_scale)
-    out, stats = dec.mlp(_norm(x, layer, "ln2", dec.norm_eps), layer)
+    x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
+                    dec.residual_scale)
+    out, stats = dec.mlp(_norm_if_held(x, layer, "ln2", eps), layer)
+    out = _norm_if_held(out, layer, "post_feedforward", eps)
     return x + _scaled(out, dec.residual_scale), stats, new_cache, shared
 
 

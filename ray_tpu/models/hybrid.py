@@ -13,8 +13,13 @@ config.json (model_type granitemoehybrid with no routed experts).
 
 Same conventions as models.gpt: dict pytrees, logical axis tables, bf16
 matmuls; float32 norms, softplus, decays and state. A layer says what it
-is by the weights it holds (`in_proj`: Mamba-2), which is all
-models.decoder looks at.
+is by the weights it holds, which is all models.decoder looks at. Of the
+mixers a decoder layer may hold there (attention, differential attention,
+Mamba-2, Mamba-1, a gated memory unit, the gated delta rule) this family
+has two: `in_proj` is a Mamba-2 layer, cache {"conv": [batch, d_conv - 1,
+inner + 2 groups x state], "ssm": [batch, heads, head_dim, state]
+float32}; `wq` + `wkv` an attention layer, cache {"k" | "v": [batch,
+n_kv_heads, max_len, head_dim]}.
 """
 
 from __future__ import annotations
@@ -157,7 +162,9 @@ def _mamba_init(key, cfg: HybridConfig, out_scale: float) -> Dict:
     }
 
 
-def _attention_init(key, cfg: HybridConfig, out_scale: float) -> Dict:
+def _attention_init(key, cfg, out_scale: float) -> Dict:
+    """`wq`, `wkv`, `wo` (models.olmo_hybrid's too: `cfg` gives d_model,
+    n_kv_heads, head_dim and dtype)."""
     kq, kkv, ko = jax.random.split(key, 3)
     d, kv_d = cfg.d_model, cfg.n_kv_heads * cfg.head_dim
     return {
@@ -167,18 +174,28 @@ def _attention_init(key, cfg: HybridConfig, out_scale: float) -> Dict:
     }
 
 
-def _layer_init(key, kind: str, cfg: HybridConfig) -> Dict:
-    k_mix, kg, ku, kd = jax.random.split(key, 4)
+def _mlp_init(keys, cfg, out_scale: float) -> Dict:
+    """The dense SwiGLU MLP's three matrices from three keys (models.
+    olmo_hybrid's too: `cfg` gives d_model, d_ff and dtype)."""
+    kg, ku, kd = keys
     d, f = cfg.d_model, cfg.d_ff
+    return {
+        "w_gate": _normal(kg, (d, f), d ** -0.5, cfg.dtype),
+        "w_up": _normal(ku, (d, f), d ** -0.5, cfg.dtype),
+        "w_down": _normal(kd, (f, d), f ** -0.5 * out_scale, cfg.dtype),
+    }
+
+
+def _layer_init(key, kind: str, cfg: HybridConfig) -> Dict:
+    k_mix, *k_mlp = jax.random.split(key, 4)
+    d = cfg.d_model
     out_scale = (2 * cfg.n_layers) ** -0.5
     mixer = _mamba_init if kind == MAMBA else _attention_init
     return {
         "ln1": jnp.ones((d,), jnp.float32),
         **mixer(k_mix, cfg, out_scale),
         "ln2": jnp.ones((d,), jnp.float32),
-        "w_gate": _normal(kg, (d, f), d ** -0.5, cfg.dtype),
-        "w_up": _normal(ku, (d, f), d ** -0.5, cfg.dtype),
-        "w_down": _normal(kd, (f, d), f ** -0.5 * out_scale, cfg.dtype),
+        **_mlp_init(k_mlp, cfg, out_scale),
     }
 
 

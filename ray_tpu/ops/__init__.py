@@ -8,9 +8,11 @@ and as the autodiff backward.
 """
 
 from .attention import flash_attention, mha_reference  # noqa: F401
+from .gated_delta import (gated_delta_plan, gated_delta_reference,  # noqa: F401
+                          gated_delta_rule)
 from .grouped_matmul import grouped_matmul  # noqa: F401
 from .layers import (causal_conv1d_silu, gated_rms_norm,  # noqa: F401
-                     layer_norm, rms_norm, rope, swiglu)
+                     head_rms_norm_gated, layer_norm, rms_norm, rope, swiglu)
 from .loss import cross_entropy  # noqa: F401
 from .selective_scan import (selective_scan, selective_scan_plan,  # noqa: F401
                              selective_scan_reference)

@@ -80,11 +80,12 @@ def _windows(x, tail, K):
 
 def _taps(windows, weight, bias, dtype):
     """bias + sum_k weight[:, k] * windows[k]: K multiply-adds in float32,
-    the bias first, rounded to `dtype`."""
+    the bias first (None: there is none, and nothing is added for it),
+    rounded to `dtype`."""
     w = weight.astype(jnp.float32)
-    y = bias.astype(jnp.float32)
+    y = None if bias is None else bias.astype(jnp.float32)
     for k, view in enumerate(windows):
-        y = y + view * w[:, k]
+        y = view * w[:, k] if y is None else y + view * w[:, k]
     return y.astype(dtype)
 
 
@@ -115,7 +116,7 @@ def _conv_silu_bwd(residuals, dy):
     # that makes g, over the same shifted rows the forward read.
     dw = jnp.stack([jnp.sum(g * view, axis=(0, 1)) for view in windows],
                    axis=1)
-    db = jnp.sum(g, axis=(0, 1))
+    db = None if bias is None else jnp.sum(g, axis=(0, 1)).astype(bias.dtype)
     # d(tail | x)[j] = sum_k weight[:, k] * g[j - k]: the same K shifted
     # multiply-adds run the other way, over g (rounded once, as x is)
     # between K - 1 rows of zeros on either side.
@@ -127,8 +128,8 @@ def _conv_silu_bwd(residuals, dy):
         return sum(gz[:, first + K - 1 - k:first + K - 1 - k + n].astype(f32)
                    * w[:, k] for k in range(K))
 
-    return (rows(K - 1, L).astype(x.dtype), dw.astype(weight.dtype),
-            db.astype(bias.dtype), rows(0, K - 1).astype(tail.dtype))
+    return (rows(K - 1, L).astype(x.dtype), dw.astype(weight.dtype), db,
+            rows(0, K - 1).astype(tail.dtype))
 
 
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
@@ -137,7 +138,8 @@ _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 def causal_conv1d_silu(x, weight, bias, tail=None):
     """silu of a depthwise causal convolution along the sequence: x
     [b, L, C], weight [C, K] (K taps, the last one on the current
-    position), bias [C]: y_t = silu(bias + sum_k weight[:, k] x_{t-K+1+k}),
+    position), bias [C] or None for a convolution that has none: y_t =
+    silu(bias + sum_k weight[:, k] x_{t-K+1+k}),
     with `tail` [b, K-1, C] the inputs before x (zeros where None). K
     shifted multiply-adds in float32, no kernel. Returns (y like x, the
     last K-1 inputs: the tail a cache hands to the next call).
@@ -165,6 +167,45 @@ def causal_conv1d_silu(x, weight, bias, tail=None):
     # nothing at all in a train step, which hands no tail on.
     return (_conv_silu(x, weight, bias, tail),
             jnp.concatenate([tail, x], axis=1)[:, x.shape[1]:])
+
+
+def _head_indicator(width: int, heads: int):
+    """[width, heads] float32, 1 where a column is one of a head's."""
+    return (jnp.arange(width)[:, None] // (width // heads)
+            == jnp.arange(heads)[None, :]).astype(jnp.float32)
+
+
+def head_sums(x, heads: int):
+    """x [..., heads * W] float32 -> [..., heads]: the sum over each
+    head's W columns, as a product with a 0/1 matrix. The plain form, a
+    reshape to [..., heads, W] and a sum, makes XLA:TPU lay the array out
+    again wherever W is no multiple of 128 lanes (96 and 192 are not: the
+    two norms of a delta-rule layer took 16 and 30 ms a layer and step at
+    16,384 tokens that way, PERF.md section 6, PR 41); this one stays in
+    the projections' own layout."""
+    return jnp.einsum("...e,eh->...h", x, _head_indicator(x.shape[-1], heads),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def head_spread(s, width: int):
+    """s [..., heads] float32 -> [..., width]: each head's value on all of
+    its columns, `head_sums` the other way round (exact: one term a
+    column)."""
+    return jnp.einsum("...h,eh->...e", s, _head_indicator(width, s.shape[-1]),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def head_rms_norm_gated(o, gate, weight, eps: float = NORM_EPS):
+    """Gated DeltaNet's output norm, the other order from `gated_rms_norm`
+    below: an RMSNorm over each head's own columns FIRST (o [..., H, V],
+    one weight [V] for all heads), THEN the gate, rmsnorm_h(o; weight) *
+    silu(gate), gate [..., H * V]. -> [..., H * V] in o's dtype."""
+    H, V = o.shape[-2:]
+    of = o.reshape(gate.shape).astype(jnp.float32)
+    inv = jax.lax.rsqrt(head_sums(jnp.square(of), H) / V + eps)
+    normed = of * head_spread(inv, H * V) * jnp.tile(
+        weight.astype(jnp.float32), H)
+    return (normed * jax.nn.silu(gate.astype(jnp.float32))).astype(o.dtype)
 
 
 def gated_rms_norm(y, gate, weight, eps: float = NORM_EPS):

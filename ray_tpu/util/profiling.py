@@ -144,8 +144,23 @@ DEVICE_SCOPES: Dict[str, str] = {
                           "_selective_bwd_kernel pallas_call: a chunk's "
                           "states again, then every gradient of the scan, "
                           "chunks last to first",
-    "ssm_conv": "models/decoder.py mamba2 and mamba1: the causal depthwise "
-                "convolution (over x | B | C; Mamba-1: over x) and its silu",
+    "gated_delta_fwd": "ops/gated_delta.py _forward_call, the "
+                       "_gd_fwd_kernel pallas_call: a linear-attention "
+                       "layer's chunked gated delta rule, o and the state "
+                       "entering each chunk",
+    "gated_delta_bwd": "ops/gated_delta.py _backward_call, the "
+                       "_gd_bwd_kernel pallas_call: a chunk's inverse, W, U "
+                       "and V' again, then every gradient of the rule, "
+                       "chunks last to first",
+    "ssm_conv": "models/decoder.py mamba2, mamba1 and gated_delta: the "
+                "causal depthwise convolution (over x | B | C; Mamba-1: "
+                "over x; the delta rule: over q | k | v, no bias) and its "
+                "silu",
+    "delta_qk_norm": "models/decoder.py gated_delta: q and k divided by "
+                     "their L2 norm a head, q scaled by 1 / sqrt(key "
+                     "width)",
+    "delta_gate_norm": "models/decoder.py gated_delta: the RMSNorm a head "
+                       "of the rule's output, then silu(gate) times it",
     "gmu": "models/decoder.py gmu: a gated memory unit's gate projection, "
            "silu, the product with the handed-on scan output and the "
            "output projection",
@@ -196,7 +211,9 @@ def kernel_calls(compiled_text: str) -> Dict[str, int]:
     models/decoder.py KEPT_UNDER_REMAT names (PERF.md §6, PR 28). The
     scan kernels count the same way: `calls["ssm_scan_fwd"] -
     calls["ssm_scan_bwd"]`, 0 in granite-4.0-h-micro's step of nine
-    Mamba-2 layers (9 and 9, not 18 and 9)."""
+    Mamba-2 layers (9 and 9, not 18 and 9), and `calls["gated_delta_fwd"]
+    - calls["gated_delta_bwd"]`, 0 in Olmo-Hybrid-7B's period of three
+    linear-attention layers."""
     calls: Dict[str, int] = {}
     for line in compiled_text.splitlines():
         name, eq, rest = line.strip().partition(" = ")

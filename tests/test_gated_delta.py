@@ -1,0 +1,210 @@
+"""ops.gated_delta against the gated delta rule as it is defined, token by
+token (chipbench/families/olmo_hybrid.py `recurrence`, nothing shared with
+ray_tpu): the jax.numpy chunked form and the Pallas kernels in interpreter
+mode (RAY_TPU_PALLAS_INTERPRET=1), forward, final state and every
+gradient, at small shapes on the CPU in float32 at highest matmul
+precision. 1e-4 of the largest value: float32 rounding through a few
+hundred dependent steps, and the inverse made by doubling blocks, stay
+under 1e-5 here; an all-bfloat16 state is off by 5e-3."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.families import olmo_hybrid as reference
+from ray_tpu.ops import (gated_delta_plan, gated_delta_reference,
+                         gated_delta_rule)
+from ray_tpu.ops import gated_delta as gd
+
+TOL = 1e-4
+NAMES = ("o", "state", "dq", "dk", "dv", "dg", "dbeta", "dinit")
+
+
+@pytest.fixture(autouse=True)
+def _highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(params=["jax", "interpreted"])
+def form(request, monkeypatch):
+    """Both forms of the rule: the jax.numpy chunked one (what the CPU
+    runs) and the Pallas kernels in interpreter mode."""
+    if request.param == "interpreted":
+        monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    return request.param
+
+
+def _close(got, want, tol=TOL):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    scale = max(1.0, float(np.max(np.abs(want))))
+    assert float(np.max(np.abs(got - want))) <= tol * scale
+
+
+def _inputs(L, decay, beta, with_state, b=2, H=3, K=12, V=20, seed=0):
+    """K = 12 and V = 20: no multiple of any tile. `decay` scales g (1e-3:
+    hardly any; 30: exp(g) underflows), `beta` is "mid" (0, 2), "low"
+    near 0 or "high" near 2."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + L), 8)
+
+    def unit(t):
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(ks[0], (b, L, H, K))) * K ** -0.5
+    # "-lean": every key leans one way (k_i . k_j about 0.8), as silu
+    # leaves them and more: every entry of A near beta
+    lean = 2.0 if beta.endswith("-lean") else 0.0
+    beta = beta.removesuffix("-lean")
+    k = unit(jax.random.normal(ks[1], (b, L, H, K)) + lean)
+    v = jax.random.normal(ks[2], (b, L, H, V))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, L, H)))
+    s = jax.nn.sigmoid(jax.random.normal(ks[4], (b, L, H)))
+    bt = {"mid": 2 * s, "low": 0.01 * s, "high": 2 - 0.01 * s}[beta]
+    init = jax.random.normal(ks[5], (b, H, K, V)) if with_state else None
+    weights = (jax.random.normal(ks[6], (b, L, H, V)),
+               jax.random.normal(ks[7], (b, H, K, V)))
+    return (q, k, v, g, bt, init), weights
+
+
+def _all_of(fn, args, weights):
+    """o, the final state and the gradient of a weighted sum of both by
+    every input (the initial state's too where there is one)."""
+    n = 6 if args[5] is not None else 5
+
+    def scalar(*given):
+        o, state = fn(*given, *args[n:])
+        return (jnp.sum(o * weights[0]) + jnp.sum(state * weights[1]),
+                (o, state))
+
+    (_, (o, state)), grads = jax.value_and_grad(
+        scalar, argnums=tuple(range(n)), has_aux=True)(*args[:n])
+    return (o, state, *grads)
+
+
+CASES = [
+    (8, 8, 1.0, "mid", False),          # one chunk
+    (32, 8, 1.0, "mid", False),         # several: the state crosses over
+    (32, 8, 1.0, "mid", True),          # from an initial state
+    (64, 16, 1.0, "mid", True),         # chunks of 16: four levels of powers
+    (64, 16, 1e-3, "high", True),       # g near 0 and beta near 2
+    (64, 16, 1e-3, "high-lean", True),  # the same, keys all leaning one way
+    (32, 8, 30.0, "low", True),         # exp(g) underflows, beta near 0
+    (48, 16, 30.0, "high", False),      # strong decay, beta near 2
+]
+IDS = ["one-chunk", "chunks", "initial-state", "chunk-16", "no-decay-beta-2",
+       "no-decay-beta-2-lean-keys",
+       "strong-decay-beta-0", "strong-decay-beta-2"]
+
+
+@pytest.mark.parametrize("L,chunk,decay,beta,with_state", CASES, ids=IDS)
+def test_rule_and_every_gradient_match_the_recurrence(form, L, chunk, decay,
+                                                      beta, with_state):
+    args, weights = _inputs(L, decay, beta, with_state)
+    want = _all_of(reference.recurrence, args, weights)
+    got = _all_of(lambda *a: gated_delta_rule(*a[:5], chunk, *a[5:]), args,
+                  weights)
+    for name, g, w in zip(NAMES, got, want, strict=False):
+        _close(g, w), name
+
+
+def test_reference_pads_a_length_that_is_no_whole_number_of_chunks():
+    args, weights = _inputs(27, 1.0, "mid", True)
+    want = _all_of(reference.recurrence, args, weights)
+    got = _all_of(lambda *a: gated_delta_reference(*a[:5], 8, *a[5:]), args,
+                  weights)
+    for g, w in zip(got, want, strict=True):
+        _close(g, w)
+    # and the public rule takes the same path for such a length
+    o, state = gated_delta_rule(*args[:5], 8, args[5])
+    _close(o, want[0])
+    _close(state, want[1])
+
+
+def test_an_all_bfloat16_state_fails_the_tolerance():
+    """The tolerance is tight enough to tell: the same recurrence with
+    every value and the state in bfloat16 is off by more than 1e-3, ten
+    times the tolerance."""
+    args, _ = _inputs(64, 1.0, "mid", True)
+    want, _ = reference.recurrence(*args)
+    low, _ = reference.recurrence(*(t.astype(jnp.bfloat16) for t in args))
+    err = float(jnp.max(jnp.abs(low.astype(jnp.float32) - want)))
+    assert err > 10 * TOL * max(1.0, float(jnp.max(jnp.abs(want))))
+
+
+def test_bfloat16_inputs_come_back_bfloat16_with_a_float32_state(form):
+    args, _ = _inputs(32, 1.0, "mid", True)
+    q, k, v = (t.astype(jnp.bfloat16) for t in args[:3])
+    o, state = gated_delta_rule(q, k, v, *args[3:5], 8, args[5])
+    assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
+    want, _ = reference.recurrence(
+        *(t.astype(jnp.float32) for t in (q, k, v)), *args[3:])
+    _close(o, want, tol=3e-2)
+
+
+@pytest.mark.parametrize("chunk,levels,lean", [
+    (8, 2, 0.3), (16, 3, 0.3), (64, 5, 0.3), (64, 5, 1.0), (64, 5, 2.0),
+    (24, 4, 1.0)])
+def test_inverse_by_doubling_blocks_holds_float32(chunk, levels, lean):
+    """(I + A)^-1 for a strictly lower A against numpy's float64 inverse.
+    `lean` 1 and 2: every entry of A near beta (k_i . k_j) for keys that
+    all point one way, at beta = 1 and 2, where the powers of A reach 1e17
+    and a product of powers has no digit left; 24: no power of two."""
+    assert gd._inverse_levels(chunk) == levels
+    noise = jax.random.uniform(jax.random.PRNGKey(chunk), (chunk, chunk),
+                               minval=-0.3, maxval=0.3)
+    A = jnp.tril(noise if lean == 0.3 else lean * (1.0 + 0.1 * noise), -1)
+    got = gd._unit_lower_inverse(A, chunk) + jnp.eye(chunk)
+    want = np.linalg.inv(np.eye(chunk) + np.asarray(A, np.float64))
+    np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("seq,heads,K,V,chunk", [
+    (16384, 30, 96, 192, 64), (256, 3, 12, 20, 8), (2048, 4, 128, 128, 128)])
+def test_plan_counts_against_the_loops(seq, heads, K, V, chunk):
+    plan = gated_delta_plan(seq, heads, K, V, chunk)
+    chunks = seq // chunk
+    assert (plan.chunks, plan.grid, plan.heads_per_block) == (
+        chunks, (chunks,), heads)
+    assert plan.key_tile == -(-K // 128) * 128 >= K
+    assert plan.value_tile == -(-V // 128) * 128 >= V
+    assert plan.state_bytes == chunks * heads * K * V * 4
+    # the loops: a squaring and a product a level, and what
+    # _head_forward / _head_backward run besides
+    levels = 0
+    while 2 ** (levels + 1) < chunk:        # block sizes 2, 4, ... < chunk
+        levels += 1
+    assert plan.inverse_matmuls == chunks * heads * 2 * levels
+    assert plan.fwd_matmuls == plan.inverse_matmuls + chunks * heads * 8
+    assert plan.bwd_matmuls == plan.fwd_matmuls + chunks * heads * 16
+    assert plan.fwd_exps == plan.bwd_exps == chunks * heads
+    assert plan.vmem_bytes <= gd.VMEM_LIMIT
+
+
+def test_plan_refuses_what_the_kernels_cannot_run():
+    with pytest.raises(ValueError, match="whole chunks"):
+        gated_delta_plan(100, 4, 16, 16, 64)
+    with pytest.raises(ValueError, match="do not fit"):
+        gated_delta_plan(4096, 256, 128, 256, 64)
+
+
+def test_kernels_count_the_products_the_plan_says(monkeypatch):
+    """The dot_generals in one head's forward and backward, traced: the
+    plan's 8 + 2 levels and 16 more."""
+    chunk, K, V = 16, 12, 20
+    f32 = jnp.float32
+
+    def dots(fn, *shapes):
+        jaxpr = jax.make_jaxpr(fn)(*(jnp.zeros(s, f32) for s in shapes))
+        return sum(e.primitive.name == "dot_general" for e in jaxpr.eqns)
+
+    head = ((chunk, K), (chunk, K), (chunk, V), (chunk, 1), (1, chunk),
+            (chunk, 1), (K, V))
+    levels = gd._inverse_levels(chunk)
+    assert dots(lambda *a: gd._head_forward(*a, chunk)[:2], *head) == (
+        gd._FWD_PRODUCTS + 2 * levels)
+    assert dots(lambda *a: gd._head_backward(*a, chunk), *head, (chunk, V),
+                (K, V)) == gd._FWD_PRODUCTS + gd._BWD_PRODUCTS + 2 * levels

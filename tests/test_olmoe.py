@@ -490,9 +490,9 @@ def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
                   if "from the argument" not in line
                   and "from a constant" not in line)
     heads = f"{short}[{b},{cfg.n_heads},{s},{cfg.head_dim}]"
-    # the ten this block makes, and a Mamba-2 and a Mamba-1 layer's two
-    # each (it has neither)
-    assert len(decoder.KEPT_UNDER_REMAT) == 14
+    # the ten this block makes, and a Mamba-2, a Mamba-1 and a
+    # gated-delta-rule layer's two each (it has none of them)
+    assert len(decoder.KEPT_UNDER_REMAT) == 16
     assert kept == sorted([
         f"{short}[{b},{s},{3 * d}]",                    # attention_qkv
         heads, heads, heads, heads,     # flash_attention_q, _k, _v, _out
