@@ -618,20 +618,22 @@ def differential_maps(q, k, v, layer, dec: Decoder, index: int,
 # and the scan's output m (ops/selective_scan.py; 0.08 and 0.17 GB a layer
 # at 16,384 tokens of Phi-4-mini-flash-reasoning), which is a block output
 # besides where a gated memory unit reads it.
-# Of a gated-delta-rule layer the same two: the state ENTERING each chunk
-# of 64 tokens, float32 [chunks, heads, K, V], from which the backward
-# kernel makes the chunk's T, W, U and V' again (0.57 GB a layer at 16,384
-# tokens of Olmo-Hybrid-7B, 30 heads of 96 x 192), and the rule's output o
-# (the gated norm's, after it; 0.19 GB). Its projections, convolution, L2
-# norms, beta, g, gated norm and output projection are made again; no
-# [C, C] tile, inverse or V' ever reaches HBM.
+# Of a gated-delta-rule layer three: the state ENTERING each chunk of 64
+# tokens, float32 [chunks, heads, K, V], from which the backward kernel
+# makes the chunk's W, U and V' again (0.57 GB a layer at 16,384 tokens of
+# Olmo-Hybrid-7B, 30 heads of 96 x 192); each head's and chunk's T - I as
+# it enters them, in the model's dtype (0.06 GB: ten float32 products a
+# head and chunk the backward kernel does not run a second time); and the
+# rule's output o (the gated norm's, after it; 0.19 GB). Its projections,
+# convolution, L2 norms, beta, g, gated norm and output projection are
+# made again; no other [C, C] tile and no V' ever reaches HBM.
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
     "moe_probs", "moe_xs", "moe_gate", "moe_up",
     "ssm_scan_y", "ssm_scan_states",
     "selective_scan_m", "selective_scan_states",
-    "gated_delta_o", "gated_delta_states")
+    "gated_delta_o", "gated_delta_states", "gated_delta_T")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
 

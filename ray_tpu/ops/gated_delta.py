@@ -40,13 +40,22 @@ of one chunk, one after another, slicing each head's columns out of the
 projections' own layout [batch, seq, heads * width]: no transposed copy,
 and no head count or width has to fall on the chip's 128-lane tiles (30
 heads of 96 and 192 do not: a head's columns are cut out of one or two
-tiles where it is read, nothing is padded in HBM). The [C, C] tiles, T, W,
-U and V' live in VMEM only, in both passes: the backward makes them again
-from the state entering the chunk, which the forward leaves in HBM one a
-chunk, [chunks, heads, K, V] float32 (never one a token). The gradient by
-the decays comes from the same float32 tiles by row and by column, so the
-running sums' reverse cumulative sum adds rectangle sums of one tile and
-subtracts nothing it did not add (ops/ssm_scan.py tells why that matters).
+tiles where it is read, nothing is padded in HBM). T is made once a head
+and chunk, by the forward kernel: a forward that will be differentiated
+(`_rule_fwd`) leaves T - I in HBM as it enters W and U, rounded to the
+operands' dtype, [chunks, chunk, heads * chunk] (the heads side by side
+along the lanes, two to a 128-lane tile: 63 MB a layer at Olmo-Hybrid-7B's
+shape), beside the state entering each chunk, [chunks, heads, K, V]
+float32 (never one a token; 566 MB). The backward kernel reads both and
+makes again only what is cheap, in bfloat16: D, k k^T, W, U, V', q k^T and
+the two tiles cut from them (five products a head and chunk; the inverse's
+ten float32 ones run in the forward alone). The other [C, C] tiles, W, U
+and V' live in VMEM only, in both passes. The call that is not
+differentiated (`_rule`: prefill, `cached_forward`) writes no T. The
+gradient by the decays comes from the same float32 tiles by row and by
+column, so the running sums' reverse cumulative sum adds rectangle sums of
+one tile and subtracts nothing it did not add (ops/ssm_scan.py tells why
+that matters).
 
 `gated_delta_plan` gives the sizes from the shape and counts what runs.
 The jax form `gated_delta_reference` serves other backends, lengths that
@@ -152,9 +161,11 @@ class GatedDeltaPlan:
     (the widths rounded up to whole 128-lane tiles: 96 -> 128, 192 -> 256;
     HBM holds the widths as they are). `fwd_matmuls` and `bwd_matmuls`
     count the products a pass runs on the matrix unit, `inverse_matmuls`
-    those of them that make T in float32; `fwd_exps` and `bwd_exps` the
-    exponentials' [chunk, chunk] tiles (the [chunk, 1] columns beside them
-    are not counted)."""
+    those of the forward's that make T in float32 (the backward makes
+    none: it reads the T - I a differentiated forward leaves in HBM,
+    `kept_bytes` a call at two bytes a value); `fwd_exps` and `bwd_exps`
+    the exponentials' [chunk, chunk] tiles (the [chunk, 1] columns beside
+    them are not counted)."""
     seq_len: int
     chunk: int
     chunks: int
@@ -164,6 +175,7 @@ class GatedDeltaPlan:
     value_tile: int
     vmem_bytes: int                # the backward kernel's, the larger
     state_bytes: int               # the chunk states in HBM, one way
+    kept_bytes: int                # T - I a head and chunk, bfloat16
     inverse_matmuls: int
     fwd_matmuls: int
     bwd_matmuls: int
@@ -178,8 +190,11 @@ def _inverse_levels(chunk: int) -> int:
 
 
 # What one head of one chunk runs besides T, by `_head_forward` and
-# `_head_backward` below.
+# `_head_backward` below: the forward's eight, the five of them the
+# backward makes again (`_head_tiles`, `_head_chunk`: all but O's two and
+# S1's), and its own sixteen.
 _FWD_PRODUCTS = 8
+_AGAIN_PRODUCTS = 5
 _BWD_PRODUCTS = 16
 
 
@@ -189,11 +204,12 @@ def _round_up(n: int, to: int) -> int:
 
 def _vmem_bytes(heads: int, key_dim: int, value_dim: int, chunk: int) -> int:
     """What the backward kernel holds: double-buffered blocks (q, k, dq,
-    dk and v, dO, dv in bf16; three state blocks), the eight [C, heads]
-    columns and rows, and a head's float32 temporaries."""
+    dk and v, dO, dv and the heads' T - I in bf16; three state blocks),
+    the eight [C, heads] columns and rows, and a head's float32
+    temporaries."""
     kt, vt = _round_up(key_dim, 128), _round_up(value_dim, 128)
     state = heads * _round_up(key_dim, 8) * vt * 4
-    acts = chunk * heads * (4 * key_dim + 3 * value_dim) * 2
+    acts = chunk * heads * (4 * key_dim + 3 * value_dim + chunk) * 2
     return (2 * acts + 2 * 3 * state + 8 * 2 * chunk * 128 * 4
             + 12 * chunk * (kt + vt) * 4 + 16 * chunk * chunk * 4)
 
@@ -218,10 +234,10 @@ def gated_delta_plan(seq_len: int, heads: int, key_dim: int, value_dim: int,
         grid=(chunks,), key_tile=_round_up(key_dim, 128),
         value_tile=_round_up(value_dim, 128), vmem_bytes=need,
         state_bytes=chunks * heads * key_dim * value_dim * 4,
+        kept_bytes=chunks * heads * chunk * chunk * 2,
         inverse_matmuls=chunks * heads * inverse,
         fwd_matmuls=chunks * heads * (inverse + _FWD_PRODUCTS),
-        bwd_matmuls=chunks * heads * (inverse + _FWD_PRODUCTS
-                                      + _BWD_PRODUCTS),
+        bwd_matmuls=chunks * heads * (_AGAIN_PRODUCTS + _BWD_PRODUCTS),
         fwd_exps=chunks * heads, bwd_exps=chunks * heads)
 
 
@@ -280,18 +296,24 @@ def _times_last(x, col):
     return x * jnp.sum(wide, axis=0, keepdims=True)
 
 
-def _head_forward(q, k, v, gc, gr, bc, S0, chunk: int):
-    """One head of one chunk. q, k [C, K], v [C, V] in the model's dtype;
-    gc [C, 1], gr [1, C] the running sums of g by row and by column, bc
-    [C, 1] beta, float32; S0 [K, V] float32 the state entering. Returns
-    (O [C, V] float32, S1, and what the backward reads again)."""
-    dtype, f32 = q.dtype, jnp.float32
+def _head_tiles(k, gc, gr, chunk: int):
+    """The [C, C] float32 tiles of one head of one chunk that both passes
+    make: (rows, cols, D_ij = exp(G_i - G_j) on and under the diagonal,
+    KK = k k^T)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     D = jnp.exp(jnp.where(rows >= cols, gc - gr, DEFAULT_MASK_VALUE))
-    KK = _dot(k, k, _NT)
-    Tm = _unit_lower_inverse(jnp.where(rows > cols, bc * KK * D, 0.0),
-                             chunk).astype(dtype)
+    return rows, cols, D, _dot(k, k, _NT)
+
+
+def _head_chunk(q, k, v, gc, bc, S0, D, Tm):
+    """One head of one chunk up to V', from the state entering and T: what
+    the forward makes once and the backward again (with `_head_tiles`'
+    product, five bfloat16 products). q, k [C, K], v [C, V] and Tm = T - I
+    [C, C] in the model's dtype; gc, bc [C, 1] the running sums of g and
+    beta, D [C, C] float32; S0 [K, V] float32."""
+    dtype, f32 = q.dtype, jnp.float32
+    chunk = q.shape[0]
     k32, v32 = k.astype(f32), v.astype(f32)
     eg = jnp.exp(gc)
     end = gc[chunk - 1:chunk, :]                            # [1, 1]
@@ -302,19 +324,32 @@ def _head_forward(q, k, v, gc, gr, bc, S0, chunk: int):
     S0b = S0.astype(dtype)
     Vp = U - _dot(W.astype(dtype), S0b, _NN)
     QK = _dot(q, k, _NT)
-    P = (QK * D).astype(dtype)
-    Vpb = Vp.astype(dtype)
-    O = eg * _dot(q, S0b, _NN) + _dot(P, Vpb, _NN)
-    Kd = (k32 * to_end).astype(dtype)
-    S1 = _times_last(S0, eg) + _dot(Kd, Vpb, _TN)
-    return O, S1, dict(D=D, KK=KK, Tm=Tm, k32=k32, v32=v32, eg=eg,
-                       to_end=to_end, exp_end=exp_end, Kbg=Kbg, W=W, U=U,
-                       S0b=S0b, QK=QK, P=P, Vpb=Vpb, Kd=Kd, rows=rows,
-                       cols=cols)
+    return dict(k32=k32, v32=v32, eg=eg, to_end=to_end, exp_end=exp_end,
+                Kbg=Kbg, W=W, U=U, S0b=S0b, QK=QK, P=(QK * D).astype(dtype),
+                Vpb=Vp.astype(dtype), Kd=(k32 * to_end).astype(dtype))
+
+
+def _head_forward(q, k, v, gc, gr, bc, S0, chunk: int):
+    """One head of one chunk. q, k [C, K], v [C, V] in the model's dtype;
+    gc [C, 1], gr [1, C] the running sums of g by row and by column, bc
+    [C, 1] beta, float32; S0 [K, V] float32 the state entering. Returns
+    (O [C, V] float32, S1, and Tm = T - I in the model's dtype, the one
+    place T is made: the backward reads it)."""
+    rows, cols, D, KK = _head_tiles(k, gc, gr, chunk)
+    Tm = _unit_lower_inverse(jnp.where(rows > cols, bc * KK * D, 0.0),
+                             chunk).astype(q.dtype)
+    t = _head_chunk(q, k, v, gc, bc, S0, D, Tm)
+    O = t["eg"] * _dot(q, t["S0b"], _NN) + _dot(t["P"], t["Vpb"], _NN)
+    S1 = _times_last(S0, t["eg"]) + _dot(t["Kd"], t["Vpb"], _TN)
+    return O, S1, Tm
 
 
 def _gd_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, init_ref,
-                   o_ref, states_ref, final_ref, *, H: int, K: int, V: int):
+                   o_ref, states_ref, final_ref, T_ref=None, *, H: int,
+                   K: int, V: int):
+    """`T_ref`: every head's T - I of the chunk, [C, H * C], where a
+    backward pass will read it (`_rule_fwd`); the call that is not
+    differentiated has no such output."""
     from jax.experimental import pallas as pl
 
     chunk = q_ref.shape[1]
@@ -328,22 +363,24 @@ def _gd_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, init_ref,
         ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
         S0 = final_ref[0, h]
         states_ref[0, 0, h] = S0
-        O, S1, _ = _head_forward(
+        O, S1, Tm = _head_forward(
             q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
             gc_all[:, h:h + 1], gr_all[h:h + 1, :], bc_all[:, h:h + 1], S0,
             chunk)
         o_ref[0, :, vs] = O.astype(o_ref.dtype)
         final_ref[0, h] = S1
+        if T_ref is not None:
+            T_ref[0, 0, :, h * chunk:(h + 1) * chunk] = Tm
 
 
-def _head_backward(q, k, v, gc, gr, bc, S0, dO, dS1, chunk: int):
+def _head_backward(q, k, v, gc, gr, bc, S0, Tm, dO, dS1, chunk: int):
     """Every gradient of one head's work in one chunk: (dq, dk [C, K], dv
     [C, V], dbeta [C, 1], the running sums' gradient by row [C, 1] and by
-    column [1, C], dS0 [K, V]), float32. The forward is made again from
-    S0 first."""
+    column [1, C], dS0 [K, V]), float32. W, U and V' are made again from
+    S0 and the Tm = T - I the forward kept; no inverse is."""
     dtype, f32 = q.dtype, jnp.float32
-    _, _, t = _head_forward(q, k, v, gc, gr, bc, S0, chunk)
-    rows, cols, D, KK, Tm = t["rows"], t["cols"], t["D"], t["KK"], t["Tm"]
+    rows, cols, D, KK = _head_tiles(k, gc, gr, chunk)
+    t = _head_chunk(q, k, v, gc, bc, S0, D, Tm)
     eg, to_end, exp_end = t["eg"], t["to_end"], t["exp_end"]
     S0b, Vpb, k32 = t["S0b"], t["Vpb"], t["k32"]
     last_row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
@@ -392,8 +429,9 @@ def _head_backward(q, k, v, gc, gr, bc, S0, dO, dS1, chunk: int):
 
 
 def _gd_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, states_ref,
-                   do_ref, dfinal_ref, dq_ref, dk_ref, dv_ref, dbeta_ref,
-                   dgc_ref, dgr_ref, dinit_ref, *, H: int, K: int, V: int):
+                   T_ref, do_ref, dfinal_ref, dq_ref, dk_ref, dv_ref,
+                   dbeta_ref, dgc_ref, dgr_ref, dinit_ref, *, H: int, K: int,
+                   V: int):
     from jax.experimental import pallas as pl
 
     chunk = q_ref.shape[1]
@@ -413,7 +451,8 @@ def _gd_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, states_ref,
         dq, dk, dv, dbeta, dg_col, dg_row, dS0 = _head_backward(
             q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
             gc_all[:, h:h + 1], gr_all[h:h + 1, :], bc_all[:, h:h + 1],
-            states_ref[0, 0, h], do_ref[0, :, vs], dinit_ref[0, h], chunk)
+            states_ref[0, 0, h], T_ref[0, 0, :, h * chunk:(h + 1) * chunk],
+            do_ref[0, :, vs], dinit_ref[0, h], chunk)
         dq_ref[0, :, ks] = dq.astype(dq_ref.dtype)
         dk_ref[0, :, ks] = dk.astype(dk_ref.dtype)
         dv_ref[0, :, vs] = dv.astype(dv_ref.dtype)
@@ -453,30 +492,42 @@ def _specs(H: int, K: int, V: int, chunk: int, chunk_of):
                          lambda i, s: (i, chunk_of(s), 0, 0)),
         state=pl.BlockSpec((1, H, K, V), lambda i, s: (i, 0, 0, 0)),
         states=pl.BlockSpec((1, 1, H, K, V),
-                            lambda i, s: (i, chunk_of(s), 0, 0, 0)))
+                            lambda i, s: (i, chunk_of(s), 0, 0, 0)),
+        # a chunk's T - I, the heads side by side along the lanes: H * 64
+        # columns fill whole 128-lane tiles two heads each, where a last
+        # axis of 64 would be padded to twice its bytes in HBM
+        inverse=pl.BlockSpec((1, 1, chunk, H * chunk),
+                             lambda i, s: (i, chunk_of(s), 0, 0)))
 
 
 # Jitted for the reason ops/attention.py's calls are: a model's layers
 # trace and lower each kernel once a step, not once a layer.
-@functools.partial(jax.jit, static_argnames=("chunk", "H"))
-def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int):
+@functools.partial(jax.jit, static_argnames=("chunk", "H", "keep_inverse"))
+def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int,
+                  keep_inverse: bool):
     """q, k [b, L, H*K]; v [b, L, H*V]; cum, beta [b, L, H] f32; init
     [b, H, K, V] f32 -> (o like v, states [b, chunks, H, K, V] f32: the
-    state ENTERING each chunk, the final state)."""
+    state ENTERING each chunk, the final state, and with `keep_inverse`
+    every head's T - I [b, chunks, chunk, H * chunk] in q's dtype)."""
     from jax.experimental import pallas as pl
 
     b, L, HK = q.shape
     K, V, nc = HK // H, v.shape[-1] // H, L // chunk
     s = _specs(H, K, V, chunk, lambda step: step)
+    out_specs = [s["value"], s["states"], s["state"]]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype),
+                 jax.ShapeDtypeStruct((b, nc, H, K, V), jnp.float32),
+                 jax.ShapeDtypeStruct((b, H, K, V), jnp.float32)]
+    if keep_inverse:
+        out_specs.append(s["inverse"])
+        out_shape.append(
+            jax.ShapeDtypeStruct((b, nc, chunk, H * chunk), q.dtype))
     call = pl.pallas_call(
         functools.partial(_gd_fwd_kernel, H=H, K=K, V=V),
         grid=(b, nc),
         in_specs=[s["key"], s["key"], s["value"], s["col"], s["row"],
                   s["col"], s["state"]],
-        out_specs=[s["value"], s["states"], s["state"]],
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct((b, nc, H, K, V), jnp.float32),
-                   jax.ShapeDtypeStruct((b, H, K, V), jnp.float32)],
+        out_specs=out_specs, out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=attention._interpret(),
     )
@@ -485,9 +536,10 @@ def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "H"))
-def _backward_call(q, k, v, cum, beta, states, do, dfinal, *, chunk: int,
-                   H: int):
-    """-> (dq, dk, dv, dbeta, d cum [b, L, H] f32, d init)."""
+def _backward_call(q, k, v, cum, beta, states, inverse, do, dfinal, *,
+                   chunk: int, H: int):
+    """`states` and `inverse` as `_forward_call` left them -> (dq, dk, dv,
+    dbeta, d cum [b, L, H] f32, d init)."""
     from jax.experimental import pallas as pl
 
     b, L, HK = q.shape
@@ -498,7 +550,8 @@ def _backward_call(q, k, v, cum, beta, states, do, dfinal, *, chunk: int,
         functools.partial(_gd_bwd_kernel, H=H, K=K, V=V),
         grid=(b, nc),
         in_specs=[s["key"], s["key"], s["value"], s["col"], s["row"],
-                  s["col"], s["states"], s["value"], s["state"]],
+                  s["col"], s["states"], s["inverse"], s["value"],
+                  s["state"]],
         out_specs=[s["key"], s["key"], s["value"], s["col"], s["col"],
                    s["row"], s["state"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -511,7 +564,8 @@ def _backward_call(q, k, v, cum, beta, states, do, dfinal, *, chunk: int,
     )
     with jax.named_scope("gated_delta_bwd"):
         dq, dk, dv, dbeta, dgc, dgr, dinit = call(
-            q, k, v, cum, _by_row(cum, chunk), beta, states, do, dfinal)
+            q, k, v, cum, _by_row(cum, chunk), beta, states, inverse, do,
+            dfinal)
     dcum = dgc + dgr.transpose(0, 1, 3, 2).reshape(b, L, H)
     return dq, dk, dv, dbeta, dcum, dinit
 
@@ -538,31 +592,46 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, initial_state=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _rule(q, k, v, g, beta, init, chunk):
-    return _rule_fwd(q, k, v, g, beta, init, chunk)[0]
+    """The call that is not differentiated (prefill, `cached_forward`): no
+    backward pass will read T, so the kernel writes none."""
+    if not _kernel_ok(q, chunk):
+        return gated_delta_reference(q, k, v, g, beta, chunk, init)
+    o, _, final = _run_forward(q, k, v, g, beta, init, chunk,
+                               keep_inverse=False)
+    return o.reshape(v.shape), final
+
+
+def _run_forward(q, k, v, g, beta, init, chunk, keep_inverse: bool):
+    """`_forward_call` on the projections' own layout: (o [b, L, H*V],
+    states, final, and with `keep_inverse` T - I)."""
+    b, L, H, K = q.shape
+    V = v.shape[-1]
+    gated_delta_plan(L, H, K, V, chunk)          # refuses what does not fit
+    return _forward_call(
+        q.reshape(b, L, H * K), k.reshape(b, L, H * K),
+        v.reshape(b, L, H * V), _chunk_sums(g, chunk), beta, init,
+        chunk=chunk, H=H, keep_inverse=keep_inverse)
 
 
 def _rule_fwd(q, k, v, g, beta, init, chunk):
     if not _kernel_ok(q, chunk):
         out = gated_delta_reference(q, k, v, g, beta, chunk, init)
-        return out, (q, k, v, g, beta, init, None)
-    b, L, H, K = q.shape
-    V = v.shape[-1]
-    gated_delta_plan(L, H, K, V, chunk)          # refuses what does not fit
-    o, states, final = _forward_call(
-        q.reshape(b, L, H * K), k.reshape(b, L, H * K),
-        v.reshape(b, L, H * V), _chunk_sums(g, chunk), beta, init,
-        chunk=chunk, H=H)
+        return out, (q, k, v, g, beta, init, None, None)
+    o, states, final, inverse = _run_forward(q, k, v, g, beta, init, chunk,
+                                             keep_inverse=True)
     # What the forward kernel made and a backward pass reads, by name: the
-    # states are the backward kernel's, o the gated norm's after it. A
-    # rematerialised block keeps both and the forward kernel runs once
-    # (models/decoder.py KEPT_UNDER_REMAT).
+    # states and T - I are the backward kernel's, o the gated norm's after
+    # it. A rematerialised block keeps all three and the forward kernel
+    # runs once (models/decoder.py KEPT_UNDER_REMAT).
     o = checkpoint_name(o, "gated_delta_o")
     states = checkpoint_name(states, "gated_delta_states")
-    return (o.reshape(v.shape), final), (q, k, v, g, beta, init, states)
+    inverse = checkpoint_name(inverse, "gated_delta_T")
+    return (o.reshape(v.shape), final), (q, k, v, g, beta, init, states,
+                                         inverse)
 
 
 def _rule_bwd(chunk, residuals, cotangents):
-    q, k, v, g, beta, init, states = residuals
+    q, k, v, g, beta, init, states, inverse = residuals
     do, dfinal = cotangents
     if states is None:
         _, vjp = jax.vjp(
@@ -574,7 +643,7 @@ def _rule_bwd(chunk, residuals, cotangents):
     cum, cum_vjp = jax.vjp(lambda g_: _chunk_sums(g_, chunk), g)
     dq, dk, dv, dbeta, dcum, dinit = _backward_call(
         q.reshape(b, L, H * K), k.reshape(b, L, H * K),
-        v.reshape(b, L, H * V), cum, beta, states,
+        v.reshape(b, L, H * V), cum, beta, states, inverse,
         do.reshape(b, L, H * V).astype(v.dtype), dfinal.astype(jnp.float32),
         chunk=chunk, H=H)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),

@@ -149,6 +149,43 @@ def test_step_holds_no_state_a_token(step):
     assert not re.search(r"bf16\[1,30,16384,(96|192)\]", entry)
 
 
+def test_step_keeps_each_layers_inverse_once_at_its_own_size(step):
+    """Each forward kernel call leaves its layer's T - I, a head and chunk
+    64 x 64 bfloat16, with the heads side by side along the lanes: 1,920
+    columns are 15 whole 128-lane tiles and 64 rows four 16-row ones, so
+    HBM holds the 63 MB `kept_bytes` counts; a last axis of 64 would be
+    padded to twice that. Each backward call takes one, and the step holds
+    no other array of that many values or of that shape doubled."""
+    from ray_tpu.ops.gated_delta import gated_delta_plan
+
+    kept = "bf16[1,256,64,1920]"
+    assert 256 * 64 * 1920 * 2 == gated_delta_plan(
+        16384, 30, 96, 192, 64).kept_bytes == 62_914_560
+    text = step[1].as_text()
+    entry = text[text.index("\nENTRY "):]
+    calls = [line.strip() for line in entry.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    made = [c for c in calls if c.startswith("%gated_delta_fwd")]
+    read = [c for c in calls if c.startswith("%gated_delta_bwd")]
+    assert len(made) == len(read) == 3
+    for call in made:       # among the results, tiled and not padded
+        results = call.split(" custom-call(")[0]
+        assert results.count(kept + "{3,2,1,0:T(8,128)(2,1)}") == 1, results
+    for call in read:
+        operands = call.split("operand_layout_constraints={")[1].split(
+            "}}")[0]
+        assert operands.count(kept) == 1, operands
+    sizes = {tuple(int(n) for n in dims.split(","))
+             for dims in re.findall(r"bf16\[((?:\d+,)*\d+)\]", entry)}
+    values = 256 * 30 * 64 * 64
+    assert [s for s in sizes if math.prod(s) == values] == [(1, 256, 64,
+                                                             1920)]
+    assert not [s for s in sizes
+                if s[-1] == 64 and math.prod(s) >= values]
+    assert not [s for s in sizes         # a chunk's, padded to twice
+                if math.prod(s) == 2 * values and s[:2] == (1, 256)]
+
+
 def test_plan_counts_what_the_kernels_loop_over():
     from ray_tpu.ops.gated_delta import VMEM_LIMIT, gated_delta_plan
 
@@ -157,6 +194,9 @@ def test_plan_counts_what_the_kernels_loop_over():
     assert (plan.key_tile, plan.value_tile) == (128, 256)
     assert plan.state_bytes == 256 * 30 * 96 * 192 * 4
     assert plan.inverse_matmuls == 256 * 30 * 10
+    # the forward makes T, the backward reads it: 18 and 21 products a
+    # head and chunk
+    assert (plan.fwd_matmuls, plan.bwd_matmuls) == (138_240, 161_280)
     assert plan.vmem_bytes <= VMEM_LIMIT
 
 

@@ -111,6 +111,66 @@ def test_rule_and_every_gradient_match_the_recurrence(form, L, chunk, decay,
         _close(g, w), name
 
 
+def _remade(kept_backward):
+    """The backward as it was before the forward kept T: one head's T made
+    again from the same k, g and beta, and the kept one not read."""
+    def head_backward(q, k, v, gc, gr, bc, S0, Tm, dO, dS1, chunk):
+        rows, cols, D, KK = gd._head_tiles(k, gc, gr, chunk)
+        again = gd._unit_lower_inverse(
+            jnp.where(rows > cols, bc * KK * D, 0.0), chunk).astype(q.dtype)
+        return kept_backward(q, k, v, gc, gr, bc, S0, again, dO, dS1, chunk)
+    return head_backward
+
+
+@pytest.mark.parametrize(
+    "L,chunk,decay,beta,with_state,dtype",
+    [(*case, jnp.float32) for case in CASES]
+    + [(*CASES[3], jnp.bfloat16), (*CASES[5], jnp.bfloat16)],
+    ids=IDS + ["chunk-16-bfloat16", "no-decay-beta-2-lean-keys-bfloat16"])
+def test_kept_inverse_gives_the_gradients_of_one_made_again(
+        monkeypatch, L, chunk, decay, beta, with_state, dtype):
+    """The T - I a differentiated forward leaves in HBM is the one the
+    backward kernel made itself, rounded once: every gradient is the same
+    number, not a close one."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    args, weights = _inputs(L, decay, beta, with_state)
+    args = (*(t.astype(dtype) for t in args[:3]), *args[3:])
+
+    def rule(*a):
+        o, state = gated_delta_rule(*a[:5], chunk, *a[5:])
+        return o.astype(jnp.float32), state
+
+    kept = _all_of(rule, args, weights)
+    # the kernel looks `_head_backward` up when it is traced, and the
+    # jitted call is traced once a shape
+    gd._backward_call.clear_cache()
+    monkeypatch.setattr(gd, "_head_backward", _remade(gd._head_backward))
+    try:
+        again = _all_of(rule, args, weights)
+    finally:
+        gd._backward_call.clear_cache()
+    for name, got, want in zip(NAMES, kept, again, strict=False):
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(want, np.float32)), name
+        assert np.isfinite(np.asarray(got, np.float32)).all(), name
+
+
+def test_call_that_is_not_differentiated_writes_no_inverse(monkeypatch):
+    """Prefill and `cached_forward` take the primal call, which no backward
+    pass follows: its kernel has no T output, the differentiated one's
+    has one, [b, chunks, chunk, heads * chunk]."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    (q, k, v, g, beta, _), _ = _inputs(32, 1.0, "mid", False)
+    kept = "tensor<2x4x8x24xf32>"       # 32 / 8 chunks, 3 heads of 8 columns
+
+    def primal(*a):
+        return gated_delta_rule(*a, 8)[0]
+
+    assert kept not in jax.jit(primal).lower(q, k, v, g, beta).as_text()
+    grad = jax.jit(jax.grad(lambda *a: jnp.sum(primal(*a))))
+    assert kept in grad.lower(q, k, v, g, beta).as_text()
+
+
 def test_reference_pads_a_length_that_is_no_whole_number_of_chunks():
     args, weights = _inputs(27, 1.0, "mid", True)
     want = _all_of(reference.recurrence, args, weights)
@@ -172,14 +232,16 @@ def test_plan_counts_against_the_loops(seq, heads, K, V, chunk):
     assert plan.key_tile == -(-K // 128) * 128 >= K
     assert plan.value_tile == -(-V // 128) * 128 >= V
     assert plan.state_bytes == chunks * heads * K * V * 4
-    # the loops: a squaring and a product a level, and what
-    # _head_forward / _head_backward run besides
+    assert plan.kept_bytes == chunks * heads * chunk * chunk * 2
+    # the loops: two products a level in the forward, none in the
+    # backward, and what _head_forward / _head_backward run besides: the
+    # forward's eight, five of them again and sixteen more
     levels = 0
     while 2 ** (levels + 1) < chunk:        # block sizes 2, 4, ... < chunk
         levels += 1
     assert plan.inverse_matmuls == chunks * heads * 2 * levels
     assert plan.fwd_matmuls == plan.inverse_matmuls + chunks * heads * 8
-    assert plan.bwd_matmuls == plan.fwd_matmuls + chunks * heads * 16
+    assert plan.bwd_matmuls == chunks * heads * (5 + 16)
     assert plan.fwd_exps == plan.bwd_exps == chunks * heads
     assert plan.vmem_bytes <= gd.VMEM_LIMIT
 
@@ -191,20 +253,33 @@ def test_plan_refuses_what_the_kernels_cannot_run():
         gated_delta_plan(4096, 256, 128, 256, 64)
 
 
-def test_kernels_count_the_products_the_plan_says(monkeypatch):
+def test_kernels_count_the_products_the_plan_says():
     """The dot_generals in one head's forward and backward, traced: the
-    plan's 8 + 2 levels and 16 more."""
+    plan's 8 + 2 levels, the inverse's at Precision.HIGHEST, and in the
+    backward, which is handed T - I, 5 of the 8 again, 16 more and none at
+    HIGHEST."""
     chunk, K, V = 16, 12, 20
     f32 = jnp.float32
 
     def dots(fn, *shapes):
-        jaxpr = jax.make_jaxpr(fn)(*(jnp.zeros(s, f32) for s in shapes))
-        return sum(e.primitive.name == "dot_general" for e in jaxpr.eqns)
+        """(products, those of them at Precision.HIGHEST)"""
+        # the precision the products name themselves, not this file's
+        with jax.default_matmul_precision("default"):
+            jaxpr = jax.make_jaxpr(fn)(*(jnp.zeros(s, f32) for s in shapes))
+        found = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+        highest = (jax.lax.Precision.HIGHEST,) * 2
+        return len(found), sum(e.params["precision"] == highest
+                               for e in found)
 
     head = ((chunk, K), (chunk, K), (chunk, V), (chunk, 1), (1, chunk),
             (chunk, 1), (K, V))
     levels = gd._inverse_levels(chunk)
-    assert dots(lambda *a: gd._head_forward(*a, chunk)[:2], *head) == (
-        gd._FWD_PRODUCTS + 2 * levels)
-    assert dots(lambda *a: gd._head_backward(*a, chunk), *head, (chunk, V),
-                (K, V)) == gd._FWD_PRODUCTS + gd._BWD_PRODUCTS + 2 * levels
+    assert dots(lambda *a: gd._head_forward(*a, chunk), *head) == (
+        gd._FWD_PRODUCTS + 2 * levels, 2 * levels)
+    assert dots(lambda *a: gd._head_backward(*a, chunk), *head,
+                (chunk, chunk), (chunk, V), (K, V)) == (
+                    gd._AGAIN_PRODUCTS + gd._BWD_PRODUCTS, 0)
+    plan = gated_delta_plan(4 * chunk, 1, K, V, chunk)
+    assert plan.fwd_matmuls == 4 * (gd._FWD_PRODUCTS + 2 * levels)
+    assert plan.bwd_matmuls == 4 * (gd._AGAIN_PRODUCTS + gd._BWD_PRODUCTS)
+    assert plan.inverse_matmuls == 4 * 2 * levels
