@@ -623,7 +623,7 @@ def differential_maps(q, k, v, layer, dec: Decoder, index: int,
 # makes the chunk's W, U and V' again (0.57 GB a layer at 16,384 tokens of
 # Olmo-Hybrid-7B, 30 heads of 96 x 192); each head's and chunk's T - I as
 # it enters them, in the model's dtype (0.06 GB: ten float32 products a
-# head and chunk the backward kernel does not run a second time); and the
+# pair of heads and chunk the backward kernel does not run again); and the
 # rule's output o (the gated norm's, after it; 0.19 GB). Its projections,
 # convolution, L2 norms, beta, g, gated norm and output projection are
 # made again; no other [C, C] tile and no V' ever reaches HBM.

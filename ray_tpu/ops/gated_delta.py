@@ -25,9 +25,9 @@ WY form (its section 3.3) solves them together:
 Every decay is exp of a number <= 0, masked BEFORE the exp. T is made by
 doubling blocks, in float32 on the matrix unit (`_unit_lower_inverse`):
 the inverses of the diagonal blocks of size m give those of size 2m,
-[[T1, 0], [-T2 A21 T1, T2]], two products a level, ten a head and chunk of
-64 and no loop over rows; every step multiplies blocks of the inverse
-itself, so it is as well conditioned as the answer (the product of powers
+[[T1, 0], [-T2 A21 T1, T2]], two products a level, ten a chunk of 64 and
+no loop over rows; every step multiplies blocks of the inverse itself, so
+it is as well conditioned as the answer (the product of powers
 (I - A)(I + A^2)(I + A^4)..., as many products, is not: there it says
 why). T enters W and U as I + (T - I) so that only the part off the
 diagonal is rounded to the operands' dtype.
@@ -36,22 +36,32 @@ Two kernels, forward and backward, each one `pallas_call` on a grid
 (batch, chunks) whose chunk axis is sequential: the states ride a float32
 output block that stays in VMEM from chunk to chunk (backward: their
 gradients, from the last chunk to the first). A program works ALL heads
-of one chunk, one after another, slicing each head's columns out of the
-projections' own layout [batch, seq, heads * width]: no transposed copy,
-and no head count or width has to fall on the chip's 128-lane tiles (30
-heads of 96 and 192 do not: a head's columns are cut out of one or two
-tiles where it is read, nothing is padded in HBM). T is made once a head
-and chunk, by the forward kernel: a forward that will be differentiated
-(`_rule_fwd`) leaves T - I in HBM as it enters W and U, rounded to the
-operands' dtype, [chunks, chunk, heads * chunk] (the heads side by side
-along the lanes, two to a 128-lane tile: 63 MB a layer at Olmo-Hybrid-7B's
+of one chunk, slicing each head's columns out of the projections' own
+layout [batch, seq, heads * width]: no transposed copy, and no head count
+or width has to fall on the chip's 128-lane tiles (30 heads of 96 and 192
+do not: a head's columns are cut out of one or two tiles where it is
+read, nothing is padded in HBM). The forward kernel walks the heads by
+the 128-lane tile up to T and one at a time after it: wherever two
+chunks' widths fit the lanes (`heads_per_tile`: two at chunks of 64, one
+at 128) two heads' [C, C] float32 tiles stand side by side in one
+[C, 2C] tile, so D is one exponential, A one mask and the inverse one
+chain of products a pair (each product the pair's tile times the pair's
+tile laid on the diagonal of a [2C, 2C] one: what a head's sums gain
+from its neighbour are exact zeros), fifteen chains a chunk of thirty
+heads where thirty ran, an odd count's last head alone; W, U, V', O and
+S1, whose operands are K and V wide, and the whole backward kernel work a
+head at a time. T is made once a head and chunk, by the forward kernel: a
+forward that will be differentiated (`_rule_fwd`) leaves T - I in HBM as
+it enters W and U, rounded to the operands' dtype, [chunks, chunk,
+heads * chunk] (the heads side by side along the lanes, two to a 128-lane
+tile, a pair's in one whole store: 63 MB a layer at Olmo-Hybrid-7B's
 shape), beside the state entering each chunk, [chunks, heads, K, V]
 float32 (never one a token; 566 MB). The backward kernel reads both and
 makes again only what is cheap, in bfloat16: D, k k^T, W, U, V', q k^T and
-the two tiles cut from them (five products a head and chunk; the inverse's
-ten float32 ones run in the forward alone). The other [C, C] tiles, W, U
-and V' live in VMEM only, in both passes. The call that is not
-differentiated (`_rule`: prefill, `cached_forward`) writes no T. The
+the two tiles cut from them (five products a head and chunk; the
+inverse's float32 ones run in the forward alone). The other [C, C]
+tiles, W, U and V' live in VMEM only, in both passes. The call that is
+not differentiated (`_rule`: prefill, `cached_forward`) writes no T. The
 gradient by the decays comes from the same float32 tiles by row and by
 column, so the running sums' reverse cumulative sum adds rectangle sums of
 one tile and subtracts nothing it did not add (ops/ssm_scan.py tells why
@@ -77,6 +87,7 @@ from . import attention
 from .attention import DEFAULT_MASK_VALUE, _NN, _NT, _dot
 
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
+LANES = 128                         # of a vector register and of a tile
 # All heads of a chunk are in VMEM at once, with a state block each way:
 # more than the 32 MiB the attention kernels are planned against, of the
 # chip's 128.
@@ -156,20 +167,29 @@ def gated_delta_reference(q, k, v, g, beta, chunk: int = 64,
 class GatedDeltaPlan:
     """Sizes of one gated_delta_rule call and what a sequence of one batch
     row executes. `grid` is the chunks a batch row; a grid program works
-    all `heads_per_block` = heads of one chunk. A head's K columns are read
+    all `heads_per_block` = heads of one chunk, the forward
+    `heads_per_tile` of them side by side up to T (two where two chunks'
+    widths fit the 128 lanes, an odd count's last head alone) and one at a
+    time after it, the backward one at a time. A head's K columns are read
     out of `key_tile` lanes of VMEM and its V columns out of `value_tile`
     (the widths rounded up to whole 128-lane tiles: 96 -> 128, 192 -> 256;
     HBM holds the widths as they are). `fwd_matmuls` and `bwd_matmuls`
     count the products a pass runs on the matrix unit, `inverse_matmuls`
-    those of the forward's that make T in float32 (the backward makes
-    none: it reads the T - I a differentiated forward leaves in HBM,
-    `kept_bytes` a call at two bytes a value); `fwd_exps` and `bwd_exps`
-    the exponentials' [chunk, chunk] tiles (the [chunk, 1] columns beside
-    them are not counted)."""
+    those of the forward's that make T in float32, two a level and tile
+    of heads (the backward makes none: it reads the T - I a
+    differentiated forward leaves in HBM, `kept_bytes` a call at two bytes
+    a value); `fwd_exps` and `bwd_exps` the exponentials' tiles, the
+    forward's [chunk, heads_per_tile * chunk], the backward's [chunk,
+    chunk] (the [chunk, 1] columns beside them are not counted). At
+    Olmo-Hybrid-7B's shape, `gated_delta_plan(16384, 30, 96, 192, 64)`:
+    `heads_per_tile` 2, `inverse_matmuls` 38,400 (76,800 a head at a
+    time), `fwd_matmuls` 99,840, `fwd_exps` 3,840, `bwd_matmuls` 161,280,
+    `bwd_exps` 7,680."""
     seq_len: int
     chunk: int
     chunks: int
     heads_per_block: int
+    heads_per_tile: int
     grid: tuple
     key_tile: int
     value_tile: int
@@ -183,13 +203,19 @@ class GatedDeltaPlan:
     bwd_exps: int
 
 
+def heads_per_tile(chunk: int) -> int:
+    """Heads whose [chunk, chunk] float32 tiles the forward works side by
+    side up to T: two where two chunks' widths fit the 128 lanes."""
+    return 2 if 2 * chunk <= LANES else 1
+
+
 def _inverse_levels(chunk: int) -> int:
     """Levels of `_unit_lower_inverse` that multiply: block sizes 2, 4,
     ... under `chunk` (two products each)."""
     return max(0, (chunk - 1).bit_length() - 1)
 
 
-# What one head of one chunk runs besides T, by `_head_forward` and
+# What one head of one chunk runs besides T, by `_tile_forward` and
 # `_head_backward` below: the forward's eight, the five of them the
 # backward makes again (`_head_tiles`, `_head_chunk`: all but O's two and
 # S1's), and its own sixteen.
@@ -207,10 +233,10 @@ def _vmem_bytes(heads: int, key_dim: int, value_dim: int, chunk: int) -> int:
     dk and v, dO, dv and the heads' T - I in bf16; three state blocks),
     the eight [C, heads] columns and rows, and a head's float32
     temporaries."""
-    kt, vt = _round_up(key_dim, 128), _round_up(value_dim, 128)
+    kt, vt = _round_up(key_dim, LANES), _round_up(value_dim, LANES)
     state = heads * _round_up(key_dim, 8) * vt * 4
     acts = chunk * heads * (4 * key_dim + 3 * value_dim + chunk) * 2
-    return (2 * acts + 2 * 3 * state + 8 * 2 * chunk * 128 * 4
+    return (2 * acts + 2 * 3 * state + 8 * 2 * chunk * LANES * 4
             + 12 * chunk * (kt + vt) * 4 + 16 * chunk * chunk * 4)
 
 
@@ -228,17 +254,20 @@ def gated_delta_plan(seq_len: int, heads: int, key_dim: int, value_dim: int,
             f"gated_delta: chunks of {chunk} with {heads} heads of "
             f"{key_dim} x {value_dim} do not fit {VMEM_LIMIT} bytes of VMEM")
     chunks = seq_len // chunk
-    inverse = 2 * _inverse_levels(chunk)
+    per_tile = heads_per_tile(chunk)
+    tiles = chunks * -(-heads // per_tile)
+    inverse = tiles * 2 * _inverse_levels(chunk)
     return GatedDeltaPlan(
         seq_len=seq_len, chunk=chunk, chunks=chunks, heads_per_block=heads,
-        grid=(chunks,), key_tile=_round_up(key_dim, 128),
-        value_tile=_round_up(value_dim, 128), vmem_bytes=need,
+        heads_per_tile=per_tile, grid=(chunks,),
+        key_tile=_round_up(key_dim, LANES),
+        value_tile=_round_up(value_dim, LANES), vmem_bytes=need,
         state_bytes=chunks * heads * key_dim * value_dim * 4,
         kept_bytes=chunks * heads * chunk * chunk * 2,
-        inverse_matmuls=chunks * heads * inverse,
-        fwd_matmuls=chunks * heads * (inverse + _FWD_PRODUCTS),
+        inverse_matmuls=inverse,
+        fwd_matmuls=inverse + chunks * heads * _FWD_PRODUCTS,
         bwd_matmuls=chunks * heads * (_AGAIN_PRODUCTS + _BWD_PRODUCTS),
-        fwd_exps=chunks * heads, bwd_exps=chunks * heads)
+        fwd_exps=tiles, bwd_exps=chunks * heads)
 
 
 def _kernel_ok(q, chunk: int) -> bool:
@@ -252,25 +281,60 @@ def _kernel_ok(q, chunk: int) -> bool:
 # ---------------------------------------------------------------------------
 # Kernels
 # ---------------------------------------------------------------------------
+def _tile_indices(chunk: int, n: int):
+    """(rows, cols) of a [C, n * C] tile that holds n heads' [C, C] tiles
+    side by side along the lanes: `cols` counts inside a head."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, n * chunk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, n * chunk), 1)
+    return rows, lane if n == 1 else lane % chunk
+
+
+def _side_by_side(cols, chunk: int):
+    """[C, 1] columns, one a head, spread over their heads' lanes of a
+    [C, n * C] tile; one column broadcasts by itself."""
+    wide = cols[-1]
+    if len(cols) > 1:
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, (chunk, len(cols) * chunk), 1)
+        for i in reversed(range(len(cols) - 1)):
+            wide = jnp.where(lane < (i + 1) * chunk, cols[i], wide)
+    return wide
+
+
 def _unit_lower_inverse(A, chunk: int):
-    """(I + A)^-1 - I for A [C, C] float32 strictly lower triangular, by
-    doubling blocks: the inverse of the diagonal blocks of size m gives
-    that of size 2m, [[T1, 0], [-T2 A21 T1, T2]], for m = 1 (the identity),
-    2, 4, ...: with A_m the entries of A that join the two halves of a
-    2m-block, T <- T - T A_m T, two float32 products on the matrix unit a
-    level and none at the first. Every step multiplies blocks of the
-    inverse itself, so nothing grows that the answer does not hold. (The
-    product (I - A)(I + A^2)(I + A^4)... is as many products and was the
-    first form here: with keys that all lean one way, as silu leaves them,
-    A ~ c L and A^k reaches c^k binom(C, k), 2e4 at c = 0.24 and 1e17 at
-    c = 1, against an inverse of entries under c: it lost the digits it
-    had, and the cell's loss was NaN within a window.)"""
+    """(I + A)^-1 - I for strictly lower triangular [C, C] float32
+    matrices, n of them side by side along the lanes in A [C, n * C] (two
+    fill a 128-lane tile at chunks of 64), by doubling blocks: the inverse
+    of the diagonal blocks of size m gives that of size 2m, [[T1, 0],
+    [-T2 A21 T1, T2]], for m = 1 (the identity), 2, 4, ...: with A_m the
+    entries of A that join the two halves of a 2m-block, T <- T - T A_m T,
+    two float32 products on the matrix unit a level and none at the first.
+    The n heads share each product: the left operand is their tile as it
+    stands, the right one their tile laid on the diagonal of an [n * C,
+    n * C] tile, so what one head's sums gain from another's are exact
+    zeros and each head's T is the number it is alone. Every step
+    multiplies blocks of the inverse itself, so nothing grows that the
+    answer does not hold. (The product (I - A)(I + A^2)(I + A^4)... is as
+    many products and was the first form here: with keys that all lean one
+    way, as silu leaves them, A ~ c L and A^k reaches c^k binom(C, k), 2e4
+    at c = 0.24 and 1e17 at c = 1, against an inverse of entries under c:
+    it lost the digits it had, and the cell's loss was NaN within a
+    window.)"""
+    n = A.shape[1] // chunk
+    rows, cols = _tile_indices(chunk, n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, A.shape, 1)
+    own = [(lane >= i * chunk) & (lane < (i + 1) * chunk) for i in range(n)]
+
     def mm(a, b):
         return jax.lax.dot_general(a, b, _NN,
                                    precision=jax.lax.Precision.HIGHEST,
                                    preferred_element_type=jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+
+    def on_diagonal(x):     # [C, n * C] -> [n * C, n * C], zeros elsewhere
+        if n == 1:
+            return x
+        return jnp.concatenate([jnp.where(head, x, 0.0) for head in own],
+                               axis=0)
 
     def joins(log_m):   # same 2m-block, different m-blocks (rows > cols)
         return ((rows >> (log_m + 1) == cols >> (log_m + 1))
@@ -280,8 +344,8 @@ def _unit_lower_inverse(A, chunk: int):
     for log_m in range(1, _inverse_levels(chunk) + 1):
         A_m = jnp.where(joins(log_m), A, 0.0)
         # (I + T) A_m (I + T), the identity's parts added, not multiplied
-        TA = A_m + mm(T, A_m)
-        T = T - TA - mm(TA, T)
+        TA = A_m + mm(T, on_diagonal(A_m))
+        T = T - TA - mm(TA, on_diagonal(T))
     return T
 
 
@@ -296,14 +360,18 @@ def _times_last(x, col):
     return x * jnp.sum(wide, axis=0, keepdims=True)
 
 
-def _head_tiles(k, gc, gr, chunk: int):
-    """The [C, C] float32 tiles of one head of one chunk that both passes
-    make: (rows, cols, D_ij = exp(G_i - G_j) on and under the diagonal,
-    KK = k k^T)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    D = jnp.exp(jnp.where(rows >= cols, gc - gr, DEFAULT_MASK_VALUE))
-    return rows, cols, D, _dot(k, k, _NT)
+def _head_tiles(ks, gcs, gr, chunk: int):
+    """The [C, C] float32 tiles of one chunk that both passes make, for
+    the n heads of `ks` side by side in [C, n * C] tiles (the forward: the
+    heads of a 128-lane tile; the backward: one head): (rows, cols inside a
+    head, D_ij = exp(G_i - G_j) on and under the diagonal, KK = k k^T).
+    `gcs` the heads' running sums by row, [C, 1] each; `gr` [1, n * C]
+    theirs by column, side by side."""
+    rows, cols = _tile_indices(chunk, len(ks))
+    D = jnp.exp(jnp.where(rows >= cols, _side_by_side(gcs, chunk) - gr,
+                          DEFAULT_MASK_VALUE))
+    KK = jnp.concatenate([_dot(k, k, _NT) for k in ks], axis=1)
+    return rows, cols, D, KK
 
 
 def _head_chunk(q, k, v, gc, bc, S0, D, Tm):
@@ -329,27 +397,37 @@ def _head_chunk(q, k, v, gc, bc, S0, D, Tm):
                 Vpb=Vp.astype(dtype), Kd=(k32 * to_end).astype(dtype))
 
 
-def _head_forward(q, k, v, gc, gr, bc, S0, chunk: int):
-    """One head of one chunk. q, k [C, K], v [C, V] in the model's dtype;
-    gc [C, 1], gr [1, C] the running sums of g by row and by column, bc
-    [C, 1] beta, float32; S0 [K, V] float32 the state entering. Returns
-    (O [C, V] float32, S1, and Tm = T - I in the model's dtype, the one
-    place T is made: the backward reads it)."""
-    rows, cols, D, KK = _head_tiles(k, gc, gr, chunk)
-    Tm = _unit_lower_inverse(jnp.where(rows > cols, bc * KK * D, 0.0),
-                             chunk).astype(q.dtype)
-    t = _head_chunk(q, k, v, gc, bc, S0, D, Tm)
-    O = t["eg"] * _dot(q, t["S0b"], _NN) + _dot(t["P"], t["Vpb"], _NN)
-    S1 = _times_last(S0, t["eg"]) + _dot(t["Kd"], t["Vpb"], _TN)
-    return O, S1, Tm
+def _tile_forward(heads, gr, chunk: int):
+    """The heads of one 128-lane tile (`heads_per_tile`: one or two) of
+    one chunk. `heads`: a head's (q, k [C, K], v [C, V] in the model's
+    dtype; gc [C, 1] the running sums of g by row and bc [C, 1] beta,
+    float32; S0 [K, V] float32 the state entering); gr [1, n * C] the
+    heads' running sums by column, side by side. Up to T the heads work
+    as one tile: one exponential, one mask, one chain of the inverse's
+    products. Returns (Tm = T - I in the model's dtype, [C, n * C], the
+    one place T is made: the backward reads it; a head's (O [C, V]
+    float32, S1))."""
+    qs, ks, _, gcs, bcs, _ = zip(*heads, strict=True)
+    rows, cols, D, KK = _head_tiles(ks, gcs, gr, chunk)
+    A = jnp.where(rows > cols, _side_by_side(bcs, chunk) * KK * D, 0.0)
+    Tm = _unit_lower_inverse(A, chunk).astype(qs[0].dtype)
+    outs = []
+    for i, (q, k, v, gc, bc, S0) in enumerate(heads):
+        own = slice(i * chunk, (i + 1) * chunk)
+        t = _head_chunk(q, k, v, gc, bc, S0, D[:, own], Tm[:, own])
+        O = t["eg"] * _dot(q, t["S0b"], _NN) + _dot(t["P"], t["Vpb"], _NN)
+        S1 = _times_last(S0, t["eg"]) + _dot(t["Kd"], t["Vpb"], _TN)
+        outs.append((O, S1))
+    return Tm, outs
 
 
 def _gd_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, init_ref,
                    o_ref, states_ref, final_ref, T_ref=None, *, H: int,
                    K: int, V: int):
-    """`T_ref`: every head's T - I of the chunk, [C, H * C], where a
-    backward pass will read it (`_rule_fwd`); the call that is not
-    differentiated has no such output."""
+    """`gr_ref`: the running sums by column of every head, [1, H * C], the
+    heads side by side as `T_ref`'s are. `T_ref`: every head's T - I of the
+    chunk, [C, H * C], where a backward pass will read it (`_rule_fwd`);
+    the call that is not differentiated has no such output."""
     from jax.experimental import pallas as pl
 
     chunk = q_ref.shape[1]
@@ -358,19 +436,24 @@ def _gd_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, bc_ref, init_ref,
     def _init():
         final_ref[...] = init_ref[...]
 
-    gc_all, gr_all, bc_all = gc_ref[0], gr_ref[0, 0], bc_ref[0]
-    for h in range(H):
-        ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
-        S0 = final_ref[0, h]
-        states_ref[0, 0, h] = S0
-        O, S1, Tm = _head_forward(
-            q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
-            gc_all[:, h:h + 1], gr_all[h:h + 1, :], bc_all[:, h:h + 1], S0,
-            chunk)
-        o_ref[0, :, vs] = O.astype(o_ref.dtype)
-        final_ref[0, h] = S1
+    gc_all, bc_all = gc_ref[0], bc_ref[0]
+    n = heads_per_tile(chunk)
+    for first in range(0, H, n):            # an odd count's last head alone
+        tile = range(first, min(first + n, H))
+        lanes = slice(first * chunk, tile.stop * chunk)
+        heads = []
+        for h in tile:
+            ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
+            S0 = final_ref[0, h]
+            states_ref[0, 0, h] = S0
+            heads.append((q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
+                          gc_all[:, h:h + 1], bc_all[:, h:h + 1], S0))
+        Tm, outs = _tile_forward(heads, gr_ref[0, 0, :, lanes], chunk)
         if T_ref is not None:
-            T_ref[0, 0, :, h * chunk:(h + 1) * chunk] = Tm
+            T_ref[0, 0, :, lanes] = Tm
+        for h, (O, S1) in zip(tile, outs, strict=True):
+            o_ref[0, :, h * V:(h + 1) * V] = O.astype(o_ref.dtype)
+            final_ref[0, h] = S1
 
 
 def _head_backward(q, k, v, gc, gr, bc, S0, Tm, dO, dS1, chunk: int):
@@ -379,7 +462,7 @@ def _head_backward(q, k, v, gc, gr, bc, S0, Tm, dO, dS1, chunk: int):
     column [1, C], dS0 [K, V]), float32. W, U and V' are made again from
     S0 and the Tm = T - I the forward kept; no inverse is."""
     dtype, f32 = q.dtype, jnp.float32
-    rows, cols, D, KK = _head_tiles(k, gc, gr, chunk)
+    rows, cols, D, KK = _head_tiles([k], [gc], gr, chunk)
     t = _head_chunk(q, k, v, gc, bc, S0, D, Tm)
     eg, to_end, exp_end = t["eg"], t["to_end"], t["exp_end"]
     S0b, Vpb, k32 = t["S0b"], t["Vpb"], t["k32"]
@@ -490,6 +573,10 @@ def _specs(H: int, K: int, V: int, chunk: int, chunk_of):
         col=pl.BlockSpec((1, chunk, H), lambda i, s: (i, chunk_of(s), 0)),
         row=pl.BlockSpec((1, 1, H, chunk),
                          lambda i, s: (i, chunk_of(s), 0, 0)),
+        # the same sums, the heads side by side along the lanes as the
+        # forward's tiles hold them
+        rows=pl.BlockSpec((1, 1, 1, H * chunk),
+                          lambda i, s: (i, chunk_of(s), 0, 0)),
         state=pl.BlockSpec((1, H, K, V), lambda i, s: (i, 0, 0, 0)),
         states=pl.BlockSpec((1, 1, H, K, V),
                             lambda i, s: (i, chunk_of(s), 0, 0, 0)),
@@ -525,14 +612,16 @@ def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int,
     call = pl.pallas_call(
         functools.partial(_gd_fwd_kernel, H=H, K=K, V=V),
         grid=(b, nc),
-        in_specs=[s["key"], s["key"], s["value"], s["col"], s["row"],
+        in_specs=[s["key"], s["key"], s["value"], s["col"], s["rows"],
                   s["col"], s["state"]],
         out_specs=out_specs, out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=attention._interpret(),
     )
     with jax.named_scope("gated_delta_fwd"):
-        return call(q, k, v, cum, _by_row(cum, chunk), beta, init)
+        return call(q, k, v, cum,
+                    _by_row(cum, chunk).reshape(b, nc, 1, H * chunk), beta,
+                    init)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "H"))

@@ -193,10 +193,13 @@ def test_plan_counts_what_the_kernels_loop_over():
     assert (plan.chunks, plan.heads_per_block, plan.grid) == (256, 30, (256,))
     assert (plan.key_tile, plan.value_tile) == (128, 256)
     assert plan.state_bytes == 256 * 30 * 96 * 192 * 4
-    assert plan.inverse_matmuls == 256 * 30 * 10
-    # the forward makes T, the backward reads it: 18 and 21 products a
-    # head and chunk
-    assert (plan.fwd_matmuls, plan.bwd_matmuls) == (138_240, 161_280)
+    # the forward makes T for two heads a 128-lane tile, ten float32
+    # products a pair and eight bfloat16 ones a head; the backward reads
+    # it: 21 products a head and chunk
+    assert plan.heads_per_tile == 2
+    assert plan.inverse_matmuls == 256 * 15 * 10 == 38_400
+    assert (plan.fwd_matmuls, plan.bwd_matmuls) == (99_840, 161_280)
+    assert (plan.fwd_exps, plan.bwd_exps) == (3_840, 7_680)
     assert plan.vmem_bytes <= VMEM_LIMIT
 
 

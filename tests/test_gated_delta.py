@@ -7,6 +7,8 @@ precision. 1e-4 of the largest value: float32 rounding through a few
 hundred dependent steps, and the inverse made by doubling blocks, stay
 under 1e-5 here; an all-bfloat16 state is off by 5e-3."""
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -111,14 +113,27 @@ def test_rule_and_every_gradient_match_the_recurrence(form, L, chunk, decay,
         _close(g, w), name
 
 
-def _remade(kept_backward):
+def _remade(kept_backward, heads=3):
     """The backward as it was before the forward kept T: one head's T made
-    again from the same k, g and beta, and the kept one not read."""
+    again from the same k, g and beta, and the kept one not read. Made as
+    the forward makes it, in the head's own place in its 128-lane tile
+    (the kernel walks the heads in order) beside copies of itself: what a
+    neighbour adds to a head's sums are exact zeros whatever it holds, but
+    where in a longer contraction a backend sums a head's own terms is its
+    own affair."""
+    calls = itertools.count()
+
     def head_backward(q, k, v, gc, gr, bc, S0, Tm, dO, dS1, chunk):
-        rows, cols, D, KK = gd._head_tiles(k, gc, gr, chunk)
+        h = next(calls) % heads
+        first = h - h % gd.heads_per_tile(chunk)
+        width = min(first + gd.heads_per_tile(chunk), heads) - first
+        rows, cols, D, KK = gd._head_tiles([k], [gc], gr, chunk)
+        A = jnp.where(rows > cols, bc * KK * D, 0.0)
         again = gd._unit_lower_inverse(
-            jnp.where(rows > cols, bc * KK * D, 0.0), chunk).astype(q.dtype)
-        return kept_backward(q, k, v, gc, gr, bc, S0, again, dO, dS1, chunk)
+            jnp.concatenate([A] * width, axis=1), chunk).astype(q.dtype)
+        own = slice((h - first) * chunk, (h - first + 1) * chunk)
+        return kept_backward(q, k, v, gc, gr, bc, S0, again[:, own], dO, dS1,
+                             chunk)
     return head_backward
 
 
@@ -205,21 +220,89 @@ def test_bfloat16_inputs_come_back_bfloat16_with_a_float32_state(form):
     _close(o, want, tol=3e-2)
 
 
-@pytest.mark.parametrize("chunk,levels,lean", [
-    (8, 2, 0.3), (16, 3, 0.3), (64, 5, 0.3), (64, 5, 1.0), (64, 5, 2.0),
-    (24, 4, 1.0)])
-def test_inverse_by_doubling_blocks_holds_float32(chunk, levels, lean):
+def _lower(chunk, lean, key):
+    """A strictly lower A: `lean` 0.3 noise in (-0.3, 0.3), else every
+    entry near `lean`."""
+    noise = jax.random.uniform(jax.random.PRNGKey(key), (chunk, chunk),
+                               minval=-0.3, maxval=0.3)
+    return jnp.tril(noise if lean == 0.3 else lean * (1.0 + 0.1 * noise), -1)
+
+
+@pytest.mark.parametrize("chunk,levels,lean,heads", [
+    (8, 2, 0.3, 1), (16, 3, 0.3, 1), (64, 5, 0.3, 1), (64, 5, 1.0, 1),
+    (64, 5, 2.0, 1), (24, 4, 1.0, 1),
+    (8, 2, 0.3, 2), (16, 3, 1.0, 2), (64, 5, 0.3, 2), (64, 5, 1.0, 2),
+    (64, 5, 2.0, 2)])
+def test_inverse_by_doubling_blocks_holds_float32(chunk, levels, lean, heads):
     """(I + A)^-1 for a strictly lower A against numpy's float64 inverse.
     `lean` 1 and 2: every entry of A near beta (k_i . k_j) for keys that
     all point one way, at beta = 1 and 2, where the powers of A reach 1e17
-    and a product of powers has no digit left; 24: no power of two."""
+    and a product of powers has no digit left; 24: no power of two. Two
+    heads: two different A side by side, as the forward kernel's tile holds
+    them: each half is its own matrix's inverse, and the number the call
+    on that matrix alone gives (the other head's terms in a sum are exact
+    zeros)."""
     assert gd._inverse_levels(chunk) == levels
-    noise = jax.random.uniform(jax.random.PRNGKey(chunk), (chunk, chunk),
-                               minval=-0.3, maxval=0.3)
-    A = jnp.tril(noise if lean == 0.3 else lean * (1.0 + 0.1 * noise), -1)
-    got = gd._unit_lower_inverse(A, chunk) + jnp.eye(chunk)
-    want = np.linalg.inv(np.eye(chunk) + np.asarray(A, np.float64))
-    np.testing.assert_allclose(got, want, atol=2e-5 * np.abs(want).max())
+    As = [_lower(chunk, lean, chunk + 1000 * h) for h in range(heads)]
+    got = gd._unit_lower_inverse(jnp.concatenate(As, axis=1), chunk)
+    assert got.shape == (chunk, heads * chunk)
+    for h, A in enumerate(As):
+        own = got[:, h * chunk:(h + 1) * chunk]
+        want = np.linalg.inv(np.eye(chunk) + np.asarray(A, np.float64))
+        np.testing.assert_allclose(own + jnp.eye(chunk), want,
+                                   atol=2e-5 * np.abs(want).max())
+        if heads > 1:
+            alone = np.asarray(gd._unit_lower_inverse(A, chunk))
+            # XLA's CPU dot has another kernel, and another order of sums,
+            # for a contraction of 32 than for one of 16: to rounding there
+            np.testing.assert_allclose(
+                own, alone, rtol=0,
+                atol=1e-6 * np.abs(want).max() if chunk == 16 else 0)
+
+
+def _kernel_wants(q, k, g, beta, v, init, chunk):
+    """What the forward kernel leaves besides o and the final state, from
+    the definitions: the state entering each chunk (the reference's final
+    state of the chunks before it) and T - I a head and chunk (numpy's
+    float64 inverse of I + A), [b, chunks, chunk, heads * chunk]."""
+    b, L, H, _ = q.shape
+    nc = L // chunk
+    states = [init] + [
+        gated_delta_reference(q[:, :n], k[:, :n], v[:, :n], g[:, :n],
+                              beta[:, :n], chunk, init)[1]
+        for n in range(chunk, L, chunk)]
+    k64, b64 = np.asarray(k, np.float64), np.asarray(beta, np.float64)
+    cum = np.asarray(gd._chunk_sums(g, chunk), np.float64)
+    T = np.zeros((b, nc, chunk, H * chunk))
+    for i, c, h in np.ndindex(b, nc, H):
+        at = slice(c * chunk, (c + 1) * chunk)
+        kc, G = k64[i, at, h], cum[i, at, h]
+        A = np.tril(b64[i, at, h, None] * (kc @ kc.T)
+                    * np.exp(np.tril(G[:, None] - G[None, :])), -1)
+        T[i, c, :, h * chunk:(h + 1) * chunk] = (
+            np.linalg.inv(np.eye(chunk) + A) - np.eye(chunk))
+    return jnp.stack(states, axis=1), T
+
+
+@pytest.mark.parametrize("H,chunk", [(4, 8), (3, 8), (1, 8), (3, 16)],
+                         ids=["even", "odd", "one-head", "odd-chunk-16"])
+def test_forward_kernel_works_the_heads_in_pairs(monkeypatch, H, chunk):
+    """The forward kernel makes T for two heads a tile and works a last
+    odd head alone: o, the state entering each chunk, the final state and
+    every head's T - I, in its own columns, are the definition's."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    assert gd.heads_per_tile(chunk) == 2
+    (q, k, v, g, beta, init), _ = _inputs(4 * chunk, 1.0, "mid-lean", True,
+                                          H=H)
+    o, states, final, T = gd._run_forward(q, k, v, g, beta, init, chunk,
+                                          keep_inverse=True)
+    want_o, want_final = gated_delta_reference(q, k, v, g, beta, chunk, init)
+    want_states, want_T = _kernel_wants(q, k, g, beta, v, init, chunk)
+    _close(o.reshape(v.shape), want_o)
+    _close(final, want_final)
+    _close(states, want_states)
+    assert T.shape == want_T.shape
+    _close(T, want_T, tol=2e-5)
 
 
 @pytest.mark.parametrize("seq,heads,K,V,chunk", [
@@ -233,16 +316,20 @@ def test_plan_counts_against_the_loops(seq, heads, K, V, chunk):
     assert plan.value_tile == -(-V // 128) * 128 >= V
     assert plan.state_bytes == chunks * heads * K * V * 4
     assert plan.kept_bytes == chunks * heads * chunk * chunk * 2
-    # the loops: two products a level in the forward, none in the
-    # backward, and what _head_forward / _head_backward run besides: the
-    # forward's eight, five of them again and sixteen more
+    # the loops: the forward walks a chunk's heads by the 128-lane tile,
+    # two products a level and one exponential a tile, none of the
+    # inverse's in the backward, and what _tile_forward / _head_backward
+    # run a head besides: the forward's eight, five of them again and
+    # sixteen more
     levels = 0
     while 2 ** (levels + 1) < chunk:        # block sizes 2, 4, ... < chunk
         levels += 1
-    assert plan.inverse_matmuls == chunks * heads * 2 * levels
+    assert plan.heads_per_tile == (2 if 2 * chunk <= 128 else 1)
+    tiles = chunks * len(range(0, heads, plan.heads_per_tile))
+    assert plan.inverse_matmuls == tiles * 2 * levels
     assert plan.fwd_matmuls == plan.inverse_matmuls + chunks * heads * 8
     assert plan.bwd_matmuls == chunks * heads * (5 + 16)
-    assert plan.fwd_exps == plan.bwd_exps == chunks * heads
+    assert (plan.fwd_exps, plan.bwd_exps) == (tiles, chunks * heads)
     assert plan.vmem_bytes <= gd.VMEM_LIMIT
 
 
@@ -254,8 +341,9 @@ def test_plan_refuses_what_the_kernels_cannot_run():
 
 
 def test_kernels_count_the_products_the_plan_says():
-    """The dot_generals in one head's forward and backward, traced: the
-    plan's 8 + 2 levels, the inverse's at Precision.HIGHEST, and in the
+    """The dot_generals in one tile's forward and one head's backward,
+    traced: the plan's 8 a head + 2 levels a tile, the inverse's at
+    Precision.HIGHEST, whether the tile holds one head or two, and in the
     backward, which is handed T - I, 5 of the 8 again, 16 more and none at
     HIGHEST."""
     chunk, K, V = 16, 12, 20
@@ -271,15 +359,23 @@ def test_kernels_count_the_products_the_plan_says():
         return len(found), sum(e.params["precision"] == highest
                                for e in found)
 
-    head = ((chunk, K), (chunk, K), (chunk, V), (chunk, 1), (1, chunk),
-            (chunk, 1), (K, V))
+    head = ((chunk, K), (chunk, K), (chunk, V), (chunk, 1), (chunk, 1),
+            (K, V))
     levels = gd._inverse_levels(chunk)
-    assert dots(lambda *a: gd._head_forward(*a, chunk), *head) == (
-        gd._FWD_PRODUCTS + 2 * levels, 2 * levels)
-    assert dots(lambda *a: gd._head_backward(*a, chunk), *head,
-                (chunk, chunk), (chunk, V), (K, V)) == (
-                    gd._AGAIN_PRODUCTS + gd._BWD_PRODUCTS, 0)
-    plan = gated_delta_plan(4 * chunk, 1, K, V, chunk)
-    assert plan.fwd_matmuls == 4 * (gd._FWD_PRODUCTS + 2 * levels)
-    assert plan.bwd_matmuls == 4 * (gd._AGAIN_PRODUCTS + gd._BWD_PRODUCTS)
-    assert plan.inverse_matmuls == 4 * 2 * levels
+    for n in (1, 2):
+        def tile(gr, *heads, n=n):
+            return gd._tile_forward(
+                [heads[i * len(head):(i + 1) * len(head)] for i in range(n)],
+                gr, chunk)
+        assert dots(tile, (1, n * chunk), *head * n) == (
+            n * gd._FWD_PRODUCTS + 2 * levels, 2 * levels)
+    assert dots(lambda *a: gd._head_backward(*a, chunk), *head[:4],
+                (1, chunk), *head[4:], (chunk, chunk), (chunk, V),
+                (K, V)) == (gd._AGAIN_PRODUCTS + gd._BWD_PRODUCTS, 0)
+    for heads, tiles in ((1, 1), (2, 1), (3, 2)):
+        plan = gated_delta_plan(4 * chunk, heads, K, V, chunk)
+        assert plan.inverse_matmuls == 4 * tiles * 2 * levels
+        assert plan.fwd_matmuls == 4 * (heads * gd._FWD_PRODUCTS
+                                        + tiles * 2 * levels)
+        assert plan.bwd_matmuls == 4 * heads * (gd._AGAIN_PRODUCTS
+                                                + gd._BWD_PRODUCTS)
