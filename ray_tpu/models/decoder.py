@@ -1,74 +1,87 @@
-"""The decoder's compute, written once: what models.gpt, models.llama and
-models.moe train and what models.generate prefills and decodes.
+"""The decoder's compute, written once: what the six families of
+ray_tpu.models train and what models.generate prefills and decodes.
 
 A family says what it is with a `Decoder`, which its config's `decoder()`
-builds from the fields it already has: the attention's head counts, its
-channel mixer, its remat policy, the rope base (None: no positions at
-all), the norm eps, the score scale and the sizes of a state-space mixer
-where it names them (ops.layers' own, 1/sqrt(head_dim), none where it
-does not), and what its embedding, its two residual branches and its
-logits are multiplied by (1 where it says nothing). Nothing here reads a
-config: one family differs from another by those values and by which
-weights a layer holds (`x_proj`: a Mamba-1 layer; `in_proj` without it: a
-Mamba-2 layer; `delta_in`: a gated-delta-rule layer; `gmu_in`: a gated
-memory unit; `lambda_q1`: differential attention, over its own keys and
-values from `wqkv` or over another layer's from `wq` alone; else
-attention, from `wqkv` or `wq` + `wkv`, with `q_norm` or not; `ln1_b`:
-LayerNorm with bias where the others have RMSNorm; `ln1`, `ln2`: a block
-that norms what its branches read, x + mixer(norm(x)), `post_attention`,
-`post_feedforward` and neither of those: one that norms what they return,
-x + norm(mixer(x))), and by nothing else. A model's layers need not be
-alike: each picks its mixer and the place of its norms by what it holds
-(`_mix`, `_block`).
+builds from the fields it already has: `kinds`, the kind of every layer
+in order (from `n_layers`, `layer_types` or `layer_kinds`; nobody sets it);
+the attention's head counts, its channel mixer, its remat policy, the rope
+base (None: no positions at all), the norm eps, the score scale and the
+sizes of a state-space mixer where it names them (ops.layers' own,
+1/sqrt(head_dim), none where it does not), and what its embedding, its two
+residual branches and its logits are multiplied by (1 where it says
+nothing). Nothing here reads a config. A model's layers need not be alike:
+a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
+runs it, what state it keeps in a cache, and whether it is windowed, hands
+its keys and values on, or reads its place in the stack. A new sequence
+mixer is its function and its row. The eight kinds:
 
-Nor need they be independent (SambaY, models.sambay): the layer stack
+    ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
+                   max_len, head_dim]}
+    MAMBA2         the chunked scan; {"conv": [batch, d_conv - 1, inner +
+                   2 groups x state] the convolution's last inputs, "ssm":
+                   [batch, heads, head_dim, state] float32}, which does
+                   not grow
+    MAMBA1         the selective scan; {"conv": [batch, d_conv - 1, inner],
+                   "ssm": [batch, inner, state] float32}
+    GATED_DELTA    the gated delta rule; {"conv": [batch, taps - 1, heads
+                   x (2 K + V)] the last inputs over q | k | v, "delta":
+                   [batch, heads, K, V] float32}, which does not grow
+    GMU            a gated memory unit over `Shared.m`; {}
+    DIFF_WINDOWED  differential attention over its own keys and values no
+                   further back than `dec.window`; ATTENTION's state
+    DIFF_FULL      the same over all of them, which it hands on as
+                   `Shared.k, v`; ATTENTION's state
+    DIFF_CROSS     differential attention of its own queries over
+                   `Shared.k, v`; {}
+
+Every leaf of a layer's state has the batch first: that is the table's one
+rule (models.generate.make_continuous_fns cuts a slot out of axis 0 of
+every leaf). A state's sizes come from `dec` and from the layer's weights
+or their shapes, as its mixer's do, so a cache is made from a model's
+parameters or from `jax.eval_shape` of its init alike.
+
+What is still read off the weights a layer holds picks no mixer and no
+state: `ln1_b`: LayerNorm with bias where the others have RMSNorm; `ln1`,
+`ln2`: a block that norms what its branches read, x + mixer(norm(x)),
+`post_attention`, `post_feedforward` and neither of those: one that norms
+what they return, x + norm(mixer(x)); `wqkv` or `wq` + `wkv`, `q_norm` or
+not, inside `attention`.
+
+Nor need the layers be independent (SambaY, models.sambay): the stack
 carries two values forward besides x, `Shared`: the scan's output `m` of
-the last Mamba-1 layer so far, which every gated memory unit after it
-multiplies, and the keys and values of the last differential-attention
-layer that saw the whole sequence, which every cross-attention layer
+the last MAMBA1 layer so far, which every GMU after it multiplies, and the
+keys and values of the last DIFF_FULL layer, which every DIFF_CROSS layer
 after it attends over. Both pass through `jax.checkpoint` as block inputs
-and outputs, and their gradients arrive from every reader. A
-differential-attention layer of its own keys is windowed (`dec.window`)
-while a Mamba-1 layer still follows it in the stack, and sees everything
-after the last one: which, `decoder_hidden` reads off the weights too.
+and outputs, and their gradients arrive from every reader.
 
     decoder_hidden      embedding, layer stack, final norm, head
     decoder_logits      its rows times its head, float32
+    empty_cache         each layer's state, by its row
       attention | mamba2 | mamba1 | gated_delta | gmu | diff_attention
                         the sequence mixers, (x, layer, dec, cache,
-                        start_pos[, shared, index, window]) -> (y, new
-                        cache[, shared]): attention is the flash kernel
-                        over the whole sequence with no cache, with one a
-                        write into it and a masked read of it; mamba2 the
-                        chunked scan (ops.ssm_scan) over the tokens given,
-                        from the cached state where there is one, and one
-                        step of the recurrence for a single token; mamba1
-                        the same over ops.selective_scan; gated_delta the
-                        same over ops.gated_delta, a matrix state a head
-                        that is read back before it is written; gmu no
-                        state at all; diff_attention two softmax maps a pair of
-                        heads, their difference times both heads' values
+                        start_pos[, shared, index, window, ...]) -> (y,
+                        new cache[, shared]): attention is the flash
+                        kernel over the whole sequence with no cache, with
+                        one a write into it and a masked read of it; mamba2
+                        the chunked scan (ops.ssm_scan) over the tokens
+                        given, from the cached state where there is one,
+                        and one step of the recurrence for a single token;
+                        mamba1 the same over ops.selective_scan;
+                        gated_delta the same over ops.gated_delta, a matrix
+                        state a head that is read back before it is
+                        written; gmu no state at all; diff_attention two
+                        softmax maps a pair of heads, their difference
+                        times both heads' values
       gelu_mlp | swiglu_mlp | fused_swiglu_mlp | routed_experts
                         the channel mixers, (y, layer) -> (out, stats or
                         None)
-
-Cache layout, per layer by its kind: attention (differential too)
-{"k"|"v": [batch, n_kv_heads, max_len, head_dim]}; Mamba-2 {"conv":
-[batch, d_conv - 1, inner + 2 groups x state] the convolution's last
-inputs, "ssm": [batch, heads, head_dim, state] float32}, which does not
-grow; Mamba-1 {"conv": [batch, d_conv - 1, inner], "ssm": [batch, inner,
-state] float32}; the gated delta rule {"conv": [batch, taps - 1, heads x
-(2 K + V)] the convolution's last inputs over q | k | v, "delta": [batch,
-heads, K, V] float32}, which does not grow either; a gated memory unit and
-a cross-attention layer hold nothing ({}): they read the tokens in flight
-and the other layer's cache.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Dict, List, NamedTuple, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,30 +98,36 @@ from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import dropless_moe_layer
 
 
+# The kinds of layer: the keys of MIXERS, below the mixers.
+(ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
+ DIFF_CROSS) = ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
+                "diff_windowed", "diff_full", "diff_cross")
+
+
 class Decoder(NamedTuple):
     n_heads: int
     n_kv_heads: int
     head_dim: int
     mlp: Callable                   # (y, layer) -> (out, stats or None)
     remat: Optional[Callable]       # a jax.checkpoint policy; None: keep all
+    kinds: Tuple[str, ...]          # a key of MIXERS a layer, in order
     rope_base: Optional[float] = ROPE_BASE     # None: no rotary
     norm_eps: float = NORM_EPS
     sm_scale: Optional[float] = None           # None: 1 / sqrt(head_dim)
     residual_scale: float = 1.0                # on both branches of a block
     embed_scale: float = 1.0
     logit_scale: float = 1.0                   # on the final-norm rows
-    # A Mamba-2 layer's sizes (layers that hold `in_proj`): inner width
-    # ssm_heads * ssm_head_dim; B and C are ssm_groups * ssm_state wide.
+    # A MAMBA2 layer's sizes: inner width ssm_heads * ssm_head_dim; B and C
+    # are ssm_groups * ssm_state wide.
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_chunk: int = 256
-    # A gated-delta-rule layer's chunk (layers that hold `delta_in`; its
-    # heads and widths are read off its weights).
+    # A GATED_DELTA layer's chunk (its heads and widths are read off its
+    # weights).
     delta_chunk: int = 64
-    # What a differential-attention layer of its own keys sees while a
-    # Mamba-1 layer still follows it: itself and the window - 1 before.
+    # What a DIFF_WINDOWED layer sees: itself and the window - 1 before.
     window: Optional[int] = None
 
 
@@ -142,25 +161,6 @@ def routed_experts(y, layer, experts_per_token: int, norm_topk_prob: bool):
     return out.reshape(b, s, d), stats
 
 
-def _is_mamba1(layer) -> bool:
-    return "x_proj" in layer
-
-
-def _is_mamba2(layer) -> bool:
-    return "in_proj" in layer and not _is_mamba1(layer)
-
-
-def _is_gated_delta(layer) -> bool:
-    return "delta_in" in layer
-
-
-def _reads_shared(layer) -> bool:
-    """A gated memory unit, or differential attention over another
-    layer's keys and values: no state of its own."""
-    return "gmu_in" in layer or ("lambda_q1" in layer
-                                 and "wqkv" not in layer)
-
-
 def _norm(x, holder, name: str, eps: float):
     """The norm whose weight `holder` has under `name`: LayerNorm where
     it has a bias beside it, RMSNorm where not."""
@@ -175,39 +175,6 @@ def _norm_if_held(x, layer, name: str, eps: float):
     (`post_attention`, `post_feedforward`: OLMo 2's order), and says which
     by the weights it holds."""
     return _norm(x, layer, name, eps) if name in layer else x
-
-
-def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
-    """The state of each of `layers` (a model's `params["layers"]`, or
-    anything that holds their keys), by its kind: an attention layer its
-    kv heads up to `max_len`, not their copies across a group; a Mamba-2
-    or Mamba-1 layer its convolution's last inputs and its state, as a
-    gated-delta-rule layer; a layer that reads what another made,
-    nothing."""
-    def one(layer):
-        if _reads_shared(layer):
-            return {}
-        if _is_mamba1(layer):
-            inner, taps = layer["conv_w"].shape
-            return {"conv": jnp.zeros((batch, taps - 1, inner), dtype),
-                    "ssm": jnp.zeros((batch, inner,
-                                      layer["A_log"].shape[1]), jnp.float32)}
-        if _is_gated_delta(layer):
-            H, K, V = _delta_sizes(layer)
-            taps = layer["conv_w"].shape[1]
-            return {"conv": jnp.zeros((batch, taps - 1, H * (2 * K + V)),
-                                      dtype),
-                    "delta": jnp.zeros((batch, H, K, V), jnp.float32)}
-        if _is_mamba2(layer):
-            conv_dim = (dec.ssm_heads * dec.ssm_head_dim
-                        + 2 * dec.ssm_groups * dec.ssm_state)
-            taps = layer["conv_w"].shape[1]
-            return {"conv": jnp.zeros((batch, taps - 1, conv_dim), dtype),
-                    "ssm": jnp.zeros((batch, dec.ssm_heads, dec.ssm_head_dim,
-                                      dec.ssm_state), jnp.float32)}
-        shape = (batch, dec.n_kv_heads, max_len, dec.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    return [one(layer) for layer in layers]
 
 
 def _across_group(t, group: int):
@@ -505,7 +472,7 @@ def diff_lambda_init(index: int) -> float:
 
 
 def diff_attention(x, layer, dec: Decoder, cache, start_pos, shared: Shared,
-                   index: int, window: Optional[int]):
+                   index: int, window: Optional[int], own, hands_on):
     """Differential attention of x [b, L, d], from the input norm to the
     output projection, no positions at all. Heads pair up neighbours:
     query pair p is heads 2p, 2p + 1; kv pair g = p // group is k heads
@@ -514,13 +481,12 @@ def diff_attention(x, layer, dec: Decoder, cache, start_pos, shared: Shared,
     its 2 hd columns (one weight a layer), times 1 - lambda_init. Each
     score map is computed once: the flash kernel takes v wider than q and
     k, so head 2p + e is q_2p+e, k_2g+e and V_g, and the subtraction comes
-    after (`diff_attention_combine`). With `wqkv` the layer attends over
-    its own keys and values (under `window`, or all of them, which it then
-    hands on in `shared`); with `wq` alone over `shared`'s. Returns (y,
-    new_cache or None, shared)."""
+    after (`diff_attention_combine`). An `own` layer attends over its own
+    keys and values from `wqkv` (under `window`, or all of them), which
+    one that `hands_on` puts in `shared`; any other over `shared`'s, from
+    `wq` alone. Returns (y, new_cache or None, shared)."""
     b, L, d = x.shape
     h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
-    own = "wqkv" in layer
     y = _norm(x, layer, "ln1", dec.norm_eps)
     sp = None if cache is None else jnp.asarray(start_pos)
 
@@ -536,7 +502,7 @@ def diff_attention(x, layer, dec: Decoder, cache, start_pos, shared: Shared,
         if cache is not None:
             new_cache = _write_cache(cache, k, v, sp)
             k, v = new_cache["k"], new_cache["v"]
-        if window is None:
+        if hands_on:
             shared = shared._replace(k=k, v=v)
     else:
         q = jnp.einsum("bsd,de->bse", y, layer["wq"]) + layer["bq"]
@@ -577,6 +543,137 @@ def differential_maps(q, k, v, layer, dec: Decoder, index: int,
                        layer["sub_norm"], dec.norm_eps) * (1.0 - lam_init)
         return out.astype(q.dtype).transpose(0, 2, 1, 3).reshape(
             b, L, h * hd)
+
+
+def _kv_state(dec: Decoder, layer, batch, max_len, dtype):
+    """An attention layer's kv heads up to `max_len`, not their copies
+    across a group."""
+    shape = (batch, dec.n_kv_heads, max_len, dec.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def _mamba2_state(dec: Decoder, layer, batch, max_len, dtype):
+    conv_dim, taps = layer["conv_w"].shape      # inner + 2 groups x state
+    return {"conv": jnp.zeros((batch, taps - 1, conv_dim), dtype),
+            "ssm": jnp.zeros((batch, dec.ssm_heads, dec.ssm_head_dim,
+                              dec.ssm_state), jnp.float32)}
+
+
+def _mamba1_state(dec: Decoder, layer, batch, max_len, dtype):
+    inner, taps = layer["conv_w"].shape
+    return {"conv": jnp.zeros((batch, taps - 1, inner), dtype),
+            "ssm": jnp.zeros((batch, inner, layer["A_log"].shape[1]),
+                             jnp.float32)}
+
+
+def _delta_state(dec: Decoder, layer, batch, max_len, dtype):
+    H, K, V = _delta_sizes(layer)
+    taps = layer["conv_w"].shape[1]
+    return {"conv": jnp.zeros((batch, taps - 1, H * (2 * K + V)), dtype),
+            "delta": jnp.zeros((batch, H, K, V), jnp.float32)}
+
+
+def _no_state(dec: Decoder, layer, batch, max_len, dtype):
+    """A layer that reads what another made."""
+    return {}
+
+
+def _attention(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*attention(x, layer, dec, cache, start_pos), shared)
+
+
+def _mamba2(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*mamba2(x, layer, dec, cache, start_pos), shared)
+
+
+def _gated_delta(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*gated_delta(x, layer, dec, cache, start_pos), shared)
+
+
+def _mamba1(x, layer, dec, cache, start_pos, shared, index, window):
+    y, new_cache, m = mamba1(x, layer, dec, cache, start_pos)
+    return y, new_cache, shared._replace(m=m)
+
+
+def _gmu(x, layer, dec, cache, start_pos, shared, index, window):
+    return gmu(x, layer, dec, shared.m), cache, shared
+
+
+class Mixer(NamedTuple):
+    """A row of MIXERS: a kind of layer's sequence mixer and its state."""
+    # (x, layer, dec, cache, start_pos, shared, index, window)
+    #     -> (y, new cache, shared)
+    apply: Callable
+    # (dec, layer, batch, max_len, dtype) -> {name: [batch, ...]}
+    state: Callable
+    windowed: bool = False      # `window` is dec.window; None elsewhere
+    hands_on_kv: bool = False   # its keys and values become Shared.k, v
+    reads_index: bool = False   # `index` is its place; 0 elsewhere
+
+
+def _differential(own, windowed=False, hands_on=False) -> Mixer:
+    def apply(x, layer, dec, cache, start_pos, shared, index, window):
+        return diff_attention(x, layer, dec, cache, start_pos, shared, index,
+                              window, own, hands_on)
+    return Mixer(apply, _kv_state if own else _no_state, windowed, hands_on,
+                 reads_index=True)
+
+
+# kind -> its row: the one place that says what a layer of a kind runs and
+# what it keeps. The layer loop (`decoder_hidden`, `_block`) and
+# `empty_cache` both go through it; a new sequence mixer adds its function
+# above and its row here. The table's one rule: every leaf of a `state` has
+# the batch first (models.generate.make_continuous_fns cuts axis 0 of every
+# leaf).
+#
+# What is frozen. The benchmark reaches into this module on the chip, where
+# no tier-1 test runs but tests/test_mixer_table.py's stand-in
+# (chipbench/families/granite_hybrid.py, sambay.py, olmo_hybrid.py:
+# `hold_kernels` and `planted`). Seven attributes of this module keep their
+# names and positional signatures:
+#     ssm_scan(x, dt, a, B, C, D, chunk, init)
+#     selective_scan(x, dt, A, B, C, D, init)
+#     flash_attention
+#     gated_delta_rule(q, k, v, g, beta, chunk, init)
+#     _unit_heads(t, heads, scale, eps)
+#     gmu(x, layer, dec, m)
+#     differential_maps(q, k, v, layer, dec, index, window)
+# The first six the benchmark also SWAPS on the module (`setattr(decoder,
+# name, faulty)`) and then traces the program, so the program must find
+# them through the module's global name at the time of the call. A row
+# that held the function object `gmu` itself, bound at import, would run
+# the real one under a planted fault, and the fault would read as harmless
+# with no error. So every `apply` here is a function whose BODY calls
+# `gmu(...)`, `mamba2(...)` and the rest by name, and the mixers call the
+# kernels the same way. Frozen besides: every parameter key, every
+# `*_init`'s `jax.random.split` layout, every `jax.named_scope` and
+# `checkpoint_name`, KEPT_UNDER_REMAT, and `_block`'s name (it is what
+# `jax.checkpoint` wraps).
+MIXERS: Dict[str, Mixer] = {
+    ATTENTION: Mixer(_attention, _kv_state),
+    MAMBA2: Mixer(_mamba2, _mamba2_state),
+    MAMBA1: Mixer(_mamba1, _mamba1_state),
+    GATED_DELTA: Mixer(_gated_delta, _delta_state),
+    GMU: Mixer(_gmu, _no_state),
+    DIFF_WINDOWED: _differential(own=True, windowed=True),
+    DIFF_FULL: _differential(own=True, hands_on=True),
+    DIFF_CROSS: _differential(own=False),
+}
+
+
+def _rows(dec: Decoder, layers) -> List[Mixer]:
+    """The row of each of `layers`, by `dec.kinds`."""
+    if len(dec.kinds) != len(layers) or set(dec.kinds) - set(MIXERS):
+        raise ValueError(f"Decoder.kinds {dec.kinds}: one of {sorted(MIXERS)} "
+                         f"for each of the {len(layers)} layers, no other")
+    return [MIXERS[kind] for kind in dec.kinds]
+
+
+def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
+    """The state of each of `layers` (a model's `params["layers"]` or
+    their shapes), by the row of its kind."""
+    return [row.state(dec, layer, batch, max_len, dtype)
+            for row, layer in zip(_rows(dec, layers), layers)]
 
 
 # What a rematerialised block keeps for its backward pass, by the names
@@ -642,28 +739,11 @@ def _scaled(t, scale: float):
     return t if scale == 1.0 else t * scale
 
 
-def _mix(x, layer, cache, start_pos, shared: Shared, dec: Decoder,
-         index: int, window: Optional[int]):
-    """The layer's sequence mixer, picked by the weights it holds:
-    (y, new cache, what the stack carries on)."""
-    if _is_mamba1(layer):
-        y, new_cache, m = mamba1(x, layer, dec, cache, start_pos)
-        return y, new_cache, shared._replace(m=m)
-    if "gmu_in" in layer:
-        return gmu(x, layer, dec, shared.m), cache, shared
-    if "lambda_q1" in layer:
-        return diff_attention(x, layer, dec, cache, start_pos, shared,
-                              index, window)
-    mixer = (gated_delta if _is_gated_delta(layer)
-             else mamba2 if _is_mamba2(layer) else attention)
-    return (*mixer(x, layer, dec, cache, start_pos), shared)
-
-
 def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
-           dec: Decoder, index: int = 0, window: Optional[int] = None):
+           dec: Decoder, kind: str, index: int = 0, window=None):
     eps = dec.norm_eps
-    y, new_cache, shared = _mix(x, layer, cache, start_pos, shared, dec,
-                                index, window)
+    y, new_cache, shared = MIXERS[kind].apply(
+        x, layer, dec, cache, start_pos, shared, index, window)
     x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
                     dec.residual_scale)
     out, stats = dec.mlp(_norm_if_held(x, layer, "ln2", eps), layer)
@@ -690,27 +770,24 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
         else jnp.take(params["embed"], tokens, axis=0)
     x = _scaled(x, dec.embed_scale)
     layers = params["layers"]
-    # Differential attention is windowed while a Mamba-1 layer follows.
-    last_scan = max((i for i, layer in enumerate(layers)
-                     if _is_mamba1(layer)), default=-1)
 
     @functools.cache
-    def block_at(index: int, window: Optional[int]):
-        block = functools.partial(_block, dec=dec, index=index, window=window)
+    def block_at(kind: str, index: int, window: Optional[int]):
+        block = functools.partial(_block, dec=dec, kind=kind, index=index,
+                                  window=window)
         if dec.remat is not None and cache is None:    # remat is training's
             block = jax.checkpoint(block, policy=dec.remat)
         return block
 
     total, new_cache, shared = None, [], Shared()
     with jax.named_scope("layers"):
-        for i, (layer, cache_layer) in enumerate(zip(
-                layers, cache or [None] * len(layers))):
-            # Only differential attention reads its place and a window:
-            # every other layer runs the one block, traced once a shape.
-            differential = "lambda_q1" in layer
-            block = block_at(
-                i if differential else 0,
-                dec.window if differential and i < last_scan else None)
+        for i, (kind, row, layer, cache_layer) in enumerate(zip(
+                dec.kinds, _rows(dec, layers), layers,
+                cache or [None] * len(layers))):
+            # A kind that reads neither its place nor a window runs one
+            # block, traced once a shape.
+            block = block_at(kind, i if row.reads_index else 0,
+                             dec.window if row.windowed else None)
             x, stats, cache_layer, shared = block(
                 x, layer, cache_layer, start_pos, shared)
             total = stats if total is None else jax.tree.map(

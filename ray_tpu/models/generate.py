@@ -1,5 +1,6 @@
 """Autoregressive generation with a cache, for any config with a
-`decoder()` (models.gpt, models.llama, models.moe, models.hybrid).
+`decoder()` and an `init(key)` (all six families of ray_tpu.models: gpt,
+llama, moe, hybrid, sambay, olmo_hybrid). No family is named here.
 
 Parity role: the reference serves LLMs by hosting external engines
 (vLLM etc.) on its actors; here the decode path is native — a
@@ -8,7 +9,12 @@ prompt bucket, one for the single-token decode step), rotary offsets per
 position, fp32 logits. The serving layer (llm.serving) drives these
 jitted steps and streams tokens through Serve.
 
-The cache's layout is models.decoder's.
+The cache is models.decoder's: one dict a layer, the state of the layer's
+kind by `decoder.MIXERS` (the eight kinds and each one's layout are in that
+module's docstring), every leaf with the batch first, which is what lets
+`make_continuous_fns` cut one request's slot out of axis 0 of every leaf.
+A family names its layers' kinds in `cfg.decoder().kinds`; the sizes come
+from the shapes of the weights `cfg.init` would make, and none is made.
 """
 
 from __future__ import annotations
@@ -20,26 +26,19 @@ import jax
 import jax.numpy as jnp
 
 from .decoder import decoder_hidden, decoder_logits, empty_cache
-from .hybrid import MAMBA
+
+
+# The shapes of a model's layers, with no weight made; kept a config
+# because tracing an init costs tens of milliseconds and `generate` asks
+# for a cache a request.
+_shapes = functools.lru_cache(maxsize=8)(
+    lambda cfg: jax.eval_shape(cfg.init, jax.random.PRNGKey(0))["layers"])
 
 
 def init_cache(cfg, batch: int, max_len: int) -> List[Dict]:
-    """An empty cache: each layer gets the state of its kind. A config
-    that names its layers' kinds (`layer_types`, models.hybrid) gets them
-    from there, as stand-ins that hold what decoder.empty_cache reads of
-    a layer, as does one that builds the stand-ins itself (`cache_layers`,
-    models.sambay); every layer of any other family is an attention
-    layer."""
-    kinds = getattr(cfg, "layer_types", None)
-    if hasattr(cfg, "cache_layers"):      # models.sambay: five kinds
-        layers = cfg.cache_layers()
-    elif kinds is None:
-        layers = [{}] * cfg.n_layers
-    else:
-        mamba = {"in_proj": None, "conv_w": jax.ShapeDtypeStruct(
-            (cfg.mamba_conv_dim, cfg.mamba_d_conv), cfg.dtype)}
-        layers = [mamba if kind == MAMBA else {} for kind in kinds]
-    return empty_cache(cfg.decoder(), layers, batch, max_len, cfg.dtype)
+    """An empty cache: each layer gets the state of its kind, sized from
+    the shapes of the model's weights."""
+    return empty_cache(cfg.decoder(), _shapes(cfg), batch, max_len, cfg.dtype)
 
 
 def cached_forward(params: Dict, tokens, cache: List[Dict],
@@ -49,8 +48,7 @@ def cached_forward(params: Dict, tokens, cache: List[Dict],
     (logits [b, L, vocab] fp32, new_cache)."""
     x, head, _, new_cache = decoder_hidden(
         params, tokens, cfg.decoder(), cache, start_pos)
-    return (decoder_logits(x, head),
-            new_cache)
+    return decoder_logits(x, head), new_cache
 
 
 @functools.lru_cache(maxsize=8)

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, decoder_logits, gelu_mlp
+from .decoder import (ATTENTION, Decoder, decoder_hidden, decoder_logits,
+                      gelu_mlp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +55,11 @@ class GPTConfig:
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
             head_dim=self.head_dim, mlp=gelu_mlp,
-            remat=policy if self.remat else None)
+            remat=policy if self.remat else None,
+            kinds=(ATTENTION,) * self.n_layers)
+
+    def init(self, key) -> Dict:
+        return gpt_init(key, self)
 
     @classmethod
     def gpt2_small(cls) -> "GPTConfig":

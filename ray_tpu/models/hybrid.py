@@ -12,14 +12,12 @@ and the logits divided by `logits_scaling`; the head is the embedding.
 config.json (model_type granitemoehybrid with no routed experts).
 
 Same conventions as models.gpt: dict pytrees, logical axis tables, bf16
-matmuls; float32 norms, softplus, decays and state. A layer says what it
-is by the weights it holds, which is all models.decoder looks at. Of the
-mixers a decoder layer may hold there (attention, differential attention,
-Mamba-2, Mamba-1, a gated memory unit, the gated delta rule) this family
-has two: `in_proj` is a Mamba-2 layer, cache {"conv": [batch, d_conv - 1,
-inner + 2 groups x state], "ssm": [batch, heads, head_dim, state]
-float32}; `wq` + `wkv` an attention layer, cache {"k" | "v": [batch,
-n_kv_heads, max_len, head_dim]}.
+matmuls; float32 norms, softplus, decays and state. `decoder()` names
+every layer's kind from `layer_types`: of models.decoder's eight (MIXERS)
+this family has two, `mamba` a MAMBA2 layer, cache {"conv": [batch, d_conv
+- 1, inner + 2 groups x state], "ssm": [batch, heads, head_dim, state]
+float32}; `attention` an ATTENTION layer from `wq` + `wkv`, cache {"k" |
+"v": [batch, n_kv_heads, max_len, head_dim]}.
 """
 
 from __future__ import annotations
@@ -32,10 +30,10 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, decoder_logits,
+from .decoder import (MAMBA2, Decoder, decoder_hidden, decoder_logits,
                       keep_kernel_outputs, swiglu_mlp)
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION = "mamba", "attention"     # ATTENTION is the decoder's too
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,6 +92,8 @@ class HybridConfig:
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, mlp=swiglu_mlp,
             remat=keep_kernel_outputs if self.remat else None,
+            kinds=tuple(MAMBA2 if kind == MAMBA else ATTENTION
+                        for kind in self.layer_types),
             rope_base=None, norm_eps=self.norm_eps,
             sm_scale=self.attention_multiplier,
             residual_scale=self.residual_multiplier,
@@ -102,6 +102,9 @@ class HybridConfig:
             ssm_heads=self.mamba_n_heads, ssm_head_dim=self.mamba_d_head,
             ssm_state=self.mamba_d_state, ssm_groups=self.mamba_n_groups,
             ssm_chunk=self.mamba_chunk_size)
+
+    def init(self, key) -> Dict:
+        return hybrid_init(key, self)
 
     @classmethod
     def tiny(cls) -> "HybridConfig":

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import Decoder, decoder_hidden, decoder_logits, swiglu_mlp
+from .decoder import (ATTENTION, Decoder, decoder_hidden, decoder_logits,
+                      swiglu_mlp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +56,10 @@ class LlamaConfig:
             head_dim=self.head_dim, mlp=swiglu_mlp,
             remat=(jax.checkpoint_policies.nothing_saveable
                    if self.remat else None),
-            rope_base=self.rope_base)
+            kinds=(ATTENTION,) * self.n_layers, rope_base=self.rope_base)
+
+    def init(self, key) -> Dict:
+        return llama_init(key, self)
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, decoder_logits,
+from .decoder import (ATTENTION, Decoder, decoder_hidden, decoder_logits,
                       keep_kernel_outputs, routed_experts)
 
 
@@ -60,12 +60,15 @@ class MoEConfig:
         made and makes the rest again."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
-            head_dim=self.head_dim, rope_base=self.rope_theta,
-            norm_eps=self.norm_eps,
+            head_dim=self.head_dim, kinds=(ATTENTION,) * self.n_layers,
+            rope_base=self.rope_theta, norm_eps=self.norm_eps,
             mlp=functools.partial(
                 routed_experts, experts_per_token=self.experts_per_token,
                 norm_topk_prob=self.norm_topk_prob),
             remat=keep_kernel_outputs if self.remat else None)
+
+    def init(self, key) -> Dict:
+        return moe_init(key, self)
 
     @classmethod
     def tiny(cls) -> "MoEConfig":

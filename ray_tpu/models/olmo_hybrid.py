@@ -15,10 +15,11 @@ untied. `OlmoHybridConfig.olmo_hybrid_7b()` is allenai/Olmo-Hybrid-7B's
 config.json (model_type olmo_hybrid).
 
 Same conventions as models.hybrid: dict pytrees, logical axis tables, bf16
-matmuls; float32 norms, softplus, sigmoid, decays and state. A layer says
-what it is, and where its norms sit, by the weights it holds (`delta_in`:
-the delta rule; `post_attention`, `post_feedforward` and no `ln1`, `ln2`:
-norms after the branches), which is all models.decoder looks at. Cache: a
+matmuls; float32 norms, softplus, sigmoid, decays and state. `decoder()`
+names every layer's kind from `layer_types` (`linear_attention`:
+models.decoder's GATED_DELTA; `full_attention`: its ATTENTION); where a
+layer's norms sit it still says by the weights it holds (`post_attention`,
+`post_feedforward` and no `ln1`, `ln2`: norms after the branches). Cache: a
 linear-attention layer {"conv": [batch, taps - 1, heads x (2 K + V)],
 "delta": [batch, heads, K, V] float32}, which does not grow; a
 full-attention layer {"k" | "v": [batch, heads, max_len, head_dim]}.
@@ -34,8 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, decoder_logits,
-                      keep_kernel_outputs, swiglu_mlp)
+from .decoder import (ATTENTION, GATED_DELTA, Decoder, decoder_hidden,
+                      decoder_logits, keep_kernel_outputs, swiglu_mlp)
 from .hybrid import _attention_init, _mlp_init, _normal
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -99,23 +100,13 @@ class OlmoHybridConfig:
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
             head_dim=self.head_dim, mlp=swiglu_mlp,
             remat=keep_kernel_outputs if self.remat else None,
+            kinds=tuple(GATED_DELTA if kind == LINEAR else ATTENTION
+                        for kind in self.layer_types),
             rope_base=self.rope_theta, norm_eps=self.norm_eps,
             delta_chunk=self.linear_chunk_size)
 
-    def cache_layers(self):
-        """Stand-ins that hold what decoder.empty_cache reads of each
-        layer (models.generate.init_cache)."""
-        H = self.linear_num_heads
-        linear = {
-            "delta_in": jax.ShapeDtypeStruct(
-                (self.d_model, self.linear_conv_dim), self.dtype),
-            "conv_w": jax.ShapeDtypeStruct(
-                (self.linear_conv_dim, self.linear_conv_kernel_dim),
-                self.dtype),
-            "A_log": jax.ShapeDtypeStruct((H,), jnp.float32),
-            "delta_norm": jax.ShapeDtypeStruct(
-                (self.linear_value_head_dim,), jnp.float32)}
-        return [linear if kind == LINEAR else {} for kind in self.layer_types]
+    def init(self, key) -> Dict:
+        return olmo_hybrid_init(key, self)
 
     @classmethod
     def tiny(cls) -> "OlmoHybridConfig":

@@ -16,31 +16,32 @@ For N layers (N % 4 == 0), h = N // 2, layer i is
 with no positional encoding anywhere, LayerNorm with bias, a SwiGLU MLP from
 one fused input matrix in every layer, and the embedding as the head. The
 kinds follow from N and `mb_per_layer` by that rule (`layer_kinds`), not
-from a list. `SambaYConfig.phi4_mini_flash()` is the published config.json
-with the sizes it does not give (Mamba-1's state 16, 4 taps, expansion 2,
-dt_rank d / 16) at the family's convention.
+from a list, and are models.decoder's own (MAMBA1, DIFF_WINDOWED, DIFF_FULL,
+GMU, DIFF_CROSS): `decoder()` hands them on as they are.
+`SambaYConfig.phi4_mini_flash()` is the published config.json with the
+sizes it does not give (Mamba-1's state 16, 4 taps, expansion 2, dt_rank
+d / 16) at the family's convention.
 
 Same conventions as models.gpt: dict pytrees, logical axis tables, bf16
-matmuls; float32 norms, softplus, decays, state and lambdas. A layer says
-what it is by the weights it holds, which is all models.decoder looks at.
+matmuls; float32 norms, softplus, decays, state and lambdas.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.loss import cross_entropy
-from .decoder import (Decoder, decoder_hidden, decoder_logits,
+from .decoder import (DIFF_CROSS, DIFF_FULL, DIFF_WINDOWED, GMU, MAMBA1,
+                      Decoder, decoder_hidden, decoder_logits,
                       fused_swiglu_mlp, keep_kernel_outputs)
 
-MAMBA, WINDOWED, FULL, GMU, CROSS = (
-    "mamba", "windowed_attention", "full_attention", "gmu",
-    "cross_attention")
+# The five kinds are models.decoder's own, under the model's names for them.
+MAMBA, WINDOWED, FULL, CROSS = MAMBA1, DIFF_WINDOWED, DIFF_FULL, DIFF_CROSS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,31 +98,19 @@ class SambaYConfig:
 
     def decoder(self) -> Decoder:
         """Differential attention with no rotary, scores scaled by
-        1 / sqrt(head_dim), windowed while a Mamba-1 layer follows; the
+        1 / sqrt(head_dim), WINDOWED layers over `sliding_window`; the
         fused SwiGLU MLP; under `remat` a block keeps what its kernels
         made and makes the rest again."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, mlp=fused_swiglu_mlp,
             remat=keep_kernel_outputs if self.remat else None,
+            kinds=self.layer_kinds,
             rope_base=None, norm_eps=self.norm_eps,
             window=self.sliding_window)
 
-    def cache_layers(self) -> List[Dict]:
-        """Stand-ins that hold what decoder.empty_cache reads of each
-        layer (models.generate.init_cache)."""
-        shape = jax.ShapeDtypeStruct
-        by_kind = {
-            MAMBA: {"x_proj": None,
-                    "conv_w": shape((self.mamba_inner, self.mamba_d_conv),
-                                    self.dtype),
-                    "A_log": shape((self.mamba_inner, self.mamba_d_state),
-                                   jnp.float32)},
-            GMU: {"gmu_in": None},
-            CROSS: {"lambda_q1": None, "wq": None},
-            WINDOWED: {"lambda_q1": None, "wqkv": None},
-            FULL: {"lambda_q1": None, "wqkv": None}}
-        return [by_kind[kind] for kind in self.layer_kinds]
+    def init(self, key) -> Dict:
+        return sambay_init(key, self)
 
     @classmethod
     def tiny(cls, n_layers: int = 8) -> "SambaYConfig":

@@ -176,9 +176,10 @@ DEVICE_SCOPES: Dict[str, str] = {
     "moe_combine": "parallel/moe.py _experts: the experts' weighted rows "
                    "back in token order and their sum",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
-              "every decoder family (gpt, llama, moe, hybrid, sambay), in the "
-              "train "
-              "step and under prefill / decode alike",
+              "every decoder family (gpt, llama, moe, hybrid, sambay, "
+              "olmo_hybrid), each layer run by the row of decoder.MIXERS "
+              "that its config's `kinds` names, in the train step and "
+              "under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "
             "gradient in one pass",

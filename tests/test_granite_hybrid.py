@@ -466,8 +466,9 @@ def test_a_mamba_block_keeps_the_scan_kernels_outputs_and_nothing_else(
     cfg, params = tiny
     dec = dataclasses.replace(cfg, remat=True).decoder()
     layer = params["layers"][0]
-    block = jax.checkpoint(functools.partial(decoder._block, dec=dec),
-                           policy=dec.remat)
+    block = jax.checkpoint(
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        policy=dec.remat)
     b, s = 2, 32
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
                           jnp.ones((b, s, cfg.d_model)), layer)

@@ -1,0 +1,201 @@
+"""models.decoder.MIXERS: a layer's kind, named by its family's config,
+picks its sequence mixer and its state in one table; a cache is made from
+that table and the shapes of the weights alone; and the seven names the
+benchmark calls on the chip (chipbench/families/*.py `hold_kernels`,
+`planted`) are there, under their signatures, and are what the program
+calls when it is traced."""
+
+import dataclasses
+import inspect
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import decoder, generate
+from ray_tpu.models.generate import init_cache, make_continuous_fns
+from ray_tpu.models.gpt import GPTConfig, gpt_loss
+from ray_tpu.models.hybrid import HybridConfig, hybrid_loss
+from ray_tpu.models.llama import LlamaConfig, llama_loss
+from ray_tpu.models.moe import MoEConfig, moe_loss
+from ray_tpu.models.olmo_hybrid import OlmoHybridConfig, olmo_hybrid_loss
+from ray_tpu.models.sambay import SambaYConfig, sambay_loss
+
+FAMILIES = {
+    "gpt": (GPTConfig, gpt_loss),
+    "llama": (LlamaConfig, llama_loss),
+    "moe": (MoEConfig, moe_loss),
+    "hybrid": (HybridConfig, hybrid_loss),
+    "sambay": (SambaYConfig, sambay_loss),
+    "olmo_hybrid": (OlmoHybridConfig, olmo_hybrid_loss),
+}
+KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
+         decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
+         decoder.DIFF_FULL, decoder.DIFF_CROSS)
+
+
+@pytest.fixture(params=sorted(FAMILIES))
+def family(request):
+    """(config at the CPU tests' size in float32, its loss)."""
+    config, loss = FAMILIES[request.param]
+    return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
+
+
+def test_the_table_has_the_eight_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 8
+    run = {kind for config, _ in FAMILIES.values()
+           for kind in config.tiny().decoder().kinds}
+    assert run == set(KINDS)
+    for kind in (decoder.GMU, decoder.DIFF_CROSS):
+        assert decoder.MIXERS[kind].state(None, {}, 2, 8, jnp.float32) == {}
+
+
+def test_a_layer_holds_what_its_kinds_mixer_reads(family):
+    """One kind a layer, and every layer `cfg.init` makes goes through its
+    row's `apply` (shapes only): a missing weight is a KeyError here."""
+    cfg, _ = family
+    dec = cfg.decoder()
+    layers = jax.eval_shape(cfg.init, jax.random.PRNGKey(0))["layers"]
+    assert len(dec.kinds) == len(layers) == cfg.n_layers
+    assert set(dec.kinds) <= set(decoder.MIXERS)
+
+    def stack(x, layers):
+        shared = decoder.Shared()
+        for i, (kind, layer) in enumerate(zip(dec.kinds, layers)):
+            row = decoder.MIXERS[kind]
+            y, cache, shared = row.apply(
+                x, layer, dec, None, None, shared, i,
+                dec.window if row.windowed else None)
+            assert cache is None and y.shape == x.shape, kind
+            assert (shared.k is not None) == (
+                decoder.DIFF_FULL in dec.kinds[:i + 1]), kind
+        return x
+
+    jax.eval_shape(stack, jax.ShapeDtypeStruct((2, 16, cfg.d_model),
+                                               cfg.dtype), layers)
+
+
+def test_a_cache_from_shapes_is_the_cache_from_weights(family):
+    """`init_cache` makes no weight; on real parameters `empty_cache`
+    gives the same leaves, and every leaf has the batch first."""
+    cfg, _ = family
+    b, n = 3, 24
+    params = cfg.init(jax.random.PRNGKey(0))
+    want = decoder.empty_cache(cfg.decoder(), params["layers"], b, n,
+                               cfg.dtype)
+    got = init_cache(cfg, b, n)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert len(got) == cfg.n_layers
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        assert g.shape[0] == b and not g.any()
+    for kind, state in zip(cfg.decoder().kinds, got):
+        assert bool(state) == (kind not in (decoder.GMU, decoder.DIFF_CROSS))
+
+
+def test_a_slot_of_the_shared_cache_is_the_request_alone(family):
+    """What the batch-first rule is for: a prompt prefilled into slot 1 of
+    three (axis 0 of every leaf cut out and written back) gives the
+    logits it gives alone, and leaves the other slots empty."""
+    cfg, _ = family
+    n, slots, length = 32, 3, 16
+    params = cfg.init(jax.random.PRNGKey(0))
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, length), 0,
+                                cfg.vocab_size)
+    insert, _ = make_continuous_fns(cfg, n, slots)
+    last, cache = insert(params, prompt, init_cache(cfg, slots, n), 1,
+                         length)
+    alone, _ = generate.cached_forward(params, prompt, init_cache(cfg, 1, n),
+                                       0, cfg)
+    assert jnp.allclose(last, alone[0, -1], atol=1e-5)
+    for leaf in jax.tree.leaves(cache):
+        assert not leaf[0].any() and not leaf[2].any()
+        assert leaf[1].any()
+
+
+@pytest.mark.parametrize("entry", ["decoder_hidden", "empty_cache"])
+@pytest.mark.parametrize("fault", ["unknown_kind", "wrong_length"])
+def test_kinds_that_do_not_fit_are_refused_by_name(entry, fault):
+    cfg = HybridConfig.tiny()
+    params = cfg.init(jax.random.PRNGKey(0))
+    kinds = cfg.decoder().kinds
+    kinds = (("latent",) + kinds[1:] if fault == "unknown_kind"
+             else kinds + (decoder.ATTENTION,))
+    dec = cfg.decoder()._replace(kinds=kinds)
+    given = "latent" if fault == "unknown_kind" else "3 layers"
+    with pytest.raises(ValueError, match=given) as refused:
+        if entry == "empty_cache":
+            decoder.empty_cache(dec, params["layers"], 2, 8, cfg.dtype)
+        else:
+            decoder.decoder_hidden(params, jnp.zeros((2, 8), jnp.int32), dec)
+    assert str(kinds) in str(refused.value)
+
+
+@pytest.mark.parametrize("n_layers", [4, 8, 12, 32])
+def test_sambay_windows_what_a_mamba1_layer_still_follows(n_layers):
+    """The rule the stack used to read off the weights, now the config's:
+    a differential layer of its own keys is windowed exactly while a
+    Mamba-1 layer follows it, the one after the last hands its keys and
+    values on, and every later one reads them."""
+    kinds = SambaYConfig.tiny(n_layers).decoder().kinds
+    last_scan = max(i for i, k in enumerate(kinds) if k == decoder.MAMBA1)
+    for i, kind in enumerate(kinds):
+        row = decoder.MIXERS[kind]
+        if kind in (decoder.MAMBA1, decoder.GMU):
+            assert (kind == decoder.MAMBA1) == (i <= last_scan)
+            assert not (row.windowed or row.hands_on_kv or row.reads_index)
+            continue
+        assert row.reads_index
+        assert row.windowed == (i < last_scan)
+        assert row.hands_on_kv == (i == last_scan + 1)
+        assert (kind == decoder.DIFF_CROSS) == (i > last_scan + 1)
+
+
+# name on ray_tpu.models.decoder -> (what the benchmark hands it by position,
+# under the benchmark's names for them; the family whose tiny program calls
+# it)
+FROZEN = {
+    "ssm_scan": (("x", "dt", "a", "B", "C", "D", "chunk", "init"), "hybrid"),
+    "selective_scan": (("x", "dt", "A", "B", "C", "D", "init"), "sambay"),
+    "flash_attention": (("q", "k", "v", "causal", "sm_scale"), "gpt"),
+    "gated_delta_rule": (("q", "k", "v", "g", "beta", "chunk", "init"),
+                         "olmo_hybrid"),
+    "_unit_heads": (("t", "heads", "scale", "eps"), "olmo_hybrid"),
+    "gmu": (("x", "layer", "dec", "m"), "sambay"),
+    "differential_maps": (("q", "k", "v", "layer", "dec", "index", "window"),
+                          "sambay"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FROZEN))
+def test_the_program_calls_the_frozen_name_through_the_module(name,
+                                                              monkeypatch):
+    """The benchmark's `planted` swaps the name on the module and traces
+    the program; `hold_kernels` calls it by position. Both happen on the
+    chip only. Here: the name is there, takes those positions, and a swap
+    is seen by a program traced after it."""
+    parameters, family = FROZEN[name]
+    real = getattr(decoder, name)
+    inspect.signature(real).bind(*parameters)      # by position, that many
+    config, loss = FAMILIES[family]
+    cfg = config.tiny()
+    seen = []
+
+    def swapped(*args, **kwargs):
+        seen.append(len(args))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(decoder, name, swapped)
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    jax.eval_shape(lambda params, tok: loss(params, (tok, tok), cfg),
+                   jax.eval_shape(cfg.init, jax.random.PRNGKey(0)), tok)
+    assert seen and min(seen) >= len(parameters), (name, seen)
+
+
+def test_generate_names_no_family():
+    import ray_tpu.models as models
+    families = {getattr(models, name) for name in (
+        "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid")}
+    held = {v for v in vars(generate).values() if inspect.ismodule(v)}
+    assert not held & families
+    assert "cache_layers" not in inspect.getsource(generate)

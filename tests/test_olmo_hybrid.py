@@ -174,7 +174,8 @@ def test_the_norms_sit_where_the_weights_say():
     # by hand, the post-norm block of the last layer
     layer, dec = params["layers"][3], cfg.decoder()
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 16, cfg.d_model))
-    got = decoder._block(x, layer, None, None, dec=dec)[0]
+    got = decoder._block(x, layer, None, None, dec=dec,
+                         kind=dec.kinds[3])[0]
     a, _ = decoder.attention(x, layer, dec)
     h = x + decoder.rms_norm(a, layer["post_attention"], cfg.norm_eps)
     out, _ = dec.mlp(h, layer)

@@ -480,8 +480,9 @@ def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
     dec = cfg.decoder()
     b, s, d, k = 2, 128, cfg.d_model, cfg.experts_per_token
     layer = moe_init(jax.random.PRNGKey(0), cfg)["layers"][0]
-    block = jax.checkpoint(functools.partial(decoder._block, dec=dec),
-                           policy=dec.remat)
+    block = jax.checkpoint(
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        policy=dec.remat)
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
                           jnp.ones((b, s, d), dtype), layer)
     # a line: `bf16[256,64] from the argument x`, `... named 'n' from f.py`

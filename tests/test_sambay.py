@@ -207,10 +207,11 @@ def test_layer_norm_is_the_definition():
 def test_other_families_carry_nothing_forward():
     """A stack with no Mamba-1 and no differential layer hands `Shared()`
     through untouched and windows nothing."""
-    from ray_tpu.models.hybrid import HybridConfig, hybrid_init
-    cfg = HybridConfig.tiny()
-    params = hybrid_init(jax.random.PRNGKey(0), cfg)
-    assert not any(decoder._is_mamba1(layer) for layer in params["layers"])
+    from ray_tpu.models.hybrid import HybridConfig
+    kinds = HybridConfig.tiny().decoder().kinds
+    rows = [decoder.MIXERS[kind] for kind in kinds]
+    assert decoder.MAMBA1 not in kinds
+    assert not any(row.windowed or row.hands_on_kv for row in rows)
     assert decoder.Shared() == (None, None, None)
 
 
@@ -229,8 +230,9 @@ def test_a_mamba1_block_keeps_the_scan_kernels_outputs_and_nothing_else(
     cfg = dataclasses.replace(SambaYConfig.tiny(8), dtype=jnp.float32)
     dec = cfg.decoder()
     layer = sambay_init(jax.random.PRNGKey(0), cfg)["layers"][0]
-    block = jax.checkpoint(functools.partial(decoder._block, dec=dec),
-                           policy=dec.remat)
+    block = jax.checkpoint(
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        policy=dec.remat)
     b, s = 2, 128
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
                           jnp.ones((b, s, cfg.d_model)), layer)
