@@ -736,9 +736,15 @@ def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
 # layer being differentiated: keeping everything does not fit at OLMoE's
 # 16,384 tokens (PERF.md §6, PR 28).
 # A held share of two-matrix experts (parallel/moe.py `_held_experts`)
-# keeps the router's scores `moe_probs` alone and makes its dispatched
-# rows and its one pre-activation again: its buffers are the worst case's,
-# eight times a balanced share's rows, and do not fit kept.
+# keeps the router's scores `moe_probs` alone. Its buffers hold a balanced
+# share's rows and an eighth, and its rule walks them in as many passes as
+# the routing needs, which no trace knows: so the rule's residuals are its
+# inputs, it makes a pass's dispatched rows and one pre-activation again
+# itself (a gather of 13,824 rows and a grouped matmul over the held ones
+# at 16,384 tokens of Nemotron-3-Nano), and the block's second forward of
+# the experts is dead code. One pass's rows kept would be 74 + 51 MB a
+# layer; keeping them where one pass is all is not done here (XLA gives
+# the cell's step 12.46 GB with none kept; PERF.md section 7, PR 46).
 # Of a Mamba-2 layer, by the same rule: what the scan kernel made and a
 # backward pass reads, the state each chunk left (its backward kernel's)
 # and y (the gated norm's, after it) (ops/ssm_scan.py; 0.13 GB each a

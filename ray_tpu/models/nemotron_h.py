@@ -319,8 +319,10 @@ def nemotron_h_loss_and_counters(params: Dict, batch: Tuple,
     train step's). Counters: `router_bias` [E layers, n_experts] (what the
     biases came to: the next step's), `expert_tokens` [E layers,
     n_experts], `expert_rows_held` [E layers] (the rows the held experts
-    saw), `expert_load_max_over_mean` (over every router output of every
-    layer), `router_bias_abs_max`, `balance_loss`."""
+    saw), `expert_passes` [E layers] (the passes they took of the layer's
+    buffers: 1 while the routing is balanced), `expert_load_max_over_mean`
+    (over every router output of every layer), `router_bias_abs_max`,
+    `balance_loss`."""
     if held is not None:
         params = with_bias(params, held, cfg)
     tokens, targets = batch
@@ -339,6 +341,7 @@ def nemotron_h_loss_and_counters(params: Dict, batch: Tuple,
             "router_bias": per_layer["router_bias"],
             "expert_tokens": counts,
             "expert_rows_held": per_layer["expert_rows_held"],
+            "expert_passes": per_layer["expert_passes"],
             "expert_load_max_over_mean":
                 jnp.max(counts) / jnp.mean(counts.astype(jnp.float32)),
             "router_bias_abs_max": jnp.max(jnp.abs(per_layer["router_bias"])),

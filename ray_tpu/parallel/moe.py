@@ -17,23 +17,25 @@ Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Three layers:
   (sigmoid scores, a selection bias no gradient sees, the k weights
   renormalised and scaled: DeepSeek-V3's router), computes the part of
   the result its own experts give, two matrices an expert with relu^2
-  between, drops nothing at any routing, and adds a shared expert every
-  token passes. `balance_bias` runs the bias's own rule to its fixed
-  point. What models/nemotron_h.py runs.
+  between, in buffers of a balanced share's rows and an eighth
+  (`held_rows_plan`) that it walks in as many passes as the held rows
+  take, so it drops nothing at any routing, and adds a shared expert
+  every token passes. `balance_bias` runs the bias's own rule to its
+  fixed point. What models/nemotron_h.py runs.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.grouped_matmul import (grouped_matmul, grouped_matmul_grads,
-                                  past_groups_zeroed)
+from ..ops.grouped_matmul import (_TILES, grouped_matmul,
+                                  grouped_matmul_grads, past_groups_zeroed)
 
 
 def top2_gating(logits, capacity: int):
@@ -369,60 +371,164 @@ def balance_bias(scores, k: int, rounds: int = 256, start=None):
     return bias - jnp.mean(bias)
 
 
-def _sum_back_held(rows, inv, k: int, counts):
-    """`_sum_back` of the rows the held experts made: an assignment whose
-    sorted place lies past the held experts' `counts` adds nothing (its
-    row was never written)."""
-    held = inv.reshape(-1, k, 1) < jnp.sum(counts)
-    per_token = _rows(rows, inv).reshape(-1, k, rows.shape[-1])
-    return jnp.sum(jnp.where(held, per_token.astype(jnp.float32), 0.0),
-                   axis=1).astype(rows.dtype)
+# A held share's buffers hold this much above a balanced share's rows. Under
+# the bias's rounds, which run before a step routes, a layer's held rows read
+# 0.9932-1.0088 of the balanced count in every one of 170 steps x 4 layers x
+# 6 seeds of Nemotron-3-Nano at 16,384 tokens (PERF.md section 6, PR 45): an
+# eighth is ten times that, and what lies above it takes a second pass.
+_HELD_ROWS_HEADROOM = 8           # one part in this many
 
 
-def _held_experts_fwd(x, weights, w_up, w_down, perm, inv, counts):
+class HeldRowsPlan(NamedTuple):
+    rows: int        # a pass's rows: what each buffer of the held share holds
+    balanced: int    # the held experts' rows under an even routing
+    tile: int        # grouped_matmul's row tile: `rows` is so many, or T * k
+
+
+def held_rows_plan(tokens: int, k: int, held: int,
+                   experts: int) -> HeldRowsPlan:
+    """The rows `held_moe_layer` gives its buffers for `tokens` tokens of k
+    assignments each where a chip holds `held` of `experts` experts: the
+    balanced share and an eighth, rounded up to grouped_matmul's row tile,
+    and never more than the tokens * k there are (all experts held, or a
+    decode step's few tokens: one pass covers any routing)."""
+    tile = _TILES[0]
+    balanced = -(-tokens * k * held // experts)
+    rows = balanced + -(-balanced // _HELD_ROWS_HEADROOM)
+    return HeldRowsPlan(min(-(-rows // tile) * tile, tokens * k), balanced,
+                        tile)
+
+
+def _windows(rows: int, weights, perm, inv, counts):
+    """window(p) -> pass p of the held order, its places [p * rows, (p + 1)
+    * rows): (the token of each place, its router weight, the held experts'
+    rows inside the window: their cumulative ends less the window's start,
+    clipped to it). The T*k tokens and weights in sorted order are made
+    once, with zeros after them up to a whole number of passes, so that a
+    window cut from them (`lax.dynamic_slice`) is never slid back to fit."""
     k = weights.shape[1]
+    pad = (0, -perm.shape[0] % rows)
+    token_of = jnp.pad(perm // k, pad)
+    w_sorted = jnp.pad(_permuted(weights.reshape(-1), inv), pad)
+
+    def window(p):
+        ends = jnp.cumsum(counts) - p * rows
+        sizes = jnp.clip(ends, 0, rows) - jnp.clip(ends - counts, 0, rows)
+        return (lax.dynamic_slice_in_dim(token_of, p * rows, rows),
+                lax.dynamic_slice_in_dim(w_sorted, p * rows, rows), sizes)
+
+    return window
+
+
+def _added_back(acc, made, tokens, sizes):
+    """`acc` [T, d] float32 with every held row of `made` [R, d] added to
+    its token's: `made` is what a kernel wrote for a window, `tokens` [R]
+    the token of each place, the first sum(sizes) of them held; a place
+    past them is sent out of bounds and dropped (what lies there was never
+    written). A scatter-add of R rows into the T, where `_sum_back` is a
+    gather: the held rows of a token are not k and not next to each other,
+    and of three forms at Nemotron-3-Nano's 16,384 tokens (a layer forward
+    and backward, 59.3 ms with T*k-row buffers) this one took 44.8 ms, k
+    masked gathers of T rows 45.4, and the rows sorted by token, summed
+    along their runs by shifted adds and gathered once 51.9: XLA writes
+    each shifted [R, d] slice out (my chip runs, PR 46; PERF.md section
+    6). The rows are added in the order of their places."""
+    held = jnp.arange(made.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
+    return acc.at[jnp.where(held, tokens, acc.shape[0])].add(
+        made.astype(acc.dtype), mode="drop")
+
+
+def _held_passes(counts, rows: int):
+    """How many passes of `rows` the held experts' `counts` take."""
+    return (jnp.sum(counts) + rows - 1) // rows
+
+
+def _first_and_rest(p, first, rest, made):
+    """A weight gradient over the passes as (pass 0's, as its kernel wrote
+    it; the later passes' summed in float32). Where one pass is all, which
+    is every step of a balanced routing, nothing is added and nothing
+    converted: summed from zeros in float32 the two expert tensors cost
+    5.0 ms a layer at Nemotron-3-Nano's shapes (my chip run, PR 46)."""
+    return lax.cond(p == 0, lambda: (made, rest),
+                    lambda: (first, rest + made.astype(rest.dtype)))
+
+
+def _over_passes(passes, first, rest):
+    """`_first_and_rest`'s two parts as one gradient in `first`'s dtype."""
+    return lax.cond(
+        passes > 1,
+        lambda: (first.astype(rest.dtype) + rest).astype(first.dtype),
+        lambda: first)
+
+
+def _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv, counts):
+    f32 = jnp.float32
     with jax.named_scope("moe_route"):
-        xs = _spread(x, perm, k)                              # [T*k, d]
-        w_sorted = _permuted(weights.reshape(-1), inv)        # [T*k]
-    up = grouped_matmul(xs, w_up, counts)
-    hidden = past_groups_zeroed(
-        jnp.square(jax.nn.relu(up.astype(jnp.float32))) * w_sorted[:, None],
-        counts).astype(x.dtype)
-    ys = grouped_matmul(hidden, w_down, counts)               # [T*k, d]
-    with jax.named_scope("moe_combine"):
-        out = _sum_back_held(ys, inv, k, counts)
-    # Nothing here is named for a rematerialised block to keep, where
-    # `_experts` names three: these buffers are the worst case's, eight
-    # times a balanced share's rows (`xs` 528 MB and `up` 365 MB a layer
-    # at 16,384 tokens of Nemotron-3-Nano), and making them again costs a
-    # gather and one grouped matmul over the held rows alone. With both
-    # kept XLA gives the cell's step 16.75 GB, with `up` 16.09, with
-    # neither 14.68 of a chip's 15.75 (PERF.md section 6, PR 45).
-    return out, (xs, up, w_sorted, w_up, w_down, perm, inv, counts)
+        window = _windows(rows, weights, perm, inv, counts)
+
+    def one_pass(p, out):
+        tokens, w, sizes = window(p)
+        with jax.named_scope("moe_route"):
+            xs = _rows(x, tokens)                             # [R, d]
+        up = grouped_matmul(xs, w_up, sizes)
+        hidden = past_groups_zeroed(
+            jnp.square(jax.nn.relu(up.astype(f32))) * w[:, None],
+            sizes).astype(x.dtype)
+        ys = grouped_matmul(hidden, w_down, sizes)            # [R, d]
+        with jax.named_scope("moe_combine"):
+            return _added_back(out, ys, tokens, sizes)
+
+    out = lax.fori_loop(0, _held_passes(counts, rows), one_pass,
+                        jnp.zeros(x.shape, f32))
+    # The residuals are the inputs: how many passes ran is data, so no
+    # pass's `xs` or `up` can be handed on, and the backward rule makes
+    # them again, a gather of R rows and one grouped matmul over the held
+    # rows. A rematerialised block's second forward is then dead code.
+    return out.astype(x.dtype), (x, weights, w_up, w_down, perm, inv, counts)
 
 
-def _held_experts_bwd(residuals, dout):
-    xs, up, w_sorted, w_up, w_down, perm, inv, counts = residuals
-    k, f32 = inv.shape[0] // dout.shape[0], jnp.float32
-    dys = _spread(dout, perm, k)              # the combine is a plain sum
-    w = w_sorted[:, None]
-    # Every value made from a kernel's output is zeroed past the held
-    # rows in the pass that makes it: what lies there was never written.
-    act = jax.nn.relu(past_groups_zeroed(up, counts).astype(f32))
-    dhidden, dw_down = grouped_matmul_grads(
-        (jnp.square(act) * w).astype(dout.dtype), w_down, counts, dys)
-    dhidden = past_groups_zeroed(dhidden, counts).astype(f32)
-    dw_sorted = jnp.sum(dhidden * jnp.square(act), axis=-1)
-    dup = dhidden * w * (2.0 * act)
-    dxs, dw_up = grouped_matmul_grads(xs, w_up, counts,
-                                      dup.astype(dout.dtype))
-    dx = _sum_back_held(dxs, inv, k, counts)
-    dweights = _permuted(dw_sorted, perm).reshape(-1, k)
-    return dx, dweights, dw_up, dw_down, None, None, None
+def _held_experts_bwd(rows, residuals, dout):
+    x, weights, w_up, w_down, perm, inv, counts = residuals
+    k, f32 = weights.shape[1], jnp.float32
+    passes = _held_passes(counts, rows)
+    window = _windows(rows, weights, perm, inv, counts)
+
+    def one_pass(p, carry):
+        dx, dw_sorted, dw_up, dw_down = carry
+        tokens, w, sizes = window(p)
+        w = w[:, None]
+        xs = _rows(x, tokens)
+        dys = _rows(dout, tokens)             # the combine is a plain sum
+        # Every value made from a kernel's output is zeroed past the held
+        # rows in the pass that makes it: what lies there was never
+        # written.
+        up = grouped_matmul(xs, w_up, sizes)
+        act = jax.nn.relu(past_groups_zeroed(up, sizes).astype(f32))
+        dhidden, ddown = grouped_matmul_grads(
+            (jnp.square(act) * w).astype(dout.dtype), w_down, sizes, dys)
+        dhidden = past_groups_zeroed(dhidden, sizes).astype(f32)
+        dw = jnp.sum(dhidden * jnp.square(act), axis=-1)
+        dup = dhidden * w * (2.0 * act)
+        dxs, dup_w = grouped_matmul_grads(xs, w_up, sizes,
+                                          dup.astype(dout.dtype))
+        return (_added_back(dx, dxs, tokens, sizes),
+                lax.dynamic_update_slice_in_dim(dw_sorted, dw, p * rows, 0),
+                _first_and_rest(p, *dw_up, dup_w),
+                _first_and_rest(p, *dw_down, ddown))
+
+    dx, dw_sorted, dw_up, dw_down = lax.fori_loop(
+        0, passes, one_pass,
+        (jnp.zeros(x.shape, f32),
+         jnp.zeros(-(-perm.shape[0] // rows) * rows, f32),   # whole passes
+         (jnp.zeros_like(w_up), jnp.zeros(w_up.shape, f32)),
+         (jnp.zeros_like(w_down), jnp.zeros(w_down.shape, f32))))
+    dweights = _permuted(dw_sorted[:perm.shape[0]], perm).reshape(-1, k)
+    return (dx.astype(x.dtype), dweights, _over_passes(passes, *dw_up),
+            _over_passes(passes, *dw_down), None, None, None)
 
 
-@jax.custom_vjp
-def _held_experts(x, weights, w_up, w_down, perm, inv, counts):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(rows, x, weights, w_up, w_down, perm, inv, counts):
     """`_experts` for the experts a chip holds, two matrices each: `perm`
     sorts the T*k assignments by held expert with those of absent experts
     last, `counts` [held] are the held experts' rows, and
@@ -430,13 +536,18 @@ def _held_experts(x, weights, w_up, w_down, perm, inv, counts):
         ys[i]  = (w[perm[i]] * relu(xs[i] U[e])^2) D[e]    for i < sum(counts)
         out[t] = sum over t's held assignments of ys[inv[t*k + j]]
 
-    The buffers are the worst case's, T*k rows (every assignment held), so
-    nothing is dropped at any routing; the grouped matmuls visit only the
-    row tiles a group covers, so the absent rows cost no matmul (the
-    gathers and element-wise passes do run over all T*k). Two grouped
-    matmuls forward, four backward, one rule, `xs` and `up` its residuals
-    as `_experts` has them."""
-    return _held_experts_fwd(x, weights, w_up, w_down, perm, inv, counts)[0]
+    The buffers hold `rows` rows (`held_rows_plan`: a balanced share and
+    an eighth), and the held order is walked in passes of that many, as
+    many as sum(counts) takes: one at a balanced routing, T*k / rows when
+    every assignment is held, none when none is. Nothing is dropped at
+    any routing, and no value is made over T*k rows but the index vectors.
+    A pass gathers its rows from the tokens', runs two grouped matmuls
+    forward and four backward (its `xs` and `up` made again: one more
+    forward), and adds its rows to their tokens' in float32; the weights'
+    gradients are summed over the passes in float32. One rule, its
+    residuals its inputs."""
+    return _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv,
+                             counts)[0]
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -466,10 +577,13 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
     with none (a cache's forward) it is used as given. What the absent
     experts would add is left out; the shared expert's part is what every
     chip computes alike. `stats` besides: `expert_tokens` [E] int32 over
-    all E (they sum to T*k), `expert_rows_held` the held experts' sum and
-    `router_prob_sum` [E] (sum over tokens of s / sum_E s: the
-    load-balancing loss's probabilities)."""
+    all E (they sum to T*k), `expert_rows_held` the held experts' sum,
+    `expert_passes` int32 (the passes that sum took of the buffers'
+    `held_rows_plan` rows: 1 at a balanced routing) and `router_prob_sum`
+    [E] (sum over tokens of s / sum_E s: the load-balancing loss's
+    probabilities)."""
     t, k, held = x.shape[0], experts_per_token, w_up.shape[0]
+    rows = held_rows_plan(t, k, held, router_w.shape[-1]).rows
     with jax.named_scope("moe_route"):
         # Kept under remat like the softmax router's probabilities, and
         # for the same reason (`dropless_moe_layer`).
@@ -490,13 +604,15 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
         _, perm = lax.sort((local, iota), num_keys=1, is_stable=True)
         _, inv = lax.sort((perm, iota), num_keys=1)
         held_counts = lax.slice_in_dim(counts, first, first + held)
-    out = _held_experts(x, weights, w_up, w_down, perm, inv, held_counts)
+    out = _held_experts(rows, x, weights, w_up, w_down, perm, inv,
+                        held_counts)
     with jax.named_scope("moe_shared"):
         hidden = jnp.square(jax.nn.relu(jnp.dot(
             x, shared_up, preferred_element_type=jnp.float32)))
         out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
     stats = {"expert_tokens": counts,
              "expert_rows_held": jnp.sum(held_counts),
+             "expert_passes": _held_passes(held_counts, rows),
              "router_prob_sum": jnp.sum(
                  scores / jnp.sum(scores, axis=-1, keepdims=True), axis=0),
              "router_bias": bias}

@@ -176,7 +176,8 @@ DEVICE_SCOPES: Dict[str, str] = {
                  "of the rows and the weights in sorted order",
     "moe_combine": "parallel/moe.py _experts and _held_experts: the "
                    "experts' weighted rows back in token order and their "
-                   "sum",
+                   "sum (a held share: a pass's rows added to their "
+                   "tokens')",
     "moe_shared": "parallel/moe.py held_moe_layer: the shared expert every "
                   "token passes, two plain matmuls with relu^2 between",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "

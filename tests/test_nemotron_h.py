@@ -29,7 +29,7 @@ from ray_tpu.ops.grouped_matmul import (grouped_matmul, grouped_matmul_grads,
 from ray_tpu.ops.layers import gated_rms_norm, rms_norm
 from ray_tpu.ops.ssm_scan import ssm_scan, ssm_scan_plan
 from ray_tpu.parallel.moe import (balance_bias, held_moe_layer,
-                                  router_scores)
+                                  held_rows_plan, router_scores)
 
 TOL = 1e-4
 
@@ -225,16 +225,22 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(form):
 
 
 @pytest.mark.parametrize("routing", ["one_held", "none_held", "all_held",
-                                     "seeded"])
+                                     "seeded", "just_over_a_pass"])
 def test_no_assignment_is_dropped_at_any_routing(form, routing):
-    """All tokens to one held expert (and five absent), to none, every
-    assignment held, and a seeded spread: output and every gradient equal
-    the reference's under the same bias."""
-    w = _layer_weights(seed=1)
-    first, held, E, k = 4, 4, 16, 3
+    """All tokens to one held expert (and two absent), to none, every
+    assignment held, a seeded spread, and all to one held expert with a
+    few to the others besides: output and every gradient equal the
+    reference's under the same bias, in as many passes of the buffers'
+    rows as the held rows take (480 tokens x 3 over 4 of 16 experts:
+    buffers of 512 rows, not 1,440)."""
+    T, first, held, E, k = 480, 4, 4, 16, 3
+    w = _layer_weights(seed=1, T=T)
+    R = held_rows_plan(T, k, held, E).rows
+    assert R == 512 < T * k
     absent = [e for e in range(E) if not first <= e < first + held]
     push = {"one_held": [first + 1] + absent[:2], "none_held": absent[:3],
-            "all_held": [first, first + 1, first + 3], "seeded": []}[routing]
+            "all_held": [first, first + 1, first + 3], "seeded": [],
+            "just_over_a_pass": [first + 1, absent[0]]}[routing]
     bias = w["bias"].at[jnp.array(push, jnp.int32)].add(10.0)
 
     def program(x, router, up, down, s_up, s_down):
@@ -260,11 +266,32 @@ def test_no_assignment_is_dropped_at_any_routing(form, routing):
     _close(out, want)
     for got, wanted in zip(grads, want_grads):
         _close(got, wanted)
-    rows = {"one_held": 64, "none_held": 0, "all_held": 64 * 3}.get(routing)
-    in_share = (chosen >= first) & (chosen < first + held)
-    assert int(stats["expert_rows_held"]) == int(jnp.sum(in_share))
+    in_share = int(jnp.sum((chosen >= first) & (chosen < first + held)))
+    assert int(stats["expert_rows_held"]) == in_share
+    rows, passes = {"one_held": (T, 1), "none_held": (0, 0),
+                    "all_held": (T * k, -(-T * k // R)), "seeded": (None, 1),
+                    "just_over_a_pass": (None, 2)}[routing]
     if rows is not None:
-        assert int(stats["expert_rows_held"]) == rows
+        assert in_share == rows
+    if routing == "just_over_a_pass":
+        assert R < in_share < R + R // 4
+    assert int(stats["expert_passes"]) == passes == -(-in_share // R)
+
+
+@pytest.mark.parametrize("tokens,k,held,experts,rows,balanced", [
+    (16384, 6, 16, 128, 13824, 12288),  # the cell's: 27 tiles, not 192
+    (16384, 6, 128, 128, 98304, 98304),     # every expert held: T x k
+    (2048, 6, 16, 128, 2048, 1536),         # 1,728 rounded up to the tile
+    (480, 3, 4, 16, 512, 360),
+    (64, 3, 4, 16, 192, 48),                # under a tile: T x k
+    (1, 6, 16, 128, 6, 1), (3, 6, 16, 128, 18, 3)])     # a decode step's
+def test_the_buffers_rows_come_from_the_shapes(tokens, k, held, experts,
+                                               rows, balanced):
+    plan = held_rows_plan(tokens, k, held, experts)
+    assert plan == (rows, balanced, 512)
+    assert rows <= tokens * k
+    assert rows == tokens * k or (
+        rows % plan.tile == 0 and 8 * rows >= 9 * balanced)
 
 
 def test_the_bias_picks_and_never_weighs():
@@ -559,6 +586,8 @@ def test_the_bias_is_state_the_optimizer_does_not_own(tiny):
         float(jnp.max(jnp.abs(new["held"]))))
     assert int(metrics["expert_rows_held"].sum()) == int(
         counts[:, cfg.held[0]:sum(cfg.held)].sum())
+    # 64 tokens x 3: the buffers hold them all, one pass a layer
+    np.testing.assert_array_equal(metrics["expert_passes"], [1, 1])
     # the counters the loss gave are the loss function's own
     _, counters = nemotron_h_loss_and_counters(params, batch, cfg)
     np.testing.assert_array_equal(metrics["expert_tokens"],
