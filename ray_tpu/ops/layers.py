@@ -179,10 +179,14 @@ def head_sums(x, heads: int):
     """x [..., heads * W] float32 -> [..., heads]: the sum over each
     head's W columns, as a product with a 0/1 matrix. The plain form, a
     reshape to [..., heads, W] and a sum, makes XLA:TPU lay the array out
-    again wherever W is no multiple of 128 lanes (96 and 192 are not: the
-    two norms of a delta-rule layer took 16 and 30 ms a layer and step at
-    16,384 tokens that way, PERF.md section 6, PR 41); this one stays in
-    the projections' own layout."""
+    again whatever W is: the heads axis becomes the second-minor one, so
+    the rows that lay on the sublanes of an (8, 128) tile give way to the
+    heads, and every element moves. It is not a matter of W being off the
+    128 lanes: at 96 and 192 the two norms of a delta-rule layer took 16
+    and 30 ms a layer and step at 16,384 tokens that way (PERF.md section
+    6, PR 41), and at 512, four whole tiles, Mamba-2's gated norm over
+    eight groups took 10.8 ms a layer and step where this form takes 5.6
+    (PR 47). This one stays in the projections' own layout."""
     return jnp.einsum("...e,eh->...h", x, _head_indicator(x.shape[-1], heads),
                       precision=jax.lax.Precision.HIGHEST)
 
@@ -211,11 +215,15 @@ def head_rms_norm_gated(o, gate, weight, eps: float = NORM_EPS):
 def gated_rms_norm(y, gate, weight, eps: float = NORM_EPS, groups: int = 1):
     """Mamba-2's output norm: the gate first, then an RMSNorm over each of
     `groups` equal runs of channels (one group: ONE norm over all of
-    them), rmsnorm_g(y * silu(gate)) * weight, one weight a channel."""
+    them), rmsnorm_g(y * silu(gate)) * weight, one weight a channel.
+    Several groups never get an axis of their own (`head_sums` tells
+    why): a group's mean of squares and its root's way back onto the
+    channels are the two 0/1 products, in y's own layout."""
     gated = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
     if groups == 1:
         return rms_norm(gated, weight, eps).astype(y.dtype)
-    runs = gated.reshape(*gated.shape[:-1], groups, -1)
-    var = jnp.mean(jnp.square(runs), axis=-1, keepdims=True)
-    normed = (runs * jax.lax.rsqrt(var + eps)).reshape(gated.shape)
-    return (normed * weight.astype(jnp.float32)).astype(y.dtype)
+    width = gated.shape[-1]
+    inv = jax.lax.rsqrt(head_sums(jnp.square(gated), groups)
+                        / (width // groups) + eps)
+    return (gated * head_spread(inv, width)
+            * weight.astype(jnp.float32)).astype(y.dtype)

@@ -150,6 +150,60 @@ def test_gated_norm_norms_each_group_alone():
     assert not np.allclose(got, gated_rms_norm(y, z, w, 1e-5), atol=1e-2)
 
 
+def _group_norm_by_reshape(y, gate, weight, groups, eps):
+    """The plain form, in float32: a groups axis, a mean, a root."""
+    f32 = jnp.float32
+    g = (y.astype(f32) * jax.nn.silu(gate.astype(f32))).reshape(
+        *y.shape[:-1], groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
+    return g.reshape(y.shape) * weight.astype(f32)
+
+
+def _norm_inputs(groups, run, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(groups * 1000 + run), 4)
+    shape = (2, 24, groups * run)
+    y, gate, cot = (jax.random.normal(k, shape).astype(dtype)
+                    for k in ks[:3])
+    return y, gate, 1.0 + 0.1 * jax.random.normal(ks[3], shape[-1:]), cot
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("run", [32, 96, 512])
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_gated_norm_against_the_reshaped_form(groups, run, dtype):
+    """The value and the gradients by y, gate and weight: float32 inputs
+    to 1e-5 of the largest value, bfloat16 inputs (the weight stays
+    float32, as a model's is) to one bfloat16 rounding of each value."""
+    y, gate, weight, cot = _norm_inputs(groups, run, dtype)
+    f32 = jnp.float32
+
+    def every(fn, *given):
+        out, vjp = jax.vjp(fn, *given)
+        return (out,) + vjp(cot.astype(out.dtype))
+
+    got = every(lambda *t: gated_rms_norm(*t, 1e-5, groups), y, gate, weight)
+    want = every(lambda *t: _group_norm_by_reshape(*t, groups, 1e-5),
+                 y.astype(f32), gate.astype(f32), weight)
+    assert [t.dtype for t in got] == [dtype, dtype, dtype, f32]
+    for a, b in zip(got, want):
+        b = np.asarray(b)
+        rounding = 2.0 ** -8 * np.abs(b) if a.dtype == jnp.bfloat16 else 0.0
+        np.testing.assert_array_less(
+            np.abs(np.asarray(a.astype(f32)) - b),
+            rounding + 1e-5 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("width", [32, 96, 512])
+def test_gated_norm_of_one_group_is_rms_norm_to_the_bit(width):
+    y, gate, weight, _ = _norm_inputs(1, width, jnp.bfloat16)
+    gated = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    np.testing.assert_array_equal(
+        np.asarray(gated_rms_norm(y, gate, weight, 1e-5).astype(jnp.float32)),
+        np.asarray(rms_norm(gated, weight, 1e-5).astype(y.dtype)
+                   .astype(jnp.float32)))
+
+
 # ---------------------------------------------------------------------------
 # grouped matmuls whose sizes sum to less than their rows
 # ---------------------------------------------------------------------------
