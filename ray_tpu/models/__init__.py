@@ -20,6 +20,15 @@ from .hybrid import (  # noqa: F401
     hybrid_param_axes,
     make_hybrid_train_step,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig,
+    lfm2_moe_forward,
+    lfm2_moe_init,
+    lfm2_moe_loss,
+    lfm2_moe_loss_and_counters,
+    lfm2_moe_param_axes,
+    make_lfm2_moe_train_step,
+)
 from .llama import (  # noqa: F401
     LlamaConfig,
     llama_forward,

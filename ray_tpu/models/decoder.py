@@ -1,10 +1,13 @@
-"""The decoder's compute, written once: what the seven families of
+"""The decoder's compute, written once: what the eight families of
 ray_tpu.models train and what models.generate prefills and decodes.
 
 A family says what it is with a `Decoder`, which its config's `decoder()`
 builds from the fields it already has: `kinds`, the kind of every layer
 in order (from `n_layers`, `layer_types` or `layer_kinds`; nobody sets it);
-the attention's head counts, its channel mixer, its remat policy, the rope
+`mlp`, the channel mixer of every layer in order, as long as `kinds` (the
+one function a layer in seven families; LFM2's dense SwiGLU in its leading
+layers and its held experts after them); the attention's head counts, its
+remat policy, the rope
 base (None: no positions at all), the norm eps, the score scale and the
 sizes of a state-space mixer where it names them (ops.layers' own,
 1/sqrt(head_dim), none where it does not), and what its embedding, its two
@@ -13,8 +16,8 @@ nothing). Nothing here reads a config. A model's layers need not be alike:
 a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
 runs it, what state it keeps in a cache, whether it is windowed, hands its
 keys and values on, or reads its place in the stack, and which branches
-its block has: a sequence mixer, the channel mixer `dec.mlp`, or both. A
-new sequence mixer is its function and its row. The eleven kinds:
+its block has: a sequence mixer, the layer's channel mixer, or both. A
+new sequence mixer is its function and its row. The twelve kinds:
 
     ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
                    max_len, head_dim]}
@@ -27,6 +30,8 @@ new sequence mixer is its function and its row. The eleven kinds:
     GATED_DELTA    the gated delta rule; {"conv": [batch, taps - 1, heads
                    x (2 K + V)] the last inputs over q | k | v, "delta":
                    [batch, heads, K, V] float32}, which does not grow
+    SHORT_CONV     the gated short convolution (ops.short_conv); {"conv":
+                   [batch, taps - 1, d] the last rows of B * x}
     GMU            a gated memory unit over `Shared.m`; {}
     DIFF_WINDOWED  differential attention over its own keys and values no
                    further back than `dec.window`; ATTENTION's state
@@ -38,7 +43,7 @@ new sequence mixer is its function and its row. The eleven kinds:
                    ATTENTION's and MAMBA2's mixer and state in a block of
                    that one branch, x + mixer(norm(x))
     EXPERTS        no sequence mixer at all: a block of the channel mixer
-                   alone, x + dec.mlp(norm(x)); {}
+                   alone, x + mlp(norm(x)); {}
 
 Every leaf of a layer's state has the batch first: that is the table's one
 rule (models.generate.make_continuous_fns cuts a slot out of axis 0 of
@@ -50,8 +55,9 @@ What is still read off the weights a layer holds picks no mixer and no
 state: `ln1_b`: LayerNorm with bias where the others have RMSNorm; `ln1`,
 `ln2`: a block that norms what its branches read, x + mixer(norm(x)),
 `post_attention`, `post_feedforward` and neither of those: one that norms
-what they return, x + norm(mixer(x)); `wqkv` or `wq` + `wkv`, `q_norm` or
-not, inside `attention`.
+what they return, x + norm(mixer(x)); `wqkv` or `wq` + `wkv`, `q_norm`
+(one norm over all of q's columns), `q_head_norm` (one over each head's)
+or neither, inside `attention`.
 
 Nor need the layers be independent (SambaY, models.sambay): the stack
 carries two values forward besides x, `Shared`: the scan's output `m` of
@@ -63,8 +69,8 @@ and outputs, and their gradients arrive from every reader.
     decoder_hidden      embedding, layer stack, final norm, head
     decoder_logits      its rows times its head, float32
     empty_cache         each layer's state, by its row
-      attention | mamba2 | mamba1 | gated_delta | gmu | diff_attention
-                        the sequence mixers, (x, layer, dec, cache,
+      attention | mamba2 | mamba1 | gated_delta | short_conv | gmu |
+      diff_attention    the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window, ...]) -> (y,
                         new cache[, shared]): attention is the flash
                         kernel over the whole sequence with no cache, with
@@ -75,11 +81,13 @@ and outputs, and their gradients arrive from every reader.
                         mamba1 the same over ops.selective_scan;
                         gated_delta the same over ops.gated_delta, a matrix
                         state a head that is read back before it is
-                        written; gmu no state at all; diff_attention two
+                        written; short_conv the kernels over the whole
+                        sequence, the shifted products from a cached tail;
+                        gmu no state at all; diff_attention two
                         softmax maps a pair of heads, their difference
                         times both heads' values
       gelu_mlp | swiglu_mlp | fused_swiglu_mlp | routed_experts |
-      held_routed_experts
+      held_routed_experts | held_gated_experts
                         the channel mixers, (y, layer) -> (out, stats or
                         None)
 """
@@ -97,26 +105,30 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
 from ..ops.gated_delta import gated_delta_rule
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
-                          gated_rms_norm, head_rms_norm_gated, head_spread,
-                          head_sums, layer_norm, rms_norm, rope, swiglu)
+                          gated_rms_norm, head_rms_norm, head_rms_norm_gated,
+                          head_spread, head_sums, layer_norm, rms_norm, rope,
+                          swiglu)
 from ..ops.loss import chip_views, lookup
 from ..ops.selective_scan import selective_scan
+from ..ops.short_conv import gated_short_conv
 from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import dropless_moe_layer, held_moe_layer
 
 
 # The kinds of layer: the keys of MIXERS, below the mixers.
 (ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
- DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS) = (
+ DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV) = (
     "attention", "mamba2", "mamba1", "gated_delta", "gmu", "diff_windowed",
-    "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts")
+    "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts",
+    "short_conv")
 
 
 class Decoder(NamedTuple):
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    mlp: Callable                   # (y, layer) -> (out, stats or None)
+    # a layer's channel mixer, in order: (y, layer) -> (out, stats or None)
+    mlp: Tuple[Callable, ...]
     remat: Optional[Callable]       # a jax.checkpoint policy; None: keep all
     kinds: Tuple[str, ...]          # a key of MIXERS a layer, in order
     rope_base: Optional[float] = ROPE_BASE     # None: no rotary
@@ -171,16 +183,36 @@ def routed_experts(y, layer, experts_per_token: int, norm_topk_prob: bool):
 
 def held_routed_experts(y, layer, experts_per_token: int, first: int,
                         routed_scale: float, bias_rounds: int = 0):
-    """One chip's share of top-k relu^2 experts (those from `first` on, as
-    many as the layer holds) and the shared expert, over the flattened
-    tokens, the selection bias moved `bias_rounds` rounds on their scores
-    first; `stats` are parallel.moe.held_moe_layer's, one layer's."""
+    """One chip's share of top-k experts of two matrices with relu^2
+    between (those from `first` on, as many as the layer holds) and the
+    shared expert of the same form, over the flattened tokens, the
+    selection bias moved `bias_rounds` rounds on their scores first;
+    `stats` are parallel.moe.held_moe_layer's, one layer's."""
     b, s, d = y.shape
     out, stats = held_moe_layer(
         y.reshape(b * s, d), layer["router"], layer["router_bias"],
         layer["expert_up"], layer["expert_down"], layer["shared_up"],
         layer["shared_down"], experts_per_token=experts_per_token,
         first=first, routed_scale=routed_scale, bias_rounds=bias_rounds)
+    return out.reshape(b, s, d), stats
+
+
+def held_gated_experts(y, layer, experts_per_token: int, first: int,
+                       routed_scale: float, weight_eps: float,
+                       bias_rounds: int = 0):
+    """One chip's share of top-k SwiGLU experts (those from `first` on, as
+    many as the layer holds; `expert_gate_up` is each one's gate and up
+    matrices side by side) with no shared expert, over the flattened
+    tokens, the k weights over their sum + `weight_eps`, the selection
+    bias moved `bias_rounds` rounds on the tokens' scores first; `stats`
+    are parallel.moe.held_moe_layer's, one layer's."""
+    b, s, d = y.shape
+    out, stats = held_moe_layer(
+        y.reshape(b * s, d), layer["router"], layer["router_bias"],
+        layer["expert_gate_up"], layer["expert_down"],
+        experts_per_token=experts_per_token, first=first,
+        routed_scale=routed_scale, bias_rounds=bias_rounds, gated=True,
+        weight_eps=weight_eps)
     return out.reshape(b, s, d), stats
 
 
@@ -288,6 +320,9 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
     if "q_norm" in layer:           # over all of q and of k, before the split
         q = rms_norm(q, layer["q_norm"], dec.norm_eps)
         k = rms_norm(k, layer["k_norm"], dec.norm_eps)
+    if "q_head_norm" in layer:      # over each head's columns, one [hd] weight
+        q = head_rms_norm(q, layer["q_head_norm"], dec.norm_eps)
+        k = head_rms_norm(k, layer["k_head_norm"], dec.norm_eps)
     # Rotary embeddings at absolute (possibly traced) positions, [L] or
     # [b, L]; with no cache rope counts from 0 itself. A model with no
     # rope base has no positions at all.
@@ -425,6 +460,25 @@ def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
         o = head_rms_norm_gated(o, gate, layer["delta_norm"], dec.norm_eps)
     new_cache = None if cache is None else {"conv": tail, "delta": state}
     return jnp.einsum("bse,ed->bsd", o, layer["delta_out"]), new_cache
+
+
+def short_conv(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """LFM2's gated short convolution of x [b, L, d], from the input norm
+    to the output projection: one projection to B | C | x, the causal
+    depthwise convolution of B * x under `conv_taps` [taps, d] with no
+    bias and no activation, times C (ops.short_conv: two kernels over a
+    whole sequence; the shifted products from a cache's tail, a prefill or
+    a single token alike), the output projection. The state is the last
+    taps - 1 rows of B * x and knows no positions: `start_pos` is not
+    read. Returns (y, new_cache or None)."""
+    y = _norm(x, layer, "ln1", dec.norm_eps)
+    with jax.named_scope("short_conv_proj"):
+        bcx = jnp.einsum("bsd,de->bse", y, layer["conv_in"])
+    mixed, tail = gated_short_conv(
+        bcx, layer["conv_taps"], None if cache is None else cache["conv"])
+    with jax.named_scope("short_conv_proj"):
+        out = jnp.einsum("bsd,de->bse", mixed, layer["conv_out"])
+    return out, None if cache is None else {"conv": tail}
 
 
 class Shared(NamedTuple):
@@ -597,6 +651,11 @@ def _delta_state(dec: Decoder, layer, batch, max_len, dtype):
             "delta": jnp.zeros((batch, H, K, V), jnp.float32)}
 
 
+def _short_conv_state(dec: Decoder, layer, batch, max_len, dtype):
+    taps, d = layer["conv_taps"].shape
+    return {"conv": jnp.zeros((batch, taps - 1, d), dtype)}
+
+
 def _no_state(dec: Decoder, layer, batch, max_len, dtype):
     """A layer that reads what another made, or mixes no sequence."""
     return {}
@@ -612,6 +671,10 @@ def _mamba2(x, layer, dec, cache, start_pos, shared, index, window):
 
 def _gated_delta(x, layer, dec, cache, start_pos, shared, index, window):
     return (*gated_delta(x, layer, dec, cache, start_pos), shared)
+
+
+def _short_conv(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*short_conv(x, layer, dec, cache, start_pos), shared)
 
 
 def _mamba1(x, layer, dec, cache, start_pos, shared, index, window):
@@ -634,7 +697,7 @@ class Mixer(NamedTuple):
     windowed: bool = False      # `window` is dec.window; None elsewhere
     hands_on_kv: bool = False   # its keys and values become Shared.k, v
     reads_index: bool = False   # `index` is its place; 0 elsewhere
-    channel: bool = True        # the block has the branch of `dec.mlp`
+    channel: bool = True        # the block has its channel mixer's branch
 
 
 def _differential(own, windowed=False, hands_on=False) -> Mixer:
@@ -664,8 +727,16 @@ def _differential(own, windowed=False, hands_on=False) -> Mixer:
 #     _unit_heads(t, heads, scale, eps)
 #     gmu(x, layer, dec, m)
 #     differential_maps(q, k, v, layer, dec, index, window)
-# The first six the benchmark also SWAPS on the module (`setattr(decoder,
-# name, faulty)`) and then traces the program, so the program must find
+# and, chipbench/families/nemotron_h.py's and lfm2_moe.py's, four more:
+#     gated_rms_norm(y, gate, weight, eps, groups)
+#     held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
+#                    shared_down, *, experts_per_token, first, routed_scale,
+#                    bias_rounds[, gated, weight_eps])
+#     gated_short_conv(bcx, weight, tail)
+#     head_rms_norm(t, weight, eps)
+# The first six and these four the benchmark also SWAPS on the module
+# (`setattr(decoder, name, faulty)`) and then traces the program, so the
+# program must find
 # them through the module's global name at the time of the call. A row
 # that held the function object `gmu` itself, bound at import, would run
 # the real one under a planted fault, and the fault would read as harmless
@@ -687,6 +758,7 @@ MIXERS: Dict[str, Mixer] = {
     ATTENTION_ONLY: Mixer(_attention, _kv_state, channel=False),
     MAMBA2_ONLY: Mixer(_mamba2, _mamba2_state, channel=False),
     EXPERTS: Mixer(None, _no_state),
+    SHORT_CONV: Mixer(_short_conv, _short_conv_state),
 }
 
 
@@ -695,6 +767,9 @@ def _rows(dec: Decoder, layers) -> List[Mixer]:
     if len(dec.kinds) != len(layers) or set(dec.kinds) - set(MIXERS):
         raise ValueError(f"Decoder.kinds {dec.kinds}: one of {sorted(MIXERS)} "
                          f"for each of the {len(layers)} layers, no other")
+    if len(dec.mlp) != len(layers):
+        raise ValueError(f"Decoder.mlp names {len(dec.mlp)} channel mixers: "
+                         f"one for each of the {len(layers)} layers")
     return [MIXERS[kind] for kind in dec.kinds]
 
 
@@ -763,6 +838,9 @@ def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
 # rule's output o (the gated norm's, after it; 0.19 GB). Its projections,
 # convolution, L2 norms, beta, g, gated norm and output projection are
 # made again; no other [C, C] tile and no V' ever reaches HBM.
+# Of a gated short convolution nothing: its kernels' residuals are their
+# inputs, the [T, 3d] projection, which a rematerialised block makes again
+# with the forward kernel's y (ops/short_conv.py).
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
@@ -779,7 +857,10 @@ def _scaled(t, scale: float):
 
 
 def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
-           dec: Decoder, kind: str, index: int = 0, window=None):
+           dec: Decoder, kind: str, mlp: Optional[Callable] = None,
+           index: int = 0, window=None):
+    """One layer: its kind's sequence mixer and `mlp`, the layer's channel
+    mixer (None where the kind's block has no such branch)."""
     eps, row = dec.norm_eps, MIXERS[kind]
     stats, new_cache = None, cache
     if row.apply is not None:
@@ -788,7 +869,7 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
         x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
                         dec.residual_scale)
     if row.channel:
-        out, stats = dec.mlp(_norm_if_held(x, layer, "ln2", eps), layer)
+        out, stats = mlp(_norm_if_held(x, layer, "ln2", eps), layer)
         out = _norm_if_held(out, layer, "post_feedforward", eps)
         x = x + _scaled(out, dec.residual_scale)
     return x, stats, new_cache, shared
@@ -816,21 +897,22 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     layers = params["layers"]
 
     @functools.cache
-    def block_at(kind: str, index: int, window: Optional[int]):
-        block = functools.partial(_block, dec=dec, kind=kind, index=index,
-                                  window=window)
+    def block_at(kind: str, mlp: Callable, index: int, window: Optional[int]):
+        block = functools.partial(_block, dec=dec, kind=kind, mlp=mlp,
+                                  index=index, window=window)
         if dec.remat is not None and cache is None:    # remat is training's
             block = jax.checkpoint(block, policy=dec.remat)
         return block
 
     per_layer, new_cache, shared = [], [], Shared()
     with jax.named_scope("layers"):
-        for i, (kind, row, layer, cache_layer) in enumerate(zip(
-                dec.kinds, _rows(dec, layers), layers,
+        for i, (kind, row, mlp, layer, cache_layer) in enumerate(zip(
+                dec.kinds, _rows(dec, layers), dec.mlp, layers,
                 cache or [None] * len(layers))):
             # A kind that reads neither its place nor a window runs one
-            # block, traced once a shape.
-            block = block_at(kind, i if row.reads_index else 0,
+            # block a channel mixer, traced once a shape.
+            block = block_at(kind, mlp if row.channel else None,
+                             i if row.reads_index else 0,
                              dec.window if row.windowed else None)
             x, stats, cache_layer, shared = block(
                 x, layer, cache_layer, start_pos, shared)

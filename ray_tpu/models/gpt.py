@@ -54,7 +54,7 @@ class GPTConfig:
         policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
-            head_dim=self.head_dim, mlp=gelu_mlp,
+            head_dim=self.head_dim, mlp=(gelu_mlp,) * self.n_layers,
             remat=policy if self.remat else None,
             kinds=(ATTENTION,) * self.n_layers)
 

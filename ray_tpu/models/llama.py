@@ -53,7 +53,7 @@ class LlamaConfig:
         positions at `rope_base`, RMSNorm at ops.layers' eps, a SwiGLU MLP."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim, mlp=swiglu_mlp,
+            head_dim=self.head_dim, mlp=(swiglu_mlp,) * self.n_layers,
             remat=(jax.checkpoint_policies.nothing_saveable
                    if self.remat else None),
             kinds=(ATTENTION,) * self.n_layers, rope_base=self.rope_base)

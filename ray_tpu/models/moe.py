@@ -62,9 +62,9 @@ class MoEConfig:
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
             head_dim=self.head_dim, kinds=(ATTENTION,) * self.n_layers,
             rope_base=self.rope_theta, norm_eps=self.norm_eps,
-            mlp=functools.partial(
+            mlp=(functools.partial(
                 routed_experts, experts_per_token=self.experts_per_token,
-                norm_topk_prob=self.norm_topk_prob),
+                norm_topk_prob=self.norm_topk_prob),) * self.n_layers,
             remat=keep_kernel_outputs if self.remat else None)
 
     def init(self, key) -> Dict:

@@ -135,11 +135,11 @@ class NemotronHConfig:
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim,
-            mlp=functools.partial(
+            mlp=(functools.partial(
                 held_routed_experts,
                 experts_per_token=self.experts_per_token,
                 first=self.held[0], routed_scale=self.routed_scale,
-                bias_rounds=bias_rounds),
+                bias_rounds=bias_rounds),) * self.n_layers,
             remat=keep_kernel_outputs if self.remat else None,
             kinds=tuple(KINDS[letter] for letter in self.pattern),
             rope_base=self.rope_theta, norm_eps=self.norm_eps,
@@ -235,7 +235,7 @@ def _balanced(params: Dict, key, cfg: NemotronHConfig) -> Dict:
     dec = cfg.decoder()._replace(remat=None)
     x = jnp.take(params["embed"], tokens, axis=0)
     layers = []
-    for kind, layer in zip(dec.kinds, params["layers"]):
+    for kind, mlp, layer in zip(dec.kinds, dec.mlp, params["layers"]):
         if kind == EXPERTS:
             y = rms_norm(x, layer["ln2"], dec.norm_eps)
             scores = router_scores(y.reshape(-1, cfg.d_model),
@@ -244,7 +244,8 @@ def _balanced(params: Dict, key, cfg: NemotronHConfig) -> Dict:
                 scores, cfg.experts_per_token)}
         # a sequence at a time (_BALANCE_SEQ)
         x = jax.lax.map(lambda one: _block(
-            one[None], layer, None, None, dec=dec, kind=kind)[0][0], x)
+            one[None], layer, None, None, dec=dec, kind=kind,
+            mlp=mlp)[0][0], x)
         layers.append(layer)
     return {**params, "layers": layers}
 

@@ -98,7 +98,7 @@ class OlmoHybridConfig:
         block keeps what its kernels made and makes the rest again."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_heads,
-            head_dim=self.head_dim, mlp=swiglu_mlp,
+            head_dim=self.head_dim, mlp=(swiglu_mlp,) * self.n_layers,
             remat=keep_kernel_outputs if self.remat else None,
             kinds=tuple(GATED_DELTA if kind == LINEAR else ATTENTION
                         for kind in self.layer_types),

@@ -103,7 +103,7 @@ class SambaYConfig:
         made and makes the rest again."""
         return Decoder(
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim, mlp=fused_swiglu_mlp,
+            head_dim=self.head_dim, mlp=(fused_swiglu_mlp,) * self.n_layers,
             remat=keep_kernel_outputs if self.remat else None,
             kinds=self.layer_kinds,
             rope_base=None, norm_eps=self.norm_eps,

@@ -199,6 +199,19 @@ def head_spread(s, width: int):
                       precision=jax.lax.Precision.HIGHEST)
 
 
+def head_rms_norm(t, weight, eps: float = NORM_EPS):
+    """An RMSNorm over each head's own columns of t [..., heads * W], one
+    weight [W] for all heads (as many heads as the weight goes into the
+    width), in t's own layout: the mean of squares a head and its root's
+    way back onto the columns are `head_sums` and `head_spread`. In
+    float32, -> t's dtype."""
+    width, W = t.shape[-1], weight.shape[0]
+    tf = t.astype(jnp.float32)
+    inv = jax.lax.rsqrt(head_sums(jnp.square(tf), width // W) / W + eps)
+    return (tf * head_spread(inv, width) * jnp.tile(
+        weight.astype(jnp.float32), width // W)).astype(t.dtype)
+
+
 def head_rms_norm_gated(o, gate, weight, eps: float = NORM_EPS):
     """Gated DeltaNet's output norm, the other order from `gated_rms_norm`
     below: an RMSNorm over each head's own columns FIRST (o [..., H, V],

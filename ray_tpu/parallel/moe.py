@@ -16,12 +16,14 @@ Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Three layers:
   (expert parallelism without its exchange): it routes over all of them
   (sigmoid scores, a selection bias no gradient sees, the k weights
   renormalised and scaled: DeepSeek-V3's router), computes the part of
-  the result its own experts give, two matrices an expert with relu^2
-  between, in buffers of a balanced share's rows and an eighth
+  the result its own experts give (two matrices an expert with relu^2
+  between, or gated: silu(gate) * up from one fused [d, 2f] matrix and a
+  second) in buffers of a balanced share's rows and an eighth
   (`held_rows_plan`) that it walks in as many passes as the held rows
   take, so it drops nothing at any routing, and adds a shared expert
-  every token passes. `balance_bias` runs the bias's own rule to its
-  fixed point. What models/nemotron_h.py runs.
+  every token passes where the model has one. `balance_bias` runs the
+  bias's own rule to its fixed point. What models/nemotron_h.py (relu^2,
+  a shared expert) and models/lfm2_moe.py (gated, none) run.
 """
 
 from __future__ import annotations
@@ -313,7 +315,8 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
 
 # ---------------------------------------------------------------------------
 # A share of the experts: sigmoid router with a selection bias, relu^2
-# experts of two matrices, a shared expert
+# experts of two matrices or gated ones of a fused first matrix and a
+# second, a shared expert or none
 # ---------------------------------------------------------------------------
 def router_scores(x, router_w):
     """sigmoid(x router_w) [T, E] in float32 at full precision: what the
@@ -461,7 +464,14 @@ def _over_passes(passes, first, rest):
         lambda: first)
 
 
-def _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv, counts):
+def _silu_and_slope(gate):
+    """(silu(gate), its derivative) of a float32 pre-activation."""
+    sig = jax.nn.sigmoid(gate)
+    return gate * sig, sig * (1.0 + gate * (1.0 - sig))
+
+
+def _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm, inv,
+                      counts):
     f32 = jnp.float32
     with jax.named_scope("moe_route"):
         window = _windows(rows, weights, perm, inv, counts)
@@ -471,9 +481,12 @@ def _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv, counts):
         with jax.named_scope("moe_route"):
             xs = _rows(x, tokens)                             # [R, d]
         up = grouped_matmul(xs, w_up, sizes)
-        hidden = past_groups_zeroed(
-            jnp.square(jax.nn.relu(up.astype(f32))) * w[:, None],
-            sizes).astype(x.dtype)
+        if gated:                                   # [R, 2f]: gate | up
+            gate, up = jnp.split(up.astype(f32), 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        else:
+            act = jnp.square(jax.nn.relu(up.astype(f32)))
+        hidden = past_groups_zeroed(act * w[:, None], sizes).astype(x.dtype)
         ys = grouped_matmul(hidden, w_down, sizes)            # [R, d]
         with jax.named_scope("moe_combine"):
             return _added_back(out, ys, tokens, sizes)
@@ -487,7 +500,7 @@ def _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv, counts):
     return out.astype(x.dtype), (x, weights, w_up, w_down, perm, inv, counts)
 
 
-def _held_experts_bwd(rows, residuals, dout):
+def _held_experts_bwd(rows, gated, residuals, dout):
     x, weights, w_up, w_down, perm, inv, counts = residuals
     k, f32 = weights.shape[1], jnp.float32
     passes = _held_passes(counts, rows)
@@ -503,12 +516,23 @@ def _held_experts_bwd(rows, residuals, dout):
         # rows in the pass that makes it: what lies there was never
         # written.
         up = grouped_matmul(xs, w_up, sizes)
-        act = jax.nn.relu(past_groups_zeroed(up, sizes).astype(f32))
+        if gated:
+            gate, up = jnp.split(
+                past_groups_zeroed(up, sizes).astype(f32), 2, axis=-1)
+            silu, slope = _silu_and_slope(gate)
+            act = silu * up
+        else:
+            relu = jax.nn.relu(past_groups_zeroed(up, sizes).astype(f32))
+            act = jnp.square(relu)
         dhidden, ddown = grouped_matmul_grads(
-            (jnp.square(act) * w).astype(dout.dtype), w_down, sizes, dys)
+            (act * w).astype(dout.dtype), w_down, sizes, dys)
         dhidden = past_groups_zeroed(dhidden, sizes).astype(f32)
-        dw = jnp.sum(dhidden * jnp.square(act), axis=-1)
-        dup = dhidden * w * (2.0 * act)
+        dw = jnp.sum(dhidden * act, axis=-1)
+        if gated:
+            dup = jnp.concatenate([dhidden * w * up * slope,
+                                   dhidden * w * silu], axis=-1)
+        else:
+            dup = dhidden * w * (2.0 * relu)
         dxs, dup_w = grouped_matmul_grads(xs, w_up, sizes,
                                           dup.astype(dout.dtype))
         return (_added_back(dx, dxs, tokens, sizes),
@@ -527,13 +551,16 @@ def _held_experts_bwd(rows, residuals, dout):
             _over_passes(passes, *dw_down), None, None, None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_experts(rows, x, weights, w_up, w_down, perm, inv, counts):
-    """`_experts` for the experts a chip holds, two matrices each: `perm`
-    sorts the T*k assignments by held expert with those of absent experts
-    last, `counts` [held] are the held experts' rows, and
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(rows, gated, x, weights, w_up, w_down, perm, inv, counts):
+    """`_experts` for the experts a chip holds: `perm` sorts the T*k
+    assignments by held expert with those of absent experts last, `counts`
+    [held] are the held experts' rows, and, an expert being two matrices
+    with relu^2 between or (`gated`) a fused first matrix U = G | U' [d,
+    2f] and a second,
 
         ys[i]  = (w[perm[i]] * relu(xs[i] U[e])^2) D[e]    for i < sum(counts)
+        ys[i]  = (w[perm[i]] * silu(xs[i] G[e]) * (xs[i] U'[e])) D[e]  gated
         out[t] = sum over t's held assignments of ys[inv[t*k + j]]
 
     The buffers hold `rows` rows (`held_rows_plan`: a balanced share and
@@ -543,31 +570,38 @@ def _held_experts(rows, x, weights, w_up, w_down, perm, inv, counts):
     any routing, and no value is made over T*k rows but the index vectors.
     A pass gathers its rows from the tokens', runs two grouped matmuls
     forward and four backward (its `xs` and `up` made again: one more
-    forward), and adds its rows to their tokens' in float32; the weights'
-    gradients are summed over the passes in float32. One rule, its
-    residuals its inputs."""
-    return _held_experts_fwd(rows, x, weights, w_up, w_down, perm, inv,
-                             counts)[0]
+    forward; a gated expert's gate and up are one grouped matmul of twice
+    the width), and adds its rows to their tokens' in float32; the
+    weights' gradients are summed over the passes in float32. One rule,
+    its residuals its inputs."""
+    return _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm,
+                             inv, counts)[0]
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
-                   shared_down, *, experts_per_token: int, first: int,
-                   routed_scale: float = 1.0, bias_rounds: int = 0):
-    """One chip's part of a top-k expert layer with a shared expert, no
-    token dropped.
+def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
+                   shared_down=None, *, experts_per_token: int, first: int,
+                   routed_scale: float = 1.0, bias_rounds: int = 0,
+                   gated: bool = False, weight_eps: float = 1e-20):
+    """One chip's part of a top-k expert layer, with the model's shared
+    expert where it has one, no token dropped.
 
     x [T, d]; router_w [d, E] float32 over ALL E experts; router_bias [E]
     float32; w_up [held, d, f], w_down [held, f, d]: experts `first` to
-    `first + held - 1`; shared_up [d, fs], shared_down [fs, d]. Returns
+    `first + held - 1`, or with `gated` w_up [held, d, 2f], each expert's
+    gate and up matrices side by side, the gate first; shared_up [d, fs],
+    shared_down [fs, d] or None for a model with no shared expert. Returns
     (out [T, d] in x's dtype, stats) where, with s = sigmoid(x router_w),
     e_1..k the top k of s + router_bias, and w_j = routed_scale * s[e_j] /
-    (sum_j s[e_j] + 1e-20),
+    (sum_j s[e_j] + weight_eps) (the model's own small number: 1e-20
+    Nemotron-H's, 1e-6 LFM2's),
 
         out[t] = sum over the HELD e_j of w_j relu(x[t] U[e_j])^2 D[e_j]
                  + relu(x[t] shared_up)^2 shared_down
+        out[t] = sum over the HELD e_j of
+                 w_j (silu(x[t] G[e_j]) * (x[t] U'[e_j])) D[e_j]     gated
 
     The bias picks and never weighs: no gradient reaches it (DeepSeek-V3's
     balancing without a loss). With `bias_rounds` (a training step's) it
@@ -596,7 +630,7 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
         counts = _assignment_counts(experts, scores.shape[-1])
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         weights = routed_scale * weights / (
-            jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+            jnp.sum(weights, axis=-1, keepdims=True) + weight_eps)
         # An absent expert's assignments sort after every held one's.
         local = experts.reshape(-1).astype(jnp.int32) - first
         local = jnp.where((local >= 0) & (local < held), local, held)
@@ -604,12 +638,13 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
         _, perm = lax.sort((local, iota), num_keys=1, is_stable=True)
         _, inv = lax.sort((perm, iota), num_keys=1)
         held_counts = lax.slice_in_dim(counts, first, first + held)
-    out = _held_experts(rows, x, weights, w_up, w_down, perm, inv,
+    out = _held_experts(rows, gated, x, weights, w_up, w_down, perm, inv,
                         held_counts)
-    with jax.named_scope("moe_shared"):
-        hidden = jnp.square(jax.nn.relu(jnp.dot(
-            x, shared_up, preferred_element_type=jnp.float32)))
-        out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
+    if shared_up is not None:
+        with jax.named_scope("moe_shared"):
+            hidden = jnp.square(jax.nn.relu(jnp.dot(
+                x, shared_up, preferred_element_type=jnp.float32)))
+            out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
     stats = {"expert_tokens": counts,
              "expert_rows_held": jnp.sum(held_counts),
              "expert_passes": _held_passes(held_counts, rows),

@@ -152,6 +152,17 @@ DEVICE_SCOPES: Dict[str, str] = {
                        "_gd_bwd_kernel pallas_call: a chunk's inverse, W, U "
                        "and V' again, then every gradient of the rule, "
                        "chunks last to first",
+    "short_conv_fwd": "ops/short_conv.py _forward_call, the "
+                      "_conv_fwd_kernel pallas_call: a gated short "
+                      "convolution's y = C * conv(B * x) from the "
+                      "projection's thirds, in one pass",
+    "short_conv_bwd": "ops/short_conv.py _backward_call, the "
+                      "_conv_bwd_kernel pallas_call: the convolution made "
+                      "again, the gradients by B, C and x and the taps' "
+                      "float32 partial sums",
+    "short_conv_proj": "models/decoder.py short_conv: the input "
+                       "projection to B | C | x and the output projection "
+                       "round the convolution's kernels",
     "ssm_conv": "models/decoder.py mamba2, mamba1 and gated_delta: the "
                 "causal depthwise convolution (over x | B | C; Mamba-1: "
                 "over x; the delta rule: over q | k | v, no bias) and its "
@@ -179,10 +190,12 @@ DEVICE_SCOPES: Dict[str, str] = {
                    "sum (a held share: a pass's rows added to their "
                    "tokens')",
     "moe_shared": "parallel/moe.py held_moe_layer: the shared expert every "
-                  "token passes, two plain matmuls with relu^2 between",
+                  "token passes where the model has one, two plain matmuls "
+                  "with relu^2 between",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
               "every decoder family (gpt, llama, moe, hybrid, sambay, "
-              "olmo_hybrid, nemotron_h), each layer run by the row of "
+              "olmo_hybrid, nemotron_h, lfm2_moe), each layer run by the "
+              "row of "
               "decoder.MIXERS "
               "that its config's `kinds` names, in the train step and "
               "under prefill / decode alike",

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 import pytest
 
 import ray_tpu
-from ray_tpu.models import (GPTConfig, HybridConfig, LlamaConfig, MoEConfig,
-                            gpt_init, make_hybrid_train_step,
+from ray_tpu.models import (GPTConfig, HybridConfig, Lfm2MoeConfig,
+                            LlamaConfig, MoEConfig, gpt_init,
+                            make_hybrid_train_step, make_lfm2_moe_train_step,
                             make_llama_train_step, make_moe_train_step,
                             make_train_step)
 from ray_tpu.util import profiling
@@ -389,6 +390,7 @@ def test_annotate_keeps_a_process_off_jax():
 
 ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
 SCAN_KERNELS = {"_ssm_fwd_kernel", "_ssm_bwd_kernel"}
+CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
 
 
 @pytest.mark.parametrize("make_step,cfg,batch,kernels", [
@@ -417,7 +419,16 @@ SCAN_KERNELS = {"_ssm_fwd_kernel", "_ssm_bwd_kernel"}
                   mamba_n_heads=4, mamba_d_head=64, mamba_d_state=128,
                   mamba_chunk_size=128, max_seq_len=256), 2,
      ATTENTION_KERNELS | SCAN_KERNELS),
-], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid"])
+    # A dense layer under a gated short convolution, then an expert layer
+    # under attention: LFM2's two sequence mixers and two channel mixers.
+    (make_lfm2_moe_train_step,
+     Lfm2MoeConfig(vocab_size=512, d_model=128, n_heads=2, n_kv_heads=1,
+                   head_dim=64, layer_types=("conv", "full_attention"),
+                   n_dense_layers=1, d_ff=256, n_experts=4,
+                   experts_held=(1, 2), experts_per_token=2, d_expert=128,
+                   bias_rounds=8, balance_tokens=0, max_seq_len=256), 2,
+     ATTENTION_KERNELS | CONV_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
+], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -440,6 +451,11 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
             assert re.search(r'loc\("(?:[^"]*/)?ssm_scan_%s/pallas_call"'
                              % kernel, text), kernel
         scopes += ["ssm_conv", "ssm_gate_norm"]
+    if kernels >= CONV_KERNELS:
+        for kernel in ("fwd", "bwd"):
+            assert re.search(r'loc\("(?:[^"]*/)?short_conv_%s/pallas_call"'
+                             % kernel, text), kernel
+        scopes += ["short_conv_proj", "moe_route", "moe_combine"]
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope

@@ -467,7 +467,8 @@ def test_a_mamba_block_keeps_the_scan_kernels_outputs_and_nothing_else(
     dec = dataclasses.replace(cfg, remat=True).decoder()
     layer = params["layers"][0]
     block = jax.checkpoint(
-        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0],
+                          mlp=dec.mlp[0]),
         policy=dec.remat)
     b, s = 2, 32
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],

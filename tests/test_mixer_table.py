@@ -16,6 +16,7 @@ from ray_tpu.models import decoder, generate
 from ray_tpu.models.generate import init_cache, make_continuous_fns
 from ray_tpu.models.gpt import GPTConfig, gpt_loss
 from ray_tpu.models.hybrid import HybridConfig, hybrid_loss
+from ray_tpu.models.lfm2_moe import Lfm2MoeConfig, lfm2_moe_loss
 from ray_tpu.models.llama import LlamaConfig, llama_loss
 from ray_tpu.models.moe import MoEConfig, moe_loss
 from ray_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_loss
@@ -27,6 +28,7 @@ FAMILIES = {
     "llama": (LlamaConfig, llama_loss),
     "moe": (MoEConfig, moe_loss),
     "hybrid": (HybridConfig, hybrid_loss),
+    "lfm2_moe": (Lfm2MoeConfig, lfm2_moe_loss),
     "sambay": (SambaYConfig, sambay_loss),
     "olmo_hybrid": (OlmoHybridConfig, olmo_hybrid_loss),
     "nemotron_h": (NemotronHConfig, nemotron_h_loss),
@@ -34,7 +36,7 @@ FAMILIES = {
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
          decoder.DIFF_FULL, decoder.DIFF_CROSS, decoder.ATTENTION_ONLY,
-         decoder.MAMBA2_ONLY, decoder.EXPERTS)
+         decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV)
 STATELESS = (decoder.GMU, decoder.DIFF_CROSS, decoder.EXPERTS)
 
 
@@ -45,8 +47,8 @@ def family(request):
     return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
 
 
-def test_the_table_has_the_eleven_kinds_and_the_tiny_models_run_them_all():
-    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 11
+def test_the_table_has_the_twelve_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 12
     run = {kind for config, _ in FAMILIES.values()
            for kind in config.tiny().decoder().kinds}
     assert run == set(KINDS)
@@ -73,15 +75,44 @@ def test_a_row_says_which_branches_its_block_has():
         decoder.ATTENTION_ONLY, decoder.EXPERTS)
 
 
+def test_a_layers_channel_mixer_is_named_as_its_sequence_mixer_is(family):
+    """`Decoder.mlp` is one callable a layer, as long as `kinds`, built by
+    the family's `decoder()` from the fields it has: the one function a
+    layer in seven families; LFM2's dense SwiGLU in its leading layers and
+    one expert layer's function in all the others."""
+    cfg, _ = family
+    dec = cfg.decoder()
+    assert isinstance(dec.mlp, tuple) and len(dec.mlp) == len(dec.kinds)
+    assert all(callable(mlp) for mlp in dec.mlp)
+    if isinstance(cfg, Lfm2MoeConfig):
+        dense = cfg.n_dense_layers
+        assert 0 < dense < cfg.n_layers
+        assert set(dec.mlp[:dense]) == {decoder.swiglu_mlp}
+        experts, = set(dec.mlp[dense:])
+        assert experts.func is decoder.held_gated_experts
+        assert experts.keywords["weight_eps"] == 1e-6
+    else:
+        assert len(set(dec.mlp)) == 1
+
+
+def test_a_channel_mixer_too_few_is_refused_by_name():
+    cfg = Lfm2MoeConfig.tiny()
+    params = cfg.init(jax.random.PRNGKey(0))
+    dec = cfg.decoder()
+    dec = dec._replace(mlp=dec.mlp[:-1])
+    with pytest.raises(ValueError, match="3 channel mixers"):
+        decoder.decoder_hidden(params, jnp.zeros((2, 8), jnp.int32), dec)
+
+
 @pytest.mark.parametrize("kind,norm,held", [
     (decoder.MAMBA2_ONLY, "ln1", "in_proj"),
     (decoder.ATTENTION_ONLY, "ln1", "wq"),
     (decoder.EXPERTS, "ln2", "router")])
 def test_a_single_branch_block_is_x_plus_its_one_mixer(kind, norm, held):
     """x + mixer(norm(x)) and nothing else: a layer of such a kind holds
-    one norm and its mixer's weights, its block ignores `dec.mlp` (or has
-    no sequence mixer to run), and zeroing the branch's output matrix
-    gives back x."""
+    one norm and its mixer's weights, its block is handed no channel
+    mixer (or has no sequence mixer to run), and zeroing the branch's
+    output matrix gives back x."""
     cfg = dataclasses.replace(NemotronHConfig.tiny(), dtype=jnp.float32)
     dec = cfg.decoder()
     index = dec.kinds.index(kind)
@@ -89,17 +120,18 @@ def test_a_single_branch_block_is_x_plus_its_one_mixer(kind, norm, held):
     assert norm in layer and held in layer
     assert not {"ln1", "ln2"} - {norm} & set(layer)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
-    boom = dec._replace(mlp=None)      # a sequence-only block never calls it
+    # a sequence-only block is handed no channel mixer and calls none
+    mlp = dec.mlp[index] if kind == decoder.EXPERTS else None
     out, stats, cache, _ = decoder._block(
-        x, layer, None, None, dec=dec if kind == decoder.EXPERTS else boom,
-        kind=kind)
+        x, layer, None, None, dec=dec, kind=kind, mlp=mlp)
     assert out.shape == x.shape and cache is None
     assert (stats is not None) == (kind == decoder.EXPERTS)
     assert float(jnp.max(jnp.abs(out - x))) > 1e-3
     silent = {**layer, **{name: jnp.zeros_like(layer[name]) for name in
                           ("out_proj", "wo", "expert_down", "shared_down")
                           if name in layer}}
-    same = decoder._block(x, silent, None, None, dec=dec, kind=kind)[0]
+    same = decoder._block(x, silent, None, None, dec=dec, kind=kind,
+                          mlp=mlp)[0]
     assert jnp.array_equal(same, x)
 
 
@@ -219,6 +251,8 @@ FROZEN = {
     "gmu": (("x", "layer", "dec", "m"), "sambay"),
     "differential_maps": (("q", "k", "v", "layer", "dec", "index", "window"),
                           "sambay"),
+    "gated_short_conv": (("bcx", "weight", "tail"), "lfm2_moe"),
+    "head_rms_norm": (("t", "weight", "eps"), "lfm2_moe"),
 }
 
 
@@ -250,7 +284,8 @@ def test_the_program_calls_the_frozen_name_through_the_module(name,
 def test_generate_names_no_family():
     import ray_tpu.models as models
     families = {getattr(models, name) for name in (
-        "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid")}
+        "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid",
+        "nemotron_h", "lfm2_moe")}
     held = {v for v in vars(generate).values() if inspect.ismodule(v)}
     assert not held & families
     assert "cache_layers" not in inspect.getsource(generate)

@@ -146,13 +146,14 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_45_names():
         "expert_gmm_ms_per_step", "expert_gmm_roofline"}
     for x in (*m["end_to_end"], *m["per_layer"]):
         if CELL in x.get("workloads", ()):
-            assert x["workloads"][-1] == CELL       # appended, nothing moved
-    cell = m["workloads"][-1]
+            # appended, nothing moved (a later cell after it at most)
+            assert CELL in x["workloads"][-2:]
+    cell = m["workloads"][6]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, "nemotron-3-nano-30b-a3b", "pretrain-nemotron3nano-b1-s16384",
         1)
-    assert len(m["workloads"]) == 7 and len(m["configs"]) == 6
-    config = m["configs"][-1]
+    assert len(m["workloads"]) >= 7 and len(m["configs"]) >= 6
+    config = m["configs"][5]
     on_disk = _load(config["file"])
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",

@@ -175,10 +175,10 @@ def test_the_norms_sit_where_the_weights_say():
     layer, dec = params["layers"][3], cfg.decoder()
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 16, cfg.d_model))
     got = decoder._block(x, layer, None, None, dec=dec,
-                         kind=dec.kinds[3])[0]
+                         kind=dec.kinds[3], mlp=dec.mlp[3])[0]
     a, _ = decoder.attention(x, layer, dec)
     h = x + decoder.rms_norm(a, layer["post_attention"], cfg.norm_eps)
-    out, _ = dec.mlp(h, layer)
+    out, _ = dec.mlp[3](h, layer)
     _close(got, h + decoder.rms_norm(out, layer["post_feedforward"],
                                      cfg.norm_eps), tol=1e-6)
 

@@ -481,7 +481,8 @@ def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
     b, s, d, k = 2, 128, cfg.d_model, cfg.experts_per_token
     layer = moe_init(jax.random.PRNGKey(0), cfg)["layers"][0]
     block = jax.checkpoint(
-        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0],
+                          mlp=dec.mlp[0]),
         policy=dec.remat)
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
                           jnp.ones((b, s, d), dtype), layer)

@@ -231,7 +231,8 @@ def test_a_mamba1_block_keeps_the_scan_kernels_outputs_and_nothing_else(
     dec = cfg.decoder()
     layer = sambay_init(jax.random.PRNGKey(0), cfg)["layers"][0]
     block = jax.checkpoint(
-        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0]),
+        functools.partial(decoder._block, dec=dec, kind=dec.kinds[0],
+                          mlp=dec.mlp[0]),
         policy=dec.remat)
     b, s = 2, 128
     print_saved_residuals(lambda x, layer: block(x, layer, None, None)[0],
