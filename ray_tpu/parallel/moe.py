@@ -382,10 +382,19 @@ def balance_bias(scores, k: int, rounds: int = 256, start=None):
 _HELD_ROWS_HEADROOM = 8           # one part in this many
 
 
+# A pass's rows go back to their tokens by k gathers of T rows where T * k
+# is at most this many times the pass's rows, by one scatter-add of the rows
+# above it: the gathers' cost is the T * k rows whatever is held, the
+# scatter-add's falls with the rows, and they cross near 2.5 (`_gathered_back`
+# has the chip's readings on both sides).
+_GATHERED_BACK_UP_TO = 2
+
+
 class HeldRowsPlan(NamedTuple):
     rows: int        # a pass's rows: what each buffer of the held share holds
     balanced: int    # the held experts' rows under an even routing
     tile: int        # grouped_matmul's row tile: `rows` is so many, or T * k
+    gathered: bool   # rows come back by k gathers of T, not a scatter-add
 
 
 def held_rows_plan(tokens: int, k: int, held: int,
@@ -394,51 +403,95 @@ def held_rows_plan(tokens: int, k: int, held: int,
     assignments each where a chip holds `held` of `experts` experts: the
     balanced share and an eighth, rounded up to grouped_matmul's row tile,
     and never more than the tokens * k there are (all experts held, or a
-    decode step's few tokens: one pass covers any routing)."""
+    decode step's few tokens: one pass covers any routing). And which way
+    a pass's rows are added back to their tokens (`_gathered_back` or
+    `_scattered_back`), from tokens * k against those rows."""
     tile = _TILES[0]
     balanced = -(-tokens * k * held // experts)
     rows = balanced + -(-balanced // _HELD_ROWS_HEADROOM)
-    return HeldRowsPlan(min(-(-rows // tile) * tile, tokens * k), balanced,
-                        tile)
+    rows = min(-(-rows // tile) * tile, tokens * k)
+    return HeldRowsPlan(rows, balanced, tile,
+                        tokens * k <= _GATHERED_BACK_UP_TO * rows)
 
 
 def _windows(rows: int, weights, perm, inv, counts):
     """window(p) -> pass p of the held order, its places [p * rows, (p + 1)
     * rows): (the token of each place, its router weight, the held experts'
     rows inside the window: their cumulative ends less the window's start,
-    clipped to it). The T*k tokens and weights in sorted order are made
-    once, with zeros after them up to a whole number of passes, so that a
-    window cut from them (`lax.dynamic_slice`) is never slid back to fit."""
+    clipped to it, and for each of the k assignment slots j the place in
+    the window of every token's j-th assignment, `inv[t * k + j] - p *
+    rows`: k index vectors of T, outside [0, rows) for another pass's).
+    The T*k tokens and weights in sorted order are made once, with zeros
+    after them up to a whole number of passes, so that a window cut from
+    them (`lax.dynamic_slice`) is never slid back to fit."""
     k = weights.shape[1]
     pad = (0, -perm.shape[0] % rows)
     token_of = jnp.pad(perm // k, pad)
     w_sorted = jnp.pad(_permuted(weights.reshape(-1), inv), pad)
+    slots = tuple(inv.reshape(-1, k).T)
 
     def window(p):
         ends = jnp.cumsum(counts) - p * rows
         sizes = jnp.clip(ends, 0, rows) - jnp.clip(ends - counts, 0, rows)
         return (lax.dynamic_slice_in_dim(token_of, p * rows, rows),
-                lax.dynamic_slice_in_dim(w_sorted, p * rows, rows), sizes)
+                lax.dynamic_slice_in_dim(w_sorted, p * rows, rows), sizes,
+                tuple(slot - p * rows for slot in slots))
 
     return window
 
 
-def _added_back(acc, made, tokens, sizes):
+def _scattered_back(acc, made, tokens, sizes):
     """`acc` [T, d] float32 with every held row of `made` [R, d] added to
     its token's: `made` is what a kernel wrote for a window, `tokens` [R]
     the token of each place, the first sum(sizes) of them held; a place
     past them is sent out of bounds and dropped (what lies there was never
-    written). A scatter-add of R rows into the T, where `_sum_back` is a
-    gather: the held rows of a token are not k and not next to each other,
-    and of three forms at Nemotron-3-Nano's 16,384 tokens (a layer forward
-    and backward, 59.3 ms with T*k-row buffers) this one took 44.8 ms, k
-    masked gathers of T rows 45.4, and the rows sorted by token, summed
-    along their runs by shifted adds and gathered once 51.9: XLA writes
-    each shifted [R, d] slice out (my chip runs, PR 46; PERF.md section
-    6). The rows are added in the order of their places."""
+    written). A scatter-add of R rows into the T, added in the order of
+    their places. `_gathered_back` has what the chip said of the two."""
     held = jnp.arange(made.shape[0], dtype=jnp.int32) < jnp.sum(sizes)
     return acc.at[jnp.where(held, tokens, acc.shape[0])].add(
         made.astype(acc.dtype), mode="drop")
+
+
+def _gathered_back(acc, made, places, sizes):
+    """`_scattered_back` as k gathers of T rows: `places` are the window's
+    k index vectors [T] (`_windows`), and slot j adds to every token the
+    row of `made` [R, d] at its j-th assignment's place, nothing where that
+    place is not one of the window's sum(sizes) held ones (an absent
+    expert's assignment, another pass's, or a row no kernel wrote). Summed
+    in float32 slot by slot, never through a [T, k, d] value; XLA writes
+    the k gathered [T, d] out in `made`'s dtype and adds them to `acc` in
+    one fusion.
+
+    Which of the two a layer takes is `held_rows_plan`'s to say, from T * k
+    rows gathered against R scattered. A layer forward and backward alone
+    on the v5e, scatter-add / gathers, ms (my chip runs, PR 49; PERF.md
+    section 6): LFM2-8B-A1B's cell (T 32,768, k 4, d 2,048, 16 of 32 held,
+    T * k / R = 1.78) 78.47 / 74.58; the same with 8 held (3.56) 43.37 /
+    46.30 and with 6 (4.74) 34.74 / 38.79; Nemotron-3-Nano's cell (T
+    16,384, k 6, d 2,688, 16 of 128 held, 7.1) 39.51 / 40.02, where PR 46
+    read 44.8 / 45.4 (and 51.9 for the rows sorted by token, summed along
+    their runs by shifted adds and gathered once: XLA writes each shifted
+    [R, d] slice out); with 32 held (3.56) 59.16 / 63.15, with 24 (4.68)
+    49.12 / 54.68. At LFM2's shape the gathers cost 4.9 ms a call whatever
+    is held (131,072 rows at 33 ns and the sum) and a scattered row 92 ns,
+    which cross at 2.5."""
+    held = jnp.sum(sizes)
+    for place in places:
+        here = (place >= 0) & (place < held)
+        # A place that is not here is read all the same (XLA's gather
+        # skips nothing), each from a row of its own: sent to one row they
+        # cost the k gathers and their sum 5.71 ms where this costs 5.39.
+        picked = _rows(made, place % made.shape[0])
+        acc = acc + jnp.where(here[:, None], picked, 0).astype(acc.dtype)
+    return acc
+
+
+def _added_back(gathered: bool, acc, made, tokens, places, sizes):
+    """A window's rows `made` added to their tokens' in `acc`, by the form
+    the layer's `held_rows_plan` names."""
+    if gathered:
+        return _gathered_back(acc, made, places, sizes)
+    return _scattered_back(acc, made, tokens, sizes)
 
 
 def _held_passes(counts, rows: int):
@@ -470,14 +523,14 @@ def _silu_and_slope(gate):
     return gate * sig, sig * (1.0 + gate * (1.0 - sig))
 
 
-def _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm, inv,
+def _held_experts_fwd(plan, gated, x, weights, w_up, w_down, perm, inv,
                       counts):
-    f32 = jnp.float32
+    rows, f32 = plan.rows, jnp.float32
     with jax.named_scope("moe_route"):
         window = _windows(rows, weights, perm, inv, counts)
 
     def one_pass(p, out):
-        tokens, w, sizes = window(p)
+        tokens, w, sizes, places = window(p)
         with jax.named_scope("moe_route"):
             xs = _rows(x, tokens)                             # [R, d]
         up = grouped_matmul(xs, w_up, sizes)
@@ -489,7 +542,8 @@ def _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm, inv,
         hidden = past_groups_zeroed(act * w[:, None], sizes).astype(x.dtype)
         ys = grouped_matmul(hidden, w_down, sizes)            # [R, d]
         with jax.named_scope("moe_combine"):
-            return _added_back(out, ys, tokens, sizes)
+            return _added_back(plan.gathered, out, ys, tokens, places,
+                               sizes)
 
     out = lax.fori_loop(0, _held_passes(counts, rows), one_pass,
                         jnp.zeros(x.shape, f32))
@@ -500,15 +554,15 @@ def _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm, inv,
     return out.astype(x.dtype), (x, weights, w_up, w_down, perm, inv, counts)
 
 
-def _held_experts_bwd(rows, gated, residuals, dout):
+def _held_experts_bwd(plan, gated, residuals, dout):
     x, weights, w_up, w_down, perm, inv, counts = residuals
-    k, f32 = weights.shape[1], jnp.float32
+    rows, k, f32 = plan.rows, weights.shape[1], jnp.float32
     passes = _held_passes(counts, rows)
     window = _windows(rows, weights, perm, inv, counts)
 
     def one_pass(p, carry):
         dx, dw_sorted, dw_up, dw_down = carry
-        tokens, w, sizes = window(p)
+        tokens, w, sizes, places = window(p)
         w = w[:, None]
         xs = _rows(x, tokens)
         dys = _rows(dout, tokens)             # the combine is a plain sum
@@ -535,7 +589,9 @@ def _held_experts_bwd(rows, gated, residuals, dout):
             dup = dhidden * w * (2.0 * relu)
         dxs, dup_w = grouped_matmul_grads(xs, w_up, sizes,
                                           dup.astype(dout.dtype))
-        return (_added_back(dx, dxs, tokens, sizes),
+        with jax.named_scope("moe_dx"):       # the dispatch's transpose
+            dx = _added_back(plan.gathered, dx, dxs, tokens, places, sizes)
+        return (dx,
                 lax.dynamic_update_slice_in_dim(dw_sorted, dw, p * rows, 0),
                 _first_and_rest(p, *dw_up, dup_w),
                 _first_and_rest(p, *dw_down, ddown))
@@ -552,7 +608,7 @@ def _held_experts_bwd(rows, gated, residuals, dout):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_experts(rows, gated, x, weights, w_up, w_down, perm, inv, counts):
+def _held_experts(plan, gated, x, weights, w_up, w_down, perm, inv, counts):
     """`_experts` for the experts a chip holds: `perm` sorts the T*k
     assignments by held expert with those of absent experts last, `counts`
     [held] are the held experts' rows, and, an expert being two matrices
@@ -563,18 +619,19 @@ def _held_experts(rows, gated, x, weights, w_up, w_down, perm, inv, counts):
         ys[i]  = (w[perm[i]] * silu(xs[i] G[e]) * (xs[i] U'[e])) D[e]  gated
         out[t] = sum over t's held assignments of ys[inv[t*k + j]]
 
-    The buffers hold `rows` rows (`held_rows_plan`: a balanced share and
-    an eighth), and the held order is walked in passes of that many, as
+    The buffers hold `plan.rows` rows (`held_rows_plan`: a balanced share
+    and an eighth), and the held order is walked in passes of that many, as
     many as sum(counts) takes: one at a balanced routing, T*k / rows when
     every assignment is held, none when none is. Nothing is dropped at
     any routing, and no value is made over T*k rows but the index vectors.
     A pass gathers its rows from the tokens', runs two grouped matmuls
     forward and four backward (its `xs` and `up` made again: one more
     forward; a gated expert's gate and up are one grouped matmul of twice
-    the width), and adds its rows to their tokens' in float32; the
+    the width), and adds its rows to their tokens' in float32, by k
+    gathers of T rows or one scatter-add as `plan.gathered` says; the
     weights' gradients are summed over the passes in float32. One rule,
     its residuals its inputs."""
-    return _held_experts_fwd(rows, gated, x, weights, w_up, w_down, perm,
+    return _held_experts_fwd(plan, gated, x, weights, w_up, w_down, perm,
                              inv, counts)[0]
 
 
@@ -617,7 +674,7 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
     [E] (sum over tokens of s / sum_E s: the load-balancing loss's
     probabilities)."""
     t, k, held = x.shape[0], experts_per_token, w_up.shape[0]
-    rows = held_rows_plan(t, k, held, router_w.shape[-1]).rows
+    plan = held_rows_plan(t, k, held, router_w.shape[-1])
     with jax.named_scope("moe_route"):
         # Kept under remat like the softmax router's probabilities, and
         # for the same reason (`dropless_moe_layer`).
@@ -638,7 +695,7 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
         _, perm = lax.sort((local, iota), num_keys=1, is_stable=True)
         _, inv = lax.sort((perm, iota), num_keys=1)
         held_counts = lax.slice_in_dim(counts, first, first + held)
-    out = _held_experts(rows, gated, x, weights, w_up, w_down, perm, inv,
+    out = _held_experts(plan, gated, x, weights, w_up, w_down, perm, inv,
                         held_counts)
     if shared_up is not None:
         with jax.named_scope("moe_shared"):
@@ -647,7 +704,7 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
             out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
     stats = {"expert_tokens": counts,
              "expert_rows_held": jnp.sum(held_counts),
-             "expert_passes": _held_passes(held_counts, rows),
+             "expert_passes": _held_passes(held_counts, plan.rows),
              "router_prob_sum": jnp.sum(
                  scores / jnp.sum(scores, axis=-1, keepdims=True), axis=0),
              "router_bias": bias}

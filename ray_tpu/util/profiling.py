@@ -188,7 +188,11 @@ DEVICE_SCOPES: Dict[str, str] = {
     "moe_combine": "parallel/moe.py _experts and _held_experts: the "
                    "experts' weighted rows back in token order and their "
                    "sum (a held share: a pass's rows added to their "
-                   "tokens')",
+                   "tokens', by k gathers of T rows or one scatter-add as "
+                   "held_rows_plan's `gathered` says)",
+    "moe_dx": "parallel/moe.py _held_experts_bwd: the transpose of a held "
+              "share's dispatch, a pass's gradient rows added to their "
+              "tokens' in float32 by the same form as moe_combine",
     "moe_shared": "parallel/moe.py held_moe_layer: the shared expert every "
                   "token passes where the model has one, two plain matmuls "
                   "with relu^2 between",
@@ -286,6 +290,32 @@ def _computations(compiled_text: str) -> Dict[str, list]:
                 body.append((name.removeprefix("ROOT ").lstrip("%"),
                              rest[:op.start()], op.group(1), rest[op.end():]))
     return computations
+
+
+def scatter_calls(compiled_text: str) -> Dict[str, int]:
+    """The scatter instructions of a compiled program, counted by the
+    array each scatters into as HLO prints it without its layout
+    (`f32[32768,2048]`): a static counter like `kernel_calls`, read from
+    `jitted.lower(...).compile().as_text()` and not from a run. A scatter
+    inside a fusion counts, one in a loop's body counts once.
+
+    What it is for: which way a held share's rows go back to their tokens
+    (parallel/moe.py `HeldRowsPlan.gathered`). A layer of `held_moe_layer`
+    that scatters them holds two scatters into float32 [T, d] a step, the
+    forward's combine and the backward's transpose of the dispatch, and
+    one that gathers them holds none: LFM2-8B-A1B's step of four expert
+    layers 8 into `f32[32768,2048]` before PR 49 and 0 since,
+    Nemotron-3-Nano's 8 into `f32[16384,2688]` before and since (PERF.md
+    section 6, PR 49). What else a step scatters stays: the embedding's
+    gradient into the table, the router's k weights' gradients into [T *
+    E] scores, the grouped matmuls' tile counts into `s32[...]`."""
+    calls: Dict[str, int] = {}
+    for body in _computations(compiled_text).values():
+        for _, shape, op, _ in body:
+            if op == "scatter":
+                into = _ARRAY.search(shape).group(0)
+                calls[into] = calls.get(into, 0) + 1
+    return calls
 
 
 def collective_calls(compiled_text: str) -> Dict[str, Any]:

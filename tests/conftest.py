@@ -15,6 +15,22 @@ import sys
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     _flags = (_flags + " --xla_force_host_platform_device_count=8").strip()
+# XLA:CPU builds fast, not well: its older emitters, LLVM at level 1 and
+# without its expensive passes. What these tests hold is the program's
+# arithmetic against a reference, on programs of a few rows that are built
+# once and run once, so the building is the time (867 programs in one
+# model's first gradient, 40 ms each; tests/test_lfm2_moe.py takes 29% less
+# of the CPU so), and the whole of tier-1 has a time limit. Not level 0,
+# which is faster still: the newer emitters then sum some bfloat16
+# products in bfloat16 (a dp=4 step's loss 0.3% off its unsharded twin,
+# test_models_ops.py) and the older ones round two forms of one sum apart
+# (test_gated_delta.py, test_loss.py). The chip's compiler, where a test
+# compiles for it, takes no notice.
+for _name, _value in (("xla_cpu_use_fusion_emitters", "false"),
+                      ("xla_backend_optimization_level", "1"),
+                      ("xla_llvm_disable_expensive_passes", "true")):
+    if _name not in _flags:
+        _flags += f" --{_name}={_value}"
 os.environ["XLA_FLAGS"] = _flags
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Worker subprocesses spawned by ray_tpu set their own env; the driver-side

@@ -242,6 +242,50 @@ def test_kernel_calls_counts_mosaic_calls_by_scope():
     assert profiling.kernel_calls("") == {}
 
 
+# Scatters as XLA:TPU prints them, cut from LFM2-8B-A1B's step before PR 49
+# (tests/test_compile_v5e_lfm2moe.py reads the whole step since): a layer's
+# rows scattered into its tokens' inside a fusion of a loop's body, the
+# embedding's gradient in the entry, a grouped matmul's tile counts.
+_SCATTERS = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.12 (param_0.1: f32[32768,2048], param_1.1: s32[73728,1], param_2.1: f32[73728,2048]) -> f32[32768,2048] {
+  %param_0.1 = f32[32768,2048]{1,0:T(8,128)} parameter(0)
+  %param_1.1 = s32[73728,1]{0,1:T(1,128)} parameter(1)
+  %param_2.1 = f32[73728,2048]{1,0:T(8,128)} parameter(2)
+  ROOT %scatter-add.348 = f32[32768,2048]{1,0:T(8,128)} scatter(%param_0.1, %param_1.1, %param_2.1), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_16.38, metadata={op_name="jit(train_step)/jvp(layers)/while/body/moe_combine/scatter-add"}
+}
+
+%fused_computation.13 (param_0.2: s32[159], param_1.2: s32[16,1], param_2.2: s32[16]) -> s32[159] {
+  %param_0.2 = s32[159]{0:T(256)S(1)} parameter(0)
+  %param_1.2 = s32[16,1]{0,1:T(1,128)} parameter(1)
+  %param_2.2 = s32[16]{0:T(256)} parameter(2)
+  ROOT %scatter-add.347 = s32[159]{0:T(256)S(1)} scatter(%param_0.2, %param_1.2, %param_2.2), update_window_dims={}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_16.39
+}
+
+%body.5 (p: (f32[32768,2048], s32[73728,1], f32[73728,2048])) -> f32[32768,2048] {
+  %p = (f32[32768,2048]{1,0}, s32[73728,1]{0,1}, f32[73728,2048]{1,0}) parameter(0)
+  %acc = f32[32768,2048]{1,0:T(8,128)} get-tuple-element(%p), index=0
+  %at = s32[73728,1]{0,1:T(1,128)} get-tuple-element(%p), index=1
+  %rows = f32[73728,2048]{1,0:T(8,128)} get-tuple-element(%p), index=2
+  ROOT %fusion.90 = f32[32768,2048]{1,0:T(8,128)} fusion(%acc, %at, %rows), kind=kCustom, calls=%fused_computation.12
+}
+
+ENTRY %main.9 (table: bf16[32768,2048], ids: s32[32768,1], g: bf16[32768,2048]) -> bf16[32768,2048] {
+  %table = bf16[32768,2048]{1,0:T(8,128)(2,1)} parameter(0)
+  %ids = s32[32768,1]{0,1:T(1,128)} parameter(1)
+  %g = bf16[32768,2048]{1,0:T(8,128)(2,1)} parameter(2)
+  ROOT %scatter-add.326 = bf16[32768,2048]{1,0:T(8,128)(2,1)} scatter(%table, %ids, %g), update_window_dims={1}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_1.2, metadata={op_name="jit(train_step)/transpose(jvp(jit(_take)))/scatter-add"}
+}
+"""
+
+
+def test_scatter_calls_counts_scatters_by_what_they_scatter_into():
+    assert profiling.scatter_calls(_SCATTERS) == {
+        "f32[32768,2048]": 1, "s32[159]": 1, "bf16[32768,2048]": 1}
+    assert profiling.scatter_calls(_COMPILED) == {}
+    assert profiling.scatter_calls("") == {}
+
+
 # Three scheduled programs as XLA:TPU prints them, cut to what
 # collective_calls reads (tests/test_compile_v5e_loss.py reads the whole
 # dp=4 step): the combiner's blocking tuple; a start/done pair; and an

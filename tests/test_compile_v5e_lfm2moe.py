@@ -163,7 +163,7 @@ def test_the_experts_buffers_hold_the_held_rows_and_nothing_is_dropped(step):
     from ray_tpu.parallel.moe import held_rows_plan
     from ray_tpu.util import profiling
 
-    assert held_rows_plan(32768, 4, 16, 32) == (73728, 65536, 512)
+    assert held_rows_plan(32768, 4, 16, 32) == (73728, 65536, 512, True)
     text = step[1].as_text()
     for shape in ("bf16[73728,2048]", "bf16[73728,3584]", "bf16[73728,1792]",
                   "bf16[16,2048,3584]", "bf16[16,1792,2048]"):
@@ -183,6 +183,36 @@ def test_the_experts_buffers_hold_the_held_rows_and_nothing_is_dropped(step):
                         for shape in long), long
     assert not re.search(r"\[32768,32,\d+\]", text)       # [T, E, C]
     assert " while(" in text                # the passes are one loop's
+
+
+def test_a_pass_s_rows_come_back_to_their_tokens_by_gathers(step):
+    """T x k = 131,072 rows gathered against 73,728 scattered, 1.78 for
+    one: the plan names the gathers (parallel/moe.py `_gathered_back`), so
+    the step scatters no row into the tokens' float32 [T, d], forward
+    (`moe_combine`) or backward (`moe_dx`), where it held 8 such
+    scatter-adds (PERF.md section 6, PR 49). What it still scatters: the
+    embedding's gradient into the [V, d] table (V is 32,768 too), each
+    layer's k router weights' gradients into its [T * E] scores, and the
+    grouped matmuls' tile counts. Each of the eight sums is k = 4 gathers
+    of `bf16[32768,2048]` from a pass's `[73728,2048]`, never one value
+    with a k axis."""
+    from ray_tpu.parallel.moe import held_rows_plan
+    from ray_tpu.util import profiling
+
+    assert held_rows_plan(32768, 4, 16, 32).gathered
+    text = step[1].as_text()
+    calls = profiling.scatter_calls(text)
+    assert "f32[32768,2048]" not in calls, calls
+    assert {into: n for into, n in calls.items()
+            if not into.startswith("s32[")} == {
+        "bf16[32768,2048]": 1, "f32[1048576]": 4}, calls
+    for scope in ("moe_combine", "moe_dx"):
+        gathers = [line for line in text.splitlines()
+                   if " gather(" in line and f"/{scope}/" in line]
+        assert len(gathers) == 16, (scope, len(gathers))
+        assert all(line.split(" = ")[1].startswith("bf16[32768,2048]")
+                   for line in gathers)
+    assert not re.search(r"\[32768,4,2048\]|\[4,32768,2048\]", text)
 
 
 def test_step_fits_a_chip(step, record_property):

@@ -152,7 +152,7 @@ def test_the_experts_buffers_hold_the_held_rows_and_nothing_is_dropped(step):
     tensor."""
     from ray_tpu.parallel.moe import held_rows_plan
 
-    assert held_rows_plan(16384, 6, 16, 128) == (13824, 12288, 512)
+    assert held_rows_plan(16384, 6, 16, 128) == (13824, 12288, 512, False)
     text = step[1].as_text()
     assert "bf16[13824,2688]" in text and "bf16[13824,1856]" in text
     assert "bf16[16,2688,1856]" in text
@@ -172,6 +172,29 @@ def test_the_experts_buffers_hold_the_held_rows_and_nothing_is_dropped(step):
                         for shape in long), long
     assert not re.search(r"\[16384,128,\d+\]", text)      # [T, E, C]
     assert " while(" in text                # the passes are one loop's
+
+
+def test_a_pass_s_rows_come_back_to_their_tokens_by_scatter_adds(step):
+    """T x k = 98,304 rows gathered against 13,824 scattered, 7.1 for one:
+    the plan keeps the scatter-add here (parallel/moe.py `_gathered_back`
+    has both shapes' chip numbers), so each of the four expert layers
+    scatters into the tokens' float32 [T, d] twice a step, forward
+    (`moe_combine`) and backward (`moe_dx`), as before PR 49, and gathers
+    no [16384, 2688] rows under either scope."""
+    from ray_tpu.parallel.moe import held_rows_plan
+    from ray_tpu.util import profiling
+
+    assert not held_rows_plan(16384, 6, 16, 128).gathered
+    text = step[1].as_text()
+    assert profiling.scatter_calls(text).get("f32[16384,2688]") == 8
+    scatters = [line for line in text.splitlines() if " scatter(" in line
+                and " f32[16384,2688]" in line.split(" scatter(")[0]]
+    assert sum("/moe_combine/" in line for line in scatters) == 4
+    assert sum("/moe_dx/" in line for line in scatters) == 4
+    assert not [line for line in text.splitlines() if " gather(" in line
+                and ("/moe_combine/" in line or "/moe_dx/" in line)
+                and "[16384,2688]" in line.split(" gather(")[0]]
+    assert not re.search(r"\[16384,6,2688\]|\[6,16384,2688\]", text)
 
 
 def test_the_gated_norm_stays_in_the_projections_layout(step):

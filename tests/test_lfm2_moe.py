@@ -9,6 +9,7 @@ a cache's tail), the norm a head, gated experts in the held-expert layer,
 a channel mixer named per layer."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from ray_tpu.models.lfm2_moe import (Lfm2MoeConfig, lfm2_moe_forward,
                                      with_bias)
 from ray_tpu.ops.layers import head_rms_norm, rope
 from ray_tpu.ops.short_conv import gated_short_conv
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import (balance_bias, held_moe_layer,
                                   held_rows_plan, router_scores)
 
@@ -55,6 +57,16 @@ def form(request, monkeypatch):
         monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     else:
         monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    return request.param
+
+
+@pytest.fixture(params=["gathered", "scattered"])
+def back(request, monkeypatch):
+    """Both ways a pass's rows are added back to their tokens, at shapes
+    whose plan would name one: the plan's bound moved past every shape, or
+    under all (`held_rows_plan`; parallel/moe.py `_gathered_back`)."""
+    monkeypatch.setattr(moe, "_GATHERED_BACK_UP_TO",
+                        {"gathered": 10 ** 9, "scattered": 0}[request.param])
     return request.param
 
 
@@ -287,37 +299,77 @@ def test_the_two_shares_add_up_to_the_uncut_layer(form):
                + stats_upper["expert_rows_held"]) == 96 * 3
 
 
-@pytest.mark.parametrize("favoured,passes", [
-    ((5, 6, 7), 0), ((2, 6, 7), 1), ((1, 2, 3), 2)],
-    ids=["none-held", "one-held", "all-held"])
-def test_no_assignment_is_dropped_at_a_skewed_routing(form, favoured,
+@functools.lru_cache(maxsize=None)
+def _skewed_case(T, E, favoured):
+    """(the case, its bias, the rows it holds, the reference's output, its
+    weighted sum and that sum's gradients), once for a routing: neither the
+    kernels' form nor the way a pass's rows are added back is the
+    reference's business."""
+    k = 3
+    c = _expert_case(T=T, E=E)
+    if favoured is None:
+        # one channel that the held experts' scores rise with and the
+        # others' fall with, set high in the first half of the tokens and
+        # low in the second
+        mine = (np.arange(E) >= 1) & (np.arange(E) < 5)
+        bias = jnp.zeros((E,))
+        c["x"] = c["x"].at[:, 0].set(
+            jnp.where(jnp.arange(T) < T // 2, 2.5, -2.5))
+        c["router"] = c["router"].at[0].set(jnp.where(mine, 2.0, -2.0))
+        rows_held = T // 2 * k
+    else:
+        bias = jnp.zeros((E,)).at[jnp.array(favoured)].set(10.0)
+        rows_held = T * sum(1 <= e < 5 for e in favoured)
+    with jax.default_matmul_precision("highest"):
+        (want, plain), dwant = _with_every_gradient(
+            c, lambda g: (_plain(g, 1, 4, bias),))
+    return c, bias, rows_held, plain[0], want, dwant
+
+
+def _with_every_gradient(c, fn):
+    """((the weighted sum of fn's first output, all of its outputs), the
+    sum's gradients by the tokens and both stacks of weights), as one
+    program."""
+    def scalar(x, gate_up, down):
+        made = fn({**c, "x": x, "gate_up": gate_up, "down": down})
+        return jnp.sum(made[0] * c["wy"]), made
+    return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2),
+                                      has_aux=True))(
+        c["x"], c["gate_up"], c["down"])
+
+
+@pytest.mark.parametrize("tokens,experts,favoured,passes", [
+    (512, 8, (5, 6, 7), 0), (512, 8, (2, 6, 7), 1), (512, 8, (1, 2, 3), 2),
+    (512, 8, None, 1), (1200, 32, (1, 2, 3), 8)],
+    ids=["none-held", "one-held", "all-held", "split", "all-held-of-32"])
+def test_no_assignment_is_dropped_at_a_skewed_routing(form, back, tokens,
+                                                      experts, favoured,
                                                       passes):
     """A bias no score outweighs puts every token on three experts: none
     of them held (no pass of the buffers), one held, or all three held
     (T * k rows where the buffers hold a balanced share and an eighth: two
-    passes). The output and the gradients are the reference's at each."""
-    c = _expert_case(T=512)
-    bias = jnp.zeros((8,)).at[jnp.array(favoured)].set(10.0)
-    plan = held_rows_plan(512, 3, 4, 8)
-    assert plan.rows == 1024 and plan.balanced == 768
+    passes where 4 of 8 experts are held, eight where 4 of 32 are); or,
+    split, the first half of the tokens have all three of theirs held and
+    the second half none. The output and the gradients are the
+    reference's at each, by either way of adding a pass's rows back."""
+    T, E, k = tokens, experts, 3
+    c, bias, rows_held, plain, want, dwant = _skewed_case(T, E, favoured)
+    plan = held_rows_plan(T, k, 4, E)
+    assert (plan.rows, plan.balanced) == {8: (1024, 768), 32: (512, 450)}[E]
+    assert plan.gathered == (back == "gathered")
 
-    def every(fn):
-        def scalar(x, gate_up, down):
-            given = {**c, "x": x, "gate_up": gate_up, "down": down}
-            return jnp.sum(fn(given) * c["wy"])
-        return jax.value_and_grad(scalar, argnums=(0, 1, 2))(
-            c["x"], c["gate_up"], c["down"])
-
-    out, stats = _held(c, 1, 4, bias)
-    held = sum(1 <= e < 5 for e in favoured)
-    assert int(stats["expert_rows_held"]) == 512 * held
+    (got, (out, stats)), dgot = _with_every_gradient(
+        c, lambda g: _held(g, 1, 4, bias))
+    assert int(stats["expert_rows_held"]) == rows_held
     assert int(stats["expert_passes"]) == passes
-    want, dwant = every(lambda g: _plain(g, 1, 4, bias))
-    got, dgot = every(lambda g: _held(g, 1, 4, bias)[0])
     _close(got, want)
     for g, w in zip(dgot, dwant):
         _close(g, w)
-    _close(out, _plain(c, 1, 4, bias))
+    _close(out, plain)
+    if favoured is None:
+        # the second half's rows are the reference's zeros, none of
+        # another token's rows
+        assert not np.asarray(out[T // 2:]).any()
 
 
 def test_the_weights_small_number_is_the_callers():

@@ -9,6 +9,7 @@ where a kernel is involved: jax.numpy (what the CPU runs) and the Pallas
 kernels interpreted."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,7 @@ from ray_tpu.ops.grouped_matmul import (grouped_matmul, grouped_matmul_grads,
                                         past_groups_zeroed)
 from ray_tpu.ops.layers import gated_rms_norm, rms_norm
 from ray_tpu.ops.ssm_scan import ssm_scan, ssm_scan_plan
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import (balance_bias, held_moe_layer,
                                   held_rows_plan, router_scores)
 
@@ -46,6 +48,16 @@ def form(request, monkeypatch):
         monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
     else:
         monkeypatch.delenv("RAY_TPU_PALLAS_INTERPRET", raising=False)
+    return request.param
+
+
+@pytest.fixture(params=["gathered", "scattered"])
+def back(request, monkeypatch):
+    """Both ways a pass's rows are added back to their tokens, at shapes
+    whose plan would name one: the plan's bound moved past every shape, or
+    under all (`held_rows_plan`; parallel/moe.py `_gathered_back`)."""
+    monkeypatch.setattr(moe, "_GATHERED_BACK_UP_TO",
+                        {"gathered": 10 ** 9, "scattered": 0}[request.param])
     return request.param
 
 
@@ -278,74 +290,119 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(form):
     _close(sum(parts) + shared, whole)
 
 
-@pytest.mark.parametrize("routing", ["one_held", "none_held", "all_held",
-                                     "seeded", "just_over_a_pass"])
-def test_no_assignment_is_dropped_at_any_routing(form, routing):
-    """All tokens to one held expert (and two absent), to none, every
-    assignment held, a seeded spread, and all to one held expert with a
-    few to the others besides: output and every gradient equal the
-    reference's under the same bias, in as many passes of the buffers'
-    rows as the held rows take (480 tokens x 3 over 4 of 16 experts:
-    buffers of 512 rows, not 1,440)."""
-    T, first, held, E, k = 480, 4, 4, 16, 3
-    w = _layer_weights(seed=1, T=T)
-    R = held_rows_plan(T, k, held, E).rows
-    assert R == 512 < T * k
-    absent = [e for e in range(E) if not first <= e < first + held]
+_ROUTINGS = ("one_held", "none_held", "all_held", "seeded",
+             "just_over_a_pass", "split", "eight_passes")
+
+
+@functools.lru_cache(maxsize=None)
+def _routing_case(routing, first=4, held=4, k=3):
+    """(the layer's arguments, the pushed bias, the weights of the scalar
+    that is differentiated, the reference's (output, chosen experts) and
+    its gradients), once for a routing: neither the kernels' form nor the
+    way a pass's rows are added back is the reference's business."""
+    T, E = (1200, 32) if routing == "eight_passes" else (480, 16)
+    w = _layer_weights(seed=1, T=T, E=E)
+    mine = (np.arange(E) >= first) & (np.arange(E) < first + held)
+    absent = [e for e in range(E) if not mine[e]]
     push = {"one_held": [first + 1] + absent[:2], "none_held": absent[:3],
             "all_held": [first, first + 1, first + 3], "seeded": [],
-            "just_over_a_pass": [first + 1, absent[0]]}[routing]
+            "just_over_a_pass": [first + 1, absent[0]], "split": [],
+            "eight_passes": [first, first + 1, first + 3]}[routing]
     bias = w["bias"].at[jnp.array(push, jnp.int32)].add(10.0)
+    if routing == "split":
+        # one channel that the held experts' scores rise with and the
+        # others' fall with, set high in the first half of the tokens and
+        # low in the second
+        w["x"] = w["x"].at[:, 0].set(
+            jnp.where(jnp.arange(T) < T // 2, 2.5, -2.5))
+        w["router"] = w["router"].at[0].set(jnp.where(mine, 2.0, -2.0))
+    given = (w["x"], w["router"], w["up"][first:first + held],
+             w["down"][first:first + held], w["s_up"], w["s_down"])
+    weights = jax.random.normal(jax.random.PRNGKey(9), w["x"].shape)
+
+    def plain(x, router, up, down, s_up, s_down):
+        return reference._plain_experts(x, router, bias, up, down, s_up,
+                                        s_down, k=k, first=first, scale=2.5)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (want, chosen, _)), want_grads = _every(plain, weights)(*given)
+    return given, bias, weights, (want, chosen), want_grads
+
+
+def _every(fn, weights):
+    """fn's result, and the gradient by every argument of its first
+    output's weighted sum, as one program."""
+    def scalar(*given):
+        made = fn(*given)
+        return jnp.sum(made[0] * weights), made
+    return jax.jit(jax.value_and_grad(scalar, argnums=tuple(range(6)),
+                                      has_aux=True))
+
+
+@pytest.mark.parametrize("routing", _ROUTINGS)
+def test_no_assignment_is_dropped_at_any_routing(form, back, routing):
+    """All tokens to one held expert (and two absent), to none, every
+    assignment held, a seeded spread, all to one held expert with a few
+    to the others besides, half the tokens with all k of theirs held and
+    the other half with none, and every assignment held where 4 of 32
+    experts are (eight passes): output and every gradient equal the
+    reference's under the same bias, by either way of adding a pass's
+    rows back, in as many passes of the buffers' rows as the held rows
+    take (480 tokens x 3 over 4 of 16 experts: buffers of 512 rows, not
+    1,440)."""
+    first, held, k = 4, 4, 3
+    given, bias, weights, (want, chosen), want_grads = _routing_case(routing)
+    T, E = given[0].shape[0], given[1].shape[1]
+    assert (T, E) == ((1200, 32) if routing == "eight_passes" else (480, 16))
+    plan = held_rows_plan(T, k, held, E)
+    R = plan.rows
+    assert R == 512 < T * k and plan.gathered == (back == "gathered")
 
     def program(x, router, up, down, s_up, s_down):
         return held_moe_layer(x, router, bias, up, down, s_up, s_down,
                               experts_per_token=k, first=first,
                               routed_scale=2.5)
 
-    def plain(x, router, up, down, s_up, s_down):
-        return reference._plain_experts(x, router, bias, up, down, s_up,
-                                        s_down, k=k, first=first, scale=2.5)
-
-    given = (w["x"], w["router"], w["up"][first:first + held],
-             w["down"][first:first + held], w["s_up"], w["s_down"])
-    weights = jax.random.normal(jax.random.PRNGKey(9), w["x"].shape)
-
-    def every(fn):
-        return jax.value_and_grad(
-            lambda *t: (jnp.sum(fn(*t)[0] * weights), fn(*t)),
-            argnums=tuple(range(6)), has_aux=True)
-
-    (_, (out, stats)), grads = every(program)(*given)
-    (_, (want, chosen, _)), want_grads = every(plain)(*given)
+    (_, (out, stats)), grads = _every(program, weights)(*given)
     _close(out, want)
     for got, wanted in zip(grads, want_grads):
         _close(got, wanted)
-    in_share = int(jnp.sum((chosen >= first) & (chosen < first + held)))
+    of_mine = jnp.sum((chosen >= first) & (chosen < first + held), axis=-1)
+    in_share = int(jnp.sum(of_mine))
     assert int(stats["expert_rows_held"]) == in_share
     rows, passes = {"one_held": (T, 1), "none_held": (0, 0),
                     "all_held": (T * k, -(-T * k // R)), "seeded": (None, 1),
-                    "just_over_a_pass": (None, 2)}[routing]
+                    "just_over_a_pass": (None, 2), "split": (T // 2 * k, 2),
+                    "eight_passes": (T * k, 8)}[routing]
     if rows is not None:
         assert in_share == rows
     if routing == "just_over_a_pass":
         assert R < in_share < R + R // 4
+    if routing == "split":
+        np.testing.assert_array_equal(
+            of_mine, np.where(np.arange(T) < T // 2, k, 0))
     assert int(stats["expert_passes"]) == passes == -(-in_share // R)
 
 
-@pytest.mark.parametrize("tokens,k,held,experts,rows,balanced", [
-    (16384, 6, 16, 128, 13824, 12288),  # the cell's: 27 tiles, not 192
-    (16384, 6, 128, 128, 98304, 98304),     # every expert held: T x k
-    (2048, 6, 16, 128, 2048, 1536),         # 1,728 rounded up to the tile
-    (480, 3, 4, 16, 512, 360),
-    (64, 3, 4, 16, 192, 48),                # under a tile: T x k
-    (1, 6, 16, 128, 6, 1), (3, 6, 16, 128, 18, 3)])     # a decode step's
+@pytest.mark.parametrize("tokens,k,held,experts,rows,balanced,gathered", [
+    # the cell's: 27 tiles, not 192, and 7.1 rows gathered for one scattered
+    (16384, 6, 16, 128, 13824, 12288, False),
+    (32768, 4, 16, 32, 73728, 65536, True),     # LFM2's cell: 1.78 for one
+    (16384, 6, 128, 128, 98304, 98304, True),   # every expert held: T x k
+    (2048, 6, 16, 128, 2048, 1536, False),      # 1,728 rounded up to the tile
+    (480, 3, 4, 16, 512, 360, False),            # 2.8 for one
+    (64, 3, 4, 16, 192, 48, True),              # under a tile: T x k
+    (1, 6, 16, 128, 6, 1, True), (3, 6, 16, 128, 18, 3, True)])   # a decode
 def test_the_buffers_rows_come_from_the_shapes(tokens, k, held, experts,
-                                               rows, balanced):
+                                               rows, balanced, gathered):
     plan = held_rows_plan(tokens, k, held, experts)
-    assert plan == (rows, balanced, 512)
+    assert plan == (rows, balanced, 512, gathered)
     assert rows <= tokens * k
     assert rows == tokens * k or (
         rows % plan.tile == 0 and 8 * rows >= 9 * balanced)
+    # the form follows from the shapes alone: T x k rows gathered against
+    # `rows` scattered
+    assert gathered == (tokens * k <= moe._GATHERED_BACK_UP_TO * rows)
 
 
 def test_the_bias_picks_and_never_weighs():
