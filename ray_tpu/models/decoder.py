@@ -856,22 +856,33 @@ def _scaled(t, scale: float):
     return t if scale == 1.0 else t * scale
 
 
+# The scope of a kind's sequence-mixer branch, by the key of MIXERS: what
+# util/profiling.by_scope files a block's device time under. Two kinds
+# that run one mixer share its name.
+MIXER_SCOPES: Dict[str, str] = {
+    kind: kind.removesuffix("_only") + "_mixer" for kind in MIXERS
+    if MIXERS[kind].apply is not None}
+
+
 def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
            dec: Decoder, kind: str, mlp: Optional[Callable] = None,
            index: int = 0, window=None):
     """One layer: its kind's sequence mixer and `mlp`, the layer's channel
-    mixer (None where the kind's block has no such branch)."""
+    mixer (None where the kind's block has no such branch), each branch
+    with its norms and its residual add under a scope of its own."""
     eps, row = dec.norm_eps, MIXERS[kind]
     stats, new_cache = None, cache
     if row.apply is not None:
-        y, new_cache, shared = row.apply(
-            x, layer, dec, cache, start_pos, shared, index, window)
-        x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
-                        dec.residual_scale)
+        with jax.named_scope(MIXER_SCOPES[kind]):
+            y, new_cache, shared = row.apply(
+                x, layer, dec, cache, start_pos, shared, index, window)
+            x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
+                            dec.residual_scale)
     if row.channel:
-        out, stats = mlp(_norm_if_held(x, layer, "ln2", eps), layer)
-        out = _norm_if_held(out, layer, "post_feedforward", eps)
-        x = x + _scaled(out, dec.residual_scale)
+        with jax.named_scope("channel_mixer"):
+            out, stats = mlp(_norm_if_held(x, layer, "ln2", eps), layer)
+            out = _norm_if_held(out, layer, "post_feedforward", eps)
+            x = x + _scaled(out, dec.residual_scale)
     return x, stats, new_cache, shared
 
 
@@ -891,9 +902,10 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     # as one sum (ops/loss.py chip_views).
     views = None if "head" in params or cache is not None \
         else chip_views(params["embed"])
-    x = lookup(views, tokens) if views is not None \
-        else jnp.take(params["embed"], tokens, axis=0)
-    x = _scaled(x, dec.embed_scale)
+    with jax.named_scope("embed"):
+        x = lookup(views, tokens) if views is not None \
+            else jnp.take(params["embed"], tokens, axis=0)
+        x = _scaled(x, dec.embed_scale)
     layers = params["layers"]
 
     @functools.cache
@@ -918,7 +930,8 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
                 x, layer, cache_layer, start_pos, shared)
             per_layer += [] if stats is None else [stats]
             new_cache.append(cache_layer)
-    x = _scaled(_norm(x, params, "lnf", dec.norm_eps), dec.logit_scale)
+    with jax.named_scope("final_norm"):
+        x = _scaled(_norm(x, params, "lnf", dec.norm_eps), dec.logit_scale)
     if views is not None:
         head = views.swapaxes(1, 2)
     else:

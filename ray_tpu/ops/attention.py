@@ -1016,6 +1016,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, window):
     return out, (q, k, v, o_saved, lse)
 
 
+@jax.named_scope("flash_attention_bwd")
 def _flash_bwd(causal, sm_scale, window, residuals, g):
     q, k, v, o, lse = residuals
     scale = _scale_of(q, sm_scale)

@@ -702,6 +702,7 @@ def _run_forward(q, k, v, g, beta, init, chunk, keep_inverse: bool):
         chunk=chunk, H=H, keep_inverse=keep_inverse)
 
 
+@jax.named_scope("gated_delta_fwd")
 def _rule_fwd(q, k, v, g, beta, init, chunk):
     if not _kernel_ok(q, chunk):
         out = gated_delta_reference(q, k, v, g, beta, chunk, init)
@@ -719,6 +720,7 @@ def _rule_fwd(q, k, v, g, beta, init, chunk):
                                          inverse)
 
 
+@jax.named_scope("gated_delta_bwd")
 def _rule_bwd(chunk, residuals, cotangents):
     q, k, v, g, beta, init, states, inverse = residuals
     do, dfinal = cotangents

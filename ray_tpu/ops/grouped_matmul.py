@@ -278,6 +278,7 @@ def _fwd(lhs, rhs, group_sizes):
     return _forward(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
 
 
+@jax.named_scope("grouped_matmul_bwd")
 def grouped_matmul_grads(lhs, rhs, group_sizes, g):
     """(dlhs, drhs) of `grouped_matmul(lhs, rhs, group_sizes)` for the
     output's cotangent `g`: its own gradient rule, and what a rule that

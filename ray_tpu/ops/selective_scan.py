@@ -416,6 +416,7 @@ def _state_untiled(s):
     return s.reshape(b, N, rows * lanes).swapaxes(1, 2)
 
 
+@jax.named_scope("selective_scan_fwd")
 def _scan_fwd(x, dt, A, B, C, init):
     b, S, channels = x.shape
     if not _kernel_ok(channels):
@@ -438,6 +439,7 @@ def _scan_fwd(x, dt, A, B, C, init):
     return (m, _state_untiled(states[:, -1])), (x, dt, A, B, C, init, states)
 
 
+@jax.named_scope("selective_scan_bwd")
 def _scan_bwd(residuals, cotangents):
     x, dt, A, B, C, init, states = residuals
     dm, dfinal = cotangents

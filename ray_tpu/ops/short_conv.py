@@ -278,6 +278,7 @@ def _gated_conv_fwd(bcx, weight):
     return _forward_call(bcx, weight), (bcx, weight)
 
 
+@jax.named_scope("short_conv_bwd")
 def _gated_conv_bwd(residuals, dy):
     return _backward_call(*residuals, dy)
 

@@ -572,6 +572,7 @@ def _zero_skip(x):
     return jnp.zeros((x.shape[2],), jnp.float32)
 
 
+@jax.named_scope("ssm_scan_fwd")
 def _scan_fwd(x, dt, a, B, C, init, chunk):
     if not _kernel_ok(x, B, chunk):
         out = ssm_scan_reference(x, dt, a, B, C, _zero_skip(x), chunk, init)
@@ -593,6 +594,7 @@ def _scan_fwd(x, dt, a, B, C, init, chunk):
             (x, dt, a, B, C, init, states))
 
 
+@jax.named_scope("ssm_scan_bwd")
 def _scan_bwd(chunk, residuals, cotangents):
     x, dt, a, B, C, init, states = residuals
     dy, dfinal = cotangents

@@ -208,6 +208,7 @@ def _experts_fwd(x, weights, w_gate, w_up, w_down, perm, inv, counts):
                  w_sorted, w_gate, w_up, w_down, perm, inv, counts)
 
 
+@jax.named_scope("moe_experts_bwd")
 def _experts_bwd(residuals, dout):
     xs, gate, up, w_sorted, w_gate, w_up, w_down, perm, inv, counts = residuals
     k, f32 = inv.shape[0] // dout.shape[0], jnp.float32
@@ -554,6 +555,7 @@ def _held_experts_fwd(plan, gated, x, weights, w_up, w_down, perm, inv,
     return out.astype(x.dtype), (x, weights, w_up, w_down, perm, inv, counts)
 
 
+@jax.named_scope("moe_experts_bwd")
 def _held_experts_bwd(plan, gated, residuals, dout):
     x, weights, w_up, w_down, perm, inv, counts = residuals
     rows, k, f32 = plan.rows, weights.shape[1], jnp.float32

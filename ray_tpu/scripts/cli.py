@@ -230,7 +230,8 @@ def cmd_profile(args):
     """Profile the chip path of a running actor (a Serve replica, a
     TrainWorker) from inside its own process: device operations beside
     the program's `ray_tpu.*` host spans (util/profiling.py), for
-    xprof/TensorBoard or jax.profiler.ProfileData.from_file."""
+    xprof/TensorBoard or jax.profiler.ProfileData.from_file; with
+    --by-scope, the captured steps' device time by the program's scopes."""
     call = _backend(args)
     out = call("profile_actor", args.actor, args.seconds)
     path = args.output or f"profile_{out['pid']}_{int(time.time())}" \
@@ -240,6 +241,18 @@ def cmd_profile(args):
     print(f"wrote {len(out['xplane'])} bytes of pid {out['pid']}'s "
           f"profile to {path} ({args.seconds:g} s from unix ns "
           f"{out['start_unix_ns']}; stop took {out['stop_s']:.2f} s)")
+    if args.by_scope:
+        from ray_tpu.util import profiling
+        trace = profiling.read_device_events(path)
+        if not trace["events"]:
+            print("no device plane (/device:TPU:n) in this trace: only a "
+                  "process that owns a chip writes one")
+            return 0
+        events, steps = profiling.step_events(trace["events"],
+                                              trace["modules"])
+        print(f"{trace['plane']}, by the program's scopes "
+              f"(util/profiling.py DEVICE_SCOPES):")
+        print(profiling.format_by_scope(profiling.by_scope(events, steps)))
     return 0
 
 
@@ -453,6 +466,9 @@ def build_parser() -> argparse.ArgumentParser:
                     "(`ray_tpu list actors`)")
     sp.add_argument("--seconds", type=float, default=5.0)
     sp.add_argument("-o", "--output", default=None)
+    sp.add_argument("--by-scope", action="store_true",
+                    help="print the device time of the captured steps by "
+                    "the program's scopes (profiling.by_scope)")
     add_address(sp)
     sp.set_defaults(fn=cmd_profile)
 

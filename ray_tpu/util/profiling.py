@@ -17,6 +17,11 @@ train/, data/) puts names into that trace:
   * `profile_actor(actor, seconds)`: the same, taken by another process's
     worker for an operator (`ray_tpu profile`): only the process that
     owns a chip can trace it.
+  * `read_device_events(xplane)`, `step_events`, `by_scope(events, steps,
+    compiled_text)`: a trace's device time by DEVICE_SCOPES' names, a row
+    a scope path and pass (`ray_tpu profile --by-scope`,
+    chipbench/scope_profile.py). Offline arithmetic: nothing of it runs
+    in a step.
 
 One clock: a read-back trace counts every event's `start_ns`, host and
 device, from its own `profile_start_time`, which is unix nanoseconds
@@ -31,6 +36,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import glob
 import math
 import os
@@ -133,25 +139,32 @@ DEVICE_SCOPES: Dict[str, str] = {
                            "weights",
     "ssm_scan_fwd": "ops/ssm_scan.py _scan_forward_call, the _ssm_fwd_kernel "
                     "pallas_call: a Mamba-2 layer's chunked selective "
-                    "scan, y and one state a chunk",
+                    "scan, y and one state a chunk; and round it _scan_fwd "
+                    "(the chunk sums, the operands' layouts)",
     "ssm_scan_bwd": "ops/ssm_scan.py _scan_backward_call, the _ssm_bwd_kernel "
                     "pallas_call: every gradient of the scan, chunks last "
-                    "to first",
+                    "to first; and round it _scan_bwd, the whole rule (the "
+                    "chunk sums' transpose, the gradients' layouts)",
     "selective_scan_fwd": "ops/selective_scan.py _scan_forward_call, the "
                           "_selective_fwd_kernel pallas_call: a Mamba-1 "
-                          "layer's selective scan, m and one state a chunk",
+                          "layer's selective scan, m and one state a chunk; "
+                          "and round it _scan_fwd (the operands padded and "
+                          "tiled in float32)",
     "selective_scan_bwd": "ops/selective_scan.py _scan_backward_call, the "
                           "_selective_bwd_kernel pallas_call: a chunk's "
                           "states again, then every gradient of the scan, "
-                          "chunks last to first",
+                          "chunks last to first; and round it _scan_bwd, the "
+                          "whole rule (the float32 tiles, B's and C's sums)",
     "gated_delta_fwd": "ops/gated_delta.py _forward_call, the "
                        "_gd_fwd_kernel pallas_call: a linear-attention "
                        "layer's chunked gated delta rule, o and the state "
-                       "entering each chunk",
+                       "entering each chunk; and round it _rule_fwd (the "
+                       "chunk sums of g)",
     "gated_delta_bwd": "ops/gated_delta.py _backward_call, the "
                        "_gd_bwd_kernel pallas_call: a chunk's inverse, W, U "
                        "and V' again, then every gradient of the rule, "
-                       "chunks last to first",
+                       "chunks last to first; and round it _rule_bwd, the "
+                       "whole rule",
     "short_conv_fwd": "ops/short_conv.py _forward_call, the "
                       "_conv_fwd_kernel pallas_call: a gated short "
                       "convolution's y = C * conv(B * x) from the "
@@ -159,14 +172,16 @@ DEVICE_SCOPES: Dict[str, str] = {
     "short_conv_bwd": "ops/short_conv.py _backward_call, the "
                       "_conv_bwd_kernel pallas_call: the convolution made "
                       "again, the gradients by B, C and x and the taps' "
-                      "float32 partial sums",
+                      "float32 partial sums; and round it _gated_conv_bwd, "
+                      "the whole rule (the padding, the taps' sum)",
     "short_conv_proj": "models/decoder.py short_conv: the input "
                        "projection to B | C | x and the output projection "
                        "round the convolution's kernels",
     "ssm_conv": "models/decoder.py mamba2, mamba1 and gated_delta: the "
                 "causal depthwise convolution (over x | B | C; Mamba-1: "
                 "over x; the delta rule: over q | k | v, no bias) and its "
-                "silu",
+                "silu; ops/layers.py _conv_silu_bwd runs under it too, as "
+                "every rule does under the scopes round its call",
     "delta_qk_norm": "models/decoder.py gated_delta: q and k divided by "
                      "their L2 norm a head, q scaled by 1 / sqrt(key "
                      "width)",
@@ -205,9 +220,38 @@ DEVICE_SCOPES: Dict[str, str] = {
               "under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
             "backbone: the scan over chunks of rows, forward and "
-            "gradient in one pass",
+            "gradient in one pass (_sum_ll_bwd, which scales it, runs "
+            "under the same scope: it is round the rule's call)",
     "optimizer_update": "models/_training.py train_step, optimizer "
                         "update and apply",
+    # The boundaries of a block, and the hand-written backward rules that
+    # no kernel's name covers: what `by_scope` files a step's time under.
+    **{kind + "_mixer": "models/decoder.py _block: the sequence-mixer "
+                        f"branch of a `{kind}` block (decoder.MIXER_SCOPES; "
+                        "a kind `*_only` runs under its mixer's name), from "
+                        "the norm the mixer reads to the residual add"
+       for kind in ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
+                    "diff_windowed", "diff_full", "diff_cross", "short_conv")},
+    "channel_mixer": "models/decoder.py _block: the channel-mixer branch "
+                     "of a block, dense, routed or held experts alike "
+                     "(`ln2`, the layer's `mlp`, `post_feedforward`, the "
+                     "residual add); the moe_* scopes lie inside it",
+    "embed": "models/decoder.py decoder_hidden: the embedding's lookup "
+             "and scale; its backward is the table's scatter-add",
+    "final_norm": "models/decoder.py decoder_hidden: the last norm and "
+                  "the logit scale (the head's matmuls are `loss`'s)",
+    "flash_attention_bwd": "ops/attention.py _flash_bwd, the whole rule: "
+                           "delta = rowsum(dO * O), the operands' layouts "
+                           "and, inside it, the dq and dkv kernels' scopes",
+    "grouped_matmul_bwd": "ops/grouped_matmul.py grouped_matmul_grads, "
+                          "both gradients of one grouped matmul: the work "
+                          "lists and, inside it, the dlhs and drhs "
+                          "kernels' scopes",
+    "moe_experts_bwd": "parallel/moe.py _experts_bwd and "
+                       "_held_experts_bwd, the whole rule: the rows "
+                       "gathered again, the activation's slope, the "
+                       "weights' gradients summed over the passes; "
+                       "moe_dx and grouped_matmul_bwd lie inside it",
     "prefill": "models/generate.py prefill / insert_prefill, round "
                "models/decoder.py's stack with a cache",
     "decode": "models/generate.py decode_step / decode_batch, round the "
@@ -244,8 +288,8 @@ def kernel_calls(compiled_text: str) -> Dict[str, int]:
         if not eq or 'custom_call_target="tpu_custom_call"' not in rest:
             continue
         name = name.removeprefix("ROOT ").lstrip("%")
-        scope = next((s for s in DEVICE_SCOPES if s in name),
-                     name.rstrip(".0123456789"))
+        scope = max((s for s in DEVICE_SCOPES if s in name), key=len,
+                    default=name.rstrip(".0123456789"))
         calls[scope] = calls.get(scope, 0) + 1
     return calls
 
@@ -258,6 +302,7 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
 _ARRAY = re.compile(r"\b(%s)\[([\d,]*)\]" % "|".join(_DTYPE_BYTES))
 _OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
 _CALLEE = re.compile(r"calls=%?([\w.-]+)")
+_OPERAND = re.compile(r"[^()]*?%([\w.-]+)")     # an instruction's first
 _ASYNC_FUSION = re.compile(r'custom_call_target="AsyncCollective(Start|Done)"')
 # What a collective can run under: whatever the schedule gives the
 # TensorCore to do meanwhile.
@@ -438,12 +483,387 @@ def scope_writes(compiled_text: str, scope: str) -> Dict[str, Any]:
     writes = []
     for name, shape, op, rest in body:
         held = _OP_NAME.search(rest)
-        if op in _NO_WRITE or not held or scope not in held.group(1):
+        if op in _NO_WRITE or not held or not re.search(
+                r"\b%s\b" % re.escape(scope), held.group(1)):
             continue
         writes.append({"name": name, "opcode": op,
                        "results": _array_bytes(shape)})
     return {"instructions": len(writes), "writes": writes,
             "bytes": sum(sum(w["results"]) for w in writes)}
+
+
+# jax's wrappers round a component of an `op_name` path: `transpose(jvp(
+# layers))` is the scope `layers` under two of them. `jit(f)` and `pjit(f)`
+# hold a function's name, which is no scope.
+_WRAPPED = re.compile(r"^([A-Za-z_][\w.]*)\((.*)\)$")
+_FUNCTION_WRAPPERS = ("jit", "pjit")
+# Scopes that say which program or stack an operation is in, not which
+# branch of a block: time under these alone is not covered.
+_NOT_A_BOUNDARY = ("layers", "prefill", "decode")
+_PASSES = ("forward", "remade", "backward")
+
+
+def scope_path(op_name: str) -> tuple:
+    """(the DEVICE_SCOPES names of an `op_name` path, outermost first; the
+    pass). jax's wrappers are unwrapped (`jvp(...)`, `transpose(...)`,
+    `vmap(...)`; the plain components `checkpoint`, `rematted_computation`,
+    `while/body`, `cond/branch_*`, `shard_map`, `custom_vjp_call`,
+    `closed_call` and every primitive's name are no scopes and fall out).
+    The pass is `remade` under a rematerialised block's second run (a
+    `rematted_computation` component), else `backward` under a
+    `transpose(...)`, else `forward`. Under `jax.checkpoint` the block's
+    own path starts again after the call's (`transpose(jvp(layers))/
+    jvp(layers)/checkpoint/...`): a path whose beginning repeats at once
+    counts it once, so `layers/layers/x` is `layers/x`, while a kernel's
+    scope inside its rule's (`x/ssm_scan_bwd/ssm_scan_bwd`) stays two."""
+    names, transposed, remade = [], False, False
+    for part in op_name.split(";")[0].split("/"):
+        inner = True
+        while (m := _WRAPPED.match(part)):
+            transposed |= m.group(1) == "transpose"
+            inner &= m.group(1) not in _FUNCTION_WRAPPERS
+            part = m.group(2)
+        remade |= part == "rematted_computation"
+        if inner and part in DEVICE_SCOPES:
+            names.append(part)
+    for n in range(len(names) // 2, 0, -1):
+        if names[:n] == names[n:2 * n]:
+            del names[:n]
+            break
+    return tuple(names), ("remade" if remade else
+                          "backward" if transposed else "forward")
+
+
+def _instruction(event_name: str) -> tuple:
+    """(instruction name, opcode, result shape without layouts) of a device
+    event named, as the v5e names them, by its whole HLO instruction."""
+    name, eq, rest = event_name.partition(" = ")
+    op = _OPCODE.search(rest) if eq else None
+    if op is None:
+        return name.lstrip("%"), name.lstrip("%"), ""
+    return (name.lstrip("%"), op.group(1),
+            re.sub(r"\{[^{}]*\}", "", rest[:op.start()]).strip())
+
+
+def _self_times(events) -> list:
+    """Each event's duration less what events inside it cover: at every
+    instant the time is the latest-started event's that is still open, so
+    a `while` does not count its body twice and the self times sum to the
+    union of the intervals whatever overlaps."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][2], -events[i][3]))
+    own, open_, now = [0.0] * len(events), [], 0.0
+    for i in [*order, None]:
+        start = float("inf") if i is None else events[i][2]
+        while open_:
+            top = open_[-1]
+            end = events[top][2] + events[top][3]
+            if end > start:
+                own[top] += max(0.0, start - now)
+                break
+            own[top] += max(0.0, end - now)
+            now = max(now, end)
+            open_.pop()
+        now = max(now, start) if open_ else start
+        open_.append(i)
+    return own
+
+
+def mixed_fusions(compiled_text: str) -> Dict[str, list]:
+    """fusion instruction -> the scope paths its fused computation holds,
+    for the fusions that hold more than one: XLA fused work of two scopes
+    and the row can stand under one name only. Instructions with no
+    `op_name` (parameters, constants) and those under no scope of ours (a
+    cast of the weights under `jit(train_step)` alone) say nothing: one
+    scope's work with such an instruction beside it is not flagged."""
+    return _mixed(_computations(compiled_text))
+
+
+def _mixed(computations: Dict[str, list]) -> Dict[str, list]:
+    found = {}
+    for body in computations.values():
+        for name, _, op, rest in body:
+            callee = _CALLEE.search(rest) if op == "fusion" else None
+            inside = {"/".join(scope_path(m.group(1))[0])
+                      for *_, r in computations.get(
+                          callee.group(1) if callee else "", ())
+                      if (m := _OP_NAME.search(r))} - {""}
+            if len(inside) > 1:
+                found[name] = sorted(inside)
+    return found
+
+
+def by_scope(events, steps: int,
+             compiled_text: Optional[str] = None) -> Dict[str, Any]:
+    """A device trace's time by the program's own names. `events` are one
+    plane's "XLA Ops" events (name, tf_op, start_ns, duration_ns), as
+    `read_device_events` gives them; `steps` the runs of the step's program
+    they span (`step_events` counts them); `compiled_text` the step's
+    `compile().as_text()` where the caller has it. Plain arithmetic, no
+    file format. Times in ms a step.
+
+        steps, busy_ms_per_step    busy: the union of the events' intervals
+        coverage                   share of busy time under a scope that
+                                   names a branch of a block or finer
+                                   (anything but `layers` alone)
+        scopes   {path: {forward_ms, remade_ms, backward_ms, calls,
+                  mixed_ms}}       `path` the event's DEVICE_SCOPES names
+                                   joined by "/", outermost first
+                                   (`scope_path`); `calls` events a step
+        unscoped [{opcode, shape, ms, calls, after}]: events with no
+                                   `op_name` or none of whose components
+                                   is a known name, by opcode and result
+                                   shape; the twenty largest, then one row
+                                   "(rest)". `after` (with `compiled_text`)
+                                   is the path of the instruction that made
+                                   the row's first operand, where most of
+                                   its time has one: whose output XLA's own
+                                   `copy` lays out again
+
+    Every event counts its SELF time (`_self_times`): the rows, scoped and
+    unscoped, sum to busy exactly, which is asserted. An event with no
+    `tf_op` takes its instruction's `op_name` from `compiled_text`. A
+    fusion that `mixed_fusions` flags counts under the scope its own
+    `op_name` gives and again under that scope's `mixed_ms`: how much of a
+    row may be another scope's work, with no time split by guesswork."""
+    events = [e for e in events if e[3] > 0]
+    own = _self_times(events)
+    busy = 0.0
+    covered_to = float("-inf")
+    for _, _, start, duration in sorted(events, key=lambda e: e[2]):
+        if start + duration > covered_to:
+            busy += start + duration - max(start, covered_to)
+            covered_to = start + duration
+    names, fed_by, mixed = {}, {}, {}
+    if compiled_text:
+        computations = _computations(compiled_text)
+        mixed = _mixed(computations)
+        for body in computations.values():
+            for name, _, _, rest in body:
+                if (m := _OP_NAME.search(rest)):
+                    names[name] = m.group(1)
+                if (m := _OPERAND.match(rest)):
+                    fed_by[name] = m.group(1)
+
+    def after(instruction):
+        """The path of what made the first operand, a few hops back."""
+        for _ in range(4):
+            instruction = fed_by.get(instruction)
+            path = scope_path(names.get(instruction, ""))[0]
+            if path or instruction is None:
+                return "/".join(path)
+        return ""
+    per = 1e-6 / max(1, steps)
+    scopes, unscoped, covered = {}, {}, 0.0
+    for (name, tf_op, _, _), mine in zip(events, own):
+        instruction, opcode, shape = _instruction(name)
+        path, which = scope_path(tf_op or names.get(instruction, ""))
+        if not path:
+            row = unscoped.setdefault((opcode, shape), [0.0, 0, {}])
+            row[0] += mine
+            row[1] += 1
+            if fed_by:
+                made = after(instruction)
+                row[2][made] = row[2].get(made, 0.0) + mine
+            continue
+        row = scopes.setdefault("/".join(path), {
+            **{p + "_ms": 0.0 for p in _PASSES}, "calls": 0, "mixed_ms": 0.0})
+        row[which + "_ms"] += mine * per
+        row["calls"] += 1
+        if instruction in mixed:
+            row["mixed_ms"] += mine * per
+        if set(path) - set(_NOT_A_BOUNDARY):
+            covered += mine
+    total = sum(own)
+    assert abs(total - busy) <= 1e-6 * max(busy, 1.0), (total, busy)
+    for row in scopes.values():
+        row["calls"] /= max(1, steps)
+    ranked = sorted(unscoped.items(), key=lambda kv: -kv[1][0])
+    listed = [{"opcode": op, "shape": shape, "ms": ns * per,
+               "calls": n / max(1, steps),
+               "after": max(made, key=made.get, default="")}
+              for (op, shape), (ns, n, made) in ranked[:20]]
+    if ranked[20:]:
+        listed.append({"opcode": "(rest)", "shape": "",
+                       "ms": sum(row[0] for _, row in ranked[20:]) * per,
+                       "calls": sum(row[1] for _, row in ranked[20:])
+                       / max(1, steps), "after": ""})
+    return {"steps": steps, "busy_ms_per_step": busy * per,
+            "coverage": covered / busy if busy else 0.0,
+            "scopes": dict(sorted(scopes.items())), "unscoped": listed}
+
+
+def _varint(buf, at: int) -> tuple:
+    """(the varint at buf[at:], the index behind it)."""
+    value = shift = 0
+    while True:
+        byte = buf[at]
+        at += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return value, at
+
+
+def _fields(buf, at: int, end: int):
+    """(field number, value) of the protobuf message buf[at:end]: an int
+    for a varint or fixed field, (start, end) for a length-delimited one.
+    The wire format alone, so that reading a trace needs no protobuf
+    package and no TensorFlow beside jax."""
+    while at < end:
+        key, at = _varint(buf, at)
+        kind = key & 7
+        if kind == 0:
+            value, at = _varint(buf, at)
+        elif kind == 2:
+            size, at = _varint(buf, at)
+            value = (at, at + size)
+            at += size
+        else:
+            size = 8 if kind == 1 else 4
+            value = int.from_bytes(buf[at:at + size], "little")
+            at += size
+        yield key >> 3, value
+
+
+def _text(buf, span) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def read_device_events(xplane_path: str) -> Dict[str, Any]:
+    """{"plane", "planes", "events", "modules"} of an `.xplane.pb`: the
+    first device plane's "XLA Ops" events as (name, tf_op, start_ns,
+    duration_ns) and its "XLA Modules" events as (name, start_ns,
+    duration_ns); `planes` names every device plane of the file.
+    The only function here that knows the file's format (xplane.proto's
+    XSpace > XPlane > XLine > XEvent, each event naming an XEventMetadata
+    of its plane).
+
+    On the v5e an operation's name is its whole HLO instruction and its
+    metadata carries a stat `tf_op` (or `hlo_op`), the instruction's
+    `op_name` with `:` and a type behind it: the path `by_scope` reads.
+    jax.profiler.ProfileData shows an event's own stats and not its
+    metadata's, which is why this reads the bytes (tests/test_by_scope.py
+    holds that on a recorded trace: once it fails, read through
+    ProfileData and delete the parser). A trace taken on another backend
+    holds no "/device:TPU:n" plane and gives no events."""
+    with open(xplane_path, "rb") as f:
+        buf = f.read()
+    found = {}
+    for number, span in _fields(buf, 0, len(buf)):
+        if number == 1:                                   # XSpace.planes
+            name = next((_text(buf, v) for n, v in _fields(buf, *span)
+                         if n == 2), "")
+            if name.startswith("/device:TPU:"):
+                found[name] = span
+    out = {"plane": None, "planes": sorted(found), "events": [],
+           "modules": []}
+    if not found:
+        return out
+    chosen = out["plane"] = out["planes"][0]
+    lines, metadata, stat_names = [], {}, {}
+    for number, span in _fields(buf, *found[chosen]):
+        if number == 3:
+            lines.append(span)
+        elif number in (4, 5):                            # a map's entry
+            entry = dict(_fields(buf, *span))
+            (metadata if number == 4 else stat_names)[entry.get(1, 0)] = \
+                entry.get(2)
+
+    def stat_name(stat_id):
+        span = stat_names.get(stat_id)
+        return next((_text(buf, v) for n, v in _fields(buf, *span)
+                     if n == 2), "") if span else ""
+
+    @functools.cache
+    def described(metadata_id):
+        """(name, tf_op) of an XEventMetadata."""
+        span, name, tf_op = metadata.get(metadata_id), "", ""
+        for number, value in _fields(buf, *span) if span else ():
+            if number == 2:
+                name = _text(buf, value)
+            elif number == 5:                             # its stats
+                stat = dict(_fields(buf, *value))
+                if stat_name(stat.get(1)) in ("tf_op", "hlo_op") \
+                        and not tf_op:
+                    tf_op = _text(buf, stat[5]) if 5 in stat \
+                        else stat_name(stat.get(7))
+        return name, tf_op.rsplit(":", 1)[0]
+
+    for span in lines:
+        line = list(_fields(buf, *span))
+        name = next((_text(buf, v) for n, v in line if n == 2), "")
+        if name not in ("XLA Ops", "XLA Modules"):
+            continue
+        zero_ns = next((v for n, v in line if n == 3), 0)
+        for number, value in line:
+            if number != 4:
+                continue
+            event = dict(_fields(buf, *value))
+            start = zero_ns + event.get(2, 0) / 1e3
+            duration = event.get(3, 0) / 1e3
+            what, tf_op = described(event.get(1, 0))
+            if name == "XLA Ops":
+                out["events"].append((what, tf_op, start, duration))
+            else:
+                out["modules"].append((what, start, duration))
+    return out
+
+
+def step_events(events, modules) -> tuple:
+    """(the events that ran inside a whole run of the step's program, the
+    runs counted): the step's program is the "XLA Modules" name with the
+    most device time. A capture that starts or ends inside a run keeps
+    the run clipped to what it saw, so a run is whole where it lasts nine
+    tenths of the median run and its events span nine tenths of it (the
+    device is busy all through a step). What ran under another program
+    (an initialisation, an evaluation between steps) is left out. With no
+    modules (a hand-made list) everything is one run."""
+    if not modules:
+        return list(events), 1
+    seconds = {}
+    for name, _, duration in modules:
+        seconds[name] = seconds.get(name, 0.0) + duration
+    step = max(seconds, key=seconds.get)
+    runs = sorted((start, start + duration)
+                  for name, start, duration in modules if name == step)
+    held, at = [[] for _ in runs], 0
+    for event in sorted(events, key=lambda e: e[2]):
+        while at < len(runs) and runs[at][1] <= event[2]:
+            at += 1
+        if at < len(runs) and runs[at][0] <= event[2]:
+            held[at].append(event)
+    lengths = sorted(end - start for start, end in runs)
+    least = 0.9 * lengths[len(lengths) // 2]
+    whole = [inside for (start, end), inside in zip(runs, held)
+             if inside and end - start >= least
+             and max(e[2] + e[3] for e in inside) - inside[0][2]
+             >= 0.9 * (end - start)]
+    return [e for inside in whole for e in inside], len(whole)
+
+
+def format_by_scope(table: Dict[str, Any]) -> str:
+    """`by_scope`'s result as the lines `ray_tpu profile --by-scope`
+    prints: a row a scope path, largest first, then the unscoped rows."""
+    busy = table["busy_ms_per_step"] or 1.0
+    lines = [f"{table['steps']} step(s), {table['busy_ms_per_step']:.3f} ms "
+             f"busy a step, {100 * table['coverage']:.2f}% under a block's "
+             f"branch or finer",
+             f"{'forward':>10} {'remade':>10} {'backward':>10} {'share':>7} "
+             f"{'mixed':>9} {'calls':>8}  scope (ms a step)"]
+    total = lambda r: sum(r[p + "_ms"] for p in _PASSES)   # noqa: E731
+    for path, r in sorted(table["scopes"].items(),
+                          key=lambda kv: -total(kv[1])):
+        lines.append(
+            f"{r['forward_ms']:10.3f} {r['remade_ms']:10.3f} "
+            f"{r['backward_ms']:10.3f} {100 * total(r) / busy:6.2f}% "
+            f"{r['mixed_ms']:9.3f} {r['calls']:8.1f}  {path}")
+    for r in table["unscoped"]:
+        lines.append(f"{r['ms']:32.3f} {100 * r['ms'] / busy:6.2f}% "
+                     f"{'':9} {r['calls']:8.1f}  unscoped: {r['opcode']} "
+                     f"{r['shape']}"
+                     + (f" (after {r['after']})" if r["after"] else ""))
+    return "\n".join(lines)
 
 
 # The worker-level actor method behind profile_actor: any actor's worker
@@ -508,18 +928,41 @@ class CompileLog:
     inner interval can stay: sum over the union of the intervals.
 
     Always on once listening: an entry costs a dict and an append, and
-    there are three a program built, none a step. Keeps the newest
-    `keep` entries and counts the rest in `dropped`; `programs_built`
+    there are three a program built, none a step. What a thread logs
+    between two of its `compile` entries is its open run: any of it may
+    yet turn out to lie inside a trace that has not ended (5,083 entries
+    in one train step). A run stands, in the log for good, at its
+    thread's next `compile` or once its thread has gone. The log keeps
+    the newest `keep` entries that stand and as many again of each open
+    run, so a run that outgrows `keep` gives up its own oldest entry,
+    never an earlier program's; `dropped` counts what stood and went,
+    and what a run gave up once it stands (the outer entry that swallows
+    a run takes the given-up ones with it uncounted). `programs_built`
     counts every `compile` entry ever, dropped ones too."""
 
     def __init__(self, keep: int = 4096):
-        # (thread that logged it, entry), in the order they ended
-        self._entries: collections.deque = collections.deque(maxlen=keep)
+        self._keep = keep
+        # entries that stand, in the order their runs stood
+        self._entries: collections.deque = collections.deque()
+        # thread -> its `trace` / `lower` entries since its last `compile`:
+        # disjoint, and each may yet turn out to lie inside a later one
+        self._runs: Dict[int, collections.deque] = {}
+        # thread -> [entries its run gave up, the first one's start]
+        self._given_up: Dict[int, list] = {}
         self._lock = threading.Lock()
         self._cache_said: Dict[int, str] = {}   # thread -> hit | miss
         self._listening = False
         self.dropped = 0
         self.programs_built = 0
+
+    def _stand(self, thread: int, *more):
+        """A thread's open run (and `more`) can be swallowed no longer."""
+        self._entries.extend(self._runs.pop(thread, ()))
+        self._entries.extend(more)
+        self.dropped += self._given_up.pop(thread, (0, 0.0))[0]
+        while len(self._entries) > self._keep:
+            self._entries.popleft()
+            self.dropped += 1
 
     def on_time_span(self, event: str, start: float, end: float, **kw):
         phase = _BUILD_PHASES.get(event)
@@ -531,20 +974,24 @@ class CompileLog:
         with self._lock:
             if phase == "compile":
                 # The answer belongs to the compile entry of the same
-                # thread that closes next: this one.
+                # thread that closes next: this one. No trace runs on
+                # while its program compiles, so the run stands.
                 entry["cache"] = self._cache_said.pop(me, None)
                 self.programs_built += 1
-            else:
-                # What began inside this one is part of it.
-                while self._entries:
-                    thread, last = self._entries[-1]
-                    if thread != me or last["phase"] == "compile" \
-                            or last["start"] < start:
-                        break
-                    self._entries.pop()
-            if len(self._entries) == self._entries.maxlen:
-                self.dropped += 1
-            self._entries.append((me, entry))
+                self._stand(me, entry)
+                return
+            run = self._runs.setdefault(me, collections.deque())
+            # What began inside this one is part of it.
+            while run and run[-1]["start"] >= start:
+                run.pop()
+            gone = self._given_up.get(me)
+            if gone and gone[1] >= start:
+                del self._given_up[me]
+            run.append(entry)
+            if len(run) > self._keep:
+                gone = self._given_up.setdefault(me, [0, run[0]["start"]])
+                gone[0] += 1
+                run.popleft()
 
     def on_event(self, event: str, **kw):
         answer = _CACHE_ANSWERS.get(event)
@@ -554,7 +1001,12 @@ class CompileLog:
 
     def entries(self) -> List[dict]:
         with self._lock:
-            return [dict(entry) for _, entry in self._entries]
+            alive = {t.ident for t in threading.enumerate()}
+            for thread in [t for t in self._runs if t not in alive]:
+                self._stand(thread)
+            held = [*self._entries, *(e for run in self._runs.values()
+                                      for e in run)]
+        return [dict(entry) for entry in sorted(held, key=lambda e: e["end"])]
 
     def listen(self) -> bool:
         """Register with jax.monitoring, once; False where this process
@@ -660,13 +1112,6 @@ def profile_start_unix_ns(xplane: str) -> int:
 def _xplanes(logdir: str):
     return glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                      recursive=True)
-
-
-@contextlib.contextmanager
-def trace(logdir: Optional[str] = None):
-    """`capture` under its older name; yields the log directory."""
-    with capture(logdir) as cap:
-        yield cap.logdir
 
 
 def capture_for(seconds: float) -> Dict[str, Any]:

@@ -27,7 +27,12 @@ from ray_tpu.models import (GPTConfig, HybridConfig, Lfm2MoeConfig,
                             LlamaConfig, MoEConfig, gpt_init,
                             make_hybrid_train_step, make_lfm2_moe_train_step,
                             make_llama_train_step, make_moe_train_step,
-                            make_train_step)
+                            make_nemotron_h_train_step,
+                            make_olmo_hybrid_train_step,
+                            make_sambay_train_step, make_train_step)
+from ray_tpu.models.nemotron_h import NemotronHConfig
+from ray_tpu.models.olmo_hybrid import OlmoHybridConfig
+from ray_tpu.models.sambay import SambaYConfig
 from ray_tpu.util import profiling
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -203,7 +208,21 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
     assert all(re.fullmatch(r"ray_tpu(\.[a-z]+)?\.[a-z_]+", n)
                for n in profiling.HOST_SPANS)
     scopes = _literals(r'named_scope\(\s*"([^"]+)"', CHIP_PATH)
-    assert set(scopes) == set(profiling.DEVICE_SCOPES)
+    # ... and the one scope that is no literal: a block's sequence-mixer
+    # branch, named from its kind's key in decoder.MIXERS
+    from ray_tpu.models import decoder
+    assert _literals(r"named_scope\(\s*([^\"\s][^)]*)\)", CHIP_PATH) == {
+        "MIXER_SCOPES[kind]": {"ray_tpu/models/decoder.py"}}
+    assert set(decoder.MIXER_SCOPES) == {
+        kind for kind, row in decoder.MIXERS.items() if row.apply}
+    assert len(set(decoder.MIXER_SCOPES.values())) == 9
+    assert set(scopes) | set(decoder.MIXER_SCOPES.values()) \
+        == set(profiling.DEVICE_SCOPES)
+    assert not set(scopes) & set(decoder.MIXER_SCOPES.values())
+    # the boundaries of a block are opened in _block and decoder_hidden
+    # and nowhere else
+    for scope in ("channel_mixer", "embed", "final_norm"):
+        assert scopes[scope] == {"ray_tpu/models/decoder.py"}
 
 
 # Lines as XLA:TPU prints them (tests/test_compile_v5e_olmoe.py reads a
@@ -472,7 +491,17 @@ CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
                    experts_held=(1, 2), experts_per_token=2, d_expert=128,
                    bias_rounds=8, balance_tokens=0, max_seq_len=256), 2,
      ATTENTION_KERNELS | CONV_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
-], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe"])
+    # The other three families at their tests' sizes, which no attention,
+    # Mamba-2 or delta-rule kernel takes: the rules' scopes are round the
+    # plain forms too. SambaY's eight kinds of layer; a delta-rule period;
+    # Nemotron-H's one branch a layer and its held experts.
+    (make_sambay_train_step, SambaYConfig.tiny(8), 2,
+     {"_selective_fwd_kernel", "_selective_bwd_kernel"}),
+    (make_olmo_hybrid_train_step, OlmoHybridConfig.tiny(), 2, set()),
+    (make_nemotron_h_train_step, NemotronHConfig.tiny(), 2,
+     {"_gmm_kernel", "_tgmm_kernel"}),
+], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe", "sambay",
+        "olmo-hybrid", "nemotron-h"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -485,10 +514,19 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == kernels
     # The kernels' wrappers are jitted (one trace a step, not one a
-    # layer), so their locations start at the scope.
-    for kernel in ("fwd", "dq", "dkv"):
+    # layer), so their locations start at the scope: a kernel's own scope
+    # is the innermost one round its call.
+    for kernel in ("fwd", "dq", "dkv") if kernels >= ATTENTION_KERNELS else ():
         assert re.search(r'loc\("(?:[^"]*/)?flash_attention_%s/pallas_call"'
                          % kernel, text), kernel
+    for found in re.findall(r'loc\("([^"]*)/pallas_call"', text):
+        assert found.rsplit("/", 1)[-1] in {
+            "flash_attention_fwd", "flash_attention_dq",
+            "flash_attention_dkv", "grouped_matmul_fwd",
+            "grouped_matmul_dlhs", "grouped_matmul_drhs", "ssm_scan_fwd",
+            "ssm_scan_bwd", "selective_scan_fwd", "selective_scan_bwd",
+            "short_conv_fwd", "short_conv_bwd"}, found
+    _every_branch_and_rule_sits_under_a_name(cfg, text)
     scopes = ["layers", "loss", "optimizer_update"]
     if kernels >= SCAN_KERNELS:
         for kernel in ("fwd", "bwd"):
@@ -503,6 +541,58 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
+
+
+# kind of layer -> the hand-written backward rules its mixer runs, by the
+# scope round each rule's whole work (ops/layers.py's runs under the
+# `ssm_conv` round its call)
+RULES = {
+    "attention": ("flash_attention_bwd",),
+    "attention_only": ("flash_attention_bwd",),
+    "diff_windowed": ("flash_attention_bwd",),
+    "diff_full": ("flash_attention_bwd",),
+    "diff_cross": ("flash_attention_bwd",),
+    "mamba2": ("ssm_scan_bwd", "ssm_conv"),
+    "mamba2_only": ("ssm_scan_bwd", "ssm_conv"),
+    "mamba1": ("selective_scan_bwd", "ssm_conv"),
+    "gated_delta": ("gated_delta_bwd", "ssm_conv"),
+    "short_conv": ("short_conv_bwd",), "gmu": (), "experts": ()}
+
+
+def _every_branch_and_rule_sits_under_a_name(cfg, text):
+    """In a lowered train step every branch of every block, the embedding,
+    the last norm and each hand-written backward rule sit under a
+    DEVICE_SCOPES name, forward, backward and (under remat) made again."""
+    from ray_tpu.models import decoder
+
+    seen = {}
+    for op_name in re.findall(r'loc\("(jit\(train_step\)/[^"]*)"', text):
+        names, which = profiling.scope_path(op_name)
+        seen.setdefault(names, set()).add(which)
+    passes = {"forward", "backward"} | ({"remade"} if cfg.remat else set())
+    dec = cfg.decoder()
+    assert set(dec.kinds) <= set(RULES)
+    for kind in set(dec.kinds):
+        row = decoder.MIXERS[kind]
+        if row.apply is not None:
+            mixer = ("layers", decoder.MIXER_SCOPES[kind])
+            assert seen[mixer] == passes, (kind, seen.get(mixer))
+            for rule in RULES[kind]:
+                assert "backward" in seen[mixer + (rule,)], (kind, rule)
+        if row.channel:
+            assert seen[("layers", "channel_mixer")] == passes, kind
+    if ("layers", "channel_mixer", "moe_route") in seen:
+        rule = ("layers", "channel_mixer", "moe_experts_bwd")
+        assert seen[rule] == {"backward"}
+        assert seen[rule + ("grouped_matmul_bwd",)] == {"backward"}
+    for scope in ("embed", "final_norm", "loss"):
+        assert seen[(scope,)] >= {"forward", "backward"}, scope
+    assert seen[("optimizer_update",)] == {"forward"}
+    # under `layers` alone stands the rematerialised blocks' call and no
+    # work; outside every name, what is no layer's (a counter, a cast of
+    # the batch, the held experts' bias)
+    assert seen.get(("layers",), set()) <= {"backward"}
+    assert all(names[:1] == ("layers",) for names in seen if len(names) > 1)
 
 
 def test_generate_steps_carry_their_scopes():
@@ -630,7 +720,7 @@ def test_replica_counts_the_wait_for_a_handler_thread():
 
 
 def test_llm_reply_timing_and_cli_profile_of_the_replica(
-        ray_start_shared, small_setup, tmp_path):
+        ray_start_shared, small_setup, tmp_path, capsys):
     from ray_tpu import serve
     from ray_tpu.llm import build_llm_app
     from ray_tpu.llm.serving import LLMEngine
@@ -665,7 +755,8 @@ def test_llm_reply_timing_and_cli_profile_of_the_replica(
         path = str(tmp_path / "replica.xplane.pb")
         done = {}
         prof = threading.Thread(target=lambda: done.update(rc=cli.main(
-            ["profile", name, "--seconds", "1.5", "-o", path])))
+            ["profile", name, "--seconds", "1.5", "-o", path,
+             "--by-scope"])))
         prof.start()
         replies = []
         while prof.is_alive():
@@ -678,6 +769,13 @@ def test_llm_reply_timing_and_cli_profile_of_the_replica(
             assert "ray_tpu.engine." + phase in spans, sorted(spans)
         assert "ray_tpu.serve.handle" in spans
         assert "$python" not in spans
+        # --by-scope reduces the trace it has just written: on the CPU
+        # there is no device plane to reduce, and it says so (the chip's
+        # table is PERF.md's; tests/test_by_scope.py has the arithmetic)
+        said = capsys.readouterr().out
+        assert f"profile to {path}" in said
+        assert "no device plane (/device:TPU:n) in this trace" in said
+        assert profiling.read_device_events(path)["planes"] == []
     finally:
         serve.shutdown()
 

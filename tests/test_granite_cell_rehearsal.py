@@ -123,6 +123,32 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
         assert worst[name][0] > family.KERNEL_LIMIT, (name, worst[name])
 
 
+def test_scope_profile_builds_the_step_and_refuses_without_a_chip(
+        manifest_path):
+    """chipbench/scope_profile.py, the tool PERF.md section 5's tables
+    come from, end to end at tiny size: it builds the cell's step from the
+    family's train program, warms it up and captures a step, and then,
+    since the CPU's trace holds no device plane, says so, exits 3 and
+    writes nothing: no table of a step's device time comes from a run
+    that had no device."""
+    written = os.path.join(ROOT, "chiprun_out", f"scope_profile_{CELL}.json")
+    before = os.stat(written).st_mtime_ns if os.path.exists(written) else None
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    proc = subprocess.run(
+        [sys.executable, "chipbench/scope_profile.py", "--rehearsal",
+         manifest_path, "--workload", CELL, "--seed", "3", "--steps", "1"],
+        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
+    assert proc.returncode == 3, proc.stderr[-3000:]
+    said = proc.stderr.strip().splitlines()[-1]
+    assert said.startswith(f"scope_profile: {CELL}: 1 step(s) ran on cpu "
+                           "(loss ") and said.endswith(
+        "holds 0 device plane(s): no device time to read, nothing written")
+    assert proc.stdout == ""
+    after = os.stat(written).st_mtime_ns if os.path.exists(written) else None
+    assert after == before
+
+
 def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
     m = _load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
@@ -130,7 +156,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
     # PR 33's split of set-up lists every cell (tests/test_run_timeline.py)
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
              and x["name"] != "time_to_first_step_s"}
-    assert len(split) == 9 and split <= listed
+    assert len(split) == 10 and split <= listed      # PR 50: step_build_s
     assert listed - split == {
         "train_tokens_per_s", "time_to_first_step_s", "step_ms_p50", "mfu",
         "train_device_idle_share", "attn_fwd_kernel_ms_per_step",

@@ -147,7 +147,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_48_names():
               if CELL in x.get("workloads", ())}
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
              and x["name"] != "time_to_first_step_s"}
-    assert len(split) == 9 and split <= listed
+    assert len(split) == 10 and split <= listed      # PR 50: step_build_s
     assert listed - split == {
         "train_tokens_per_s", "time_to_first_step_s", "step_ms_p50", "mfu",
         "train_device_idle_share", "attn_fwd_kernel_ms_per_step",
@@ -158,7 +158,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_48_names():
     for x in (*m["end_to_end"], *m["per_layer"]):
         if CELL in x.get("workloads", ()):
             assert x["workloads"][-1] == CELL       # appended, nothing moved
-    new = m["per_layer"][-2:]
+    new = m["per_layer"][-3:-1]             # PR 50 appended step_build_s
     assert [x["name"] for x in new] == ["short_conv_ms_per_step",
                                         "short_conv_roofline"]
     for x in new:

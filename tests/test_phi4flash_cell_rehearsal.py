@@ -128,7 +128,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_31_names():
     # PR 33's split of set-up lists every cell (tests/test_run_timeline.py)
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
              and x["name"] != "time_to_first_step_s"}
-    assert len(split) == 9 and split <= listed
+    assert len(split) == 10 and split <= listed      # PR 50: step_build_s
     assert listed - split == {
         "train_tokens_per_s", "time_to_first_step_s", "step_ms_p50", "mfu",
         "train_device_idle_share", "attn_fwd_kernel_ms_per_step",

@@ -12,11 +12,13 @@ class TestProfiling:
         import jax.numpy as jnp
 
         from ray_tpu.util import profiling
-        with profiling.trace(str(tmp_path / "tb")) as logdir:
+        with profiling.capture(str(tmp_path / "tb")) as cap:
             x = jnp.ones((128, 128))
             jax.block_until_ready(x @ x)
-        files = glob.glob(os.path.join(logdir, "**", "*"),
+        assert not hasattr(profiling, "trace")      # capture's older name
+        files = glob.glob(os.path.join(cap.logdir, "**", "*"),
                           recursive=True)
+        assert cap.xplane in files
         assert any("trace" in f or f.endswith(".pb") or ".xplane." in f
                    for f in files), files
 

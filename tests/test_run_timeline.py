@@ -344,6 +344,94 @@ def test_the_log_folds_what_began_inside_an_entry_into_it():
     assert log.programs_built == 2 and log.dropped == 0
 
 
+def test_no_number_of_nested_traces_pushes_an_earlier_program_out():
+    """PR 42's case: 73 programs built, then one train step whose trace
+    holds 5,083 jitted functions of its own (jax.numpy's, the kernels'
+    bodies). The log kept 4,096 entries, newest first, so the nested ones
+    pushed 73 programs out before the outer trace ended and swallowed
+    them, and `programs_built` / `trace_lower_s` read 1 / 30.9 for a run
+    whose truth was 74 / 76.5. What a thread has logged since its last
+    `compile` now has room of its own: a run of nested entries gives up
+    its own oldest, and the outer entry takes those with it uncounted."""
+    log = profiling.CompileLog()
+    for i in range(73):
+        t = 10.0 * i
+        log.on_time_span(SPAN("jaxpr_trace"), t, t + 1, fun_name=f"f{i}")
+        log.on_time_span(SPAN("jaxpr_to_mlir_module"), t + 1, t + 2,
+                         fun_name=f"jit(f{i})")
+        log.on_time_span(SPAN("backend_compile"), t + 2, t + 9,
+                         fun_name=f"jit(f{i})")
+    for i in range(5083):
+        log.on_time_span(SPAN("jaxpr_trace"), 1000.0 + i, 1000.5 + i,
+                         fun_name="multiply")
+    assert len(log.entries()) == 3 * 73 + 4096 and log.dropped == 0
+    log.on_time_span(SPAN("jaxpr_trace"), 999.0, 7000.0,
+                     fun_name="train_step")
+    log.on_time_span(SPAN("jaxpr_to_mlir_module"), 7000.0, 7030.0,
+                     fun_name="jit(train_step)")
+    log.on_time_span(SPAN("backend_compile"), 7030.0, 7100.0,
+                     fun_name="jit(train_step)")
+    entries = log.entries()
+    assert [e["fun"] for e in entries if e["phase"] == "compile"] == [
+        f"jit(f{i})" for i in range(73)] + ["jit(train_step)"]
+    assert len(entries) == 3 * 74 and log.programs_built == 74
+    assert log.dropped == 0
+    assert [e["end"] for e in entries] == sorted(e["end"] for e in entries)
+    # entries that do stand and do not fit are dropped, oldest first, and
+    # counted: 5,000 traces that no later one swallows, then a compile
+    log = profiling.CompileLog(keep=100)
+    log.on_time_span(SPAN("backend_compile"), 0.0, 1.0, fun_name="jit(a)")
+    for i in range(5000):
+        log.on_time_span(SPAN("jaxpr_trace"), 10.0 + i, 10.5 + i,
+                         fun_name="eval_shape")
+    log.on_time_span(SPAN("backend_compile"), 9000.0, 9001.0,
+                     fun_name="jit(b)")
+    assert [e["fun"] for e in log.entries()] == ["eval_shape"] * 99 + [
+        "jit(b)"]
+    assert log.dropped == 4902 and log.programs_built == 2
+
+
+def test_one_threads_open_run_costs_another_thread_nothing():
+    """A thread inside a long trace holds a full run of nested entries
+    while another thread builds a program: the other's entries are all
+    kept (the log once dropped the entry just logged where everything
+    it held was another thread's open run), a program built before
+    either is not pushed out, and a run whose thread has gone stands
+    and is no longer held under the thread."""
+    log = profiling.CompileLog(keep=8)
+    log.on_time_span(SPAN("backend_compile"), 0.0, 1.0, fun_name="jit(a)")
+    ready, go = threading.Event(), threading.Event()
+
+    def tracing():
+        for i in range(20):                 # nested in a trace still open
+            log.on_time_span(SPAN("jaxpr_trace"), 10.0 + i, 10.5 + i,
+                             fun_name="multiply")
+        ready.set()
+        go.wait(10)
+
+    other = threading.Thread(target=tracing)
+    other.start()
+    assert ready.wait(10)
+    log.on_time_span(SPAN("jaxpr_trace"), 50.0, 51.0, fun_name="mine")
+    assert [e["fun"] for e in log.entries()] == [
+        "jit(a)"] + ["multiply"] * 8 + ["mine"]
+    log.on_time_span(SPAN("jaxpr_to_mlir_module"), 51.0, 52.0,
+                     fun_name="jit(mine)")
+    log.on_time_span(SPAN("backend_compile"), 52.0, 53.0,
+                     fun_name="jit(mine)")
+    assert [e["fun"] for e in log.entries() if e["fun"] != "multiply"] == [
+        "jit(a)", "mine", "jit(mine)", "jit(mine)"]
+    assert log.dropped == 0 and len(log._runs) == 1
+    go.set()
+    other.join(10)
+    assert not other.is_alive()
+    # the thread went with its trace unfinished: its eight entries stand,
+    # the twelve it gave up are counted, and the oldest that stood go
+    assert [e["fun"] for e in log.entries()] == ["multiply"] * 8
+    assert log.dropped == 12 + 4 and log._runs == {}
+    assert log.programs_built == 2
+
+
 def test_a_cache_answer_goes_to_its_own_threads_next_compile():
     log = profiling.CompileLog()
     log.on_event("/jax/compilation_cache/cache_hits")
@@ -427,6 +515,54 @@ def test_reader_on_the_recorded_timeline(recorded, metric):
     later = dict(record, window_start_unix=record["window_start_unix"] + 1e6)
     assert harness.reader(metric).read(later) is None
     assert harness.reader(metric).read({"counters": {}, "trace": {}}) is None
+
+
+def test_step_build_s_is_the_programs_own_step_in_the_log(
+        recorded, monkeypatch, tmp_path):
+    """`step_build_s`: the compile log's seconds for `train_step` and
+    `jit(train_step)`, trace, lower, compile or cache read, each second
+    once, cut to rank 0's loop up to the window. On the recorded timeline
+    (a warm cache: 5.68 s of reading the step back) and on a hand-made
+    one."""
+    from chipbench import harness
+
+    record, want = recorded
+    read = harness.reader("step_build_s").read
+    split = sum(want["metrics"][m] for m in (
+        "trace_lower_s", "compile_s", "cache_read_s"))
+    assert 5.68 < read(record) < split
+    assert read(dict(record, window_start_unix=1e12)) is None
+
+    def entry(fun, phase, start, end, cache=None):
+        return {"fun": fun, "phase": phase, "start": start, "end": end,
+                "cache": cache}
+    spans = [{"name": "ray_tpu.train.fit", "start": 0.0, "end": 200.0},
+             {"name": "ray_tpu.train.loop", "start": 10.0, "end": 190.0,
+              "attributes": {"rank": 0}}]
+    log = [entry("reference_loss", "trace", 11.0, 14.0),
+           entry("jit(reference_loss)", "compile", 14.0, 30.0, "miss"),
+           entry("train_step", "trace", 5.0, 12.0),      # 2 s in the loop
+           entry("train_step", "trace", 40.0, 50.0),
+           entry("multiply", "trace", 41.0, 42.0),       # inside it
+           entry("jit(train_step)", "lower", 50.0, 55.0),
+           entry("jit(train_step)", "compile", 55.0, 85.0, "hit"),
+           entry("jit(train_step)", "compile", 95.0, 120.0, "miss")]
+    where = tmp_path / "chipbench_out" / "made" / "train" / "made"
+    where.mkdir(parents=True)
+    (where / "run_timeline.json").write_text(json.dumps(
+        {"spans": spans, "workers": {"0": {"compile_log": log}}}))
+    monkeypatch.setattr(harness, "REPO", str(tmp_path))
+    made = {"cell": {"name": "made"}, "window_start_unix": 100.0}
+    # 2 + 10 + 5 + 30 + the 5 s of the last compile before the window
+    assert read(made) == pytest.approx(52.0)
+    assert harness.reader("compile_s").read(made) \
+        + harness.reader("cache_read_s").read(made) \
+        + harness.reader("trace_lower_s").read(made) == pytest.approx(70.0)
+    m = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    assert m["per_layer"][-1] == {
+        "name": "step_build_s", "unit": "s", "better": "lower",
+        "source": "host_clock", "layer": "train step", "moves": "setup_s",
+        "workloads": [w["name"] for w in m["workloads"]]}
 
 
 def test_the_seven_times_add_up_to_the_runs_set_up(recorded):
