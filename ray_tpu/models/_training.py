@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import kernel_sharding
+from ..ops.attention import kernel_sharding, step_memory
 
 
 def place_params(params, axes, mesh, rules):
@@ -93,6 +93,17 @@ def _reduce_options(mesh, rules) -> Optional[Dict]:
     return dict(_ASYNC_GRADIENT_REDUCE) if chips > 1 and on_tpu else None
 
 
+def step_state_bytes(state) -> int:
+    """What a step holds beside its activations, from the shapes of its
+    `state` (arrays, tracers or `jax.eval_shape`'s): the parameters, the
+    optimizer's state and the rest of it, and gradients the size of the
+    parameters. What `train_step` hands down, while it is traced, to the
+    blocks that keep what fits (ops.attention.step_memory;
+    models/decoder.py `remat_plan`)."""
+    return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves((state, state["params"])))
+
+
 def make_train_step_for(init_fn: Callable[[Any], Dict],
                         loss_fn: Callable[[Dict, Any], Any],
                         axes: Optional[Dict] = None,
@@ -158,7 +169,7 @@ def make_train_step_for(init_fn: Callable[[Any], Dict],
     def train_step(state, batch):
         loss_of = loss_fn if held_update is None else functools.partial(
             loss_fn, held=state["held"])
-        with step_split():
+        with step_split(), step_memory(state_bytes=step_state_bytes(state)):
             loss, grads = jax.value_and_grad(loss_of, has_aux=has_aux)(
                 state["params"], batch)
         loss, counters = loss if has_aux else (loss, {})

@@ -67,6 +67,7 @@ after it attends over. Both pass through `jax.checkpoint` as block inputs
 and outputs, and their gradients arrive from every reader.
 
     decoder_hidden      embedding, layer stack, final norm, head
+    remat_plan          what each rematerialised layer keeps, from shapes
     decoder_logits      its rows times its head, float32
     empty_cache         each layer's state, by its row
       attention | mamba2 | mamba1 | gated_delta | short_conv | gmu |
@@ -101,18 +102,23 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.extend.core import Var
 
-from ..ops.attention import DEFAULT_MASK_VALUE, flash_attention
+from ..ops.attention import (DEFAULT_MASK_VALUE, a_chip_alone,
+                             flash_attention, step_memory_given,
+                             step_sharding)
 from ..ops.gated_delta import gated_delta_rule
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
                           gated_rms_norm, head_rms_norm, head_rms_norm_gated,
                           head_spread, head_sums, layer_norm, rms_norm, rope,
                           swiglu)
 from ..ops.loss import chip_views, lookup
+from ..ops.loss import working_set_bytes as loss_working_set_bytes
 from ..ops.selective_scan import selective_scan
 from ..ops.short_conv import gated_short_conv
 from ..ops.ssm_scan import ssm_scan
-from ..parallel.moe import dropless_moe_layer, held_moe_layer
+from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
+                            held_moe_layer)
 
 
 # The kinds of layer: the keys of MIXERS, below the mixers.
@@ -129,7 +135,9 @@ class Decoder(NamedTuple):
     head_dim: int
     # a layer's channel mixer, in order: (y, layer) -> (out, stats or None)
     mlp: Tuple[Callable, ...]
-    remat: Optional[Callable]       # a jax.checkpoint policy; None: keep all
+    # a jax.checkpoint policy; None: keep all. `keep_kernel_outputs` grows,
+    # layer by layer, by what `remat_plan` says fits; any other is as given
+    remat: Optional[Callable]
     kinds: Tuple[str, ...]          # a key of MIXERS a layer, in order
     rope_base: Optional[float] = ROPE_BASE     # None: no rotary
     norm_eps: float = NORM_EPS
@@ -163,8 +171,8 @@ def swiglu_mlp(y, layer):
 
 def fused_swiglu_mlp(y, layer):
     """SwiGLU from one input matrix: [gate | up] = y fc1, the gate first."""
-    gate, up = jnp.split(jnp.einsum("bsd,df->bsf", y, layer["fc1"]), 2,
-                         axis=-1)
+    gate, up = jnp.split(checkpoint_name(
+        jnp.einsum("bsd,df->bsf", y, layer["fc1"]), "mlp_gate_up"), 2, axis=-1)
     return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
                       layer["fc2"]), None
 
@@ -364,8 +372,8 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
     H, P, N, G = dec.ssm_heads, dec.ssm_head_dim, dec.ssm_state, dec.ssm_groups
     inner, bc = H * P, G * N
     y = _norm(x, layer, "ln1", dec.norm_eps)
-    z, xbc, dt = jnp.split(
-        jnp.einsum("bsd,de->bse", y, layer["in_proj"]),
+    z, xbc, dt = jnp.split(checkpoint_name(
+        jnp.einsum("bsd,de->bse", y, layer["in_proj"]), "ssm_in_proj"),
         [inner, 2 * inner + 2 * bc], axis=-1)
     with jax.named_scope("ssm_conv"):
         xbc, tail = causal_conv1d_silu(
@@ -390,8 +398,9 @@ def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
             xs, dt, a, B, C, layer["D"], dec.ssm_chunk,
             None if cache is None else cache["ssm"])
     with jax.named_scope("ssm_gate_norm"):
-        ys = gated_rms_norm(ys.reshape(b, L, inner), z, layer["ssm_norm"],
-                            dec.norm_eps, G)
+        ys = checkpoint_name(
+            gated_rms_norm(ys.reshape(b, L, inner), z, layer["ssm_norm"],
+                           dec.norm_eps, G), "ssm_gated")
     new_cache = None if cache is None else {"conv": tail, "ssm": state}
     return jnp.einsum("bse,ed->bsd", ys, layer["out_proj"]), new_cache
 
@@ -429,7 +438,8 @@ def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
     H, K, V = _delta_sizes(layer)
     f32 = jnp.float32
     y = _norm_if_held(x, layer, "ln1", dec.norm_eps)
-    qkv = jnp.einsum("bsd,de->bse", y, layer["delta_in"])
+    qkv = checkpoint_name(jnp.einsum("bsd,de->bse", y, layer["delta_in"]),
+                          "gated_delta_in")
     with jax.named_scope("ssm_conv"):
         qkv, tail = causal_conv1d_silu(
             qkv, layer["conv_w"], None,
@@ -455,7 +465,8 @@ def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
         o, state = gated_delta_rule(
             q, k, v, g, beta, dec.delta_chunk,
             None if cache is None else cache["delta"])
-    gate = jnp.einsum("bsd,de->bse", y, layer["delta_gate"])
+    gate = checkpoint_name(
+        jnp.einsum("bsd,de->bse", y, layer["delta_gate"]), "gated_delta_in")
     with jax.named_scope("delta_gate_norm"):
         o = head_rms_norm_gated(o, gate, layer["delta_norm"], dec.norm_eps)
     new_cache = None if cache is None else {"conv": tail, "delta": state}
@@ -473,7 +484,8 @@ def short_conv(x, layer, dec: Decoder, cache=None, start_pos=None):
     read. Returns (y, new_cache or None)."""
     y = _norm(x, layer, "ln1", dec.norm_eps)
     with jax.named_scope("short_conv_proj"):
-        bcx = jnp.einsum("bsd,de->bse", y, layer["conv_in"])
+        bcx = checkpoint_name(
+            jnp.einsum("bsd,de->bse", y, layer["conv_in"]), "short_conv_in")
     mixed, tail = gated_short_conv(
         bcx, layer["conv_taps"], None if cache is None else cache["conv"])
     with jax.named_scope("short_conv_proj"):
@@ -505,8 +517,9 @@ def mamba1(x, layer, dec: Decoder, cache=None, start_pos=None):
     N = layer["A_log"].shape[1]
     rank = layer["dt_proj"].shape[0]
     y = _norm(x, layer, "ln1", dec.norm_eps)
-    xs, z = jnp.split(jnp.einsum("bsd,de->bse", y, layer["in_proj"]), 2,
-                      axis=-1)
+    xs, z = jnp.split(checkpoint_name(
+        jnp.einsum("bsd,de->bse", y, layer["in_proj"]), "ssm_in_proj"), 2,
+        axis=-1)
     with jax.named_scope("ssm_conv"):
         xs, tail = causal_conv1d_silu(
             xs, layer["conv_w"], layer["conv_b"],
@@ -841,6 +854,15 @@ def empty_cache(dec: Decoder, layers, batch, max_len, dtype) -> List[Dict]:
 # Of a gated short convolution nothing: its kernels' residuals are their
 # inputs, the [T, 3d] projection, which a rematerialised block makes again
 # with the forward kernel's y (ops/short_conv.py).
+# These seventeen are what EVERY rematerialised block keeps, on any device
+# and at any size. What a block may keep besides (a mixer's input
+# projection, an MLP's gate and up, a held share's routing) is in a second
+# table below, KEPT_WHERE_IT_FITS, and `remat_plan` hands those names out
+# layer by layer into the memory a step leaves free: they are not added
+# here, because a name added here is kept by every layer of every model,
+# and what fits one cell overflows the next (the Mamba-2 input projection:
+# 3.6 GiB free in Nemotron-3-Nano's cell, 15.56 GiB of 15.75 taken in
+# granite-4.0-h-micro's with all nine kept).
 KEPT_UNDER_REMAT = (
     "attention_qkv", "flash_attention_q", "flash_attention_k",
     "flash_attention_v", "flash_attention_out", "flash_attention_lse",
@@ -850,6 +872,120 @@ KEPT_UNDER_REMAT = (
     "gated_delta_o", "gated_delta_states", "gated_delta_T")
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
+
+
+# What a rematerialised block keeps BESIDES, layer by layer, where the step
+# has the room (`remat_plan`): values the backward pass reads that no kernel
+# made, cheap to keep for what making them again costs. A name here is
+# kept by as many layers as the shapes say fit, in the order of cost saved
+# a byte kept, and by none where no capacity is known (the CPU: the base
+# set's program).
+# name -> what making a value of that name again costs, as a function
+# (value's shape and dtype, the block's width d) -> flops, or bytes moved
+# counted as the flops the chip does meanwhile:
+#     ssm_in_proj     Mamba-2's z | x B C | dt, Mamba-1's x | z
+#     ssm_gated       Mamba-2's gated norm's output, the output projection's
+#                     input: three passes of its bytes (the scan's output
+#                     and the gate read, the value written)
+#     gated_delta_in  the delta rule's q | k | v and its gate
+#     short_conv_in   the short convolution's B | C | x
+#     mlp_gate_up     a dense SwiGLU's gate and up, fused or apart
+#     moe_shared_up   a shared expert's up projection, float32
+#     moe_choice      a held share's routing: the top-k's experts and the two
+#                     sorts' orders, integers (parallel/moe.py): two sorts
+#                     for under a megabyte a layer, before everything else
+# (only the candidates' order reads the costs, so one chip's ratio of the
+# two serves every chip whose matmuls outrun its memory)
+_FLOPS_A_BYTE = 240         # a v5e's 197 TFLOP/s over its 819 GB/s
+
+
+def _a_matmul(value, d: int) -> float:
+    """A row of the block's d-wide input times a column, an element."""
+    return 2.0 * d * value.size
+
+
+def _passes(count: float) -> Callable:
+    """So many passes of the value's bytes over HBM."""
+    def cost(value, d: int) -> float:
+        return count * value.size * value.dtype.itemsize * _FLOPS_A_BYTE
+    return cost
+
+
+def _first(value, d: int) -> float:
+    return math.inf
+
+
+KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
+    "ssm_in_proj": _a_matmul, "ssm_gated": _passes(3),
+    "gated_delta_in": _a_matmul, "short_conv_in": _a_matmul,
+    "mlp_gate_up": _a_matmul, "moe_shared_up": _a_matmul,
+    "moe_choice": _first}
+
+
+class RematPlan(NamedTuple):
+    """What each rematerialised layer keeps beyond KEPT_UNDER_REMAT, and
+    the account it was decided by, in bytes a chip."""
+    extras: Tuple[Tuple[str, ...], ...]    # a layer's further names, sorted
+    kept_extra_bytes: int                  # what they hold, all layers
+    layers_extended: int                   # layers with any, of len(extras)
+    base_bytes: int            # what the base set keeps: a layer's input and
+    #                            the values of KEPT_UNDER_REMAT's names
+    reserve_bytes: int         # one block's backward and the loss (`_reserve`)
+    state_bytes: int           # parameters, optimizer state, gradients
+    capacity: Optional[int]    # the chip's memory; None: not known
+    bytes_left: int            # of capacity - state - base - reserve; 0 with
+    #                            no capacity
+
+
+def _chip_capacity(mesh) -> Optional[int]:
+    """The memory of one of the devices the step runs on, by the device: a
+    constant of the chip's kind, not of what is allocated now, so every
+    run of a job traces the same step. None where the device keeps no
+    account (the CPU)."""
+    device = jax.local_devices()[0] if mesh is None else mesh.devices.flat[0]
+    stats = device.memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def _nbytes(values) -> int:
+    return sum(math.prod(v.shape) * jnp.dtype(v.dtype).itemsize
+               for v in jax.tree.leaves(values))
+
+
+def _block_account(block: Callable, x, layer, shared):
+    """One abstract linearisation of `block` under a policy that keeps
+    both tables' names -> (the block's x and `Shared` as it returns them,
+    the bytes it keeps of the base set, ((name, bytes, cost), ...) of each
+    value with a name of KEPT_WHERE_IT_FITS). Shapes in, shapes out:
+    nothing is computed and no kernel is lowered."""
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *KEPT_UNDER_REMAT, *KEPT_WHERE_IT_FITS)
+
+    def linearized(x, layer, shared):
+        out, pushforward = jax.linearize(
+            jax.checkpoint(lambda *a: block(a[0], a[1], None, None, a[2]),
+                           policy=policy), x, layer, shared)
+        return (out[0], out[3]), pushforward
+
+    closed, ((x_out, shared_out), pushforward) = jax.make_jaxpr(
+        linearized, return_shape=True)(x, layer, shared)
+    jaxpr = closed.jaxpr
+    # The pushforward's leaves are what the forward pass left for the
+    # backward: the jaxpr's last outputs. Those that are its own inputs
+    # (x, the weights, `Shared`) are not the block's to count.
+    residuals = jaxpr.outvars[len(jaxpr.outvars)
+                              - len(jax.tree.leaves(pushforward)):]
+    given = {id(v) for v in jaxpr.invars}
+    kept = _nbytes({id(v): v.aval for v in residuals
+                    if isinstance(v, Var) and id(v) not in given})
+    extras = tuple(
+        (eqn.params["name"], _nbytes(eqn.outvars[0].aval),
+         KEPT_WHERE_IT_FITS[eqn.params["name"]](eqn.outvars[0].aval,
+                                                x.shape[-1]))
+        for eqn in jaxpr.eqns if eqn.primitive.name == "name"
+        and eqn.params["name"] in KEPT_WHERE_IT_FITS)
+    base = _nbytes(x) + kept - sum(size for _, size, _ in extras)
+    return x_out, shared_out, base, extras
 
 
 def _scaled(t, scale: float):
@@ -886,6 +1022,160 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
     return x, stats, new_cache, shared
 
 
+def _block_keys(dec: Decoder, layers) -> List[Tuple]:
+    """(kind, mlp, index, window) a layer: what its block is traced by. A
+    kind that reads neither its place nor a window runs one block a
+    channel mixer, traced once a shape."""
+    return [(kind, mlp if row.channel else None,
+             i if row.reads_index else 0,
+             dec.window if row.windowed else None)
+            for i, (kind, row, mlp) in enumerate(zip(
+                dec.kinds, _rows(dec, layers), dec.mlp))]
+
+
+def _block_of(dec: Decoder, kind: str, mlp: Optional[Callable], index: int,
+              window: Optional[int]) -> Callable:
+    return functools.partial(_block, dec=dec, kind=kind, mlp=mlp,
+                             index=index, window=window)
+
+
+# The margin `remat_plan` leaves under a chip's capacity: XLA's own
+# figure for the step is to stay a GiB under it (the runtime holds some
+# back, and a kept value costs up to a fifth above its bytes where XLA keeps
+# the pieces it is split into beside it).
+_UNDER_CAPACITY = 2 ** 30
+
+
+def _backward_holds(mlp: Optional[Callable], tokens: int, layer) -> int:
+    """What a channel mixer's hand-written backward rule holds that no name
+    shows, by the rule's own account: a held share of experts'
+    (parallel.moe.held_backward_bytes). Nothing for the others: autodiff
+    derives their backward from values the account has seen."""
+    mixer = getattr(mlp, "func", mlp)
+    if mixer not in (held_routed_experts, held_gated_experts):
+        return 0
+    up = "expert_gate_up" if mixer is held_gated_experts else "expert_up"
+    return held_backward_bytes(
+        tokens, mlp.keywords["experts_per_token"], layer["router"].shape[-1],
+        layer[up], layer["expert_down"])
+
+
+def _reserve(accounts, keys, layers, x, vocab: int, chips: int) -> int:
+    """What a chip holds at a step's peak beside its state and what its
+    blocks keep, from the shapes it holds: the loss's working set
+    (ops.loss.working_set_bytes) and a block's backward pass, everything
+    of the block that has a name in either table, alive at once while it
+    is differentiated, and what its channel mixer's own rule says it holds
+    besides (`_backward_holds`). On one chip the two do not meet and the
+    largest block counts: the larger of the loss and it. Where the batch
+    is split over `chips` the step reduces its gradients under compute
+    (models/_training.py `_ASYNC_GRADIENT_REDUCE`), and XLA moves every
+    layer's weight gradients behind the last backward kernel: their
+    operands, every block's working set, are alive at once, after a loss
+    whose head gradient still waits for its reduce, so all of them count
+    and the loss beside them. An estimate from above, so that what it
+    leaves is there: PERF.md section 6, PR 51, sets it beside XLA's
+    `memory_analysis()` of the six rematerialised cells' steps compiled
+    for a v5e (total - state - base set) and of a step over a v5e:2x2;
+    tests/test_compile_v5e_*.py hold each total."""
+    tokens, d = math.prod(x.shape[:-1]), x.shape[-1]
+    loss = loss_working_set_bytes(tokens, d, vocab)
+    blocks = [base + sum(size for _, size, _ in extras)
+              + _backward_holds(key[1], tokens, layer)
+              for (base, extras), key, layer in zip(accounts, keys, layers)]
+    return max(loss, *blocks) if chips == 1 else loss + sum(blocks)
+
+
+def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
+               state_bytes: Optional[int], chips: int = 1) -> RematPlan:
+    """Which names of KEPT_WHERE_IT_FITS each layer of a rematerialised
+    stack keeps: a pure function of shapes, as ops.attention.attention_plan
+    is of a kernel's. `layers` are a model's `params["layers"]` or their
+    shapes, `x` [batch, L, d] the stack's input as ONE CHIP holds it (its
+    shape and dtype: where the batch is split over chips, a chip's share
+    of it), `vocab` the vocabulary's rows, `capacity` one chip's memory
+    and `state_bytes` what the step holds there beside activations, both
+    in bytes, `chips` how many the batch is split over. Every byte of the
+    account is a chip's: the activations are traced at the chip's batch,
+    what the batch does not split (the loss's chunk and head gradient, a
+    rule's weight-sized gradients) is whole, and nothing is divided by
+    `chips`, which only says how the step is scheduled (`_reserve`). Every
+    layer's block is linearised abstractly once a kind and shape
+    (`_block_account`) for what it keeps of the base set and for its
+    candidates; a layer's values of one name go together; the candidates
+    of all layers are taken in the order of cost saved a byte kept, a
+    layer's before the next one's at the same rate, each that still fits
+    what `capacity` leaves after the state, the base set, `_reserve` and
+    those before it. More capacity never keeps fewer bytes; with no
+    capacity (None, the CPU) or no state given nothing is added: the base
+    set's program."""
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    x, shared, accounts, traced = shapes(x), Shared(), [], {}
+    keys = _block_keys(dec, layers)
+    with a_chip_alone():
+        for key, layer in zip(keys, layers):
+            layer = shapes(layer)
+            seen = (key, tuple(jax.tree.leaves(layer)),
+                    jax.tree.structure(layer), shared)
+            if seen not in traced:
+                traced[seen] = _block_account(_block_of(dec, *key), x, layer,
+                                              shared)
+            x, shared, base, extras = traced[seen]
+            accounts.append((base, extras))
+    base = sum(base for base, _ in accounts)
+    reserve = _reserve(accounts, keys, layers, x, vocab, chips)
+    taken: List[List[str]] = [[] for _ in accounts]
+    left = kept = 0
+    if capacity is not None and state_bytes is not None:
+        left = capacity - _UNDER_CAPACITY - state_bytes - base - reserve
+        candidates = []
+        for i, (_, extras) in enumerate(accounts):
+            for name in sorted({name for name, _, _ in extras}):
+                size = sum(s for n, s, _ in extras if n == name)
+                cost = sum(c for n, _, c in extras if n == name)
+                candidates.append((-cost / size, i, name, size))
+        for _, i, name, size in sorted(candidates):
+            if size <= left:
+                taken[i].append(name)
+                left, kept = left - size, kept + size
+    return RematPlan(
+        extras=tuple(tuple(sorted(names)) for names in taken),
+        kept_extra_bytes=kept, layers_extended=sum(map(bool, taken)),
+        base_bytes=base, reserve_bytes=reserve,
+        state_bytes=state_bytes or 0, capacity=capacity,
+        bytes_left=max(left, 0))
+
+
+def _planned_extras(dec: Decoder, layers, x, vocab: int) -> Tuple:
+    """What each rematerialised layer keeps beyond the base set: what
+    `remat_plan` adds inside a training step (ops.attention.step_memory)
+    on a chip that says what it holds, nothing anywhere else. Any policy
+    but `keep_kernel_outputs` is the family's own and is left alone. Where
+    the step splits its batch over chips (kernel_sharding) the plan is
+    asked at a chip's share of it; the state is counted whole, which is
+    a chip's under data parallelism and more than a chip's where the
+    parameters are split as well, so the plan then keeps less than fits."""
+    state_bytes, capacity = step_memory_given()
+    if dec.remat is not keep_kernel_outputs or state_bytes is None:
+        return ((),) * len(layers)
+    mesh, chips = None, 1
+    if step_sharding() is not None:
+        mesh, spec = step_sharding()
+        axes = (spec[0],) if isinstance(spec[0], str) else spec[0] or ()
+        chips = math.prod(mesh.shape[axis] for axis in axes)
+    if capacity is None:
+        capacity = _chip_capacity(mesh)
+    if capacity is None:
+        return ((),) * len(layers)
+    a_chips = jax.ShapeDtypeStruct(
+        (-(-x.shape[0] // chips),) + x.shape[1:], x.dtype)
+    return remat_plan(dec, layers, a_chips, vocab, capacity, state_bytes,
+                      chips).extras
+
+
 def decoder_hidden(params: Dict, tokens, dec: Decoder,
                    cache: Optional[List[Dict]] = None, start_pos=None):
     """tokens [b, L] -> (final-norm rows [b, L, d], the output head
@@ -908,25 +1198,25 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
         x = _scaled(x, dec.embed_scale)
     layers = params["layers"]
 
+    extras = _planned_extras(dec, layers, x, params["embed"].shape[0]) \
+        if cache is None else ((),) * len(layers)
+
     @functools.cache
-    def block_at(kind: str, mlp: Callable, index: int, window: Optional[int]):
-        block = functools.partial(_block, dec=dec, kind=kind, mlp=mlp,
-                                  index=index, window=window)
+    def block_at(key: Tuple, extra: Tuple[str, ...]):
+        block = _block_of(dec, *key)
         if dec.remat is not None and cache is None:    # remat is training's
-            block = jax.checkpoint(block, policy=dec.remat)
+            policy = dec.remat if not extra else (
+                jax.checkpoint_policies.save_only_these_names(
+                    *KEPT_UNDER_REMAT, *extra))
+            block = jax.checkpoint(block, policy=policy)
         return block
 
     per_layer, new_cache, shared = [], [], Shared()
     with jax.named_scope("layers"):
-        for i, (kind, row, mlp, layer, cache_layer) in enumerate(zip(
-                dec.kinds, _rows(dec, layers), dec.mlp, layers,
-                cache or [None] * len(layers))):
-            # A kind that reads neither its place nor a window runs one
-            # block a channel mixer, traced once a shape.
-            block = block_at(kind, mlp if row.channel else None,
-                             i if row.reads_index else 0,
-                             dec.window if row.windowed else None)
-            x, stats, cache_layer, shared = block(
+        for key, extra, layer, cache_layer in zip(
+                _block_keys(dec, layers), extras, layers,
+                cache or [None] * len(layers)):
+            x, stats, cache_layer, shared = block_at(key, extra)(
                 x, layer, cache_layer, start_pos, shared)
             per_layer += [] if stats is None else [stats]
             new_cache.append(cache_layer)

@@ -141,6 +141,52 @@ def step_sharding():
     return _SHARDING.get()
 
 
+@contextlib.contextmanager
+def a_chip_alone():
+    """Trace the enclosed as one chip's own share of a step: no enclosing
+    kernel_sharding splits it again (models.decoder.remat_plan's blocks,
+    at the batch a chip holds)."""
+    token = _SHARDING.set(None)
+    try:
+        yield
+    finally:
+        _SHARDING.reset(token)
+
+
+# (state bytes, capacity bytes) while a train step is being traced; see
+# step_memory.
+_MEMORY: contextvars.ContextVar = contextvars.ContextVar(
+    "step_memory", default=(None, None))
+
+
+@contextlib.contextmanager
+def step_memory(state_bytes: Optional[int] = None,
+                capacity: Optional[int] = None):
+    """Trace the enclosed step knowing what it holds beside its
+    activations, `state_bytes` (models._training.make_train_step_for
+    counts its state's shapes), and, where no device can say, one chip's
+    `capacity` (a compile for a described chip). What is not given stays
+    as an enclosing `step_memory` gave it, so a caller's
+    `step_memory(capacity=0)` round a step's first call is the way back
+    to the base set where the plan's estimate is short. Handed down as
+    kernel_sharding hands the kernels their split; read by
+    models.decoder.decoder_hidden for `remat_plan`."""
+    held_state, held_capacity = _MEMORY.get()
+    token = _MEMORY.set((
+        held_state if state_bytes is None else state_bytes,
+        held_capacity if capacity is None else capacity))
+    try:
+        yield
+    finally:
+        _MEMORY.reset(token)
+
+
+def step_memory_given():
+    """(state bytes, capacity bytes) of the enclosing step_memory, each
+    None where none gave it."""
+    return _MEMORY.get()
+
+
 def _per_shard(fn):
     """`fn` over q-shaped arrays, run per shard under kernel_sharding."""
     ctx = step_sharding()

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # What a model that names neither constant runs on (models.decoder.Decoder).
 NORM_EPS = 1e-6
@@ -65,9 +66,12 @@ def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None):
 
 def swiglu(x, w_gate, w_up, w_down):
     """SwiGLU MLP: down( silu(x@gate) * (x@up) )."""
-    gate = jax.nn.silu(jnp.einsum("...d,df->...f", x, w_gate))
-    up = jnp.einsum("...d,df->...f", x, w_up)
-    return jnp.einsum("...f,fd->...d", gate * up, w_down)
+    # The two names are for a rematerialised block that has room for them
+    # (models/decoder.py KEPT_WHERE_IT_FITS).
+    gate = checkpoint_name(jnp.einsum("...d,df->...f", x, w_gate),
+                           "mlp_gate_up")
+    up = checkpoint_name(jnp.einsum("...d,df->...f", x, w_up), "mlp_gate_up")
+    return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up, w_down)
 
 
 def _windows(x, tail, K):

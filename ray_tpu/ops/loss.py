@@ -142,6 +142,29 @@ def _sum_ll_bwd(residuals, g):
 _sum_ll.defvjp(_sum_ll_fwd, _sum_ll_bwd)
 
 
+def _chunk_rows(rows: int) -> int:
+    """The rows of one chunk of `rows`: `_LOSS_CHUNK` or the largest of its
+    halvings that divides them, all of them where none does."""
+    chunk = _LOSS_CHUNK
+    while chunk > 1 and rows % chunk:
+        chunk //= 2
+    return chunk if chunk > 1 else rows
+
+
+def working_set_bytes(rows: int, d: int, vocab: int) -> int:
+    """What the loss holds at its peak over `rows` rows of width d on one
+    chip (under kernel_sharding every chip scans its own rows, so `rows`
+    are a chip's and the figure is not divided again): a chunk's float32
+    logits and half again for what is made from them, and the head's
+    float32 gradient. From above, by XLA's `memory_analysis()` of the
+    train cells' steps compiled for a v5e: 3.29 GB for the 3.16 read at
+    100,352 vocabulary rows of width 2,560, 1.74 for 1.64 at 50,016
+    (PERF.md section 6, PR 51). What models.decoder.remat_plan sets aside
+    for it; whoever changes `_scan_chunks`'s buffers changes this beside
+    it."""
+    return 6 * _chunk_rows(rows) * vocab + 4 * d * vocab
+
+
 def _scan_chunks(x, head, targets):
     """(sum of log-likelihood, its gradient by x, by head in float32) over
     the rows given, `_LOSS_CHUNK` at a time: no chunk's logits outlive
@@ -149,12 +172,7 @@ def _scan_chunks(x, head, targets):
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     tf = targets.reshape(-1)
-    rows = xf.shape[0]
-    chunk = _LOSS_CHUNK
-    while chunk > 1 and rows % chunk:
-        chunk //= 2
-    if chunk <= 1:
-        chunk = rows
+    chunk = _chunk_rows(xf.shape[0])
 
     def one_chunk(carry, inputs):
         total, dhead = carry

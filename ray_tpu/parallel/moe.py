@@ -29,6 +29,7 @@ Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Three layers:
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -415,6 +416,27 @@ def held_rows_plan(tokens: int, k: int, held: int,
                         tokens * k <= _GATHERED_BACK_UP_TO * rows)
 
 
+def held_backward_bytes(tokens: int, k: int, experts: int, w_up,
+                        w_down) -> int:
+    """What `_held_experts_bwd` holds at its peak that is not a residual,
+    from shapes (`w_up` [held, d, f or 2 f] and `w_down` [held, f, d], or
+    their shapes and dtypes; `tokens` those of one chip): gradients the size
+    of the held experts' weights, and a pass's buffers, six values of
+    `held_rows_plan`'s rows as wide as the two tensors' last axes together
+    (the gathered rows and their cotangents, the up projection, the
+    activation and the two gradients). From above, by XLA's
+    `memory_analysis()` of the two cells' steps compiled for a v5e (PERF.md
+    section 6, PR 51). What models.decoder.remat_plan sets aside for a
+    layer of held experts; whoever changes the rule's buffers changes this
+    beside it (tests/test_compile_v5e_lfm2moe.py and _nemotron.py hold the
+    steps' totals)."""
+    rows = held_rows_plan(tokens, k, w_up.shape[0], experts).rows
+    sized = [(math.prod(w.shape), w.shape[-1], jnp.dtype(w.dtype).itemsize)
+             for w in (w_up, w_down)]
+    return sum(size * item + 6 * rows * wide * item
+               for size, wide, item in sized)
+
+
 def _windows(rows: int, weights, perm, inv, counts):
     """window(p) -> pass p of the held order, its places [p * rows, (p + 1)
     * rows): (the token of each place, its router weight, the held experts'
@@ -687,9 +709,6 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                 balance_bias(scores, k, bias_rounds, bias), "moe_probs")
         _, experts = lax.top_k(scores + bias, k)
         counts = _assignment_counts(experts, scores.shape[-1])
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
-        weights = routed_scale * weights / (
-            jnp.sum(weights, axis=-1, keepdims=True) + weight_eps)
         # An absent expert's assignments sort after every held one's.
         local = experts.reshape(-1).astype(jnp.int32) - first
         local = jnp.where((local >= 0) & (local < held), local, held)
@@ -697,12 +716,23 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
         _, perm = lax.sort((local, iota), num_keys=1, is_stable=True)
         _, inv = lax.sort((perm, iota), num_keys=1)
         held_counts = lax.slice_in_dim(counts, first, first + held)
+        # What the top-k and the two sorts chose, four small integer arrays
+        # ([T, k] and [T * k]), for a rematerialised block that has room
+        # for them (models/decoder.py KEPT_WHERE_IT_FITS): kept, the
+        # backward pass sorts nothing again.
+        experts, perm, inv, held_counts = (
+            checkpoint_name(choice, "moe_choice")
+            for choice in (experts, perm, inv, held_counts))
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = routed_scale * weights / (
+            jnp.sum(weights, axis=-1, keepdims=True) + weight_eps)
     out = _held_experts(plan, gated, x, weights, w_up, w_down, perm, inv,
                         held_counts)
     if shared_up is not None:
         with jax.named_scope("moe_shared"):
-            hidden = jnp.square(jax.nn.relu(jnp.dot(
-                x, shared_up, preferred_element_type=jnp.float32)))
+            hidden = jnp.square(jax.nn.relu(checkpoint_name(
+                jnp.dot(x, shared_up, preferred_element_type=jnp.float32),
+                "moe_shared_up")))
             out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
     stats = {"expert_tokens": counts,
              "expert_rows_held": jnp.sum(held_counts),
