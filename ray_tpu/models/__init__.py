@@ -87,3 +87,12 @@ from .vit import (  # noqa: F401
     vit_loss,
     vit_param_axes,
 )
+from .xing4 import (  # noqa: F401
+    Xing4Config,
+    make_xing4_train_step,
+    xing4_forward,
+    xing4_init,
+    xing4_loss,
+    xing4_loss_and_counters,
+    xing4_param_axes,
+)

@@ -1,4 +1,4 @@
-"""The decoder's compute, written once: what the eight families of
+"""The decoder's compute, written once: what the nine families of
 ray_tpu.models train and what models.generate prefills and decodes.
 
 A family says what it is with a `Decoder`, which its config's `decoder()`
@@ -17,7 +17,7 @@ a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
 runs it, what state it keeps in a cache, whether it is windowed, hands its
 keys and values on, or reads its place in the stack, and which branches
 its block has: a sequence mixer, the layer's channel mixer, or both. A
-new sequence mixer is its function and its row. The twelve kinds:
+new sequence mixer is its function and its row. The thirteen kinds:
 
     ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
                    max_len, head_dim]}
@@ -44,6 +44,12 @@ new sequence mixer is its function and its row. The twelve kinds:
                    that one branch, x + mixer(norm(x))
     EXPERTS        no sequence mixer at all: a block of the channel mixer
                    alone, x + mlp(norm(x)); {}
+    LATENT_ATTENTION
+                   softmax attention whose keys and values are made from ONE
+                   latent a token (DeepSeek-V3's MLA); {"latent": [batch,
+                   max_len, latent width] the normed latent, "k_rope":
+                   [batch, max_len, rope width] the rotated key part every
+                   head shares}: no per-head K or V is cached
 
 Every leaf of a layer's state has the batch first: that is the table's one
 rule (models.generate.make_continuous_fns cuts a slot out of axis 0 of
@@ -57,7 +63,11 @@ state: `ln1_b`: LayerNorm with bias where the others have RMSNorm; `ln1`,
 `post_attention`, `post_feedforward` and neither of those: one that norms
 what they return, x + norm(mixer(x)); `wqkv` or `wq` + `wkv`, `q_norm`
 (one norm over all of q's columns), `q_head_norm` (one over each head's)
-or neither, inside `attention`.
+or neither, inside `attention`; `w_qa`: queries through a normed latent of
+their own, inside `latent_attention`; `hc_mixer`, `hc_mlp`: a branch that
+reads one learned mix of `dec.hyper.streams` residual streams and writes
+back through a doubly stochastic matrix (`hyper_connection`), where a layer
+that holds neither adds its branch to the one stream.
 
 Nor need the layers be independent (SambaY, models.sambay): the stack
 carries two values forward besides x, `Shared`: the scan's output `m` of
@@ -70,12 +80,17 @@ and outputs, and their gradients arrive from every reader.
     remat_plan          what each rematerialised layer keeps, from shapes
     decoder_logits      its rows times its head, float32
     empty_cache         each layer's state, by its row
-      attention | mamba2 | mamba1 | gated_delta | short_conv | gmu |
-      diff_attention    the sequence mixers, (x, layer, dec, cache,
+    hyper_connection    a hyper-connected branch's three sets of
+                        coefficients, from the streams and its weights
+      attention | latent_attention | mamba2 | mamba1 | gated_delta |
+      short_conv | gmu | diff_attention
+                        the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window, ...]) -> (y,
                         new cache[, shared]): attention is the flash
                         kernel over the whole sequence with no cache, with
-                        one a write into it and a masked read of it; mamba2
+                        one a write into it and a masked read of it;
+                        latent_attention the same, its per-head keys and
+                        values made from the (cached) latent first; mamba2
                         the chunked scan (ops.ssm_scan) over the tokens
                         given, from the cached state where there is one,
                         and one step of the recurrence for a single token;
@@ -123,10 +138,23 @@ from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
 
 # The kinds of layer: the keys of MIXERS, below the mixers.
 (ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
- DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV) = (
+ DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV,
+ LATENT_ATTENTION) = (
     "attention", "mamba2", "mamba1", "gated_delta", "gmu", "diff_windowed",
     "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts",
-    "short_conv")
+    "short_conv", "latent_attention")
+
+
+class HyperConnections(NamedTuple):
+    """The residual path of a model whose blocks are joined by
+    manifold-constrained hyper-connections (`hyper_connection`): how many
+    streams the stack carries, and the three guards of the mixing matrix
+    (config.json's hc_mult, hc_sinkhorn_iters, hc_eps,
+    mhc_h_res_clamp_min / _max)."""
+    streams: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: Tuple[float, float] = (-30.0, 30.0)
 
 
 class Decoder(NamedTuple):
@@ -157,6 +185,13 @@ class Decoder(NamedTuple):
     delta_chunk: int = 64
     # What a DIFF_WINDOWED layer sees: itself and the window - 1 before.
     window: Optional[int] = None
+    # The rotated pairs' frequencies where they are not `rope_base`'s own
+    # (a scaled context's: ops.layers.yarn_inv_freq), one a pair.
+    rope_inv_freq: Optional[Tuple[float, ...]] = None
+    # None: one residual stream, every branch added to it. Else the stack
+    # carries `hyper.streams` of them, a tuple of so many [b, L, d], from
+    # the embedding (each a copy of it) to the final norm (of their sum).
+    hyper: Optional[HyperConnections] = None
 
 
 def gelu_mlp(y, layer):
@@ -210,14 +245,17 @@ def held_gated_experts(y, layer, experts_per_token: int, first: int,
                        bias_rounds: int = 0):
     """One chip's share of top-k SwiGLU experts (those from `first` on, as
     many as the layer holds; `expert_gate_up` is each one's gate and up
-    matrices side by side) with no shared expert, over the flattened
-    tokens, the k weights over their sum + `weight_eps`, the selection
-    bias moved `bias_rounds` rounds on the tokens' scores first; `stats`
-    are parallel.moe.held_moe_layer's, one layer's."""
+    matrices side by side) and, where the layer holds one, the shared
+    SwiGLU expert of the same form (`shared_gate_up`, `shared_down`:
+    DeepSeek-V3's; LFM2 has none), over the flattened tokens, the k
+    weights over their sum + `weight_eps`, the selection bias moved
+    `bias_rounds` rounds on the tokens' scores first; `stats` are
+    parallel.moe.held_moe_layer's, one layer's."""
     b, s, d = y.shape
     out, stats = held_moe_layer(
         y.reshape(b * s, d), layer["router"], layer["router_bias"],
         layer["expert_gate_up"], layer["expert_down"],
+        layer.get("shared_gate_up"), layer.get("shared_down"),
         experts_per_token=experts_per_token, first=first,
         routed_scale=routed_scale, bias_rounds=bias_rounds, gated=True,
         weight_eps=weight_eps)
@@ -354,6 +392,113 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
         attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh,
                                             dec.sm_scale)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * hd)
+    return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+# The eps of the norms over a latent (the keys' and the queries'): the
+# published family code builds them with its class's default, not the
+# config's `rms_norm_eps`.
+LATENT_NORM_EPS = 1e-6
+
+
+def _latent_sizes(layer, heads: int):
+    """(latent width, rope width, a head's no-rope width, a head's value
+    width) of a latent-attention layer, off its weights (or their shapes):
+    `w_kva` is latent | shared rope key side by side, `wq` (or, behind a
+    query latent, `w_qb`) a head's no-rope | rope columns, `w_kvb` a head's
+    no-rope key | value columns."""
+    latent = layer["latent_norm"].shape[0]
+    r = layer["w_kva"].shape[1] - latent
+    wq = layer["w_qb" if "w_qa" in layer else "wq"]
+    n = wq.shape[1] // heads - r
+    return latent, r, n, layer["w_kvb"].shape[1] // heads - n
+
+
+def _from_latent(latent, k_rope, layer, heads: int, n: int):
+    """Per-head keys and values of the positions whose normed `latent`
+    [b, m, c] and rotated shared key `k_rope` [b, m, r] are given: [k_n | v]
+    a head = latent W_kvb, k = [k_n | k_rope] with the one rotated key
+    under every head -> (k [b, h, m, n + r], v [b, h, m, vd])."""
+    b, m, r = k_rope.shape
+    kv = jnp.einsum("bmc,ce->bme", latent, layer["w_kvb"]).reshape(
+        b, m, heads, -1).transpose(0, 2, 1, 3)
+    k_n, v = jnp.split(kv, [n], axis=-1)
+    k = jnp.concatenate(
+        [k_n, jnp.broadcast_to(k_rope[:, None], (b, heads, m, r))], axis=-1)
+    return k, v
+
+
+def _write_rows(buffer, rows, sp):
+    """rows [b, L, w] written into buffer [b, max_len, w] at positions sp +
+    [0, L), `sp` a scalar or one position a row."""
+    rows = rows.astype(buffer.dtype)
+    if sp.ndim == 1:
+        b, L, _ = rows.shape
+        return buffer.at[jnp.arange(b)[:, None],
+                         sp[:, None] + jnp.arange(L)[None]].set(rows)
+    return jax.lax.dynamic_update_slice(buffer, rows, (0, sp, 0))
+
+
+def latent_attention(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """DeepSeek-V3's multi-head latent attention of x [b, L, d], from the
+    input norm to the output projection: q = y W_q or, where the layer
+    holds `w_qa`, through a latent of the queries' own, q = rmsnorm(y W_qa;
+    `q_latent_norm`) W_qb; a head [q_n | q_r]; [c | k_r] = y W_kva, ONE
+    latent c and ONE rope key k_r a token; c normed (`latent_norm`; both
+    latents' norms at LATENT_NORM_EPS); q_r and k_r rotated over their own
+    columns at `dec.rope_base`, or at `dec.rope_inv_freq` where the context
+    is a scaled one, k_r the same under every head; a head's [k_n | v] =
+    c W_kvb; scores q . [k_n | k_r] / sqrt(n + r) unless `dec.sm_scale`
+    says otherwise (YaRN's carries its temperature squared); out =
+    concat(P v) W_o. Every width is read off the weights. One
+    implementation serves training (the flash kernel, q and k wider than
+    v), prefill and decode: with a cache the normed latent and the rotated
+    key of x's positions are written into it, K and V of ALL cached
+    positions are made from the latent by W_kvb, and the read is masked
+    (the plain form; folding W_kvb into q and o is ROADMAP's). Returns (y,
+    new_cache or None)."""
+    b, L, d = x.shape
+    h = dec.n_heads
+    latent, r, n, vd = _latent_sizes(layer, h)
+    y = _norm(x, layer, "ln1", dec.norm_eps)
+    sp = positions = None
+    if cache is not None:
+        sp = jnp.asarray(start_pos)
+        positions = (sp[:, None] if sp.ndim == 1 else sp) + jnp.arange(L)
+
+    def rotate(t):
+        return rope(t, base=dec.rope_base, positions=positions,
+                    inv_freq=dec.rope_inv_freq)
+
+    with jax.named_scope("mla_project"):
+        if "w_qa" in layer:
+            q = jnp.einsum("bsc,ce->bse", rms_norm(
+                jnp.einsum("bsd,dc->bsc", y, layer["w_qa"]),
+                layer["q_latent_norm"], LATENT_NORM_EPS), layer["w_qb"])
+        else:
+            q = jnp.einsum("bsd,de->bse", y, layer["wq"])
+        q_n, q_r = jnp.split(
+            q.reshape(b, L, h, n + r).transpose(0, 2, 1, 3), [n], axis=-1)
+        q = jnp.concatenate([q_n, rotate(q_r)], axis=-1)
+        c, k_r = jnp.split(jnp.einsum("bsd,de->bse", y, layer["w_kva"]),
+                           [latent], axis=-1)
+        # What a rematerialised block keeps of its keys and values: latent
+        # + rope widths a token, from which `_from_latent` makes them again.
+        c = checkpoint_name(
+            rms_norm(c, layer["latent_norm"], LATENT_NORM_EPS), "mla_latent")
+        k_r = checkpoint_name(rotate(k_r[:, None])[:, 0], "mla_k_rope")
+    new_cache = None
+    if cache is not None:
+        new_cache = {"latent": _write_rows(cache["latent"], c, sp),
+                     "k_rope": _write_rows(cache["k_rope"], k_r, sp)}
+        c, k_r = new_cache["latent"], new_cache["k_rope"]
+    with jax.named_scope("mla_expand"):
+        k, v = _from_latent(c, k_r, layer, h, n)
+    if cache is None:
+        attn = flash_attention(q, k, v, True, dec.sm_scale)
+    else:
+        attn = _attend_cache(q, k, v, sp, dec.sm_scale)
+    attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * vd)
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
 
 
@@ -643,6 +788,14 @@ def _kv_state(dec: Decoder, layer, batch, max_len, dtype):
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _latent_state(dec: Decoder, layer, batch, max_len, dtype):
+    """A latent-attention layer's normed latents and rotated shared keys up
+    to `max_len`: no head has a key or a value of its own here."""
+    latent, r, _, _ = _latent_sizes(layer, dec.n_heads)
+    return {"latent": jnp.zeros((batch, max_len, latent), dtype),
+            "k_rope": jnp.zeros((batch, max_len, r), dtype)}
+
+
 def _mamba2_state(dec: Decoder, layer, batch, max_len, dtype):
     conv_dim, taps = layer["conv_w"].shape      # inner + 2 groups x state
     return {"conv": jnp.zeros((batch, taps - 1, conv_dim), dtype),
@@ -676,6 +829,10 @@ def _no_state(dec: Decoder, layer, batch, max_len, dtype):
 
 def _attention(x, layer, dec, cache, start_pos, shared, index, window):
     return (*attention(x, layer, dec, cache, start_pos), shared)
+
+
+def _latent_attention(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*latent_attention(x, layer, dec, cache, start_pos), shared)
 
 
 def _mamba2(x, layer, dec, cache, start_pos, shared, index, window):
@@ -740,16 +897,20 @@ def _differential(own, windowed=False, hands_on=False) -> Mixer:
 #     _unit_heads(t, heads, scale, eps)
 #     gmu(x, layer, dec, m)
 #     differential_maps(q, k, v, layer, dec, index, window)
-# and, chipbench/families/nemotron_h.py's and lfm2_moe.py's, four more:
+# and, chipbench/families/nemotron_h.py's, lfm2_moe.py's and xing4.py's,
+# seven more:
 #     gated_rms_norm(y, gate, weight, eps, groups)
 #     held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up,
 #                    shared_down, *, experts_per_token, first, routed_scale,
 #                    bias_rounds[, gated, weight_eps])
 #     gated_short_conv(bcx, weight, tail)
 #     head_rms_norm(t, weight, eps)
-# The first six and these four the benchmark also SWAPS on the module
-# (`setattr(decoder, name, faulty)`) and then traces the program, so the
-# program must find
+#     latent_attention(x, layer, dec[, cache, start_pos])
+#     hyper_connection(streams, hc, hyper)
+#     _streams_read(x, hc, hyper) -> (read, write, counters)
+# The first six and these but the last the benchmark also SWAPS on the
+# module (`setattr(decoder, name, faulty)`) and then traces the program, so
+# the program must find
 # them through the module's global name at the time of the call. A row
 # that held the function object `gmu` itself, bound at import, would run
 # the real one under a planted fault, and the fault would read as harmless
@@ -772,6 +933,7 @@ MIXERS: Dict[str, Mixer] = {
     MAMBA2_ONLY: Mixer(_mamba2, _mamba2_state, channel=False),
     EXPERTS: Mixer(None, _no_state),
     SHORT_CONV: Mixer(_short_conv, _short_conv_state),
+    LATENT_ATTENTION: Mixer(_latent_attention, _latent_state),
 }
 
 
@@ -873,6 +1035,25 @@ KEPT_UNDER_REMAT = (
 keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
     *KEPT_UNDER_REMAT)
 
+# A kind whose block keeps other names than those seventeen. A latent
+# layer's keys and values are one product away from latent + rope widths a
+# token (`_from_latent`), so its block keeps the normed latent and the
+# rotated shared key in the place of per-head K and V: 19 MB a layer against
+# 336 at 16,384 tokens of Xing4.0's 32 heads of 192 | 128, for a [T, 512] x
+# [512, 8192] product and K's assembly made again. (Per-head K and V are no
+# candidates of KEPT_WHERE_IT_FITS: nothing has measured what keeping them
+# saves.)
+KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
+    LATENT_ATTENTION: tuple(
+        name for name in KEPT_UNDER_REMAT
+        if name not in ("flash_attention_k", "flash_attention_v"))
+    + ("mla_latent", "mla_k_rope")}
+
+
+def _kept(kind: str) -> Tuple[str, ...]:
+    """The names every rematerialised block of `kind` keeps."""
+    return KEPT_BY_KIND.get(kind, KEPT_UNDER_REMAT)
+
 
 # What a rematerialised block keeps BESIDES, layer by layer, where the step
 # has the room (`remat_plan`): values the backward pass reads that no kernel
@@ -947,19 +1128,28 @@ def _chip_capacity(mesh) -> Optional[int]:
     return stats.get("bytes_limit") if stats else None
 
 
+def _rows_and_width(x) -> Tuple[int, int]:
+    """(batch x L, d) of a stack's x: [batch, L, d], or so many of them
+    where the stack carries several streams."""
+    stream = jax.tree.leaves(x)[0]
+    return math.prod(stream.shape[:-1]), stream.shape[-1]
+
+
 def _nbytes(values) -> int:
     return sum(math.prod(v.shape) * jnp.dtype(v.dtype).itemsize
                for v in jax.tree.leaves(values))
 
 
-def _block_account(block: Callable, x, layer, shared):
+def _block_account(block: Callable, x, layer, shared,
+                   kept: Tuple[str, ...] = KEPT_UNDER_REMAT):
     """One abstract linearisation of `block` under a policy that keeps
-    both tables' names -> (the block's x and `Shared` as it returns them,
+    `kept`, its kind's base set, and KEPT_WHERE_IT_FITS' names -> (the
+    block's x and `Shared` as it returns them,
     the bytes it keeps of the base set, ((name, bytes, cost), ...) of each
     value with a name of KEPT_WHERE_IT_FITS). Shapes in, shapes out:
     nothing is computed and no kernel is lowered."""
     policy = jax.checkpoint_policies.save_only_these_names(
-        *KEPT_UNDER_REMAT, *KEPT_WHERE_IT_FITS)
+        *kept, *KEPT_WHERE_IT_FITS)
 
     def linearized(x, layer, shared):
         out, pushforward = jax.linearize(
@@ -981,7 +1171,7 @@ def _block_account(block: Callable, x, layer, shared):
     extras = tuple(
         (eqn.params["name"], _nbytes(eqn.outvars[0].aval),
          KEPT_WHERE_IT_FITS[eqn.params["name"]](eqn.outvars[0].aval,
-                                                x.shape[-1]))
+                                                _rows_and_width(x)[1]))
         for eqn in jaxpr.eqns if eqn.primitive.name == "name"
         and eqn.params["name"] in KEPT_WHERE_IT_FITS)
     base = _nbytes(x) + kept - sum(size for _, size, _ in extras)
@@ -1000,26 +1190,150 @@ MIXER_SCOPES: Dict[str, str] = {
     if MIXERS[kind].apply is not None}
 
 
+@jax.custom_vjp
+def _stream_products(x, phi):
+    """x [b, L, d] times phi [d, k], accumulated and returned in float32.
+    The rule is written out for the cotangent of x alone: derived, it is a
+    float32 [b, L, d] value that is rounded to x's dtype next; here the
+    k-wide cotangent is rounded first and the product comes out in x's
+    dtype (0.94 GB less alive at 16,384 tokens of four 3,584-wide streams,
+    by XLA's account of the step compiled for a v5e)."""
+    return jnp.einsum("bsd,dk->bsk", x, phi,
+                      preferred_element_type=jnp.float32)
+
+
+def _stream_products_fwd(x, phi):
+    return _stream_products(x, phi), (x, phi)
+
+
+def _stream_products_bwd(saved, g):
+    x, phi = saved
+    g = g.astype(x.dtype)
+    d_phi = jnp.einsum("bsd,bsk->dk", x, g,
+                       preferred_element_type=jnp.float32)
+    return jnp.einsum("bsk,dk->bsd", g, phi), d_phi.astype(phi.dtype)
+
+
+_stream_products.defvjp(_stream_products_fwd, _stream_products_bwd)
+
+
+def hyper_connection(streams, hc, hyper: HyperConnections):
+    """The coefficients that join one branch to the n residual streams
+    (`streams`: n arrays [b, L, d]) by manifold-constrained
+    hyper-connections (mHC, DeepSeek 2025, over Hyper-Connections,
+    arXiv:2409.19606), a token each, all in float32: with x the token's
+    streams as one vector [n d], normed with no weight (phi absorbs one),
+    [p | q | r] = x^ phi (`hc["phi"]` [n d, 2 n + n^2]), `hc["alpha"]` [3]
+    the three gains and `hc["b"]` [2 n + n^2] the static part,
+
+        H_pre  = sigmoid(alpha_0 p + b_pre)              [b, L, n]
+        H_post = 2 sigmoid(alpha_1 q + b_post)           [b, L, n]
+        H_res  = sinkhorn(exp(clamp(alpha_2 r + b_res))) [b, L, n, n]
+
+    the last `hyper.sinkhorn_iters` rounds of columns then rows each over
+    their sum + `hyper.eps`, which leaves rows summing to 1 exactly and
+    columns nearly: a doubly stochastic mix of the streams, whose products
+    down a stack stay doubly stochastic. The branch reads sum_i H_pre[i]
+    X[i] and the block returns H_res X + H_post (x) branch (`_streams_read`).
+    The norm's factor is a scalar a token, so it multiplies the 2 n + n^2
+    products and no normed copy of the streams is made."""
+    n, (b, L, d), f32 = len(streams), streams[0].shape, jnp.float32
+    mean_sq = sum(jnp.sum(jnp.square(x.astype(f32)), axis=-1, keepdims=True)
+                  for x in streams) / (n * d)
+    phi = hc["phi"].reshape(n, d, -1)
+    raw = sum(_stream_products(x, phi[i]) for i, x in enumerate(streams)) \
+        * jax.lax.rsqrt(mean_sq + hyper.eps)
+    alpha, bias = hc["alpha"].astype(f32), hc["b"].astype(f32)
+    p, q, r = jnp.split(raw, [n, 2 * n], axis=-1)
+    b_pre, b_post, b_res = jnp.split(bias, [n, 2 * n])
+    h_pre = jax.nn.sigmoid(alpha[0] * p + b_pre)
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * q + b_post)
+    m = jnp.exp(jnp.clip((alpha[2] * r + b_res).reshape(b, L, n, n),
+                         *hyper.clamp))
+    for _ in range(hyper.sinkhorn_iters):
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + hyper.eps)   # columns
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + hyper.eps)   # rows
+    return h_pre, h_post, m
+
+
+def _streams_read(x, hc, hyper: Optional[HyperConnections]):
+    """What a branch reads of the block's x, how its output is joined to
+    it, and what the join counts. With no hyper-connection weights `hc`: x
+    itself, the residual add, nothing. With them, x is the streams, n
+    arrays [b, L, d]: the branch reads their mix by H_pre [b, L, d],
+    `write(y)` is H_res X + H_post (x) y, n arrays again, and the counters
+    are the largest off-diagonal entry of H_res and the largest |column
+    sum - 1| (the streams still mix; the matrix is still on its manifold).
+    The mixes accumulate in float32 and return the streams' dtype. The
+    streams are apart, not one [b, L, n, d] value, so that every mix is
+    multiply-adds of [b, L, d] values by a column a token: XLA for the v5e
+    copies slices of a stream axis out in float32 and pads them back in
+    the backward pass (3.7 GB more alive a step at 16,384 tokens of four
+    3,584-wide streams, compiled for the chip)."""
+    if hc is None:
+        return x, lambda y: x + y, None
+    n, f32, dtype = len(x), jnp.float32, x[0].dtype
+    with jax.named_scope("hc_coefficients"):
+        h_pre, h_post, h_res = hyper_connection(x, hc, hyper)
+        off_diagonal = jnp.max(h_res * (1.0 - jnp.eye(n, dtype=f32)))
+        column_error = jnp.max(jnp.abs(jnp.sum(h_res, axis=-2) - 1.0))
+    with jax.named_scope("hc_read"):
+        u = sum(h_pre[..., i, None] * x[i].astype(f32)
+                for i in range(n)).astype(dtype)
+
+    def write(y):
+        with jax.named_scope("hc_write"):
+            return tuple(
+                (h_post[..., i, None] * y.astype(f32)
+                 + sum(h_res[..., i, j, None] * x[j].astype(f32)
+                       for j in range(n))).astype(dtype)
+                for i in range(n))
+
+    return u, write, (off_diagonal, column_error)
+
+
+def _with_hc_counters(stats, counted):
+    """A block's `stats` with its hyper-connected branches' counters beside
+    the channel mixer's own: `hc_res_offdiag_max`, `hc_res_col_err_max`,
+    each a tuple of one scalar a branch in the block's order (as the
+    branches' scopes made them: no operation stands outside a branch)."""
+    counted = [c for c in counted if c is not None]
+    if not counted:
+        return stats
+    off_diagonal, column_error = zip(*counted)
+    return {**(stats or {}), "hc_res_offdiag_max": off_diagonal,
+            "hc_res_col_err_max": column_error}
+
+
 def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
            dec: Decoder, kind: str, mlp: Optional[Callable] = None,
            index: int = 0, window=None):
     """One layer: its kind's sequence mixer and `mlp`, the layer's channel
     mixer (None where the kind's block has no such branch), each branch
-    with its norms and its residual add under a scope of its own."""
+    with its norms and its residual join under a scope of its own: the add,
+    or where the layer holds `hc_mixer` / `hc_mlp` the hyper-connection of
+    x's streams (`_streams_read`); a branch sees [b, L, d] either way."""
     eps, row = dec.norm_eps, MIXERS[kind]
     stats, new_cache = None, cache
+    counted = []
     if row.apply is not None:
         with jax.named_scope(MIXER_SCOPES[kind]):
+            u, write, counters = _streams_read(x, layer.get("hc_mixer"),
+                                               dec.hyper)
             y, new_cache, shared = row.apply(
-                x, layer, dec, cache, start_pos, shared, index, window)
-            x = x + _scaled(_norm_if_held(y, layer, "post_attention", eps),
-                            dec.residual_scale)
+                u, layer, dec, cache, start_pos, shared, index, window)
+            x = write(_scaled(_norm_if_held(y, layer, "post_attention", eps),
+                              dec.residual_scale))
+            counted.append(counters)
     if row.channel:
         with jax.named_scope("channel_mixer"):
-            out, stats = mlp(_norm_if_held(x, layer, "ln2", eps), layer)
+            u, write, counters = _streams_read(x, layer.get("hc_mlp"),
+                                               dec.hyper)
+            out, stats = mlp(_norm_if_held(u, layer, "ln2", eps), layer)
             out = _norm_if_held(out, layer, "post_feedforward", eps)
-            x = x + _scaled(out, dec.residual_scale)
-    return x, stats, new_cache, shared
+            x = write(_scaled(out, dec.residual_scale))
+            counted.append(counters)
+    return x, _with_hc_counters(stats, counted), new_cache, shared
 
 
 def _block_keys(dec: Decoder, layers) -> List[Tuple]:
@@ -1060,14 +1374,27 @@ def _backward_holds(mlp: Optional[Callable], tokens: int, layer) -> int:
         layer[up], layer["expert_down"])
 
 
+def _streams_hold(x, layer) -> int:
+    """What the backward pass of a hyper-connected block holds that no name
+    shows: four values of the streams' size, the streams between its two
+    branches made again and the cotangents of its output, of those and of
+    its input (XLA's account of Xing4.0's step compiled for a v5e, with the
+    base set kept: total - state - base set 3.61 GB, where the named values
+    and the held experts' rule account for 1.97 and these four for 1.88;
+    PERF.md section 6, PR 53). Nothing for a block joined by the add."""
+    hyper_connected = "hc_mixer" in layer or "hc_mlp" in layer
+    return 4 * _nbytes(x) if hyper_connected else 0
+
+
 def _reserve(accounts, keys, layers, x, vocab: int, chips: int) -> int:
     """What a chip holds at a step's peak beside its state and what its
     blocks keep, from the shapes it holds: the loss's working set
     (ops.loss.working_set_bytes) and a block's backward pass, everything
     of the block that has a name in either table, alive at once while it
     is differentiated, and what its channel mixer's own rule says it holds
-    besides (`_backward_holds`). On one chip the two do not meet and the
-    largest block counts: the larger of the loss and it. Where the batch
+    besides (`_backward_holds`, `_streams_hold`). On one chip the two do
+    not meet and the largest block counts: the larger of the loss and it.
+    Where the batch
     is split over `chips` the step reduces its gradients under compute
     (models/_training.py `_ASYNC_GRADIENT_REDUCE`), and XLA moves every
     layer's weight gradients behind the last backward kernel: their
@@ -1078,10 +1405,11 @@ def _reserve(accounts, keys, layers, x, vocab: int, chips: int) -> int:
     `memory_analysis()` of the six rematerialised cells' steps compiled
     for a v5e (total - state - base set) and of a step over a v5e:2x2;
     tests/test_compile_v5e_*.py hold each total."""
-    tokens, d = math.prod(x.shape[:-1]), x.shape[-1]
+    tokens, d = _rows_and_width(x)
     loss = loss_working_set_bytes(tokens, d, vocab)
     blocks = [base + sum(size for _, size, _ in extras)
               + _backward_holds(key[1], tokens, layer)
+              + _streams_hold(x, layer)
               for (base, extras), key, layer in zip(accounts, keys, layers)]
     return max(loss, *blocks) if chips == 1 else loss + sum(blocks)
 
@@ -1091,7 +1419,8 @@ def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
     """Which names of KEPT_WHERE_IT_FITS each layer of a rematerialised
     stack keeps: a pure function of shapes, as ops.attention.attention_plan
     is of a kernel's. `layers` are a model's `params["layers"]` or their
-    shapes, `x` [batch, L, d] the stack's input as ONE CHIP holds it (its
+    shapes, `x` [batch, L, d] (or the streams, so many of them: a block's
+    input at its real width) the stack's input as ONE CHIP holds it (its
     shape and dtype: where the batch is split over chips, a chip's share
     of it), `vocab` the vocabulary's rows, `capacity` one chip's memory
     and `state_bytes` what the step holds there beside activations, both
@@ -1122,7 +1451,7 @@ def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
                     jax.tree.structure(layer), shared)
             if seen not in traced:
                 traced[seen] = _block_account(_block_of(dec, *key), x, layer,
-                                              shared)
+                                              shared, _kept(key[0]))
             x, shared, base, extras = traced[seen]
             accounts.append((base, extras))
     base = sum(base for base, _ in accounts)
@@ -1170,8 +1499,8 @@ def _planned_extras(dec: Decoder, layers, x, vocab: int) -> Tuple:
         capacity = _chip_capacity(mesh)
     if capacity is None:
         return ((),) * len(layers)
-    a_chips = jax.ShapeDtypeStruct(
-        (-(-x.shape[0] // chips),) + x.shape[1:], x.dtype)
+    a_chips = jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+        (-(-t.shape[0] // chips),) + t.shape[1:], t.dtype), x)
     return remat_plan(dec, layers, a_chips, vocab, capacity, state_bytes,
                       chips).extras
 
@@ -1196,6 +1525,8 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
         x = lookup(views, tokens) if views is not None \
             else jnp.take(params["embed"], tokens, axis=0)
         x = _scaled(x, dec.embed_scale)
+        if dec.hyper is not None:       # every stream starts as the embedding
+            x = (x,) * dec.hyper.streams
     layers = params["layers"]
 
     extras = _planned_extras(dec, layers, x, params["embed"].shape[0]) \
@@ -1205,9 +1536,11 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     def block_at(key: Tuple, extra: Tuple[str, ...]):
         block = _block_of(dec, *key)
         if dec.remat is not None and cache is None:    # remat is training's
-            policy = dec.remat if not extra else (
-                jax.checkpoint_policies.save_only_these_names(
-                    *KEPT_UNDER_REMAT, *extra))
+            policy = dec.remat
+            if extra or (policy is keep_kernel_outputs
+                         and key[0] in KEPT_BY_KIND):
+                policy = jax.checkpoint_policies.save_only_these_names(
+                    *_kept(key[0]), *extra)
             block = jax.checkpoint(block, policy=policy)
         return block
 
@@ -1221,6 +1554,8 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
             per_layer += [] if stats is None else [stats]
             new_cache.append(cache_layer)
     with jax.named_scope("final_norm"):
+        if dec.hyper is not None:       # the streams' sum is what is normed
+            x = sum(t.astype(jnp.float32) for t in x).astype(x[0].dtype)
         x = _scaled(_norm(x, params, "lnf", dec.norm_eps), dec.logit_scale)
     if views is not None:
         head = views.swapaxes(1, 2)

@@ -7,6 +7,8 @@ already fuses); Pallas is reserved for ops XLA can't fuse (attention).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -36,20 +38,26 @@ def layer_norm(x, weight, bias, eps: float = 1e-5):
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None):
+def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None,
+         inv_freq=None):
     """Rotary position embedding for [batch, heads, seq, head_dim].
 
     `positions` overrides `position_offset` and may be traced: shape
     (seq,) — the KV-cache decode path passes start_pos + arange — or
     (batch, seq) for per-sequence offsets (continuous batching decodes
-    every slot at its own position). One implementation serves train
-    and decode so the formulas can't diverge."""
+    every slot at its own position). `inv_freq` [head_dim / 2], where
+    given, are the pairs' frequencies themselves (a scaled context's, such
+    as `yarn_inv_freq`'s) and `base` is not read. One implementation
+    serves train and decode so the formulas can't diverge."""
     *_, seq_len, head_dim = x.shape
     if positions is None:
         positions = position_offset + jnp.arange(seq_len)
     pos = jnp.asarray(positions, jnp.float32)
-    inv_freq = 1.0 / (base ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if inv_freq is None:
+        inv_freq = 1.0 / (base ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     if pos.ndim == 2:                                # (batch, seq)
         angles = pos[:, :, None] * inv_freq          # (b, seq, d/2)
         cos = jnp.cos(angles)[:, None]               # (b, 1, seq, d/2)
@@ -62,6 +70,40 @@ def rope(x, position_offset=0, base: float = ROPE_BASE, positions=None):
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's blended frequencies of `dim` rotated columns (arXiv:2309.00071,
+    as DeepSeek-V3's rotary has them), a tuple of dim / 2 floats for
+    `rope`'s `inv_freq`: pair i turns at f_i = base^(-2i / dim) where it
+    makes more than `beta_fast` turns over the `original` context, at
+    f_i / factor where it makes fewer than `beta_slow`, and at a linear
+    blend of the two between: with c(r) = dim ln(original / (2 pi r)) /
+    (2 ln base), low = floor(c(beta_fast)), high = ceil(c(beta_slow)), both
+    clipped to [0, dim - 1], ramp_i = clip((i - low) / (high - low), 0, 1)
+    and inv_freq_i = (f_i / factor) ramp_i + f_i (1 - ramp_i). Plain Python
+    floats: a config can hold them and a trace reads them as constants."""
+    def pair_of(turns: float) -> float:
+        return dim * math.log(original / (2 * math.pi * turns)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    span = max(high - low, 1e-3)        # the published guard of low == high
+    out = []
+    for i in range(dim // 2):
+        f = base ** (-2.0 * i / dim)
+        ramp = min(max((i - low) / span, 0.0), 1.0)
+        out.append(f / factor * ramp + f * (1.0 - ramp))
+    return tuple(out)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature m(a) = 0.1 a ln(factor) + 1 (1 at a
+    factor of 1 or less): a softmax scale carries m(mscale_all_dim)^2, cos
+    and sin m(mscale) / m(mscale_all_dim)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def swiglu(x, w_gate, w_up, w_down):

@@ -21,9 +21,10 @@ Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Three layers:
   second) in buffers of a balanced share's rows and an eighth
   (`held_rows_plan`) that it walks in as many passes as the held rows
   take, so it drops nothing at any routing, and adds a shared expert
-  every token passes where the model has one. `balance_bias` runs the
-  bias's own rule to its fixed point. What models/nemotron_h.py (relu^2,
-  a shared expert) and models/lfm2_moe.py (gated, none) run.
+  every token passes where the model has one, of the experts' form.
+  `balance_bias` runs the bias's own rule to its fixed point. What
+  models/nemotron_h.py (relu^2, a shared expert), models/lfm2_moe.py
+  (gated, none) and models/xing4.py (gated, a gated shared one) run.
 """
 
 from __future__ import annotations
@@ -672,8 +673,9 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
     x [T, d]; router_w [d, E] float32 over ALL E experts; router_bias [E]
     float32; w_up [held, d, f], w_down [held, f, d]: experts `first` to
     `first + held - 1`, or with `gated` w_up [held, d, 2f], each expert's
-    gate and up matrices side by side, the gate first; shared_up [d, fs],
-    shared_down [fs, d] or None for a model with no shared expert. Returns
+    gate and up matrices side by side, the gate first; shared_up [d, fs]
+    (with `gated` [d, 2 fs], gate | up as an expert's), shared_down [fs, d]
+    or None for a model with no shared expert. Returns
     (out [T, d] in x's dtype, stats) where, with s = sigmoid(x router_w),
     e_1..k the top k of s + router_bias, and w_j = routed_scale * s[e_j] /
     (sum_j s[e_j] + weight_eps) (the model's own small number: 1e-20
@@ -683,6 +685,7 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                  + relu(x[t] shared_up)^2 shared_down
         out[t] = sum over the HELD e_j of
                  w_j (silu(x[t] G[e_j]) * (x[t] U'[e_j])) D[e_j]     gated
+                 + (silu(x[t] G_s) * (x[t] U_s)) shared_down
 
     The bias picks and never weighs: no gradient reaches it (DeepSeek-V3's
     balancing without a loss). With `bias_rounds` (a training step's) it
@@ -730,9 +733,18 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                         held_counts)
     if shared_up is not None:
         with jax.named_scope("moe_shared"):
-            hidden = jnp.square(jax.nn.relu(checkpoint_name(
-                jnp.dot(x, shared_up, preferred_element_type=jnp.float32),
-                "moe_shared_up")))
+            # relu^2's product is float32 (Nemotron's: a square doubles a
+            # rounding); a gated one is in x's dtype, as a gated expert's
+            # and ops.layers.swiglu's are, at half the bytes.
+            hidden = checkpoint_name(jnp.dot(
+                x, shared_up,
+                preferred_element_type=None if gated else jnp.float32),
+                "moe_shared_up")
+            if gated:
+                gate, up = jnp.split(hidden, 2, axis=-1)
+                hidden = jax.nn.silu(gate) * up
+            else:
+                hidden = jnp.square(jax.nn.relu(hidden))
             out = out + jnp.dot(hidden.astype(x.dtype), shared_down)
     stats = {"expert_tokens": counts,
              "expert_rows_held": jnp.sum(held_counts),

@@ -210,12 +210,12 @@ DEVICE_SCOPES: Dict[str, str] = {
               "tokens' in float32 by the same form as moe_combine",
     "moe_shared": "parallel/moe.py held_moe_layer: the shared expert every "
                   "token passes where the model has one, two plain matmuls "
-                  "with relu^2 between",
+                  "with relu^2 between or, gated, silu(gate) * up from one "
+                  "fused first matrix",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
               "every decoder family (gpt, llama, moe, hybrid, sambay, "
-              "olmo_hybrid, nemotron_h, lfm2_moe), each layer run by the "
-              "row of "
-              "decoder.MIXERS "
+              "olmo_hybrid, nemotron_h, lfm2_moe, xing4), each layer run by "
+              "the row of decoder.MIXERS "
               "that its config's `kinds` names, in the train step and "
               "under prefill / decode alike",
     "loss": "ops/loss.py cross_entropy, every family's loss after its "
@@ -231,7 +231,26 @@ DEVICE_SCOPES: Dict[str, str] = {
                         "a kind `*_only` runs under its mixer's name), from "
                         "the norm the mixer reads to the residual add"
        for kind in ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
-                    "diff_windowed", "diff_full", "diff_cross", "short_conv")},
+                    "diff_windowed", "diff_full", "diff_cross", "short_conv",
+                    "latent_attention")},
+    "mla_project": "models/decoder.py latent_attention: the products from "
+                   "the block's input to q (through its normed latent where "
+                   "the layer holds one) and to the latent | shared key, "
+                   "the latents' norms and the rotary of q's and the key's "
+                   "rope columns",
+    "mla_expand": "models/decoder.py latent_attention: per-head keys and "
+                  "values from the normed latent by W_kvb and K's assembly "
+                  "from its no-rope part and the one rotated key under "
+                  "every head: the part a rematerialised block makes again",
+    "hc_coefficients": "models/decoder.py _streams_read, inside a branch's "
+                       "scope: a hyper-connected branch's H_pre, H_post and "
+                       "H_res from the streams (their norm, phi's product, "
+                       "the sigmoids, the exp and the Sinkhorn rounds) and "
+                       "its two counters",
+    "hc_read": "models/decoder.py _streams_read: what a hyper-connected "
+               "branch reads, the streams' mix by H_pre",
+    "hc_write": "models/decoder.py _streams_read: what a hyper-connected "
+                "branch returns to the streams, H_res X + H_post (x) y",
     "channel_mixer": "models/decoder.py _block: the channel-mixer branch "
                      "of a block, dense, routed or held experts alike "
                      "(`ln2`, the layer's `mlp`, `post_feedforward`, the "

@@ -33,6 +33,7 @@ from ray_tpu.models import (GPTConfig, HybridConfig, Lfm2MoeConfig,
 from ray_tpu.models.nemotron_h import NemotronHConfig
 from ray_tpu.models.olmo_hybrid import OlmoHybridConfig
 from ray_tpu.models.sambay import SambaYConfig
+from ray_tpu.models.xing4 import Xing4Config, make_xing4_train_step
 from ray_tpu.util import profiling
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -215,7 +216,7 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
         "MIXER_SCOPES[kind]": {"ray_tpu/models/decoder.py"}}
     assert set(decoder.MIXER_SCOPES) == {
         kind for kind, row in decoder.MIXERS.items() if row.apply}
-    assert len(set(decoder.MIXER_SCOPES.values())) == 9
+    assert len(set(decoder.MIXER_SCOPES.values())) == 10
     assert set(scopes) | set(decoder.MIXER_SCOPES.values()) \
         == set(profiling.DEVICE_SCOPES)
     assert not set(scopes) & set(decoder.MIXER_SCOPES.values())
@@ -500,8 +501,19 @@ CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
     (make_olmo_hybrid_train_step, OlmoHybridConfig.tiny(), 2, set()),
     (make_nemotron_h_train_step, NemotronHConfig.tiny(), 2,
      {"_gmm_kernel", "_tgmm_kernel"}),
+    # A dense layer, then an expert layer, both under latent attention at
+    # q and k wider than v, every branch joined to two streams by
+    # hyper-connections.
+    (make_xing4_train_step,
+     Xing4Config(vocab_size=512, d_model=128, n_heads=2, qk_nope_head_dim=64,
+                 qk_rope_head_dim=64, v_head_dim=64, q_lora_rank=64,
+                 kv_lora_rank=64, n_layers=2, n_dense_layers=1, d_ff=256,
+                 n_experts=4, experts_held=(1, 2), experts_per_token=2,
+                 d_expert=128, hc_mult=2, hc_sinkhorn_iters=2, bias_rounds=8,
+                 balance_tokens=0, max_seq_len=256), 2,
+     ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
 ], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe", "sambay",
-        "olmo-hybrid", "nemotron-h"])
+        "olmo-hybrid", "nemotron-h", "xing4"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -538,6 +550,14 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
             assert re.search(r'loc\("(?:[^"]*/)?short_conv_%s/pallas_call"'
                              % kernel, text), kernel
         scopes += ["short_conv_proj", "moe_route", "moe_combine"]
+    if cfg.decoder().hyper is not None:
+        # a hyper-connected branch's three parts inside the branch's scope,
+        # the latent mixer's two round its products
+        for branch in ("latent_attention_mixer", "channel_mixer"):
+            for part in ("hc_coefficients", "hc_read", "hc_write"):
+                assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s/%s\b'
+                                 % (branch, part), text), (branch, part)
+        scopes += ["mla_project", "mla_expand", "moe_shared"]
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
@@ -556,7 +576,8 @@ RULES = {
     "mamba2_only": ("ssm_scan_bwd", "ssm_conv"),
     "mamba1": ("selective_scan_bwd", "ssm_conv"),
     "gated_delta": ("gated_delta_bwd", "ssm_conv"),
-    "short_conv": ("short_conv_bwd",), "gmu": (), "experts": ()}
+    "short_conv": ("short_conv_bwd",), "gmu": (), "experts": (),
+    "latent_attention": ("flash_attention_bwd",)}
 
 
 def _every_branch_and_rule_sits_under_a_name(cfg, text):

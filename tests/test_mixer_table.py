@@ -22,6 +22,7 @@ from ray_tpu.models.moe import MoEConfig, moe_loss
 from ray_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_loss
 from ray_tpu.models.olmo_hybrid import OlmoHybridConfig, olmo_hybrid_loss
 from ray_tpu.models.sambay import SambaYConfig, sambay_loss
+from ray_tpu.models.xing4 import Xing4Config, xing4_loss
 
 FAMILIES = {
     "gpt": (GPTConfig, gpt_loss),
@@ -32,11 +33,13 @@ FAMILIES = {
     "sambay": (SambaYConfig, sambay_loss),
     "olmo_hybrid": (OlmoHybridConfig, olmo_hybrid_loss),
     "nemotron_h": (NemotronHConfig, nemotron_h_loss),
+    "xing4": (Xing4Config, xing4_loss),
 }
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
          decoder.DIFF_FULL, decoder.DIFF_CROSS, decoder.ATTENTION_ONLY,
-         decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV)
+         decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV,
+         decoder.LATENT_ATTENTION)
 STATELESS = (decoder.GMU, decoder.DIFF_CROSS, decoder.EXPERTS)
 
 
@@ -47,8 +50,8 @@ def family(request):
     return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
 
 
-def test_the_table_has_the_twelve_kinds_and_the_tiny_models_run_them_all():
-    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 12
+def test_the_table_has_the_thirteen_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 13
     run = {kind for config, _ in FAMILIES.values()
            for kind in config.tiny().decoder().kinds}
     assert run == set(KINDS)
@@ -78,19 +81,20 @@ def test_a_row_says_which_branches_its_block_has():
 def test_a_layers_channel_mixer_is_named_as_its_sequence_mixer_is(family):
     """`Decoder.mlp` is one callable a layer, as long as `kinds`, built by
     the family's `decoder()` from the fields it has: the one function a
-    layer in seven families; LFM2's dense SwiGLU in its leading layers and
-    one expert layer's function in all the others."""
+    layer in seven families; LFM2's and Xing4's dense SwiGLU in their
+    leading layers and one expert layer's function in all the others."""
     cfg, _ = family
     dec = cfg.decoder()
     assert isinstance(dec.mlp, tuple) and len(dec.mlp) == len(dec.kinds)
     assert all(callable(mlp) for mlp in dec.mlp)
-    if isinstance(cfg, Lfm2MoeConfig):
+    if isinstance(cfg, (Lfm2MoeConfig, Xing4Config)):
         dense = cfg.n_dense_layers
         assert 0 < dense < cfg.n_layers
         assert set(dec.mlp[:dense]) == {decoder.swiglu_mlp}
         experts, = set(dec.mlp[dense:])
         assert experts.func is decoder.held_gated_experts
-        assert experts.keywords["weight_eps"] == 1e-6
+        assert experts.keywords["weight_eps"] == (
+            1e-6 if isinstance(cfg, Lfm2MoeConfig) else 1e-20)
     else:
         assert len(set(dec.mlp)) == 1
 
@@ -253,6 +257,9 @@ FROZEN = {
                           "sambay"),
     "gated_short_conv": (("bcx", "weight", "tail"), "lfm2_moe"),
     "head_rms_norm": (("t", "weight", "eps"), "lfm2_moe"),
+    "latent_attention": (("x", "layer", "dec"), "xing4"),
+    "hyper_connection": (("streams", "hc", "hyper"), "xing4"),
+    "_streams_read": (("x", "hc", "hyper"), "xing4"),
 }
 
 
@@ -285,7 +292,7 @@ def test_generate_names_no_family():
     import ray_tpu.models as models
     families = {getattr(models, name) for name in (
         "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid",
-        "nemotron_h", "lfm2_moe")}
+        "nemotron_h", "lfm2_moe", "xing4")}
     held = {v for v in vars(generate).values() if inspect.ismodule(v)}
     assert not held & families
     assert "cache_layers" not in inspect.getsource(generate)

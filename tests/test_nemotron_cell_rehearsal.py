@@ -144,10 +144,12 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_45_names():
         "attn_dq_kernel_ms_per_step", "attn_dkv_kernel_ms_per_step",
         "attn_scoped_roofline", "ssm_scan_ms_per_step", "ssm_scan_roofline",
         "expert_gmm_ms_per_step", "expert_gmm_roofline"}
+    order = [w["name"] for w in m["workloads"]]
     for x in (*m["end_to_end"], *m["per_layer"]):
         if CELL in x.get("workloads", ()):
-            # appended, nothing moved (a later cell after it at most)
-            assert CELL in x["workloads"][-2:]
+            # appended, nothing moved: every list in the cells' own order
+            assert x["workloads"] == [n for n in order
+                                      if n in x["workloads"]], x["name"]
     cell = m["workloads"][6]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, "nemotron-3-nano-30b-a3b", "pretrain-nemotron3nano-b1-s16384",
