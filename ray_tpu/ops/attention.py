@@ -376,11 +376,19 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     where the sequence tiles by it and is longer (measured on a v5e at
     head_dim 64 and 128: 128 is 8-9% faster at 1,024 positions, 256 2-3%
     faster at 2,048 and 4,096; PERF.md §6, PR 26). A kernel's own block is
-    the largest multiple of the sub-block up to 1,024 that tiles the
-    sequence; the swept side (K and V in forward and dQ; Q, dO and their
-    rows in dK/dV) is resident whole where the estimate fits VMEM_BUDGET,
-    else in the largest blocks that do, swept on the grid with the same
-    loops inside.
+    sized first: the largest multiple of the sub-block up to 1,024 that
+    tiles the sequence. Then the swept side (K and V in forward and dQ; Q,
+    dO and their rows in dK/dV): resident whole where the estimate fits
+    VMEM_BUDGET with that block, else in the largest blocks that do, swept
+    on the grid with the same loops inside; a smaller own block only where
+    no swept size fits beside the larger one. A score tile is block x
+    block, and what a kernel pays once a tile (the forward's row maxima,
+    sums and rescale most of all) it pays four times as often at 512 as at
+    1,024, which costs more than a swept side in two or four grid blocks:
+    at (16384, 192 | 128) the forward takes 23.4 ms a 32 heads at 1,024 x
+    8,192 where 512 x 16,384 took 34.8, and dK/dV at 1,024 x 4,096 is 7-15%
+    faster than at 512 x 8,192 at every 8k and 16k shape of the cells
+    (PERF.md §6, PR 54).
 
     Under a `window` (causal, a query sees itself and the window - 1
     positions before it) the sizes follow the same rules, and a program
@@ -404,12 +412,14 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     sizes = [b for b in range(seq_len, 0, -sub) if seq_len % b == 0]
 
     def plan(kernel: str) -> KernelPlan:
-        for swept in sizes:
-            tile = None if window is None else sub * (
-                _strip_tile(sub, window, swept) or min(_MAX_BLOCK, swept))
-            for block in sizes:
-                if block > _MAX_BLOCK or swept % block:
+        for block in sizes:
+            if block > _MAX_BLOCK:
+                continue
+            for swept in sizes:
+                if swept % block:
                     continue
+                tile = None if window is None else sub * (
+                    _strip_tile(sub, window, swept) or min(_MAX_BLOCK, swept))
                 need = _vmem_bytes(kernel, block, swept, head_dim, itemsize,
                                    v_dim, tile)
                 if need <= VMEM_BUDGET:

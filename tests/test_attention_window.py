@@ -45,8 +45,11 @@ def _inputs(S, hd, vd, seed=0):
             jax.random.normal(ks[3], (1, 2, S, vd)))
 
 
-def _check(S, hd, vd, window, scale=0.125):
+def _check(S, hd, vd, window, scale=0.125, dense=_dense):
     q, k, v, w = _inputs(S, hd, vd)
+
+    def reference(q, k, v):
+        return dense(q, k, v, window, scale)
 
     def both(fn):
         return jax.jit(jax.value_and_grad(
@@ -55,7 +58,7 @@ def _check(S, hd, vd, window, scale=0.125):
 
     got, got_grads = both(lambda q, k, v: flash_attention(
         q, k, v, True, scale, window))
-    want, want_grads = both(lambda q, k, v: _dense(q, k, v, window, scale))
+    want, want_grads = both(reference)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
     for a, b in zip(got_grads, want_grads, strict=True):
         assert a.shape == b.shape
@@ -63,8 +66,7 @@ def _check(S, hd, vd, window, scale=0.125):
                                    atol=2e-4, rtol=2e-3)
     out = flash_attention(q, k, v, True, scale, window)
     assert out.shape == v.shape
-    np.testing.assert_allclose(out, _dense(q, k, v, window, scale),
-                               atol=2e-5, rtol=2e-4)
+    np.testing.assert_allclose(out, reference(q, k, v), atol=2e-5, rtol=2e-4)
 
 
 # S = 512 runs sub-blocks of 128 (a windowed kernel's own block), S = 2048
@@ -99,6 +101,26 @@ def test_window_with_the_swept_side_on_the_grid(window, monkeypatch):
     assert (attention._strip_tile(128, window, plan.fwd.swept)
             == (256 if window == 100 else None))
     _check(1024, 64, 128, window)
+
+
+@pytest.mark.parametrize("hd,budget", [(192, 4_000_000), (64, 3_000_000)],
+                         ids=["192|128", "64|128"])
+def test_causal_wide_keys_with_the_swept_side_on_the_grid(hd, budget,
+                                                          monkeypatch):
+    """The path a 16k call at 192 | 128 takes (latent attention): causal,
+    no window, values not as wide as keys, own blocks of several
+    sub-blocks and the swept side in blocks on the grid in all three
+    kernels, so `acc`, `m`, `l`, dq, dk and dv are carried from one swept
+    block to the next and the diagonal's block lies in one of them."""
+    monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
+    plan = attention_plan(1024, hd, True, jnp.float32, None, 128)
+    for kernel in (plan.fwd, plan.dq, plan.dkv):
+        assert kernel.sub < kernel.block <= kernel.swept < 1024, plan
+    assert (plan.fwd.block, plan.fwd.swept) == (256, 512)
+    assert (plan.dq.block, plan.dq.swept) == (256, 512)
+    _check(1024, hd, 128, None, 0.14468,
+           lambda q, k, v, window, scale: mha_reference(
+               q, k, v, True, scale, window))
 
 
 def _relative_errors(S, hd, vd, window, heads=1):
@@ -288,7 +310,9 @@ def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
     # boundary go in 3 pieces each, as the last two do in 2 + 1
     assert (band.dkv.block, band.dkv.swept) == (1024, 8192)
     assert (band.dkv.tiles, band.dkv.masked) == (69, 129)
-    assert causal.dkv.block == 512 and causal.fwd.tiles == 184
+    # the full layers' dK/dV: its own block sized first, as the forward's
+    assert (causal.dkv.block, causal.dkv.swept) == (1024, 4096)
+    assert causal.dkv.tiles == causal.fwd.tiles == 184
     for kernel in (band.fwd, band.dq, band.dkv):
         assert kernel.tiles <= 16384 // 256 + 8
         assert kernel.vmem_bytes <= attention.VMEM_BUDGET
@@ -297,32 +321,52 @@ def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
         16384, 64, True, jnp.bfloat16, None, None)
 
 
-def test_unwindowed_plans_are_what_they_were():
-    """Every window=None plan the five cells run, field for field as
-    before a window could hold a large own block (a literal copy of the
-    parent commit's, PR 34's tree), beside the new count of tiles."""
-    fields = ("block", "swept", "sub", "vmem_bytes", "computed", "masked",
-              "skipped", "tiles")
-    for args, want in [
-            ((1024, 64), [(1024, 1024, 128, 15990784, 36, 8, 28, 8),
-                          (1024, 1024, 128, 16252928, 36, 8, 28, 8),
-                          (1024, 1024, 128, 16777216, 36, 8, 28, 8)]),
-            ((4096, 128), [(1024, 4096, 256, 20447232, 136, 16, 120, 22),
-                           (1024, 4096, 256, 20971520, 136, 16, 120, 22),
-                           (1024, 4096, 256, 28311552, 136, 16, 120, 22)]),
-            ((16384, 64), [(1024, 16384, 256, 23855104, 2080, 64, 2016, 184),
-                           (1024, 16384, 256, 24117248, 2080, 64, 2016, 184),
-                           (512, 8192, 256, 24903680, 2080, 64, 2016, 560)]),
-            ((16384, 64, True, jnp.bfloat16, None, 128),
-             [(1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
-              (1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
-              (512, 8192, 256, 27394048, 2080, 64, 2016, 560)])]:
-        plan = attention_plan(*args)
-        assert (plan.seq_len, plan.head_dim, plan.causal, plan.window,
-                plan.vmem_budget) == (*args[:2], True, None, 32 * 2 ** 20)
-        got = [tuple(getattr(kernel, f) for f in fields)
-               for kernel in (plan.fwd, plan.dq, plan.dkv)]
-        assert got == want, args
+_FIELDS = ("block", "swept", "sub", "vmem_bytes", "computed", "masked",
+           "skipped", "tiles")
+
+
+@pytest.mark.parametrize("args,want", [
+    ((1024, 64), [(1024, 1024, 128, 15990784, 36, 8, 28, 8),
+                  (1024, 1024, 128, 16252928, 36, 8, 28, 8),
+                  (1024, 1024, 128, 16777216, 36, 8, 28, 8)]),
+    ((4096, 128), [(1024, 4096, 256, 20447232, 136, 16, 120, 22),
+                   (1024, 4096, 256, 20971520, 136, 16, 120, 22),
+                   (1024, 4096, 256, 28311552, 136, 16, 120, 22)]),
+    ((16384, 64), [(1024, 16384, 256, 23855104, 2080, 64, 2016, 184),
+                   (1024, 16384, 256, 24117248, 2080, 64, 2016, 184),
+                   (1024, 4096, 256, 24641536, 2080, 64, 2016, 184)]),
+    ((16384, 64, True, jnp.bfloat16, None, 128),
+     [(1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
+      (1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
+      (1024, 4096, 256, 26476544, 2080, 64, 2016, 184)]),
+    ((16384, 128), [(1024, 16384, 256, 33030144, 2080, 64, 2016, 184),
+                    (1024, 16384, 256, 33554432, 2080, 64, 2016, 184),
+                    (1024, 4096, 256, 28311552, 2080, 64, 2016, 184)]),
+    ((8192, 64), [(1024, 8192, 256, 19660800, 528, 32, 496, 60),
+                  (1024, 8192, 256, 19922944, 528, 32, 496, 60),
+                  (1024, 4096, 256, 24641536, 528, 32, 496, 60)]),
+    ((16384, 192, True, jnp.bfloat16, None, 128),
+     [(1024, 8192, 256, 27000832, 2080, 64, 2016, 184),
+      (1024, 8192, 256, 28049408, 2080, 64, 2016, 184),
+      (1024, 4096, 256, 30146560, 2080, 64, 2016, 184)])],
+    ids=["1024x64", "4096x128", "16384x64", "16384x64|128", "16384x128",
+         "8192x64", "16384x192|128"])
+def test_unwindowed_plans_are_what_they_were(args, want):
+    """Every window=None plan the nine cells run, field for field, beside
+    the count of tiles. Forward and dQ of the first four shapes are a
+    literal copy of PR 34's tree, and so are all three kernels where the
+    sequence is resident whole (gpt2's and OLMoE's shapes). Since PR 54 a
+    kernel's own block is sized before its swept side: dK/dV at 8k and 16k
+    holds 1,024 keys against queries in grid blocks of 4,096 (184 tiles
+    where 512 x 8,192 made 560), and at 192 | 128 forward and dQ hold 1,024
+    queries against K and V in two grid blocks of 8,192 where they held
+    512 against the whole 16,384."""
+    plan = attention_plan(*args)
+    assert (plan.seq_len, plan.head_dim, plan.causal, plan.window,
+            plan.vmem_budget) == (*args[:2], True, None, 32 * 2 ** 20)
+    got = [tuple(getattr(kernel, f) for f in _FIELDS)
+           for kernel in (plan.fwd, plan.dq, plan.dkv)]
+    assert got == want
 
 
 def test_a_window_is_causal_and_positive():

@@ -139,6 +139,63 @@ def test_lowered_step_calls_the_five_kernels_at_192_and_128_with_no_map(
     assert "32x16384x16384" not in lowered
 
 
+def _mosaic_grids(lowered_text, kernels):
+    """{kernel name: {(grid, [each operand's and result's block])}} of a
+    lowered program's calls of `kernels`, read out of their serialized
+    bodies (`iteration_bounds` and every `window_bounds` of the kernel's
+    function, in the order of its arguments)."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    def numbers(array):
+        return tuple(int(n) for n in array.split(","))
+
+    grids = {}
+    for line in lowered_text.splitlines():
+        name = re.search(r'kernel_name = "(\w+)"', line)
+        if "@tpu_custom_call" not in line or not name \
+                or name.group(1) not in kernels:
+            continue
+        config = re.search(r'backend_config = "((?:[^"\\]|\\.)*)"', line)
+        body = base64.b64decode(json.loads(config.group(1).replace(
+            "\\22", '"'))["custom_call_config"]["body"])
+        context = ir.Context()
+        context.allow_unregistered_dialects = True  # Mosaic's own dialect
+        with context:
+            text = str(ir.Module.parse(body))
+        grid, = re.findall(r"iteration_bounds = array<i64: ([\d, ]+)>", text)
+        blocks = re.findall(r"window_bounds = array<i64: ([\d, ]+)>", text)
+        grids.setdefault(name.group(1), set()).add(
+            (numbers(grid), tuple(numbers(b) for b in blocks)))
+    return grids
+
+
+def test_forward_and_dq_run_the_grid_the_plan_says(lowered_step):
+    """`attention_plan(16384, 192, v_dim=128)` sizes a kernel's own block
+    first: forward and dQ hold 1,024 queries against K and V in two grid
+    blocks of 8,192, 16 x 2 programs a head, and the lowered step's Mosaic
+    calls carry that grid and those blocks (a score tile 1,024 wide, as
+    every other 16k shape has)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import attention_plan
+
+    plan = attention_plan(16384, 192, True, jnp.bfloat16, None, 128)
+    for kernel in (plan.fwd, plan.dq):
+        assert (kernel.block, kernel.swept, kernel.tiles) == (1024, 8192, 184)
+    grids = _mosaic_grids(lowered_step.as_text(),
+                          ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))
+    q, k, v = (1, 1024, 192), (1, 8192, 192), (1, 8192, 128)
+    o = row = (1, 1024, 128)
+    assert grids["_fwd_kernel"] == {((32, 16, 2), (q, k, v, o, row))}
+    assert grids["_dq_kernel"] == {((32, 16, 2), (q, k, v, o, row, row, q))}
+    (grid, blocks), = grids["_dkv_kernel"]
+    own, swept = plan.dkv.block, plan.dkv.swept
+    assert grid == (32, 16384 // own, 16384 // swept)
+    assert blocks[:3] == ((1, swept, 192), (1, own, 192), (1, own, 128))
+
+
 def test_the_plan_says_what_the_blocks_keep(lowered_step):
     """`remat_plan` as the step was traced with a chip's 15.75 GiB: state
     6.08 GB (weights, two moments, gradients), the base set 5.50 (a layer's
