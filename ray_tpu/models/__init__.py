@@ -96,3 +96,12 @@ from .xing4 import (  # noqa: F401
     xing4_loss_and_counters,
     xing4_param_axes,
 )
+from .glm4_moe_lite import (  # noqa: F401
+    Glm4MoeLiteConfig,
+    glm4_moe_lite_forward,
+    glm4_moe_lite_init,
+    glm4_moe_lite_loss,
+    glm4_moe_lite_loss_and_counters,
+    glm4_moe_lite_param_axes,
+    make_glm4_moe_lite_train_step,
+)

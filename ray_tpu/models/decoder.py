@@ -1,4 +1,4 @@
-"""The decoder's compute, written once: what the nine families of
+"""The decoder's compute, written once: what the ten families of
 ray_tpu.models train and what models.generate prefills and decodes.
 
 A family says what it is with a `Decoder`, which its config's `decoder()`
@@ -67,7 +67,10 @@ or neither, inside `attention`; `w_qa`: queries through a normed latent of
 their own, inside `latent_attention`; `hc_mixer`, `hc_mlp`: a branch that
 reads one learned mix of `dec.hyper.streams` residual streams and writes
 back through a doubly stochastic matrix (`hyper_connection`), where a layer
-that holds neither adds its branch to the one stream.
+that holds neither adds its branch to the one stream. And off the model's:
+`mtp`, a prediction module (DeepSeek-V3's multi-token prediction), which a
+training forward that is given the next tokens runs behind the stack
+(`prediction_module`) and nothing else reads.
 
 Nor need the layers be independent (SambaY, models.sambay): the stack
 carries two values forward besides x, `Shared`: the scan's output `m` of
@@ -77,6 +80,9 @@ after it attends over. Both pass through `jax.checkpoint` as block inputs
 and outputs, and their gradients arrive from every reader.
 
     decoder_hidden      embedding, layer stack, final norm, head
+    prediction_module   a multi-token-prediction module behind the stack:
+                        the next token's embedding joined to the last
+                        block's output, one more block, a norm of its own
     remat_plan          what each rematerialised layer keeps, from shapes
     decoder_logits      its rows times its head, float32
     empty_cache         each layer's state, by its row
@@ -908,6 +914,11 @@ def _differential(own, windowed=False, hands_on=False) -> Mixer:
 #     latent_attention(x, layer, dec[, cache, start_pos])
 #     hyper_connection(streams, hc, hyper)
 #     _streams_read(x, hc, hyper) -> (read, write, counters)
+# and, chipbench/families/glm4_moe_lite.py's, one more, which it swaps too
+# and, with `_block_of(dec, *_block_keys(dec, layers)[i])`, calls:
+#     prediction_module(h, embedded, module, block, eps) -> (rows, stats)
+# beside `_next_targets(targets)` and `joint_loss(x, x_next, head, targets,
+# weight)` on models/glm4_moe_lite.py, found through that module's names.
 # The first six and these but the last the benchmark also SWAPS on the
 # module (`setattr(decoder, name, faulty)`) and then traces the program, so
 # the program must find
@@ -1043,10 +1054,18 @@ keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
 # [512, 8192] product and K's assembly made again. (Per-head K and V are no
 # candidates of KEPT_WHERE_IT_FITS: nothing has measured what keeping them
 # saves.)
+# Nor does it keep q among what EVERY such block keeps: q is two products
+# and a rotary away from the block's input (through the 768-wide query
+# latent: 0.13 TFLOP a layer at GLM-4.7-Flash's 20 heads of 256, under a
+# millisecond of a v5e), and 168 MB a layer there, where six blocks and two
+# losses beside 9.3 GB of state leave no room: q is the first name of
+# KEPT_WHERE_IT_FITS instead, kept layer by layer where the step has the
+# room (Xing4.0's cell: in all five layers, as before it was a candidate).
 KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
     LATENT_ATTENTION: tuple(
         name for name in KEPT_UNDER_REMAT
-        if name not in ("flash_attention_k", "flash_attention_v"))
+        if name not in ("flash_attention_k", "flash_attention_v",
+                        "flash_attention_q"))
     + ("mla_latent", "mla_k_rope")}
 
 
@@ -1075,6 +1094,12 @@ def _kept(kind: str) -> Tuple[str, ...]:
 #     moe_choice      a held share's routing: the top-k's experts and the two
 #                     sorts' orders, integers (parallel/moe.py): two sorts
 #                     for under a megabyte a layer, before everything else
+#     flash_attention_q
+#                     a latent layer's q, which its kind's base set leaves out
+#                     (KEPT_BY_KIND; any other kind keeps it always and it is
+#                     no candidate there): before everything else too, so
+#                     that a step with room keeps what it kept before q was
+#                     a candidate
 # (only the candidates' order reads the costs, so one chip's ratio of the
 # two serves every chip whose matmuls outrun its memory)
 _FLOPS_A_BYTE = 240         # a v5e's 197 TFLOP/s over its 819 GB/s
@@ -1100,7 +1125,7 @@ KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
     "ssm_in_proj": _a_matmul, "ssm_gated": _passes(3),
     "gated_delta_in": _a_matmul, "short_conv_in": _a_matmul,
     "mlp_gate_up": _a_matmul, "moe_shared_up": _a_matmul,
-    "moe_choice": _first}
+    "moe_choice": _first, "flash_attention_q": _first}
 
 
 class RematPlan(NamedTuple):
@@ -1166,15 +1191,17 @@ def _block_account(block: Callable, x, layer, shared,
     residuals = jaxpr.outvars[len(jaxpr.outvars)
                               - len(jax.tree.leaves(pushforward)):]
     given = {id(v) for v in jaxpr.invars}
-    kept = _nbytes({id(v): v.aval for v in residuals
+    held = _nbytes({id(v): v.aval for v in residuals
                     if isinstance(v, Var) and id(v) not in given})
+    # (a name of both tables is the base set's: no candidate)
     extras = tuple(
         (eqn.params["name"], _nbytes(eqn.outvars[0].aval),
          KEPT_WHERE_IT_FITS[eqn.params["name"]](eqn.outvars[0].aval,
                                                 _rows_and_width(x)[1]))
         for eqn in jaxpr.eqns if eqn.primitive.name == "name"
-        and eqn.params["name"] in KEPT_WHERE_IT_FITS)
-    base = _nbytes(x) + kept - sum(size for _, size, _ in extras)
+        and eqn.params["name"] in KEPT_WHERE_IT_FITS
+        and eqn.params["name"] not in kept)
+    base = _nbytes(x) + held - sum(size for _, size, _ in extras)
     return x_out, shared_out, base, extras
 
 
@@ -1386,14 +1413,38 @@ def _streams_hold(x, layer) -> int:
     return 4 * _nbytes(x) if hyper_connected else 0
 
 
-def _reserve(accounts, keys, layers, x, vocab: int, chips: int) -> int:
+def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
+    """What the backward pass of a latent-attention block joined by the add
+    holds that no name shows: per-head K and V made again from the kept
+    latent, and the cotangents of the kernel's output, q, K and V (XLA's
+    account of GLM-4.7-Flash's five layers with no module, compiled for a
+    v5e with the base set kept: total - state - base set 3.08 GB, where the
+    named values and the held experts' rule account for 2.13 and these six
+    for 1.01; PERF.md section 6, PR 55). Nothing for a hyper-connected
+    block: `_streams_hold`'s four values were read with these among them."""
+    if kind != LATENT_ATTENTION or "hc_mixer" in layer or "hc_mlp" in layer:
+        return 0
+    _, r, n, vd = _latent_sizes(layer, dec.n_heads)
+    itemsize = jnp.dtype(layer["w_kvb"].dtype).itemsize
+    return 3 * tokens * dec.n_heads * (n + r + vd) * itemsize
+
+
+def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
+             losses: int = 1) -> int:
     """What a chip holds at a step's peak beside its state and what its
     blocks keep, from the shapes it holds: the loss's working set
     (ops.loss.working_set_bytes) and a block's backward pass, everything
     of the block that has a name in either table, alive at once while it
     is differentiated, and what its channel mixer's own rule says it holds
     besides (`_backward_holds`, `_streams_hold`). On one chip the two do
-    not meet and the largest block counts: the larger of the loss and it.
+    not meet and the largest block counts: the larger of the loss and it;
+    where the step has further `losses` over the one head (a prediction
+    module's), a working set each beside either: the stack's loss leaves
+    its rows' and its head's gradients waiting while the module's block
+    runs, scans and is differentiated, beside that block's input, the next
+    tokens' rows and the module's own head gradient (XLA's account of
+    GLM-4.7-Flash's step compiled for a v5e: 1.13 GB more beside the
+    largest block with the module than without; a working set is 1.27).
     Where the batch
     is split over `chips` the step reduces its gradients under compute
     (models/_training.py `_ASYNC_GRADIENT_REDUCE`), and XLA moves every
@@ -1410,19 +1461,24 @@ def _reserve(accounts, keys, layers, x, vocab: int, chips: int) -> int:
     blocks = [base + sum(size for _, size, _ in extras)
               + _backward_holds(key[1], tokens, layer)
               + _streams_hold(x, layer)
+              + _latent_holds(key[0], tokens, layer, dec)
               for (base, extras), key, layer in zip(accounts, keys, layers)]
-    return max(loss, *blocks) if chips == 1 else loss + sum(blocks)
+    return (max(loss, *blocks) if chips == 1 else loss + sum(blocks)) \
+        + (losses - 1) * loss
 
 
 def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
-               state_bytes: Optional[int], chips: int = 1) -> RematPlan:
+               state_bytes: Optional[int], chips: int = 1,
+               losses: int = 1) -> RematPlan:
     """Which names of KEPT_WHERE_IT_FITS each layer of a rematerialised
     stack keeps: a pure function of shapes, as ops.attention.attention_plan
     is of a kernel's. `layers` are a model's `params["layers"]` or their
-    shapes, `x` [batch, L, d] (or the streams, so many of them: a block's
-    input at its real width) the stack's input as ONE CHIP holds it (its
-    shape and dtype: where the batch is split over chips, a chip's share
-    of it), `vocab` the vocabulary's rows, `capacity` one chip's memory
+    shapes (a prediction module's block after them where the step runs
+    one: `dec` then names its kind and channel mixer last, and `losses` is
+    2, the module's own beside the stack's), `x` [batch, L, d] (or the
+    streams, so many of them: a block's input at its real width) the
+    stack's input as ONE CHIP holds it (its shape and dtype: where the
+    batch is split over chips, a chip's share of it), `vocab` the vocabulary's rows, `capacity` one chip's memory
     and `state_bytes` what the step holds there beside activations, both
     in bytes, `chips` how many the batch is split over. Every byte of the
     account is a chip's: the activations are traced at the chip's batch,
@@ -1455,7 +1511,7 @@ def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
             x, shared, base, extras = traced[seen]
             accounts.append((base, extras))
     base = sum(base for base, _ in accounts)
-    reserve = _reserve(accounts, keys, layers, x, vocab, chips)
+    reserve = _reserve(dec, accounts, keys, layers, x, vocab, chips, losses)
     taken: List[List[str]] = [[] for _ in accounts]
     left = kept = 0
     if capacity is not None and state_bytes is not None:
@@ -1478,7 +1534,8 @@ def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
         bytes_left=max(left, 0))
 
 
-def _planned_extras(dec: Decoder, layers, x, vocab: int) -> Tuple:
+def _planned_extras(dec: Decoder, layers, x, vocab: int,
+                    losses: int = 1) -> Tuple:
     """What each rematerialised layer keeps beyond the base set: what
     `remat_plan` adds inside a training step (ops.attention.step_memory)
     on a chip that says what it holds, nothing anywhere else. Any policy
@@ -1502,11 +1559,35 @@ def _planned_extras(dec: Decoder, layers, x, vocab: int) -> Tuple:
     a_chips = jax.tree.map(lambda t: jax.ShapeDtypeStruct(
         (-(-t.shape[0] // chips),) + t.shape[1:], t.dtype), x)
     return remat_plan(dec, layers, a_chips, vocab, capacity, state_bytes,
-                      chips).extras
+                      chips, losses).extras
+
+
+def prediction_module(h, embedded, module: Dict, block: Callable, eps: float):
+    """A multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437,
+    section 2.2) behind a stack: `h` [b, L, d] the last block's output
+    BEFORE the final norm, `embedded` [b, L, d] the main table's rows of
+    the tokens one further on, each normed by a weight of its own
+    (`enorm`, `hnorm`), side by side, the embedding first, times `w_eh`
+    [2 d, d]; `block`, one more layer over the same positions, causal as
+    any (`module["block"]` its weights: its own router, selection bias
+    and, were it served, cache entry); `norm`, the module's own final
+    norm -> (rows [b, L, d] that the MAIN head turns into the logits of
+    the token two on, the block's `stats`). The module holds no table and
+    no head: the caller looks `embedded` up in the stack's and hands the
+    rows to the stack's head, so each gathers a gradient a use."""
+    with jax.named_scope("mtp_embed"):
+        e = _norm(embedded, module, "enorm", eps)
+    with jax.named_scope("mtp_project"):
+        u = jnp.einsum("bsd,de->bse", jnp.concatenate(
+            [e, _norm(h, module, "hnorm", eps)], axis=-1), module["w_eh"])
+    u, stats, _, _ = block(u, module["block"], None, None, Shared())
+    with jax.named_scope("mtp_norm"):
+        return _norm(u, module, "norm", eps), stats
 
 
 def decoder_hidden(params: Dict, tokens, dec: Decoder,
-                   cache: Optional[List[Dict]] = None, start_pos=None):
+                   cache: Optional[List[Dict]] = None, start_pos=None,
+                   next_tokens=None):
     """tokens [b, L] -> (final-norm rows [b, L, d], the output head
     [d, vocab] (for `cross_entropy` or `decoder_logits`: a tied head under
     a data-parallel training step is `chip_views`, [chips, d, vocab]), the
@@ -1515,21 +1596,39 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     With a `cache` (an `empty_cache`, or the last call's) the tokens sit
     at `start_pos` + [0, L) and the mixers read and write it; with none
     this is the training forward. The rows come multiplied by
-    `dec.logit_scale`, so rows @ head are the model's logits."""
+    `dec.logit_scale`, so rows @ head are the model's logits.
+    With `next_tokens` [b, L], the tokens one further on (a training
+    forward's alone), the model's prediction module `params["mtp"]` runs
+    behind the stack on the last block's output as it is before the final
+    norm (`prediction_module`): `dec.kinds` and `dec.mlp` then name the
+    module's block after the layers', it is rematerialised and planned as
+    one of them, its `stats` come last, and the rows are a pair, (the
+    stack's, the module's), for the one head."""
     # A tied table under a data-parallel training step is one view a
     # chip, so that its lookup's and its head's gradients cross the chips
     # as one sum (ops/loss.py chip_views).
     views = None if "head" in params or cache is not None \
         else chip_views(params["embed"])
+
+    def embedded(ids):
+        return lookup(views, ids) if views is not None \
+            else jnp.take(params["embed"], ids, axis=0)
+
     with jax.named_scope("embed"):
-        x = lookup(views, tokens) if views is not None \
-            else jnp.take(params["embed"], tokens, axis=0)
-        x = _scaled(x, dec.embed_scale)
+        x = _scaled(embedded(tokens), dec.embed_scale)
         if dec.hyper is not None:       # every stream starts as the embedding
             x = (x,) * dec.hyper.streams
-    layers = params["layers"]
+    layers = blocks = params["layers"]
+    if next_tokens is not None:
+        if cache is not None or dec.hyper is not None:
+            raise ValueError(
+                "a prediction module runs in a training forward of one "
+                "residual stream: how several streams enter one is not built")
+        blocks = [*layers, params["mtp"]["block"]]
+    keys = _block_keys(dec, blocks)
 
-    extras = _planned_extras(dec, layers, x, params["embed"].shape[0]) \
+    extras = _planned_extras(dec, blocks, x, params["embed"].shape[0],
+                             losses=1 + (next_tokens is not None)) \
         if cache is None else ((),) * len(layers)
 
     @functools.cache
@@ -1547,12 +1646,20 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
     per_layer, new_cache, shared = [], [], Shared()
     with jax.named_scope("layers"):
         for key, extra, layer, cache_layer in zip(
-                _block_keys(dec, layers), extras, layers,
-                cache or [None] * len(layers)):
+                keys, extras, layers, cache or [None] * len(layers)):
             x, stats, cache_layer, shared = block_at(key, extra)(
                 x, layer, cache_layer, start_pos, shared)
             per_layer += [] if stats is None else [stats]
             new_cache.append(cache_layer)
+    if next_tokens is not None:
+        with jax.named_scope("mtp"):
+            with jax.named_scope("mtp_embed"):
+                rows = _scaled(embedded(next_tokens), dec.embed_scale)
+            x_next, stats = prediction_module(
+                x, rows, params["mtp"], block_at(keys[-1], extras[-1]),
+                dec.norm_eps)
+            x_next = _scaled(x_next, dec.logit_scale)
+            per_layer += [] if stats is None else [stats]
     with jax.named_scope("final_norm"):
         if dec.hyper is not None:       # the streams' sum is what is normed
             x = sum(t.astype(jnp.float32) for t in x).astype(x[0].dtype)
@@ -1561,6 +1668,8 @@ def decoder_hidden(params: Dict, tokens, dec: Decoder,
         head = views.swapaxes(1, 2)
     else:
         head = params["head"] if "head" in params else params["embed"].T
+    if next_tokens is not None:
+        x = (x, x_next)
     return x, head, per_layer, (new_cache if cache is not None else None)
 
 

@@ -1,7 +1,7 @@
 """Autoregressive generation with a cache, for any config with a
-`decoder()` and an `init(key)` (all nine families of ray_tpu.models: gpt,
-llama, moe, hybrid, sambay, olmo_hybrid, nemotron_h, lfm2_moe, xing4). No
-family is named here.
+`decoder()` and an `init(key)` (all ten families of ray_tpu.models: gpt,
+llama, moe, hybrid, sambay, olmo_hybrid, nemotron_h, lfm2_moe, xing4,
+glm4_moe_lite). No family is named here.
 
 Parity role: the reference serves LLMs by hosting external engines
 (vLLM etc.) on its actors; here the decode path is native — a

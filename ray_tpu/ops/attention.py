@@ -375,7 +375,8 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     that the triangle is all the work and its offsets are static, and 256
     where the sequence tiles by it and is longer (measured on a v5e at
     head_dim 64 and 128: 128 is 8-9% faster at 1,024 positions, 256 2-3%
-    faster at 2,048 and 4,096; PERF.md §6, PR 26). A kernel's own block is
+    faster at 2,048 and 4,096; PERF.md §6, PR 26; at head_dim 256 the
+    sub-block was not swept: 16k runs at 256, the rule's). A kernel's own block is
     sized first: the largest multiple of the sub-block up to 1,024 that
     tiles the sequence. Then the swept side (K and V in forward and dQ; Q,
     dO and their rows in dK/dV): resident whole where the estimate fits
@@ -388,7 +389,14 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     at (16384, 192 | 128) the forward takes 23.4 ms a 32 heads at 1,024 x
     8,192 where 512 x 16,384 took 34.8, and dK/dV at 1,024 x 4,096 is 7-15%
     faster than at 512 x 8,192 at every 8k and 16k shape of the cells
-    (PERF.md §6, PR 54).
+    (PERF.md §6, PR 54). At (16384, 256 | 256), the widest the cells run,
+    the swept side is in FOUR grid blocks of 4,096 in forward and dQ and
+    EIGHT of 2,048 in dK/dV beside a 1,024 own block (26.2, 27.3 and 27.3 MB
+    of the 32 MiB): read on a v5e in a train step of 20 heads, 0.894, 1.192
+    and 1.478 ms a head, 78%, 88% and 94% of the MXU's peak for what each
+    computes, where 128 | 128 with K and V whole reads 69%, 94% and 90.5%:
+    four grid blocks cost dQ some six points, eight cost dK/dV none
+    (PERF.md §6, PR 55).
 
     Under a `window` (causal, a query sees itself and the window - 1
     positions before it) the sizes follow the same rules, and a program

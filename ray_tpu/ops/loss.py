@@ -83,31 +83,38 @@ def lookup(views, tokens):
         check_vma=False)(views, tokens)
 
 
-def cross_entropy(x, head, targets):
+def cross_entropy(x, head, targets, valid=None):
     """Mean over every row of -log softmax(x @ head)[target].
 
     x [batch, ..., d] hidden rows, head [d, vocab] or, from `chip_views`,
     [chips, d, vocab] (its gradient is then one partial sum a chip, which
     the views add up), targets [batch, ...] int. bf16 operands keep their
     precision (the logits are the matmul's output in the operands'
-    dtype); the log-sum-exp and every accumulation are float32."""
+    dtype); the log-sum-exp and every accumulation are float32.
+
+    `valid` [batch, ...] bool, where given: the rows that count (a row
+    with no target: a prediction module's last position); the others add
+    nothing to the sum or to any gradient, and the mean is over the rows
+    that count."""
     with jax.named_scope("loss"):
-        return -_sum_ll(x, head, targets) / targets.size
+        rows = targets.size if valid is None else jnp.sum(valid)
+        return -_sum_ll(x, head, targets, valid) / rows
 
 
 @jax.custom_vjp
-def _sum_ll(x, head, targets):
-    """Sum over rows of the target's log-likelihood (float32 scalar)."""
-    return _sum_ll_fwd(x, head, targets)[0]
+def _sum_ll(x, head, targets, valid=None):
+    """Sum over the rows that count (all of them with no `valid`) of the
+    target's log-likelihood (float32 scalar)."""
+    return _sum_ll_fwd(x, head, targets, valid)[0]
 
 
-def _sum_ll_fwd(x, head, targets):
+def _sum_ll_fwd(x, head, targets, valid=None):
     """The loss and, as residuals, its whole gradient: dx like x, and the
     head's as one float32 [d, vocab] partial sum per chip of the batch
     axes, stacked; _sum_ll_bwd adds them up."""
     split = _batch_split()
     if split is None:
-        total, dx, dhead = _scan_chunks(x, head, targets)
+        total, dx, dhead = _scan_chunks(x, head, targets, valid)
         return total, (dx, dhead[None], head)
 
     # Each chip scans its own rows; the other axes (tp on vocab, fsdp on
@@ -119,15 +126,18 @@ def _sum_ll_fwd(x, head, targets):
 
     mesh, rows, manual = split
 
-    def per_chip(xl, hl, tl):
-        total, dx, dhead = _scan_chunks(xl, hl[0] if hl.ndim == 3 else hl, tl)
+    def per_chip(xl, hl, tl, *vl):
+        total, dx, dhead = _scan_chunks(xl, hl[0] if hl.ndim == 3 else hl, tl,
+                                        *vl)
         return total[None], dx, dhead[None]
 
+    counted = () if valid is None else (valid,)
     totals, dx, dheads = jax.shard_map(
         per_chip, mesh=mesh,
-        in_specs=(rows, rows if head.ndim == 3 else P(), rows),
+        in_specs=(rows, rows if head.ndim == 3 else P(), rows,
+                  *(rows for _ in counted)),
         out_specs=(rows, rows, rows), axis_names=manual,
-        check_vma=False)(x, head, targets)
+        check_vma=False)(x, head, targets, *counted)
     return jnp.sum(totals), (dx, dheads, head)
 
 
@@ -136,7 +146,7 @@ def _sum_ll_bwd(residuals, g):
     dhead = (g * dheads).astype(head.dtype)
     if head.ndim == 2:
         dhead = jnp.sum(dhead, axis=0)
-    return (g * dx).astype(dx.dtype), dhead, None
+    return (g * dx).astype(dx.dtype), dhead, None, None
 
 
 _sum_ll.defvjp(_sum_ll_fwd, _sum_ll_bwd)
@@ -165,10 +175,10 @@ def working_set_bytes(rows: int, d: int, vocab: int) -> int:
     return 6 * _chunk_rows(rows) * vocab + 4 * d * vocab
 
 
-def _scan_chunks(x, head, targets):
+def _scan_chunks(x, head, targets, valid=None):
     """(sum of log-likelihood, its gradient by x, by head in float32) over
     the rows given, `_LOSS_CHUNK` at a time: no chunk's logits outlive
-    its iteration."""
+    its iteration. With `valid`, over the rows it marks alone."""
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     tf = targets.reshape(-1)
@@ -176,20 +186,27 @@ def _scan_chunks(x, head, targets):
 
     def one_chunk(carry, inputs):
         total, dhead = carry
-        xs, ts = inputs
+        xs, ts, *counts = inputs
         lg = (xs @ head).astype(jnp.float32)
         lse = jax.nn.logsumexp(lg, axis=-1)
         hit = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 1) == ts[:, None]
         tgt = jnp.sum(jnp.where(hit, lg, 0.0), axis=-1)
         # d(sum of log-likelihood) / d(logits): one-hot less softmax.
-        dlg = (hit - jnp.exp(lg - lse[:, None])).astype(x.dtype)
+        dlg = hit - jnp.exp(lg - lse[:, None])
+        if counts:
+            dlg = jnp.where(counts[0][:, None], dlg, 0.0)
+        dlg = dlg.astype(x.dtype)
         dxs = (dlg @ head.T).astype(x.dtype)
         dhead = dhead + jnp.matmul(xs.T, dlg,
                                    preferred_element_type=jnp.float32)
-        return (total + jnp.sum(tgt - lse), dhead), dxs
+        ll = tgt - lse
+        if counts:
+            ll = jnp.where(counts[0], ll, 0.0)
+        return (total + jnp.sum(ll), dhead), dxs
 
     zero = (jnp.zeros((), jnp.float32), jnp.zeros(head.shape, jnp.float32))
+    counted = () if valid is None else (valid.reshape(-1, chunk),)
     (total, dhead), dx = jax.lax.scan(
         one_chunk, zero,
-        (xf.reshape(-1, chunk, d), tf.reshape(-1, chunk)))
+        (xf.reshape(-1, chunk, d), tf.reshape(-1, chunk), *counted))
     return total, dx.reshape(x.shape), dhead

@@ -214,7 +214,8 @@ DEVICE_SCOPES: Dict[str, str] = {
                   "fused first matrix",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
               "every decoder family (gpt, llama, moe, hybrid, sambay, "
-              "olmo_hybrid, nemotron_h, lfm2_moe, xing4), each layer run by "
+              "olmo_hybrid, nemotron_h, lfm2_moe, xing4, glm4_moe_lite), "
+              "each layer run by "
               "the row of decoder.MIXERS "
               "that its config's `kinds` names, in the train step and "
               "under prefill / decode alike",
@@ -222,6 +223,25 @@ DEVICE_SCOPES: Dict[str, str] = {
             "backbone: the scan over chunks of rows, forward and "
             "gradient in one pass (_sum_ll_bwd, which scales it, runs "
             "under the same scope: it is round the rule's call)",
+    "mtp": "models/decoder.py decoder_hidden, everything of a "
+           "multi-token-prediction module behind the stack (a training "
+           "forward that is given the next tokens): the scopes below and, "
+           "inside it, its block's own as any layer's "
+           "(`latent_attention_mixer`, `channel_mixer` and theirs)",
+    "mtp_embed": "models/decoder.py decoder_hidden and prediction_module: "
+                 "the second lookup of the main table, at the next tokens, "
+                 "and its norm (`enorm`); its backward is the table's "
+                 "second scatter-add",
+    "mtp_project": "models/decoder.py prediction_module: the norm of the "
+                   "last block's output (`hnorm`), the concatenation "
+                   "[embedding ; hidden] and its product with W_eh",
+    "mtp_norm": "models/decoder.py prediction_module: the module's own "
+                "final norm (the head's matmuls are `mtp_loss`'s)",
+    "mtp_loss": "models/glm4_moe_lite.py joint_loss, round a step's second "
+                "cross entropy (whose own `loss` lies inside it, so the "
+                "path is `mtp_loss/loss`): the module's rows against the "
+                "tokens two on through the MAIN head, the last position "
+                "masked; `loss` alone is the stack's own",
     "optimizer_update": "models/_training.py train_step, optimizer "
                         "update and apply",
     # The boundaries of a block, and the hand-written backward rules that

@@ -103,6 +103,10 @@ def test_window_with_the_swept_side_on_the_grid(window, monkeypatch):
     _check(1024, 64, 128, window)
 
 
+_FIELDS = ("block", "swept", "sub", "vmem_bytes", "computed", "masked",
+           "skipped", "tiles")
+
+
 @pytest.mark.parametrize("hd,budget", [(192, 4_000_000), (64, 3_000_000)],
                          ids=["192|128", "64|128"])
 def test_causal_wide_keys_with_the_swept_side_on_the_grid(hd, budget,
@@ -121,6 +125,46 @@ def test_causal_wide_keys_with_the_swept_side_on_the_grid(hd, budget,
     _check(1024, hd, 128, None, 0.14468,
            lambda q, k, v, window, scale: mha_reference(
                q, k, v, True, scale, window))
+
+
+@pytest.mark.parametrize("budget,blocks", [
+    (3_000_000, [(128, 256), (128, 256), (128, 128)]),
+    (5_000_000, [(256, 512), (256, 256), (256, 256)])],
+    ids=["4-4-8_grid_blocks", "own_blocks_of_two"])
+def test_256_wide_heads_with_the_swept_side_in_four_and_eight_grid_blocks(
+        budget, blocks, monkeypatch):
+    """The path a 16k call at 256 | 256 takes (GLM-4.7-Flash's latent
+    attention: 192 no-rope + 64 rope columns against values of 256): q, k
+    and v alike wide, the scale 1/16 an exact one (moved onto q), the swept
+    side in FOUR grid blocks in forward and dQ and EIGHT in dK/dV, as the
+    16k plan has it, and own blocks of two sub-blocks against four; `acc`,
+    `m`, `l`, dq, dk and dv are carried across every one of them."""
+    assert attention._scale_is_exact(256 ** -0.5)
+    monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
+    plan = attention_plan(1024, 256, True, jnp.float32, None, 256)
+    assert [(k.block, k.swept) for k in (plan.fwd, plan.dq, plan.dkv)] \
+        == blocks
+    assert max(1024 // k.swept for k in (plan.fwd, plan.dq, plan.dkv)) >= 4
+    _check(1024, 256, 256, None, 256 ** -0.5,
+           lambda q, k, v, window, scale: mha_reference(
+               q, k, v, True, scale, window))
+
+
+def test_the_plan_at_16k_and_256_wide_heads_is_pinned():
+    """`attention_plan(16384, 256, v_dim=256)`: forward and dQ hold 1,024
+    queries against K and V in four grid blocks of 4,096, dK/dV 1,024 keys
+    against queries in eight of 2,048; 26.2, 27.3 and 27.3 MB of the 32
+    MiB; the same 184 tiles a head as every other 16k shape."""
+    plan = attention_plan(16384, 256, True, jnp.bfloat16, None, 256)
+    assert [tuple(getattr(kernel, f) for f in _FIELDS)
+            for kernel in (plan.fwd, plan.dq, plan.dkv)] == [
+        (1024, 4096, 256, 26214400, 2080, 64, 2016, 184),
+        (1024, 4096, 256, 27262976, 2080, 64, 2016, 184),
+        (1024, 2048, 256, 27262976, 2080, 64, 2016, 184)]
+    assert [16384 // k.swept for k in (plan.fwd, plan.dq, plan.dkv)] \
+        == [4, 4, 8]
+    # v as wide as q and k: naming v's width changes nothing
+    assert plan == attention_plan(16384, 256)
 
 
 def _relative_errors(S, hd, vd, window, heads=1):
@@ -319,10 +363,6 @@ def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
     # and an unwindowed call's plan is what it was before windows existed
     assert attention_plan(16384, 64) == attention_plan(
         16384, 64, True, jnp.bfloat16, None, None)
-
-
-_FIELDS = ("block", "swept", "sub", "vmem_bytes", "computed", "masked",
-           "skipped", "tiles")
 
 
 @pytest.mark.parametrize("args,want", [

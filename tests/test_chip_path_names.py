@@ -33,6 +33,8 @@ from ray_tpu.models import (GPTConfig, HybridConfig, Lfm2MoeConfig,
 from ray_tpu.models.nemotron_h import NemotronHConfig
 from ray_tpu.models.olmo_hybrid import OlmoHybridConfig
 from ray_tpu.models.sambay import SambaYConfig
+from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
+                                          make_glm4_moe_lite_train_step)
 from ray_tpu.models.xing4 import Xing4Config, make_xing4_train_step
 from ray_tpu.util import profiling
 
@@ -512,8 +514,19 @@ CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
                  d_expert=128, hc_mult=2, hc_sinkhorn_iters=2, bias_rounds=8,
                  balance_tokens=0, max_seq_len=256), 2,
      ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
+    # A dense layer, then an expert layer, both under latent attention at
+    # q, k and v alike wide, on one residual stream, and a prediction module
+    # behind them: one more such block and a second loss over the one head.
+    (make_glm4_moe_lite_train_step,
+     Glm4MoeLiteConfig(vocab_size=512, d_model=128, n_heads=2,
+                       qk_nope_head_dim=64, qk_rope_head_dim=64,
+                       v_head_dim=128, q_lora_rank=64, kv_lora_rank=64,
+                       n_layers=2, n_dense_layers=1, d_ff=256, n_experts=4,
+                       experts_held=(1, 2), experts_per_token=2, d_expert=128,
+                       bias_rounds=8, balance_tokens=0, max_seq_len=256), 2,
+     ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
 ], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe", "sambay",
-        "olmo-hybrid", "nemotron-h", "xing4"])
+        "olmo-hybrid", "nemotron-h", "xing4", "glm4-moe-lite"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -558,6 +571,18 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
                 assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s/%s\b'
                                  % (branch, part), text), (branch, part)
         scopes += ["mla_project", "mla_expand", "moe_shared"]
+    if getattr(cfg, "n_predict_layers", 0):
+        # the module's parts inside `mtp`, its block's as any layer's, and
+        # its loss under a name apart from the stack's
+        for part in ("mtp_embed", "mtp_project", "mtp_norm",
+                     "latent_attention_mixer/mla_project",
+                     "channel_mixer/moe_route", "channel_mixer/moe_shared"):
+            assert re.search(
+                r'loc\("jit\(train_step\)/[^"]*\bmtp\)*/(?:[^"]*/)?%s\b' % part,
+                text), part
+        # the second loss stands beside the module, not inside it
+        assert not re.search(r'loc\("[^"]*\bmtp\)*/[^"]*loss\b', text)
+        scopes += ["mtp_loss", "mla_project", "mla_expand", "moe_shared"]
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
@@ -613,7 +638,20 @@ def _every_branch_and_rule_sits_under_a_name(cfg, text):
     # work; outside every name, what is no layer's (a counter, a cast of
     # the batch, the held experts' bias)
     assert seen.get(("layers",), set()) <= {"backward"}
-    assert all(names[:1] == ("layers",) for names in seen if len(names) > 1)
+    assert all(names[:1] in (("layers",), ("mtp",), ("mtp_loss",))
+               for names in seen if len(names) > 1)
+    if getattr(cfg, "n_predict_layers", 0):
+        # a prediction module: its own lines and its block's two branches
+        # forward, backward and made again, its loss beside the stack's
+        kind, = set(dec.kinds)
+        for part in ("mtp_embed", "mtp_project", "mtp_norm"):
+            assert seen[("mtp", part)] >= {"forward", "backward"}, part
+        for branch in (decoder.MIXER_SCOPES[kind], "channel_mixer"):
+            assert seen[("mtp", branch)] == passes, branch
+        assert "backward" in seen[("mtp", decoder.MIXER_SCOPES[kind],
+                                   "flash_attention_bwd")]
+        assert seen[("mtp_loss", "loss")] >= {"forward", "backward"}
+        assert seen.get(("mtp",), set()) <= {"backward"}
 
 
 def test_generate_steps_carry_their_scopes():

@@ -76,7 +76,8 @@ def step(topo):
         patch.setattr(attention, "_on_tpu", lambda: True)
 
         def planned(dec, layers, x, *rest, _plan=decoder.remat_plan):
-            PLANS[:] = [(x, rest[-1], _plan(dec, layers, x, *rest))]
+            # rest: vocab, capacity, state_bytes, chips, losses
+            PLANS[:] = [(x, rest[3], _plan(dec, layers, x, *rest))]
             return PLANS[0][2]
 
         patch.setattr(decoder, "remat_plan", planned)

@@ -22,6 +22,8 @@ from ray_tpu.models.moe import MoEConfig, moe_loss
 from ray_tpu.models.nemotron_h import NemotronHConfig, nemotron_h_loss
 from ray_tpu.models.olmo_hybrid import OlmoHybridConfig, olmo_hybrid_loss
 from ray_tpu.models.sambay import SambaYConfig, sambay_loss
+from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
+                                          glm4_moe_lite_loss)
 from ray_tpu.models.xing4 import Xing4Config, xing4_loss
 
 FAMILIES = {
@@ -34,6 +36,7 @@ FAMILIES = {
     "olmo_hybrid": (OlmoHybridConfig, olmo_hybrid_loss),
     "nemotron_h": (NemotronHConfig, nemotron_h_loss),
     "xing4": (Xing4Config, xing4_loss),
+    "glm4_moe_lite": (Glm4MoeLiteConfig, glm4_moe_lite_loss),
 }
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
@@ -81,13 +84,23 @@ def test_a_row_says_which_branches_its_block_has():
 def test_a_layers_channel_mixer_is_named_as_its_sequence_mixer_is(family):
     """`Decoder.mlp` is one callable a layer, as long as `kinds`, built by
     the family's `decoder()` from the fields it has: the one function a
-    layer in seven families; LFM2's and Xing4's dense SwiGLU in their
-    leading layers and one expert layer's function in all the others."""
+    layer in seven families; LFM2's, Xing4's and GLM-4.7-Flash's dense
+    SwiGLU in their leading layers and one expert layer's function in all
+    the others, a prediction module's block named last where one is asked
+    for."""
     cfg, _ = family
     dec = cfg.decoder()
     assert isinstance(dec.mlp, tuple) and len(dec.mlp) == len(dec.kinds)
     assert all(callable(mlp) for mlp in dec.mlp)
-    if isinstance(cfg, (Lfm2MoeConfig, Xing4Config)):
+    if isinstance(cfg, Glm4MoeLiteConfig):
+        with_module = cfg.decoder(module=True)
+        assert with_module.kinds[:-1] == dec.kinds and [
+            getattr(m, "func", m) for m in with_module.mlp[:-1]] == [
+            getattr(m, "func", m) for m in dec.mlp]
+        assert with_module.mlp[-1] is with_module.mlp[-2]   # an expert layer's
+        assert with_module._replace(mlp=dec.mlp) == dec._replace(
+            kinds=with_module.kinds)
+    if isinstance(cfg, (Lfm2MoeConfig, Xing4Config, Glm4MoeLiteConfig)):
         dense = cfg.n_dense_layers
         assert 0 < dense < cfg.n_layers
         assert set(dec.mlp[:dense]) == {decoder.swiglu_mlp}
@@ -260,6 +273,8 @@ FROZEN = {
     "latent_attention": (("x", "layer", "dec"), "xing4"),
     "hyper_connection": (("streams", "hc", "hyper"), "xing4"),
     "_streams_read": (("x", "hc", "hyper"), "xing4"),
+    "prediction_module": (("h", "embedded", "module", "block", "eps"),
+                          "glm4_moe_lite"),
 }
 
 
@@ -292,7 +307,7 @@ def test_generate_names_no_family():
     import ray_tpu.models as models
     families = {getattr(models, name) for name in (
         "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid",
-        "nemotron_h", "lfm2_moe", "xing4")}
+        "nemotron_h", "lfm2_moe", "xing4", "glm4_moe_lite")}
     held = {v for v in vars(generate).values() if inspect.ismodule(v)}
     assert not held & families
     assert "cache_layers" not in inspect.getsource(generate)

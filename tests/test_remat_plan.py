@@ -162,9 +162,10 @@ def test_a_batch_over_several_chips_is_planned_at_a_chips_share(
                                                       None)), \
             attention.step_memory(state_bytes=state_bytes, capacity=1 << 40):
         decoder._planned_extras(dec, layers, x8, vocab)
-    (_, _, a_chips, rows, capacity, state, chips), = asked
+    (_, _, a_chips, rows, capacity, state, chips, losses), = asked
     assert a_chips.shape == (2,) + x.shape[1:]
     assert (rows, capacity, state, chips) == (vocab, 1 << 40, state_bytes, 4)
+    assert losses == 1      # a stack with no prediction module behind it
 
 
 def test_only_activations_shrink_with_a_chips_share(nemotron):
@@ -193,7 +194,13 @@ def test_only_activations_shrink_with_a_chips_share(nemotron):
 
 
 def test_the_second_table_is_beside_the_first():
-    assert not set(decoder.KEPT_WHERE_IT_FITS) & set(decoder.KEPT_UNDER_REMAT)
+    # one name is of both: q, a candidate of the one kind whose base set
+    # leaves it out (a latent block's, KEPT_BY_KIND) and of no other's
+    assert set(decoder.KEPT_WHERE_IT_FITS) & set(decoder.KEPT_UNDER_REMAT) \
+        == {"flash_attention_q"}
+    assert [kind for kind in decoder.MIXERS
+            if "flash_attention_q" not in decoder._kept(kind)] \
+        == [decoder.LATENT_ATTENTION]
     value = jax.ShapeDtypeStruct((16384, 10304), jnp.bfloat16)
     per_byte = {name: cost(value, 2688) / (value.size * 2)
                 for name, cost in decoder.KEPT_WHERE_IT_FITS.items()}

@@ -507,10 +507,11 @@ def test_yarn_frequencies_and_scale_are_the_hand_worked_ones():
 def test_a_latent_block_under_remat_keeps_the_latent_not_the_heads(
         monkeypatch, capsys):
     """Under the family's policy a latent layer's block is handed its
-    arguments and keeps q, the kernel's output and lse, the normed latent
-    and the rotated shared key: no per-head K or V ([b, h, s, 24] or
-    [b, h, s, 12]) beyond q and the output; and its gradients are the
-    unrematerialised block's."""
+    arguments and keeps the kernel's output and lse, the normed latent
+    and the rotated shared key, and, where the step has room for the first
+    name of KEPT_WHERE_IT_FITS (as here), q: no per-head K or V ([b, h, s,
+    24] or [b, h, s, 12]) beyond q and the output; and its gradients are
+    the unrematerialised block's."""
     from jax.ad_checkpoint import print_saved_residuals
 
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
@@ -518,11 +519,13 @@ def test_a_latent_block_under_remat_keeps_the_latent_not_the_heads(
     params = xing4_init(jax.random.PRNGKey(0), cfg)
     dec, layer = cfg.decoder(), params["layers"][0]
     assert dec.remat is decoder.keep_kernel_outputs
-    kept = decoder._kept(decoder.LATENT_ATTENTION)
-    assert set(decoder.KEPT_UNDER_REMAT) - set(kept) == {
-        "flash_attention_k", "flash_attention_v"}
-    assert set(kept) - set(decoder.KEPT_UNDER_REMAT) == {
+    base = decoder._kept(decoder.LATENT_ATTENTION)
+    assert set(decoder.KEPT_UNDER_REMAT) - set(base) == {
+        "flash_attention_k", "flash_attention_v", "flash_attention_q"}
+    assert set(base) - set(decoder.KEPT_UNDER_REMAT) == {
         "mla_latent", "mla_k_rope"}
+    assert decoder.KEPT_WHERE_IT_FITS["flash_attention_q"] is decoder._first
+    kept = (*base, "flash_attention_q")
     assert decoder._kept(decoder.ATTENTION) is decoder.KEPT_UNDER_REMAT
     assert not {"mla_k", "mla_v"} & set(decoder.KEPT_WHERE_IT_FITS)
     plain = functools.partial(decoder._block, dec=dec, kind=dec.kinds[0],
