@@ -914,6 +914,9 @@ def _differential(own, windowed=False, hands_on=False) -> Mixer:
 #     latent_attention(x, layer, dec[, cache, start_pos])
 #     hyper_connection(streams, hc, hyper)
 #     _streams_read(x, hc, hyper) -> (read, write, counters)
+#         (`_block` hands it a fourth for a channel branch, the name
+#         `write`'s operand keeps under remat; a call with three names
+#         nothing)
 # and, chipbench/families/glm4_moe_lite.py's, one more, which it swaps too
 # and, with `_block_of(dec, *_block_keys(dec, layers)[i])`, calls:
 #     prediction_module(h, embedded, module, block, eps) -> (rows, stats)
@@ -1097,9 +1100,35 @@ def _kept(kind: str) -> Tuple[str, ...]:
 #     flash_attention_q
 #                     a latent layer's q, which its kind's base set leaves out
 #                     (KEPT_BY_KIND; any other kind keeps it always and it is
-#                     no candidate there): before everything else too, so
-#                     that a step with room keeps what it kept before q was
-#                     a candidate
+#                     no candidate there): before everything else too.
+#                     By the clock it is the cheapest byte here to make
+#                     again (below), but a step of Xing4.0's cell that makes
+#                     q again in every layer is an executable 3.4 times the
+#                     size (653 MB serialized against 190, 118 against 35 in
+#                     the persistent cache, compiled for a v5e: q kept or not
+#                     is all that moves it), which the compile cache of the
+#                     machines the cell runs on no longer holds beside the
+#                     cell's other programs: every run then compiles all of
+#                     them (`setup_s` 126 -> 540 s). Until that is
+#                     understood q keeps its place (PERF.md section 7)
+#     hc_channel_out  a hyper-connected block's channel branch as `write` is
+#                     handed it (`_streams_read`): what closes the branch, a
+#                     dense MLP's down projection from some d's width, or a
+#                     held share's down stage, the combine's k gathers of T
+#                     rows and the shared expert's down projection. Kept, the
+#                     experts' forward is dead code in the block's second
+#                     run, as it is under the add (the rule's residuals are
+#                     its inputs)
+# The costs are in the order the chip gave them, name by name, in Xing4.0's
+# cell (one call, one seed, `scope_profile.py`'s busy ms a step under forced
+# tables; PERF.md section 6, PR 56), ms saved a GB kept: hc_channel_out 68.9
+# (8.1 ms a layer for 117 MB: 3.8 matmuls an element), mlp_gate_up 20.9,
+# moe_shared_up 19.1 (1.15 and 1.05: a matmul each), flash_attention_q 12.3
+# (0.67 of one; 9.8 in GLM-4.7-Flash's, PR 55). The mixer branch's output
+# (its output projection alone: 20.0, 1.10) has no name yet: at a matmul an
+# element it would take, by the order of layers, the room that cell's
+# shared up projections hold, and without those XLA's total for the step
+# is 15.90 GB where with them it is 15.57 (PERF.md section 7).
 # (only the candidates' order reads the costs, so one chip's ratio of the
 # two serves every chip whose matmuls outrun its memory)
 _FLOPS_A_BYTE = 240         # a v5e's 197 TFLOP/s over its 819 GB/s
@@ -1117,6 +1146,13 @@ def _passes(count: float) -> Callable:
     return cost
 
 
+def _matmuls(count: float) -> Callable:
+    """So many of `_a_matmul`."""
+    def cost(value, d: int) -> float:
+        return count * _a_matmul(value, d)
+    return cost
+
+
 def _first(value, d: int) -> float:
     return math.inf
 
@@ -1125,7 +1161,8 @@ KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
     "ssm_in_proj": _a_matmul, "ssm_gated": _passes(3),
     "gated_delta_in": _a_matmul, "short_conv_in": _a_matmul,
     "mlp_gate_up": _a_matmul, "moe_shared_up": _a_matmul,
-    "moe_choice": _first, "flash_attention_q": _first}
+    "moe_choice": _first, "flash_attention_q": _first,
+    "hc_channel_out": _matmuls(4)}
 
 
 class RematPlan(NamedTuple):
@@ -1283,7 +1320,8 @@ def hyper_connection(streams, hc, hyper: HyperConnections):
     return h_pre, h_post, m
 
 
-def _streams_read(x, hc, hyper: Optional[HyperConnections]):
+def _streams_read(x, hc, hyper: Optional[HyperConnections],
+                  out_name: Optional[str] = None):
     """What a branch reads of the block's x, how its output is joined to
     it, and what the join counts. With no hyper-connection weights `hc`: x
     itself, the residual add, nothing. With them, x is the streams, n
@@ -1292,7 +1330,12 @@ def _streams_read(x, hc, hyper: Optional[HyperConnections]):
     are the largest off-diagonal entry of H_res and the largest |column
     sum - 1| (the streams still mix; the matrix is still on its manifold).
     The mixes accumulate in float32 and return the streams' dtype. The
-    streams are apart, not one [b, L, n, d] value, so that every mix is
+    value `write` is handed carries `out_name` where one is given
+    (`_block`: a channel branch's is `hc_channel_out`, a candidate of
+    KEPT_WHERE_IT_FITS): H_post's gradient is a row dot of the streams'
+    cotangent with it, so a rematerialised block that does not keep it runs
+    the whole branch again to have it.
+    The streams are apart, not one [b, L, n, d] value, so that every mix is
     multiply-adds of [b, L, d] values by a column a token: XLA for the v5e
     copies slices of a stream axis out in float32 and pads them back in
     the backward pass (3.7 GB more alive a step at 16,384 tokens of four
@@ -1309,6 +1352,8 @@ def _streams_read(x, hc, hyper: Optional[HyperConnections]):
                 for i in range(n)).astype(dtype)
 
     def write(y):
+        if out_name is not None:
+            y = checkpoint_name(y, out_name)
         with jax.named_scope("hc_write"):
             return tuple(
                 (h_post[..., i, None] * y.astype(f32)
@@ -1355,7 +1400,7 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
     if row.channel:
         with jax.named_scope("channel_mixer"):
             u, write, counters = _streams_read(x, layer.get("hc_mlp"),
-                                               dec.hyper)
+                                               dec.hyper, "hc_channel_out")
             out, stats = mlp(_norm_if_held(u, layer, "ln2", eps), layer)
             out = _norm_if_held(out, layer, "post_feedforward", eps)
             x = write(_scaled(out, dec.residual_scale))
@@ -1403,14 +1448,25 @@ def _backward_holds(mlp: Optional[Callable], tokens: int, layer) -> int:
 
 def _streams_hold(x, layer) -> int:
     """What the backward pass of a hyper-connected block holds that no name
-    shows: four values of the streams' size, the streams between its two
-    branches made again and the cotangents of its output, of those and of
-    its input (XLA's account of Xing4.0's step compiled for a v5e, with the
-    base set kept: total - state - base set 3.61 GB, where the named values
-    and the held experts' rule account for 1.97 and these four for 1.88;
-    PERF.md section 6, PR 53). Nothing for a block joined by the add."""
+    shows: two and a half values of the streams' size (ten of one
+    stream's), the streams between its two branches made again and the
+    cotangents of its output and of those, less what XLA shares among them.
+    A calibration, not a count: XLA's account of Xing4.0's step compiled
+    for a v5e, total - state - base set - what the plan keeps, reads 3.13
+    to 3.27 GB under six plans that keep every layer's `hc_channel_out`
+    (3.13 under the one the cell runs: 15.57 GB in all) and 3.23 with the
+    base set alone, where the named values and the held experts' rule
+    account for 2.09 and these for 1.17: 3.26. It was four values while
+    the channel branch's output had no name and the branch was made again
+    beside five kept q: 3.61 of 3.85, PR 53. The account is not from above
+    for every set of names: keeping q and every channel output but no
+    shared expert's up projection reads 3.74 (XLA holds more where it
+    makes that projection again), which this table's order does not give
+    at a v5e's capacity; tests/test_compile_v5e_xing4.py holds the total
+    of the plan it does give (PERF.md section 6, PR 56). Nothing for a
+    block joined by the add."""
     hyper_connected = "hc_mixer" in layer or "hc_mlp" in layer
-    return 4 * _nbytes(x) if hyper_connected else 0
+    return 5 * _nbytes(x) // 2 if hyper_connected else 0
 
 
 def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
@@ -1421,7 +1477,7 @@ def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
     v5e with the base set kept: total - state - base set 3.08 GB, where the
     named values and the held experts' rule account for 2.13 and these six
     for 1.01; PERF.md section 6, PR 55). Nothing for a hyper-connected
-    block: `_streams_hold`'s four values were read with these among them."""
+    block: `_streams_hold`'s values were read with these among them."""
     if kind != LATENT_ATTENTION or "hc_mixer" in layer or "hc_mlp" in layer:
         return 0
     _, r, n, vd = _latent_sizes(layer, dec.n_heads)

@@ -7,7 +7,7 @@ frequencies, a dense SwiGLU of 9216, a 64-wide router over 8 held SwiGLU
 experts of 1024 beside a gated shared one, V 16,384 untied), layer 0 and
 layers 2-5, B=1 x S=16384, remat on, AdamW at the family's rate — compiles
 for one chip, calls exactly the attention and grouped-matmul kernels under
-the program's scopes, no attention forward twice though remat is on, keeps
+the program's scopes, no forward kernel twice though remat is on, keeps
 the latent and no per-head key or value, never holds a [32, 16384, 16384]
 map or a float32 copy of the streams, and fits the chip by XLA's memory
 analysis (PERF.md section 4 has the figure). What the LOWERED step shows
@@ -198,23 +198,35 @@ def test_forward_and_dq_run_the_grid_the_plan_says(lowered_step):
 
 def test_the_plan_says_what_the_blocks_keep(lowered_step):
     """`remat_plan` as the step was traced with a chip's 15.75 GiB: state
-    6.08 GB (weights, two moments, gradients), the base set 5.50 (a layer's
-    four streams 0.47, q 0.20, the output 0.13, the lane-padded lse 0.27,
-    the latent and shared key 0.02, the router's scores), the reserve 3.85
-    of which the streams a hyper-connected block's backward holds are 1.88
-    (`_streams_hold`): room for the four expert layers' routing choices and
-    shared projections (0.27 GB) and not for the dense layer's gate and up
-    (0.60). Since PR 55 a latent block's q (0.20 GB a layer) is the first
-    candidate and no longer of the base set: every layer keeps it here, the
-    same names as before, 1.01 GB moved from one account to the other."""
+    6.08 GB (weights, two moments, gradients), the base set 4.49 (a layer's
+    four streams 0.47, the output 0.13, the lane-padded lse 0.27, the
+    latent and shared key 0.02, the router's scores), the reserve 3.26 of
+    which the streams a hyper-connected block's backward holds are 1.17
+    (`_streams_hold`: two and a half values of their size since PR 56,
+    four while the channel branch was made again), 2.00 GB of room. First
+    what stands first: every latent block's q (0.20 GB a layer) and the
+    four expert layers' routing choices (6 MB); then every layer's channel
+    branch's output (`hc_channel_out`, 0.12 GB a layer, four matmuls an
+    element: the expert layers' forward no longer runs twice); then at a
+    matmul an element the four shared experts' up projections (0.07 a
+    layer) and not the dense layer's gate and up (0.60): PR 55's names and
+    five branch outputs, 0.59 GB more, 0.14 left."""
     plan, = PLANS
-    assert plan.extras == (("flash_attention_q",),) + (
-        ("flash_attention_q", "moe_choice", "moe_shared_up"),) * 4
+    assert plan.extras == (("flash_attention_q", "hc_channel_out"),) + (
+        ("flash_attention_q", "hc_channel_out", "moe_choice",
+         "moe_shared_up"),) * 4
     assert plan.layers_extended == 5
-    q = 5 * 32 * 16384 * 192 * 2
-    assert 0.25e9 < plan.kept_extra_bytes - q < 0.30e9
-    assert 5.4e9 < plan.base_bytes + q < 5.6e9
-    assert 3.7e9 < plan.reserve_bytes < 4.0e9
+    q = 32 * 16384 * 192 * 2
+    branch = 16384 * 3584 * 2           # a branch's output, bfloat16
+    choices = 3 * 16384 * 4 * 4 + 8 * 4     # k = 4, 8 held experts
+    shared_up = 16384 * 2048 * 2
+    assert plan.kept_extra_bytes == 5 * (q + branch) \
+        + 4 * (choices + shared_up) == 1_865_416_832
+    assert plan.base_bytes == 4_490_003_712
+    assert plan.reserve_bytes == 3_259_760_928
+    assert plan.bytes_left == 140_031_096 == (
+        int(HBM_BYTES) - 2 ** 30 - plan.state_bytes - plan.base_bytes
+        - plan.reserve_bytes - plan.kept_extra_bytes)
 
 
 @pytest.mark.slow
@@ -243,22 +255,23 @@ def test_step_calls_exactly_the_five_kernels_under_the_programs_scopes(step):
 
 
 @pytest.mark.slow
-def test_no_attention_forward_runs_twice_and_the_experts_forward_does(step):
+def test_no_forward_kernel_runs_twice_the_experts_neither(step):
     """Remat is on, and a latent layer's block keeps q, the kernel's output
-    and lse (models/decoder.py KEPT_BY_KIND): each of the five layers calls
-    its forward kernel once, with keys and values made again from the kept
-    latent. The four expert layers call their two forward grouped matmuls
-    (gate | up as one, down) TWICE, and make the first again in the
-    backward rule: 20 calls beside 8 gradients by the rows and 8 by the
-    weights. Where a block is joined by the add the rule's residuals are its
-    inputs and the block's second forward is dead (lfm2moe: 12); here H_post's
-    gradient reads the branch's output, so the second forward lives (PERF.md
-    section 7)."""
+    and lse (models/decoder.py KEPT_BY_KIND and the plan): each of the five
+    layers calls its forward kernel once, with keys and values made again
+    from the kept latent. The four expert layers call their two forward grouped matmuls
+    (gate | up as one, down) ONCE, and make the first again in the backward
+    rule: 12 calls beside 8 gradients by the rows and 8 by the weights, as
+    a held-expert block joined by the add has (lfm2moe). Until PR 56 there
+    were 20: H_post's gradient reads the branch's output, which no kept
+    name held, so the block's second forward of the experts lived; every
+    layer keeps `hc_channel_out` now and it is dead code (the rule's
+    residuals are its inputs)."""
     from ray_tpu.util import profiling
 
     assert profiling.kernel_calls(step[1]) == {
         "flash_attention_fwd": 5, "flash_attention_dq": 5,
-        "flash_attention_dkv": 5, "grouped_matmul_fwd": 20,
+        "flash_attention_dkv": 5, "grouped_matmul_fwd": 12,
         "grouped_matmul_dlhs": 8, "grouped_matmul_drhs": 8}
 
 
@@ -291,7 +304,8 @@ def test_step_fits_a_chip_by_xlas_own_total(step, record_property):
     print(f"xing4-train-1chip step: {total / 1e9:.2f} GB "
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")
-    # XLA's own total: 15.46 GB, 1.35 GiB under the chip's 15.75 GiB (with
-    # the base set alone 15.19 GB)
+    # XLA's own total: 15.57 GB (15,568,587,264), 1.25 GiB under the chip's
+    # 15.75 GiB; the plan's own sum is 15.70. With the base set alone 13.80
+    # GB; under PR 55's plan (the same names less the branch outputs) 15.46.
     assert total < 15.6e9
     assert total <= HBM_BYTES - 2 ** 30

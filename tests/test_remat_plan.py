@@ -193,6 +193,57 @@ def test_only_activations_shrink_with_a_chips_share(nemotron):
         16384, x.shape[-1], vocab) == 6 * 4096 * vocab + 4 * 2688 * vocab
 
 
+_MAMBA, _EXPERTS = ("ssm_gated", "ssm_in_proj"), ("moe_choice", "moe_shared_up")
+
+
+@pytest.mark.parametrize("family, config, traffic, extras, kept", [
+    ("olmoe", "olmoe-1b-7b", "pretrain-olmoe-b4-s4096", ((),) * 2, 0),
+    ("granite_hybrid", "granite-4.0-h-micro", "pretrain-granite4h-b1-s16384",
+     (("mlp_gate_up", "ssm_gated", "ssm_in_proj"), ("ssm_in_proj",))
+     + ((),) * 8, 1_228_931_072),
+    ("sambay", "phi-4-mini-flash-reasoning", "pretrain-phi4flash-b1-s16384",
+     (("mlp_gate_up", "ssm_in_proj"), (), ("ssm_in_proj",)) + ((),) * 5,
+     1_342_177_280),
+    ("olmo_hybrid", "olmo-hybrid-7b", "pretrain-olmohybrid-b1-s16384",
+     (("gated_delta_in", "mlp_gate_up"),) + ((),) * 3, 1_287_651_328),
+    ("nemotron_h", "nemotron-3-nano-30b-a3b",
+     "pretrain-nemotron3nano-b1-s16384",
+     (_MAMBA, _EXPERTS, _MAMBA, _EXPERTS, _MAMBA, (), _EXPERTS, _MAMBA,
+      _EXPERTS), 2_865_234_176),
+    ("lfm2_moe", "lfm2-8b-a1b", "pretrain-lfm2moe-s8192",
+     ((),) + (("moe_choice",),) * 4, 6_291_712),
+], ids=["olmoe", "granite4h", "phi4flash", "olmohybrid", "nemotron3nano",
+        "lfm2moe"])
+def test_a_block_joined_by_the_add_offers_no_branch_output(
+        monkeypatch, family, config, traffic, extras, kept):
+    """The six rematerialised cells whose layers hold no hyper-connection:
+    no block's account lists `hc_channel_out` (the name is given where
+    `write` is the hyper-connection's, and nowhere else), none has a latent
+    layer, so none offers q either, and the plan at
+    a v5e's capacity is the parent's (PR 55's), name for name and byte for
+    byte."""
+    import importlib
+
+    dec, layers, x, vocab, state_bytes = _cell(
+        monkeypatch, importlib.import_module(f"chipbench.families.{family}"),
+        config, traffic)
+    assert dec.hyper is None
+    assert decoder.LATENT_ATTENTION not in dec.kinds
+    offered = set()
+    real = decoder._block_account
+
+    def account(*args, **kwargs):
+        out = real(*args, **kwargs)
+        offered.update(name for name, _, _ in out[3])
+        return out
+
+    monkeypatch.setattr(decoder, "_block_account", account)
+    plan = decoder.remat_plan(dec, layers, x, vocab, V5E_BYTES, state_bytes)
+    assert not offered & {"hc_channel_out", "flash_attention_q"}
+    assert plan.extras == extras
+    assert plan.kept_extra_bytes == kept
+
+
 def test_the_second_table_is_beside_the_first():
     # one name is of both: q, a candidate of the one kind whose base set
     # leaves it out (a latent block's, KEPT_BY_KIND) and of no other's
@@ -207,6 +258,9 @@ def test_the_second_table_is_beside_the_first():
     assert per_byte["ssm_in_proj"] == 2688.0      # 2 d flops for 2 bytes
     assert per_byte["moe_choice"] > per_byte["ssm_in_proj"] > per_byte[
         "ssm_gated"] > 0
+    # what closes a hyper-connected block's channel branch: four matmuls
+    # an element (as the chip ordered them: PERF.md section 6, PR 56)
+    assert per_byte["moe_choice"] > per_byte["hc_channel_out"] == 4 * 2688.0
 
 
 # ---------------------------------------------------------------------------

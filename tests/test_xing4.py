@@ -28,6 +28,7 @@ from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.xing4 import (HC_BRANCHES, Xing4Config,
                                   make_xing4_train_step, xing4_forward,
                                   xing4_init, xing4_loss, xing4_param_axes)
+from ray_tpu.ops import attention
 from ray_tpu.ops.layers import yarn_inv_freq, yarn_mscale
 from ray_tpu.parallel.moe import held_moe_layer
 
@@ -570,14 +571,114 @@ def test_the_plan_counts_a_block_input_at_its_real_width():
     streams = cfg.hc_mult * 256 * cfg.d_model * 2
     assert decoder._rows_and_width(x) == (256, cfg.d_model)
     assert decoder._rows_and_width(one) == (256, cfg.d_model)
-    assert decoder._streams_hold(x, layers[0]) == 4 * streams
+    assert decoder._streams_hold(x, layers[0]) == 5 * streams // 2
     assert decoder._streams_hold(one, {"wq": None}) == 0
     plan = decoder.remat_plan(dec, layers, x, cfg.vocab_size, 2 ** 34, 0)
     assert plan.base_bytes >= cfg.n_layers * streams
-    assert plan.reserve_bytes >= 4 * streams
+    assert plan.reserve_bytes >= 5 * streams // 2
     assert plan.layers_extended == cfg.n_layers
     none = decoder.remat_plan(dec, layers, x, cfg.vocab_size, 2 ** 20, 0)
     assert none.layers_extended == 0 and none.kept_extra_bytes == 0
+
+
+# ---------------------------------------------------------------------------
+# a hyper-connected branch's output, kept where it fits
+# ---------------------------------------------------------------------------
+def test_loss_and_every_gradient_with_the_branches_outputs_kept(
+        tiny, monkeypatch):
+    """The tiny model with remat on, inside `step_memory` with room for
+    everything: the plan is asked and every layer's block keeps
+    `hc_channel_out` beside the other candidates; the
+    loss and every gradient are the base policy's to the last bit (a kept
+    value is the value that was made again), and the unrematerialised
+    program's as far as the base policy's are: XLA:CPU fuses one of 43
+    gradients another way round a `jax.checkpoint`, 1.5e-8 apart."""
+    cfg, params, batch = tiny
+    asked = []
+    real = decoder.remat_plan
+
+    def spy(*args, **kwargs):
+        asked.append(real(*args, **kwargs))
+        return asked[-1]
+
+    monkeypatch.setattr(decoder, "remat_plan", spy)
+
+    def step(cfg):
+        return _loss_and_gradients(lambda p, b: xing4_loss(p, b, cfg),
+                                   params, batch)
+
+    plain = step(dataclasses.replace(cfg, remat=False))
+    assert cfg.decoder().remat is decoder.keep_kernel_outputs
+    base = step(cfg)
+    assert not asked                    # no step's memory: no plan at all
+    with attention.step_memory(state_bytes=0, capacity=1 << 40):
+        kept = step(cfg)
+    plan, = asked
+    assert plan.extras == (
+        ("hc_channel_out", "mlp_gate_up"),
+        ("hc_channel_out", "moe_choice", "moe_shared_up"))
+    for g, b, w in zip(*map(jax.tree.leaves, (kept, base, plain))):
+        np.testing.assert_array_equal(g, b)
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("index, remade_without, remade_with", [
+    (0, set(), set()),
+    (1, {"moe_combine", "moe_shared", "moe_route"},
+     {"moe_shared", "moe_route"})], ids=["dense", "experts"])
+def test_a_block_that_keeps_its_channel_branchs_output_closes_it_once(
+        index, remade_without, remade_with, capsys):
+    """A hyper-connected block linearised under its base set plus
+    `hc_channel_out`: the branch's output [b, L, d] is among the residuals
+    under that name, and the compiled backward pass makes nothing of what
+    closes the branch again: no second down projection (a dense SwiGLU's,
+    the shared expert's), no second combine; what the branch's own
+    gradients read (the dense layer's gate and up, the shared expert's up
+    projection and activation, the router's weights) still is."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    cfg = _tiny(**CHEAP)
+    params = _spread(xing4_init(jax.random.PRNGKey(0), cfg), cfg)
+    dec, layer = cfg.decoder(), params["layers"][index]
+    plain = functools.partial(decoder._block, dec=dec, kind=dec.kinds[index],
+                              mlp=dec.mlp[index])
+    b, s = 2, 40
+    x = tuple(jax.random.normal(jax.random.PRNGKey(2 + i),
+                                (b, s, cfg.d_model))
+              for i in range(cfg.hc_mult))
+
+    def remade(extra):
+        """(the residuals `write` named, the channel branch's scopes the
+        compiled backward runs a second time, its matmuls made again)."""
+        block = jax.checkpoint(
+            plain, policy=jax.checkpoint_policies.save_only_these_names(
+                *decoder._kept(dec.kinds[index]), *extra))
+        print_saved_residuals(
+            lambda x, layer: block(x, layer, None, None)[0], x, layer)
+        named = [line for line in capsys.readouterr().out.splitlines()
+                 if line.startswith(f"f32[{b},{s},{cfg.d_model}] output")
+                 and "_streams_read.<locals>.write" in line]
+
+        def loss(x, layer):
+            return sum(jnp.sum(jnp.sin(t))
+                       for t in block(x, layer, None, None)[0])
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            x, layer).compile().as_text()
+        again = [line for line in text.splitlines()
+                 if "rematted_computation" in line
+                 and "channel_mixer" in line]
+        scopes = {scope for scope in ("moe_combine", "moe_shared",
+                                      "moe_route")
+                  if any(f"/{scope}" in line for line in again)}
+        return named, scopes, sum(" dot(" in line for line in again)
+
+    named, scopes, dots = remade(())
+    assert not named
+    named_kept, scopes_kept, dots_kept = remade(("hc_channel_out",))
+    assert len(named_kept) == 1     # the value `write` was handed, [b, L, d]
+    assert dots_kept < dots     # the down projection(s) run once
+    assert (scopes, scopes_kept) == (remade_without, remade_with)
 
 
 # ---------------------------------------------------------------------------
