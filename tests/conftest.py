@@ -25,12 +25,14 @@ if "xla_force_host_platform_device_count" not in _flags:
 # products in bfloat16 (a dp=4 step's loss 0.3% off its unsharded twin,
 # test_models_ops.py) and the older ones round two forms of one sum apart
 # (test_gated_delta.py, test_loss.py). The chip's compiler, where a test
-# compiles for it, takes no notice.
-for _name, _value in (("xla_cpu_use_fusion_emitters", "false"),
-                      ("xla_backend_optimization_level", "1"),
-                      ("xla_llvm_disable_expensive_passes", "true")):
-    if _name not in _flags:
-        _flags += f" --{_name}={_value}"
+# compiles for it, takes no notice. tests/cell_rehearsal.py hands the same
+# three to the subprocesses it starts.
+FAST_BUILD_FLAGS = ("--xla_cpu_use_fusion_emitters=false",
+                    "--xla_backend_optimization_level=1",
+                    "--xla_llvm_disable_expensive_passes=true")
+for _flag in FAST_BUILD_FLAGS:
+    if _flag.split("=")[0].lstrip("-") not in _flags:
+        _flags += " " + _flag
 os.environ["XLA_FLAGS"] = _flags
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Worker subprocesses spawned by ray_tpu set their own env; the driver-side

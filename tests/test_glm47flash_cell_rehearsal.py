@@ -4,108 +4,48 @@ the tools its limits and counters are read with, what BENCHMARK.json and
 the configuration's file say of it, and its readers on a hand-made record
 at its real sizes.
 
-The manifest is BENCHMARK.json as it is with the cell's configuration and
-traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/glm47flash-tiny.json,
-.../traffic/tiny-train-glm47flash.json: one dense layer, one expert layer
+The tiny stand-ins are
+chipbench/tests/rehearsal/data/configs/glm47flash-tiny.json and
+.../traffic/tiny-train-glm47flash.json (one dense layer, one expert layer
 and the prediction module behind them, four heads of 24 + 8 | 32 over
 latents of 64 and 48, experts 2 to 5 of 8 held beside a shared expert, two
-sequences of 64), as tests/test_xing4_cell_rehearsal.py does for its cell
-and for its reason. The numbers of a CPU run mean nothing and are written
-nowhere."""
+sequences of 64); tests/cell_rehearsal.py has the manifest, the runs and
+why the cell is rehearsed from here."""
 
 import dataclasses
 import json
+import math
 import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import cell_rehearsal as rehearsal
+from cell_rehearsal import load
+
 CELL = "glm47flash-train-1chip"
-TINY = "chipbench/tests/rehearsal/data"
 CONFIG = "chipbench/configs/glm-4.7-flash.json"
 MIX = "chipbench/traffic/pretrain-glm47flash-b1-s16384.json"
-
-
-def _load(rel):
-    with open(os.path.join(ROOT, rel)) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def manifest_path(tmp_path_factory) -> str:
-    m = _load("BENCHMARK.json")
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/glm47flash-tiny.json"
-    cell["traffic"] = "tiny-train-glm47flash"
-    m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("glm47flash_rehearsal") / "BENCHMARK.json"
-    path.write_text(json.dumps(m))
-    return str(path)
-
-
-# tests/test_olmoe_cell_rehearsal.py has why run.py's one glob over
-# /dev/shm answers nothing here.
-RUN_PY = r"""
-import glob, runpy, sys
-_glob = glob.glob
-glob.glob = lambda p, *a, **k: [] if str(p).startswith(
-    "/dev/shm/ray_tpu_session_") else _glob(p, *a, **k)
-sys.argv = ["chipbench/run.py"] + sys.argv[1:]
-runpy.run_path("chipbench/run.py", run_name="__main__")
-"""
-
-
 # chipbench/limit_readings.py with three of the family's eleven faults to
 # plant, one of the module's own lines, one of its loss and one of a
 # config's number: the pass reads each fault's loss and kernel errors in a
 # program of its own; the others are the chip's (PERF.md section 4).
 KEPT_FAULTS = ("halves_swapped", "target_not_shifted", "mtp_loss_weight_1")
-LIMITS_PY = r"""
-import runpy, sys
-sys.path.insert(0, ".")
-from chipbench.families import glm4_moe_lite as family
-family.STRUCTURAL_FAULTS = {
-    name: family.STRUCTURAL_FAULTS[name] for name in %r}
-sys.argv = [sys.argv[1]] + sys.argv[2:]
-runpy.run_path(sys.argv[0], run_name="__main__")
-""" % (KEPT_FAULTS,)
 
 
-def _env():
-    return {k: v for k, v in os.environ.items()
-            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+@pytest.fixture(scope="module")
+def manifest_path(tmp_path_factory) -> str:
+    return rehearsal.manifest(tmp_path_factory, CELL, "glm47flash-tiny",
+                              "tiny-train-glm47flash")
 
 
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path):
     """The traced run: the loop, the comparison that decides `correct` (the
     step's summed loss against the reference's), the trace's reduction and
     every reader the cell is listed under."""
-    proc = subprocess.run(
-        [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed",
-         "2147483900", "--seconds", "2.0", "--trace", "1"],
-        capture_output=True, text=True, timeout=400, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
-    detail, line = lines[-2], lines[-1]
-    assert line["correct"] is True, (line, detail)
-    assert line["attempted"] > 0 and line["failed"] == 0
-    assert line["device"]["platform"] == "cpu"
-    check = detail["checks"]["loss_vs_reference"]
-    assert abs(check["got"] - check["want"]) <= check["tolerance"]
+    detail, _ = rehearsal.run_cell(manifest_path, CELL, 2147483900, 1)
     # the sum L = CE + 0.3 CE' at a vocabulary of 512: about 1.3 ln 512
-    assert 7.5 < check["want"] < 8.7
+    assert 7.5 < detail["checks"]["loss_vs_reference"]["want"] < 8.7
     assert detail["checks"]["compiled_in_window"] == 0
-    declared = {m["name"] for m in _load("BENCHMARK.json")["per_layer"]
-                if CELL in m.get("workloads", [CELL])}
-    assert set(line["metrics"]) <= declared
-    assert {"step_ms_p50", "time_to_first_step_s"} <= set(line["metrics"])
-    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert set(detail["end_to_end"]) == {"train_tokens_per_s"}
 
 
@@ -125,18 +65,9 @@ def test_limit_readings_reads_both_limits_and_a_fault_of_each_kind(
         "block_not_causal", "table_gradient_dropped",
         "scale_of_the_no_rope_width", "rope_on_the_no_rope_columns",
         "routed_scale_left_out"}
-    proc = subprocess.run(
-        [sys.executable, "-c", LIMITS_PY, "chipbench/limit_readings.py",
-         "--rehearsal", manifest_path, "--workload", CELL, "--seeds",
-         "2147483900"],
-        capture_output=True, text=True, timeout=900, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    ranges = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
-                                            *KEPT_FAULTS}
+    _, ranges = rehearsal.limit_readings(manifest_path, CELL, 2147483900,
+                                         family, KEPT_FAULTS)
     worst = ranges["kernel_errors_worst"]
-    assert set(worst) == set(ranges["off_reference"])
-    assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     # The limit is the chip's, set between the kernels' reading and the
     # all-bfloat16 forms' at the published sizes (PERF.md section 4): here
     # the program is the jax.numpy forms in bfloat16 at a toy size, which
@@ -144,7 +75,6 @@ def test_limit_readings_reads_both_limits_and_a_fault_of_each_kind(
     assert worst["program"][1] <= 2 * family.KERNEL_LIMIT
     for name in KEPT_FAULTS:
         assert worst[name][0] > 5 * family.KERNEL_LIMIT, (name, worst[name])
-    import math
     assert ranges["off_reference"]["mtp_loss_weight_1"][0] == pytest.approx(
         0.7 * math.log(512), rel=0.05)
     assert ranges["off_reference"]["program"][1] <= ranges["tolerance"]
@@ -154,24 +84,19 @@ def test_step_counters_read_the_modules_row(manifest_path):
     """chipbench/step_counters.py at tiny size: `expert_rows_held` comes a
     row an expert layer and the module's block last, each read against the
     balanced count."""
-    proc = subprocess.run(
-        [sys.executable, "chipbench/step_counters.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "3", "--steps", "3"],
-        capture_output=True, text=True, timeout=400, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    line = rehearsal.step_counters(manifest_path, CELL, 3, 3)
     # 2 x 64 tokens, 3 of 8 experts a token, 4 held: 192 rows a layer
     assert line["rows_balanced"] == 192
     low, high = line["rows_held_over_balanced"]
     assert 0.5 < low <= high < 1.5
-    with open(os.path.join(ROOT, "chiprun_out",
+    with open(os.path.join(rehearsal.ROOT, "chiprun_out",
                            f"step_counters_{CELL}.json")) as f:
         steps = json.load(f)[0]["per_step"]
     assert all(len(step["expert_rows_held"]) == 2 for step in steps)
 
 
 def test_benchmark_lists_the_cell_under_the_metrics_issue_55_names():
-    m = _load("BENCHMARK.json")
+    m = load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
@@ -205,14 +130,14 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_55_names():
     assert len(m["workloads"]) >= 10 and len(m["configs"]) >= 9
     assert all(len(x["why"]) <= 200 for x in (*m["workloads"], *m["configs"]))
     config = m["configs"][8]
-    on_disk = _load(config["file"])
+    on_disk = load(config["file"])
     assert config["file"] == CONFIG
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "n_routed_experts", "vocab_size"]
     assert on_disk["source"] == config["source"] == (
         "https://huggingface.co/zai-org/GLM-4.7-Flash/blob/main/config.json")
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load(MIX)
+    mix = load(MIX)
     assert (mix["driver"], mix["global_batch"], mix["seq"], mix["mesh_dp"],
             mix["remat"], mix["ring_batches"], mix["report_every"],
             mix["fetch_lag_groups"], mix["median_over_groups"],
@@ -227,7 +152,7 @@ def test_configuration_is_the_catalogs_but_the_three_keys_cut():
     """Every key of the catalog's entry at its value but depth, the experts
     held and the vocabulary; the prediction module kept; the published
     counts stated beside."""
-    on_disk = _load(CONFIG)
+    on_disk = load(CONFIG)
     published = {
         "attention_bias": False, "hidden_act": "silu", "hidden_size": 2048,
         "intermediate_size": 10240, "max_position_embeddings": 202752,
@@ -286,25 +211,10 @@ def test_configuration_is_the_catalogs_but_the_three_keys_cut():
 
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
-    """On a tree from before models/glm4_moe_lite.py (the parent commit,
-    with this benchmark laid over it) looking the cell up fails at once, in
-    run.py's own process, before a cluster or a chip is touched."""
-    import shutil
-    tree = tmp_path / "tree"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), tree / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
-    shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
-                    ignore=shutil.ignore_patterns(
-                        "__pycache__", "glm4_moe_lite.py", "*.so"))
-    init = tree / "ray_tpu" / "models" / "__init__.py"
-    init.write_text(init.read_text().split("from .glm4_moe_lite import")[0])
-    proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "1", "--seconds", "1", "--trace", "0"], cwd=tree,
-        capture_output=True, text=True, timeout=60,
-        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
-    assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
+    """On a tree from before models/glm4_moe_lite.py (the parent commit, with
+    this benchmark laid over it) looking the cell up fails at once."""
+    proc = rehearsal.lookup_in_tree_without(
+        tmp_path, CELL, ("glm4_moe_lite.py",), "from .glm4_moe_lite import")
     assert "cannot run a glm4_moe_lite configuration" in proc.stderr
 
 
@@ -320,34 +230,19 @@ def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     ms at 197 TFLOP/s; attention operations 6 calls x 2 x 16384^2 x 20 x 3
     x 512 / 2 = 4.947e13 -> 251.1 ms (bytes 6 x 6 x 16384 x 20 x 512 x 2 =
     1.208e10 -> 14.7 ms, the smaller)."""
-    code = r"""
-import json, sys
-sys.path.insert(0, %r)
-from chipbench import harness
-config = json.load(open(%r))
-from chipbench.families import glm4_moe_lite
-record = {
-    "config": config,
-    "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
-                 "tokens_per_s": 11000.0,
-                 "train_flops_per_token":
-                     glm4_moe_lite.train_flops_per_token(config, 16384),
-                 "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
-    "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:jvp_grouped_matmul_fwd_": 0.1,
-        "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.05,
-        "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.05,
-        "mosaic:flash_attention_fwd": 0.6,
-        "mosaic:flash_attention_dq": 0.6,
-        "mosaic:flash_attention_dkv": 1.2}}}
-out = {n: harness.reader(n).read(record) for n in %r}
-assert "jax" not in sys.modules, "a reader imported jax"
-print(json.dumps(out))
-""" % (ROOT, CONFIG, READERS)
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    got = json.loads(proc.stdout)
+    got = rehearsal.read_without_jax(READERS, {
+        "config": load(CONFIG),
+        "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
+                     "tokens_per_s": 11000.0,
+                     "peaks": {"bf16_flops": 197e12,
+                               "hbm_bytes_per_s": 819e9}},
+        "trace": {"steps": 4, "mosaic_by_name": {
+            "mosaic:jvp_grouped_matmul_fwd_": 0.1,
+            "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.05,
+            "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.05,
+            "mosaic:flash_attention_fwd": 0.6,
+            "mosaic:flash_attention_dq": 0.6,
+            "mosaic:flash_attention_dkv": 1.2}}}, family="glm4_moe_lite")
     assert got["expert_gmm_ms_per_step"] == pytest.approx(50.0)
     flops = 5 * 9 * 2 * 16384 * 2048 * 1536
     assert got["expert_gmm_roofline"] == pytest.approx(
@@ -359,7 +254,7 @@ print(json.dumps(out))
     assert got["attn_scoped_roofline"] == pytest.approx(41.9, abs=0.05)
     from chipbench.families import glm4_moe_lite
     assert got["mfu"] == pytest.approx(
-        100 * glm4_moe_lite.train_flops_per_token(_load(CONFIG), 16384)
+        100 * glm4_moe_lite.train_flops_per_token(load(CONFIG), 16384)
         * 11000.0 / 197e12)
     assert got["mfu"] == pytest.approx(32.1, abs=0.1)
     assert all(0 < got[name] <= 100 for name in READERS[1:])

@@ -2,94 +2,31 @@
 through the benchmark's own command line (`chipbench/run.py --rehearsal`),
 and its two new per-layer readers on hand-made records.
 
-The manifest is BENCHMARK.json as it is with the cell's configuration and
-traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/granite-tiny.json,
-.../traffic/tiny-train-granite.json: two Mamba-2 layers and one attention
-layer, chunks of 8, one sequence of 128). chipbench's own rehearsal
-(chipbench/tests, not part of tier-1) looks every configuration up in
-rehearsal/data/tiny.json and asserts `reduced == []`; both are files the
-benchmark already has, which PR 29 may not edit (PERF.md §7), so the new
-cell is rehearsed from here, as tests/test_olmoe_cell_rehearsal.py does
-for its cell. The numbers of a CPU run mean nothing and are written
-nowhere."""
+The tiny stand-ins are
+chipbench/tests/rehearsal/data/configs/granite-tiny.json and
+.../traffic/tiny-train-granite.json (two Mamba-2 layers and one attention
+layer, chunks of 8, one sequence of 128); tests/cell_rehearsal.py has the
+manifest, the runs and why the cell is rehearsed from here."""
 
-import json
 import os
-import subprocess
-import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import cell_rehearsal as rehearsal
+from cell_rehearsal import load
+
 CELL = "granite4h-train-1chip"
-TINY = "chipbench/tests/rehearsal/data"
-
-
-def _load(rel):
-    with open(os.path.join(ROOT, rel)) as f:
-        return json.load(f)
 
 
 @pytest.fixture(scope="module")
 def manifest_path(tmp_path_factory) -> str:
-    m = _load("BENCHMARK.json")
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/granite-tiny.json"
-    cell["traffic"] = "tiny-train-granite"
-    m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("granite_rehearsal") / "BENCHMARK.json"
-    path.write_text(json.dumps(m))
-    return str(path)
-
-
-# run.py ends by requiring that no /dev/shm/ray_tpu_session_* appeared
-# during its run and stayed. That looks at the whole machine, and tier-1
-# runs several test files, each with clusters of its own, at once: their
-# sessions are not this run's leftovers. So the rehearsal runs run.py as
-# __main__ with that one glob answering nothing, and everything else as it
-# is (the chip run keeps the check: there run.py is alone on its machine).
-RUN_PY = r"""
-import glob, runpy, sys
-_glob = glob.glob
-glob.glob = lambda p, *a, **k: [] if str(p).startswith(
-    "/dev/shm/ray_tpu_session_") else _glob(p, *a, **k)
-sys.argv = ["chipbench/run.py"] + sys.argv[1:]
-runpy.run_path("chipbench/run.py", run_name="__main__")
-"""
+    return rehearsal.manifest(tmp_path_factory, CELL, "granite-tiny",
+                              "tiny-train-granite")
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path, trace):
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    proc = subprocess.run(
-        [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed", "3",
-         "--seconds", "2.0", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
-    detail, line = lines[-2], lines[-1]
-    assert line["correct"] is True, (line, detail)
-    assert line["attempted"] > 0 and line["failed"] == 0
-    assert line["device"]["platform"] == "cpu"
-    check = detail["checks"]["loss_vs_reference"]
-    assert abs(check["got"] - check["want"]) <= check["tolerance"]
-    declared = {m["name"] for m in _load("BENCHMARK.json")[
-        "per_layer" if trace else "end_to_end"]
-        if CELL in m.get("workloads", [CELL])}
-    assert set(line["metrics"]) <= declared
-    if trace:
-        # The CPU has no Mosaic rows, so the kernel metrics are left out;
-        # what the host clock gives is there.
-        assert {"step_ms_p50", "time_to_first_step_s"} <= set(
-            line["metrics"])
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    rehearsal.run_cell(manifest_path, CELL, 3, trace)
 
 
 def test_limit_readings_reads_both_limits_and_every_planted_fault(
@@ -104,20 +41,9 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
     precision that the kernels' bfloat16 products already hide."""
     from chipbench.families import granite_hybrid as family
 
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    proc = subprocess.run(
-        [sys.executable, "chipbench/limit_readings.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "3,2147483900"],
-        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    ranges = json.loads(proc.stdout.strip().splitlines()[-1])
-    faults = {*family.STRUCTURAL_FAULTS, *family.PRECISION_FAULTS}
-    assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
-                                            *faults}
+    _, ranges = rehearsal.limit_readings(manifest_path, CELL,
+                                         "3,2147483900", family)
     worst = ranges["kernel_errors_worst"]
-    assert set(worst) == set(ranges["off_reference"])
-    assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     assert worst["program"][1] <= family.KERNEL_LIMIT
     for name in ("all_bfloat16", *family.STRUCTURAL_FAULTS):
         assert worst[name][0] > family.KERNEL_LIMIT, (name, worst[name])
@@ -131,14 +57,10 @@ def test_scope_profile_builds_the_step_and_refuses_without_a_chip(
     since the CPU's trace holds no device plane, says so, exits 3 and
     writes nothing: no table of a step's device time comes from a run
     that had no device."""
-    written = os.path.join(ROOT, "chiprun_out", f"scope_profile_{CELL}.json")
+    written = os.path.join(rehearsal.ROOT, "chiprun_out",
+                           f"scope_profile_{CELL}.json")
     before = os.stat(written).st_mtime_ns if os.path.exists(written) else None
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    proc = subprocess.run(
-        [sys.executable, "chipbench/scope_profile.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seed", "3", "--steps", "1"],
-        capture_output=True, text=True, timeout=400, env=env, cwd=ROOT)
+    proc = rehearsal.scope_profile(manifest_path, CELL, 3, 1)
     assert proc.returncode == 3, proc.stderr[-3000:]
     said = proc.stderr.strip().splitlines()[-1]
     assert said.startswith(f"scope_profile: {CELL}: 1 step(s) ran on cpu "
@@ -150,7 +72,7 @@ def test_scope_profile_builds_the_step_and_refuses_without_a_chip(
 
 
 def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
-    m = _load("BENCHMARK.json")
+    m = load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
     # PR 33's split of set-up lists every cell (tests/test_run_timeline.py)
@@ -171,12 +93,12 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
         "granite-4.0-h-micro", "pretrain-granite4h-b1-s16384", 1)
     assert m["workloads"][3] is cell and len(m["workloads"]) >= 4
     config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    on_disk = _load(config["file"])
+    on_disk = load(config["file"])
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "layer_types"]
     assert on_disk["source"] == config["source"]
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load("chipbench/traffic/pretrain-granite4h-b1-s16384.json")
+    mix = load("chipbench/traffic/pretrain-granite4h-b1-s16384.json")
     assert (mix["global_batch"], mix["seq"], mix["remat"],
             mix["report_every"]) == (1, 16384, True, 2)
 
@@ -184,7 +106,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_29_names():
 def test_configuration_is_one_whole_period_at_published_widths():
     """Every number of the catalog's entry at its value but the depth:
     the first ten of the forty published layer types, in order."""
-    on_disk = _load("chipbench/configs/granite-4.0-h-micro.json")
+    on_disk = load("chipbench/configs/granite-4.0-h-micro.json")
     assert on_disk["layer_types"] == ["mamba"] * 5 + ["attention"] + [
         "mamba"] * 4
     assert on_disk["num_hidden_layers"] == len(on_disk["layer_types"]) == 10
@@ -207,22 +129,9 @@ def test_configuration_is_one_whole_period_at_published_widths():
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
     """On a tree from before models/hybrid.py (the parent commit, with
-    this benchmark laid over it) looking the cell up fails at once, in
-    run.py's own process, before a cluster or a chip is touched."""
-    import shutil
-    tree = tmp_path / "tree"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), tree / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
-    shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
-                    ignore=shutil.ignore_patterns(
-                        "__pycache__", "hybrid.py", "*.so"))
-    proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "1", "--seconds", "1", "--trace", "0"], cwd=tree,
-        capture_output=True, text=True, timeout=60,
-        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
-    assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
+    this benchmark laid over it) looking the cell up fails at once."""
+    proc = rehearsal.lookup_in_tree_without(
+        tmp_path, CELL, ("hybrid.py",))
     assert "cannot run a granite-hybrid configuration" in proc.stderr
     assert proc.stdout.strip() == ""
 
@@ -249,28 +158,17 @@ def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     4096 x 128) = 1.4061e12 -> 7.14 ms at 197 TFLOP/s; bytes 9 x (16384 x
     (17152 + 26112) + 2 x 64 x 4096 x 128 x 4) = 8.7952e9 -> 10.74 ms at 819
     GB/s, the larger: 10.74 / 35 ms = 30.68%."""
-    code = r"""
-import json, sys
-sys.path.insert(0, %r)
-from chipbench import harness
-record = {
-    "config": json.load(open("chipbench/configs/granite-4.0-h-micro.json")),
-    "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
-                 "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
-    "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:ssm_scan_fwd": 0.04,
-        "mosaic:transpose_jvp_ssm_scan_bwd__": 0.1,
-        "mosaic:flash_attention_fwd": 0.06,
-        "mosaic:flash_attention_dq": 0.07,
-        "mosaic:flash_attention_dkv": 0.12}}}
-out = {n: harness.reader(n).read(record) for n in %r}
-assert "jax" not in sys.modules, "a reader imported jax"
-print(json.dumps(out))
-""" % (ROOT, READERS + ("attn_scoped_roofline",))
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    got = json.loads(proc.stdout)
+    got = rehearsal.read_without_jax(READERS + ("attn_scoped_roofline",), {
+        "config": load("chipbench/configs/granite-4.0-h-micro.json"),
+        "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
+                     "peaks": {"bf16_flops": 197e12,
+                               "hbm_bytes_per_s": 819e9}},
+        "trace": {"steps": 4, "mosaic_by_name": {
+            "mosaic:ssm_scan_fwd": 0.04,
+            "mosaic:transpose_jvp_ssm_scan_bwd__": 0.1,
+            "mosaic:flash_attention_fwd": 0.06,
+            "mosaic:flash_attention_dq": 0.07,
+            "mosaic:flash_attention_dkv": 0.12}}})
     assert got["ssm_scan_ms_per_step"] == pytest.approx(35.0)
     nbytes = 9 * (16384 * (17152 + 26112) + 2 * 64 * 4096 * 128 * 4)
     flops = 9 * 3 * 16384 * (256 * 128 + 256 * 4096 + 4 * 4096 * 128)

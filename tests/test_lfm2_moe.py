@@ -476,6 +476,43 @@ def test_loss_and_every_gradient_are_the_references(form, tiny,
             _close(g, w)
 
 
+def _jitted_loss(cfg, params, batch) -> float:
+    # a function of its own: jit keeps what it traced for the loss
+    return float(jax.jit(lambda p, b: lfm2_moe_loss(p, b, cfg))(params, batch))
+
+
+@pytest.fixture(scope="module")
+def loss_off_reference(tiny, reference_of_tiny):
+    """How far the program's own loss lies from the reference's (at this
+    size, in float32: a rounding or none)."""
+    off = abs(_jitted_loss(*tiny) - float(reference_of_tiny[0]))
+    assert off <= 1e-5
+    return off
+
+
+@pytest.mark.parametrize("fault", list(reference.STRUCTURAL_FAULTS))
+def test_a_planted_fault_moves_the_loss_and_its_layer(
+        tiny, reference_of_tiny, loss_off_reference, fault):
+    """Each fault chipbench/limit_readings.py plants, here in-process (the
+    cell's rehearsal plants two of the eight, tests/
+    test_lfm2moe_cell_rehearsal.py): the model's loss leaves the
+    reference's by ten times what the program's own is off at least (the
+    bias added to the weights moves it least, 3.7e-4), the layers' own
+    errors leave KERNEL_LIMIT far behind in some value, and `planted` puts
+    the real layers back."""
+    before = (decoder.gated_short_conv, decoder.head_rms_norm,
+              decoder.held_moe_layer)
+    with reference.planted(fault):
+        wrong = _jitted_loss(*tiny)
+        errors = reference.kernel_errors(tiny[0], seed=3, long=64)
+    assert (decoder.gated_short_conv, decoder.head_rms_norm,
+            decoder.held_moe_layer) == before
+    off = abs(wrong - float(reference_of_tiny[0]))
+    assert off > max(1e-4, 10 * loss_off_reference), (fault, wrong)
+    assert np.isfinite(list(errors.values())).all()
+    assert max(errors.values()) > 20 * reference.KERNEL_LIMIT, errors
+
+
 def test_bfloat16_program_is_near_the_float32_reference():
     """The model's own dtype: bfloat16 weights and activations against the
     float32 reference on the same weights, loss and every gradient. Every

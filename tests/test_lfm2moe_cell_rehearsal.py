@@ -4,97 +4,47 @@ the tools its limits and counters are read with, what BENCHMARK.json and
 the configuration's file say of it, and its readers on a hand-made record
 at its real sizes.
 
-The manifest is BENCHMARK.json as it is with the cell's configuration and
-traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/lfm2moe-tiny.json,
-.../traffic/tiny-train-lfm2moe.json: one dense layer then three expert
+The tiny stand-ins are
+chipbench/tests/rehearsal/data/configs/lfm2moe-tiny.json and
+.../traffic/tiny-train-lfm2moe.json (one dense layer then three expert
 layers, conv | full_attention conv conv, experts 2 to 5 of 8 held, two
-sequences of 64), as tests/test_nemotron_cell_rehearsal.py does for its
-cell and for its reason. The numbers of a CPU run mean nothing and are
-written nowhere."""
-
-import json
-import os
-import subprocess
-import sys
+sequences of 64); tests/cell_rehearsal.py has the manifest, the runs and
+why the cell is rehearsed from here."""
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import cell_rehearsal as rehearsal
+from cell_rehearsal import load
+
 CELL = "lfm2moe-train-1chip"
-TINY = "chipbench/tests/rehearsal/data"
 CONFIG = "chipbench/configs/lfm2-8b-a1b.json"
-
-
-def _load(rel):
-    with open(os.path.join(ROOT, rel)) as f:
-        return json.load(f)
+# chipbench/limit_readings.py with two of the family's eight faults to
+# plant, one of the convolution's and one of the held experts': the pass
+# reads each fault's loss and kernel errors in a program of its own. All
+# eight are planted in-process, on the model's loss and on the layers
+# (tests/test_lfm2_moe.py::
+# test_a_planted_fault_moves_the_loss_and_its_layer[*]).
+KEPT_FAULTS = ("rows_read_across_sequences", "silu_on_the_wrong_half")
 
 
 @pytest.fixture(scope="module")
 def manifest_path(tmp_path_factory) -> str:
-    m = _load("BENCHMARK.json")
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/lfm2moe-tiny.json"
-    cell["traffic"] = "tiny-train-lfm2moe"
-    m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("lfm2moe_rehearsal") / "BENCHMARK.json"
-    path.write_text(json.dumps(m))
-    return str(path)
-
-
-# tests/test_olmoe_cell_rehearsal.py has why run.py's one glob over
-# /dev/shm answers nothing here.
-RUN_PY = r"""
-import glob, runpy, sys
-_glob = glob.glob
-glob.glob = lambda p, *a, **k: [] if str(p).startswith(
-    "/dev/shm/ray_tpu_session_") else _glob(p, *a, **k)
-sys.argv = ["chipbench/run.py"] + sys.argv[1:]
-runpy.run_path("chipbench/run.py", run_name="__main__")
-"""
-
-
-def _env():
-    return {k: v for k, v in os.environ.items()
-            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    return rehearsal.manifest(tmp_path_factory, CELL, "lfm2moe-tiny",
+                              "tiny-train-lfm2moe")
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path, trace):
-    proc = subprocess.run(
-        [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed",
-         "2147483900", "--seconds", "2.0", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=400, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
-    detail, line = lines[-2], lines[-1]
-    assert line["correct"] is True, (line, detail)
-    assert line["attempted"] > 0 and line["failed"] == 0
-    assert line["device"]["platform"] == "cpu"
-    check = detail["checks"]["loss_vs_reference"]
-    assert abs(check["got"] - check["want"]) <= check["tolerance"]
-    declared = {m["name"] for m in _load("BENCHMARK.json")[
-        "per_layer" if trace else "end_to_end"]
-        if CELL in m.get("workloads", [CELL])}
-    assert set(line["metrics"]) <= declared
-    if trace:
-        assert {"step_ms_p50", "time_to_first_step_s"} <= set(
-            line["metrics"])
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    rehearsal.run_cell(manifest_path, CELL, 2147483900, trace)
 
 
-def test_limit_readings_reads_both_limits_and_every_planted_fault(
+def test_limit_readings_reads_both_limits_and_a_fault_of_each_kind(
         manifest_path):
     """chipbench/limit_readings.py end to end at tiny size: a loss for the
-    program, the reference, the all-bfloat16 reference and each planted
-    fault, and the new layers' own errors for the same; KERNEL_LIMIT lies
-    between the program and every planted fault."""
+    program, the reference, the all-bfloat16 reference and a planted fault
+    of the convolution and of the held experts (KEPT_FAULTS), and the new
+    layers' own errors for the same; KERNEL_LIMIT lies between the program
+    and every planted fault."""
     from chipbench.families import lfm2_moe as family
 
     assert set(family.STRUCTURAL_FAULTS) == {
@@ -102,17 +52,9 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
         "b_gate_dropped", "c_gate_dropped", "silu_on_the_wrong_half",
         "absent_rows_computed", "bias_added_to_the_weights",
         "norm_over_all_columns"}
-    proc = subprocess.run(
-        [sys.executable, "chipbench/limit_readings.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "2147483900"],
-        capture_output=True, text=True, timeout=900, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    ranges = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
-                                            *family.STRUCTURAL_FAULTS}
+    _, ranges = rehearsal.limit_readings(manifest_path, CELL, 2147483900,
+                                         family, KEPT_FAULTS)
     worst = ranges["kernel_errors_worst"]
-    assert set(worst) == set(ranges["off_reference"])
-    assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     # The limit is the chip's, set between the kernels' reading and the
     # all-bfloat16 forms' at the published sizes (PERF.md section 4): here
     # the program is the jax.numpy forms in bfloat16 at a toy size, which
@@ -120,7 +62,7 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
     # rows), under the all-bfloat16 forms and far under every fault.
     assert worst["program"][1] <= 1.5 * family.KERNEL_LIMIT
     assert worst["all_bfloat16"][0] > worst["program"][1]
-    for name in family.STRUCTURAL_FAULTS:
+    for name in KEPT_FAULTS:
         assert worst[name][0] > 20 * family.KERNEL_LIMIT, (name, worst[name])
 
 
@@ -128,13 +70,7 @@ def test_step_counters_reads_the_held_rows_of_every_step(manifest_path):
     """chipbench/step_counters.py end to end at tiny size: the step at the
     default optimizer, its counters fetched a step; the held experts' rows
     stay near the balanced count the operations are reckoned for."""
-    proc = subprocess.run(
-        [sys.executable, "chipbench/step_counters.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "2147483900",
-         "--steps", "6"],
-        capture_output=True, text=True, timeout=900, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    run = json.loads(proc.stdout.strip().splitlines()[-1])
+    run = rehearsal.step_counters(manifest_path, CELL, 2147483900, 6)
     assert run["steps"] == 6 and run["rows_balanced"] == 2 * 64 * 3 * 4 / 8
     low, high = run["rows_held_over_balanced"]
     assert 0.8 < low <= high < 1.2, run
@@ -142,7 +78,7 @@ def test_step_counters_reads_the_held_rows_of_every_step(manifest_path):
 
 
 def test_benchmark_lists_the_cell_under_the_metrics_issue_48_names():
-    m = _load("BENCHMARK.json")
+    m = load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
@@ -173,14 +109,14 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_48_names():
     assert len(m["workloads"]) >= 8 and len(m["configs"]) >= 7
     assert all(len(x["why"]) <= 200 for x in (*m["workloads"], *m["configs"]))
     config = m["configs"][6]
-    on_disk = _load(config["file"])
+    on_disk = load(config["file"])
     assert config["file"] == CONFIG
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "layer_types", "num_dense_layers",
         "num_experts", "vocab_size"]
     assert on_disk["source"] == config["source"]
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load("chipbench/traffic/pretrain-lfm2moe-s8192.json")
+    mix = load("chipbench/traffic/pretrain-lfm2moe-s8192.json")
     assert (mix["global_batch"], mix["seq"], mix["remat"],
             mix["ring_batches"], mix["report_every"],
             mix["fetch_lag_groups"], mix["median_over_groups"],
@@ -193,7 +129,7 @@ def test_configuration_is_the_catalogs_but_the_five_keys_cut():
     """Every key of the catalog's entry at its value but depth, the layer
     types, the dense layers, the experts held and the vocabulary; the
     published counts stated beside."""
-    on_disk = _load(CONFIG)
+    on_disk = load(CONFIG)
     published = {
         "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
         "intermediate_size": 7168, "max_position_embeddings": 128000,
@@ -233,23 +169,10 @@ def test_configuration_is_the_catalogs_but_the_five_keys_cut():
 
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
-    """On a tree from before models/lfm2_moe.py (the parent commit, with
-    this benchmark laid over it) looking the cell up fails at once, in
-    run.py's own process, before a cluster or a chip is touched."""
-    import shutil
-    tree = tmp_path / "tree"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), tree / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
-    shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
-                    ignore=shutil.ignore_patterns(
-                        "__pycache__", "lfm2_moe.py", "*.so"))
-    proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "1", "--seconds", "1", "--trace", "0"], cwd=tree,
-        capture_output=True, text=True, timeout=60,
-        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
-    assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
+    """On a tree from before models/lfm2_moe.py (the parent commit, with this
+    benchmark laid over it) looking the cell up fails at once."""
+    proc = rehearsal.lookup_in_tree_without(
+        tmp_path, CELL, ("lfm2_moe.py",))
     assert "cannot run a lfm2-moe configuration" in proc.stderr
 
 
@@ -269,36 +192,21 @@ def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     (operations 4 x 32768 x 2048 x 30 = 8.05e9 -> 0.04 ms, the smaller);
     attention operations 6 x 2 x 4 x 8192^2 x 2048 / 2 = 3.2985e12 ->
     16.74 ms."""
-    code = r"""
-import json, sys
-sys.path.insert(0, %r)
-from chipbench import harness
-config = json.load(open(%r))
-from chipbench.families import lfm2_moe
-record = {
-    "config": config,
-    "counters": {"global_batch": 4, "seq": 8192, "chips": 1,
-                 "tokens_per_s": 55000.0,
-                 "train_flops_per_token":
-                     lfm2_moe.train_flops_per_token(config, 8192),
-                 "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
-    "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:jvp_grouped_matmul_fwd_": 0.2,
-        "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.1,
-        "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.1,
-        "mosaic:jvp_short_conv_fwd_": 0.03,
-        "mosaic:transpose_jvp_short_conv_bwd__": 0.05,
-        "mosaic:flash_attention_fwd": 0.05,
-        "mosaic:flash_attention_dq": 0.05,
-        "mosaic:flash_attention_dkv": 0.1}}}
-out = {n: harness.reader(n).read(record) for n in %r}
-assert "jax" not in sys.modules, "a reader imported jax"
-print(json.dumps(out))
-""" % (ROOT, CONFIG, READERS)
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    got = json.loads(proc.stdout)
+    got = rehearsal.read_without_jax(READERS, {
+        "config": load(CONFIG),
+        "counters": {"global_batch": 4, "seq": 8192, "chips": 1,
+                     "tokens_per_s": 55000.0,
+                     "peaks": {"bf16_flops": 197e12,
+                               "hbm_bytes_per_s": 819e9}},
+        "trace": {"steps": 4, "mosaic_by_name": {
+            "mosaic:jvp_grouped_matmul_fwd_": 0.2,
+            "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.1,
+            "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.1,
+            "mosaic:jvp_short_conv_fwd_": 0.03,
+            "mosaic:transpose_jvp_short_conv_bwd__": 0.05,
+            "mosaic:flash_attention_fwd": 0.05,
+            "mosaic:flash_attention_dq": 0.05,
+            "mosaic:flash_attention_dkv": 0.1}}}, family="lfm2_moe")
     assert got["expert_gmm_ms_per_step"] == pytest.approx(100.0)
     flops = 4 * 9 * 2 * 65536 * 2048 * 1792
     assert got["expert_gmm_roofline"] == pytest.approx(
@@ -314,7 +222,7 @@ print(json.dumps(out))
         100 * (attn / 197e12) / 0.05)
     from chipbench.families import lfm2_moe
     assert got["mfu"] == pytest.approx(
-        100 * lfm2_moe.train_flops_per_token(_load(CONFIG), 8192)
+        100 * lfm2_moe.train_flops_per_token(load(CONFIG), 8192)
         * 55000.0 / 197e12)
 
 
@@ -322,7 +230,7 @@ def test_a_record_without_the_convolutions_rows_leaves_the_metrics_out():
     """What the parent gives for a metric new in this PR: a trace with no
     `short_conv` row reads as nothing, and nothing is raised."""
     from chipbench import harness
-    record = {"config": _load(CONFIG),
+    record = {"config": load(CONFIG),
               "counters": {"global_batch": 4, "seq": 8192, "chips": 1,
                            "peaks": {"bf16_flops": 197e12,
                                      "hbm_bytes_per_s": 819e9}},

@@ -4,110 +4,57 @@ the tool its limits are read with, what BENCHMARK.json and the
 configuration's file say of it, and the readers it shares with the OLMoE
 and granite cells on a hand-made record at its real sizes.
 
-The manifest is BENCHMARK.json as it is with the cell's configuration and
-traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/nemotron-tiny.json,
-.../traffic/tiny-train-nemotron.json: MEM*E, two groups, experts 2 to 5 of
-8 held, chunks of 8, one sequence of 128), as tests/
-test_olmoe_cell_rehearsal.py does for its cell and for its reason. The
-numbers of a CPU run mean nothing and are written nowhere."""
-
-import json
-import os
-import subprocess
-import sys
+The tiny stand-ins are
+chipbench/tests/rehearsal/data/configs/nemotron-tiny.json and
+.../traffic/tiny-train-nemotron.json (MEM*E, two groups, experts 2 to 5 of
+8 held, chunks of 8, one sequence of 128); tests/cell_rehearsal.py has the
+manifest, the runs and why the cell is rehearsed from here."""
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import cell_rehearsal as rehearsal
+from cell_rehearsal import load
+
 CELL = "nemotron3nano-train-1chip"
-TINY = "chipbench/tests/rehearsal/data"
-
-
-def _load(rel):
-    with open(os.path.join(ROOT, rel)) as f:
-        return json.load(f)
+CONFIG = "chipbench/configs/nemotron-3-nano-30b-a3b.json"
+# chipbench/limit_readings.py with two of the family's six faults to plant,
+# one of the grouped scan's and one of the held experts': the pass reads
+# each fault's loss and kernel errors in a program of its own. All six are
+# planted in-process, on the model's loss and on the layers
+# (tests/test_nemotron_h.py::test_a_planted_fault_moves_the_models_loss[*],
+# ::test_the_cell_holds_the_new_layers_to_a_limit_of_their_own[interpreted-*]).
+KEPT_FAULTS = ("group_read_by_wrong_heads", "shared_expert_dropped")
 
 
 @pytest.fixture(scope="module")
 def manifest_path(tmp_path_factory) -> str:
-    m = _load("BENCHMARK.json")
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/nemotron-tiny.json"
-    cell["traffic"] = "tiny-train-nemotron"
-    m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("nemotron_rehearsal") / "BENCHMARK.json"
-    path.write_text(json.dumps(m))
-    return str(path)
-
-
-# tests/test_olmoe_cell_rehearsal.py has why run.py's one glob over
-# /dev/shm answers nothing here.
-RUN_PY = r"""
-import glob, runpy, sys
-_glob = glob.glob
-glob.glob = lambda p, *a, **k: [] if str(p).startswith(
-    "/dev/shm/ray_tpu_session_") else _glob(p, *a, **k)
-sys.argv = ["chipbench/run.py"] + sys.argv[1:]
-runpy.run_path("chipbench/run.py", run_name="__main__")
-"""
-
-
-def _env():
-    return {k: v for k, v in os.environ.items()
-            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    return rehearsal.manifest(tmp_path_factory, CELL, "nemotron-tiny",
+                              "tiny-train-nemotron")
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path, trace):
-    proc = subprocess.run(
-        [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed",
-         "2147483900", "--seconds", "2.0", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=400, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
-    detail, line = lines[-2], lines[-1]
-    assert line["correct"] is True, (line, detail)
-    assert line["attempted"] > 0 and line["failed"] == 0
-    assert line["device"]["platform"] == "cpu"
-    check = detail["checks"]["loss_vs_reference"]
-    assert abs(check["got"] - check["want"]) <= check["tolerance"]
-    declared = {m["name"] for m in _load("BENCHMARK.json")[
-        "per_layer" if trace else "end_to_end"]
-        if CELL in m.get("workloads", [CELL])}
-    assert set(line["metrics"]) <= declared
-    if trace:
-        assert {"step_ms_p50", "time_to_first_step_s"} <= set(
-            line["metrics"])
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    rehearsal.run_cell(manifest_path, CELL, 2147483900, trace)
 
 
-def test_limit_readings_reads_both_limits_and_every_planted_fault(
+def test_limit_readings_reads_both_limits_and_a_fault_of_each_kind(
         manifest_path):
     """chipbench/limit_readings.py end to end at tiny size: a loss for the
-    program, the reference, the all-bfloat16 reference and each planted
-    fault, and the new layers' own errors for the same; KERNEL_LIMIT lies
-    between the program and every planted fault."""
+    program, the reference, the all-bfloat16 reference and a planted fault
+    of the grouped scan and of the held experts (KEPT_FAULTS), and the new
+    layers' own errors for the same; KERNEL_LIMIT lies between the program
+    and every planted fault."""
     from chipbench.families import nemotron_h as family
 
-    proc = subprocess.run(
-        [sys.executable, "chipbench/limit_readings.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "2147483900"],
-        capture_output=True, text=True, timeout=900, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    ranges = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
-                                            *family.STRUCTURAL_FAULTS}
+    assert set(family.STRUCTURAL_FAULTS) == {
+        "group_read_by_wrong_heads", "norm_over_all_channels",
+        "bias_added_to_the_weights", "shared_expert_dropped",
+        "absent_rows_computed", "routed_scale_dropped"}
+    _, ranges = rehearsal.limit_readings(manifest_path, CELL, 2147483900,
+                                         family, KEPT_FAULTS)
     worst = ranges["kernel_errors_worst"]
-    assert set(worst) == set(ranges["off_reference"])
-    assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     assert worst["program"][1] <= family.KERNEL_LIMIT
-    for name in family.STRUCTURAL_FAULTS:
+    for name in KEPT_FAULTS:
         assert worst[name][0] > family.KERNEL_LIMIT, (name, worst[name])
     # at this size the all-bfloat16 forms lie above the program and about
     # the limit; the chip's reading at the published sizes is PERF.md's
@@ -118,13 +65,7 @@ def test_step_counters_reads_the_held_rows_of_every_step(manifest_path):
     """chipbench/step_counters.py end to end at tiny size: the step at the
     default optimizer, its counters fetched a step; the held experts' rows
     stay near the balanced count the operations are reckoned for."""
-    proc = subprocess.run(
-        [sys.executable, "chipbench/step_counters.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "2147483900",
-         "--steps", "6"],
-        capture_output=True, text=True, timeout=900, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    run = json.loads(proc.stdout.strip().splitlines()[-1])
+    run = rehearsal.step_counters(manifest_path, CELL, 2147483900, 6)
     assert run["steps"] == 6 and run["rows_balanced"] > 0
     low, high = run["rows_held_over_balanced"]
     assert 0.8 < low <= high < 1.2, run
@@ -132,7 +73,7 @@ def test_step_counters_reads_the_held_rows_of_every_step(manifest_path):
 
 
 def test_benchmark_lists_the_cell_under_the_metrics_issue_45_names():
-    m = _load("BENCHMARK.json")
+    m = load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
     split = {x["name"] for x in m["per_layer"] if x["moves"] == "setup_s"
@@ -156,13 +97,13 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_45_names():
         1)
     assert len(m["workloads"]) >= 7 and len(m["configs"]) >= 6
     config = m["configs"][5]
-    on_disk = _load(config["file"])
+    on_disk = load(config["file"])
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
         "vocab_size"]
     assert on_disk["source"] == config["source"]
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load("chipbench/traffic/pretrain-nemotron3nano-b1-s16384.json")
+    mix = load("chipbench/traffic/pretrain-nemotron3nano-b1-s16384.json")
     assert (mix["global_batch"], mix["seq"], mix["remat"],
             mix["ring_batches"], mix["fetch_lag_groups"],
             mix["median_over_groups"]) == (1, 16384, True, 8, 1, 6)
@@ -171,7 +112,7 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_45_names():
 def test_configuration_is_the_catalogs_but_the_four_keys_cut():
     """Every key of the catalog's entry at its value but depth, pattern,
     experts held and vocabulary; the published counts stated beside."""
-    on_disk = _load("chipbench/configs/nemotron-3-nano-30b-a3b.json")
+    on_disk = load(CONFIG)
     published = {
         "attention_bias": False, "chunk_size": 128, "conv_kernel": 4,
         "expand": 2, "head_dim": 128, "hidden_size": 2688,
@@ -215,23 +156,10 @@ def test_configuration_is_the_catalogs_but_the_four_keys_cut():
 
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
-    """On a tree from before models/nemotron_h.py (the parent commit, with
-    this benchmark laid over it) looking the cell up fails at once, in
-    run.py's own process, before a cluster or a chip is touched."""
-    import shutil
-    tree = tmp_path / "tree"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), tree / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
-    shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
-                    ignore=shutil.ignore_patterns(
-                        "__pycache__", "nemotron_h.py", "*.so"))
-    proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "1", "--seconds", "1", "--trace", "0"], cwd=tree,
-        capture_output=True, text=True, timeout=60,
-        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
-    assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
+    """On a tree from before models/nemotron_h.py (the parent commit, with this
+    benchmark laid over it) looking the cell up fails at once."""
+    proc = rehearsal.lookup_in_tree_without(
+        tmp_path, CELL, ("nemotron_h.py",))
     assert "cannot run a nemotron-h configuration" in proc.stderr
 
 
@@ -251,35 +179,20 @@ def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     (operations 4 x 3 x 16384 x 2752512 = 5.41e11 -> 2.75 ms, the
     smaller); attention operations 6 x 2 x 16384^2 x 4096 / 2 = 6.597e12
     -> 33.49 ms."""
-    code = r"""
-import json, sys
-sys.path.insert(0, %r)
-from chipbench import harness
-config = json.load(open("chipbench/configs/nemotron-3-nano-30b-a3b.json"))
-from chipbench.families import nemotron_h
-record = {
-    "config": config,
-    "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
-                 "tokens_per_s": 40000.0,
-                 "train_flops_per_token":
-                     nemotron_h.train_flops_per_token(config, 16384),
-                 "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
-    "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:jvp_grouped_matmul_fwd_": 0.02,
-        "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.01,
-        "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.01,
-        "mosaic:ssm_scan_fwd": 0.15, "mosaic:ssm_scan_bwd": 0.25,
-        "mosaic:flash_attention_fwd": 0.1,
-        "mosaic:flash_attention_dq": 0.1,
-        "mosaic:flash_attention_dkv": 0.2}}}
-out = {n: harness.reader(n).read(record) for n in %r}
-assert "jax" not in sys.modules, "a reader imported jax"
-print(json.dumps(out))
-""" % (ROOT, READERS)
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    got = json.loads(proc.stdout)
+    got = rehearsal.read_without_jax(READERS, {
+        "config": load(CONFIG),
+        "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
+                     "tokens_per_s": 40000.0,
+                     "peaks": {"bf16_flops": 197e12,
+                               "hbm_bytes_per_s": 819e9}},
+        "trace": {"steps": 4, "mosaic_by_name": {
+            "mosaic:jvp_grouped_matmul_fwd_": 0.02,
+            "mosaic:transpose_jvp_grouped_matmul_dlhs__": 0.01,
+            "mosaic:transpose_jvp_grouped_matmul_drhs__": 0.01,
+            "mosaic:ssm_scan_fwd": 0.15, "mosaic:ssm_scan_bwd": 0.25,
+            "mosaic:flash_attention_fwd": 0.1,
+            "mosaic:flash_attention_dq": 0.1,
+            "mosaic:flash_attention_dkv": 0.2}}}, family="nemotron_h")
     assert got["expert_gmm_ms_per_step"] == pytest.approx(10.0)
     flops = 4 * 6 * 2 * 12288 * 2688 * 1856
     assert got["expert_gmm_roofline"] == pytest.approx(
@@ -292,11 +205,7 @@ print(json.dumps(out))
     attn = 6 * 2 * 16384 ** 2 * 4096 / 2
     assert got["attn_scoped_roofline"] == pytest.approx(
         100 * (attn / 197e12) / 0.1)
-    assert got["mfu"] == pytest.approx(
-        100 * nemotron_flops() * 40000.0 / 197e12)
-
-
-def nemotron_flops() -> float:
     from chipbench.families import nemotron_h
-    return nemotron_h.train_flops_per_token(
-        _load("chipbench/configs/nemotron-3-nano-30b-a3b.json"), 16384)
+    assert got["mfu"] == pytest.approx(
+        100 * nemotron_h.train_flops_per_token(load(CONFIG), 16384)
+        * 40000.0 / 197e12)

@@ -2,94 +2,30 @@
 through the benchmark's own command line (`chipbench/run.py --rehearsal`),
 and its two new per-layer readers on hand-made records.
 
-The manifest is BENCHMARK.json as it is with the cell's configuration and
-traffic mix swapped for new tiny stand-ins
-(chipbench/tests/rehearsal/data/configs/phi4flash-tiny.json,
-.../traffic/tiny-train-phi4flash.json: eight layers by the model's own
+The tiny stand-ins are
+chipbench/tests/rehearsal/data/configs/phi4flash-tiny.json and
+.../traffic/tiny-train-phi4flash.json (eight layers by the model's own
 rule, 128 Mamba-1 channels x 4 states, a window of 32, one sequence of
-128). chipbench's own rehearsal (chipbench/tests, not part of tier-1)
-looks every configuration up in rehearsal/data/tiny.json, a file the
-benchmark already has, which PR 31 may not edit (PERF.md §7), so the new
-cell is rehearsed from here, as tests/test_granite_cell_rehearsal.py does
-for its cell. The numbers of a CPU run mean nothing and are written
-nowhere."""
-
-import json
-import os
-import subprocess
-import sys
+128); tests/cell_rehearsal.py has the manifest, the runs and why the cell
+is rehearsed from here."""
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import cell_rehearsal as rehearsal
+from cell_rehearsal import load
+
 CELL = "phi4flash-train-1chip"
-TINY = "chipbench/tests/rehearsal/data"
-
-
-def _load(rel):
-    with open(os.path.join(ROOT, rel)) as f:
-        return json.load(f)
 
 
 @pytest.fixture(scope="module")
 def manifest_path(tmp_path_factory) -> str:
-    m = _load("BENCHMARK.json")
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    m["paths"] = [TINY]
-    config["file"] = f"{TINY}/configs/phi4flash-tiny.json"
-    cell["traffic"] = "tiny-train-phi4flash"
-    m["workloads"], m["configs"] = [cell], [config]
-    path = tmp_path_factory.mktemp("phi4flash_rehearsal") / "BENCHMARK.json"
-    path.write_text(json.dumps(m))
-    return str(path)
-
-
-# As tests/test_granite_cell_rehearsal.py: run.py as __main__ with the one
-# machine-wide glob for leftover object-store sessions answering nothing
-# (tier-1 runs several clusters at once), everything else as it is.
-RUN_PY = r"""
-import glob, runpy, sys
-_glob = glob.glob
-glob.glob = lambda p, *a, **k: [] if str(p).startswith(
-    "/dev/shm/ray_tpu_session_") else _glob(p, *a, **k)
-sys.argv = ["chipbench/run.py"] + sys.argv[1:]
-runpy.run_path("chipbench/run.py", run_name="__main__")
-"""
-
-
-def _env():
-    return {k: v for k, v in os.environ.items()
-            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    return rehearsal.manifest(tmp_path_factory, CELL, "phi4flash-tiny",
+                              "tiny-train-phi4flash")
 
 
 @pytest.mark.parametrize("trace", [0, 1])
 def test_cell_runs_end_to_end_on_the_cpu(manifest_path, trace):
-    proc = subprocess.run(
-        [sys.executable, "-c", RUN_PY,
-         "--rehearsal", manifest_path, "--workload", CELL, "--seed",
-         "2147483900", "--seconds", "2.0", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=600, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    lines = [json.loads(x) for x in proc.stdout.strip().splitlines()]
-    detail, line = lines[-2], lines[-1]
-    assert line["correct"] is True, (line, detail)
-    assert line["attempted"] > 0 and line["failed"] == 0
-    assert line["device"]["platform"] == "cpu"
-    check = detail["checks"]["loss_vs_reference"]
-    assert abs(check["got"] - check["want"]) <= check["tolerance"]
-    declared = {m["name"] for m in _load("BENCHMARK.json")[
-        "per_layer" if trace else "end_to_end"]
-        if CELL in m.get("workloads", [CELL])}
-    assert set(line["metrics"]) <= declared
-    if trace:
-        # The CPU has no Mosaic rows, so the kernel metrics are left out;
-        # what the host clock gives is there.
-        assert {"step_ms_p50", "time_to_first_step_s"} <= set(
-            line["metrics"])
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    rehearsal.run_cell(manifest_path, CELL, 2147483900, trace)
 
 
 def test_limit_readings_reads_both_limits_and_every_planted_fault(
@@ -102,27 +38,19 @@ def test_limit_readings_reads_both_limits_and_every_planted_fault(
     among them."""
     from chipbench.families import sambay as family
 
-    proc = subprocess.run(
-        [sys.executable, "chipbench/limit_readings.py", "--rehearsal",
-         manifest_path, "--workload", CELL, "--seeds", "3,2147483900"],
-        capture_output=True, text=True, timeout=600, env=_env(), cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    ranges = json.loads(proc.stdout.strip().splitlines()[-1])
     faults = set(family.STRUCTURAL_FAULTS)
     assert faults == {"window_ignored", "lambda_term_dropped",
                       "chunk_carry_dropped", "gmu_memory_zeroed"}
-    assert set(ranges["off_reference"]) == {"program", "all_bfloat16",
-                                            *faults}
+    _, ranges = rehearsal.limit_readings(manifest_path, CELL,
+                                         "3,2147483900", family)
     worst = ranges["kernel_errors_worst"]
-    assert set(worst) == set(ranges["off_reference"])
-    assert ranges["kernel_limit"] == family.KERNEL_LIMIT
     assert worst["program"][1] <= family.KERNEL_LIMIT
     for name in ("all_bfloat16", *faults):
         assert worst[name][0] > family.KERNEL_LIMIT, (name, worst[name])
 
 
 def test_benchmark_lists_the_cell_under_the_metrics_issue_31_names():
-    m = _load("BENCHMARK.json")
+    m = load("BENCHMARK.json")
     listed = {x["name"] for g in ("end_to_end", "per_layer") for x in m[g]
               if CELL in x.get("workloads", ())}
     # PR 33's split of set-up lists every cell (tests/test_run_timeline.py)
@@ -147,12 +75,12 @@ def test_benchmark_lists_the_cell_under_the_metrics_issue_31_names():
     assert len(cell["why"]) <= 200
     config = next(c for c in m["configs"] if c["name"] == cell["config"])
     assert m["configs"][3] is config and len(config["why"]) <= 200
-    on_disk = _load(config["file"])
+    on_disk = load(config["file"])
     assert on_disk["reduced"] == config["reduced"] == [
         "num_hidden_layers", "vocab_size"]
     assert on_disk["source"] == config["source"]
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    mix = _load("chipbench/traffic/pretrain-phi4flash-b1-s16384.json")
+    mix = load("chipbench/traffic/pretrain-phi4flash-b1-s16384.json")
     assert (mix["global_batch"], mix["seq"], mix["remat"], mix["mesh_dp"],
             mix["ring_batches"], mix["report_every"],
             mix["fetch_lag_groups"], mix["median_over_groups"],
@@ -164,7 +92,7 @@ def test_configuration_is_the_catalog_entry_but_depth_and_vocabulary():
     """Every number of the catalog's entry at its value but the two that
     `reduced` names, and every line of the layer equations that
     config.json does not give under `assumed`."""
-    on_disk = _load("chipbench/configs/phi-4-mini-flash-reasoning.json")
+    on_disk = load("chipbench/configs/phi-4-mini-flash-reasoning.json")
     catalog = {
         "embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560,
         "intermediate_size": 10240, "layer_norm_eps": 1e-05,
@@ -190,24 +118,10 @@ def test_configuration_is_the_catalog_entry_but_depth_and_vocabulary():
 
 
 def test_family_refuses_a_tree_without_the_program(tmp_path):
-    """On a tree from before models/sambay.py (the parent commit, with
-    this benchmark laid over it) looking the cell up fails at once, in
-    run.py's own process, before a cluster or a chip is touched."""
-    import shutil
-    tree = tmp_path / "tree"
-    shutil.copytree(os.path.join(ROOT, "chipbench"), tree / "chipbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
-    shutil.copytree(os.path.join(ROOT, "ray_tpu"), tree / "ray_tpu",
-                    ignore=shutil.ignore_patterns(
-                        "__pycache__", "sambay.py", "selective_scan.py",
-                        "*.so"))
-    proc = subprocess.run(
-        [sys.executable, "chipbench/run.py", "--workload", CELL, "--seed",
-         "1", "--seconds", "1", "--trace", "0"], cwd=tree,
-        capture_output=True, text=True, timeout=60,
-        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
-    assert proc.returncode not in (0, 124, 137), proc.stderr[-2000:]
+    """On a tree from before models/sambay.py (the parent commit, with this
+    benchmark laid over it) looking the cell up fails at once."""
+    proc = rehearsal.lookup_in_tree_without(
+        tmp_path, CELL, ("sambay.py", "selective_scan.py"))
     assert "cannot run a sambay configuration" in proc.stderr
     assert proc.stdout.strip() == ""
 
@@ -249,29 +163,17 @@ def test_readers_give_the_hand_computed_numbers_and_import_no_jax():
     16384 x (5120 x 22 + 6 x 16 x 2) = 5.546e9 -> 6.77 ms at 819 GB/s, the
     larger: 6.77 / 35 ms = 19.35%. The attention kernels: two windowed
     layers over the band and two over the triangle."""
-    code = r"""
-import json, sys
-sys.path.insert(0, %r)
-from chipbench import harness
-record = {
-    "config": json.load(open(
-        "chipbench/configs/phi-4-mini-flash-reasoning.json")),
-    "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
-                 "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}},
-    "trace": {"steps": 4, "mosaic_by_name": {
-        "mosaic:selective_scan_fwd": 0.04,
-        "mosaic:transpose_jvp_selective_scan_bwd__": 0.1,
-        "mosaic:flash_attention_fwd": 0.06,
-        "mosaic:flash_attention_dq": 0.07,
-        "mosaic:flash_attention_dkv": 0.12}}}
-out = {n: harness.reader(n).read(record) for n in %r}
-assert "jax" not in sys.modules, "a reader imported jax"
-print(json.dumps(out))
-""" % (ROOT, READERS + ("attn_scoped_roofline",))
-    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    got = json.loads(proc.stdout)
+    got = rehearsal.read_without_jax(READERS + ("attn_scoped_roofline",), {
+        "config": load("chipbench/configs/phi-4-mini-flash-reasoning.json"),
+        "counters": {"global_batch": 1, "seq": 16384, "chips": 1,
+                     "peaks": {"bf16_flops": 197e12,
+                               "hbm_bytes_per_s": 819e9}},
+        "trace": {"steps": 4, "mosaic_by_name": {
+            "mosaic:selective_scan_fwd": 0.04,
+            "mosaic:transpose_jvp_selective_scan_bwd__": 0.1,
+            "mosaic:flash_attention_fwd": 0.06,
+            "mosaic:flash_attention_dq": 0.07,
+            "mosaic:flash_attention_dkv": 0.12}}})
     assert got["selective_scan_ms_per_step"] == pytest.approx(35.0)
     nbytes = 3 * 16384 * (5120 * 22 + 6 * 16 * 2)
     flops = 3 * 3 * 6 * 16384 * 5120 * 16
