@@ -1109,8 +1109,15 @@ def _kept(kind: str) -> Tuple[str, ...]:
 #                     is all that moves it), which the compile cache of the
 #                     machines the cell runs on no longer holds beside the
 #                     cell's other programs: every run then compiles all of
-#                     them (`setup_s` 126 -> 540 s). Until that is
-#                     understood q keeps its place (PERF.md section 7)
+#                     them (`setup_s` 126 -> 540 s). Understood since PR
+#                     58: it was not q. XLA:TPU shares the code of the
+#                     layers only where the step would not fit the chip
+#                     otherwise, and the plans that made q again were the
+#                     ones that left it room; the step asks for shared
+#                     code now (models/_training.py `_SHARED_CODE`), so a
+#                     plan no longer moves the executable's size. q keeps
+#                     its place until a plan by the clock is measured
+#                     again (PERF.md section 7 (37))
 #     hc_channel_out  a hyper-connected block's channel branch as `write` is
 #                     handed it (`_streams_read`): what closes the branch, a
 #                     dense MLP's down projection from some d's width, or a
@@ -1491,8 +1498,9 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
     blocks keep, from the shapes it holds: the loss's working set
     (ops.loss.working_set_bytes) and a block's backward pass, everything
     of the block that has a name in either table, alive at once while it
-    is differentiated, and what its channel mixer's own rule says it holds
-    besides (`_backward_holds`, `_streams_hold`). On one chip the two do
+    is differentiated, and what no name shows (`unnamed`: what its channel
+    mixer's own rule says it holds, `_backward_holds`, and the fitted
+    `_streams_hold` and `_latent_holds`). On one chip the two do
     not meet and the largest block counts: the larger of the loss and it;
     where the step has further `losses` over the one head (a prediction
     module's), a working set each beside either: the stack's loss leaves
@@ -1514,10 +1522,23 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
     tests/test_compile_v5e_*.py hold each total."""
     tokens, d = _rows_and_width(x)
     loss = loss_working_set_bytes(tokens, d, vocab)
-    blocks = [base + sum(size for _, size, _ in extras)
-              + _backward_holds(key[1], tokens, layer)
-              + _streams_hold(x, layer)
-              + _latent_holds(key[0], tokens, layer, dec)
+
+    def unnamed(key, layer) -> int:
+        rule = _backward_holds(key[1], tokens, layer)
+        fitted = _streams_hold(x, layer) \
+            + _latent_holds(key[0], tokens, layer, dec)
+        # The cotangent of the block's output waits while the block is
+        # differentiated. The two fitted terms were read off XLA's totals
+        # with it among them, and a block of named values alone is counted
+        # with all of them alive at once, which they are not; beside a
+        # rule's own exact account nothing is slack and it shows: XLA's
+        # total for LFM2's step leaves 6.154 GB beside the state, the base
+        # set and what is kept, the rule and its block's names come to
+        # 6.020, and a value of x's size is 0.134 (PERF.md section 6, PR 58:
+        # 0.54 GB of lane padding in that block's lse had covered it).
+        return rule + (fitted or (_nbytes(x) if rule else 0))
+
+    blocks = [base + sum(size for _, size, _ in extras) + unnamed(key, layer)
               for (base, extras), key, layer in zip(accounts, keys, layers)]
     return (max(loss, *blocks) if chips == 1 else loss + sum(blocks)) \
         + (losses - 1) * loss

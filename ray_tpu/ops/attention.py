@@ -7,7 +7,9 @@ attention written for the TPU memory hierarchy, forward AND backward:
 * Forward: a grid program holds a block of Q rows and, where VMEM allows,
   the head's whole K and V (fetched once a head: their block index does
   not depend on the Q block); fp32 accumulators persist in VMEM scratch;
-  the log-sum-exp per row is saved for the backward.
+  the log-sum-exp per row is saved for the backward, a lane row a head
+  ([batch, heads, 1, seq] float32, four bytes a position; the backward's
+  delta = rowsum(dO * O) travels in the same form).
 * Backward: flash-2 style dQ (a Q block against resident K/V) and dK/dV
   (a K/V block against resident Q, dO) kernels that recompute attention
   probabilities from the saved logsumexp — no (seq, seq) matrix is ever
@@ -347,16 +349,17 @@ def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
     elements, block x block where None. v, o and their gradients are
     `v_dim` wide (head_dim where None)."""
     v_dim = head_dim if v_dim is None else v_dim
-    row = 128 * 4                                   # a lane-padded f32 row
+    col = 128 * 4       # a position of an [n, 1] f32 column: lane-padded
+    row = 8 * 4         # of a [1, n] f32 row: it fills eight sublanes
     own, other = block * head_dim * itemsize, swept * head_dim * itemsize
     own_v, other_v = block * v_dim * itemsize, swept * v_dim * itemsize
     tiles = 3 * (block * block if tile is None else tile) * 4
     if kernel == "fwd":     # q | k, v -> o, lse; acc, m, l
         return (2 * (own + other + other_v) + 2 * (own_v + block * row)
-                + block * (v_dim * 4 + 2 * row) + tiles)
-    if kernel == "dq":      # q, do, lse, delta | k, v -> dq; acc
+                + block * (v_dim * 4 + 2 * col) + tiles)
+    if kernel == "dq":      # q, do, lse, delta | k, v -> dq; acc, lse, delta
         return (2 * (own + own_v + 2 * block * row + other + other_v)
-                + 2 * own + block * head_dim * 4 + tiles)
+                + 2 * own + block * (head_dim * 4 + 2 * col) + tiles)
     # dkv: k, v | q, do, lse, delta -> dk, dv; two accs
     return (2 * (own + own_v + other + other_v + 2 * swept * row)
             + 2 * (own + own_v) + block * (head_dim + v_dim) * 4 + tiles)
@@ -382,7 +385,11 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     dO and their rows in dK/dV): resident whole where the estimate fits
     VMEM_BUDGET with that block, else in the largest blocks that do, swept
     on the grid with the same loops inside; a smaller own block only where
-    no swept size fits beside the larger one. A score tile is block x
+    no swept size fits beside the larger one. The estimate (`_vmem_bytes`)
+    counts lse and delta at 32 bytes a position (a [1, n] float32 block
+    fills eight sublanes) beside the swept queries of dK/dV, and a column of
+    them, 512 bytes a position, in dQ's scratch for its own block. A score
+    tile is block x
     block, and what a kernel pays once a tile (the forward's row maxima,
     sums and rescale most of all) it pays four times as often at 512 as at
     1,024, which costs more than a swept side in two or four grid blocks:
@@ -390,13 +397,27 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     8,192 where 512 x 16,384 took 34.8, and dK/dV at 1,024 x 4,096 is 7-15%
     faster than at 512 x 8,192 at every 8k and 16k shape of the cells
     (PERF.md §6, PR 54). At (16384, 256 | 256), the widest the cells run,
-    the swept side is in FOUR grid blocks of 4,096 in forward and dQ and
-    EIGHT of 2,048 in dK/dV beside a 1,024 own block (26.2, 27.3 and 27.3 MB
-    of the 32 MiB): read on a v5e in a train step of 20 heads, 0.894, 1.192
-    and 1.478 ms a head, 78%, 88% and 94% of the MXU's peak for what each
-    computes, where 128 | 128 with K and V whole reads 69%, 94% and 90.5%:
-    four grid blocks cost dQ some six points, eight cost dK/dV none
-    (PERF.md §6, PR 55).
+    the swept side is in FOUR grid blocks of 4,096 in all three kernels
+    beside a 1,024 own block (25.2, 26.3 and 27.8 MB of the 32 MiB; read on
+    a v5e in a train step of 20 heads with dK/dV's in EIGHT of 2,048:
+    0.894, 1.192 and 1.478 ms a head, 78%, 88% and 94% of the MXU's peak
+    for what each computes, where 128 | 128 with K and V whole reads 69%,
+    94% and 90.5%; PERF.md §6, PR 55).
+
+    What dK/dV sweeps at 16,384 positions beside an own block of 1,024 keys
+    (swept queries, `vmem_bytes`; a call's device time on a v5e beside
+    what it was while lse and delta were padded to 128 lanes, 8 MiB of
+    them at 4,096 queries, and the queries came in grid blocks of 4,096,
+    2,048 at 256 | 256, 8,192 under the window; PERF.md §6, PR 58):
+    (16384, 64) 16,384 whole, 24.6 MB, 32 heads 26.69 -> 25.89 ms;
+    (16384, 128) 8,192, 25.2 MB, 32 heads 24.81 -> 24.15; (16384, 192 |
+    128) 8,192, 28.0 MB, 32 heads 38.49 -> 37.85; (16384, 256 | 256)
+    4,096, 27.8 MB, 20 heads 29.69 -> 29.50; (16384, 64, window 512, v 128)
+    16,384 whole, 19.4 MB, 65 tiles a head where 69 were, 20 heads 3.01 ->
+    2.37. Forward and dQ keep their swept sides; dQ's relayout of its
+    block's two lane rows into columns, once a program, is 1.4% of a call
+    at 16k x 64 and 9% at 1,024 positions (the forward's, `_lane_row`,
+    costs nothing measurable).
 
     Under a `window` (causal, a query sees itself and the window - 1
     positions before it) the sizes follow the same rules, and a program
@@ -629,6 +650,23 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _lane_row(col):
+    """A (rows, 1) float32 column as the (1, rows) lane row of the same
+    values, to the bit: 128 rows at a time, the column against the
+    identity's mask, summed over sublanes (a value and 127 zeros). A
+    transpose (`col.T`) gives the same row through the transpose unit and
+    costs a program 0.4 us a 1,024 rows on a v5e, 7% of a forward program
+    at 1,024 positions; this form costs it nothing measurable there and
+    the same at 4,096 x 128 (PERF.md section 6, PR 58). The other way, a
+    lane row to a column, Mosaic lowers as a transpose only (dQ's)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+    return jnp.concatenate([
+        jnp.sum(jnp.where(rows == cols, col[at:at + 128, :], 0.0), axis=0,
+                keepdims=True)
+        for at in range(0, col.shape[0], 128)], axis=1)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel: grid (bh, q blocks, k blocks). Float32 accumulators ride
 # VMEM scratch across a program's tiles and the K blocks of the grid.
@@ -697,15 +735,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         o_ref[0, rows, :] = (_dot(p.astype(v.dtype), v, _NN) / l).astype(
             o_ref.dtype)
         if save_lse:
-            lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l),
-                                                   (rows.size, 128))
+            m_scr[rows, :] = m + jnp.log(l)
 
     def finish(rows):
         l = l_scr[rows, :]
         o_ref[0, rows, :] = (acc_scr[rows, :] / l).astype(o_ref.dtype)
         if save_lse:
-            lse_ref[0, rows, :] = jnp.broadcast_to(
-                m_scr[rows, :] + jnp.log(l), (rows.size, 128))
+            m_scr[rows, :] += jnp.log(l)
 
     if window is None:
         _causal_work(step, qi * block - ki * swept, block, swept, sub,
@@ -716,6 +752,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         _band_work(step, qi * (block // sub) - ki * (swept // sub), block,
                    swept, sub, window, False, by_strip, **whole)
     if by_strip:
+        if save_lse:    # every strip left its rows' lse where its maxima were
+            lse_ref[0] = _lane_row(m_scr[...])
         return
 
     @pl.when(ki == grid[2] - 1)
@@ -726,8 +764,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
         if save_lse:
-            lse_ref[0] = jnp.broadcast_to(
-                m_scr[...] + jnp.log(l_safe), (block, 128))
+            lse_ref[0] = _lane_row(m_scr[...] + jnp.log(l_safe))
 
 
 def _swept_index(causal: bool, block: int, swept: int, mirrored: bool,
@@ -790,16 +827,15 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
     out_specs = [o_spec]
     out_shape = [jax.ShapeDtypeStruct((bh, seq_len, v_dim), qf.dtype)]
     if save_lse:
-        # lse is lane-replicated to 128 so its block satisfies the TPU
-        # (8, 128) tile rule (the layout jax's own TPU flash kernel uses
-        # for its residuals). Inference-only forwards skip it entirely —
-        # pallas outputs are opaque to XLA DCE, so an unused lse would
-        # still cost seq*128*4 bytes of HBM writes per (batch, head).
+        # One lane row a head, four bytes a position: a program turns its
+        # rows' column of lse into its stretch of the row once, at its end.
+        # Inference-only forwards skip it entirely: pallas outputs are
+        # opaque to XLA DCE, so an unused lse would still be written.
         out_specs.append(
-            pl.BlockSpec((1, block, 128), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, 1, block), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM))
         out_shape.append(
-            jax.ShapeDtypeStruct((bh, seq_len, 128), jnp.float32))
+            jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32))
     fwd = pl.pallas_call(
         kernel,
         grid=grid,
@@ -831,12 +867,9 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
         v.reshape(batch * heads, seq_len, v.shape[-1]), causal=causal,
         sm_scale=sm_scale, plan=plan, save_lse=save_lse, window=window)
     out = result[0].reshape(v.shape)
-    # lse stays lane-replicated (.., seq, 128): the backward feeds it
-    # straight back to the kernels, avoiding a slice + rebroadcast HBM
-    # round trip per training step. It carries q's leading [batch, heads]
-    # so it shards like q (see kernel_sharding).
-    lse = result[1].reshape(batch, heads, seq_len, 128) if save_lse \
-        else None
+    # lse is a lane row a head, [batch, heads, 1, seq]: q's leading axes
+    # and rank, so it shards like q (see kernel_sharding).
+    lse = result[1].reshape(batch, heads, 1, seq_len) if save_lse else None
     return out, lse
 
 
@@ -844,8 +877,8 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
 # Backward kernels (flash-2): recompute P per tile from saved lse.
 # ---------------------------------------------------------------------------
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale: float, causal: bool, sub: int,
-               grid: tuple, window: Optional[int] = None):
+               dq_scr, lse_scr, delta_scr, *, sm_scale: float, causal: bool,
+               sub: int, grid: tuple, window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     block, swept = q_ref.shape[1], k_ref.shape[1]
@@ -855,6 +888,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        # Its own rows' lse and delta, from lane rows to the columns a tile
+        # of scores subtracts: once a program.
+        lse_scr[...] = lse_ref[0].T
+        delta_scr[...] = delta_ref[0].T
 
     def step(rows, cols, on_diagonal):
         q = q_ref[0, rows, :]
@@ -865,9 +902,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         if not fold:
             s = s * sm_scale
         s = _masked(s, on_diagonal, sub, window, False)
-        p = jnp.exp(s - lse_ref[0, rows, :1])
+        p = jnp.exp(s - lse_scr[rows, :])
         dp = _dot(do_ref[0, rows, :], v_ref[0, cols, :], _NT)
-        ds = p * (dp - delta_ref[0, rows, :1])
+        ds = p * (dp - delta_scr[rows, :])
         if not fold:
             ds = ds * sm_scale
         dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
@@ -912,10 +949,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if not fold:
             s_t = s_t * sm_scale
         s_t = _masked(s_t, on_diagonal, sub, window, True)
-        p_t = jnp.exp(s_t - lse_ref[0, cols, 0][None, :])
+        p_t = jnp.exp(s_t - lse_ref[0, :, cols])
         dv_scr[rows, :] += _dot(p_t.astype(do.dtype), do, _NN)
         dp_t = _dot(v_ref[0, rows, :], do, _NT)
-        ds_t = p_t * (dp_t - delta_ref[0, cols, 0][None, :])
+        ds_t = p_t * (dp_t - delta_ref[0, :, cols])
         if not fold:
             ds_t = ds_t * sm_scale
         dk_scr[rows, :] += _dot(ds_t.astype(q.dtype), q, _NN)
@@ -940,9 +977,10 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
                      sm_scale: float, plan: KernelPlan,
                      window: Optional[int] = None):
     """The pallas_call of a backward kernel on a grid (bh, own blocks,
-    swept blocks): Q, dO and their lse and delta rows on one side, K and V
-    on the other, `q` [bh, seq, head_dim] and `v` [bh, seq, v_dim] giving
-    the widths; dQ like q, or (`mirrored`) dK like q and dV like v."""
+    swept blocks): Q, dO and their stretch of the lse and delta rows on one
+    side, K and V on the other, `q` [bh, seq, head_dim] and `v` [bh, seq,
+    v_dim] giving the widths; dQ like q, or (`mirrored`) dK like q and dV
+    like v."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -958,8 +996,14 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
                             memory_space=pltpu.VMEM)
     q_spec, do_spec = spec(q_len, head_dim, q_map), spec(q_len, v_dim, q_map)
     k_spec, v_spec = spec(k_len, head_dim, k_map), spec(k_len, v_dim, k_map)
-    row_spec = spec(q_len, 128, q_map)
+    row_spec = pl.BlockSpec(
+        (1, 1, q_len), lambda *ids: (ids[0], 0, q_map(*ids)[1]),
+        memory_space=pltpu.VMEM)
     outs = [(k_spec, q), (v_spec, v)] if mirrored else [(q_spec, q)]
+    scratch = [pltpu.VMEM((plan.block, like.shape[-1]), jnp.float32)
+               for _, like in outs]
+    if not mirrored:    # dQ's own rows' lse and delta, as columns
+        scratch += [pltpu.VMEM((plan.block, 1), jnp.float32)] * 2
     grid = (bh, seq_len // plan.block, seq_len // plan.swept)
     return pl.pallas_call(
         functools.partial(kernel, sm_scale=sm_scale, causal=causal,
@@ -969,8 +1013,7 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
         out_specs=[o for o, _ in outs],
         out_shape=[jax.ShapeDtypeStruct(like.shape, like.dtype)
                    for _, like in outs],
-        scratch_shapes=[pltpu.VMEM((plan.block, like.shape[-1]), jnp.float32)
-                        for _, like in outs],
+        scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
     )
@@ -999,15 +1042,12 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     bh = batch * heads
     flat = (bh, seq_len, head_dim)
     flat_v = (bh, seq_len, v.shape[-1])
-    # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA.
-    delta = jnp.broadcast_to(
-        jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                axis=-1).reshape(bh, seq_len)[:, :, None],
-        (bh, seq_len, 128))
+    # delta_i = rowsum(dO_i * O_i) — cheap elementwise reduce in XLA; like
+    # lse a lane row a head.
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, seq_len)
     operands = (q.reshape(flat), k.reshape(flat), v.reshape(flat_v),
-                g.reshape(flat_v),
-                lse.reshape(bh, seq_len, 128),  # lane-replicated by forward
-                delta)
+                g.reshape(flat_v), lse.reshape(bh, 1, seq_len), delta)
     dq, = _dq_call(*operands, causal=causal, sm_scale=sm_scale,
                    plan=dq_plan, window=window)
     # dK/dV: K-outer, Q-inner sweep.

@@ -103,6 +103,32 @@ def lowered_cell_step(topo, family, config_file, mix_file) -> CellStep:
     return CellStep(cfg, mix, lowered, plans[0] if plans else None)
 
 
+def mosaic_call_types(lowered_text, kernels):
+    """[(kernel name, its operands' and results' types as the text has
+    them)] of a lowered program's calls of `kernels`, a call a pair."""
+    calls = []
+    for line in lowered_text.splitlines():
+        name = re.search(r'kernel_name = "(\w+)"', line)
+        if "@tpu_custom_call" in line and name and name.group(1) in kernels:
+            calls.append((name.group(1), line[line.rindex("} : ("):]))
+    return calls
+
+
+def assert_flash_rows_are_lane_rows(lowered_text, heads: int):
+    """The flash kernels' two per-row float32 residuals, lse and delta, are
+    lane rows [heads, 1, 16384] in a lowered 16k step: the forward's second
+    result, the last two operands of dQ and of dK/dV, and no operand or
+    result of the three is [.., 16384, 128] float32 (512 bytes a row until
+    PR 58)."""
+    rows = {"_fwd_kernel": 1, "_dq_kernel": 2, "_dkv_kernel": 2}
+    calls = mosaic_call_types(lowered_text, tuple(rows))
+    assert {name for name, _ in calls} == set(rows)
+    for name, types in calls:
+        assert "16384x128xf32" not in types, (name, types)
+        assert types.count(f"<{heads}x1x16384xf32>") == rows[name], (
+            name, types)
+
+
 def mosaic_grids(lowered_text, kernels):
     """{kernel name: {(grid, [each operand's and result's block])}} of a
     lowered program's calls of `kernels`, read out of their serialized

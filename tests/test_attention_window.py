@@ -91,9 +91,9 @@ def test_window_with_the_swept_side_on_the_grid(window, monkeypatch):
     """Under a budget whole sequences do not fit, keys (forward, dQ) and
     queries (dK/dV) come in blocks on the grid: a strip's sub-block
     numbers are traced values there and run negative and past the block.
-    Swept blocks of two sub-blocks (one in dK/dV): a window of 100 has
-    every other strip's tile inside one, the wider ones none, and those
-    strips go in pieces."""
+    Swept blocks of two sub-blocks: a window of 100 has every other
+    strip's tile inside one, the wider ones none, and those strips go in
+    pieces."""
     monkeypatch.setattr(attention, "VMEM_BUDGET", 1_500_000)
     plan = attention_plan(1024, 64, True, jnp.float32, window, 128)
     assert plan.fwd.swept < 1024 and plan.dkv.swept < 1024, plan
@@ -128,7 +128,7 @@ def test_causal_wide_keys_with_the_swept_side_on_the_grid(hd, budget,
 
 
 @pytest.mark.parametrize("budget,blocks", [
-    (3_000_000, [(128, 256), (128, 256), (128, 128)]),
+    (2_500_000, [(128, 256), (128, 256), (128, 128)]),
     (5_000_000, [(256, 512), (256, 256), (256, 256)])],
     ids=["4-4-8_grid_blocks", "own_blocks_of_two"])
 def test_256_wide_heads_with_the_swept_side_in_four_and_eight_grid_blocks(
@@ -153,16 +153,18 @@ def test_256_wide_heads_with_the_swept_side_in_four_and_eight_grid_blocks(
 def test_the_plan_at_16k_and_256_wide_heads_is_pinned():
     """`attention_plan(16384, 256, v_dim=256)`: forward and dQ hold 1,024
     queries against K and V in four grid blocks of 4,096, dK/dV 1,024 keys
-    against queries in eight of 2,048; 26.2, 27.3 and 27.3 MB of the 32
-    MiB; the same 184 tiles a head as every other 16k shape."""
+    against queries in four of 4,096 (eight of 2,048 while lse and delta
+    travelled padded to 128 lanes: 2 MiB a block of them at 2,048); 25.2,
+    26.3 and 27.8 MB of the 32 MiB; the same 184 tiles a head as every
+    other 16k shape."""
     plan = attention_plan(16384, 256, True, jnp.bfloat16, None, 256)
     assert [tuple(getattr(kernel, f) for f in _FIELDS)
             for kernel in (plan.fwd, plan.dq, plan.dkv)] == [
-        (1024, 4096, 256, 26214400, 2080, 64, 2016, 184),
-        (1024, 4096, 256, 27262976, 2080, 64, 2016, 184),
-        (1024, 2048, 256, 27262976, 2080, 64, 2016, 184)]
+        (1024, 4096, 256, 25231360, 2080, 64, 2016, 184),
+        (1024, 4096, 256, 26345472, 2080, 64, 2016, 184),
+        (1024, 4096, 256, 27787264, 2080, 64, 2016, 184)]
     assert [16384 // k.swept for k in (plan.fwd, plan.dq, plan.dkv)] \
-        == [4, 4, 8]
+        == [4, 4, 4]
     # v as wide as q and k: naming v's width changes nothing
     assert plan == attention_plan(16384, 256)
 
@@ -223,15 +225,17 @@ def test_large_own_blocks_at_head_dim_128():
 
 
 @pytest.mark.parametrize("S,window,dtype,budget,whole", [
-    (4096, 256, jnp.bfloat16, 5_000_000, (True, True, True)),
-    (4096, 512, jnp.bfloat16, 5_750_000, (True, True, False)),
-    (2048, 100, jnp.float32, 5_500_000, (True, True, True))])
+    (4096, 256, jnp.bfloat16, 3_250_000, (True, True, True)),
+    (4096, 512, jnp.bfloat16, 4_250_000, (False, False, False)),
+    (2048, 100, jnp.float32, 5_000_000, (True, True, True))])
 def test_strips_across_swept_blocks(S, window, dtype, budget, whole,
                                     monkeypatch):
     """Own blocks of several sub-blocks with the swept side on the grid:
     a strip whose tile lies inside a swept block works it whole, one that
-    lies across two works its pieces in both; in dK/dV (swept blocks of
-    two sub-blocks) every other strip does."""
+    lies across two works its pieces in both; against swept blocks of two
+    sub-blocks (dK/dV's, and at 4,096 all three kernels') every other
+    strip does, and every strip where the tile is three sub-blocks wide
+    (a window of 512)."""
     monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
     plan = attention_plan(S, 64, True, dtype, window, 128)
     for kernel, tile in zip((plan.fwd, plan.dq, plan.dkv), whole,
@@ -349,13 +353,16 @@ def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
         assert (kernel.block, kernel.swept, kernel.sub) == (1024, 16384, 256)
         assert (kernel.tiles, kernel.masked) == (65, 127)
     assert attention._strip_tile(256, 512, 16384) == 768
-    # dK/dV: queries in two grid blocks of 8,192 (lse and delta travel
-    # lane-replicated), so the two key strips whose queries lie across the
-    # boundary go in 3 pieces each, as the last two do in 2 + 1
-    assert (band.dkv.block, band.dkv.swept) == (1024, 8192)
-    assert (band.dkv.tiles, band.dkv.masked) == (69, 129)
-    # the full layers' dK/dV: its own block sized first, as the forward's
-    assert (causal.dkv.block, causal.dkv.swept) == (1024, 4096)
+    # dK/dV: the queries resident too (lse and delta are lane rows of four
+    # bytes a query; padded to 128 lanes they took 8 MiB at 4,096 queries,
+    # the queries came in two grid blocks of 8,192, and the two key strips
+    # across the boundary went in 3 pieces each: 69 tiles), so only the
+    # last two strips go in pieces, 2 + 1
+    assert (band.dkv.block, band.dkv.swept) == (1024, 16384)
+    assert (band.dkv.tiles, band.dkv.masked) == (65, 127)
+    # the full layers' dK/dV: its own block sized first, as the forward's,
+    # and the queries whole
+    assert (causal.dkv.block, causal.dkv.swept) == (1024, 16384)
     assert causal.dkv.tiles == causal.fwd.tiles == 184
     for kernel in (band.fwd, band.dq, band.dkv):
         assert kernel.tiles <= 16384 // 256 + 8
@@ -366,41 +373,42 @@ def test_the_cells_windowed_layers_compute_a_sixteenth_of_the_triangle():
 
 
 @pytest.mark.parametrize("args,want", [
-    ((1024, 64), [(1024, 1024, 128, 15990784, 36, 8, 28, 8),
-                  (1024, 1024, 128, 16252928, 36, 8, 28, 8),
-                  (1024, 1024, 128, 16777216, 36, 8, 28, 8)]),
-    ((4096, 128), [(1024, 4096, 256, 20447232, 136, 16, 120, 22),
-                   (1024, 4096, 256, 20971520, 136, 16, 120, 22),
-                   (1024, 4096, 256, 28311552, 136, 16, 120, 22)]),
-    ((16384, 64), [(1024, 16384, 256, 23855104, 2080, 64, 2016, 184),
-                   (1024, 16384, 256, 24117248, 2080, 64, 2016, 184),
-                   (1024, 4096, 256, 24641536, 2080, 64, 2016, 184)]),
+    ((1024, 64), [(1024, 1024, 128, 15007744, 36, 8, 28, 8),
+                  (1024, 1024, 128, 15335424, 36, 8, 28, 8),
+                  (1024, 1024, 128, 14811136, 36, 8, 28, 8)]),
+    ((4096, 128), [(1024, 4096, 256, 19464192, 136, 16, 120, 22),
+                   (1024, 4096, 256, 20054016, 136, 16, 120, 22),
+                   (1024, 4096, 256, 20447232, 136, 16, 120, 22)]),
+    ((16384, 64), [(1024, 16384, 256, 22872064, 2080, 64, 2016, 184),
+                   (1024, 16384, 256, 23199744, 2080, 64, 2016, 184),
+                   (1024, 16384, 256, 24641536, 2080, 64, 2016, 184)]),
     ((16384, 64, True, jnp.bfloat16, None, 128),
-     [(1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
-      (1024, 16384, 256, 28573696, 2080, 64, 2016, 184),
-      (1024, 4096, 256, 26476544, 2080, 64, 2016, 184)]),
-    ((16384, 128), [(1024, 16384, 256, 33030144, 2080, 64, 2016, 184),
-                    (1024, 16384, 256, 33554432, 2080, 64, 2016, 184),
-                    (1024, 4096, 256, 28311552, 2080, 64, 2016, 184)]),
-    ((8192, 64), [(1024, 8192, 256, 19660800, 528, 32, 496, 60),
-                  (1024, 8192, 256, 19922944, 528, 32, 496, 60),
-                  (1024, 4096, 256, 24641536, 528, 32, 496, 60)]),
+     [(1024, 16384, 256, 27590656, 2080, 64, 2016, 184),
+      (1024, 16384, 256, 27656192, 2080, 64, 2016, 184),
+      (1024, 16384, 256, 29622272, 2080, 64, 2016, 184)]),
+    ((16384, 128), [(1024, 16384, 256, 32047104, 2080, 64, 2016, 184),
+                    (1024, 16384, 256, 32636928, 2080, 64, 2016, 184),
+                    (1024, 8192, 256, 25165824, 2080, 64, 2016, 184)]),
+    ((8192, 64), [(1024, 8192, 256, 18677760, 528, 32, 496, 60),
+                  (1024, 8192, 256, 19005440, 528, 32, 496, 60),
+                  (1024, 8192, 256, 19398656, 528, 32, 496, 60)]),
     ((16384, 192, True, jnp.bfloat16, None, 128),
-     [(1024, 8192, 256, 27000832, 2080, 64, 2016, 184),
-      (1024, 8192, 256, 28049408, 2080, 64, 2016, 184),
-      (1024, 4096, 256, 30146560, 2080, 64, 2016, 184)])],
+     [(1024, 8192, 256, 26017792, 2080, 64, 2016, 184),
+      (1024, 8192, 256, 27131904, 2080, 64, 2016, 184),
+      (1024, 8192, 256, 28049408, 2080, 64, 2016, 184)])],
     ids=["1024x64", "4096x128", "16384x64", "16384x64|128", "16384x128",
          "8192x64", "16384x192|128"])
 def test_unwindowed_plans_are_what_they_were(args, want):
     """Every window=None plan the nine cells run, field for field, beside
-    the count of tiles. Forward and dQ of the first four shapes are a
-    literal copy of PR 34's tree, and so are all three kernels where the
-    sequence is resident whole (gpt2's and OLMoE's shapes). Since PR 54 a
-    kernel's own block is sized before its swept side: dK/dV at 8k and 16k
-    holds 1,024 keys against queries in grid blocks of 4,096 (184 tiles
-    where 512 x 8,192 made 560), and at 192 | 128 forward and dQ hold 1,024
-    queries against K and V in two grid blocks of 8,192 where they held
-    512 against the whole 16,384."""
+    the count of tiles. Blocks, sub-blocks and counts of forward and dQ
+    are PR 54's (a kernel's own block sized before its swept side: 1,024
+    queries against K and V whole, in two grid blocks of 8,192 at 192 |
+    128). Since PR 58 lse and delta are lane rows of four bytes a position
+    (32 in the estimate: a [1, n] block fills eight sublanes) where they
+    were padded to 128 lanes, 512 bytes: every estimate is smaller, and
+    dK/dV, which holds the swept queries' two rows, keeps the queries
+    whole at 64 | 64, 64 | 128 and 8k and in two grid blocks of 8,192 at
+    128 | 128 and 192 | 128 where it swept them in blocks of 4,096."""
     plan = attention_plan(*args)
     assert (plan.seq_len, plan.head_dim, plan.causal, plan.window,
             plan.vmem_budget) == (*args[:2], True, None, 32 * 2 ** 20)
@@ -414,3 +422,86 @@ def test_a_window_is_causal_and_positive():
         attention_plan(512, 64, False, jnp.float32, 128)
     with pytest.raises(ValueError, match="at least 1"):
         attention_plan(512, 64, True, jnp.float32, 0)
+
+
+# The kernels' two row residuals, lse and delta, are lane rows of four
+# bytes a position: [batch, heads, 1, S] float32, q's leading axes and rank.
+# (S, head_dim, v_dim, window): one block of eight strips; four blocks of
+# 1,024 with the sequence resident; a band with values twice as wide; latent
+# attention's 192 | 128.
+ROW_CASES = pytest.mark.parametrize("S,hd,vd,window", [
+    (1024, 64, 64, None), (4096, 128, 128, None), (2048, 64, 128, 512),
+    (2048, 192, 128, None)],
+    ids=["1024x64", "4096x128", "2048x64|128w512", "2048x192|128"])
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, those of its calls too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@ROW_CASES
+def test_lse_is_a_lane_row_a_head_and_the_backward_reads_it(S, hd, vd,
+                                                            window):
+    """`_flash_forward` hands back the log-sum-exp of each query's visible
+    scores as [batch, heads, 1, S] float32, and `_flash_backward` through
+    that value (and a delta of the same form) gives `mha_reference`'s
+    gradients."""
+    heads = 1 if S == 4096 else 2
+    q, k, v, g = (t[:, :heads] for t in _inputs(S, hd, vd))
+    scale = hd ** -0.5
+    plan = attention_plan(S, hd, True, jnp.float32, window, vd)
+    out, lse = attention._flash_forward(q, k, v, True, scale, plan.fwd,
+                                        True, window)
+    assert lse.shape == (1, heads, 1, S) and lse.dtype == jnp.float32
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    seen = (ahead >= 0) if window is None else (ahead >= 0) & (ahead < window)
+    want = jax.nn.logsumexp(jnp.where(seen, sc, -jnp.inf), -1)
+    np.testing.assert_allclose(lse[:, :, 0], want, atol=2e-5, rtol=2e-5)
+    ref, vjp = jax.vjp(lambda *a: mha_reference(*a, True, scale, window),
+                       q, k, v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-4)
+    grads = attention._flash_backward(q, k, v, out, lse, g, True, scale,
+                                      plan.dq, plan.dkv, window)
+    for a, b in zip(grads, vjp(g), strict=True):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-3)
+
+
+@ROW_CASES
+def test_the_kernels_results_and_operands_hold_no_padded_row(S, hd, vd,
+                                                             window):
+    """What the three kernels are called with: a forward that saves lse
+    has [bh, 1, S] float32 as its second result, one that does not
+    (`save_lse=False`, the serving path) has ONE result, and both backward
+    kernels read lse and delta as [bh, 1, S] behind q, k, v and dO: no
+    operand or result is a row padded to [.., S, 128]."""
+    q, k, v, g = _inputs(S, hd, vd)
+    scale = hd ** -0.5
+    plan = attention_plan(S, hd, True, jnp.float32, window, vd)
+
+    def results(save_lse):
+        call, = _pallas_calls(jax.make_jaxpr(
+            lambda q, k, v: attention._flash_forward(
+                q, k, v, True, scale, plan.fwd, save_lse, window))(
+                    q, k, v).jaxpr)
+        return [(o.aval.shape, o.aval.dtype) for o in call.outvars]
+    row = ((2, 1, S), jnp.float32)
+    assert results(True) == [((2, S, vd), q.dtype), row]
+    assert results(False) == [((2, S, vd), q.dtype)]
+    lse = jnp.zeros((1, 2, 1, S), jnp.float32)
+    calls = list(_pallas_calls(jax.make_jaxpr(
+        lambda *a: attention._flash_backward(
+            *a, True, scale, plan.dq, plan.dkv, window))(
+                q, k, v, v, lse, g).jaxpr))
+    assert len(calls) == 2
+    wide, narrow = ((2, S, hd), q.dtype), ((2, S, vd), q.dtype)
+    for call in calls:
+        assert [(x.aval.shape, x.aval.dtype) for x in call.invars] == [
+            wide, wide, narrow, narrow, row, row]

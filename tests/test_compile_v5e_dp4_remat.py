@@ -11,8 +11,11 @@ split is whole on every chip (the loss's chunk and head gradient, 3.29 GB:
 ops.loss.working_set_bytes), and under data parallelism XLA moves every
 layer's weight gradients behind the last backward kernel
 (models/_training.py `_ASYNC_GRADIENT_REDUCE`), so all four blocks'
-working sets count at once: the plan keeps two layers' gate | up where a
-one-chip step of the same share would keep all four. (At eight layers the
+working sets count at once: the plan kept two layers' gate | up where a
+one-chip step of the same share would keep all four, while a layer's lse
+was padded to 128 lanes (0.13 GB a layer, in the base set and again in each
+block's working set); at 4 bytes a row, since PR 58, all four fit, with
+0.26 GB left. (At eight layers the
 same step stands at 15.26 GiB with the base set alone, 4.2 GB above the
 one-chip step of a chip's share, and the plan adds nothing: PERF.md
 section 6, PR 51.)"""
@@ -76,13 +79,14 @@ def test_the_plan_is_asked_at_a_chips_share_with_the_loss_whole(step):
     (x, chips, plan), = PLANS
     assert x.shape == (4, 4096, 2048) and chips == 4
     assert plan.state_bytes == 5_301_895_176      # the whole state, a chip's
-    assert plan.base_bytes == 4 * 469_762_560
+    assert plan.base_bytes == 4 * (469_762_560 - 4 * 16 * 4096 * (512 - 4))
     # the loss's 3.29 GB and every block's backward at once
     loss = working_set_bytes(16384, 2048, 100352)
     assert loss == 6 * 4096 * 100352 + 4 * 2048 * 100352
     assert plan.reserve_bytes == loss + plan.base_bytes + 4 * 536_870_912
-    assert plan.extras == (("mlp_gate_up",),) * 2 + ((),) * 2
-    assert plan.kept_extra_bytes == 2 * 536_870_912
+    assert plan.extras == (("mlp_gate_up",),) * 4
+    assert plan.kept_extra_bytes == 4 * 536_870_912
+    assert plan.bytes_left == 259_747_832
 
 
 def test_a_chip_of_the_dp4_step_stays_a_gib_under(step, record_property):

@@ -22,7 +22,8 @@ import pytest
 
 from chipbench.families import glm4_moe_lite as family
 from compile_v5e import (HBM_BYTES, lowered_cell_step,  # noqa: F401
-                         mosaic_grids, topo, total)
+                         assert_flash_rows_are_lane_rows, mosaic_grids,
+                         topo, total)
 
 
 @pytest.fixture(scope="module")
@@ -79,8 +80,10 @@ def test_lowered_step_calls_the_five_kernels_at_256_and_256_with_no_map(cell):
 def test_the_three_kernels_run_the_grid_the_plan_says(cell):
     """`attention_plan(16384, 256, v_dim=256)`: forward and dQ hold 1,024
     queries against K and V in FOUR grid blocks of 4,096, 16 x 4 programs a
-    head, dK/dV 1,024 keys against queries in EIGHT of 2,048, and the
-    lowered step's Mosaic calls carry those grids and blocks."""
+    head, dK/dV 1,024 keys against queries in FOUR of 4,096 too (eight of
+    2,048 until PR 58, beside lse and delta blocks of [2048, 128]; they are
+    lane rows [1, 4096] now), and the lowered step's Mosaic calls carry
+    those grids and blocks."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import attention_plan
@@ -88,42 +91,59 @@ def test_the_three_kernels_run_the_grid_the_plan_says(cell):
     plan = attention_plan(16384, 256, True, jnp.bfloat16, None, 256)
     for kernel in (plan.fwd, plan.dq):
         assert (kernel.block, kernel.swept, kernel.tiles) == (1024, 4096, 184)
-    assert (plan.dkv.block, plan.dkv.swept) == (1024, 2048)
+    assert (plan.dkv.block, plan.dkv.swept) == (1024, 4096)
     grids = mosaic_grids(cell.lowered.as_text(),
                           ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))
-    own, swept, row = (1, 1024, 256), (1, 4096, 256), (1, 1024, 128)
+    own, swept, row = (1, 1024, 256), (1, 4096, 256), (1, 1, 1024)
     assert grids["_fwd_kernel"] == {((20, 16, 4), (own, swept, swept, own,
                                                    row))}
     (grid, blocks), = grids["_dq_kernel"]
-    assert grid == (20, 16, 4) and blocks[:3] == (own, swept, swept)
+    assert grid == (20, 16, 4)
+    assert blocks[:6] == (own, swept, swept, own, row, row)
     (grid, blocks), = grids["_dkv_kernel"]
-    assert grid == (20, 16, 8)
-    assert blocks[:3] == ((1, 2048, 256), own, own)
+    assert grid == (20, 16, 4)
+    assert blocks[:6] == (swept, own, own, swept, (1, 1, 4096), (1, 1, 4096))
+
+
+def test_the_kernels_rows_are_four_bytes_a_position(cell):
+    """lse and delta, the flash kernels' two per-row float32 residuals, are
+    lane rows [20, 1, 16384] in the lowered step: the forward's second
+    result, the last two operands of dQ and of dK/dV, and no operand or
+    result of the three is [.., 16384, 128] float32 (512 bytes a row, 168 MB
+    a block each, until PR 58). By the account a block keeps 1.3 MB of lse:
+    `test_the_plan_counts_the_module_and_the_second_loss` holds the base
+    set."""
+    assert_flash_rows_are_lane_rows(cell.lowered.as_text(), 20)
 
 
 def test_the_plan_counts_the_module_and_the_second_loss(cell):
     """`remat_plan` as the step was traced with a chip's 15.75 GiB: six
     blocks (the module's last) and two losses. State 9.31 GB (weights, two
-    moments, gradients), the base set 2.57 (a block's input 0.07, the
-    kernel's output 0.17, the lane-padded lse 0.17, the latent and shared
-    key 0.02, the router's scores: q, 0.17 a layer, is a candidate and no
-    longer of the base set), the reserve 4.41: the largest block's
-    backward with the 1.01 GB of keys, values and cotangents no name shows
-    (`_latent_holds`) and a second loss's working set, 1.27. That leaves
-    nothing under the plan's margin: no block keeps anything besides, q
-    is made again in all six, and XLA's own total is 15.97 GB."""
+    moments, gradients), the base set 1.57 (a block's input 0.07, the
+    kernel's output 0.17, lse 1.3 MB, the latent and shared key 0.02, the
+    router's scores: q, 0.17 a layer, is a candidate and not of the base
+    set; 2.57 while lse was padded to 128 lanes, 0.17 GB a block), the
+    reserve 4.24: the largest block's backward with the 1.01 GB of keys,
+    values and cotangents no name shows (`_latent_holds`) and a second
+    loss's working set, 1.27 (4.41 with that block's padded lse). Until
+    PR 58 that left nothing under the plan's margin and q was made again in
+    all six blocks; now 0.71 GB are left, which hold q in the first four
+    (168 MB each) and the five expert blocks' routing choices, and 36 MB
+    stay: XLA's own total is 15.83 GB where it was 15.97."""
     plan = cell.plan
-    assert plan.extras == ((),) * 6
-    assert plan.layers_extended == 0 and plan.kept_extra_bytes == 0
+    q, choice = ("flash_attention_q",), ("moe_choice",)
+    assert plan.extras == (q,) + (q + choice,) * 3 + (choice,) * 2
+    assert plan.layers_extended == 6
+    assert plan.kept_extra_bytes == 4 * 20 * 16384 * 256 * 2 + 5 * (
+        3 * 16384 * 4 * 4 + 16 * 4) == 675_021_120
     assert 9.30e9 < plan.state_bytes < 9.32e9
-    assert 2.5e9 < plan.base_bytes < 2.65e9
-    assert 4.3e9 < plan.reserve_bytes < 4.5e9
+    assert plan.base_bytes == 1_572_341_248
+    assert plan.reserve_bytes == 4_242_538_816
     from ray_tpu.ops.loss import working_set_bytes
     loss = working_set_bytes(16384, 2048, 38720)
     assert 1.26e9 < loss < 1.28e9
     assert plan.reserve_bytes - loss > loss        # a block's, not the loss's
-    assert plan.state_bytes + plan.base_bytes + plan.reserve_bytes \
-        > HBM_BYTES - 2 ** 30
+    assert 0 < plan.bytes_left == 35_787_384 < 20 * 16384 * 256 * 2
 
 
 def test_step_calls_exactly_the_five_kernels_under_the_programs_scopes(step):
@@ -154,10 +174,11 @@ def test_step_calls_exactly_the_five_kernels_under_the_programs_scopes(step):
     assert re.search(r"mtp\)*/[^\"]*channel_mixer/moe_route", compiled)
 
 
-def test_no_attention_forward_runs_twice_and_q_is_made_again(step):
+def test_no_attention_forward_runs_twice_and_q_is_made_again(step, cell):
     """Remat is on, and a latent layer's block keeps the kernel's output
     and lse (models/decoder.py KEPT_BY_KIND): each of the six blocks calls
-    its forward kernel once, with q, keys and values made again. The five
+    its forward kernel once, with keys and values made again, and q in the
+    last two blocks, which the plan leaves no room to keep it in. The five
     expert layers (the module's among them) call their two forward grouped
     matmuls once and make the first again in the backward rule: 15 calls
     beside 10 gradients by the rows and 10 by the weights (a block joined
@@ -170,6 +191,8 @@ def test_no_attention_forward_runs_twice_and_q_is_made_again(step):
         "flash_attention_dkv": 6, "grouped_matmul_fwd": 15,
         "grouped_matmul_dlhs": 10, "grouped_matmul_drhs": 10}
     assert not re.search(r"\[(1,)?20,16384,16384\]", step[1])
+    assert ["flash_attention_q" in names for names in cell.plan.extras] \
+        == [True] * 4 + [False] * 2
 
 
 def test_step_fits_a_chip_by_xlas_own_total(step, cell, record_property):
@@ -180,9 +203,15 @@ def test_step_fits_a_chip_by_xlas_own_total(step, cell, record_property):
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")
     plan = cell.plan
-    # XLA's own total: 15.97 GB, 0.94 GB under the chip's 15.75 GiB (with q
-    # kept in every block, as before PR 55: 17.10 GB, over it), and under
-    # what the plan reckoned, which is from above
-    assert nbytes < 16.1e9
-    assert nbytes <= HBM_BYTES - 0.75 * 2 ** 30
-    assert nbytes <= plan.state_bytes + plan.base_bytes + plan.reserve_bytes
+    # XLA's own total: 15.83 GB (15,826,912,768), a GiB under the chip's
+    # 15.75 GiB by 11 MB, with q kept in four blocks (15.97 with q made
+    # again in all six while lse and delta were padded to 128 lanes; with q
+    # kept in every block then, as before PR 55: 17.10 GB, over the chip's).
+    # What the plan reckoned is 15.80: from above while nothing was kept
+    # (16.29 against 15.97), 25 MB under now, because XLA's total is not
+    # additive in the q kept: under forced plans it reads 15.08 GB with q
+    # in no block, 15.94 in the first three, 15.83 in four, 15.86 in all
+    # six (PERF.md section 7, PR 58); the plan's GiB of margin is for that.
+    assert nbytes <= HBM_BYTES - 2 ** 30
+    assert nbytes <= plan.state_bytes + plan.base_bytes \
+        + plan.reserve_bytes + plan.kept_extra_bytes + 2 ** 25

@@ -121,8 +121,10 @@ def test_step_fits_a_chip(step, cell, record_property):
     mem = step[1].memory_analysis()
     nbytes = total(mem)
     record_property("olmoe_b4_s4096_bytes", nbytes)
-    # 11.71 GB since PR 30 (11.69 before it: `moe_xs` is kept where the
-    # unsorted rows were); the runtime's peak on the chip is in PERF.md §2.
+    # 11.25 GB (11,253,872,640) since PR 58: two layers' lse and delta at 4
+    # bytes a row where they were padded to 128 lanes. 11.71 GB since PR 30
+    # (11.69 before it: `moe_xs` is kept where the unsorted rows were); the
+    # runtime's peak on the chip is in PERF.md §2.
     print(f"olmoe-train-1chip step: {nbytes / 1e9:.2f} GB "
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")

@@ -135,8 +135,9 @@ def test_windowed_layers_compute_the_band_and_nothing_else():
     the other side (the far edge's sub-block and the diagonal's masked,
     the one between whole): 189 of the 4,096 sub-blocks in the square a
     head, where the causal triangle alone is 2,080, in 65 tiles (69 in
-    dK/dV, whose queries come in two grid blocks of 8,192) where own
-    blocks of one sub-block ran them as 189 tiles of 64 programs."""
+    dK/dV until PR 58, while its queries came in two grid blocks of 8,192
+    beside lse and delta padded to 128 lanes; they are resident now) where
+    own blocks of one sub-block ran them as 189 tiles of 64 programs."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import VMEM_BUDGET, attention_plan
@@ -145,7 +146,7 @@ def test_windowed_layers_compute_the_band_and_nothing_else():
                           v_dim=128)
     causal = attention_plan(16384, 64, True, jnp.bfloat16, v_dim=128)
     for kernel, swept, tiles in ((band.fwd, 16384, 65), (band.dq, 16384, 65),
-                                 (band.dkv, 8192, 69)):
+                                 (band.dkv, 16384, 65)):
         assert (kernel.block, kernel.swept, kernel.sub) == (1024, swept, 256)
         assert (kernel.computed, kernel.skipped) == (189, 4096 - 189)
         assert 126 <= kernel.masked <= 129
@@ -164,13 +165,17 @@ def test_step_fits_a_chip(step, cell, record_property):
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")
     # With the base set alone XLA gives the step 14,254,285,824 bytes (PR
     # 51's compile, as since PR 37), under ISSUE 31's line for its one
-    # fallback, so the cell stays at 16,384. What that leaves holds the
+    # fallback, so the cell stays at 16,384. What that leaves held the
     # first layer's two projections and the second Mamba-1 layer's input
-    # projection (1.34 GB), and XLA's figure stays a GiB under the chip's
-    # (15.59 GB, 14.52 GiB).
+    # projection (1.34 GB); since PR 58 the four attention layers' lse is 4
+    # bytes a row (1.33 GB less in the base set) and it holds the first
+    # three layers' gate and up and both Mamba-1 layers' input projections
+    # (2.68 GB), and XLA's figure stays a GiB under the chip's as it was
+    # (15,594,413,568: 15.59 GB, 14.52 GiB).
     plan = cell.plan
-    assert plan.extras == (("mlp_gate_up", "ssm_in_proj"), (),
-                           ("ssm_in_proj",)) + ((),) * 5
+    assert plan.extras == (("mlp_gate_up", "ssm_in_proj"), ("mlp_gate_up",),
+                           ("mlp_gate_up", "ssm_in_proj")) + ((),) * 5
+    assert plan.kept_extra_bytes == 2_684_354_560
     assert nbytes - plan.kept_extra_bytes < FALLBACK_OVER
     assert nbytes <= HBM_BYTES - 2 ** 30
     # PR 34's and PR 37's line still, on the step less what the plan added
@@ -178,4 +183,4 @@ def test_step_fits_a_chip(step, cell, record_property):
     # convolution's rule, nor with the band's large own blocks (VMEM, not
     # HBM); and the base set is the seventeen names' and a layer's input
     assert nbytes - plan.kept_extra_bytes <= 14_254_285_824
-    assert plan.base_bytes <= 4_781_506_560
+    assert plan.base_bytes <= 3_449_815_040

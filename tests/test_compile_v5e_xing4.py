@@ -9,7 +9,8 @@ layers 2-5, B=1 x S=16384, remat on, AdamW at the family's rate — compiles
 for one chip, calls exactly the attention and grouped-matmul kernels under
 the program's scopes, no forward kernel twice though remat is on, keeps
 the latent and no per-head key or value, never holds a [32, 16384, 16384]
-map or a float32 copy of the streams, and fits the chip by XLA's memory
+map, a float32 copy of the streams or a row residual padded to 128 lanes,
+and fits the chip by XLA's memory
 analysis (PERF.md section 4 has the figure). What the LOWERED step shows
 (the five kernels, their operands' widths, no attention map, what the plan
 kept) is tier-1's; what only XLA's compile shows (the scopes on the
@@ -25,7 +26,8 @@ import pytest
 
 from chipbench.families import xing4
 from compile_v5e import (HBM_BYTES, lowered_cell_step,  # noqa: F401
-                         mosaic_grids, topo, total)
+                         assert_flash_rows_are_lane_rows, mosaic_grids,
+                         topo, total)
 
 
 @pytest.fixture(scope="module")
@@ -84,7 +86,10 @@ def test_forward_and_dq_run_the_grid_the_plan_says(cell):
     first: forward and dQ hold 1,024 queries against K and V in two grid
     blocks of 8,192, 16 x 2 programs a head, and the lowered step's Mosaic
     calls carry that grid and those blocks (a score tile 1,024 wide, as
-    every other 16k shape has)."""
+    every other 16k shape has). dK/dV holds 1,024 keys against the queries
+    in two grid blocks of 8,192 as well (four of 4,096 until PR 58: the
+    queries' lse and delta are a lane row each, [1, 8192], where they were
+    [4096, 128]), and a kernel's own rows' lse and delta are [1, 1024]."""
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import attention_plan
@@ -95,32 +100,45 @@ def test_forward_and_dq_run_the_grid_the_plan_says(cell):
     grids = mosaic_grids(cell.lowered.as_text(),
                           ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))
     q, k, v = (1, 1024, 192), (1, 8192, 192), (1, 8192, 128)
-    o = row = (1, 1024, 128)
+    o, row = (1, 1024, 128), (1, 1, 1024)
     assert grids["_fwd_kernel"] == {((32, 16, 2), (q, k, v, o, row))}
     assert grids["_dq_kernel"] == {((32, 16, 2), (q, k, v, o, row, row, q))}
     (grid, blocks), = grids["_dkv_kernel"]
-    own, swept = plan.dkv.block, plan.dkv.swept
-    assert grid == (32, 16384 // own, 16384 // swept)
-    assert blocks[:3] == ((1, swept, 192), (1, own, 192), (1, own, 128))
+    assert (plan.dkv.block, plan.dkv.swept) == (1024, 8192)
+    assert grid == (32, 16, 2)
+    assert blocks[:6] == ((1, 8192, 192), (1, 1024, 192), (1, 1024, 128),
+                          (1, 8192, 128), (1, 1, 8192), (1, 1, 8192))
+
+
+def test_the_kernels_rows_are_four_bytes_a_position(cell):
+    """lse and delta, the flash kernels' two per-row float32 residuals, are
+    lane rows [32, 1, 16384] in the lowered step: the forward's second
+    result, the last two operands of dQ and of dK/dV, and no operand or
+    result of the three is [.., 16384, 128] float32 (512 bytes a row, 268 MB
+    a layer each, until PR 58). By the account a layer keeps 2 MB of lse:
+    `test_the_plan_says_what_the_blocks_keep` holds the base set."""
+    assert_flash_rows_are_lane_rows(cell.lowered.as_text(), 32)
 
 
 def test_the_plan_says_what_the_blocks_keep(cell):
     """`remat_plan` as the step was traced with a chip's 15.75 GiB: state
-    6.08 GB (weights, two moments, gradients), the base set 4.49 (a layer's
-    four streams 0.47, the output 0.13, the lane-padded lse 0.27, the
-    latent and shared key 0.02, the router's scores), the reserve 3.26 of
-    which the streams a hyper-connected block's backward holds are 1.17
-    (`_streams_hold`: two and a half values of their size since PR 56,
-    four while the channel branch was made again), 2.00 GB of room. First
-    what stands first: every latent block's q (0.20 GB a layer) and the
-    four expert layers' routing choices (6 MB); then every layer's channel
-    branch's output (`hc_channel_out`, 0.12 GB a layer, four matmuls an
-    element: the expert layers' forward no longer runs twice); then at a
-    matmul an element the four shared experts' up projections (0.07 a
-    layer) and not the dense layer's gate and up (0.60): PR 55's names and
-    five branch outputs, 0.59 GB more, 0.14 left."""
+    6.08 GB (weights, two moments, gradients), the base set 3.16 (a layer's
+    four streams 0.47, the output 0.13, lse 2 MB, the latent and shared key
+    0.02, the router's scores; 4.49 while lse was padded to 128 lanes, 0.27
+    GB a layer), the reserve 2.99 of which the streams a hyper-connected
+    block's backward holds are 1.17 (`_streams_hold`: two and a half values
+    of their size since PR 56, four while the channel branch was made
+    again; 3.26 with the largest block's padded lse), 3.60 GB of room.
+    First what stands first: every latent block's q (0.20 GB a layer) and
+    the four expert layers' routing choices (6 MB); then every layer's
+    channel branch's output (`hc_channel_out`, 0.12 GB a layer, four
+    matmuls an element: the expert layers' forward no longer runs twice);
+    then at a matmul an element the four shared experts' up projections
+    (0.07 a layer) and, since PR 58, the dense layer's gate and up (0.60):
+    every candidate the table has, 1.13 left."""
     plan = cell.plan
-    assert plan.extras == (("flash_attention_q", "hc_channel_out"),) + (
+    assert plan.extras == (
+        ("flash_attention_q", "hc_channel_out", "mlp_gate_up"),) + (
         ("flash_attention_q", "hc_channel_out", "moe_choice",
          "moe_shared_up"),) * 4
     assert plan.layers_extended == 5
@@ -128,11 +146,15 @@ def test_the_plan_says_what_the_blocks_keep(cell):
     branch = 16384 * 3584 * 2           # a branch's output, bfloat16
     choices = 3 * 16384 * 4 * 4 + 8 * 4     # k = 4, 8 held experts
     shared_up = 16384 * 2048 * 2
+    gate_up = 2 * 16384 * 9216 * 2
     assert plan.kept_extra_bytes == 5 * (q + branch) \
-        + 4 * (choices + shared_up) == 1_865_416_832
-    assert plan.base_bytes == 4_490_003_712
-    assert plan.reserve_bytes == 3_259_760_928
-    assert plan.bytes_left == 140_031_096 == (
+        + 4 * (choices + shared_up) + gate_up == 2_469_396_608
+    # five layers' lse at 4 bytes a row where it was 512
+    assert plan.base_bytes == 4_490_003_712 - 5 * 32 * 16384 * (512 - 4) \
+        == 3_158_312_192
+    assert plan.reserve_bytes == 3_259_760_928 - 32 * 16384 * (512 - 4) \
+        == 2_993_422_624
+    assert plan.bytes_left == 1_134_081_144 == (
         int(HBM_BYTES) - 2 ** 30 - plan.state_bytes - plan.base_bytes
         - plan.reserve_bytes - plan.kept_extra_bytes)
 
@@ -210,8 +232,15 @@ def test_step_fits_a_chip_by_xlas_own_total(step, record_property):
     print(f"xing4-train-1chip step: {nbytes / 1e9:.2f} GB "
           f"(arguments {mem.argument_size_in_bytes / 1e9:.2f}, "
           f"temporaries {mem.temp_size_in_bytes / 1e9:.2f})")
-    # XLA's own total: 15.57 GB (15,568,587,264), 1.25 GiB under the chip's
-    # 15.75 GiB; the plan's own sum is 15.70. With the base set alone 13.80
-    # GB; under PR 55's plan (the same names less the branch outputs) 15.46.
-    assert nbytes < 15.6e9
+    # XLA's own total: 14.95 GB (14,951,981,568), 1.83 GiB under the chip's
+    # 15.75 GiB; the plan's own sum is 14.70. Until PR 58, with lse and
+    # delta padded to 128 lanes and without the dense layer's gate and up:
+    # 15.57 (the base set alone 13.80; PR 55's plan 15.46).
+    assert nbytes < 15.0e9
     assert nbytes <= HBM_BYTES - 2 ** 30
+    # The five layers' code is emitted once and called (`_SHARED_CODE`,
+    # models/_training.py): 118,878,720 bytes of it. Left to the compiler's
+    # own rule this step, with 1.8 GiB to spare, reads 576,258,048, its
+    # executable 647 MB serialized where the parent's was 189, and the
+    # cell's programs no longer fit its machines' compile cache.
+    assert mem.generated_code_size_in_bytes < 150e6

@@ -311,8 +311,7 @@ class TestFlashKernelInterpret:
     # sub-blocks computed and 8 masked; 4 strips at head_dim 128, whose
     # scale is not a power of two; 3 strips; and, under a budget that
     # whole sequences do not fit, the swept side in blocks on the grid
-    # (forward and dQ 128 against 512, dK/dV 128 against 256) round the
-    # same loops.
+    # (all three 128 against 512) round the same loops.
     SHAPES = [((1, 2, 256, 64), None), ((1, 3, 1024, 64), None),
               ((1, 3, 512, 128), None), ((3, 1, 384, 64), None),
               ((1, 3, 1024, 64), 1_500_000)]
@@ -430,6 +429,38 @@ class TestFlashKernelInterpret:
         _, m2 = step2(state2, sharded)
         np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
                                    rtol=1e-3)
+
+    def test_the_row_residual_shards_like_q_under_the_dp_rule(self):
+        """Under `kernel_sharding` by the dp rule (batch over four chips)
+        forward and backward lower with lse as [batch, heads, 1, S], which
+        `_per_shard`'s one four-entry spec splits as it splits q, and give
+        what the unsharded kernels give."""
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.ops.attention import kernel_sharding
+        ks = jax.random.split(jax.random.PRNGKey(8), 4)
+        q, k, v, w = (jax.random.normal(kk, (4, 2, 256, 64), jnp.float32)
+                      for kk in ks)
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, True, None) * w)
+
+        def grad():     # (a trace of its own each: jit keys on the function)
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        want = grad()(q, k, v)
+        mesh = make_mesh(MeshConfig(dp=4, tp=2))
+        with kernel_sharding(mesh, P("dp", None, None, None)):
+            lowered = grad().lower(q, k, v)
+        text = lowered.as_text()
+        assert "sdy.manual_computation" in text
+        # a chip's share of the residual, as the forward leaves it and the
+        # backward takes it
+        assert text.count("tensor<1x2x1x256xf32>") >= 2
+        assert "256x128xf32" not in text
+        got = lowered.compile()(q, k, v)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5, rtol=1e-5)
 
     def test_kernel_sharding_refuses_a_sequence_split(self):
         from jax.sharding import PartitionSpec as P

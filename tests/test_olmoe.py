@@ -498,7 +498,7 @@ def test_a_block_keeps_the_named_values_and_nothing_else(dtype, short,
     assert kept == sorted([
         f"{short}[{b},{s},{3 * d}]",                    # attention_qkv
         heads, heads, heads, heads,     # flash_attention_q, _k, _v, _out
-        f"f32[{b},{cfg.n_heads},{s},128]",              # flash_attention_lse
+        f"f32[{b},{cfg.n_heads},1,{s}]",                # flash_attention_lse
         f"f32[{b * s},{cfg.n_experts}]",                # moe_probs
         f"{short}[{b * s * k},{d}]",                    # moe_xs
         f"{short}[{b * s * k},{cfg.d_expert}]",         # moe_gate
