@@ -105,3 +105,12 @@ from .glm4_moe_lite import (  # noqa: F401
     glm4_moe_lite_param_axes,
     make_glm4_moe_lite_train_step,
 )
+from .keye_vl2 import (  # noqa: F401
+    KeyeVL2Config,
+    keye_vl2_forward,
+    keye_vl2_init,
+    keye_vl2_loss,
+    keye_vl2_loss_and_counters,
+    keye_vl2_param_axes,
+    make_keye_vl2_train_step,
+)

@@ -17,7 +17,7 @@ a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
 runs it, what state it keeps in a cache, whether it is windowed, hands its
 keys and values on, or reads its place in the stack, and which branches
 its block has: a sequence mixer, the layer's channel mixer, or both. A
-new sequence mixer is its function and its row. The thirteen kinds:
+new sequence mixer is its function and its row. The fourteen kinds:
 
     ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
                    max_len, head_dim]}
@@ -50,6 +50,16 @@ new sequence mixer is its function and its row. The thirteen kinds:
                    max_len, latent width] the normed latent, "k_rope":
                    [batch, max_len, rope width] the rotated key part every
                    head shares}: no per-head K or V is cached
+    SPARSE_ATTENTION
+                   softmax attention over the `Decoder.sparse_topk` keys a
+                   query's own lightning indexer scores highest (DeepSeek
+                   sparse attention, ops/sparse_index.py); ATTENTION's
+                   state and {"k_index": [batch, max_len, index width] the
+                   indexer's rotated keys}. The one sequence mixer that
+                   hands `stats` out of the stack, its indexer's own loss
+                   term `index_loss` and `selected_keys_mean`, in its
+                   layer's entry of `decoder_hidden`'s list beside the
+                   channel mixer's
 
 Every leaf of a layer's state has the batch first: that is the table's one
 rule (models.generate.make_continuous_fns cuts a slot out of axis 0 of
@@ -88,11 +98,11 @@ and outputs, and their gradients arrive from every reader.
     empty_cache         each layer's state, by its row
     hyper_connection    a hyper-connected branch's three sets of
                         coefficients, from the streams and its weights
-      attention | latent_attention | mamba2 | mamba1 | gated_delta |
-      short_conv | gmu | diff_attention
+      attention | sparse_attention | latent_attention | mamba2 | mamba1 |
+      gated_delta | short_conv | gmu | diff_attention
                         the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window, ...]) -> (y,
-                        new cache[, shared]): attention is the flash
+                        new cache[, shared or stats]): attention is the flash
                         kernel over the whole sequence with no cache, with
                         one a write into it and a masked read of it;
                         latent_attention the same, its per-head keys and
@@ -126,8 +136,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.extend.core import Var
 
 from ..ops.attention import (DEFAULT_MASK_VALUE, a_chip_alone,
-                             flash_attention, step_memory_given,
-                             step_sharding)
+                             attention_and_lse, flash_attention,
+                             step_memory_given, step_sharding)
 from ..ops.gated_delta import gated_delta_rule
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
                           gated_rms_norm, head_rms_norm, head_rms_norm_gated,
@@ -137,6 +147,8 @@ from ..ops.loss import chip_views, lookup
 from ..ops.loss import working_set_bytes as loss_working_set_bytes
 from ..ops.selective_scan import selective_scan
 from ..ops.short_conv import gated_short_conv
+from ..ops.sparse_index import (index_scores, indexer_loss, kth_largest,
+                                select)
 from ..ops.ssm_scan import ssm_scan
 from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
                             held_moe_layer)
@@ -145,10 +157,10 @@ from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
 # The kinds of layer: the keys of MIXERS, below the mixers.
 (ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
  DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV,
- LATENT_ATTENTION) = (
+ LATENT_ATTENTION, SPARSE_ATTENTION) = (
     "attention", "mamba2", "mamba1", "gated_delta", "gmu", "diff_windowed",
     "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts",
-    "short_conv", "latent_attention")
+    "short_conv", "latent_attention", "sparse_attention")
 
 
 class HyperConnections(NamedTuple):
@@ -198,6 +210,9 @@ class Decoder(NamedTuple):
     # carries `hyper.streams` of them, a tuple of so many [b, L, d], from
     # the embedding (each a copy of it) to the final norm (of their sum).
     hyper: Optional[HyperConnections] = None
+    # The keys a SPARSE_ATTENTION layer's query may name (its indexer's
+    # heads and widths are read off its weights).
+    sparse_topk: int = 0
 
 
 def gelu_mlp(y, layer):
@@ -248,23 +263,24 @@ def held_routed_experts(y, layer, experts_per_token: int, first: int,
 
 def held_gated_experts(y, layer, experts_per_token: int, first: int,
                        routed_scale: float, weight_eps: float,
-                       bias_rounds: int = 0):
+                       bias_rounds: int = 0, softmax: bool = False):
     """One chip's share of top-k SwiGLU experts (those from `first` on, as
     many as the layer holds; `expert_gate_up` is each one's gate and up
     matrices side by side) and, where the layer holds one, the shared
     SwiGLU expert of the same form (`shared_gate_up`, `shared_down`:
     DeepSeek-V3's; LFM2 has none), over the flattened tokens, the k
     weights over their sum + `weight_eps`, the selection bias moved
-    `bias_rounds` rounds on the tokens' scores first; `stats` are
+    `bias_rounds` rounds on the tokens' scores first, or with `softmax` a
+    softmax router with no bias, the layer holding none; `stats` are
     parallel.moe.held_moe_layer's, one layer's."""
     b, s, d = y.shape
     out, stats = held_moe_layer(
-        y.reshape(b * s, d), layer["router"], layer["router_bias"],
+        y.reshape(b * s, d), layer["router"], layer.get("router_bias"),
         layer["expert_gate_up"], layer["expert_down"],
         layer.get("shared_gate_up"), layer.get("shared_down"),
         experts_per_token=experts_per_token, first=first,
         routed_scale=routed_scale, bias_rounds=bias_rounds, gated=True,
-        weight_eps=weight_eps)
+        weight_eps=weight_eps, **({"softmax": True} if softmax else {}))
     return out.reshape(b, s, d), stats
 
 
@@ -312,48 +328,59 @@ def _write_cache(cache, k, v, sp):
     return {"k": k_cache, "v": v_cache}
 
 
-def _attend_cache(q, k_all, v_all, sp, sm_scale, window=None):
+def _cache_mask(L: int, max_len: int, sp, window=None):
+    """Which of `max_len` cached positions the queries at sp + [0, L) see,
+    each up to its own (and, under a `window`, no further back than window
+    - 1): [1 or b, L, max_len] bool, `sp` a scalar or one position a
+    row."""
+    q_iota = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 0)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 1)
+    q_pos = (sp[:, None, None] if sp.ndim == 1 else sp) + q_iota[None]
+    mask = k_pos[None] <= q_pos
+    if window is not None:
+        mask &= k_pos[None] > q_pos - window
+    return mask
+
+
+def _attend_cache(q, k_all, v_all, sp, sm_scale, window=None, selected=None):
     """q [b, h, L, hd] at positions sp + [0, L) over k_all, v_all
     [b, h, max_len, .], each query up to its own position (and, under a
-    `window`, no further back than window - 1): plain masked softmax."""
+    `window`, no further back than window - 1; of those, where `selected`
+    [b, L, max_len] bool is given, the ones it names): plain masked
+    softmax."""
     L, hd = q.shape[-2:]
     max_len = k_all.shape[-2]
     scale = hd ** -0.5 if sm_scale is None else sm_scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k_all.astype(jnp.float32)) * scale
-    q_iota = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (L, max_len), 1)
-    if sp.ndim == 1:
-        q_pos = sp[:, None, None] + q_iota[None]         # (b, L, max)
-        k_pos, lead = k_pos[None], (slice(None), None)   # (b,1,L,max)
-    else:
-        q_pos, lead = sp + q_iota, (None, None)          # (1,1,L,max)
-    mask = k_pos <= q_pos
-    if window is not None:
-        mask &= k_pos > q_pos - window
-    s = jnp.where(mask[lead], s, DEFAULT_MASK_VALUE)
+    mask = _cache_mask(L, max_len, sp, window)
+    if selected is not None:
+        mask = mask & selected
+    s = jnp.where(mask[:, None], s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v_all.dtype), v_all)
 
 
-def _cached_attention(q, k, v, cache, sp, group: int, sm_scale):
+def _cached_attention(q, k, v, cache, sp, group: int, sm_scale,
+                      selected=None):
     """Write k, v [b, kvh, L, hd] into the cache at positions sp + [0, L)
     and attend q [b, h, L, hd] over the cache up to each query's own
-    position. Only the write and the mask specialize on whether `sp` is
-    a scalar or one position a row."""
+    position (of those, the `selected` ones where given). Only the write
+    and the mask specialize on whether `sp` is a scalar or one position a
+    row."""
     new_cache = _write_cache(cache, k, v, sp)
     attn = _attend_cache(q, _across_group(new_cache["k"], group),
-                         _across_group(new_cache["v"], group), sp, sm_scale)
+                         _across_group(new_cache["v"], group), sp, sm_scale,
+                         selected=selected)
     return attn, new_cache
 
 
-def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
-    """Causal self-attention of x [b, L, d], from the input norm to the
-    output projection. With a cache, `start_pos` is the absolute offset
-    of x's positions — a scalar (all rows aligned: prefill / single-stream
-    decode) or a [b] vector (continuous batching: every row decodes at its
-    own position). One implementation serves training, prefill and decode
-    so the formulas can't diverge. Returns (y, new_cache or None)."""
+def _qkv_heads(x, layer, dec: Decoder, cache, start_pos):
+    """What `attention` and `sparse_attention` share, from the input norm
+    to the rotated heads: (y the normed input [b, L, d], q [b, h, L, hd],
+    k and v [b, kvh, L, hd], sp the cache's start position as an array or
+    None, the rows' absolute positions or None: rope then counts from 0
+    itself)."""
     b, L, d = x.shape
     h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
 
@@ -386,9 +413,20 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
         if dec.rope_base is None:
             return t
         return rope(t, base=dec.rope_base, positions=positions)
-    q = rotate(heads(q, h))
-    k = rotate(heads(k, kvh))
-    v = heads(v, kvh)
+    return y, rotate(heads(q, h)), rotate(heads(k, kvh)), heads(v, kvh), \
+        sp, positions
+
+
+def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """Causal self-attention of x [b, L, d], from the input norm to the
+    output projection. With a cache, `start_pos` is the absolute offset
+    of x's positions — a scalar (all rows aligned: prefill / single-stream
+    decode) or a [b] vector (continuous batching: every row decodes at its
+    own position). One implementation serves training, prefill and decode
+    so the formulas can't diverge. Returns (y, new_cache or None)."""
+    b, L, d = x.shape
+    h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
+    _, q, k, v, sp, _ = _qkv_heads(x, layer, dec, cache, start_pos)
     if cache is None:
         attn = flash_attention(q, _across_group(k, h // kvh),
                                _across_group(v, h // kvh), True,
@@ -399,6 +437,108 @@ def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
                                             dec.sm_scale)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * hd)
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+# The eps of an indexer's key norm (a LayerNorm of gain and bias).
+INDEX_NORM_EPS = 1e-6
+
+
+def _detached(y):
+    """What an indexer reads of the block's normed input: its value alone."""
+    return jax.lax.stop_gradient(y)
+
+
+def _index_heads(y, layer, dec: Decoder, positions):
+    """A lightning indexer's three projections of the normed input y [b, L,
+    d], which its caller has DETACHED (`_detached`): q_I [b, H, L, D] = y `index_wq`, rotated; k_I [b, L, D] =
+    layernorm(y `index_wk`; `index_k_norm`, `index_k_norm_b`), rotated; w
+    [b, L, H] float32 = y `index_ww` / sqrt(H D). Rotary over all D
+    columns at the model's base; H and D are read off the weights."""
+    b, L, _ = y.shape
+    H, D = layer["index_ww"].shape[1], layer["index_wk"].shape[1]
+
+    def rotate(t):
+        if dec.rope_base is None:
+            return t
+        return rope(t, base=dec.rope_base, positions=positions)
+
+    with jax.named_scope("sparse_index_proj"):
+        q = rotate(jnp.einsum("bsd,de->bse", y, layer["index_wq"]).reshape(
+            b, L, H, D).transpose(0, 2, 1, 3))
+        k = layer_norm(jnp.einsum("bsd,de->bse", y, layer["index_wk"]),
+                       layer["index_k_norm"], layer["index_k_norm_b"],
+                       INDEX_NORM_EPS)
+        k = rotate(k[:, None])[:, 0]
+        w = jnp.einsum("bsd,dh->bsh", y, layer["index_ww"],
+                       preferred_element_type=jnp.float32) * (H * D) ** -0.5
+    return q, k, w
+
+
+def _cache_index_scores(q_index, k_index, w):
+    """The index scores of q_index [b, H, L, D] and w [b, L, H] against
+    EVERY cached key k_index [b, max_len, D] -> [b, L, max_len] float32:
+    the plain form, for a cache's forward (ops.sparse_index has a whole
+    sequence's)."""
+    s = jnp.einsum("bhld,bmd->bhlm", q_index, k_index,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("blh,bhlm->blm", w, jnp.maximum(s, 0.0))
+
+
+def sparse_attention(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """`attention` over a learned sparse subset of positions (DeepSeek
+    sparse attention), from the input norm to the output projection: the
+    same projections, head norms and rotary, then each query sees the
+    `dec.sparse_topk` keys at or before it that its layer's lightning
+    indexer scores highest (ops/sparse_index.py has the equations), every
+    head the same ones, a key head under its group of query heads. The
+    indexer reads the normed input DETACHED and its selection has no
+    gradient: the cross entropy reaches none of its weights, and its own
+    loss L_I (the KL from the heads' mean attention probability over the
+    selected keys, itself detached, to the softmax of the index scores
+    there) reaches nothing else. Training: `index_scores`, `select`, the
+    flash kernels under the selection, `indexer_loss`. With a cache the
+    rotated index key of x's positions is written beside k and v, every
+    cached position is scored in the plain form and the read is masked by
+    the selection. Returns (y, new_cache or None, {`index_loss`: L_I (0
+    with a cache), `selected_keys_mean`: the keys a query saw})."""
+    b, L, d = x.shape
+    h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
+    y, q, k, v, sp, positions = _qkv_heads(x, layer, dec, cache, start_pos)
+    q_index, k_index, w = _index_heads(_detached(y), layer, dec, positions)
+    # What a rematerialised block keeps of the selection's making, beside
+    # the selection itself: the rotated index key (`indexer_loss`'s rule
+    # keeps its own three gradients).
+    k_index = checkpoint_name(k_index, "sparse_index_k")
+    if cache is None:
+        # Read, not differentiated: `indexer_loss` is the indexer's rule.
+        scores = index_scores(*jax.lax.stop_gradient((q_index, k_index, w)))
+        selected = checkpoint_name(select(scores, dec.sparse_topk)[0],
+                                   "sparse_selected")
+        attn, lse = attention_and_lse(
+            q, _across_group(k, h // kvh), _across_group(v, h // kvh),
+            dec.sm_scale, selected)
+        scale = hd ** -0.5 if dec.sm_scale is None else dec.sm_scale
+        # (q, k and lse are read: the rule hands them no cotangent)
+        index_loss = indexer_loss(q_index, k_index, w, scores, selected, q,
+                                  k, lse, scale)
+        seen = jnp.sum(selected, dtype=jnp.float32) / (b * L)
+        new_cache = None
+    else:
+        k_index = _write_rows(cache["k_index"], k_index, sp)
+        with jax.named_scope("sparse_select"):
+            causal = _cache_mask(L, k_index.shape[1], sp)
+            scores = jnp.where(
+                causal, _cache_index_scores(q_index, k_index, w), -jnp.inf)
+            selected = causal & (
+                scores >= kth_largest(scores, dec.sparse_topk)[..., None])
+        attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh,
+                                            dec.sm_scale, selected)
+        new_cache["k_index"] = k_index
+        index_loss = jnp.zeros((), jnp.float32)
+        seen = jnp.mean(jnp.sum(selected, axis=-1, dtype=jnp.float32))
+    attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * hd)
+    return (jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache,
+            {"index_loss": index_loss, "selected_keys_mean": seen})
 
 
 # The eps of the norms over a latent (the keys' and the queries'): the
@@ -794,6 +934,14 @@ def _kv_state(dec: Decoder, layer, batch, max_len, dtype):
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _sparse_state(dec: Decoder, layer, batch, max_len, dtype):
+    """ATTENTION's state and the indexer's rotated keys up to `max_len`,
+    one head of them: what a new query is scored against."""
+    return {**_kv_state(dec, layer, batch, max_len, dtype),
+            "k_index": jnp.zeros(
+                (batch, max_len, layer["index_wk"].shape[1]), dtype)}
+
+
 def _latent_state(dec: Decoder, layer, batch, max_len, dtype):
     """A latent-attention layer's normed latents and rotated shared keys up
     to `max_len`: no head has a key or a value of its own here."""
@@ -841,6 +989,11 @@ def _latent_attention(x, layer, dec, cache, start_pos, shared, index, window):
     return (*latent_attention(x, layer, dec, cache, start_pos), shared)
 
 
+def _sparse_attention(x, layer, dec, cache, start_pos, shared, index, window):
+    y, new_cache, stats = sparse_attention(x, layer, dec, cache, start_pos)
+    return y, new_cache, shared, stats
+
+
 def _mamba2(x, layer, dec, cache, start_pos, shared, index, window):
     return (*mamba2(x, layer, dec, cache, start_pos), shared)
 
@@ -866,7 +1019,10 @@ class Mixer(NamedTuple):
     """A row of MIXERS: a kind of layer's sequence mixer, its state, and
     the branches of its block."""
     # (x, layer, dec, cache, start_pos, shared, index, window)
-    #     -> (y, new cache, shared); None: the block has no sequence branch
+    #     -> (y, new cache, shared[, stats]); None: the block has no
+    # sequence branch. `stats`, where a mixer hands any (a loss term of its
+    # own, counters), leave the stack beside the channel mixer's, in the
+    # layer's one entry of `decoder_hidden`'s list.
     apply: Optional[Callable]
     # (dec, layer, batch, max_len, dtype) -> {name: [batch, ...]}
     state: Callable
@@ -948,6 +1104,7 @@ MIXERS: Dict[str, Mixer] = {
     EXPERTS: Mixer(None, _no_state),
     SHORT_CONV: Mixer(_short_conv, _short_conv_state),
     LATENT_ATTENTION: Mixer(_latent_attention, _latent_state),
+    SPARSE_ATTENTION: Mixer(_sparse_attention, _sparse_state),
 }
 
 
@@ -1064,12 +1221,32 @@ keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
 # losses beside 9.3 GB of state leave no room: q is the first name of
 # KEPT_WHERE_IT_FITS instead, kept layer by layer where the step has the
 # room (Xing4.0's cell: in all five layers, as before it was a candidate).
+# A sparse-attention layer keeps, beside those seventeen, what its
+# selection made: the selection itself (`sparse_selected`, [T, T] int8: 268
+# MB a layer at 16,384 tokens, where scoring every causal pair again and
+# selecting again is a [T, T] float32 and 32 counts of it), and what its
+# indexer's rule left for the backward pass, which is that pass whole at a
+# cotangent of one (`sparse_index_grads`, ops/sparse_index.py: 34 MB), so a
+# rematerialised block runs neither indexer kernel and no target pass
+# again. `sparse_index_k`, the rotated index key, is named for a plan that
+# would make the scores again from it and is kept by none. Nor does the
+# block keep q, k and v among what EVERY such block keeps: they are two
+# products, two head norms and a rotary away from the block's input (0.3
+# TFLOP a layer at 32 | 4 heads of 128), and k and v as the kernels take
+# them are copies across a group of eight, 134 MB each a layer at 16,384
+# tokens where six selections already hold 1.6 GB; q stays a candidate of
+# KEPT_WHERE_IT_FITS, as a latent layer's is.
 KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
     LATENT_ATTENTION: tuple(
         name for name in KEPT_UNDER_REMAT
         if name not in ("flash_attention_k", "flash_attention_v",
                         "flash_attention_q"))
-    + ("mla_latent", "mla_k_rope")}
+    + ("mla_latent", "mla_k_rope"),
+    SPARSE_ATTENTION: tuple(
+        name for name in KEPT_UNDER_REMAT
+        if name not in ("flash_attention_k", "flash_attention_v",
+                        "flash_attention_q"))
+    + ("sparse_selected", "sparse_index_grads")}
 
 
 def _kept(kind: str) -> Tuple[str, ...]:
@@ -1399,7 +1576,7 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
         with jax.named_scope(MIXER_SCOPES[kind]):
             u, write, counters = _streams_read(x, layer.get("hc_mixer"),
                                                dec.hyper)
-            y, new_cache, shared = row.apply(
+            y, new_cache, shared, *mixer_stats = row.apply(
                 u, layer, dec, cache, start_pos, shared, index, window)
             x = write(_scaled(_norm_if_held(y, layer, "post_attention", eps),
                               dec.residual_scale))
@@ -1412,6 +1589,8 @@ def _block(x, layer, cache, start_pos, shared: Shared = Shared(), *,
             out = _norm_if_held(out, layer, "post_feedforward", eps)
             x = write(_scaled(out, dec.residual_scale))
             counted.append(counters)
+    if row.apply is not None and mixer_stats:
+        stats = {**mixer_stats[0], **(stats or {})}
     return x, _with_hc_counters(stats, counted), new_cache, shared
 
 
@@ -1492,6 +1671,25 @@ def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
     return 3 * tokens * dec.n_heads * (n + r + vd) * itemsize
 
 
+def _selection_holds(kind: str, tokens: int, x) -> int:
+    """What a sparse-attention block holds that no name shows, in [T, T]
+    buffers a sequence: sixteen bytes a (query, key) pair. The index scores
+    and their gradient, float32 both, are alive at once inside
+    `indexer_loss`'s forward rule, eight; the other eight are a
+    calibration, not a count (the target's chunks of [heads, 256, T]
+    scores, the backward kernel's partial sums of dk_I, the selection
+    transposed for dK/dV): XLA's account of Keye-VL-2.0's six-layer step
+    compiled for a v5e, total - state - base set - what the plan keeps,
+    reads 5.91 GB where the block's named values come to 1.6 and these to
+    4.29 (PERF.md section 6, PR 60). They are alive in a block's FORWARD
+    pass and are counted with its backward set as if they met: from
+    above."""
+    if kind != SPARSE_ATTENTION:
+        return 0
+    batch = jax.tree.leaves(x)[0].shape[0]
+    return 16 * batch * (tokens // batch) ** 2
+
+
 def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
              losses: int = 1) -> int:
     """What a chip holds at a step's peak beside its state and what its
@@ -1500,7 +1698,7 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
     of the block that has a name in either table, alive at once while it
     is differentiated, and what no name shows (`unnamed`: what its channel
     mixer's own rule says it holds, `_backward_holds`, and the fitted
-    `_streams_hold` and `_latent_holds`). On one chip the two do
+    `_streams_hold`, `_latent_holds` and `_selection_holds`). On one chip the two do
     not meet and the largest block counts: the larger of the loss and it;
     where the step has further `losses` over the one head (a prediction
     module's), a working set each beside either: the stack's loss leaves
@@ -1526,7 +1724,8 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
     def unnamed(key, layer) -> int:
         rule = _backward_holds(key[1], tokens, layer)
         fitted = _streams_hold(x, layer) \
-            + _latent_holds(key[0], tokens, layer, dec)
+            + _latent_holds(key[0], tokens, layer, dec) \
+            + _selection_holds(key[0], tokens, x)
         # The cotangent of the block's output waits while the block is
         # differentiated. The two fitted terms were read off XLA's totals
         # with it among them, and a block of named values alone is counted

@@ -61,10 +61,11 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # ---------------------------------------------------------------------------
 def mha_reference(q, k, v, causal: bool = True,
                   sm_scale: Optional[float] = None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, selected=None):
     """Plain XLA attention; numerically the ground truth for the kernel.
     With a `window` a query sees itself and the window - 1 positions
-    before it."""
+    before it; with `selected` [batch, seq_q, seq_k] (nonzero: seen) only
+    the keys its row of it names, of those the causal mask leaves."""
     *_, seq_q, head_dim = q.shape
     seq_k = k.shape[-2]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(head_dim)
@@ -78,6 +79,9 @@ def mha_reference(q, k, v, causal: bool = True,
             mask &= ~jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool),
                               k=seq_k - seq_q - window)
         logits = jnp.where(mask, logits, DEFAULT_MASK_VALUE)
+    if selected is not None:
+        logits = jnp.where(selected[:, None] != 0, logits,
+                           DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -248,6 +252,29 @@ class AttentionPlan:
     dq: KernelPlan
     dkv: KernelPlan
     window: Optional[int] = None
+    # Under a selection (`flash_attention(..., selected=)`): how many keys
+    # a query may name. The kernels then work the causal triangle as
+    # without one, a tile of the selection applied after the causal mask.
+    selected: Optional[int] = None
+
+    @property
+    def executed_pairs(self) -> int:
+        """(query, key) pairs a head's forward kernel computes: every
+        computed sub-block whole, masked or not."""
+        return self.fwd.computed * self.fwd.sub ** 2
+
+    @property
+    def required_pairs(self) -> int:
+        """Pairs a head's softmax runs over: the triangle (the band under
+        a window), and under a selection a query's min(t + 1, selected)."""
+        n, reach = self.seq_len, self.seq_len
+        if not self.causal:
+            return n * n
+        if self.window is not None:
+            reach = min(reach, self.window)
+        if self.selected is not None:
+            reach = min(reach, self.selected)
+        return reach * (reach + 1) // 2 + (n - reach) * reach
 
     @property
     def executed_share(self) -> float:
@@ -342,18 +369,21 @@ def _count(seq_len: int, block: int, swept: int, sub: int, causal: bool,
 
 def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
                 itemsize: int, v_dim: Optional[int] = None,
-                tile: Optional[int] = None) -> int:
+                tile: Optional[int] = None, selected: bool = False) -> int:
     """An upper estimate of one program's VMEM: every operand and result
     block twice (the pipeline's two buffers), float32 scratch, and three
     float32 tiles (scores, probabilities, their gradient) of `tile`
     elements, block x block where None. v, o and their gradients are
-    `v_dim` wide (head_dim where None)."""
+    `v_dim` wide (head_dim where None). Under a selection its block x swept
+    tile of int8 comes in beside them, twice too."""
     v_dim = head_dim if v_dim is None else v_dim
     col = 128 * 4       # a position of an [n, 1] f32 column: lane-padded
     row = 8 * 4         # of a [1, n] f32 row: it fills eight sublanes
     own, other = block * head_dim * itemsize, swept * head_dim * itemsize
     own_v, other_v = block * v_dim * itemsize, swept * v_dim * itemsize
     tiles = 3 * (block * block if tile is None else tile) * 4
+    if selected:
+        tiles += 2 * block * swept
     if kernel == "fwd":     # q | k, v -> o, lse; acc, m, l
         return (2 * (own + other + other_v) + 2 * (own_v + block * row)
                 + block * (v_dim * 4 + 2 * col) + tiles)
@@ -367,7 +397,8 @@ def _vmem_bytes(kernel: str, block: int, swept: int, head_dim: int,
 
 def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
                    dtype=jnp.bfloat16, window: Optional[int] = None,
-                   v_dim: Optional[int] = None) -> AttentionPlan:
+                   v_dim: Optional[int] = None,
+                   selected: Optional[int] = None) -> AttentionPlan:
     """The tiling `flash_attention` runs a [.., seq_len, head_dim] call at,
     and the sub-blocks a head computes, masks and skips in each kernel.
 
@@ -430,9 +461,22 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
     across two swept blocks, or would be larger than a causal block's
     (`_strip_tile`), goes in pieces: a sub-block for each masked edge,
     the sub-blocks between in chunks of up to 1,024 positions. `v_dim` is
-    the width of v and of the output where it is not q's and k's."""
+    the width of v and of the output where it is not q's and k's.
+
+    Under a selection (`selected`: the keys a query may name; causal, no
+    window) the kernels compute what they compute without one, every tile
+    of the triangle, and mask each by its tile of the selection [seq, seq]
+    int8 after the causal mask: a program's own block x swept tile of it
+    is one more operand in VMEM (16 MiB twice at 1,024 x 16,384), so the
+    swept side comes in smaller grid blocks; `executed_pairs` and
+    `required_pairs` say what that costs (at 16,384 positions and 2,048
+    keys a query 4.3 pairs computed for one the softmax runs over)."""
     if window is not None and (not causal or window < 1):
         raise ValueError("a window is causal and at least 1 wide")
+    if selected is not None and (not causal or window is not None
+                                 or selected < 1):
+        raise ValueError("a selection is causal, unwindowed and at least "
+                         "one key a query")
     if seq_len < 128 or seq_len % 128:
         raise ValueError(
             f"the kernels tile sequences in multiples of 128, not {seq_len}")
@@ -450,7 +494,7 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
                 tile = None if window is None else sub * (
                     _strip_tile(sub, window, swept) or min(_MAX_BLOCK, swept))
                 need = _vmem_bytes(kernel, block, swept, head_dim, itemsize,
-                                   v_dim, tile)
+                                   v_dim, tile, selected is not None)
                 if need <= VMEM_BUDGET:
                     return KernelPlan(block, swept, sub, need, *_count(
                         seq_len, block, swept, sub, causal,
@@ -460,7 +504,8 @@ def attention_plan(seq_len: int, head_dim: int, causal: bool = True,
             f"{VMEM_BUDGET} bytes of VMEM")
 
     return AttentionPlan(seq_len, head_dim, causal, VMEM_BUDGET,
-                         plan("fwd"), plan("dq"), plan("dkv"), window)
+                         plan("fwd"), plan("dq"), plan("dkv"), window,
+                         selected)
 
 
 def _scale_is_exact(sm_scale: float) -> bool:
@@ -639,6 +684,15 @@ def _mask_diagonal(s, sub: int, mirrored: bool):
     return jnp.concatenate([p for p in parts if p.shape[1]], axis=1)
 
 
+def _select(s, sel_ref, rows, cols):
+    """A tile of scores under its tile of the selection (int8, nonzero:
+    seen), where the call has one; after the causal mask."""
+    if sel_ref is None:
+        return s
+    seen = sel_ref[0, rows, cols].astype(jnp.int32) != 0
+    return jnp.where(seen, s, DEFAULT_MASK_VALUE)
+
+
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
 
@@ -671,9 +725,10 @@ def _lane_row(col):
 # Forward kernel: grid (bh, q blocks, k blocks). Float32 accumulators ride
 # VMEM scratch across a program's tiles and the K blocks of the grid.
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float,
                 causal: bool, sub: int, grid: tuple, save_lse: bool,
-                window: Optional[int] = None):
+                window: Optional[int] = None, selected: bool = False):
+    sel_ref, o_ref, *rest = rest if selected else (None, *rest)
     if save_lse:
         lse_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -710,7 +765,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
         s = _dot(q, k_ref[0, cols, :], _NT)
         if not fold:
             s = s * sm_scale
-        return _masked(s, mask, sub, window, False)
+        return _select(_masked(s, mask, sub, window, False), sel_ref, rows,
+                       cols)
 
     def step(rows, cols, mask):
         s = scores(rows, cols, mask)
@@ -802,11 +858,24 @@ def _compiler_params():
 # (12 x 3 kernels of 8 unrolled strips cost gpt2-small's step 4 s of
 # set-up otherwise). Only the pallas_call is inside: what XLA can fuse
 # with its neighbours (reshapes, delta) stays in the caller's program.
+def _selection_spec(heads: int, own_len: int, swept_len: int, swept_map):
+    """The BlockSpec of a program's tile of the selection [batch, seq,
+    seq] int8 (dK/dV's: of its transpose), every head of a batch row
+    reading the same one: the own block's rows, the swept block's columns
+    by the swept side's own (clamped) index map."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.BlockSpec(
+        (1, own_len, swept_len),
+        lambda b, i, j: (b // heads, i, swept_map(b, i, j)[1]),
+        memory_space=pltpu.VMEM)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sm_scale", "plan", "save_lse", "window"))
-def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
+    "causal", "sm_scale", "plan", "save_lse", "window", "heads"))
+def _forward_call(qf, kf, vf, sel=None, *, causal: bool, sm_scale: float,
                   plan: KernelPlan, save_lse: bool,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, heads: int = 1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -816,7 +885,8 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
     grid = (bh, seq_len // block, seq_len // swept)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, sub=plan.sub,
-        grid=grid, save_lse=save_lse, window=window)
+        grid=grid, save_lse=save_lse, window=window,
+        selected=sel is not None)
     own = lambda b, i, j: (b, i, 0)  # noqa: E731
     other = _swept_index(causal, block, swept, False, window)
     q_spec = pl.BlockSpec((1, block, head_dim), own, memory_space=pltpu.VMEM)
@@ -836,10 +906,14 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
                          memory_space=pltpu.VMEM))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32))
+    operands, in_specs = (qf, kf, vf), [q_spec, k_spec, v_spec]
+    if sel is not None:
+        operands += (sel,)
+        in_specs.append(_selection_spec(heads, block, swept, other))
     fwd = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, k_spec, v_spec],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -854,18 +928,20 @@ def _forward_call(qf, kf, vf, *, causal: bool, sm_scale: float,
     # the HLO instruction, which is what a device trace shows, and leaves
     # kernel_name (_fwd_kernel) as it is (util/profiling.py DEVICE_SCOPES).
     with jax.named_scope("flash_attention_fwd"):
-        return fwd(qf, kf, vf)
+        return fwd(*operands)
 
 
 def _flash_forward(q, k, v, causal: bool, sm_scale: float,
                    plan: KernelPlan, save_lse: bool = True,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None, sel=None):
     batch, heads, seq_len, head_dim = q.shape
     flat = (batch * heads, seq_len, head_dim)
+    given = {} if sel is None else {"sel": sel, "heads": heads}
     result = _forward_call(
         q.reshape(flat), k.reshape(flat),
         v.reshape(batch * heads, seq_len, v.shape[-1]), causal=causal,
-        sm_scale=sm_scale, plan=plan, save_lse=save_lse, window=window)
+        sm_scale=sm_scale, plan=plan, save_lse=save_lse, window=window,
+        **given)
     out = result[0].reshape(v.shape)
     # lse is a lane row a head, [batch, heads, 1, seq]: q's leading axes
     # and rank, so it shards like q (see kernel_sharding).
@@ -876,10 +952,13 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float,
 # ---------------------------------------------------------------------------
 # Backward kernels (flash-2): recompute P per tile from saved lse.
 # ---------------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, lse_scr, delta_scr, *, sm_scale: float, causal: bool,
-               sub: int, grid: tuple, window: Optional[int] = None):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+               sm_scale: float, causal: bool, sub: int, grid: tuple,
+               window: Optional[int] = None, selected: bool = False):
     from jax.experimental import pallas as pl
+
+    sel_ref, dq_ref, dq_scr, lse_scr, delta_scr = \
+        rest if selected else (None, *rest)
 
     block, swept = q_ref.shape[1], k_ref.shape[1]
     qi, ki = _block_id(1, grid[1]), _block_id(2, grid[2])
@@ -901,7 +980,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = _dot(q, k, _NT)
         if not fold:
             s = s * sm_scale
-        s = _masked(s, on_diagonal, sub, window, False)
+        s = _select(_masked(s, on_diagonal, sub, window, False), sel_ref,
+                    rows, cols)
         p = jnp.exp(s - lse_scr[rows, :])
         dp = _dot(do_ref[0, rows, :], v_ref[0, cols, :], _NT)
         ds = p * (dp - delta_scr[rows, :])
@@ -924,11 +1004,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                causal: bool, sub: int, grid: tuple,
-                window: Optional[int] = None):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                sm_scale: float, causal: bool, sub: int, grid: tuple,
+                window: Optional[int] = None, selected: bool = False):
     from jax.experimental import pallas as pl
+
+    # Under a selection its tile comes transposed, rows keys, as the
+    # scores here are.
+    sel_ref, dk_ref, dv_ref, dk_scr, dv_scr = \
+        rest if selected else (None, *rest)
 
     block, swept = k_ref.shape[1], q_ref.shape[1]
     ki, qi = _block_id(1, grid[1]), _block_id(2, grid[2])
@@ -948,7 +1032,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s_t = _dot(k, q, _NT)                     # rows keys, columns queries
         if not fold:
             s_t = s_t * sm_scale
-        s_t = _masked(s_t, on_diagonal, sub, window, True)
+        s_t = _select(_masked(s_t, on_diagonal, sub, window, True), sel_ref,
+                      rows, cols)
         p_t = jnp.exp(s_t - lse_ref[0, :, cols])
         dv_scr[rows, :] += _dot(p_t.astype(do.dtype), do, _NN)
         dp_t = _dot(v_ref[0, rows, :], do, _NT)
@@ -975,12 +1060,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
                      sm_scale: float, plan: KernelPlan,
-                     window: Optional[int] = None):
+                     window: Optional[int] = None, heads: int = 0):
     """The pallas_call of a backward kernel on a grid (bh, own blocks,
     swept blocks): Q, dO and their stretch of the lse and delta rows on one
     side, K and V on the other, `q` [bh, seq, head_dim] and `v` [bh, seq,
     v_dim] giving the widths; dQ like q, or (`mirrored`) dK like q and dV
-    like v."""
+    like v. `heads` (a selection's call): a seventh operand, the selection
+    [batch, seq, seq] int8, dK/dV's transposed."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1005,11 +1091,16 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
     if not mirrored:    # dQ's own rows' lse and delta, as columns
         scratch += [pltpu.VMEM((plan.block, 1), jnp.float32)] * 2
     grid = (bh, seq_len // plan.block, seq_len // plan.swept)
+    in_specs = [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec]
+    if heads:
+        in_specs.append(
+            _selection_spec(heads, plan.block, plan.swept, other))
     return pl.pallas_call(
         functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                          sub=plan.sub, grid=grid, window=window),
+                          sub=plan.sub, grid=grid, window=window,
+                          selected=bool(heads)),
         grid=grid,
-        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        in_specs=in_specs,
         out_specs=[o for o, _ in outs],
         out_shape=[jax.ShapeDtypeStruct(like.shape, like.dtype)
                    for _, like in outs],
@@ -1020,7 +1111,7 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sm_scale", "plan", "window"))
+    "causal", "sm_scale", "plan", "window", "heads"))
 def _dq_call(*operands, **static):
     with jax.named_scope("flash_attention_dq"):
         return _backward_pallas(_dq_kernel, False, operands[0], operands[2],
@@ -1028,7 +1119,7 @@ def _dq_call(*operands, **static):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sm_scale", "plan", "window"))
+    "causal", "sm_scale", "plan", "window", "heads"))
 def _dkv_call(*operands, **static):
     with jax.named_scope("flash_attention_dkv"):
         return _backward_pallas(_dkv_kernel, True, operands[0], operands[2],
@@ -1037,7 +1128,7 @@ def _dkv_call(*operands, **static):
 
 def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
                     dq_plan: KernelPlan, dkv_plan: KernelPlan,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, sel=None):
     batch, heads, seq_len, head_dim = q.shape
     bh = batch * heads
     flat = (bh, seq_len, head_dim)
@@ -1048,11 +1139,14 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
                     axis=-1).reshape(bh, 1, seq_len)
     operands = (q.reshape(flat), k.reshape(flat), v.reshape(flat_v),
                 g.reshape(flat_v), lse.reshape(bh, 1, seq_len), delta)
-    dq, = _dq_call(*operands, causal=causal, sm_scale=sm_scale,
-                   plan=dq_plan, window=window)
+    rows, cols = ((), ()) if sel is None else (
+        (sel,), (jnp.swapaxes(sel, 1, 2),))     # dK/dV's rows are keys
+    given = {} if sel is None else {"heads": heads}
+    dq, = _dq_call(*operands, *rows, causal=causal, sm_scale=sm_scale,
+                   plan=dq_plan, window=window, **given)
     # dK/dV: K-outer, Q-inner sweep.
-    dk, dv = _dkv_call(*operands, causal=causal, sm_scale=sm_scale,
-                       plan=dkv_plan, window=window)
+    dk, dv = _dkv_call(*operands, *cols, causal=causal, sm_scale=sm_scale,
+                       plan=dkv_plan, window=window, **given)
     return dq.reshape(q.shape), dk.reshape(q.shape), dv.reshape(v.shape)
 
 
@@ -1062,7 +1156,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, selected=None):
     """Flash attention: Pallas kernels on TPU, reference elsewhere.
 
     Differentiable end to end without materializing the (seq, seq)
@@ -1072,11 +1166,15 @@ def flash_attention(q, k, v, causal: bool = True,
     sees itself and the window - 1 positions before it, and the kernels
     compute the band and nothing else (`attention_plan`). v may be wider
     than q and k (differential attention: one score map times two heads'
-    values side by side); the output is as wide as v.
+    values side by side); the output is as wide as v. With `selected`
+    [batch, seq, seq] int8 (nonzero: seen; one map for every head; causal,
+    no window) a query sees the keys its row names of those at or before
+    it: a mask in all three kernels, a tile of it beside each tile of
+    scores, and no gradient (`attention_plan` says what runs).
     """
     # Primal-only call (no differentiation): skip the lse residual.
     out, _ = _flash_attention_fwd_impl(q, k, v, causal, sm_scale, window,
-                                       save_lse=False)
+                                       selected, save_lse=False)
     return out
 
 
@@ -1085,27 +1183,43 @@ def _scale_of(q, sm_scale):
         q.shape[-1])
 
 
-def _plan_of(q, v, causal, window) -> AttentionPlan:
+def _plan_of(q, v, causal, window, selected=None) -> AttentionPlan:
+    # (how many keys a query names is the caller's to know: the tiling
+    # needs that there is a selection, not its size)
     return attention_plan(q.shape[-2], q.shape[-1], causal, q.dtype,
-                          window, v.shape[-1])
+                          window, v.shape[-1],
+                          None if selected is None else q.shape[-2])
+
+
+def _with_selection(fn, selected):
+    """`fn` over q-shaped arrays and, where there is one, the selection
+    after them; per shard where there is none."""
+    if selected is None:
+        return _per_shard(fn)
+    if step_sharding() is not None:
+        raise NotImplementedError(
+            "a selection [batch, seq, seq] under kernel_sharding: the "
+            "kernels' shard_map splits q-shaped operands only")
+    return lambda *operands: fn(*operands, sel=selected)
 
 
 def _flash_attention_fwd_impl(q, k, v, causal, sm_scale, window=None,
-                              save_lse=True):
+                              selected=None, save_lse=True):
     scale = _scale_of(q, sm_scale)
     seq_len = q.shape[-2]
     if _kernel_ok(seq_len):
-        plan = _plan_of(q, v, causal, window)
-        out, lse = _per_shard(functools.partial(
+        plan = _plan_of(q, v, causal, window, selected)
+        out, lse = _with_selection(functools.partial(
             _flash_forward, causal=causal, sm_scale=scale, plan=plan.fwd,
-            save_lse=save_lse, window=window))(q, k, v)
+            save_lse=save_lse, window=window), selected)(q, k, v)
         return out, (out, lse)
-    return mha_reference(q, k, v, causal, scale, window), (None, None)
+    return mha_reference(q, k, v, causal, scale, window, selected), \
+        (None, None)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, window):
+def _flash_fwd(q, k, v, causal, sm_scale, window, selected=None):
     out, (o_saved, lse) = _flash_attention_fwd_impl(
-        q, k, v, causal, sm_scale, window)
+        q, k, v, causal, sm_scale, window, selected)
     if o_saved is not None:
         # What the backward kernels read, by name: a rematerialised block
         # keeps these, so the forward kernel does not run again, nor the
@@ -1117,24 +1231,65 @@ def _flash_fwd(q, k, v, causal, sm_scale, window):
         q = checkpoint_name(q, "flash_attention_q")
         k = checkpoint_name(k, "flash_attention_k")
         v = checkpoint_name(v, "flash_attention_v")
-    return out, (q, k, v, o_saved, lse)
+    return out, (q, k, v, o_saved, lse, selected)
 
 
 @jax.named_scope("flash_attention_bwd")
 def _flash_bwd(causal, sm_scale, window, residuals, g):
-    q, k, v, o, lse = residuals
+    q, k, v, o, lse, selected = residuals
     scale = _scale_of(q, sm_scale)
     if o is None:
         # Non-kernel path: autodiff through the reference.
         _, vjp = jax.vjp(
             lambda q_, k_, v_: mha_reference(q_, k_, v_, causal, sm_scale,
-                                             window),
+                                             window, selected),
             q, k, v)
-        return vjp(g)
-    plan = _plan_of(q, v, causal, window)
-    return _per_shard(functools.partial(
+        return (*vjp(g), None)
+    plan = _plan_of(q, v, causal, window, selected)
+    return (*_with_selection(functools.partial(
         _flash_backward, causal=causal, sm_scale=scale, dq_plan=plan.dq,
-        dkv_plan=plan.dkv, window=window))(q, k, v, o, lse, g)
+        dkv_plan=plan.dkv, window=window), selected)(q, k, v, o, lse, g),
+            None)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _reference_lse(q, k, sm_scale, selected):
+    """The log of each causal softmax's sum [batch, heads, 1, seq]
+    float32 as the forward kernel saves it, in plain XLA."""
+    seq = q.shape[-2]
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * sm_scale
+    seen = jnp.tril(jnp.ones((seq, seq), dtype=bool))
+    if selected is not None:
+        seen = seen & (selected[:, None] != 0)
+    return jax.nn.logsumexp(jnp.where(seen, logits, DEFAULT_MASK_VALUE),
+                            axis=-1)[:, :, None, :]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def attention_and_lse(q, k, v, sm_scale: Optional[float] = None,
+                      selected=None):
+    """Causal `flash_attention` that also hands out what its forward kernel
+    saves for its backward: (out, lse [batch, heads, 1, seq] float32, the
+    log of each query's softmax sum), for a caller that needs the
+    probabilities again (the sparse indexer's target,
+    ops/sparse_index.py). lse is read, not differentiated: no cotangent of
+    it reaches q, k or v."""
+    return _attention_and_lse_fwd(q, k, v, sm_scale, selected)[0]
+
+
+def _attention_and_lse_fwd(q, k, v, sm_scale, selected=None):
+    out, saved = _flash_fwd(q, k, v, True, sm_scale, None, selected)
+    lse = saved[4]
+    if lse is None:
+        lse = _reference_lse(q, k, _scale_of(q, sm_scale), selected)
+    return (out, lse), saved
+
+
+def _attention_and_lse_bwd(sm_scale, residuals, cotangents):
+    return _flash_bwd(True, sm_scale, None, residuals, cotangents[0])
+
+
+attention_and_lse.defvjp(_attention_and_lse_fwd, _attention_and_lse_bwd)

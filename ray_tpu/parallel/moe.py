@@ -15,14 +15,17 @@ Net-new vs the reference (SURVEY.md §2.4: EP "Absent"). Three layers:
 * `held_moe_layer`: the same for a chip that holds a SHARE of the experts
   (expert parallelism without its exchange): it routes over all of them
   (sigmoid scores, a selection bias no gradient sees, the k weights
-  renormalised and scaled: DeepSeek-V3's router), computes the part of
-  the result its own experts give (two matrices an expert with relu^2
+  renormalised and scaled: DeepSeek-V3's router; or with `softmax` a
+  softmax over all of them and no bias at all: Qwen3-MoE's), computes the
+  part of the result its own experts give (two matrices an expert with relu^2
   between, or gated: silu(gate) * up from one fused [d, 2f] matrix and a
   second) in buffers of a balanced share's rows and an eighth
   (`held_rows_plan`) that it walks in as many passes as the held rows
   take, so it drops nothing at any routing, and adds a shared expert
   every token passes where the model has one, of the experts' form.
-  `balance_bias` runs the bias's own rule to its fixed point. What
+  `balance_bias` runs the bias's own rule to its fixed point;
+  `place_experts` says which experts a chip holds where a router has no
+  bias to balance with (loads in, a chip for each expert out). What
   models/nemotron_h.py (relu^2, a shared expert), models/lfm2_moe.py
   (gated, none) and models/xing4.py (gated, a gated shared one) run.
 """
@@ -321,12 +324,14 @@ def dropless_moe_layer(x, router_w, w_gate, w_up, w_down, *,
 # experts of two matrices or gated ones of a fused first matrix and a
 # second, a shared expert or none
 # ---------------------------------------------------------------------------
-def router_scores(x, router_w):
+def router_scores(x, router_w, softmax: bool = False):
     """sigmoid(x router_w) [T, E] in float32 at full precision: what the
-    experts are chosen by (plus the bias) and weighted with (without)."""
-    return jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    experts are chosen by (plus the bias) and weighted with (without).
+    With `softmax`, softmax(x router_w) over all E in its place."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    return jax.nn.softmax(logits, axis=-1) if softmax \
+        else jax.nn.sigmoid(logits)
 
 
 # balance_bias ranks the tokens' experts anew once in this many rounds.
@@ -666,7 +671,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                    shared_down=None, *, experts_per_token: int, first: int,
                    routed_scale: float = 1.0, bias_rounds: int = 0,
-                   gated: bool = False, weight_eps: float = 1e-20):
+                   gated: bool = False, weight_eps: float = 1e-20,
+                   softmax: bool = False):
     """One chip's part of a top-k expert layer, with the model's shared
     expert where it has one, no token dropped.
 
@@ -699,18 +705,28 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
     `expert_passes` int32 (the passes that sum took of the buffers'
     `held_rows_plan` rows: 1 at a balanced routing) and `router_prob_sum`
     [E] (sum over tokens of s / sum_E s: the load-balancing loss's
-    probabilities)."""
+    probabilities).
+
+    With `softmax` (Qwen3-MoE's router) s = softmax(x router_w) over all E
+    in float32, the experts are the top k of s itself, and there is no
+    bias: `router_bias` is None, no round runs, `stats` carry none, and
+    `router_prob_sum` is the sum of s (models.moe.balance_loss's)."""
     t, k, held = x.shape[0], experts_per_token, w_up.shape[0]
     plan = held_rows_plan(t, k, held, router_w.shape[-1])
     with jax.named_scope("moe_route"):
         # Kept under remat like the softmax router's probabilities, and
         # for the same reason (`dropless_moe_layer`).
-        scores = checkpoint_name(router_scores(x, router_w), "moe_probs")
-        bias = lax.stop_gradient(router_bias)
-        if bias_rounds:
-            bias = checkpoint_name(
-                balance_bias(scores, k, bias_rounds, bias), "moe_probs")
-        _, experts = lax.top_k(scores + bias, k)
+        scores = checkpoint_name(router_scores(x, router_w, softmax),
+                                 "moe_probs")
+        if router_bias is None:
+            bias, biased = None, scores
+        else:
+            bias = lax.stop_gradient(router_bias)
+            if bias_rounds:
+                bias = checkpoint_name(
+                    balance_bias(scores, k, bias_rounds, bias), "moe_probs")
+            biased = scores + bias
+        _, experts = lax.top_k(biased, k)
         counts = _assignment_counts(experts, scores.shape[-1])
         # An absent expert's assignments sort after every held one's.
         local = experts.reshape(-1).astype(jnp.int32) - first
@@ -750,6 +766,88 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
              "expert_rows_held": jnp.sum(held_counts),
              "expert_passes": _held_passes(held_counts, plan.rows),
              "router_prob_sum": jnp.sum(
-                 scores / jnp.sum(scores, axis=-1, keepdims=True), axis=0),
-             "router_bias": bias}
+                 scores if softmax
+                 else scores / jnp.sum(scores, axis=-1, keepdims=True),
+                 axis=0)}
+    if bias is not None:
+        stats["router_bias"] = bias
     return out, stats
+
+
+# ---------------------------------------------------------------------------
+# Which experts a chip holds
+# ---------------------------------------------------------------------------
+def place_experts(loads, chips: int):
+    """A chip for each expert, every chip holding as many, so that the
+    chips' loads are as even as a greedy rule and swaps get them: what a
+    deployment calls once, at set-up, for a router that has no bias to
+    balance with (a softmax router: `held_moe_layer` with `softmax`).
+
+    `loads` [E], or [occasions, E] where the chips are to be even on each
+    occasion and not only on their sum (a ring of batches: a step sees one
+    of them, not their mean): the assignments each expert was given,
+    counted by routing the deployment's own tokens through its own model.
+    Returns numpy int32 [E], expert e's chip in [0, chips). Longest first,
+    each expert into the lightest chip (by the occasions' sum) that still
+    has room for one; then, while one helps, the swap of two experts
+    between the chip furthest from even on any occasion and another that
+    brings the largest distance down most. Plain numpy on the host: E is
+    hundreds. The caller relabels: the experts of chip c, in order, become
+    c * E / chips onwards (`placement_order`), by permuting the router's
+    columns, after which `held_moe_layer`'s `first` is c * E / chips."""
+    import numpy as np
+
+    loads = np.atleast_2d(np.asarray(loads, np.float64))        # [n, E]
+    experts = loads.shape[1]
+    if experts % chips:
+        raise ValueError(f"{experts} experts do not divide over {chips} "
+                         f"chips")
+    room = experts // chips
+    chip_of = np.full(experts, -1, np.int32)
+    held, total = np.zeros(chips, np.int64), np.zeros(chips)
+    summed = loads.sum(axis=0)
+    for e in np.argsort(-summed, kind="stable"):
+        c = min((c for c in range(chips) if held[c] < room),
+                key=lambda c: total[c])
+        chip_of[e], held[c], total[c] = c, held[c] + 1, total[c] + summed[e]
+    even = loads.sum(axis=1, keepdims=True) / chips             # [n, 1]
+    on_chip = np.stack([loads[:, chip_of == c].sum(axis=1)
+                        for c in range(chips)], axis=1)         # [n, chips]
+    for _ in range(16 * experts):
+        off = np.abs(on_chip - even)
+        worst = off.max()
+        a = int(np.unravel_index(off.argmax(), off.shape)[1])
+        best = None
+        mine = np.flatnonzero(chip_of == a)
+        for b in range(chips):
+            if b == a:
+                continue
+            theirs = np.flatnonzero(chip_of == b)
+            # moved[n, i, j]: what chip a loses on occasion n by giving
+            # its expert i for b's expert j
+            moved = loads[:, mine, None] - loads[:, None, theirs]
+            after = np.maximum(
+                np.abs(on_chip[:, a, None, None] - moved - even[:, :, None]),
+                np.abs(on_chip[:, b, None, None] + moved - even[:, :, None])
+            ).max(axis=0)
+            i, j = np.unravel_index(after.argmin(), after.shape)
+            # the swap must also leave the two chips' other occasions
+            # under the worst there was
+            if after[i, j] < worst and (best is None or after[i, j] < best[0]):
+                best = (after[i, j], b, mine[i], theirs[j])
+        if best is None:
+            break
+        _, b, i, j = best
+        on_chip[:, a] += loads[:, j] - loads[:, i]
+        on_chip[:, b] += loads[:, i] - loads[:, j]
+        chip_of[i], chip_of[j] = b, a
+    return chip_of
+
+
+def placement_order(chip_of):
+    """The relabelling a placement asks for: order[new] = old, chip 0's
+    experts first in their old order, then chip 1's and so on (a stable
+    sort of the experts by chip). `router[:, order]` is the router whose
+    expert `new` is the old expert order[new]."""
+    import numpy as np
+    return np.argsort(np.asarray(chip_of), kind="stable").astype(np.int32)

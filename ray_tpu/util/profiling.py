@@ -252,7 +252,30 @@ DEVICE_SCOPES: Dict[str, str] = {
                         "the norm the mixer reads to the residual add"
        for kind in ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
                     "diff_windowed", "diff_full", "diff_cross", "short_conv",
-                    "latent_attention")},
+                    "latent_attention", "sparse_attention")},
+    "sparse_index_proj": "models/decoder.py _index_heads: a lightning "
+                         "indexer's three projections of the detached "
+                         "normed input, its key norm and the rotary of q_I "
+                         "and k_I",
+    "sparse_index_fwd": "ops/sparse_index.py _fwd_call, the "
+                        "_index_fwd_kernel pallas_call: the index scores I "
+                        "[T, T] float32 over every causal pair, a product a "
+                        "head and tile (elsewhere the plain form under the "
+                        "same scope)",
+    "sparse_index_bwd": "ops/sparse_index.py _bwd_call, the "
+                        "_index_bwd_kernel pallas_call: dq_I, dk_I and dw "
+                        "from dI, the heads' products made again a tile; it "
+                        "runs in the FORWARD pass, inside `indexer_loss`'s "
+                        "forward rule (elsewhere the plain form's gradient "
+                        "under the same scope)",
+    "sparse_select": "ops/sparse_index.py select: tau a row by bisection "
+                     "over the float32 order, 32 counts of the row, by "
+                     "query chunk, and the selection [T, T] int8 (with a "
+                     "cache, models/decoder.py sparse_attention's plain "
+                     "form)",
+    "sparse_target": "ops/sparse_index.py index_target: the heads' mean "
+                     "attention probability over the selected keys from q, "
+                     "k and lse again, L_I and dI, in query chunks",
     "mla_project": "models/decoder.py latent_attention: the products from "
                    "the block's input to q (through its normed latent where "
                    "the layer holds one) and to the latent | shared key, "

@@ -505,3 +505,83 @@ def test_the_kernels_results_and_operands_hold_no_padded_row(S, hd, vd,
     for call in calls:
         assert [(x.aval.shape, x.aval.dtype) for x in call.invars] == [
             wide, wide, narrow, narrow, row, row]
+
+
+# ---------------------------------------------------------------------------
+# under a selection (PR 60): a [batch, seq, seq] int8 mask in all three
+# ---------------------------------------------------------------------------
+def test_the_plan_under_a_selection_at_16k_and_128_is_pinned():
+    """`attention_plan(16384, 128, selected=2048)`, field for field: the
+    kernels' own blocks stay 1,024, and a program's [1024, swept] tile of
+    the selection, twice in VMEM, brings the swept side to FOUR grid blocks
+    of 4,096 in all three (K and V whole and dK/dV's queries in two of
+    8,192 without one: `test_unwindowed_plans_are_what_they_were`); the
+    counts are the causal triangle's, since every tile is computed and
+    masked; of 136,314,880 pairs a head computed the softmax runs over
+    31,458,304, sum_t min(t + 1, 2048)."""
+    plan = attention_plan(16384, 128, selected=2048)
+    assert (plan.seq_len, plan.head_dim, plan.causal, plan.window,
+            plan.selected, plan.vmem_budget) == (
+        16384, 128, True, None, 2048, 32 * 2 ** 20)
+    assert [tuple(getattr(kernel, f) for f in _FIELDS)
+            for kernel in (plan.fwd, plan.dq, plan.dkv)] == [
+        (1024, 4096, 256, 27852800, 2080, 64, 2016, 184),
+        (1024, 4096, 256, 28442624, 2080, 64, 2016, 184),
+        (1024, 4096, 256, 28835840, 2080, 64, 2016, 184)]
+    assert plan.executed_pairs == 2080 * 256 * 256 == 136_314_880
+    assert plan.required_pairs == 31_458_304
+    assert plan.executed_share == attention_plan(16384, 128).executed_share
+    # without one the same fields are what they were, and the two counts
+    # are the triangle's and the band's
+    dense = attention_plan(16384, 128)
+    assert dense.selected is None
+    assert dense.required_pairs == 16384 * 16385 // 2
+    assert attention_plan(2048, 64, window=512).required_pairs \
+        == 512 * 513 // 2 + (2048 - 512) * 512
+    for bad in (dict(causal=False), dict(window=128)):
+        with pytest.raises(ValueError, match="selection is causal"):
+            attention_plan(1024, 64, selected=256, **bad)
+
+
+@pytest.mark.parametrize("S,budget", [(512, None), (2048, None),
+                                      (2048, 3 * 2 ** 20)])
+def test_the_three_kernels_under_a_random_selection(S, budget, monkeypatch):
+    """Forward, dQ and dK/dV in interpret mode with one [S, S] int8 selection
+    for both heads of two batch rows (a third of the pairs, every query's
+    own key among them or not) against `mha_reference` with the same mask:
+    one block, several sub-blocks, and the swept side on the grid, where a
+    program's first tiles can hold no selected key at all."""
+    if budget is not None:
+        monkeypatch.setattr(attention, "VMEM_BUDGET", budget)
+    ks = jax.random.split(jax.random.PRNGKey(S), 5)
+    q, k, v, w = (jax.random.normal(kk, (2, 2, S, 64)) for kk in ks[:4])
+    selected = (jax.random.uniform(ks[4], (2, S, S)) < 0.33).astype(jnp.int8)
+    # every query sees its first key, so no softmax is empty
+    selected = selected.at[:, :, 0].set(1)
+    plan = attention_plan(S, 64, True, jnp.float32, None, 64, S)
+    if budget is not None:
+        assert plan.fwd.swept < S and plan.dkv.swept < S
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * w),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    got, got_grads = both(lambda q, k, v: flash_attention(
+        q, k, v, True, 0.125, None, selected))
+    out, lse = attention.attention_and_lse(q, k, v, 0.125, selected)
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "0")
+    want, want_grads = both(lambda q, k, v: mha_reference(
+        q, k, v, True, 0.125, None, selected))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    for a, b in zip(got_grads, want_grads, strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-3)
+    np.testing.assert_allclose(out, mha_reference(
+        q, k, v, True, 0.125, None, selected), atol=2e-5, rtol=2e-4)
+    assert lse.shape == (2, 2, 1, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        lse, attention._reference_lse(q, k, 0.125, selected), atol=1e-4)
+    # and it differs from the unselected call: the mask is applied
+    assert float(jnp.max(jnp.abs(out - flash_attention(
+        q, k, v, True, 0.125)))) > 0.1

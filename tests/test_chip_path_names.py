@@ -35,6 +35,7 @@ from ray_tpu.models.olmo_hybrid import OlmoHybridConfig
 from ray_tpu.models.sambay import SambaYConfig
 from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                           make_glm4_moe_lite_train_step)
+from ray_tpu.models.keye_vl2 import KeyeVL2Config, make_keye_vl2_train_step
 from ray_tpu.models.xing4 import Xing4Config, make_xing4_train_step
 from ray_tpu.util import profiling
 
@@ -218,7 +219,7 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
         "MIXER_SCOPES[kind]": {"ray_tpu/models/decoder.py"}}
     assert set(decoder.MIXER_SCOPES) == {
         kind for kind, row in decoder.MIXERS.items() if row.apply}
-    assert len(set(decoder.MIXER_SCOPES.values())) == 10
+    assert len(set(decoder.MIXER_SCOPES.values())) == 11
     assert set(scopes) | set(decoder.MIXER_SCOPES.values()) \
         == set(profiling.DEVICE_SCOPES)
     assert not set(scopes) & set(decoder.MIXER_SCOPES.values())
@@ -457,6 +458,7 @@ def test_annotate_keeps_a_process_off_jax():
 ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
 SCAN_KERNELS = {"_ssm_fwd_kernel", "_ssm_bwd_kernel"}
 CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
+INDEX_KERNELS = {"_index_fwd_kernel", "_index_bwd_kernel"}
 
 
 @pytest.mark.parametrize("make_step,cfg,batch,kernels", [
@@ -525,8 +527,17 @@ CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
                        experts_held=(1, 2), experts_per_token=2, d_expert=128,
                        bias_rounds=8, balance_tokens=0, max_seq_len=256), 2,
      ATTENTION_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
+    # sparse attention: the indexer's two kernels beside attention's three,
+    # a query naming 128 of 256 keys
+    (make_keye_vl2_train_step,
+     KeyeVL2Config(vocab_size=512, d_model=128, n_layers=2, n_heads=2,
+                   n_kv_heads=1, head_dim=64, index_heads=2,
+                   index_head_dim=64, index_topk=128, n_experts=4,
+                   experts_held=(1, 2), experts_per_token=2, d_expert=128,
+                   max_seq_len=256), 2,
+     ATTENTION_KERNELS | INDEX_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
 ], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe", "sambay",
-        "olmo-hybrid", "nemotron-h", "xing4", "glm4-moe-lite"])
+        "olmo-hybrid", "nemotron-h", "xing4", "glm4-moe-lite", "keye-vl2"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -550,7 +561,8 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
             "flash_attention_dkv", "grouped_matmul_fwd",
             "grouped_matmul_dlhs", "grouped_matmul_drhs", "ssm_scan_fwd",
             "ssm_scan_bwd", "selective_scan_fwd", "selective_scan_bwd",
-            "short_conv_fwd", "short_conv_bwd"}, found
+            "short_conv_fwd", "short_conv_bwd", "sparse_index_fwd",
+            "sparse_index_bwd"}, found
     _every_branch_and_rule_sits_under_a_name(cfg, text)
     scopes = ["layers", "loss", "optimizer_update"]
     if kernels >= SCAN_KERNELS:
@@ -583,6 +595,18 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
         # the second loss stands beside the module, not inside it
         assert not re.search(r'loc\("[^"]*\bmtp\)*/[^"]*loss\b', text)
         scopes += ["mtp_loss", "mla_project", "mla_expand", "moe_shared"]
+    if kernels >= INDEX_KERNELS:
+        # the indexer's passes inside the branch's scope, each kernel's own
+        # scope the innermost round its call; the backward kernel under the
+        # target's rule, in the forward pass
+        for part in ("sparse_index_proj", "sparse_select", "sparse_target"):
+            assert re.search(
+                r'loc\("jit\(train_step\)/[^"]*\bsparse_attention_mixer\)*/'
+                r'(?:[^"]*/)?%s\b' % part, text), part
+        for kernel in ("fwd", "bwd"):
+            assert re.search(r'loc\("(?:[^"]*/)?sparse_index_%s/pallas_call"'
+                             % kernel, text), kernel
+        scopes += ["moe_route"]
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
@@ -602,7 +626,8 @@ RULES = {
     "mamba1": ("selective_scan_bwd", "ssm_conv"),
     "gated_delta": ("gated_delta_bwd", "ssm_conv"),
     "short_conv": ("short_conv_bwd",), "gmu": (), "experts": (),
-    "latent_attention": ("flash_attention_bwd",)}
+    "latent_attention": ("flash_attention_bwd",),
+    "sparse_attention": ("flash_attention_bwd",)}
 
 
 def _every_branch_and_rule_sits_under_a_name(cfg, text):

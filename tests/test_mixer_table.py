@@ -24,6 +24,7 @@ from ray_tpu.models.olmo_hybrid import OlmoHybridConfig, olmo_hybrid_loss
 from ray_tpu.models.sambay import SambaYConfig, sambay_loss
 from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                           glm4_moe_lite_loss)
+from ray_tpu.models.keye_vl2 import KeyeVL2Config, keye_vl2_loss
 from ray_tpu.models.xing4 import Xing4Config, xing4_loss
 
 FAMILIES = {
@@ -37,12 +38,13 @@ FAMILIES = {
     "nemotron_h": (NemotronHConfig, nemotron_h_loss),
     "xing4": (Xing4Config, xing4_loss),
     "glm4_moe_lite": (Glm4MoeLiteConfig, glm4_moe_lite_loss),
+    "keye_vl2": (KeyeVL2Config, keye_vl2_loss),
 }
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
          decoder.DIFF_FULL, decoder.DIFF_CROSS, decoder.ATTENTION_ONLY,
          decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV,
-         decoder.LATENT_ATTENTION)
+         decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION)
 STATELESS = (decoder.GMU, decoder.DIFF_CROSS, decoder.EXPERTS)
 
 
@@ -53,8 +55,8 @@ def family(request):
     return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
 
 
-def test_the_table_has_the_thirteen_kinds_and_the_tiny_models_run_them_all():
-    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 13
+def test_the_table_has_the_fourteen_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 14
     run = {kind for config, _ in FAMILIES.values()
            for kind in config.tiny().decoder().kinds}
     assert run == set(KINDS)
@@ -167,9 +169,10 @@ def test_a_layer_holds_what_its_kinds_mixer_reads(family):
             row = decoder.MIXERS[kind]
             if row.apply is None:           # a block of the channel mixer
                 continue
-            y, cache, shared = row.apply(
+            y, cache, shared, *stats = row.apply(
                 x, layer, dec, None, None, shared, i,
                 dec.window if row.windowed else None)
+            assert bool(stats) == (kind == decoder.SPARSE_ATTENTION), kind
             assert cache is None and y.shape == x.shape, kind
             assert (shared.k is not None) == (
                 decoder.DIFF_FULL in dec.kinds[:i + 1]), kind
@@ -275,6 +278,17 @@ FROZEN = {
     "_streams_read": (("x", "hc", "hyper"), "xing4"),
     "prediction_module": (("h", "embedded", "module", "block", "eps"),
                           "glm4_moe_lite"),
+    # chipbench/families/keye_vl2.py's: the mixer and the passes it calls
+    "sparse_attention": (("x", "layer", "dec"), "keye_vl2"),
+    "_detached": (("y",), "keye_vl2"),
+    "_index_heads": (("y", "layer", "dec", "positions"), "keye_vl2"),
+    "layer_norm": (("x", "weight", "bias", "eps"), "keye_vl2"),
+    "index_scores": (("q", "k", "w"), "keye_vl2"),
+    "select": (("scores", "topk"), "keye_vl2"),
+    "attention_and_lse": (("q", "k", "v", "sm_scale", "selected"),
+                          "keye_vl2"),
+    "indexer_loss": (("q_index", "k_index", "w", "scores", "selected", "q",
+                      "k", "lse", "sm_scale"), "keye_vl2"),
 }
 
 
@@ -307,7 +321,7 @@ def test_generate_names_no_family():
     import ray_tpu.models as models
     families = {getattr(models, name) for name in (
         "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid",
-        "nemotron_h", "lfm2_moe", "xing4", "glm4_moe_lite")}
+        "nemotron_h", "lfm2_moe", "xing4", "glm4_moe_lite", "keye_vl2")}
     held = {v for v in vars(generate).values() if inspect.ismodule(v)}
     assert not held & families
     assert "cache_layers" not in inspect.getsource(generate)

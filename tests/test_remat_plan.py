@@ -255,13 +255,14 @@ def test_a_block_joined_by_the_add_offers_no_branch_output(
 
 
 def test_the_second_table_is_beside_the_first():
-    # one name is of both: q, a candidate of the one kind whose base set
-    # leaves it out (a latent block's, KEPT_BY_KIND) and of no other's
+    # one name is of both: q, a candidate of the two kinds whose base set
+    # leaves it out (a latent and a sparse block's, KEPT_BY_KIND) and of no
+    # other's
     assert set(decoder.KEPT_WHERE_IT_FITS) & set(decoder.KEPT_UNDER_REMAT) \
         == {"flash_attention_q"}
     assert [kind for kind in decoder.MIXERS
             if "flash_attention_q" not in decoder._kept(kind)] \
-        == [decoder.LATENT_ATTENTION]
+        == [decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION]
     value = jax.ShapeDtypeStruct((16384, 10304), jnp.bfloat16)
     per_byte = {name: cost(value, 2688) / (value.size * 2)
                 for name, cost in decoder.KEPT_WHERE_IT_FITS.items()}
