@@ -29,9 +29,19 @@ What runs, in the order a layer runs it:
                     query chunk; then the selection [T, T] int8, which is
                     what ops.attention's kernels take (`sparse_select`)
     index_target    p from attention's own q, k and lse, L_I and dI, in
-                    query chunks in plain XLA (`sparse_target`)
+                    query chunks in plain XLA, a chunk's rows of dI over
+                    the rows of I it read (`sparse_target`)
     index_grads     `sparse_index_bwd`: dw, dq_I, dk_I from dI, the H_I
                     products made again a tile
+
+The two plain passes work the causal triangle as the kernels do, in BANDS
+(`key_bands`): the queries in equal bands, a loop a band, and a chunk of
+band g counts or multiplies only the first (g + 1) T / G keys, a static
+prefix that covers its last query: (G + 1) / 2G of the square, 5/8 at the
+four bands of 16,384 positions. The keys right of
+it are above the diagonal: -inf in I, which never decides a count
+(`kth_largest`), outside every selection, zero in p and in dI. A short
+sequence is one band, the whole row.
 
 `indexer_loss` is the one gradient rule. Its FORWARD pass runs the target
 and the backward kernel, at a cotangent of one, and keeps the three
@@ -64,6 +74,10 @@ from .attention import DEFAULT_MASK_VALUE, VMEM_BUDGET, _NN, _NT, _dot
 _TILE = 512                 # the config's q_chunk_size / kv_chunk_size
 _QUERY_CHUNK = 512          # rows a selection's bisection counts at once
 _TARGET_CHUNK = 256         # queries whose [heads, chunk, T] scores are held
+_BAND = 2048                # the fewest queries a band of the plain passes has
+_BANDS = 4                  # the most bands: 5/8 of the square (eight are 1%
+#                             more tokens/s at 16,384 and 5 s more to read the
+#                             step from the compile cache: PERF.md section 6)
 _LANES = 128
 
 
@@ -77,7 +91,9 @@ class SparseIndexPlan:
     squared; the others write -inf or zeros and compute nothing), a
     product a head and tile forward and four backward (the scores again,
     dq_I, dk_I and, on the vector unit, dw), `*_flops` counting the matrix
-    unit's, `*_bytes` what each pass moves over HBM once."""
+    unit's, `*_bytes` what each pass moves over HBM once; and the (query,
+    key) pairs the two plain passes execute in their bands (`key_bands`):
+    `select_pairs` counted 32 times, `target_pairs` multiplied a head."""
     seq_len: int
     heads: int
     head_dim: int
@@ -91,10 +107,42 @@ class SparseIndexPlan:
     fwd_bytes: int
     bwd_bytes: int
     vmem_bytes: int
+    select_pairs: int
+    target_pairs: int
 
 
 def _tile_of(seq_len: int) -> Optional[int]:
     return next((t for t in (_TILE, 256, 128) if seq_len % t == 0), None)
+
+
+def key_bands(seq_len: int, chunk: int) -> tuple:
+    """The key extents of a plain pass's bands of queries, from the shape:
+    G equal bands of whole chunks, band g's queries [g, g + 1) T / G
+    against the keys [0, (g + 1) T / G). G is the most, up to `_BANDS`,
+    that leaves a band `_BAND` queries; a count whose edge would cut a
+    chunk falls to the next one under it, a short sequence to one band of
+    every key."""
+    bands = next((g for g in range(min(_BANDS, seq_len // _BAND), 1, -1)
+                  if seq_len % (g * chunk) == 0), 1)
+    return tuple((g + 1) * (seq_len // bands) for g in range(bands))
+
+
+def _select_bands(seq_len: int) -> tuple:
+    """(`select`'s query chunk, its bands' key extents)."""
+    chunk = next(c for c in (_QUERY_CHUNK, 256, 128, seq_len)
+                 if seq_len % c == 0)
+    return chunk, key_bands(seq_len, chunk)
+
+
+def _target_bands(seq_len: int) -> tuple:
+    """(`index_target`'s query chunk, its bands' key extents)."""
+    chunk = next(c for c in (_TARGET_CHUNK, 128, seq_len)
+                 if seq_len % c == 0)
+    return chunk, key_bands(seq_len, chunk)
+
+
+def _pairs(seq_len: int, extents: tuple) -> int:
+    return sum(extents) * (seq_len // len(extents))
 
 
 def sparse_index_plan(seq_len: int, heads: int, head_dim: int,
@@ -124,7 +172,9 @@ def sparse_index_plan(seq_len: int, heads: int, head_dim: int,
         bwd_flops=3 * tiles * heads * product,
         fwd_bytes=operands + square,
         bwd_bytes=2 * operands + square + n * seq_len * head_dim * 4,
-        vmem_bytes=vmem)
+        vmem_bytes=vmem,
+        select_pairs=_pairs(seq_len, _select_bands(seq_len)[1]),
+        target_pairs=_pairs(seq_len, _target_bands(seq_len)[1]))
 
 
 def _kernel_ok(seq_len: int) -> bool:
@@ -352,16 +402,39 @@ def kth_largest(scores, k: int):
     return jax.lax.bitcast_convert_type(_ordered(lo), jnp.float32)
 
 
+def _chunks(t, axis: int, chunk: int):
+    """The function that reads chunk c of `chunk` along `axis` of t where
+    it lies: the axis split in two and the chunks' moved first, which is a
+    view (one batch) or a layout XLA reads in place."""
+    shape = t.shape[:axis] + (-1, chunk) + t.shape[axis + 1:]
+    t = jnp.moveaxis(t.reshape(shape), axis, 0)
+    return lambda c: jax.lax.dynamic_index_in_dim(t, c, 0, False)
+
+
 @jax.named_scope("sparse_select")
 def select(scores, topk: int):
     """I [b, T, T] float32 (-inf above the diagonal) -> (the selection
     [b, T, T] int8, 1 where s <= t and I[t, s] >= tau_t; tau [b, T]).
-    Exact: no approximate top-k, and a tie at tau_t keeps both."""
+    Exact: no approximate top-k, and a tie at tau_t keeps both. A chunk of
+    queries counts its band's key prefix (`key_bands`): the keys right of
+    it are -inf, which `kth_largest` counts only where every entry
+    counts."""
+    chunk, extents = _select_bands(scores.shape[1])
+    return _select(scores, topk=topk, chunk=chunk, extents=extents)
+
+
+# The two passes are traced once a shape, not once a layer: their loops a
+# band are most of what a layer's trace would hold.
+@functools.partial(jax.jit, static_argnames=("topk", "chunk", "extents"))
+def _select(scores, *, topk: int, chunk: int, extents: tuple):
     b, seq, _ = scores.shape
-    chunk = next(c for c in (_QUERY_CHUNK, 256, 128, seq) if seq % c == 0)
-    tau = jax.lax.map(
-        lambda rows: kth_largest(rows, topk),
-        scores.reshape(b, seq // chunk, chunk, seq).swapaxes(0, 1))
+    each = seq // chunk // len(extents)
+    rows = _chunks(scores, 1, chunk)
+    tau = jnp.concatenate([
+        jax.lax.map(lambda c, extent=extent: kth_largest(
+            rows(c)[..., :extent], topk),
+            jnp.arange(g * each, (g + 1) * each))
+        for g, extent in enumerate(extents)])
     tau = tau.swapaxes(0, 1).reshape(b, seq)
     causal = jnp.tril(jnp.ones((seq, seq), dtype=bool))
     return (causal & (scores >= tau[..., None])).astype(jnp.int8), tau
@@ -377,35 +450,54 @@ def index_target(scores, selected, q, k, lse, sm_scale: float):
     is the heads' mean probability exp(q . k sm_scale - lse) over S_t; L_I
     the batch's mean of a sequence's 1/T sum_t KL(p[t] || softmax_{S_t}
     I[t]); dI = (softmax_{S_t}(I) - p) / (b T) inside S_t, zero outside.
-    In chunks of queries: a chunk holds its [h, chunk, T] scores."""
+    In chunks of queries: a chunk holds its [h, chunk, extent] scores
+    against its band's key prefix (`key_bands`) and writes its rows of dI,
+    whole, over the rows of I it read: the bands' loops carry I, which
+    leaves them as dI, so where I is not read again (`indexer_loss`) no
+    second [b, T, T] float32 is held and none is zeroed."""
+    chunk, extents = _target_bands(q.shape[2])
+    return _index_target(scores, selected, q, k, lse, sm_scale=sm_scale,
+                         chunk=chunk, extents=extents)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "chunk", "extents"))
+def _index_target(scores, selected, q, k, lse, *, sm_scale: float,
+                  chunk: int, extents: tuple):
     b, h, seq, hd = q.shape
     kvh = k.shape[1]
-    chunk = next(c for c in (_TARGET_CHUNK, 128, seq) if seq % c == 0)
-    n = seq // chunk
+    each = seq // chunk // len(extents)
+    seen_of, q_of, lse_of = (
+        _chunks(selected, 1, chunk), _chunks(q, 2, chunk),
+        _chunks(lse[:, :, 0], 2, chunk))
 
-    def chunks(t, axis):
-        shape = t.shape[:axis] + (n, chunk) + t.shape[axis + 1:]
-        return jnp.moveaxis(t.reshape(shape), axis, 0)
-
-    def one(args):
-        rows, seen, qc, lsec = args
-        seen = seen != 0
+    def one(rows, c, extent):
+        """`rows` [b, chunks, chunk, T]: I where no chunk has been, dI
+        where one has."""
+        scores = jax.lax.dynamic_index_in_dim(rows, c, 1, False)[..., :extent]
+        seen = seen_of(c)[..., :extent] != 0
         s = jnp.einsum("bjgqd,bjkd->bjgqk",
-                       qc.reshape(b, kvh, h // kvh, chunk, hd), k,
+                       q_of(c).reshape(b, kvh, h // kvh, chunk, hd),
+                       k[:, :, :extent],
                        preferred_element_type=jnp.float32) * sm_scale
-        a = jnp.exp(s - lsec.reshape(b, kvh, h // kvh, chunk, 1))
+        a = jnp.exp(s - lse_of(c).reshape(b, kvh, h // kvh, chunk, 1))
         p = jnp.where(seen, jnp.mean(a, axis=(1, 2)), 0.0)
         log_i = jax.nn.log_softmax(
-            jnp.where(seen, rows, DEFAULT_MASK_VALUE), axis=-1)
+            jnp.where(seen, scores, DEFAULT_MASK_VALUE), axis=-1)
         kl = jnp.sum(jnp.where(p > 0.0, p * (jnp.log(
             jnp.where(p > 0.0, p, 1.0)) - log_i), 0.0))
-        return kl, jnp.where(seen, jnp.exp(log_i) - p, 0.0) / (b * seq)
+        d = jnp.where(seen, jnp.exp(log_i) - p, 0.0) / (b * seq)
+        d = jnp.pad(d, ((0, 0), (0, 0), (0, seq - extent)))
+        return jax.lax.dynamic_update_slice(
+            rows, d[:, None], (0, c, 0, 0)), kl
 
-    kl, d_scores = jax.lax.map(one, (
-        chunks(scores, 1), chunks(selected, 1), chunks(q, 2),
-        chunks(lse[:, :, 0], 2)))
-    return (jnp.sum(kl) / (b * seq),
-            jnp.moveaxis(d_scores, 0, 1).reshape(b, seq, seq))
+    rows, kl = scores.reshape(b, seq // chunk, chunk, seq), []
+    for g, extent in enumerate(extents):
+        rows, sums = jax.lax.scan(
+            functools.partial(one, extent=extent), rows,
+            jnp.arange(g * each, (g + 1) * each))
+        kl.append(sums)
+    return (jnp.sum(jnp.concatenate(kl)) / (b * seq),
+            rows.reshape(b, seq, seq))
 
 
 # ---------------------------------------------------------------------------

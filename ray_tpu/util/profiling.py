@@ -269,13 +269,17 @@ DEVICE_SCOPES: Dict[str, str] = {
                         "forward rule (elsewhere the plain form's gradient "
                         "under the same scope)",
     "sparse_select": "ops/sparse_index.py select: tau a row by bisection "
-                     "over the float32 order, 32 counts of the row, by "
-                     "query chunk, and the selection [T, T] int8 (with a "
-                     "cache, models/decoder.py sparse_attention's plain "
-                     "form)",
+                     "over the float32 order, 32 counts of the key prefix "
+                     "of the query chunk's band (key_bands: up to four "
+                     "bands, the keys up to the band's last query), a loop "
+                     "a band, and the selection [T, T] int8 in one pass "
+                     "over I (with a cache, models/decoder.py "
+                     "sparse_attention's plain form: the whole row)",
     "sparse_target": "ops/sparse_index.py index_target: the heads' mean "
                      "attention probability over the selected keys from q, "
-                     "k and lse again, L_I and dI, in query chunks",
+                     "k and lse again, L_I and dI, a query chunk against "
+                     "its band's key prefix (key_bands), a loop a band, a "
+                     "chunk's rows of dI written over the rows of I it read",
     "mla_project": "models/decoder.py latent_attention: the products from "
                    "the block's input to q (through its normed latent where "
                    "the layer holds one) and to the latent | shared key, "

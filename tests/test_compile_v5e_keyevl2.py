@@ -114,6 +114,11 @@ def test_the_kernels_run_the_grids_the_plans_say(cell):
                           tile)
     index = sparse_index_plan(16384, 16, 64)
     assert (index.tile, index.grid) == (512, 32)
+    # the two plain passes' (query, key) pairs: a chunk of each against
+    # its band's key prefix, four bands of 4,096 queries, 5/8 of the square
+    # (ISSUE 61 asked 0.57; PERF.md section 6 has why the passes keep four)
+    assert index.select_pairs == index.target_pairs == 167_772_160 \
+        == 5 * 16384 ** 2 // 8
     q, k, w, square = (1, 16, 512, 64), (1, 512, 64), (1, 512, 16), \
         (1, 512, 512)
     assert grids["_index_fwd_kernel"] == {((1, 32, 32), (q, k, w, square))}
@@ -190,6 +195,26 @@ def test_no_kernel_runs_twice_though_remat_is_on(step):
     assert not re.search(r"\[(1,)?(16|32),16384,16384\]", step[1])
 
 
+def test_the_plain_passes_loop_a_band_over_key_prefixes(step):
+    """`select` runs four loops a layer (each with its bisection's loop
+    of 32 counts inside) and `index_target` four, each under its scope,
+    and a loop's chunk reads its band's key prefix: the bisection's counts
+    compare [512, extent] tiles and the target's products are [.., 256,
+    extent], extent the band's last query; only the last band's are the
+    whole row."""
+    compiled = step[1]
+    for scope, chunk, extents, nested in (
+            ("sparse_select", 512, range(4096, 16385, 4096), 2),
+            ("sparse_target", 256, range(4096, 16385, 4096), 1)):
+        lines = [line for line in compiled.splitlines()
+                 if f"/sparse_attention_mixer/{scope}/" in line]
+        whiles = [line for line in lines if re.search(r" while\(", line)]
+        assert len(whiles) == 6 * len(extents) * nested, (scope, len(whiles))
+        for extent in extents:
+            assert any(re.search(rf"\[(\d+,)*{chunk},{extent}\]", line)
+                       for line in lines), (scope, extent)
+
+
 def test_step_fits_a_chip_by_xlas_own_total(step, cell, record_property):
     mem = step[2]
     nbytes = total(mem)
@@ -204,3 +229,9 @@ def test_step_fits_a_chip_by_xlas_own_total(step, cell, record_property):
     assert nbytes <= HBM_BYTES - 2 ** 30
     assert nbytes <= plan.state_bytes + plan.base_bytes \
         + plan.reserve_bytes + plan.kept_extra_bytes + 2 ** 25
+    # Under the step before the plain passes' bands (PR 60's tree:
+    # 15,106,948,608). dI is written over the rows of I it was read from,
+    # and XLA then runs each layer's target and backward kernel before the
+    # next layer's scores are made, where four layers' I, dI and a loop's
+    # copy stood together at the peak: 11,305,062,400.
+    assert nbytes <= 11_400_000_000

@@ -360,8 +360,159 @@ def test_the_plan_counts_what_the_cell_runs():
     assert plan.fwd_products == 528 * 16 and plan.bwd_products == 3 * 8448
     assert plan.fwd_flops == 528 * 16 * 2 * 512 * 512 * 64
     assert plan.vmem_bytes <= 32 * 2 ** 20
+    # both plain passes in four bands of 4,096 queries: 5/8 of the square
+    assert plan.select_pairs == plan.target_pairs == 5 * 16384 ** 2 // 8
     with pytest.raises(ValueError, match="multiples of 128"):
         sparse_index.sparse_index_plan(1000, 16, 64)
+
+
+@pytest.mark.parametrize("seq, chunk, want", [
+    (16384, 512, (4096, 8192, 12288, 16384)),       # the cell's select
+    (16384, 256, (4096, 8192, 12288, 16384)),       # and its target
+    (32768, 512, (8192, 16384, 24576, 32768)),      # never over four
+    (4096, 512, (2048, 4096)),
+    (2048, 512, (2048,)), (128, 128, (128,)),       # tier-1's, the cache's
+    (6144, 512, (2048, 4096, 6144)),
+    (5120, 512, (2560, 5120)),          # 2,560 = five chunks of 512
+    (7680, 512, (2560, 5120, 7680)),
+    (7168, 512, (3584, 7168)),          # 14 chunks: three bands cut one
+    (4608, 512, (4608,)),               # nine chunks: two bands cut one
+    (4608, 256, (2304, 4608))])         # eighteen of 256: they do not
+def test_the_bands_are_whole_chunks_from_the_shape(seq, chunk, want):
+    """G equal bands, band g's keys [0, (g + 1) T / G): the most bands, up
+    to four, of at least 2,048 queries whose edges cut no chunk; a count
+    that would is passed over for the next one under it, down to one band
+    of the whole row. The plan counts the pairs they hold, (G + 1) / 2G of
+    the square."""
+    got = sparse_index.key_bands(seq, chunk)
+    assert got == want
+    band = seq // len(got)
+    assert band % chunk == 0 and got[-1] == seq
+    assert len(got) == 1 or (band >= 2048 and len(got) <= 4)
+    plan = sparse_index.sparse_index_plan(seq, 16, 64)
+    for pairs, (c, extents) in (
+            (plan.select_pairs, sparse_index._select_bands(seq)),
+            (plan.target_pairs, sparse_index._target_bands(seq))):
+        g = len(extents)
+        assert seq // g % c == 0
+        assert pairs == seq * seq * (g + 1) // (2 * g)
+
+
+BANDED_SEQ = 1024
+
+
+@pytest.fixture(scope="module")
+def banded_scores():
+    """I [2, 1024, 1024] as `index_scores` leaves it, with what a trimmed
+    count could get wrong: rows of ties at tau across a band's edge, rows
+    with fewer finite entries than any topk under the diagonal, a row of
+    equal scores, and signed zeros."""
+    causal = jnp.tril(jnp.ones((BANDED_SEQ, BANDED_SEQ), bool))
+    x = jax.random.normal(jax.random.PRNGKey(61), (2, BANDED_SEQ, BANDED_SEQ))
+    x = x.at[0, 700, 100:650].set(0.25).at[0, 701].set(1.5)
+    x = x.at[1, 300, 40:].set(-jnp.inf).at[1, 900, ::3].set(-jnp.inf)
+    x = x.at[1, 600, ::2].set(-0.0).at[1, 600, 1::4].set(0.0)
+    return jnp.where(causal, x, -jnp.inf)
+
+
+def _with_bands_of(monkeypatch, band, fn, *args):
+    """fn(*args) traced with the fewest queries of a band at `band` and
+    chunks of 128 queries, and with up to eight bands: the shape then
+    falls to 1,024 / band bands (a test's only way to several bands under
+    4,096 positions; the program reads the shape alone)."""
+    monkeypatch.setattr(sparse_index, "_BAND", band)
+    monkeypatch.setattr(sparse_index, "_BANDS", 8)
+    monkeypatch.setattr(sparse_index, "_QUERY_CHUNK", 128)
+    monkeypatch.setattr(sparse_index, "_TARGET_CHUNK", 128)
+    return jax.jit(fn)(*args)
+
+
+@pytest.mark.parametrize("band", [128, 256, 512])
+@pytest.mark.parametrize("topk", [1, 200, 256, 700, 2000])
+def test_the_banded_selection_is_the_whole_rows_bit_for_bit(
+        monkeypatch, banded_scores, band, topk):
+    """`select` over 8, 4 and 2 bands of key prefixes against one band of
+    the whole row: the same selection and the same tau, every bit, at a
+    topk under a band's extent, equal to it, between two bands' (the first
+    bands give -inf at once, or count rows with fewer finite entries than
+    topk), and over the sequence (every causal key)."""
+    def pick(x):
+        return sparse_index.select(x, topk)
+    want_sel, want_tau = _with_bands_of(monkeypatch, 2048, pick, banded_scores)
+    assert sparse_index._select_bands(BANDED_SEQ) == (128, (BANDED_SEQ,))
+    sel, tau = _with_bands_of(monkeypatch, band, pick, banded_scores)
+    assert len(sparse_index._select_bands(BANDED_SEQ)[1]) == BANDED_SEQ // band
+    assert sel.dtype == jnp.int8 and tau.dtype == jnp.float32
+    assert bool(jnp.all(sel == want_sel))
+    assert np.array_equal(np.asarray(tau).view(np.int32),
+                          np.asarray(want_tau).view(np.int32))
+    seen = jnp.sum(sel, axis=-1)
+    rows = jnp.arange(BANDED_SEQ)
+    if topk >= BANDED_SEQ:
+        assert bool(jnp.all(tau == -jnp.inf))
+    else:
+        assert bool(jnp.all((tau == -jnp.inf)[:, :topk - 1]))
+        assert bool(jnp.all(seen[0, :700] == jnp.minimum(rows + 1, topk)[:700]))
+    if topk == 200:
+        assert float(tau[0, 700]) == 0.25 and int(seen[0, 700]) > 550   # a tie
+        # 40 finite entries: -inf, and `scores >= -inf` is every causal key
+        assert float(tau[1, 300]) == -jnp.inf and int(seen[1, 300]) == 301
+
+
+@pytest.mark.parametrize("band", [128, 256, 512])
+def test_the_banded_target_is_the_whole_rows(monkeypatch, banded_scores,
+                                             band):
+    """`index_target` over 8, 4 and 2 bands against one: L_I and dI to the
+    order of a float32 sum (a trimmed key adds an exact zero), dI exactly
+    zero above the diagonal and outside the selection, its rows summing to
+    nothing (softmax less p, both of mass one over S_t)."""
+    b, h, kvh, hd, topk = 2, 4, 2, 16, 200
+    ks = jax.random.split(jax.random.PRNGKey(band), 2)
+    q = jax.random.normal(ks[0], (b, h, BANDED_SEQ, hd))
+    k = jax.random.normal(ks[1], (b, kvh, BANDED_SEQ, hd))
+    causal = jnp.tril(jnp.ones((BANDED_SEQ, BANDED_SEQ), bool))
+    scores = jnp.where(causal & ~jnp.isfinite(banded_scores), -3.0,
+                       banded_scores)      # I is finite under the diagonal
+    selected = sparse_index.select(scores, topk)[0]
+    sm_scale = hd ** -0.5
+    s = jnp.einsum("bjgqd,bjkd->bjgqk",
+                   q.reshape(b, kvh, h // kvh, BANDED_SEQ, hd), k) * sm_scale
+    lse = jax.nn.logsumexp(
+        jnp.where(selected[:, None, None] != 0, s, -jnp.inf),
+        axis=-1).reshape(b, h, 1, BANDED_SEQ)
+
+    def target(*args):
+        return sparse_index.index_target(*args, sm_scale)
+    args = (scores, selected, q, k, lse)
+    want_loss, want = _with_bands_of(monkeypatch, 2048, target, *args)
+    loss, got = _with_bands_of(monkeypatch, band, target, *args)
+    assert len(sparse_index._target_bands(BANDED_SEQ)[1]) == BANDED_SEQ // band
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert float(want_loss) > 0.1
+    assert abs(float(loss) - float(want_loss)) <= 1e-6 * float(want_loss)
+    assert float(jnp.max(jnp.abs(got - want))) \
+        <= 1e-6 * float(jnp.max(jnp.abs(want)))
+    assert bool(jnp.all(jnp.where(selected != 0, 0.0, got) == 0.0))
+    assert bool(jnp.all(jnp.triu(got, 1) == 0.0))
+    assert float(jnp.max(jnp.abs(jnp.sum(got, axis=-1)))) < 1e-8
+
+
+def test_two_bands_from_the_shape_alone_are_the_whole_rows():
+    """No constant moved: 4,096 positions fall to two bands of 2,048 by
+    themselves, and `select` there (a topk over the first band's extent,
+    which gives -inf at once, and one under it) is `kth_largest` of the
+    whole row."""
+    seq = 4096
+    assert sparse_index._select_bands(seq) == (512, (2048, 4096))
+    causal = jnp.tril(jnp.ones((seq, seq), bool))
+    x = jax.random.normal(jax.random.PRNGKey(4096), (1, seq, seq))
+    x = jnp.where(causal, x, -jnp.inf)
+    for topk in (2500, 64):
+        want = jax.jit(lambda s: sparse_index.kth_largest(s, topk))(x)
+        sel, tau = jax.jit(lambda s: sparse_index.select(s, topk))(x)
+        assert np.array_equal(np.asarray(tau).view(np.int32),
+                              np.asarray(want).view(np.int32))
+        assert bool(jnp.all((sel != 0) == (causal & (x >= want[..., None]))))
 
 
 # ---------------------------------------------------------------------------
