@@ -393,9 +393,11 @@ def _qkv_heads(x, layer, dec: Decoder, cache, start_pos):
             jnp.einsum("bsd,de->bse", y, layer["wqkv"]), "attention_qkv"),
             3, axis=-1)
     else:
-        q = jnp.einsum("bsd,de->bse", y, layer["wq"])
-        k, v = jnp.split(
-            jnp.einsum("bsd,de->bse", y, layer["wkv"]), 2, axis=-1)
+        q = checkpoint_name(
+            jnp.einsum("bsd,de->bse", y, layer["wq"]), "attention_q_proj")
+        k, v = jnp.split(checkpoint_name(
+            jnp.einsum("bsd,de->bse", y, layer["wkv"]), "attention_kv_proj"),
+            2, axis=-1)
     if "q_norm" in layer:           # over all of q and of k, before the split
         q = rms_norm(q, layer["q_norm"], dec.norm_eps)
         k = rms_norm(k, layer["k_norm"], dec.norm_eps)
@@ -413,8 +415,10 @@ def _qkv_heads(x, layer, dec: Decoder, cache, start_pos):
         if dec.rope_base is None:
             return t
         return rope(t, base=dec.rope_base, positions=positions)
-    return y, rotate(heads(q, h)), rotate(heads(k, kvh)), heads(v, kvh), \
-        sp, positions
+    q, k = rotate(heads(q, h)), rotate(heads(k, kvh))
+    if "wqkv" not in layer:         # at kv-head width, before any group's copy
+        k = checkpoint_name(k, "attention_k_heads")
+    return y, q, k, heads(v, kvh), sp, positions
 
 
 def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
@@ -537,7 +541,11 @@ def sparse_attention(x, layer, dec: Decoder, cache=None, start_pos=None):
         index_loss = jnp.zeros((), jnp.float32)
         seen = jnp.mean(jnp.sum(selected, axis=-1, dtype=jnp.float32))
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * hd)
-    return (jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache,
+    # (named: the channel branch reads x + this, so a rematerialised block
+    # that does not keep it runs the output projection again)
+    out = checkpoint_name(jnp.einsum("bsd,de->bse", attn, layer["wo"]),
+                          "sparse_attention_out")
+    return (out, new_cache,
             {"index_loss": index_loss, "selected_keys_mean": seen})
 
 
@@ -1234,8 +1242,13 @@ keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
 # products, two head norms and a rotary away from the block's input (0.3
 # TFLOP a layer at 32 | 4 heads of 128), and k and v as the kernels take
 # them are copies across a group of eight, 134 MB each a layer at 16,384
-# tokens where six selections already hold 1.6 GB; q stays a candidate of
-# KEPT_WHERE_IT_FITS, as a latent layer's is.
+# tokens where six selections already hold 1.6 GB. q stays a candidate of
+# KEPT_WHERE_IT_FITS, as a latent layer's is, and what q, k and v are made
+# from is one of this kind's own, with the branch's output (FITS_BY_KIND
+# below: the two projections' outputs and k at kv-head width, 184 MB a
+# layer there for the copies' 268): where the step has the room a block
+# makes only k's and v's copies again, a broadcast each, the head norms'
+# inverse roots, and `ln1` for the projections' weight gradients.
 KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
     LATENT_ATTENTION: tuple(
         name for name in KEPT_UNDER_REMAT
@@ -1348,6 +1361,39 @@ KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
     "moe_choice": _first, "flash_attention_q": _first,
     "hc_channel_out": _matmuls(4)}
 
+# Candidates of one kind's blocks alone, beside those: what a sparse block's
+# q, k and v are made from where the model holds `wq` and `wkv` apart
+# (`_qkv_heads`' unfused arm), and the branch's output. The head norms are
+# autodiff's and their backward reads their INPUT, so with q alone kept a
+# block's second forward still ran both projections, both norms in float32
+# and k's rotary, and the output projection for the channel branch's input
+# (5.9 ms a layer at 16,384 tokens of 32 | 4 heads of 128: PERF.md section
+# 6, PR 62):
+#     attention_q_proj   y wq  [T, h hd]      134 MB a layer there
+#     attention_kv_proj  y wkv [T, 2 kvh hd]  34 MB: k before its norm, and v
+#     attention_k_heads  k normed and rotated, at kv-head width, 17 MB: with
+#                        q, a norm, a rotary and their float32 passes for it
+#     sparse_attention_out
+#                        the kernel's output times wo [T, d], 67 MB: a
+#                        product from h hd = 2 d wide, two matmuls an element
+# With the four and q kept a second forward makes `ln1`, the norms' inverse
+# roots, v's transpose and k's and v's copies across their groups, which are
+# a broadcast away from 17 MB each and 134 kept.
+# By kind and not in the table above on purpose: `attention` blocks of four
+# other cells run the same arm, their plans are full, and a candidate at a
+# matmul's rate would reshuffle what they keep with nothing measured to say
+# it should.
+FITS_BY_KIND: Dict[str, Dict[str, Callable]] = {
+    SPARSE_ATTENTION: {"attention_q_proj": _a_matmul,
+                       "attention_kv_proj": _a_matmul,
+                       "attention_k_heads": _first,
+                       "sparse_attention_out": _matmuls(2)}}
+
+
+def _fits(kind: str) -> Dict[str, Callable]:
+    """name -> cost of everything a block of `kind` may keep besides."""
+    return {**KEPT_WHERE_IT_FITS, **FITS_BY_KIND.get(kind, {})}
+
 
 class RematPlan(NamedTuple):
     """What each rematerialised layer keeps beyond KEPT_UNDER_REMAT, and
@@ -1387,15 +1433,16 @@ def _nbytes(values) -> int:
 
 
 def _block_account(block: Callable, x, layer, shared,
-                   kept: Tuple[str, ...] = KEPT_UNDER_REMAT):
+                   kept: Tuple[str, ...] = KEPT_UNDER_REMAT,
+                   fits: Dict[str, Callable] = KEPT_WHERE_IT_FITS):
     """One abstract linearisation of `block` under a policy that keeps
-    `kept`, its kind's base set, and KEPT_WHERE_IT_FITS' names -> (the
+    `kept`, its kind's base set, and the names of `fits`, its kind's
+    candidates (`_fits`) -> (the
     block's x and `Shared` as it returns them,
     the bytes it keeps of the base set, ((name, bytes, cost), ...) of each
-    value with a name of KEPT_WHERE_IT_FITS). Shapes in, shapes out:
+    value with a name of `fits`). Shapes in, shapes out:
     nothing is computed and no kernel is lowered."""
-    policy = jax.checkpoint_policies.save_only_these_names(
-        *kept, *KEPT_WHERE_IT_FITS)
+    policy = jax.checkpoint_policies.save_only_these_names(*kept, *fits)
 
     def linearized(x, layer, shared):
         out, pushforward = jax.linearize(
@@ -1417,10 +1464,10 @@ def _block_account(block: Callable, x, layer, shared,
     # (a name of both tables is the base set's: no candidate)
     extras = tuple(
         (eqn.params["name"], _nbytes(eqn.outvars[0].aval),
-         KEPT_WHERE_IT_FITS[eqn.params["name"]](eqn.outvars[0].aval,
-                                                _rows_and_width(x)[1]))
+         fits[eqn.params["name"]](eqn.outvars[0].aval,
+                                  _rows_and_width(x)[1]))
         for eqn in jaxpr.eqns if eqn.primitive.name == "name"
-        and eqn.params["name"] in KEPT_WHERE_IT_FITS
+        and eqn.params["name"] in fits
         and eqn.params["name"] not in kept)
     base = _nbytes(x) + held - sum(size for _, size, _ in extras)
     return x_out, shared_out, base, extras
@@ -1673,21 +1720,31 @@ def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
 
 def _selection_holds(kind: str, tokens: int, x) -> int:
     """What a sparse-attention block holds that no name shows, in [T, T]
-    buffers a sequence: sixteen bytes a (query, key) pair. The index scores
-    and their gradient, float32 both, are alive at once inside
-    `indexer_loss`'s forward rule, eight; the other eight are a
-    calibration, not a count (the target's chunks of [heads, 256, T]
-    scores, the backward kernel's partial sums of dk_I, the selection
-    transposed for dK/dV): XLA's account of Keye-VL-2.0's six-layer step
-    compiled for a v5e, total - state - base set - what the plan keeps,
-    reads 5.91 GB where the block's named values come to 1.6 and these to
-    4.29 (PERF.md section 6, PR 60). They are alive in a block's FORWARD
-    pass and are counted with its backward set as if they met: from
-    above."""
+    buffers a sequence: six bytes a (query, key) pair. Five are counted: at
+    the step's peak, inside the last layer's target pass, the buffer
+    assignment of Keye-VL-2.0's six-layer step compiled for a v5e holds the
+    index scores I, float32, which their gradient is written over (four),
+    and the int8 selection transposed for dK/dV (one); the sixth is for
+    what a chunk of the target and the backward kernel's partial sums of
+    dk_I hold beside them (0.14 + 0.13 GB there). By XLA's own account the
+    six are from above under every plan tried: total - state - base set -
+    what the plan keeps reads 2.04 GB with nothing kept, 2.10 with q and
+    the routing's choices (the plan the cell ran until PR 62: 11.30 GB in
+    all), 2.09 with k at kv-head width beside those, 1.84 with both
+    projections of every layer besides and 1.44 with the branch's output
+    too, every candidate of every layer (the plan the cell runs: 12.15 GB
+    in all), where the block's named values come to 1.85 and these to
+    1.61; the total less what is kept falls from 10.5 to 9.8, so the
+    schedule that runs a layer's target and backward kernel before the
+    next layer's scores holds under all five (PERF.md section 6, PR 62).
+    It was sixteen while six such buffers stood at the peak (PR 60: 5.91 GB
+    of 1.6 + 4.29; PR 61 took them to one). The buffers are alive in a
+    block's FORWARD pass and are counted with its backward set as if they
+    met: from above."""
     if kind != SPARSE_ATTENTION:
         return 0
     batch = jax.tree.leaves(x)[0].shape[0]
-    return 16 * batch * (tokens // batch) ** 2
+    return 6 * batch * (tokens // batch) ** 2
 
 
 def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
@@ -1783,7 +1840,8 @@ def remat_plan(dec: Decoder, layers, x, vocab: int, capacity: Optional[int],
                     jax.tree.structure(layer), shared)
             if seen not in traced:
                 traced[seen] = _block_account(_block_of(dec, *key), x, layer,
-                                              shared, _kept(key[0]))
+                                              shared, _kept(key[0]),
+                                              _fits(key[0]))
             x, shared, base, extras = traced[seen]
             accounts.append((base, extras))
     base = sum(base for base, _ in accounts)

@@ -123,6 +123,40 @@ def test_one_step_trains_two_disjoint_sets_of_leaves(both, term):
             assert reached == mine, (term, name)
 
 
+def test_kept_or_made_again_is_the_same_step_bit_for_bit(monkeypatch):
+    """A two-layer sparse stack under remat, the kernels interpreted:
+    with room for everything (`step_memory`'s forced capacity) each block
+    keeps q, what its q, k and v are made from and the branch's output,
+    with none the base set alone, and the loss, L_I and every gradient
+    are equal to the bit. Keeping a value changes no arithmetic."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    cfg = dataclasses.replace(CFG, n_layers=2)
+    params = keye_vl2_init(jax.random.PRNGKey(5), cfg)
+    batch = _batch(cfg)
+    asked = []
+    real = decoder.remat_plan
+    monkeypatch.setattr(decoder, "remat_plan", lambda *a, **k: asked.append(
+        real(*a, **k)) or asked[-1])
+
+    def step(capacity):
+        with attention.step_memory(state_bytes=0, capacity=capacity):
+            return jax.jit(jax.value_and_grad(
+                lambda p: keye_vl2_loss_and_counters(p, batch, cfg),
+                has_aux=True))(params)
+
+    (loss, counters), grads = step(1 << 40)
+    (base_loss, base_counters), base = step(0)
+    names = ("attention_k_heads", "attention_kv_proj", "attention_q_proj",
+             "flash_attention_q", "moe_choice", "sparse_attention_out")
+    assert [plan.extras for plan in asked] == [(names,) * 2, ((),) * 2]
+    assert float(loss) == float(base_loss)
+    np.testing.assert_array_equal(counters["index_loss"],
+                                  base_counters["index_loss"])
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(base)):
+        np.testing.assert_array_equal(got, want)
+        assert float(jnp.max(jnp.abs(want))) > 0
+
+
 def _selections(cfg, params, tokens):
     """(the program's selection of layer 0, the reference's)."""
     lay, dec = params["layers"][0], cfg.decoder()

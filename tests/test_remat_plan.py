@@ -7,6 +7,7 @@ Mamba-2 + experts decoder, that a step under an extended policy is the base
 policy's step to float32 rounding. What XLA makes of each cell's plan is
 tests/test_compile_v5e_*.py's."""
 
+import collections
 import dataclasses
 import json
 import os
@@ -63,6 +64,13 @@ def granite(monkeypatch):
     from chipbench.families import granite_hybrid
     return _cell(monkeypatch, granite_hybrid, "granite-4.0-h-micro",
                  "pretrain-granite4h-b1-s16384")
+
+
+@pytest.fixture
+def keye(monkeypatch):
+    from chipbench.families import keye_vl2
+    return _cell(monkeypatch, keye_vl2, "keye-vl-2.0-30b-a3b",
+                 "pretrain-keyevl2-b1-s16384")
 
 
 def test_nemotrons_cell_keeps_every_candidate_of_its_four_mamba_layers(
@@ -134,17 +142,20 @@ def test_no_capacity_or_no_state_is_the_base_set(nemotron, capacity, state):
     assert plan.base_bytes == 3_009_413_120       # counted all the same
 
 
-def test_more_capacity_never_keeps_less(nemotron):
-    dec, layers, x, vocab, state_bytes = nemotron
+@pytest.mark.parametrize("cell, eighths, extended", [
+    ("nemotron", range(96, 128), 8),              # 12 to 15.875 GiB
+    ("keye", range(80, 128), 6)])                 # 10 to 15.875: by kind too
+def test_more_capacity_never_keeps_less(request, cell, eighths, extended):
+    dec, layers, x, vocab, state_bytes = request.getfixturevalue(cell)
     before, steps = None, 0
-    for eighths in range(96, 128):                # 12 to 15.875 GiB
-        plan = decoder.remat_plan(dec, layers, x, vocab, eighths << 27,
+    for eighth in eighths:
+        plan = decoder.remat_plan(dec, layers, x, vocab, eighth << 27,
                                   state_bytes)
         if before is not None:
-            assert before.kept_extra_bytes <= plan.kept_extra_bytes, eighths
+            assert before.kept_extra_bytes <= plan.kept_extra_bytes, eighth
             steps += before.kept_extra_bytes < plan.kept_extra_bytes
         before = plan
-    assert steps >= 4 and before.layers_extended == 8
+    assert steps >= 4 and before.layers_extended == extended
 
 
 def test_a_batch_over_several_chips_is_planned_at_a_chips_share(
@@ -199,6 +210,23 @@ def test_only_activations_shrink_with_a_chips_share(nemotron):
 _MAMBA, _EXPERTS = ("ssm_gated", "ssm_in_proj"), ("moe_choice", "moe_shared_up")
 
 
+def _offered(monkeypatch) -> collections.Counter:
+    """name -> bytes of every candidate the blocks' accounts list from here
+    on (a block is traced once a kind and shape), a layer's values of one
+    name together."""
+    offered = collections.Counter()
+    real = decoder._block_account
+
+    def account(*args, **kwargs):
+        out = real(*args, **kwargs)
+        for name, size, _ in out[3]:
+            offered[name] += size
+        return out
+
+    monkeypatch.setattr(decoder, "_block_account", account)
+    return offered
+
+
 @pytest.mark.parametrize("family, config, traffic, extras, kept", [
     ("olmoe", "olmoe-1b-7b", "pretrain-olmoe-b4-s4096", ((),) * 2, 0),
     ("granite_hybrid", "granite-4.0-h-micro", "pretrain-granite4h-b1-s16384",
@@ -239,19 +267,50 @@ def test_a_block_joined_by_the_add_offers_no_branch_output(
         config, traffic)
     assert dec.hyper is None
     assert decoder.LATENT_ATTENTION not in dec.kinds
-    offered = set()
-    real = decoder._block_account
-
-    def account(*args, **kwargs):
-        out = real(*args, **kwargs)
-        offered.update(name for name, _, _ in out[3])
-        return out
-
-    monkeypatch.setattr(decoder, "_block_account", account)
+    offered = _offered(monkeypatch)
     plan = decoder.remat_plan(dec, layers, x, vocab, V5E_BYTES, state_bytes)
-    assert not offered & {"hc_channel_out", "flash_attention_q"}
+    assert not set(offered) & {"hc_channel_out", "flash_attention_q"}
+    # nor what a sparse block's q, k and v are made from, though granite's,
+    # olmo-hybrid's, Nemotron's and LFM2's attention layers run the arm of
+    # `_qkv_heads` that names them: candidates of that kind alone
+    assert not set(offered) & set(
+        decoder.FITS_BY_KIND[decoder.SPARSE_ATTENTION])
     assert plan.extras == extras
     assert plan.kept_extra_bytes == kept
+
+
+def test_a_sparse_block_offers_what_its_q_k_v_are_made_from(keye,
+                                                            monkeypatch):
+    """Keye-VL-2.0's six sparse blocks at 16,384 tokens of 32 | 4 heads of
+    128, `wq` and `wkv` apart: each offers q as the kernels take it and,
+    by its kind's own table, the q projection's output ([16384, 4096]
+    bfloat16, as large), the k | v projection's ([16384, 1024]), k normed
+    and rotated at kv-head width ([1, 4, 16384, 128]) and the branch's
+    output ([16384, 2048]), beside its routing's choices; never k's and
+    v's copies across a group. Beside 5.29 GB of state on a v5e all six
+    keep all six, and 1.7 GB are left."""
+    dec, layers, x, vocab, state_bytes = keye
+    assert set(dec.kinds) == {decoder.SPARSE_ATTENTION}
+    offered = _offered(monkeypatch)
+    plan = decoder.remat_plan(dec, layers, x, vocab, V5E_BYTES, state_bytes)
+    sizes = {"attention_q_proj": 134_217_728, "attention_kv_proj": 33_554_432,
+             "attention_k_heads": 16_777_216,
+             "sparse_attention_out": 67_108_864,
+             "flash_attention_q": 134_217_728, "moe_choice": 1_572_928}
+    assert offered == sizes
+    assert plan.extras == (tuple(sorted(sizes)),) * 6
+    assert plan.kept_extra_bytes == 6 * sum(sizes.values()) == 2_324_693_376
+    assert plan.bytes_left == 1_666_242_872
+    # the order is cost saved a byte kept: with room for 1.4 GB, all six q,
+    # rotated k and choices (what stands first), then all six outputs (two
+    # matmuls an element), then the k | v projections of two layers (one)
+    some = decoder.remat_plan(
+        dec, layers, x, vocab, V5E_BYTES - plan.bytes_left
+        - plan.kept_extra_bytes + 14 * 10 ** 8, state_bytes)
+    firsts = ("attention_k_heads", "flash_attention_q", "moe_choice",
+              "sparse_attention_out")
+    assert some.extras == (
+        tuple(sorted(firsts + ("attention_kv_proj",))),) * 2 + (firsts,) * 4
 
 
 def test_the_second_table_is_beside_the_first():
@@ -263,6 +322,15 @@ def test_the_second_table_is_beside_the_first():
     assert [kind for kind in decoder.MIXERS
             if "flash_attention_q" not in decoder._kept(kind)] \
         == [decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION]
+    # and a third beside both, by kind: a sparse block's own candidates,
+    # names of neither table
+    assert list(decoder.FITS_BY_KIND) == [decoder.SPARSE_ATTENTION]
+    own = set(decoder.FITS_BY_KIND[decoder.SPARSE_ATTENTION])
+    assert not own & (set(decoder.KEPT_WHERE_IT_FITS)
+                      | set(decoder.KEPT_UNDER_REMAT))
+    assert set(decoder._fits(decoder.SPARSE_ATTENTION)) \
+        == own | set(decoder.KEPT_WHERE_IT_FITS)
+    assert decoder._fits(decoder.ATTENTION) == decoder.KEPT_WHERE_IT_FITS
     value = jax.ShapeDtypeStruct((16384, 10304), jnp.bfloat16)
     per_byte = {name: cost(value, 2688) / (value.size * 2)
                 for name, cost in decoder.KEPT_WHERE_IT_FITS.items()}
