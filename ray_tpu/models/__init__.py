@@ -114,3 +114,12 @@ from .keye_vl2 import (  # noqa: F401
     keye_vl2_param_axes,
     make_keye_vl2_train_step,
 )
+from .bailing_hybrid import (  # noqa: F401
+    BailingHybridConfig,
+    bailing_hybrid_forward,
+    bailing_hybrid_init,
+    bailing_hybrid_loss,
+    bailing_hybrid_loss_and_counters,
+    bailing_hybrid_param_axes,
+    make_bailing_hybrid_train_step,
+)

@@ -17,7 +17,7 @@ a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
 runs it, what state it keeps in a cache, whether it is windowed, hands its
 keys and values on, or reads its place in the stack, and which branches
 its block has: a sequence mixer, the layer's channel mixer, or both. A
-new sequence mixer is its function and its row. The fourteen kinds:
+new sequence mixer is its function and its row. The fifteen kinds:
 
     ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
                    max_len, head_dim]}
@@ -55,11 +55,17 @@ new sequence mixer is its function and its row. The fourteen kinds:
                    query's own lightning indexer scores highest (DeepSeek
                    sparse attention, ops/sparse_index.py); ATTENTION's
                    state and {"k_index": [batch, max_len, index width] the
-                   indexer's rotated keys}. The one sequence mixer that
+                   indexer's rotated keys}. A sequence mixer that
                    hands `stats` out of the stack, its indexer's own loss
                    term `index_loss` and `selected_keys_mean`, in its
                    layer's entry of `decoder_hidden`'s list beside the
                    channel mixer's
+    KDA            Kimi Delta Attention, the delta rule with a decay a key
+                   channel (ops/kda.py); {"conv": [batch, taps - 1, heads x
+                   (2 K + V)] the last inputs over q | k | v, "kda":
+                   [batch, heads, K, V] float32}, which does not grow. It
+                   hands a counter out of the stack as `stats`,
+                   `kda_log_decay_min`
 
 Every leaf of a layer's state has the batch first: that is the table's one
 rule (models.generate.make_continuous_fns cuts a slot out of axis 0 of
@@ -99,7 +105,7 @@ and outputs, and their gradients arrive from every reader.
     hyper_connection    a hyper-connected branch's three sets of
                         coefficients, from the streams and its weights
       attention | sparse_attention | latent_attention | mamba2 | mamba1 |
-      gated_delta | short_conv | gmu | diff_attention
+      gated_delta | kda | short_conv | gmu | diff_attention
                         the sequence mixers, (x, layer, dec, cache,
                         start_pos[, shared, index, window, ...]) -> (y,
                         new cache[, shared or stats]): attention is the flash
@@ -113,7 +119,9 @@ and outputs, and their gradients arrive from every reader.
                         mamba1 the same over ops.selective_scan;
                         gated_delta the same over ops.gated_delta, a matrix
                         state a head that is read back before it is
-                        written; short_conv the kernels over the whole
+                        written; kda the same over ops.kda, the state's
+                        decay a vector a head, one number a key channel;
+                        short_conv the kernels over the whole
                         sequence, the shifted products from a cached tail;
                         gmu no state at all; diff_attention two
                         softmax maps a pair of heads, their difference
@@ -139,6 +147,7 @@ from ..ops.attention import (DEFAULT_MASK_VALUE, a_chip_alone,
                              attention_and_lse, flash_attention,
                              step_memory_given, step_sharding)
 from ..ops.gated_delta import gated_delta_rule
+from ..ops.kda import kda_rule
 from ..ops.layers import (NORM_EPS, ROPE_BASE, causal_conv1d_silu,
                           gated_rms_norm, head_rms_norm, head_rms_norm_gated,
                           head_spread, head_sums, layer_norm, rms_norm, rope,
@@ -157,10 +166,10 @@ from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
 # The kinds of layer: the keys of MIXERS, below the mixers.
 (ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
  DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV,
- LATENT_ATTENTION, SPARSE_ATTENTION) = (
+ LATENT_ATTENTION, SPARSE_ATTENTION, KDA) = (
     "attention", "mamba2", "mamba1", "gated_delta", "gmu", "diff_windowed",
     "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts",
-    "short_conv", "latent_attention", "sparse_attention")
+    "short_conv", "latent_attention", "sparse_attention", "kda")
 
 
 class HyperConnections(NamedTuple):
@@ -213,6 +222,9 @@ class Decoder(NamedTuple):
     # The keys a SPARSE_ATTENTION layer's query may name (its indexer's
     # heads and widths are read off its weights).
     sparse_topk: int = 0
+    # The bound a KDA layer's gate keeps a step's log-decay above (its
+    # chunk is `delta_chunk`; heads and widths are read off its weights).
+    kda_lower_bound: float = -5.0
 
 
 def gelu_mlp(y, layer):
@@ -263,7 +275,8 @@ def held_routed_experts(y, layer, experts_per_token: int, first: int,
 
 def held_gated_experts(y, layer, experts_per_token: int, first: int,
                        routed_scale: float, weight_eps: float,
-                       bias_rounds: int = 0, softmax: bool = False):
+                       bias_rounds: int = 0, softmax: bool = False,
+                       n_group: int = 1, topk_group: int = 1):
     """One chip's share of top-k SwiGLU experts (those from `first` on, as
     many as the layer holds; `expert_gate_up` is each one's gate and up
     matrices side by side) and, where the layer holds one, the shared
@@ -271,8 +284,9 @@ def held_gated_experts(y, layer, experts_per_token: int, first: int,
     DeepSeek-V3's; LFM2 has none), over the flattened tokens, the k
     weights over their sum + `weight_eps`, the selection bias moved
     `bias_rounds` rounds on the tokens' scores first, or with `softmax` a
-    softmax router with no bias, the layer holding none; `stats` are
-    parallel.moe.held_moe_layer's, one layer's."""
+    softmax router with no bias, the layer holding none; with `n_group` >
+    1 the k chosen inside the `topk_group` groups a token keeps; `stats`
+    are parallel.moe.held_moe_layer's, one layer's."""
     b, s, d = y.shape
     out, stats = held_moe_layer(
         y.reshape(b * s, d), layer["router"], layer.get("router_bias"),
@@ -280,7 +294,9 @@ def held_gated_experts(y, layer, experts_per_token: int, first: int,
         layer.get("shared_gate_up"), layer.get("shared_down"),
         experts_per_token=experts_per_token, first=first,
         routed_scale=routed_scale, bias_rounds=bias_rounds, gated=True,
-        weight_eps=weight_eps, **({"softmax": True} if softmax else {}))
+        weight_eps=weight_eps, **({"softmax": True} if softmax else {}),
+        **({"n_group": n_group, "topk_group": topk_group}
+           if n_group > 1 else {}))
     return out.reshape(b, s, d), stats
 
 
@@ -604,7 +620,9 @@ def latent_attention(x, layer, dec: Decoder, cache=None, start_pos=None):
     is a scaled one, k_r the same under every head; a head's [k_n | v] =
     c W_kvb; scores q . [k_n | k_r] / sqrt(n + r) unless `dec.sm_scale`
     says otherwise (YaRN's carries its temperature squared); out =
-    concat(P v) W_o. Every width is read off the weights. One
+    concat(P v) W_o, or where the layer holds `head_gate` [d, heads]
+    (concat(P v) * sigmoid(y W_g), one gate a head) W_o. Every width is
+    read off the weights. One
     implementation serves training (the flash kernel, q and k wider than
     v), prefill and decode: with a cache the normed latent and the rotated
     key of x's positions are written into it, K and V of ALL cached
@@ -653,7 +671,19 @@ def latent_attention(x, layer, dec: Decoder, cache=None, start_pos=None):
     else:
         attn = _attend_cache(q, k, v, sp, dec.sm_scale)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * vd)
+    if "head_gate" in layer:
+        with jax.named_scope("mla_gate"):
+            attn = _gated_by_head(attn, y, layer["head_gate"])
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+def _gated_by_head(t, y, w_gate):
+    """t [b, L, heads * W] times sigmoid(y w_gate) [b, L, heads], one gate
+    a head on all of its columns, in float32 and t's own layout."""
+    gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", y, w_gate,
+                                     preferred_element_type=jnp.float32))
+    return (t.astype(jnp.float32)
+            * head_spread(gate, t.shape[-1])).astype(t.dtype)
 
 
 def mamba2(x, layer, dec: Decoder, cache=None, start_pos=None):
@@ -722,6 +752,20 @@ def _unit_heads(t, heads: int, scale: float, eps: float):
         b, L, heads, -1)
 
 
+def _delta_rule_step(state, decay, q, k, v, beta):
+    """One token of a delta rule, a decode step's: state [b, H, K, V]
+    float32 times `decay` (a head's [b, H, 1, 1], or a key channel's [b, H,
+    K, 1]), read back, corrected and written; q, k [b, 1, H, K], v [b, 1,
+    H, V], beta [b, 1, H] -> (o [b, 1, H, V] in v's dtype, the new
+    state)."""
+    q1, k1, v1 = (t[:, 0].astype(jnp.float32) for t in (q, k, v))
+    state = decay * state
+    u = beta[:, 0, :, None] * (v1 - jnp.einsum("bhkv,bhk->bhv", state, k1))
+    state = state + k1[..., :, None] * u[..., None, :]
+    return (jnp.einsum("bhkv,bhk->bhv", state, q1)[:, None].astype(v.dtype),
+            state)
+
+
 def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
     """The Gated DeltaNet mixer of x [b, L, d], from the input norm to the
     output projection: one projection to q | k | v, a causal depthwise
@@ -754,12 +798,8 @@ def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
     g = -jnp.exp(layer["A_log"].astype(f32)) * jax.nn.softplus(
         a + layer["dt_bias"])
     if cache is not None and L == 1:
-        q1, k1, v1 = (t[:, 0].astype(f32) for t in (q, k, v))
-        state = jnp.exp(g[:, 0])[..., None, None] * cache["delta"]
-        u = beta[:, 0, :, None] * (
-            v1 - jnp.einsum("bhkv,bhk->bhv", state, k1))
-        state = state + k1[..., :, None] * u[..., None, :]
-        o = jnp.einsum("bhkv,bhk->bhv", state, q1)[:, None].astype(x.dtype)
+        o, state = _delta_rule_step(
+            cache["delta"], jnp.exp(g[:, 0])[..., None, None], q, k, v, beta)
     else:
         o, state = gated_delta_rule(
             q, k, v, g, beta, dec.delta_chunk,
@@ -770,6 +810,68 @@ def gated_delta(x, layer, dec: Decoder, cache=None, start_pos=None):
         o = head_rms_norm_gated(o, gate, layer["delta_norm"], dec.norm_eps)
     new_cache = None if cache is None else {"conv": tail, "delta": state}
     return jnp.einsum("bse,ed->bsd", o, layer["delta_out"]), new_cache
+
+
+def _kda_sizes(layer):
+    """(heads, key width, value width) of a KDA layer, off its weights (or
+    their shapes): `kda_in` is q | k | v side by side, `kda_f` the decay's
+    projection, a key's width a head."""
+    H, V = layer["A_log"].shape[0], layer["kda_norm"].shape[0]
+    return H, layer["kda_f"].shape[1] // H, V
+
+
+def kda(x, layer, dec: Decoder, cache=None, start_pos=None):
+    """The Kimi Delta Attention mixer of x [b, L, d] (arXiv:2510.26692),
+    from the input norm to the output projection: one projection to q | k |
+    v, a causal depthwise convolution with no bias and silu over all of
+    them, q and k L2-normalised a head (q scaled by 1 / sqrt(key width)
+    besides), beta = sigmoid(y W_beta) in (0, 1) a head, the log-decay ONE
+    NUMBER A KEY CHANNEL, g = `dec.kda_lower_bound` sigmoid(exp(A_log_h) (y
+    W_f + dt_bias)) in (bound, 0) (flash-linear-attention's lower-bounded
+    gate, float32), the delta rule (ops.kda), an RMSNorm a head THEN one
+    sigmoid gate a head (`head_gate`), the output projection. Training,
+    prefill from a cached state and decode (L = 1: one step of the
+    recurrence) as `gated_delta`, whose convolution, L2 norm and head norm
+    these are; `start_pos` is not read. Returns (y, new_cache or None,
+    {"kda_log_decay_min": the smallest g of the call})."""
+    b, L, d = x.shape
+    H, K, V = _kda_sizes(layer)
+    f32 = jnp.float32
+    y = _norm_if_held(x, layer, "ln1", dec.norm_eps)
+    qkv = checkpoint_name(jnp.einsum("bsd,de->bse", y, layer["kda_in"]),
+                          "kda_in")
+    with jax.named_scope("ssm_conv"):
+        qkv, tail = causal_conv1d_silu(
+            qkv, layer["conv_w"], None,
+            None if cache is None else cache["conv"])
+    q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
+    with jax.named_scope("kda_qk_norm"):
+        q = _unit_heads(q, H, K ** -0.5, dec.norm_eps)
+        k = _unit_heads(k, H, 1.0, dec.norm_eps)
+    v = v.reshape(b, L, H, V)
+    with jax.named_scope("kda_gate"):
+        f = jnp.einsum("bsd,de->bse", y, layer["kda_f"],
+                       preferred_element_type=f32) + layer["dt_bias"]
+        rate = jnp.repeat(jnp.exp(layer["A_log"].astype(f32)), K)
+        g = checkpoint_name(
+            dec.kda_lower_bound * jax.nn.sigmoid(rate * f), "kda_g")
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", y, layer["kda_beta"], preferred_element_type=f32))
+    g = g.reshape(b, L, H, K)
+    if cache is not None and L == 1:
+        o, state = _delta_rule_step(
+            cache["kda"], jnp.exp(g[:, 0])[..., None], q, k, v, beta)
+    else:
+        o, state = kda_rule(
+            q, k, v, g, beta, dec.delta_chunk,
+            None if cache is None else cache["kda"], dec.kda_lower_bound)
+    with jax.named_scope("kda_gate_norm"):
+        o = _gated_by_head(
+            head_rms_norm(o.reshape(b, L, H * V), layer["kda_norm"],
+                          dec.norm_eps), y, layer["head_gate"])
+    new_cache = None if cache is None else {"conv": tail, "kda": state}
+    return (jnp.einsum("bse,ed->bsd", o, layer["kda_out"]), new_cache,
+            {"kda_log_decay_min": jnp.min(g)})
 
 
 def short_conv(x, layer, dec: Decoder, cache=None, start_pos=None):
@@ -979,6 +1081,13 @@ def _delta_state(dec: Decoder, layer, batch, max_len, dtype):
             "delta": jnp.zeros((batch, H, K, V), jnp.float32)}
 
 
+def _kda_state(dec: Decoder, layer, batch, max_len, dtype):
+    H, K, V = _kda_sizes(layer)
+    taps = layer["conv_w"].shape[1]
+    return {"conv": jnp.zeros((batch, taps - 1, H * (2 * K + V)), dtype),
+            "kda": jnp.zeros((batch, H, K, V), jnp.float32)}
+
+
 def _short_conv_state(dec: Decoder, layer, batch, max_len, dtype):
     taps, d = layer["conv_taps"].shape
     return {"conv": jnp.zeros((batch, taps - 1, d), dtype)}
@@ -1008,6 +1117,11 @@ def _mamba2(x, layer, dec, cache, start_pos, shared, index, window):
 
 def _gated_delta(x, layer, dec, cache, start_pos, shared, index, window):
     return (*gated_delta(x, layer, dec, cache, start_pos), shared)
+
+
+def _kda(x, layer, dec, cache, start_pos, shared, index, window):
+    y, new_cache, stats = kda(x, layer, dec, cache, start_pos)
+    return y, new_cache, shared, stats
 
 
 def _short_conv(x, layer, dec, cache, start_pos, shared, index, window):
@@ -1113,6 +1227,7 @@ MIXERS: Dict[str, Mixer] = {
     SHORT_CONV: Mixer(_short_conv, _short_conv_state),
     LATENT_ATTENTION: Mixer(_latent_attention, _latent_state),
     SPARSE_ATTENTION: Mixer(_sparse_attention, _sparse_state),
+    KDA: Mixer(_kda, _kda_state),
 }
 
 
@@ -1259,7 +1374,16 @@ KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
         name for name in KEPT_UNDER_REMAT
         if name not in ("flash_attention_k", "flash_attention_v",
                         "flash_attention_q"))
-    + ("sparse_selected", "sparse_index_grads")}
+    + ("sparse_selected", "sparse_index_grads"),
+    # A KDA layer keeps what a gated-delta-rule layer does, under its own
+    # kernels' names (ops/kda.py): the state ENTERING each chunk of 64
+    # tokens (the model's dtype [chunks, heads, K, V]: 268 MB a layer at
+    # 16,384 tokens of 32 heads of 128 x 128), each head's and chunk's T - I
+    # (67 MB) and the rule's output o (134 MB). Its projections, convolution,
+    # L2 norms, beta, the float32 log-decay g [T, heads * K] (268 MB a
+    # layer there, where gated_delta's is a [T, 30]: a candidate below),
+    # head norm, gate and output projection are made again.
+    KDA: KEPT_UNDER_REMAT + ("kda_o", "kda_states", "kda_T")}
 
 
 def _kept(kind: str) -> Tuple[str, ...]:
@@ -1383,11 +1507,15 @@ KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
 # other cells run the same arm, their plans are full, and a candidate at a
 # matmul's rate would reshuffle what they keep with nothing measured to say
 # it should.
+# A KDA block's: its q | k | v projection (`kda_in`, a matmul an element)
+# and the float32 log-decay `kda_g`, a matmul an element too but four bytes
+# wide, so half the saving a byte: after every two-byte projection.
 FITS_BY_KIND: Dict[str, Dict[str, Callable]] = {
     SPARSE_ATTENTION: {"attention_q_proj": _a_matmul,
                        "attention_kv_proj": _a_matmul,
                        "attention_k_heads": _first,
-                       "sparse_attention_out": _matmuls(2)}}
+                       "sparse_attention_out": _matmuls(2)},
+    KDA: {"kda_in": _a_matmul, "kda_g": _a_matmul}}
 
 
 def _fits(kind: str) -> Dict[str, Callable]:
@@ -1718,6 +1846,27 @@ def _latent_holds(kind: str, tokens: int, layer, dec: Decoder) -> int:
     return 3 * tokens * dec.n_heads * (n + r + vd) * itemsize
 
 
+def _kda_holds(kind: str, tokens: int, layer) -> int:
+    """What the backward pass of a KDA block holds that no name shows, in
+    float32 [T, heads * K] values: eight of them (268 MB each at 16,384
+    tokens of 32 heads of 128). Where `gated_delta`'s log-decay is a [T, 30],
+    this rule's is a value a key channel: g, its running sums, the sums'
+    gradient from the backward kernel and g's own are four such, and the
+    convolution's hand-written backward works q | k | v, three widths of it,
+    in float32 (silu's slope times the cotangent, then the taps the other
+    way) beside the L2 norms' float32 passes over q and k. A calibration,
+    not a count: XLA's account of Ling-3.0-flash's six-layer step compiled
+    for a v5e, total - state - base set - what the plan keeps, reads 4.07 GB
+    with nothing kept and 3.35 with every layer's `kda_in` kept (which the
+    block's named values then count), where the block's named values and
+    the held experts' rule account for 1.98: 2.09 more, and eight are 2.15
+    (PERF.md section 6, PR 63)."""
+    if kind != KDA:
+        return 0
+    H, K, _ = _kda_sizes(layer)
+    return 8 * tokens * H * K * 4
+
+
 def _selection_holds(kind: str, tokens: int, x) -> int:
     """What a sparse-attention block holds that no name shows, in [T, T]
     buffers a sequence: six bytes a (query, key) pair. Five are counted: at
@@ -1755,7 +1904,8 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
     of the block that has a name in either table, alive at once while it
     is differentiated, and what no name shows (`unnamed`: what its channel
     mixer's own rule says it holds, `_backward_holds`, and the fitted
-    `_streams_hold`, `_latent_holds` and `_selection_holds`). On one chip the two do
+    `_streams_hold`, `_latent_holds`, `_selection_holds` and `_kda_holds`). On
+    one chip the two do
     not meet and the largest block counts: the larger of the loss and it;
     where the step has further `losses` over the one head (a prediction
     module's), a working set each beside either: the stack's loss leaves
@@ -1782,7 +1932,8 @@ def _reserve(dec: Decoder, accounts, keys, layers, x, vocab: int, chips: int,
         rule = _backward_holds(key[1], tokens, layer)
         fitted = _streams_hold(x, layer) \
             + _latent_holds(key[0], tokens, layer, dec) \
-            + _selection_holds(key[0], tokens, x)
+            + _selection_holds(key[0], tokens, x) \
+            + _kda_holds(key[0], tokens, layer)
         # The cotangent of the block's output waits while the block is
         # differentiated. The two fitted terms were read off XLA's totals
         # with it among them, and a block of named values alone is counted

@@ -1,7 +1,7 @@
 """Autoregressive generation with a cache, for any config with a
-`decoder()` and an `init(key)` (all ten families of ray_tpu.models: gpt,
-llama, moe, hybrid, sambay, olmo_hybrid, nemotron_h, lfm2_moe, xing4,
-glm4_moe_lite). No family is named here.
+`decoder()` and an `init(key)` (every decoder family of ray_tpu.models:
+gpt, llama, moe, hybrid, sambay, olmo_hybrid, nemotron_h, lfm2_moe, xing4,
+glm4_moe_lite, keye_vl2, bailing_hybrid). No family is named here.
 
 Parity role: the reference serves LLMs by hosting external engines
 (vLLM etc.) on its actors; here the decode path is native — a
@@ -11,7 +11,7 @@ position, fp32 logits. The serving layer (llm.serving) drives these
 jitted steps and streams tokens through Serve.
 
 The cache is models.decoder's: one dict a layer, the state of the layer's
-kind by `decoder.MIXERS` (the thirteen kinds and each one's layout are in that
+kind by `decoder.MIXERS` (the kinds and each one's layout are in that
 module's docstring), every leaf with the batch first, which is what lets
 `make_continuous_fns` cut one request's slot out of axis 0 of every leaf.
 A family names its layers' kinds in `cfg.decoder().kinds`; the sizes come

@@ -11,6 +11,7 @@ from .attention import flash_attention, mha_reference  # noqa: F401
 from .gated_delta import (gated_delta_plan, gated_delta_reference,  # noqa: F401
                           gated_delta_rule)
 from .grouped_matmul import grouped_matmul  # noqa: F401
+from .kda import kda_plan, kda_reference, kda_rule  # noqa: F401
 from .layers import (causal_conv1d_silu, gated_rms_norm,  # noqa: F401
                      head_rms_norm_gated, layer_norm, rms_norm, rope, swiglu)
 from .loss import cross_entropy  # noqa: F401

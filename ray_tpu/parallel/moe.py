@@ -334,13 +334,41 @@ def router_scores(x, router_w, softmax: bool = False):
         else jax.nn.sigmoid(logits)
 
 
+def within_groups(biased, n_group: int = 1, topk_group: int = 1):
+    """The one place a token's experts are narrowed before its top k is
+    taken (`held_moe_layer`'s choice and `balance_bias`'s counts both pass
+    through it): DeepSeek-V3's group limit. The E experts are `n_group`
+    groups of E / n_group neighbours (a host's, where a group is a host);
+    a group's mark is the sum of its two largest `biased` scores, the
+    `topk_group` groups with the largest marks are kept, and every expert
+    of another group falls out of the choice (-inf). Returns (`biased`
+    [N, E] so narrowed, which groups each row kept [N, n_group] bool); with
+    one group `biased` as it is and None: no operation is added."""
+    if n_group == 1:
+        return biased, None
+    n, e = biased.shape
+    grouped = biased.reshape(n, n_group, e // n_group)
+    member = lax.broadcasted_iota(jnp.int32, grouped.shape, 2)
+    best = jnp.argmax(grouped, axis=-1, keepdims=True)
+    marks = jnp.max(grouped, axis=-1) + jnp.max(
+        jnp.where(member == best, -jnp.inf, grouped), axis=-1)
+    _, kept = lax.top_k(marks, topk_group)
+    keep = jnp.any(
+        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype), axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e), keep
+
+
 # balance_bias ranks the tokens' experts anew once in this many rounds.
 _ROUNDS_A_RANKING = 8
 
 
-def balance_bias(scores, k: int, rounds: int = 256, start=None):
+def balance_bias(scores, k: int, rounds: int = 256, start=None,
+                 n_group: int = 1, topk_group: int = 1):
     """The selection bias b [E] float32 under which the top-k of `scores`
-    [N, E] + b fall evenly on the experts: the router's own update, b <- b
+    [N, E] + b (inside the groups `within_groups` keeps, where the router
+    has a group limit: the groups are chosen anew at every ranking, under
+    the bias as it then stands) fall evenly on the experts: the router's
+    own update, b <- b
     + r sign(mean(c) - c(b)) with c(b) the top-k counts under b, run to
     its fixed point from `start` (zeros) with the step r falling
     geometrically from the scores' spread to 1e-4 over `rounds`. A round
@@ -358,7 +386,7 @@ def balance_bias(scores, k: int, rounds: int = 256, start=None):
     steps = spread * (1e-4 / spread) ** jnp.linspace(0.0, 1.0, rounds)
 
     def refreshed(bias, steps):
-        biased = scores + bias
+        biased = within_groups(scores + bias, n_group, topk_group)[0]
         best = lax.top_k(biased, k + 1)[0]
         kth, next_best = best[:, k - 1:k], best[:, k:]
         # A chosen expert stays chosen while it beats the best one left
@@ -672,7 +700,8 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                    shared_down=None, *, experts_per_token: int, first: int,
                    routed_scale: float = 1.0, bias_rounds: int = 0,
                    gated: bool = False, weight_eps: float = 1e-20,
-                   softmax: bool = False):
+                   softmax: bool = False, n_group: int = 1,
+                   topk_group: int = 1):
     """One chip's part of a top-k expert layer, with the model's shared
     expert where it has one, no token dropped.
 
@@ -710,7 +739,15 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
     With `softmax` (Qwen3-MoE's router) s = softmax(x router_w) over all E
     in float32, the experts are the top k of s itself, and there is no
     bias: `router_bias` is None, no round runs, `stats` carry none, and
-    `router_prob_sum` is the sum of s (models.moe.balance_loss's)."""
+    `router_prob_sum` is the sum of s (models.moe.balance_loss's).
+
+    With `n_group` > 1 the top k are taken inside the `topk_group` groups
+    of E / n_group experts a token keeps (`within_groups`, on s +
+    router_bias; the bias's rounds choose under the same limit), and
+    `stats` carry `expert_groups_kept` [n_group] int32, the tokens that
+    kept each group (they sum to T * topk_group). The choice is made over
+    all E alike on every chip, so the held shares still add up to the
+    whole layer."""
     t, k, held = x.shape[0], experts_per_token, w_up.shape[0]
     plan = held_rows_plan(t, k, held, router_w.shape[-1])
     with jax.named_scope("moe_route"):
@@ -724,8 +761,10 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
             bias = lax.stop_gradient(router_bias)
             if bias_rounds:
                 bias = checkpoint_name(
-                    balance_bias(scores, k, bias_rounds, bias), "moe_probs")
+                    balance_bias(scores, k, bias_rounds, bias, n_group,
+                                 topk_group), "moe_probs")
             biased = scores + bias
+        biased, groups_kept = within_groups(biased, n_group, topk_group)
         _, experts = lax.top_k(biased, k)
         counts = _assignment_counts(experts, scores.shape[-1])
         # An absent expert's assignments sort after every held one's.
@@ -771,6 +810,9 @@ def held_moe_layer(x, router_w, router_bias, w_up, w_down, shared_up=None,
                  axis=0)}
     if bias is not None:
         stats["router_bias"] = bias
+    if groups_kept is not None:
+        stats["expert_groups_kept"] = jnp.sum(groups_kept, axis=0,
+                                              dtype=jnp.int32)
     return out, stats
 
 

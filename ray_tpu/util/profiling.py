@@ -165,6 +165,16 @@ DEVICE_SCOPES: Dict[str, str] = {
                        "and V' again, then every gradient of the rule, "
                        "chunks last to first; and round it _rule_bwd, the "
                        "whole rule",
+    "kda_fwd": "ops/kda.py _forward_call, the _kda_fwd_kernel pallas_call: a "
+               "KDA layer's chunked delta rule with a decay a key channel "
+               "(chunks of 64 in sub-blocks of 16 rows, a reference row "
+               "each), o and the state entering each chunk; and round it "
+               "_rule_fwd (the chunk sums of g, [T, heads * K] float32)",
+    "kda_bwd": "ops/kda.py _backward_call, the _kda_bwd_kernel pallas_call: "
+               "a chunk's decayed tiles, W, U and V' again from the kept "
+               "state and T - I, then every gradient of the rule, chunks "
+               "last to first; and round it _rule_bwd, the whole rule (the "
+               "chunk sums' transpose)",
     "short_conv_fwd": "ops/short_conv.py _forward_call, the "
                       "_conv_fwd_kernel pallas_call: a gated short "
                       "convolution's y = C * conv(B * x) from the "
@@ -177,9 +187,9 @@ DEVICE_SCOPES: Dict[str, str] = {
     "short_conv_proj": "models/decoder.py short_conv: the input "
                        "projection to B | C | x and the output projection "
                        "round the convolution's kernels",
-    "ssm_conv": "models/decoder.py mamba2, mamba1 and gated_delta: the "
+    "ssm_conv": "models/decoder.py mamba2, mamba1, gated_delta and kda: the "
                 "causal depthwise convolution (over x | B | C; Mamba-1: "
-                "over x; the delta rule: over q | k | v, no bias) and its "
+                "over x; the delta rules: over q | k | v, no bias) and its "
                 "silu; ops/layers.py _conv_silu_bwd runs under it too, as "
                 "every rule does under the scopes round its call",
     "delta_qk_norm": "models/decoder.py gated_delta: q and k divided by "
@@ -187,6 +197,17 @@ DEVICE_SCOPES: Dict[str, str] = {
                      "width)",
     "delta_gate_norm": "models/decoder.py gated_delta: the RMSNorm a head "
                        "of the rule's output, then silu(gate) times it",
+    "kda_qk_norm": "models/decoder.py kda: q and k divided by their L2 "
+                   "norm a head, q scaled by 1 / sqrt(key width)",
+    "kda_gate": "models/decoder.py kda: W_f to the float32 log-decay g = "
+                "bound * sigmoid(exp(A_log) (y W_f + dt_bias)), one number "
+                "a key channel, and beta = sigmoid(y W_beta)",
+    "kda_gate_norm": "models/decoder.py kda: the RMSNorm a head of the "
+                     "rule's output, then sigmoid(y W_g) times it, one "
+                     "gate a head",
+    "mla_gate": "models/decoder.py latent_attention where the layer holds "
+                "`head_gate`: sigmoid(y W_g), one gate a head, times the "
+                "heads' outputs before W_o",
     "gmu": "models/decoder.py gmu: a gated memory unit's gate projection, "
            "silu, the product with the handed-on scan output and the "
            "output projection",
@@ -214,7 +235,8 @@ DEVICE_SCOPES: Dict[str, str] = {
                   "fused first matrix",
     "layers": "models/decoder.py decoder_hidden, the layer stack of "
               "every decoder family (gpt, llama, moe, hybrid, sambay, "
-              "olmo_hybrid, nemotron_h, lfm2_moe, xing4, glm4_moe_lite), "
+              "olmo_hybrid, nemotron_h, lfm2_moe, xing4, glm4_moe_lite, "
+              "keye_vl2, bailing_hybrid), "
               "each layer run by "
               "the row of decoder.MIXERS "
               "that its config's `kinds` names, in the train step and "
@@ -252,7 +274,7 @@ DEVICE_SCOPES: Dict[str, str] = {
                         "the norm the mixer reads to the residual add"
        for kind in ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
                     "diff_windowed", "diff_full", "diff_cross", "short_conv",
-                    "latent_attention", "sparse_attention")},
+                    "latent_attention", "sparse_attention", "kda")},
     "sparse_index_proj": "models/decoder.py _index_heads: a lightning "
                          "indexer's three projections of the detached "
                          "normed input, its key norm and the rotary of q_I "

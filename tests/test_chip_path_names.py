@@ -36,6 +36,8 @@ from ray_tpu.models.sambay import SambaYConfig
 from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                           make_glm4_moe_lite_train_step)
 from ray_tpu.models.keye_vl2 import KeyeVL2Config, make_keye_vl2_train_step
+from ray_tpu.models.bailing_hybrid import (BailingHybridConfig,
+                                           make_bailing_hybrid_train_step)
 from ray_tpu.models.xing4 import Xing4Config, make_xing4_train_step
 from ray_tpu.util import profiling
 
@@ -219,7 +221,7 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
         "MIXER_SCOPES[kind]": {"ray_tpu/models/decoder.py"}}
     assert set(decoder.MIXER_SCOPES) == {
         kind for kind, row in decoder.MIXERS.items() if row.apply}
-    assert len(set(decoder.MIXER_SCOPES.values())) == 11
+    assert len(set(decoder.MIXER_SCOPES.values())) == 12
     assert set(scopes) | set(decoder.MIXER_SCOPES.values()) \
         == set(profiling.DEVICE_SCOPES)
     assert not set(scopes) & set(decoder.MIXER_SCOPES.values())
@@ -459,6 +461,7 @@ ATTENTION_KERNELS = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"}
 SCAN_KERNELS = {"_ssm_fwd_kernel", "_ssm_bwd_kernel"}
 CONV_KERNELS = {"_conv_fwd_kernel", "_conv_bwd_kernel"}
 INDEX_KERNELS = {"_index_fwd_kernel", "_index_bwd_kernel"}
+KDA_KERNELS = {"_kda_fwd_kernel", "_kda_bwd_kernel"}
 
 
 @pytest.mark.parametrize("make_step,cfg,batch,kernels", [
@@ -536,8 +539,21 @@ INDEX_KERNELS = {"_index_fwd_kernel", "_index_bwd_kernel"}
                    experts_held=(1, 2), experts_per_token=2, d_expert=128,
                    max_seq_len=256), 2,
      ATTENTION_KERNELS | INDEX_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
+    # a KDA layer (two heads of 128, the rule's two kernels) before a gated
+    # latent layer, a dense layer then experts 1-2 of 8 in 4 groups
+    (make_bailing_hybrid_train_step,
+     BailingHybridConfig(vocab_size=512, d_model=128, n_heads=2,
+                         kda_head_dim=128, qk_nope_head_dim=64,
+                         qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=64,
+                         n_layers=2, layer_group_size=2, n_dense_layers=1,
+                         d_ff=256, n_experts=8, experts_held=(1, 2),
+                         experts_per_token=2, n_group=4, topk_group=2,
+                         d_expert=128, bias_rounds=8, balance_tokens=0,
+                         max_seq_len=256), 2,
+     ATTENTION_KERNELS | KDA_KERNELS | {"_gmm_kernel", "_tgmm_kernel"}),
 ], ids=["tiny", "gpt2-small", "llama", "moe", "hybrid", "lfm2-moe", "sambay",
-        "olmo-hybrid", "nemotron-h", "xing4", "glm4-moe-lite", "keye-vl2"])
+        "olmo-hybrid", "nemotron-h", "xing4", "glm4-moe-lite", "keye-vl2",
+        "bailing-hybrid"])
 def test_lowered_train_step_carries_scopes_and_kernel_names(
         monkeypatch, make_step, cfg, batch, kernels):
     from ray_tpu.ops import attention
@@ -562,7 +578,7 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
             "grouped_matmul_dlhs", "grouped_matmul_drhs", "ssm_scan_fwd",
             "ssm_scan_bwd", "selective_scan_fwd", "selective_scan_bwd",
             "short_conv_fwd", "short_conv_bwd", "sparse_index_fwd",
-            "sparse_index_bwd"}, found
+            "sparse_index_bwd", "kda_fwd", "kda_bwd"}, found
     _every_branch_and_rule_sits_under_a_name(cfg, text)
     scopes = ["layers", "loss", "optimizer_update"]
     if kernels >= SCAN_KERNELS:
@@ -607,6 +623,22 @@ def test_lowered_train_step_carries_scopes_and_kernel_names(
             assert re.search(r'loc\("(?:[^"]*/)?sparse_index_%s/pallas_call"'
                              % kernel, text), kernel
         scopes += ["moe_route"]
+    if kernels >= KDA_KERNELS:
+        # the KDA mixer's parts inside the branch's scope, each kernel's own
+        # scope the innermost round its call; the latent layer's gate
+        # inside its own branch, the group choice inside `moe_route`
+        for part in ("ssm_conv", "kda_qk_norm", "kda_gate", "kda_fwd",
+                     "kda_bwd", "kda_gate_norm"):
+            assert re.search(
+                r'loc\("jit\(train_step\)/[^"]*\bkda_mixer\)*/'
+                r'(?:[^"]*/)?%s\b' % part, text), part
+        for kernel in ("fwd", "bwd"):
+            assert re.search(r'loc\("(?:[^"]*/)?kda_%s/pallas_call"'
+                             % kernel, text), kernel
+        assert re.search(r'loc\("jit\(train_step\)/[^"]*\b'
+                         r'latent_attention_mixer\)*/(?:[^"]*/)?mla_gate\b',
+                         text)
+        scopes += ["moe_route", "moe_shared", "mla_project", "mla_expand"]
     for scope in scopes:
         assert re.search(r'loc\("jit\(train_step\)/[^"]*\b%s\b' % scope,
                          text), scope
@@ -627,7 +659,8 @@ RULES = {
     "gated_delta": ("gated_delta_bwd", "ssm_conv"),
     "short_conv": ("short_conv_bwd",), "gmu": (), "experts": (),
     "latent_attention": ("flash_attention_bwd",),
-    "sparse_attention": ("flash_attention_bwd",)}
+    "sparse_attention": ("flash_attention_bwd",),
+    "kda": ("kda_bwd", "ssm_conv")}
 
 
 def _every_branch_and_rule_sits_under_a_name(cfg, text):

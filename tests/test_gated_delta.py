@@ -7,6 +7,7 @@ precision. 1e-4 of the largest value: float32 rounding through a few
 hundred dependent steps, and the inverse made by doubling blocks, stay
 under 1e-5 here; an all-bfloat16 state is off by 5e-3."""
 
+import functools
 import itertools
 
 import jax
@@ -72,9 +73,11 @@ def _inputs(L, decay, beta, with_state, b=2, H=3, K=12, V=20, seed=0):
     return (q, k, v, g, bt, init), weights
 
 
-def _all_of(fn, args, weights):
+def _all_of(fn, args, weights, jitted=False):
     """o, the final state and the gradient of a weighted sum of both by
-    every input (the initial state's too where there is one)."""
+    every input (the initial state's too where there is one). `jitted`:
+    as one program, which the plain forms build faster than op by op (the
+    interpreted kernels do not: a jit round them inlines the interpreter)."""
     n = 6 if args[5] is not None else 5
 
     def scalar(*given):
@@ -82,8 +85,8 @@ def _all_of(fn, args, weights):
         return (jnp.sum(o * weights[0]) + jnp.sum(state * weights[1]),
                 (o, state))
 
-    (_, (o, state)), grads = jax.value_and_grad(
-        scalar, argnums=tuple(range(n)), has_aux=True)(*args[:n])
+    run = jax.value_and_grad(scalar, argnums=tuple(range(n)), has_aux=True)
+    (_, (o, state)), grads = (jax.jit(run) if jitted else run)(*args[:n])
     return (o, state, *grads)
 
 
@@ -102,13 +105,23 @@ IDS = ["one-chunk", "chunks", "initial-state", "chunk-16", "no-decay-beta-2",
        "strong-decay-beta-0", "strong-decay-beta-2"]
 
 
+@functools.lru_cache(maxsize=None)
+def _wanted(L, decay, beta, with_state):
+    """A case's inputs and what the recurrence makes of them, once for
+    both forms of the rule (the chunk is the rule's, not the
+    recurrence's): 2 x L dependent steps and their gradients, traced and
+    built a case and not a case and form."""
+    args, weights = _inputs(L, decay, beta, with_state)
+    return args, weights, _all_of(reference.recurrence, args, weights,
+                                  jitted=True)
+
+
 @pytest.mark.parametrize("L,chunk,decay,beta,with_state", CASES, ids=IDS)
 def test_rule_and_every_gradient_match_the_recurrence(form, L, chunk, decay,
                                                       beta, with_state):
-    args, weights = _inputs(L, decay, beta, with_state)
-    want = _all_of(reference.recurrence, args, weights)
+    args, weights, want = _wanted(L, decay, beta, with_state)
     got = _all_of(lambda *a: gated_delta_rule(*a[:5], chunk, *a[5:]), args,
-                  weights)
+                  weights, jitted=form == "jax")
     for name, g, w in zip(NAMES, got, want, strict=False):
         _close(g, w), name
 
@@ -188,9 +201,9 @@ def test_call_that_is_not_differentiated_writes_no_inverse(monkeypatch):
 
 def test_reference_pads_a_length_that_is_no_whole_number_of_chunks():
     args, weights = _inputs(27, 1.0, "mid", True)
-    want = _all_of(reference.recurrence, args, weights)
+    want = _all_of(reference.recurrence, args, weights, jitted=True)
     got = _all_of(lambda *a: gated_delta_reference(*a[:5], 8, *a[5:]), args,
-                  weights)
+                  weights, jitted=True)
     for g, w in zip(got, want, strict=True):
         _close(g, w)
     # and the public rule takes the same path for such a length
@@ -267,10 +280,14 @@ def _kernel_wants(q, k, g, beta, v, init, chunk):
     float64 inverse of I + A), [b, chunks, chunk, heads * chunk]."""
     b, L, H, _ = q.shape
     nc = L // chunk
-    states = [init] + [
-        gated_delta_reference(q[:, :n], k[:, :n], v[:, :n], g[:, :n],
-                              beta[:, :n], chunk, init)[1]
-        for n in range(chunk, L, chunk)]
+    # a chunk at a time from the state the chunk before left: the same
+    # states as the prefixes' final ones, and one shape for every call
+    states = [init]
+    for n in range(chunk, L, chunk):
+        at = slice(n - chunk, n)
+        states.append(gated_delta_reference(
+            q[:, at], k[:, at], v[:, at], g[:, at], beta[:, at], chunk,
+            states[-1])[1])
     k64, b64 = np.asarray(k, np.float64), np.asarray(beta, np.float64)
     cum = np.asarray(gd._chunk_sums(g, chunk), np.float64)
     T = np.zeros((b, nc, chunk, H * chunk))

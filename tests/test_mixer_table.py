@@ -26,6 +26,8 @@ from ray_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                           glm4_moe_lite_loss)
 from ray_tpu.models.keye_vl2 import KeyeVL2Config, keye_vl2_loss
 from ray_tpu.models.xing4 import Xing4Config, xing4_loss
+from ray_tpu.models.bailing_hybrid import (BailingHybridConfig,
+                                           bailing_hybrid_loss)
 
 FAMILIES = {
     "gpt": (GPTConfig, gpt_loss),
@@ -39,12 +41,13 @@ FAMILIES = {
     "xing4": (Xing4Config, xing4_loss),
     "glm4_moe_lite": (Glm4MoeLiteConfig, glm4_moe_lite_loss),
     "keye_vl2": (KeyeVL2Config, keye_vl2_loss),
+    "bailing_hybrid": (BailingHybridConfig, bailing_hybrid_loss),
 }
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
          decoder.DIFF_FULL, decoder.DIFF_CROSS, decoder.ATTENTION_ONLY,
          decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV,
-         decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION)
+         decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION, decoder.KDA)
 STATELESS = (decoder.GMU, decoder.DIFF_CROSS, decoder.EXPERTS)
 
 
@@ -55,8 +58,8 @@ def family(request):
     return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
 
 
-def test_the_table_has_the_fourteen_kinds_and_the_tiny_models_run_them_all():
-    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 14
+def test_the_table_has_the_fifteen_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 15
     run = {kind for config, _ in FAMILIES.values()
            for kind in config.tiny().decoder().kinds}
     assert run == set(KINDS)
@@ -102,7 +105,8 @@ def test_a_layers_channel_mixer_is_named_as_its_sequence_mixer_is(family):
         assert with_module.mlp[-1] is with_module.mlp[-2]   # an expert layer's
         assert with_module._replace(mlp=dec.mlp) == dec._replace(
             kinds=with_module.kinds)
-    if isinstance(cfg, (Lfm2MoeConfig, Xing4Config, Glm4MoeLiteConfig)):
+    if isinstance(cfg, (Lfm2MoeConfig, Xing4Config, Glm4MoeLiteConfig,
+                        BailingHybridConfig)):
         dense = cfg.n_dense_layers
         assert 0 < dense < cfg.n_layers
         assert set(dec.mlp[:dense]) == {decoder.swiglu_mlp}
@@ -172,7 +176,8 @@ def test_a_layer_holds_what_its_kinds_mixer_reads(family):
             y, cache, shared, *stats = row.apply(
                 x, layer, dec, None, None, shared, i,
                 dec.window if row.windowed else None)
-            assert bool(stats) == (kind == decoder.SPARSE_ATTENTION), kind
+            assert bool(stats) == (kind in (decoder.SPARSE_ATTENTION,
+                                            decoder.KDA)), kind
             assert cache is None and y.shape == x.shape, kind
             assert (shared.k is not None) == (
                 decoder.DIFF_FULL in dec.kinds[:i + 1]), kind
@@ -289,6 +294,12 @@ FROZEN = {
                           "keye_vl2"),
     "indexer_loss": (("q_index", "k_index", "w", "scores", "selected", "q",
                       "k", "lse", "sm_scale"), "keye_vl2"),
+    # chipbench/families/bailing_hybrid.py's: the rule and the mixer
+    # (`held_moe_layer`, which it hands the group limit by keyword, and
+    # `_unit_heads` and `latent_attention` are above)
+    "kda_rule": (("q", "k", "v", "g", "beta", "chunk", "init",
+                  "lower_bound"), "bailing_hybrid"),
+    "kda": (("x", "layer", "dec"), "bailing_hybrid"),
 }
 
 
@@ -321,7 +332,8 @@ def test_generate_names_no_family():
     import ray_tpu.models as models
     families = {getattr(models, name) for name in (
         "gpt", "llama", "moe", "hybrid", "sambay", "olmo_hybrid",
-        "nemotron_h", "lfm2_moe", "xing4", "glm4_moe_lite", "keye_vl2")}
+        "nemotron_h", "lfm2_moe", "xing4", "glm4_moe_lite", "keye_vl2",
+        "bailing_hybrid")}
     held = {v for v in vars(generate).values() if inspect.ismodule(v)}
     assert not held & families
     assert "cache_layers" not in inspect.getsource(generate)

@@ -8,6 +8,7 @@ random weights; the kernels run interpreted (RAY_TPU_PALLAS_INTERPRET=1)
 beside their jax.numpy form."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -55,9 +56,12 @@ def _cfg(**kw):
                                remat=False, **kw)
 
 
+@functools.lru_cache(maxsize=None)
 def _seeded(cfg, seq=32, batch=2, seed=0):
     """Weights with every norm weight, A_log and dt_bias moved off their
-    initial values, so that none multiplies by one unseen."""
+    initial values, so that none multiplies by one unseen. Made once a
+    (configuration, shape, seed) for the module: every case reads the
+    same arrays."""
     params = olmo_hybrid_init(jax.random.PRNGKey(seed), cfg)
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
 
@@ -80,21 +84,43 @@ def _close(got, want, tol=TOL):
     assert float(np.max(np.abs(got - want))) <= tol * scale
 
 
+@functools.lru_cache(maxsize=None)
+def _wanted_logits(cfg):
+    """The reference's logits on `_seeded(cfg)`, one jitted program, made
+    once for every case and form that reads them (the reference does not
+    know the forms of the rule apart)."""
+    params, tok = _seeded(cfg)
+    return jax.jit(lambda p: reference.reference_logits(p, tok, cfg))(params)
+
+
+@functools.lru_cache(maxsize=None)
+def _wanted_loss_and_gradients(cfg):
+    params, tok = _seeded(cfg)
+    return jax.jit(jax.value_and_grad(lambda p: reference.reference_loss(
+        p, tok, jnp.roll(tok, -1, 1), cfg)))(params)
+
+
+@functools.lru_cache(maxsize=None)
+def _wanted_final_states(cfg):
+    params, tok = _seeded(cfg)
+    return jax.jit(lambda p: reference.reference_final_states(
+        p, tok, cfg))(params)
+
+
 def test_logits_match_the_reference(form):
     cfg = _cfg()
     params, tok = _seeded(cfg)
-    _close(olmo_hybrid_forward(params, tok, cfg),
-           reference.reference_logits(params, tok, cfg))
+    _close(jax.jit(lambda p: olmo_hybrid_forward(p, tok, cfg))(params),
+           _wanted_logits(cfg))
 
 
 def test_loss_and_every_gradient_match_the_reference(form):
     cfg = _cfg()
     params, tok = _seeded(cfg)
     tgt = jnp.roll(tok, -1, 1)
-    got, got_g = jax.value_and_grad(
-        lambda p: olmo_hybrid_loss(p, (tok, tgt), cfg))(params)
-    want, want_g = jax.value_and_grad(
-        lambda p: reference.reference_loss(p, tok, tgt, cfg))(params)
+    got, got_g = jax.jit(jax.value_and_grad(
+        lambda p: olmo_hybrid_loss(p, (tok, tgt), cfg)))(params)
+    want, want_g = _wanted_loss_and_gradients(cfg)
     assert abs(float(got) - float(want)) <= 1e-5
     flat_got = jax.tree_util.tree_leaves_with_path(got_g)
     flat_want = jax.tree.leaves(want_g)
@@ -112,8 +138,9 @@ def test_an_all_bfloat16_reference_fails_the_tolerance():
     parameter and value in bfloat16 is off by more than TOL."""
     cfg = _cfg()
     params, tok = _seeded(cfg)
-    want = reference.reference_logits(params, tok, cfg)
-    low, head, _ = reference._hidden(params, tok, cfg, jnp.bfloat16)
+    want = _wanted_logits(cfg)
+    low, head, _ = jax.jit(lambda p: reference._hidden(
+        p, tok, cfg, jnp.bfloat16))(params)
     got = (low @ head).astype(jnp.float32)
     assert float(jnp.max(jnp.abs(got - want))) > 10 * TOL * float(
         jnp.max(jnp.abs(want)))
@@ -123,8 +150,9 @@ def test_final_states_match_the_reference(form):
     cfg = _cfg()
     params, tok = _seeded(cfg)
     cache = init_cache(cfg, tok.shape[0], tok.shape[1])
-    _, cache = cached_forward(params, tok, cache, 0, cfg)
-    want = reference.reference_final_states(params, tok, cfg)
+    _, cache = jax.jit(lambda p, cache: cached_forward(
+        p, tok, cache, 0, cfg))(params, cache)
+    want = _wanted_final_states(cfg)
     got = [c["delta"] for c in cache if "delta" in c]
     assert len(got) == len(want) == 3
     for g, w in zip(got, want):
@@ -140,17 +168,20 @@ def test_prefill_then_decode_matches_the_full_forward(form, prefill):
     19 tokens (the jax.numpy form pads), then one token at a time."""
     cfg = _cfg()
     params, tok = _seeded(cfg)
-    want = reference.reference_logits(params, tok, cfg)
+    want = _wanted_logits(cfg)
     cache = init_cache(cfg, tok.shape[0], tok.shape[1])
     assert [sorted(c) for c in cache] == [["conv", "delta"]] * 3 + [
         ["k", "v"]]
     assert cache[0]["delta"].shape == (2, 3, 12, 20)
     assert cache[0]["conv"].shape == (2, 3, 3 * (2 * 12 + 20))
-    logits, cache = cached_forward(params, tok[:, :prefill], cache, 0, cfg)
+    # one jitted program a shape: the prefill's, and the decode step's for
+    # every token after it
+    forward = jax.jit(lambda p, toks, cache, at: cached_forward(
+        p, toks, cache, at, cfg))
+    logits, cache = forward(params, tok[:, :prefill], cache, 0)
     outs = [logits]
     for t in range(prefill, tok.shape[1]):
-        logits, cache = cached_forward(params, tok[:, t:t + 1], cache, t,
-                                       cfg)
+        logits, cache = forward(params, tok[:, t:t + 1], cache, t)
         outs.append(logits)
     _close(jnp.concatenate(outs, axis=1), want)
 

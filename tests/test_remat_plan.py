@@ -313,6 +313,55 @@ def test_a_sparse_block_offers_what_its_q_k_v_are_made_from(keye,
         tuple(sorted(firsts + ("attention_kv_proj",))),) * 2 + (firsts,) * 4
 
 
+@pytest.fixture
+def ling(monkeypatch):
+    from chipbench.families import bailing_hybrid
+    return _cell(monkeypatch, bailing_hybrid, "ling-3.0-flash",
+                 "pretrain-ling3flash-b1-s16384")
+
+
+def test_a_kda_block_keeps_its_rules_three_and_counts_the_log_decay(
+        ling, monkeypatch):
+    """Ling-3.0-flash's period at 16,384 tokens of 32 heads of 128 | 128: a
+    KDA block keeps, beside its input, the rule's output [16384, 4096]
+    (134 MB), the state entering each of 256 chunks in the model's dtype
+    (268 MB) and T - I (67 MB), by its kind's base set; it offers its q | k
+    | v projection [16384, 12288] bfloat16 and the float32 log-decay
+    [16384, 4096], 268 MB where `gated_delta` has a [T, 30]; and its
+    reserve counts eight float32 values of that size under no name
+    (`_kda_holds`)."""
+    dec, layers, x, vocab, state_bytes = ling
+    assert dec.kinds == (decoder.KDA,) * 5 + (decoder.LATENT_ATTENTION,)
+    assert 7.60e9 < state_bytes < 7.70e9
+    assert decoder._kept(decoder.KDA) == decoder.KEPT_UNDER_REMAT + (
+        "kda_o", "kda_states", "kda_T")
+    offered = _offered(monkeypatch)
+    plan = decoder.remat_plan(dec, layers, x, vocab, V5E_BYTES, state_bytes)
+    log_decay = 16384 * 4096 * 4
+    # one account a kind and channel mixer: the dense KDA block's, an expert
+    # KDA block's, the latent block's
+    assert offered["kda_in"] == 2 * 16384 * 12288 * 2
+    assert offered["kda_g"] == 2 * log_decay == 536_870_912
+    assert decoder._kda_holds(decoder.KDA, 16384, layers[0]) == 8 * log_decay
+    assert decoder._kda_holds(decoder.LATENT_ATTENTION, 16384, layers[5]) == 0
+    kept = 16384 * 4096 * 2 + 256 * 32 * 128 * 128 * 2 + 256 * 32 * 64 * 64 * 2
+    assert plan.base_bytes > 5 * (kept + 16384 * 2560 * 2)
+    assert plan.reserve_bytes > 8 * log_decay + kept
+    # what is left beside 7.66 GB of state goes to the first layer's
+    # projection, the choices and the shared experts' up projections; no
+    # layer has room for its float32 log-decay
+    assert plan.extras[0] == ("kda_in",)
+    assert all("kda_g" not in names for names in plan.extras)
+    assert all({"moe_choice", "moe_shared_up"} <= set(names)
+               for names in plan.extras[1:])
+    assert "flash_attention_q" in plan.extras[5]
+    assert plan.state_bytes + plan.base_bytes + plan.reserve_bytes \
+        + plan.kept_extra_bytes <= V5E_BYTES - 2 ** 30
+    # with nothing known of the chip nothing is added
+    assert decoder.remat_plan(dec, layers, x, vocab, None,
+                              state_bytes).extras == ((),) * 6
+
+
 def test_the_second_table_is_beside_the_first():
     # one name is of both: q, a candidate of the two kinds whose base set
     # leaves it out (a latent and a sparse block's, KEPT_BY_KIND) and of no
@@ -322,12 +371,15 @@ def test_the_second_table_is_beside_the_first():
     assert [kind for kind in decoder.MIXERS
             if "flash_attention_q" not in decoder._kept(kind)] \
         == [decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION]
-    # and a third beside both, by kind: a sparse block's own candidates,
-    # names of neither table
-    assert list(decoder.FITS_BY_KIND) == [decoder.SPARSE_ATTENTION]
+    # and a third beside both, by kind: a sparse block's and a KDA
+    # block's own candidates, names of neither table
+    assert list(decoder.FITS_BY_KIND) == [decoder.SPARSE_ATTENTION,
+                                          decoder.KDA]
+    assert set(decoder.FITS_BY_KIND[decoder.KDA]) == {"kda_in", "kda_g"}
+    for own in decoder.FITS_BY_KIND.values():
+        assert not set(own) & (set(decoder.KEPT_WHERE_IT_FITS)
+                               | set(decoder.KEPT_UNDER_REMAT))
     own = set(decoder.FITS_BY_KIND[decoder.SPARSE_ATTENTION])
-    assert not own & (set(decoder.KEPT_WHERE_IT_FITS)
-                      | set(decoder.KEPT_UNDER_REMAT))
     assert set(decoder._fits(decoder.SPARSE_ATTENTION)) \
         == own | set(decoder.KEPT_WHERE_IT_FITS)
     assert decoder._fits(decoder.ATTENTION) == decoder.KEPT_WHERE_IT_FITS
