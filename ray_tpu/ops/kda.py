@@ -37,6 +37,18 @@ rows are stacked, so both P tiles of a head and chunk are one [2C, 4K] x
 come out large and finite, and are masked. Every exponent is masked BEFORE
 the exp.
 
+G is made inside the kernels, from g itself: a grid program sums its block
+of g [C, heads_per_program * K] float32 down the chunk's rows before its
+heads are worked (`_running_sums`: log2(C) rolled, masked additions on the
+vector unit), and the backward kernel sums the heads' gradients by G the
+other way, from a row to its chunk's end, before the block is written: the
+gradient by g. Float32 additions only: G reaches 64 * lower_bound at a
+chunk's end, where one rounding to bfloat16 is off by more than 1, and the
+gate's bias sums the gradient over every token. HBM holds g and its
+gradient and no running sum (as an XLA window sum over [16384, 4096] and
+its transpose round the kernels they were 45.7 ms of a 911.7 ms step of
+five layers: PERF.md section 6, PR 64).
+
 T is ops/gated_delta.py's `_unit_lower_inverse` (two heads' tiles side by
 side at chunks of 64), the states' layout and the T - I a differentiated
 forward leaves in HBM are that module's too; the states are left in the
@@ -122,10 +134,12 @@ class KdaPlan:
     float32 ones that make T, two a level and pair of heads),
     `fwd_exps` / `bwd_exps` the exponentials' [chunk, K] tiles: the row
     factor, one column factor a sub-block, exp(G) and exp(G_C - G).
+    The running sums of g and their transpose are the vector unit's and
+    in neither count.
     `state_bytes` the chunk states in HBM (bfloat16: the operand the
     chunk's products take, where ops/gated_delta.py keeps float32),
     `kept_bytes` T - I a head and chunk (bfloat16), `decay_bytes` the
-    float32 running sums [L, H * K]. At
+    float32 g [L, H * K] (its gradient is as much). At
     Ling-3.0-flash's shape, `kda_plan(16384, 32, 128, 128, 64)`:
     `inverse_matmuls` 40,960, `fwd_matmuls` 98,304, `bwd_matmuls` 155,648,
     `fwd_exps` 57,344 = `bwd_exps`."""
@@ -168,15 +182,19 @@ def heads_per_program(heads: int) -> int:
 def _vmem_bytes(per_program: int, key_dim: int, value_dim: int,
                 chunk: int) -> int:
     """What the backward kernel holds: double-buffered blocks (q, k, dq,
-    dk, v, dO, dv in bf16, G and dG in float32, T - I; three state blocks)
-    and a head's float32 temporaries (the [2C, 4K] operands of P)."""
+    dk, v, dO, dv in bf16, g and dg in float32, T - I; three state blocks),
+    a head's float32 temporaries (the [2C, 4K] operands of P) and the
+    sums' (`_running_sums`): the block's G, the heads' gradients by G read
+    back to be summed, and a step's rolled copy, float32 [C, per_program *
+    K] each."""
     subs = chunk // SUB
     state = per_program * key_dim * value_dim * 4
     acts = chunk * per_program * (
         (4 * key_dim + 3 * value_dim + chunk) * 2 + 2 * key_dim * 4)
     head = (6 * chunk * subs * key_dim + 12 * chunk * (key_dim + value_dim)
             + 16 * chunk * chunk) * 4
-    return 2 * acts + 2 * 3 * state + head
+    sums = 3 * chunk * per_program * key_dim * 4
+    return 2 * acts + 2 * 3 * state + head + sums
 
 
 def kda_plan(seq_len: int, heads: int, key_dim: int, value_dim: int,
@@ -273,6 +291,37 @@ def _own_block(wide, block, subs: int):
     return out
 
 
+def _running_sums(x, to_end: bool = False):
+    """x [C, n] float32 summed along the rows inside the chunk, float32:
+    row i the sum of rows 0..i, or of rows i..C-1 where `to_end` (the
+    transpose: what a gradient by the running sums is by their terms).
+    log2(C) steps on the vector unit, each the block rolled along its
+    sublanes by twice the step before, masked where it wrapped, and added:
+    float32 additions and nothing else. x or its sums rounded ONCE to
+    bfloat16 would be off by more than 1 at a chunk's end (G reaches 64 *
+    -5 there) and, in the gradient, is PR 63's finding (a) over again. The
+    kernel pair alone on the chip, a layer at 16,384 tokens, forward +
+    backward: this 22.96 ms; as products with the triangle of ones, x in
+    three bfloat16 pieces that sum to it, 23.80 to 24.34, as one float32
+    product at `Precision.HIGHEST` 24.97; the window sum of the whole
+    [16384, 4096] array and its transpose in XLA round the kernels 27.59
+    (my chip runs, PR 64; PERF.md section 6 has the forward's and why the
+    same product on the whole array in XLA was worse still)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    chunk = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    step = 1
+    while step < chunk:
+        if to_end:      # row i takes row i + step
+            x = x + jnp.where(row < chunk - step,
+                              pltpu.roll(x, chunk - step, 0), 0.0)
+        else:           # row i takes row i - step
+            x = x + jnp.where(row >= step, pltpu.roll(x, step, 0), 0.0)
+        step *= 2
+    return x
+
+
 def _head_tiles(q, k, G, chunk: int):
     """What both passes make of one head's decays in one chunk. q, k
     [C, K] in the model's dtype, G [C, K] float32 the running sums.
@@ -360,7 +409,8 @@ def _tile_forward(heads, chunk: int):
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, init_ref, o_ref,
                     states_ref, final_ref, T_ref=None, *, hb: int, K: int,
                     V: int):
-    """One chunk of `hb` heads. `g_ref` the running sums [C, hb * K];
+    """One chunk of `hb` heads. `g_ref` the log-decays [C, hb * K]
+    float32, whose running sums G are made here for the whole block;
     `b_ref` beta [C, hb]; `T_ref` the heads' T - I [C, hb * C] where a
     backward pass will read it."""
     from jax.experimental import pallas as pl
@@ -372,6 +422,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, init_ref, o_ref,
         final_ref[...] = init_ref[...]
 
     b_all = b_ref[0, 0]
+    G_all = _running_sums(g_ref[0])
     n = heads_per_tile(chunk)
     for first in range(0, hb, n):
         tile = range(first, min(first + n, hb))
@@ -382,7 +433,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, init_ref, o_ref,
             S0 = final_ref[0, h]
             states_ref[0, 0, h] = S0.astype(states_ref.dtype)
             heads.append((q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
-                          g_ref[0, :, ks], b_all[:, h:h + 1], S0))
+                          G_all[:, ks], b_all[:, h:h + 1], S0))
         Tm, outs = _tile_forward(heads, chunk)
         if T_ref is not None:
             T_ref[0, 0, :, lanes] = Tm
@@ -465,6 +516,10 @@ def _head_backward(q, k, v, G, bc, S0, Tm, dO, dS1, chunk: int):
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, T_ref,
                     do_ref, dfinal_ref, dq_ref, dk_ref, dv_ref, dg_ref,
                     dbeta_ref, dinit_ref, *, hb: int, K: int, V: int):
+    """One chunk of `hb` heads, last chunk first. `g_ref` the log-decays
+    as the forward kernel took them and G made from them the same way;
+    `dg_ref`'s block first collects the heads' gradients by G, then holds
+    their sums from each row to the chunk's end: the gradient by g."""
     from jax.experimental import pallas as pl
 
     chunk = q_ref.shape[1]
@@ -474,13 +529,14 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, T_ref,
         dinit_ref[...] = dfinal_ref[...]
 
     b_all = b_ref[0, 0]
+    G_all = _running_sums(g_ref[0])
     head_lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, hb), 1)
     dbeta_tile = jnp.zeros((chunk, hb), jnp.float32)
     for h in range(hb):
         ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
         dq, dk, dG, dv, dbeta, dS0 = _head_backward(
             q_ref[0, :, ks], k_ref[0, :, ks], v_ref[0, :, vs],
-            g_ref[0, :, ks], b_all[:, h:h + 1], states_ref[0, 0, h],
+            G_all[:, ks], b_all[:, h:h + 1], states_ref[0, 0, h],
             T_ref[0, 0, :, h * chunk:(h + 1) * chunk], do_ref[0, :, vs],
             dinit_ref[0, h], chunk)
         dq_ref[0, :, ks] = dq.astype(dq_ref.dtype)
@@ -490,6 +546,7 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, T_ref,
         dinit_ref[0, h] = dS0
         dbeta_tile = jnp.where(head_lane == h, dbeta, dbeta_tile)
     dbeta_ref[0, 0] = dbeta_tile
+    dg_ref[0] = _running_sums(dg_ref[0], to_end=True)
 
 
 def _compiler_params():
@@ -533,9 +590,9 @@ def _from_groups(x):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "H", "keep_inverse"))
-def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int,
+def _forward_call(q, k, v, g, beta, init, *, chunk: int, H: int,
                   keep_inverse: bool):
-    """q, k [b, L, H*K]; v [b, L, H*V]; cum [b, L, H*K] f32; beta [b, L,
+    """q, k [b, L, H*K]; v [b, L, H*V]; g [b, L, H*K] f32; beta [b, L,
     H] f32; init [b, H, K, V] f32 -> (o like v, states [b, chunks, H, K,
     V] in q's dtype: the state ENTERING each chunk as the chunk's products
     take it (the carried state itself stays float32 in VMEM), the final
@@ -565,14 +622,14 @@ def _forward_call(q, k, v, cum, beta, init, *, chunk: int, H: int,
         interpret=attention._interpret(),
     )
     with jax.named_scope("kda_fwd"):
-        return call(q, k, v, cum, _by_group(beta, hb), init)
+        return call(q, k, v, g, _by_group(beta, hb), init)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "H"))
-def _backward_call(q, k, v, cum, beta, states, inverse, do, dfinal, *,
+def _backward_call(q, k, v, g, beta, states, inverse, do, dfinal, *,
                    chunk: int, H: int):
     """`states` and `inverse` as `_forward_call` left them -> (dq, dk, dv,
-    d cum [b, L, H*K] f32, dbeta [b, L, H] f32, d init)."""
+    dg [b, L, H*K] f32, dbeta [b, L, H] f32, d init)."""
     from jax.experimental import pallas as pl
 
     b, L, HK = q.shape
@@ -589,33 +646,21 @@ def _backward_call(q, k, v, cum, beta, states, inverse, do, dfinal, *,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(cum.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(g.shape, jnp.float32),
                    jax.ShapeDtypeStruct((b, H // hb, L, hb), jnp.float32),
                    jax.ShapeDtypeStruct((b, H, K, V), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=attention._interpret(),
     )
     with jax.named_scope("kda_bwd"):
-        dq, dk, dv, dcum, dbeta, dinit = call(
-            q, k, v, cum, _by_group(beta, hb), states, inverse, do, dfinal)
-    return dq, dk, dv, dcum, _from_groups(dbeta), dinit
+        dq, dk, dv, dg, dbeta, dinit = call(
+            q, k, v, g, _by_group(beta, hb), states, inverse, do, dfinal)
+    return dq, dk, dv, dg, _from_groups(dbeta), dinit
 
 
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
-def _chunk_sums(g, chunk: int):
-    """Running sum of g inside each chunk, float32: g [b, L, ...], the
-    sum over axis 1 inside runs of `chunk`. (As a product with the lower
-    triangle of ones at full precision it read 25 ms a step MORE over five
-    layers at 16,384 tokens than this window sum: float32 operands go
-    through the matrix unit as three bfloat16 pieces, each a pass of the
-    [16384, 4096] array; my chip runs, PR 63.)"""
-    b, L = g.shape[:2]
-    return jnp.cumsum(g.reshape(b, L // chunk, chunk, *g.shape[2:]),
-                      axis=2).reshape(g.shape)
-
-
 def _heads_apart(t, H: int):
     """[b, L, H * W] -> [b, L, H, W]."""
     return t.reshape(*t.shape[:2], H, -1)
@@ -677,7 +722,7 @@ def _run_forward(q, k, v, g, beta, init, chunk, lower_bound, H,
     L = q.shape[1]
     # refuses what does not fit
     kda_plan(L, H, q.shape[-1] // H, v.shape[-1] // H, chunk, lower_bound)
-    return _forward_call(q, k, v, _chunk_sums(g, chunk), beta, init,
+    return _forward_call(q, k, v, g, beta, init,
                          chunk=chunk, H=H, keep_inverse=keep_inverse)
 
 
@@ -706,11 +751,9 @@ def _rule_bwd(chunk, lower_bound, H, residuals, cotangents):
             lambda *args: _by_reference(*args, chunk, H),
             q, k, v, g, beta, init)
         return vjp((do, dfinal))
-    cum, cum_vjp = jax.vjp(lambda g_: _chunk_sums(g_, chunk), g)
-    dq, dk, dv, dcum, dbeta, dinit = _backward_call(
-        q, k, v, cum, beta, states, inverse, do.astype(v.dtype),
+    return _backward_call(
+        q, k, v, g, beta, states, inverse, do.astype(v.dtype),
         dfinal.astype(jnp.float32), chunk=chunk, H=H)
-    return dq, dk, dv, cum_vjp(dcum)[0], dbeta, dinit
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)

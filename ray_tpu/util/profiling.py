@@ -168,13 +168,14 @@ DEVICE_SCOPES: Dict[str, str] = {
     "kda_fwd": "ops/kda.py _forward_call, the _kda_fwd_kernel pallas_call: a "
                "KDA layer's chunked delta rule with a decay a key channel "
                "(chunks of 64 in sub-blocks of 16 rows, a reference row "
-               "each), o and the state entering each chunk; and round it "
-               "_rule_fwd (the chunk sums of g, [T, heads * K] float32)",
+               "each; the running sums of g made in the kernel), o and "
+               "the state entering each chunk; and round it _rule_fwd "
+               "(beta by head group)",
     "kda_bwd": "ops/kda.py _backward_call, the _kda_bwd_kernel pallas_call: "
                "a chunk's decayed tiles, W, U and V' again from the kept "
                "state and T - I, then every gradient of the rule, chunks "
-               "last to first; and round it _rule_bwd, the whole rule (the "
-               "chunk sums' transpose)",
+               "last to first, the gradient by g summed to each chunk's "
+               "end in the kernel; and round it _rule_bwd, the whole rule",
     "short_conv_fwd": "ops/short_conv.py _forward_call, the "
                       "_conv_fwd_kernel pallas_call: a gated short "
                       "convolution's y = C * conv(B * x) from the "

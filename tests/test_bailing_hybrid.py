@@ -147,7 +147,8 @@ def test_train_step_keeps_the_biases_and_reports_the_counters(tiny):
 # ---------------------------------------------------------------------------
 # the rule
 # ---------------------------------------------------------------------------
-def _rule_inputs(L, H=2, K=128, with_state=False, seed=0, bound=-5.0):
+def _rule_inputs(L, H=2, K=128, with_state=False, seed=0, bound=-5.0,
+                 decay="drawn"):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = jax.random.normal(ks[0], (1, L, H, K))
     k = jax.random.normal(ks[1], (1, L, H, K))
@@ -157,6 +158,10 @@ def _rule_inputs(L, H=2, K=128, with_state=False, seed=0, bound=-5.0):
     # drawn down to the bound: some channel of every sub-block sits at it
     g = bound * jax.random.uniform(ks[3], (1, L, H, K))
     g = g.at[:, :, :, 0].set(bound)
+    if decay == "bound":        # every row and channel: G ends a chunk at
+        g = jnp.full_like(g, bound)                     # 64 * bound
+    elif decay == "near_zero":  # every row and channel within 1e-3 of 0
+        g = g * (1e-3 / -bound)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, L, H)))
     init = 0.5 * jax.random.normal(ks[5], (1, H, K, K)) if with_state \
         else None
@@ -169,13 +174,19 @@ def _weighted(fn, *given):
             + jnp.sum(S * jnp.sin(jnp.arange(S.size).reshape(S.shape))))
 
 
-@pytest.mark.parametrize("chunks, with_state", [(2, False), (3, True)])
+@pytest.mark.parametrize("chunks, with_state, decay", [
+    (2, False, "drawn"), (3, True, "drawn"),
+    (2, True, "bound"), (2, False, "near_zero")])
 def test_kernels_are_the_recurrence_with_g_down_to_the_bound(
-        monkeypatch, chunks, with_state):
+        monkeypatch, chunks, with_state, decay):
     """The interpreted kernel pair against the family's token-by-token
-    recurrence: o, the final state and every gradient."""
+    recurrence: o, the final state and every gradient. The kernels make
+    the running sums of g themselves: with g AT the bound on every row and
+    channel a chunk's last sum is -320, where a sum (or a g) rounded once
+    to bfloat16 is off by more than 1; with g within 1e-3 of 0 everywhere
+    the sums are all the decay there is."""
     monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
-    given = _rule_inputs(64 * chunks, with_state=with_state)
+    given = _rule_inputs(64 * chunks, with_state=with_state, decay=decay)
     n = 6 if with_state else 5
 
     def ours(*a):
@@ -193,6 +204,48 @@ def test_kernels_are_the_recurrence_with_g_down_to_the_bound(
                              argnums=range(n)))(*given[:n])
     for got, want in zip(dgot, dwant):
         _close(got, want)
+
+
+@pytest.mark.parametrize("decay", ["drawn", "near_zero"])
+def test_a_bias_on_every_tokens_g_has_the_recurrences_gradient(
+        monkeypatch, decay):
+    """The gradient the gate's bias takes (PR 63's finding (a)): one number
+    a channel added to g on every token, so its gradient sums dg over the
+    whole sequence, and what a chunk's sum should cancel has to cancel. dg
+    is the backward kernel's gradient by G summed from each row to its
+    chunk's end, inside the kernel: a gradient or a sum rounded to
+    bfloat16 there fails here, before `hold_kernels` meets it on the
+    chip."""
+    monkeypatch.setenv("RAY_TPU_PALLAS_INTERPRET", "1")
+    q, k, v, g, beta, init = _rule_inputs(192, with_state=True, seed=3,
+                                          decay=decay)
+    H, K = g.shape[2:]
+
+    def biased(rule):
+        return jax.jit(jax.grad(lambda bias: _weighted(
+            rule, q, k, v, g + bias, beta, init)))(jnp.zeros((H, K)))
+
+    got = biased(lambda *a: kda.kda_rule(*a[:5], 64, a[5]))
+    want = biased(reference.recurrence)
+    assert float(jnp.max(jnp.abs(want))) > 0
+    _close(got, want)
+    # and the kernels' sums themselves, both ways, against float64's: 64
+    # float32 additions of values up to 320 in size, nothing rounded shorter
+    from jax.experimental import pallas as pl
+
+    def both_ways(x_ref, down_ref, up_ref):
+        down_ref[...] = kda._running_sums(x_ref[...])
+        up_ref[...] = kda._running_sums(x_ref[...], to_end=True)
+
+    x = g[0, :64].reshape(64, -1)
+    down, up = pl.pallas_call(
+        both_ways, out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)] * 2,
+        interpret=True)(x)
+    x = np.asarray(x, np.float64)
+    for ours, exact in ((down, np.cumsum(x, 0)),
+                        (up, np.cumsum(x[::-1], 0)[::-1])):
+        assert np.max(np.abs(np.asarray(ours, np.float64) - exact)) \
+            <= 64 * 2.0 ** -24 * 320
 
 
 def test_a_length_that_is_no_whole_number_of_chunks_runs_the_reference(
@@ -231,6 +284,7 @@ def test_plan_counts_and_refuses_a_bound_the_sub_blocks_do_not_hold():
     assert (plan.chunks, plan.sub_blocks, plan.heads_per_program,
             plan.grid) == (256, 4, 8, (4, 256))
     assert plan.inverse_matmuls == 256 * 16 * 2 * 5 == 40_960
+    # (the running sums of g are the vector unit's: no product is added)
     assert plan.fwd_matmuls == 40_960 + 256 * 32 * 7 == 98_304
     assert plan.bwd_matmuls == 256 * 32 * 19 == 155_648
     assert plan.fwd_exps == plan.bwd_exps == 256 * 32 * 7 == 57_344

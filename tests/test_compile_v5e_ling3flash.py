@@ -60,8 +60,8 @@ SCOPES = ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv",
 
 def test_lowered_step_hands_the_rule_a_decay_a_channel(cell):
     """Before XLA: the step's Mosaic kernels are the family's seven; the
-    KDA forward kernel takes q, k, v [1, 16384, 4096] bfloat16, the running
-    sums [1, 16384, 4096] float32 (gated_delta's are a [.., 30]) and beta by
+    KDA forward kernel takes q, k, v [1, 16384, 4096] bfloat16, the log-decay
+    g [1, 16384, 4096] float32 (gated_delta's is a [.., 30]) and beta by
     head group, and leaves o, the state entering each of 256 chunks in the
     model's dtype and T - I; the backward kernel reads those and writes a
     float32 gradient a channel; the grid is (batch, 4 groups of 8 heads, 256
@@ -78,7 +78,7 @@ def test_lowered_step_hands_the_rule_a_decay_a_channel(cell):
         assert "<1x256x32x128x128xbf16>" in types           # chunk states
         assert "<1x256x64x2048xbf16>" in types              # T - I
         assert len(re.findall(r"<1x16384x4096xbf16>", types)) >= 4
-    assert bwd.count("<1x16384x4096xf32>") == 2             # G in, dG out
+    assert bwd.count("<1x16384x4096xf32>") == 2             # g in, dg out
     grids = mosaic_grids(lowered, ("_kda_fwd_kernel", "_kda_bwd_kernel"))
     for kernel in ("_kda_fwd_kernel", "_kda_bwd_kernel"):
         (grid, blocks), = grids[kernel]
@@ -142,6 +142,13 @@ def test_step_calls_the_seven_kernels_once_a_layer_under_the_scopes(step):
     # to the kernels and back: as a [16384, 32, 128] residual it was another
     # tiling and twenty copies a step ([2048, 8, 32, 128] as XLA cut it)
     assert "f32[2048,8,32,128]" not in compiled
+    # and its running sums are the kernels' own: XLA sums no float32
+    # [16384, 4096] value along its rows round them, either way
+    summed = [line for line in compiled.splitlines()
+              if ("/kda_fwd" in line or "/kda_bwd" in line)
+              and ("reduce-window" in line or "cumsum" in line)
+              and re.search(r"f32\[(1,)?(16384|256,64),4096\]", line)]
+    assert not summed, summed[:2]
     assert profiling.kernel_calls(compiled) == {
         "flash_attention_fwd": 1, "flash_attention_dq": 1,
         "flash_attention_dkv": 1, "kda_fwd": 5, "kda_bwd": 5,
@@ -163,6 +170,9 @@ def test_step_fits_a_chip_by_xlas_own_total(step, cell, record_property):
     assert nbytes <= HBM_BYTES - 2 ** 30
     assert nbytes <= plan.state_bytes + plan.base_bytes \
         + plan.reserve_bytes + plan.kept_extra_bytes
-    # 15,571,643,904 on PR 63's tree (17,313,021,952 with the chunk states
-    # float32 and no such reserve: over the chip)
-    assert nbytes <= 15_650_000_000
+    # 12,761,842,176 since PR 64, the running sums of g made inside the
+    # kernels: 15,571,643,904 on PR 63's tree, whose two window sums a
+    # layer XLA padded and held beside their float32 operands
+    # (17,313,021,952 with the chunk states float32 and no such reserve:
+    # over the chip)
+    assert nbytes <= 12_830_000_000
