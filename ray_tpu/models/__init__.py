@@ -123,3 +123,12 @@ from .bailing_hybrid import (  # noqa: F401
     bailing_hybrid_param_axes,
     make_bailing_hybrid_train_step,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig,
+    afmoe_forward,
+    afmoe_init,
+    afmoe_loss,
+    afmoe_loss_and_counters,
+    afmoe_param_axes,
+    make_afmoe_train_step,
+)

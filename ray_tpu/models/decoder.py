@@ -17,10 +17,17 @@ a layer's kind is a key of `MIXERS`, whose row says which sequence mixer
 runs it, what state it keeps in a cache, whether it is windowed, hands its
 keys and values on, or reads its place in the stack, and which branches
 its block has: a sequence mixer, the layer's channel mixer, or both. A
-new sequence mixer is its function and its row. The fifteen kinds:
+new sequence mixer is its function and its row. The seventeen kinds:
 
     ATTENTION      softmax attention; {"k" | "v": [batch, n_kv_heads,
                    max_len, head_dim]}
+    WINDOWED_ATTENTION, ATTENTION_NOPE
+                   ATTENTION's mixer and state in a stack that mixes the
+                   two: the first rotated at `dec.rope_base` and seeing no
+                   further back than `dec.window`, the second over every
+                   position and with no positions at all, whatever
+                   `dec.rope_base` says. Their blocks keep less under remat
+                   (KEPT_BY_KIND)
     MAMBA2         the chunked scan; {"conv": [batch, d_conv - 1, inner +
                    2 groups x state] the convolution's last inputs, "ssm":
                    [batch, heads, head_dim, state] float32}, which does
@@ -77,9 +84,11 @@ What is still read off the weights a layer holds picks no mixer and no
 state: `ln1_b`: LayerNorm with bias where the others have RMSNorm; `ln1`,
 `ln2`: a block that norms what its branches read, x + mixer(norm(x)),
 `post_attention`, `post_feedforward` and neither of those: one that norms
-what they return, x + norm(mixer(x)); `wqkv` or `wq` + `wkv`, `q_norm`
-(one norm over all of q's columns), `q_head_norm` (one over each head's)
-or neither, inside `attention`; `w_qa`: queries through a normed latent of
+what they return, x + norm(mixer(x)), all four: both (sandwich norms);
+`wqkv` or `wq` + `wkv`, `q_norm` (one norm over all of q's columns),
+`q_head_norm` (one over each head's) or neither, and `attn_gate` [d, heads
+x head_dim]: the kernel's output times sigmoid(y attn_gate), one gate a
+channel, before `wo`, inside `attention`; `w_qa`: queries through a normed latent of
 their own, inside `latent_attention`; `hc_mixer`, `hc_mlp`: a branch that
 reads one learned mix of `dec.hyper.streams` residual streams and writes
 back through a doubly stochastic matrix (`hyper_connection`), where a layer
@@ -166,10 +175,12 @@ from ..parallel.moe import (dropless_moe_layer, held_backward_bytes,
 # The kinds of layer: the keys of MIXERS, below the mixers.
 (ATTENTION, MAMBA2, MAMBA1, GATED_DELTA, GMU, DIFF_WINDOWED, DIFF_FULL,
  DIFF_CROSS, ATTENTION_ONLY, MAMBA2_ONLY, EXPERTS, SHORT_CONV,
- LATENT_ATTENTION, SPARSE_ATTENTION, KDA) = (
+ LATENT_ATTENTION, SPARSE_ATTENTION, KDA, WINDOWED_ATTENTION,
+ ATTENTION_NOPE) = (
     "attention", "mamba2", "mamba1", "gated_delta", "gmu", "diff_windowed",
     "diff_full", "diff_cross", "attention_only", "mamba2_only", "experts",
-    "short_conv", "latent_attention", "sparse_attention", "kda")
+    "short_conv", "latent_attention", "sparse_attention", "kda",
+    "windowed_attention", "attention_nope")
 
 
 class HyperConnections(NamedTuple):
@@ -210,7 +221,8 @@ class Decoder(NamedTuple):
     # A GATED_DELTA layer's chunk (its heads and widths are read off its
     # weights).
     delta_chunk: int = 64
-    # What a DIFF_WINDOWED layer sees: itself and the window - 1 before.
+    # What a DIFF_WINDOWED or WINDOWED_ATTENTION layer sees: itself and the
+    # window - 1 before.
     window: Optional[int] = None
     # The rotated pairs' frequencies where they are not `rope_base`'s own
     # (a scaled context's: ops.layers.yarn_inv_freq), one a pair.
@@ -378,16 +390,16 @@ def _attend_cache(q, k_all, v_all, sp, sm_scale, window=None, selected=None):
 
 
 def _cached_attention(q, k, v, cache, sp, group: int, sm_scale,
-                      selected=None):
+                      selected=None, window=None):
     """Write k, v [b, kvh, L, hd] into the cache at positions sp + [0, L)
     and attend q [b, h, L, hd] over the cache up to each query's own
-    position (of those, the `selected` ones where given). Only the write
-    and the mask specialize on whether `sp` is a scalar or one position a
-    row."""
+    position (under a `window` no further back than window - 1; of those,
+    the `selected` ones where given). Only the write and the mask
+    specialize on whether `sp` is a scalar or one position a row."""
     new_cache = _write_cache(cache, k, v, sp)
     attn = _attend_cache(q, _across_group(new_cache["k"], group),
                          _across_group(new_cache["v"], group), sp, sm_scale,
-                         selected=selected)
+                         window, selected)
     return attn, new_cache
 
 
@@ -437,26 +449,45 @@ def _qkv_heads(x, layer, dec: Decoder, cache, start_pos):
     return y, q, k, heads(v, kvh), sp, positions
 
 
-def attention(x, layer, dec: Decoder, cache=None, start_pos=None):
+def attention(x, layer, dec: Decoder, cache=None, start_pos=None,
+              window: Optional[int] = None):
     """Causal self-attention of x [b, L, d], from the input norm to the
     output projection. With a cache, `start_pos` is the absolute offset
     of x's positions — a scalar (all rows aligned: prefill / single-stream
     decode) or a [b] vector (continuous batching: every row decodes at its
-    own position). One implementation serves training, prefill and decode
-    so the formulas can't diverge. Returns (y, new_cache or None)."""
+    own position). Under a `window` a query sees itself and the window - 1
+    positions before it (the kernels' band; with a cache the read's mask).
+    Where the layer holds `attn_gate` [d, h hd] the heads' outputs are
+    multiplied by sigmoid(y attn_gate), y the normed input, one gate a
+    channel, before the output projection. One implementation serves
+    training, prefill and decode so the formulas can't diverge. Returns (y,
+    new_cache or None)."""
     b, L, d = x.shape
     h, kvh, hd = dec.n_heads, dec.n_kv_heads, dec.head_dim
-    _, q, k, v, sp, _ = _qkv_heads(x, layer, dec, cache, start_pos)
+    y, q, k, v, sp, _ = _qkv_heads(x, layer, dec, cache, start_pos)
     if cache is None:
         attn = flash_attention(q, _across_group(k, h // kvh),
                                _across_group(v, h // kvh), True,
-                               dec.sm_scale)
+                               dec.sm_scale, window)
         new_cache = None
     else:
         attn, new_cache = _cached_attention(q, k, v, cache, sp, h // kvh,
-                                            dec.sm_scale)
+                                            dec.sm_scale, window=window)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, L, h * hd)
+    if "attn_gate" in layer:
+        with jax.named_scope("attention_gate"):
+            attn = _gated_by_channel(attn, y, layer["attn_gate"])
     return jnp.einsum("bsd,de->bse", attn, layer["wo"]), new_cache
+
+
+def _gated_by_channel(t, y, w_gate):
+    """t [b, L, w] times sigmoid(y w_gate) [b, L, w], one gate a channel,
+    the sigmoid and the product in float32. The projection is named: what
+    a rematerialised block may keep of the gate (FITS_BY_KIND)."""
+    gate = checkpoint_name(jnp.einsum("bsd,de->bse", y, w_gate),
+                           "attention_gate_proj")
+    return (t.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(t.dtype)
 
 
 # The eps of an indexer's key norm (a LayerNorm of gain and bias).
@@ -1102,6 +1133,16 @@ def _attention(x, layer, dec, cache, start_pos, shared, index, window):
     return (*attention(x, layer, dec, cache, start_pos), shared)
 
 
+def _windowed_attention(x, layer, dec, cache, start_pos, shared, index,
+                        window):
+    return (*attention(x, layer, dec, cache, start_pos, window), shared)
+
+
+def _attention_nope(x, layer, dec, cache, start_pos, shared, index, window):
+    return (*attention(x, layer, dec._replace(rope_base=None), cache,
+                       start_pos), shared)
+
+
 def _latent_attention(x, layer, dec, cache, start_pos, shared, index, window):
     return (*latent_attention(x, layer, dec, cache, start_pos), shared)
 
@@ -1228,6 +1269,8 @@ MIXERS: Dict[str, Mixer] = {
     LATENT_ATTENTION: Mixer(_latent_attention, _latent_state),
     SPARSE_ATTENTION: Mixer(_sparse_attention, _sparse_state),
     KDA: Mixer(_kda, _kda_state),
+    WINDOWED_ATTENTION: Mixer(_windowed_attention, _kv_state, windowed=True),
+    ATTENTION_NOPE: Mixer(_attention_nope, _kv_state),
 }
 
 
@@ -1364,17 +1407,23 @@ keep_kernel_outputs = jax.checkpoint_policies.save_only_these_names(
 # layer there for the copies' 268): where the step has the room a block
 # makes only k's and v's copies again, a broadcast each, the head norms'
 # inverse roots, and `ln1` for the projections' weight gradients.
+# A windowed or position-free attention layer of a stack that mixes the two
+# (Trinity's: 48 query heads over 8 key-value heads of 128, a gate a
+# channel) keeps the kernel's output and lse and no q, k or v among what
+# EVERY such block keeps: as the kernels take them k and v are copies
+# across a group of six, 101 MB each a layer at 8,192 tokens beside q's
+# 101, where the step's state is three quarters of the chip (0.40 GB a
+# block with them, 2.34 GB over five blocks against 0.83 without: the plan
+# then has 0.6 GB less than the chip; PERF.md section 4). What they are made
+# from is among the kind's candidates below.
+_LESS_QKV = tuple(
+    name for name in KEPT_UNDER_REMAT
+    if name not in ("flash_attention_k", "flash_attention_v",
+                    "flash_attention_q"))
 KEPT_BY_KIND: Dict[str, Tuple[str, ...]] = {
-    LATENT_ATTENTION: tuple(
-        name for name in KEPT_UNDER_REMAT
-        if name not in ("flash_attention_k", "flash_attention_v",
-                        "flash_attention_q"))
-    + ("mla_latent", "mla_k_rope"),
-    SPARSE_ATTENTION: tuple(
-        name for name in KEPT_UNDER_REMAT
-        if name not in ("flash_attention_k", "flash_attention_v",
-                        "flash_attention_q"))
-    + ("sparse_selected", "sparse_index_grads"),
+    LATENT_ATTENTION: _LESS_QKV + ("mla_latent", "mla_k_rope"),
+    SPARSE_ATTENTION: _LESS_QKV + ("sparse_selected", "sparse_index_grads"),
+    WINDOWED_ATTENTION: _LESS_QKV, ATTENTION_NOPE: _LESS_QKV,
     # A KDA layer keeps what a gated-delta-rule layer does, under its own
     # kernels' names (ops/kda.py): the state ENTERING each chunk of 64
     # tokens (the model's dtype [chunks, heads, K, V]: 268 MB a layer at
@@ -1510,13 +1559,29 @@ KEPT_WHERE_IT_FITS: Dict[str, Callable] = {
 # A KDA block's: its q | k | v projection (`kda_in`, a matmul an element)
 # and the float32 log-decay `kda_g`, a matmul an element too but four bytes
 # wide, so half the saving a byte: after every two-byte projection.
+# A windowed or position-free block's: q (KEPT_WHERE_IT_FITS's
+# `flash_attention_q`, which this kind's base set leaves out) and k at
+# kv-head width, where the kernels take six copies of it, before everything
+# else, as a latent and a sparse block's; then what q, k and v are made from
+# (the sparse kind's two projections) and the gate's projection [T, h hd], a
+# matmul an element each. By the clock a projection saves four times what q
+# does a byte (with its projection kept q is a head norm and a rotary away),
+# but the plan that takes the projections first (q and k at three passes of
+# their bytes) reads 16.54 GB by XLA's total for Trinity's step compiled for
+# a v5e, over the chip, where this order reads 15.17 for the same 0.82 GB
+# kept: XLA holds more than a kept projection's bytes, and `_reserve` has no
+# term for it yet (PERF.md section 7).
+_GATED_GQA_FITS = {"attention_q_proj": _a_matmul,
+                   "attention_kv_proj": _a_matmul,
+                   "attention_gate_proj": _a_matmul,
+                   "attention_k_heads": _first}
 FITS_BY_KIND: Dict[str, Dict[str, Callable]] = {
     SPARSE_ATTENTION: {"attention_q_proj": _a_matmul,
                        "attention_kv_proj": _a_matmul,
                        "attention_k_heads": _first,
                        "sparse_attention_out": _matmuls(2)},
-    KDA: {"kda_in": _a_matmul, "kda_g": _a_matmul}}
-
+    KDA: {"kda_in": _a_matmul, "kda_g": _a_matmul},
+    WINDOWED_ATTENTION: _GATED_GQA_FITS, ATTENTION_NOPE: _GATED_GQA_FITS}
 
 def _fits(kind: str) -> Dict[str, Callable]:
     """name -> cost of everything a block of `kind` may keep besides."""
@@ -1609,8 +1674,8 @@ def _scaled(t, scale: float):
 # util/profiling.by_scope files a block's device time under. Two kinds
 # that run one mixer share its name.
 MIXER_SCOPES: Dict[str, str] = {
-    kind: kind.removesuffix("_only") + "_mixer" for kind in MIXERS
-    if MIXERS[kind].apply is not None}
+    kind: kind.removesuffix("_only").removesuffix("_nope") + "_mixer"
+    for kind in MIXERS if MIXERS[kind].apply is not None}
 
 
 @jax.custom_vjp

@@ -927,7 +927,11 @@ def _forward_call(qf, kf, vf, sel=None, *, causal: bool, sm_scale: float,
     # A scope, never pallas_call(name=...): the scope reaches the name of
     # the HLO instruction, which is what a device trace shows, and leaves
     # kernel_name (_fwd_kernel) as it is (util/profiling.py DEVICE_SCOPES).
-    with jax.named_scope("flash_attention_fwd"):
+    # Under a window the name gains `_window`, here and round the two
+    # backward calls: a trace tells the banded calls' rows from the full
+    # ones', and whoever matches the kernel's name as a substring reads both.
+    with jax.named_scope("flash_attention_fwd") if window is None \
+            else jax.named_scope("flash_attention_fwd_window"):
         return fwd(*operands)
 
 
@@ -1113,7 +1117,9 @@ def _backward_pallas(kernel, mirrored: bool, q, v, *, causal: bool,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "plan", "window", "heads"))
 def _dq_call(*operands, **static):
-    with jax.named_scope("flash_attention_dq"):
+    with jax.named_scope("flash_attention_dq") \
+            if static.get("window") is None \
+            else jax.named_scope("flash_attention_dq_window"):
         return _backward_pallas(_dq_kernel, False, operands[0], operands[2],
                                 **static)(*operands)
 
@@ -1121,7 +1127,9 @@ def _dq_call(*operands, **static):
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "plan", "window", "heads"))
 def _dkv_call(*operands, **static):
-    with jax.named_scope("flash_attention_dkv"):
+    with jax.named_scope("flash_attention_dkv") \
+            if static.get("window") is None \
+            else jax.named_scope("flash_attention_dkv_window"):
         return _backward_pallas(_dkv_kernel, True, operands[0], operands[2],
                                 **static)(*operands)
 

@@ -128,6 +128,12 @@ DEVICE_SCOPES: Dict[str, str] = {
                           "_dq_kernel pallas_call",
     "flash_attention_dkv": "ops/attention.py _flash_backward, the "
                            "_dkv_kernel pallas_call",
+    **{scope + "_window": f"ops/attention.py, the `{scope}` call under a "
+                          "window: the same kernel over the band (a "
+                          "reader that matches the kernel's name as a "
+                          "substring reads both rows)"
+       for scope in ("flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv")},
     "grouped_matmul_fwd": "ops/grouped_matmul.py _gmm, the _gmm_kernel "
                           "pallas_call: an expert layer's gate, up and "
                           "down matmuls",
@@ -206,6 +212,9 @@ DEVICE_SCOPES: Dict[str, str] = {
     "kda_gate_norm": "models/decoder.py kda: the RMSNorm a head of the "
                      "rule's output, then sigmoid(y W_g) times it, one "
                      "gate a head",
+    "attention_gate": "models/decoder.py attention where the layer holds "
+                      "`attn_gate`: sigmoid(y W_g), one gate a channel, "
+                      "times the heads' outputs before W_o",
     "mla_gate": "models/decoder.py latent_attention where the layer holds "
                 "`head_gate`: sigmoid(y W_g), one gate a head, times the "
                 "heads' outputs before W_o",
@@ -271,11 +280,13 @@ DEVICE_SCOPES: Dict[str, str] = {
     # no kernel's name covers: what `by_scope` files a step's time under.
     **{kind + "_mixer": "models/decoder.py _block: the sequence-mixer "
                         f"branch of a `{kind}` block (decoder.MIXER_SCOPES; "
-                        "a kind `*_only` runs under its mixer's name), from "
+                        "a kind `*_only` or `*_nope` runs under its "
+                        "mixer's name), from "
                         "the norm the mixer reads to the residual add"
        for kind in ("attention", "mamba2", "mamba1", "gated_delta", "gmu",
                     "diff_windowed", "diff_full", "diff_cross", "short_conv",
-                    "latent_attention", "sparse_attention", "kda")},
+                    "latent_attention", "sparse_attention", "kda",
+                    "windowed_attention")},
     "sparse_index_proj": "models/decoder.py _index_heads: a lightning "
                          "indexer's three projections of the detached "
                          "normed input, its key norm and the rotary of q_I "

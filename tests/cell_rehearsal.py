@@ -83,14 +83,17 @@ runpy.run_path("chipbench/run.py", run_name="__main__")
 """
 
 
-def run_cell(manifest, cell, seed, trace):
+def run_cell(manifest, cell, seed, trace, seconds=2.0):
     """`run.py --rehearsal` on the cell: (the detail line, the last line)
     of a run that was correct on the CPU, its loss inside its tolerance
     of the reference's and its metrics among those BENCHMARK.json lists
-    the cell under on that side of `--trace`."""
+    the cell under on that side of `--trace`. The window is `seconds`
+    long: it has to hold a report group of the tiny steps on a machine
+    whose cores five other test workers share (drivers/train.py refuses a
+    window that closed before one did)."""
     proc = subprocess.run(
         [sys.executable, "-c", RUN_PY, "--rehearsal", manifest,
-         "--workload", cell, "--seed", str(seed), "--seconds", "2.0",
+         "--workload", cell, "--seed", str(seed), "--seconds", str(seconds),
          "--trace", str(trace)],
         capture_output=True, text=True, timeout=600, env=subprocess_env(),
         cwd=ROOT)

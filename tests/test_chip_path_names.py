@@ -221,7 +221,7 @@ def test_the_table_lists_exactly_the_names_the_program_emits():
         "MIXER_SCOPES[kind]": {"ray_tpu/models/decoder.py"}}
     assert set(decoder.MIXER_SCOPES) == {
         kind for kind, row in decoder.MIXERS.items() if row.apply}
-    assert len(set(decoder.MIXER_SCOPES.values())) == 12
+    assert len(set(decoder.MIXER_SCOPES.values())) == 13
     assert set(scopes) | set(decoder.MIXER_SCOPES.values()) \
         == set(profiling.DEVICE_SCOPES)
     assert not set(scopes) & set(decoder.MIXER_SCOPES.values())

@@ -42,7 +42,13 @@ def test_cell_runs_end_to_end_on_the_cpu(manifest_path):
     decides `correct` (the step's summed loss against the reference's), the
     driver's falling-loss check of a batch against itself, the trace's
     reduction and every reader the cell is listed under."""
-    detail, _ = rehearsal.run_cell(manifest_path, CELL, 2147483900, 1)
+    # (a window of 6 s: the first traced group of two tiny steps is 0.4 s
+    # alone, the profiler's start in it, and 2 s leave it five times of room
+    # on a machine whose cores five other workers share; the test failed in
+    # the driver's run of PR 64's tree and passes alone (22 s) and under six
+    # workers of rehearsals here either way: load, not the tree)
+    detail, _ = rehearsal.run_cell(manifest_path, CELL, 2147483900, 1,
+                                   seconds=6.0)
     checks = detail["checks"]
     # L = CE + 0.001 balance + two layers' L_I at a vocabulary of 512
     assert 6.3 < checks["loss_vs_reference"]["want"] < 6.9

@@ -28,6 +28,7 @@ from ray_tpu.models.keye_vl2 import KeyeVL2Config, keye_vl2_loss
 from ray_tpu.models.xing4 import Xing4Config, xing4_loss
 from ray_tpu.models.bailing_hybrid import (BailingHybridConfig,
                                            bailing_hybrid_loss)
+from ray_tpu.models.afmoe import AfmoeConfig, afmoe_loss
 
 FAMILIES = {
     "gpt": (GPTConfig, gpt_loss),
@@ -42,12 +43,14 @@ FAMILIES = {
     "glm4_moe_lite": (Glm4MoeLiteConfig, glm4_moe_lite_loss),
     "keye_vl2": (KeyeVL2Config, keye_vl2_loss),
     "bailing_hybrid": (BailingHybridConfig, bailing_hybrid_loss),
+    "afmoe": (AfmoeConfig, afmoe_loss),
 }
 KINDS = (decoder.ATTENTION, decoder.MAMBA2, decoder.MAMBA1,
          decoder.GATED_DELTA, decoder.GMU, decoder.DIFF_WINDOWED,
          decoder.DIFF_FULL, decoder.DIFF_CROSS, decoder.ATTENTION_ONLY,
          decoder.MAMBA2_ONLY, decoder.EXPERTS, decoder.SHORT_CONV,
-         decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION, decoder.KDA)
+         decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION, decoder.KDA,
+         decoder.WINDOWED_ATTENTION, decoder.ATTENTION_NOPE)
 STATELESS = (decoder.GMU, decoder.DIFF_CROSS, decoder.EXPERTS)
 
 
@@ -58,8 +61,8 @@ def family(request):
     return dataclasses.replace(config.tiny(), dtype=jnp.float32), loss
 
 
-def test_the_table_has_the_fifteen_kinds_and_the_tiny_models_run_them_all():
-    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 15
+def test_the_table_has_the_seventeen_kinds_and_the_tiny_models_run_them_all():
+    assert set(decoder.MIXERS) == set(KINDS) and len(set(KINDS)) == 17
     run = {kind for config, _ in FAMILIES.values()
            for kind in config.tiny().decoder().kinds}
     assert run == set(KINDS)
@@ -106,7 +109,7 @@ def test_a_layers_channel_mixer_is_named_as_its_sequence_mixer_is(family):
         assert with_module._replace(mlp=dec.mlp) == dec._replace(
             kinds=with_module.kinds)
     if isinstance(cfg, (Lfm2MoeConfig, Xing4Config, Glm4MoeLiteConfig,
-                        BailingHybridConfig)):
+                        BailingHybridConfig, AfmoeConfig)):
         dense = cfg.n_dense_layers
         assert 0 < dense < cfg.n_layers
         assert set(dec.mlp[:dense]) == {decoder.swiglu_mlp}

@@ -370,11 +370,15 @@ def test_the_second_table_is_beside_the_first():
         == {"flash_attention_q"}
     assert [kind for kind in decoder.MIXERS
             if "flash_attention_q" not in decoder._kept(kind)] \
-        == [decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION]
-    # and a third beside both, by kind: a sparse block's and a KDA
-    # block's own candidates, names of neither table
-    assert list(decoder.FITS_BY_KIND) == [decoder.SPARSE_ATTENTION,
-                                          decoder.KDA]
+        == [decoder.LATENT_ATTENTION, decoder.SPARSE_ATTENTION,
+            decoder.WINDOWED_ATTENTION, decoder.ATTENTION_NOPE]
+    # and a third beside both, by kind: a sparse block's, a KDA block's and
+    # a gated grouped-query block's own candidates, names of neither table
+    assert list(decoder.FITS_BY_KIND) == [
+        decoder.SPARSE_ATTENTION, decoder.KDA, decoder.WINDOWED_ATTENTION,
+        decoder.ATTENTION_NOPE]
+    assert decoder.FITS_BY_KIND[decoder.WINDOWED_ATTENTION] \
+        is decoder.FITS_BY_KIND[decoder.ATTENTION_NOPE]
     assert set(decoder.FITS_BY_KIND[decoder.KDA]) == {"kda_in", "kda_g"}
     for own in decoder.FITS_BY_KIND.values():
         assert not set(own) & (set(decoder.KEPT_WHERE_IT_FITS)
